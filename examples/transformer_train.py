@@ -117,6 +117,9 @@ def main():
             pp_virtual_stages=args.virtual_stages, sp_impl=args.sp_impl)
         seq_len = args.seq_len
     if ctx.is_chief:
+        dev = jax.devices()[0]
+        print(f"device: platform={dev.platform} kind={dev.device_kind!r} "
+              f"count={jax.device_count()}", flush=True)
         print(f"transformer: mesh={dict(mesh.shape)} seq={seq_len} "
               f"experts={cfg.n_experts}", flush=True)
 

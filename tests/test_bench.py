@@ -28,6 +28,13 @@ def test_flops_formulas():
         6 * (784 * 100 + 100 * 10) * 100
 
 
+def test_unknown_device_kind_is_an_error():
+    """No default peak: the CPU is not in the table, so an MFU against
+    it must raise instead of assuming the v5e figure."""
+    with pytest.raises(RuntimeError, match="no published bf16 peak"):
+        bench._peak_flops()
+
+
 def test_bandwidth_multi_device_path():
     out = bench.bench_bandwidth(sizes=[1 << 18])
     assert out["allreduce_gbps"] is not None and out["allreduce_gbps"] > 0
@@ -41,14 +48,10 @@ def test_decode_bench_smoke():
 
 
 def test_mnist_bench_smoke():
-    """Runs in a CLEAN subprocess with the persistent compilation cache
-    off: jaxlib 0.4.x CPU leaves the native heap latently corrupted
-    after deserializing cached multi-device executables, and THIS
-    workload's allocation pattern is what trips it (malloc abort /
-    SIGSEGV that killed entire suite runs at this test).  By the time
-    this test runs, the suite process has live cache-deserialized
-    executables, so in-process isolation is impossible — the subprocess
-    asserts the same quantities from a pristine heap."""
+    """Runs in a clean subprocess on the 8-device virtual CPU mesh.  The
+    CPU has no published peak and ``_peak_flops`` refuses to guess one, so
+    the test names a stand-in for it: only "runs and returns finite
+    values" is asserted, never the MFU's size."""
     import json
     import os
     import subprocess
@@ -59,11 +62,11 @@ def test_mnist_bench_smoke():
         "from tfmesos_tpu.utils.platform import force_platform\n"
         "force_platform('cpu', min_host_devices=8)\n"
         "import bench\n"
+        "bench.PEAK_BF16['cpu'] = 1e12\n"
         "s, l, m = bench.bench_mnist_replica(steps=40, warmup=20)\n"
         "print(json.dumps({'steps': s, 'loss': l, 'mfu': m}))\n")
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     proc = subprocess.run([sys.executable, "-c", code], cwd=repo, env=env,
                           capture_output=True, timeout=240)
     assert proc.returncode == 0, proc.stderr.decode()

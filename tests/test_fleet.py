@@ -555,6 +555,36 @@ def test_registry_role_and_headroom_fields(stub_fleet):
     sock.close()
 
 
+def test_registry_device_field_feeds_the_devices_gauge(stub_fleet):
+    """What a replica says it runs on ({platform, kind, id, chips}) rides
+    its beats into ``device_summary`` — keyed by task node, or by address
+    for a replica launched outside the scheduler — and a malformed field
+    costs the field, never the beat."""
+    token, reg, servers = stub_fleet
+    sock = wire.connect(reg.addr)
+    tpu = {"platform": "tpu", "kind": "TPU v5 lite", "id": 0, "chips": "2"}
+    wire.send_msg(sock, {"op": "hello", "addr": "10.0.0.9:1", "capacity": 4,
+                         "node": "replica:0", "device": tpu}, token)
+    wire.send_msg(sock, {"op": "hello", "addr": "10.0.0.9:2", "capacity": 4,
+                         "device": {"platform": "cpu", "kind": "cpu",
+                                    "id": 0}}, token)
+    wire.send_msg(sock, {"op": "hello", "addr": "10.0.0.9:3",
+                         "capacity": 4}, token)
+    assert _wait(lambda: len(reg.alive()) == 3)
+    assert reg.device_summary() == {
+        "replica:0": tpu,
+        "10.0.0.9:2": {"platform": "cpu", "kind": "cpu", "id": 0,
+                       "chips": ""}}
+    wire.send_msg(sock, {"op": "heartbeat", "addr": "10.0.0.9:1",
+                         "device": {"platform": "tpu", "id": "x"}}, token)
+    time.sleep(0.1)
+    assert reg.device_summary()["replica:0"] == tpu
+    view = {r["addr"]: r for r in reg.registry_view()["replicas"]}
+    assert view["10.0.0.9:1"]["device"] == tpu
+    assert "device" not in view["10.0.0.9:3"]
+    sock.close()
+
+
 def test_registry_spec_field_and_fleet_acceptance_rate(stub_fleet):
     """The spec observability satellite, jax-free: the ``spec``
     heartbeat field lands on ReplicaInfo, and spec_summary() (the

@@ -8,8 +8,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 
-from tfmesos_tpu.compat import shard_map
 from tfmesos_tpu.models import transformer
 from tfmesos_tpu.ops.layers import cross_entropy_loss, fused_linear_cross_entropy
 from tfmesos_tpu.parallel.mesh import build_mesh

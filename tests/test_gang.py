@@ -337,6 +337,9 @@ def _dyn_scheduler():
         def revive(self):
             pass
 
+        def unplaceable(self, task):
+            return None
+
     sched = TPUMesosScheduler.__new__(TPUMesosScheduler)
     # The minimum state add_gang/_batch_order/remove_task touch — the
     # full constructor wants a live backend + wire server.

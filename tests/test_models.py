@@ -31,18 +31,9 @@ def test_transformer_sp_mesh_matches_single_device():
     params = transformer.init_params(TINY, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, TINY.vocab_size)
     ref = transformer.forward(TINY, params, tokens)
-    with jax.sharding.use_mesh(mesh) if hasattr(jax.sharding, "use_mesh") else _null():
-        got = jax.jit(lambda p, t: transformer.forward(TINY, p, t, mesh))(params, tokens)
+    got = jax.jit(lambda p, t: transformer.forward(TINY, p, t, mesh))(params, tokens)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
-
-
-class _null:
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *a):
-        return False
 
 
 def test_transformer_pp_matches_sequential():

@@ -5,9 +5,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from tfmesos_tpu.compat import shard_map
 from tfmesos_tpu.parallel import MeshSpec, build_mesh, mesh_from_jobs
 from tfmesos_tpu.parallel import collectives as col
 from tfmesos_tpu.parallel.pipeline import (pipeline_apply, stack_stage_params,

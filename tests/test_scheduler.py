@@ -313,7 +313,7 @@ def test_local_spawn_failure_exhausts_into_cluster_error(monkeypatch):
     assert time.monotonic() - t0 < 30.0     # << start_timeout
     assert s.task_failure_count["w:0"] == MAX_FAILURE_COUNT
     # Accounting rolled back on every failed spawn.
-    assert s.backend._in_use == [0.0, 0.0, 0]
+    assert s.backend._in_use == [0.0, 0.0]
 
 
 def test_local_spawn_failure_once_recovers_via_revive(monkeypatch):

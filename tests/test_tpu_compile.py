@@ -1,0 +1,182 @@
+"""The Pallas kernels of the main path compile for a v5e — with no chip.
+
+The TPU compiler is installed wherever libtpu is, and compiles for a device
+that is *described*, not attached (``jax.experimental.topologies``).  That
+catches what interpret mode cannot: tilings Mosaic refuses, too much VMEM,
+lowerings the installed JAX no longer has.  Nothing runs, so nothing here
+says anything about results or speed.  Shapes are the flagship's (bf16,
+8 heads of 64, 8 layers, page 64, rows 8, max_len 1024) and the variants
+the model code can select (GQA, head dim 128, windows, int8 caches, the
+shard_map wrappers, ring and Ulysses inner kernels).
+
+Every case forces the kernel path (``use_pallas=True`` / ``impl="flash"``):
+the ``jax.default_backend()`` gates see the CPU here.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from tfmesos_tpu.ops.attention import (flash_attention, flash_decode,
+                                       flash_decode_paged,
+                                       sharded_flash_attention,
+                                       sharded_flash_decode)
+from tfmesos_tpu.ops.quant import QTensor, quantize_int8
+from tfmesos_tpu.parallel.ring_attention import ring_attention
+from tfmesos_tpu.parallel.ulysses import ulysses_attention
+
+BF16, I8, I32, F32 = jnp.bfloat16, jnp.int8, jnp.int32, jnp.float32
+B, H, D, L = 8, 8, 64, 8            # rows, heads, head dim, layers
+M, PAGE, NP, POOL = 1024, 64, 16, 129   # max_len, page, table width, pages
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """A described 2x2 v5e.  The persistent compile cache is off around
+    these compiles: an executable for an unattached device is written to it
+    but cannot be read back, and every later run would warn."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no libtpu, or it refuses
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _grad(fn):
+    return jax.grad(lambda q, k, v: fn(q, k, v).astype(F32).sum(),
+                    argnums=(0, 1, 2))
+
+
+def _flash(**kw):
+    return lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                           use_pallas=True, **kw)
+
+
+def _paged(q, k, v, table, pos, layer, *self_kv):
+    return flash_decode_paged(q, k, v, table, pos, layer=layer,
+                              use_pallas=True, self_kv=self_kv or None)
+
+
+def _linear(q, k, v, pos, layer):
+    return flash_decode(q, k, v, pos, layer=layer, use_pallas=True)
+
+
+def _qtensor(shape):
+    """An int8 cache/pool leaf with its lane-major scales."""
+    return QTensor((shape, I8), (shape[:-2] + (1, shape[-2]), F32))
+
+
+_POS, _LAYER, _TABLE = ((B,), I32), ((), I32), ((B, NP), I32)
+_POOL = ((L, POOL, H, PAGE, D), BF16)
+
+# name -> (mesh axes or None, fn(mesh), argument (shape, dtype[, spec])s,
+#          kernels expected in the program)
+CASES = {
+    "flash_fwd": (None, lambda m: _flash(),
+                  [((B, 2048, H, D), BF16)] * 3, 1),
+    "flash_fwd_bwd": (None, lambda m: _grad(_flash()),
+                      [((B, 2048, H, D), BF16)] * 3, 3),
+    "flash_gqa_fwd_bwd": (None, lambda m: _grad(_flash()),
+                          [((B, 2048, H, D), BF16)]
+                          + [((B, 2048, 2, D), BF16)] * 2, 3),
+    "flash_window_fwd_bwd": (None, lambda m: _grad(_flash(window=256)),
+                             [((B, 2048, H, D), BF16)] * 3, 3),
+    # A length with no 8-aligned divisor runs as ONE block; Mosaic refused
+    # its dynamically-sliced K read (found by transformer.generate on the
+    # chip at a 410-token prompt) until the single block was read whole.
+    "flash_odd_length_fwd_bwd": (None, lambda m: _grad(_flash()),
+                                 [((1, 410, H, D), BF16)] * 3, 3),
+    "flash_d128_fwd_bwd": (None, lambda m: _grad(_flash()),
+                           [((4, 1024, H, 128), BF16)] * 3, 3),
+    "decode_linear": (None, lambda m: _linear,
+                      [((B, H, D), BF16)]
+                      + [((L, B, H, M, D), BF16)] * 2 + [_POS, _LAYER], 1),
+    "decode_linear_int8": (None, lambda m: _linear,
+                           [((B, H, D), BF16)]
+                           + [_qtensor((L, B, H, M, D))] * 2
+                           + [_POS, _LAYER], 1),
+    "paged_t1": (None, lambda m: _paged,
+                 [((B, H, D), BF16), _POOL, _POOL, _TABLE, _POS, _LAYER], 1),
+    "paged_t1_self": (None, lambda m: _paged,
+                      [((B, 1, H, D), BF16), _POOL, _POOL, _TABLE, _POS,
+                       _LAYER] + [((B, 1, H, D), BF16)] * 2, 1),
+    "paged_t8_self": (None, lambda m: _paged,
+                      [((B, 8, H, D), BF16), _POOL, _POOL, _TABLE, _POS,
+                       _LAYER] + [((B, 8, H, D), BF16)] * 2, 1),
+    "paged_int8_t1_self": (None, lambda m: _paged,
+                           [((B, 1, H, D), BF16)]
+                           + [_qtensor((L, POOL, H, PAGE, D))] * 2
+                           + [_TABLE, _POS, _LAYER]
+                           + [((B, 1, H, D), BF16)] * 2, 1),
+    # Grouped queries over a one-token self chunk: Mosaic refused this
+    # lowering until the self scores were widened to f32.
+    "paged_gqa_t1_self": (None, lambda m: _paged,
+                          [((B, 1, H, D), BF16)]
+                          + [((L, POOL, 2, PAGE, D), BF16)] * 2
+                          + [_TABLE, _POS, _LAYER]
+                          + [((B, 1, 2, D), BF16)] * 2, 1),
+    "quantize_int8": (None, lambda m: lambda x: quantize_int8(
+        x, use_pallas=True), [((4096, 512), F32)], 1),
+    "quantize_int8_stochastic": (None, lambda m: lambda x: quantize_int8(
+        x, stochastic=True, seed=3, use_pallas=True),
+        [((4096, 512), F32)], 1),
+    "sharded_flash_decode": (
+        {"dp": 2, "tp": 2},
+        lambda m: lambda q, k, v, pos, layer: sharded_flash_decode(
+            q, k, v, pos, m, layer=layer, use_pallas=True),
+        [((B, H, D), BF16, P("dp", "tp", None))]
+        + [((L, B, H, M, D), BF16, P(None, "dp", "tp", None, None))] * 2
+        + [((B,), I32, P("dp")), ((), I32, P())], 1),
+    "sharded_flash_attention_fwd_bwd": (
+        {"fsdp": 2, "tp": 2},
+        lambda m: _grad(lambda q, k, v: sharded_flash_attention(
+            q, k, v, m, causal=True, use_pallas=True)),
+        [((B, 2048, H, D), BF16, P("fsdp", None, "tp", None))] * 3, 3),
+    "ring_flash_fwd_bwd": (
+        {"dp": 1, "sp": 4},
+        lambda m: _grad(lambda q, k, v: ring_attention(
+            q, k, v, m, causal=True, impl="flash")),
+        [((2, 4096, H, D), BF16, P("dp", "sp", None, None))] * 3, 3),
+    "ulysses_flash_fwd_bwd": (
+        {"dp": 1, "sp": 4},
+        lambda m: _grad(lambda q, k, v: ulysses_attention(
+            q, k, v, m, causal=True, use_pallas=True)),
+        [((2, 4096, H, D), BF16, P("dp", "sp", None, None))] * 3, 3),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_compiles_for_v5e(topo, name):
+    axes, make, args, n_kernels = CASES[name]
+    mesh = None
+    if axes is not None:
+        mesh = Mesh(np.array(topo.devices).reshape(tuple(axes.values())),
+                    tuple(axes))
+
+    def struct(shape, dtype, spec=None):
+        sharding = (SingleDeviceSharding(topo.devices[0]) if mesh is None
+                    else NamedSharding(mesh, spec))
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    def arg(a):
+        if isinstance(a, QTensor):
+            return QTensor(struct(*a.values), struct(*a.scales))
+        return struct(*a)
+
+    compiled = jax.jit(make(mesh)).lower(*map(arg, args)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= n_kernels

@@ -11,7 +11,7 @@ speaking JSON/RecordIO directly with no pymesos dependency).
 from __future__ import annotations
 
 import abc
-from typing import List, Sequence
+from typing import List, Optional, Sequence
 
 from tfmesos_tpu.spec import Offer
 
@@ -63,6 +63,12 @@ class ResourceBackend(abc.ABC):
 
     def acknowledge(self, status) -> None:  # only meaningful for Mesos
         pass
+
+    def unplaceable(self, task) -> Optional[str]:
+        """Why ``task`` can never be placed, where the backend can tell
+        before any offer (the local host's chip count is fixed; a Mesos
+        cluster's agents come and go, so it cannot)."""
+        return None
 
 
 def first_fit(tasks, offer: Offer) -> List:

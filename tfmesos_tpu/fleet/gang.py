@@ -33,8 +33,8 @@ generation.  The leader never serves while forming: it registers
 ``warming`` and only flips routable once all members have joined.
 
 On a real pod slice the members hold mesh shards of the model and the
-dispatch fan-out carries per-shard work; under CI (CPU, and a jax
-without ``shard_map``) members MIRROR-execute the full request — the
+dispatch fan-out carries per-shard work; today members
+MIRROR-execute the full request, each on its own device — the
 wire contract, placement atomicity, fencing, and failure semantics
 are exactly the pod-slice ones, and the digest check is exactly the
 SPMD token-identity invariant.  Everything here is jax-free; the

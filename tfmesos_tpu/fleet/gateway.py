@@ -207,6 +207,10 @@ class Gateway:
         # every field.
         if hasattr(self.registry, "gang_summary"):
             metrics.register_gauge("gangs", self.registry.gang_summary)
+        # What each replica runs on (platform, device kind, device id),
+        # as its own process reported it.
+        if hasattr(self.registry, "device_summary"):
+            metrics.register_gauge("devices", self.registry.device_summary)
         # Items that expired while queued still owe the client an
         # explicit answer — the controller hands them back here from
         # whichever worker's get() swept them.

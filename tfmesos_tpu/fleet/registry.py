@@ -166,6 +166,13 @@ class ReplicaInfo:
     gang_size: int = 1
     gang_live: int = -1
     gang_coord: str = ""
+    # What the replica's process runs on, as JAX reported it to the
+    # replica itself ({platform, kind, id}) plus the host chips its
+    # backend gave it ({chips}: "2", "0,1,2,3", "" on the CPU) — a
+    # heartbeat field, so the gateway's ``devices`` gauge shows per
+    # replica whether it came up on the chip it was launched with.
+    # None until advertised.
+    device: Optional[dict] = None
 
 
 def _advertises_prefix(rep: "ReplicaInfo") -> int:
@@ -551,6 +558,15 @@ class ReplicaRegistry:
                     rep.kv_headroom = int(msg["kv_headroom"])
                 except (TypeError, ValueError):
                     pass    # a bad field never costs the beat
+            raw_dev = msg.get("device")
+            if isinstance(raw_dev, dict):
+                try:
+                    rep.device = {"platform": str(raw_dev["platform"])[:32],
+                                  "kind": str(raw_dev["kind"])[:64],
+                                  "id": int(raw_dev["id"]),
+                                  "chips": str(raw_dev.get("chips", ""))[:64]}
+                except (KeyError, TypeError, ValueError):
+                    pass
             raw_gang = msg.get("gang")
             if isinstance(raw_gang, dict):
                 # Gang identity rides the leader's beats as one dict;
@@ -759,6 +775,16 @@ class ReplicaRegistry:
                 d["target"] = target
         return out
 
+    def device_summary(self) -> Dict[str, dict]:
+        """The device each live replica reported ({platform, kind, id,
+        chips}), keyed by its task node ("job:index") or, for a replica
+        launched outside the scheduler, its address — the gateway's
+        ``devices`` gauge."""
+        with self._lock:
+            return {rep.node or rep.addr: dict(rep.device)
+                    for rep in self._table.values()
+                    if rep.device is not None and rep.state != DEAD}
+
     def gang_lookup(self, gang_id) -> Dict[str, Any]:
         """Resolve one gang's leader-coordination address and launch
         generation (the member-rendezvous reply).  ``found`` stays
@@ -914,6 +940,8 @@ class ReplicaRegistry:
                     d["warm_pool"] = True
                 if rep.adapter_version:
                     d["adapter_version"] = rep.adapter_version
+                if rep.device is not None:
+                    d["device"] = rep.device
                 if rep.gang_id or rep.gang_size > 1:
                     d["gang"] = {"id": rep.gang_id,
                                  "size": rep.gang_size,

@@ -1389,6 +1389,21 @@ def main(argv: Optional[List[str]] = None) -> int:
         # Dedicated fabric holder: jax-free, no batcher build at all.
         return _kv_holder_main(args, token, generation, node)
 
+    # Every model-serving process (single replica, gang leader, gang
+    # member) refuses the wrong device before it builds anything: the
+    # same check a Mode-A task gets from runtime.initialize().
+    from tfmesos_tpu.runtime import ENV_CHIPS, check_platform
+    from tfmesos_tpu.utils.platform import enable_compile_cache
+
+    enable_compile_cache()
+    dev = check_platform()
+    # The JAX device id is local to the process (0 in every one-chip
+    # task); the host chips the backend gave it are what tells co-located
+    # replicas apart.
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "id": int(dev.id),
+              "chips": os.environ.get(ENV_CHIPS, "")}
+
     # Gang identity (docs/SERVING.md "Gang replicas"): when this
     # process was launched as one task of an N-task gang, rank 0 is
     # the LEADER — the one process that owns the fleet identity below —
@@ -1539,7 +1554,7 @@ def main(argv: Optional[List[str]] = None) -> int:
         # task node), plus the prefix-cache summary when one runs.
         beat: Dict[str, Any] = {"role": args.role,
                                 "kv_headroom": batcher.kv_headroom(),
-                                "gen": generation}
+                                "gen": generation, "device": device}
         if args.weights_version:
             beat["weights_version"] = args.weights_version
         if node:
@@ -1625,8 +1640,9 @@ def main(argv: Optional[List[str]] = None) -> int:
               f"({leader.size} members, generation {generation})",
               flush=True)
     server.set_status(None)     # routable: the next beat drops 'warming'
-    print(f"replica serving on {server.addr} (role {args.role})",
-          flush=True)
+    print(f"replica serving on {server.addr} (role {args.role}) on "
+          f"{device['platform']} {device['kind']!r} device {device['id']} "
+          f"(host chips: {device['chips'] or 'none'})", flush=True)
 
     def on_signal(signum, frame) -> None:
         log.info("signal %d: draining", signum)

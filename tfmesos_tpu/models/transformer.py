@@ -26,9 +26,10 @@ from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, PartitionSpec as P
 
-from tfmesos_tpu.compat import axis_size, shard_map
 from tfmesos_tpu.ops.attention import attend, mha_reference
 from tfmesos_tpu.ops.layers import (cross_entropy_loss,
                                     data_parallel_fused_cross_entropy,

@@ -19,10 +19,9 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from tfmesos_tpu.compat import shard_map
 
 NEG_INF = float("-inf")
 
@@ -153,8 +152,14 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, cfg: _FlashCfg,
 
     def body(j, carry):
         o, m, l = carry
-        k_blk = k_ref[0, 0, pl.ds(j * bk, bk), :]  # [bk, d]
-        v_blk = v_ref[0, 0, pl.ds(j * bk, bk), :]
+        if bk == seq_len:
+            # One K block (a length with no 8-aligned divisor lands here):
+            # read it whole — Mosaic cannot prove a dynamic start of
+            # j * bk tile-aligned when bk is not a multiple of 8.
+            k_blk, v_blk = k_ref[0, 0, :, :], v_ref[0, 0, :, :]
+        else:
+            k_blk = k_ref[0, 0, pl.ds(j * bk, bk), :]  # [bk, d]
+            v_blk = v_ref[0, 0, pl.ds(j * bk, bk), :]
         s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # [bq, bk]
         s = s * cfg.scale
@@ -957,6 +962,13 @@ def _flash_decode_paged_kernel(s_ref, pt_ref, q_ref, k_ref, v_ref, *rest,
             for h in range(head_block):
                 sl = slice(h * tg, (h + 1) * tg)
                 q = q_ref[0, h, :, :]
+                if kself_ref.shape[2] == 1:
+                    # A one-token chunk makes this a [tg, d] x [d, 1]
+                    # product, which Mosaic lowers as a broadcast
+                    # multiply that must keep its element type: widen
+                    # first (bf16 -> f32 in the broadcast is refused for
+                    # grouped queries, tg > 1).
+                    q = q.astype(jnp.float32)
                 s = _decode_block_scores(q, kself_ref[0, h, :, :], scale)
                 # Intra-chunk causality: self slot ss holds chunk token
                 # ss's K/V, and row tt attends slots <= tt (t = 1 masks

@@ -12,9 +12,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-from tfmesos_tpu.compat import shard_map
 
 
 def rms_norm(x, weight, eps: float = 1e-6):

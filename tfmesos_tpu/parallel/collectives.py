@@ -13,8 +13,7 @@ from typing import Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
-
-from tfmesos_tpu.compat import axis_size
+from jax.lax import axis_size
 
 AxisName = Union[str, Sequence[str]]
 
@@ -96,10 +95,6 @@ def ppermute_shift(x, axis: str, shift: int = 1):
 
 def axis_index(axis: str):
     return jax.lax.axis_index(axis)
-
-
-# axis_size is re-exported from tfmesos_tpu.compat (imported above): the
-# jax-version-portable size of a named mesh axis.
 
 
 def barrier(axis: AxisName):

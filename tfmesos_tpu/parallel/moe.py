@@ -30,9 +30,9 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, PartitionSpec as P
-
-from tfmesos_tpu.compat import axis_size, shard_map
 
 
 def _routing(x, router_w, n_experts: int, capacity: int, top_k: int = 1

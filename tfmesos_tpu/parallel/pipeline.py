@@ -40,9 +40,9 @@ from typing import Any, Callable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from tfmesos_tpu.compat import shard_map
 from tfmesos_tpu.parallel.collectives import ppermute_shift
 from tfmesos_tpu.parallel.sharding import data_axes
 

@@ -31,9 +31,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, PartitionSpec as P
 
-from tfmesos_tpu.compat import axis_size, shard_map
 from tfmesos_tpu.parallel.sharding import data_axes
 
 

@@ -24,6 +24,9 @@ ENV_CLUSTER_DEF = "TPUMESOS_CLUSTER_DEF"
 ENV_JOB_NAME = "TPUMESOS_JOB_NAME"
 ENV_TASK_INDEX = "TPUMESOS_TASK_INDEX"
 ENV_MESH_AXES = "TPUMESOS_MESH_AXES"
+#: The host chips the local backend gave this task ("2", "0,1,2,3"), as
+#: indices into the host's device nodes — whatever else libtpu is told.
+ENV_CHIPS = "TPUMESOS_CHIPS"
 
 _initialized = False
 
@@ -122,12 +125,8 @@ def initialize(ctx: Optional[TaskContext] = None) -> TaskContext:
     global _initialized
     if ctx is None:
         ctx = TaskContext.from_env()
-    # Make the env var authoritative even when a site-installed PJRT plugin
-    # pre-set the platform via jax.config at interpreter start (config beats
-    # JAX_PLATFORMS; without this a multi-process CPU cluster silently falls
-    # apart into single-device processes).
-    from tfmesos_tpu.utils.platform import force_platform
-    force_platform()
+    from tfmesos_tpu.utils.platform import enable_compile_cache
+    enable_compile_cache()
     import jax
     if ctx.world_size > 1 and not _initialized:
         jax.distributed.initialize(
@@ -136,18 +135,24 @@ def initialize(ctx: Optional[TaskContext] = None) -> TaskContext:
             process_id=ctx.rank,
         )
         _initialized = True
-    # force_platform is best-effort (a plugin that already initialized a
-    # backend wins silently) — verify, because proceeding on the wrong
-    # platform is exactly the silent degradation this guard exists to stop.
-    # Checked only after distributed init: querying devices earlier would
-    # initialize the local backend and break jax.distributed.
+    check_platform()
+    return ctx
+
+
+def check_platform():
+    """Fail unless JAX came up on a platform ``JAX_PLATFORMS`` names, and
+    return the first local device.  The local backend launches a chip task
+    with ``JAX_PLATFORMS=tpu`` and a 0-chip task with ``cpu``, so a task
+    never carries on silently on the wrong one.  Call only after
+    ``jax.distributed.initialize``: querying devices earlier would
+    initialize the local backend and break it."""
+    import jax
+    device = jax.local_devices()[0]
     requested = os.environ.get("JAX_PLATFORMS")
     if requested:
         allowed = [p.strip() for p in requested.split(",") if p.strip()]
-        got = jax.local_devices()[0].platform
-        if got not in allowed:
+        if device.platform not in allowed:
             raise RuntimeError(
                 f"JAX_PLATFORMS={requested} was requested but the backend "
-                f"initialized as {got!r} — a site PJRT plugin pinned the "
-                "platform before runtime.initialize() ran")
-    return ctx
+                f"initialized as {device.platform!r}")
+    return device

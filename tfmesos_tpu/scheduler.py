@@ -837,9 +837,10 @@ class TPUMesosScheduler:
         if self._fatal:
             raise ClusterError(self._fatal)
         index = self._dyn_index.get(job_name, 0)
-        self._dyn_index[job_name] = index + 1
         task = Task(job_name, index, cpus=cpus, mem=mem,
                     chips=chips, cmd=cmd, volumes=self.volumes)
+        self._check_placeable(task)
+        self._dyn_index[job_name] = index + 1
         task.dynamic = True
         task.generation = self.generation
         if env:
@@ -1035,6 +1036,8 @@ class TPUMesosScheduler:
         """Bind rendezvous socket → start backend → event loop until every
         task registers → broadcast cluster config (reference start(),
         scheduler.py:320-369)."""
+        for task in self.tasks:
+            self._check_placeable(task)
         self._listen = wire.bind_ephemeral()
         self.addr = wire.sock_addr(self._listen,
                                    advertise_host=os.environ.get("TPUMESOS_ADVERTISE_HOST"))
@@ -1118,6 +1121,13 @@ class TPUMesosScheduler:
             self._start_cluster()
         finally:
             sel.close()
+
+    def _check_placeable(self, task: Task) -> None:
+        """Fail at once, not at ``start_timeout``, on a task the backend
+        already knows it can never offer resources to."""
+        why = self.backend.unplaceable(task)
+        if why:
+            raise ClusterError(f"{task.job_name}:{task.task_index} {why}")
 
     def _connection_owned(self, conn: socket.socket) -> bool:
         return any(t.connection is conn for t in self.tasks)

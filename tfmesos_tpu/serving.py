@@ -44,9 +44,9 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 
 from tfmesos_tpu import prefixhash as _ph
-from tfmesos_tpu.compat import shard_map
 from tfmesos_tpu.fleet.tracing import FlightRecorder
 from tfmesos_tpu.models.transformer import (PageAllocator, TransformerConfig,
                                             decode_step,
@@ -2116,9 +2116,8 @@ class ContinuousBatcher:
     def _make_decode(self):
         """K decode steps fused into ONE dispatch (``lax.scan``): the host
         syncs a [rows, K] token block instead of one [rows] vector per
-        token, so the per-dispatch + device-to-host round-trip cost —
-        the dominant serving cost on remote-attached runtimes, and a real
-        tax everywhere — amortizes over K tokens.  Stops and quota
+        token, so the per-dispatch + device-to-host round-trip cost
+        amortizes over K tokens.  Stops and quota
         endings are detected at block granularity: in-block steps past a
         row's end compute garbage the host discards, and their cache
         writes land either inside the row's reservation-clamped own
@@ -3914,8 +3913,7 @@ class ContinuousBatcher:
                 # newcomer's worst case.  Prefills DISPATCH inside the
                 # loop but their first-token fetches are deferred to one
                 # burst sync after it — admitting W requests costs one
-                # device-to-host round-trip, not W (the round-trip is
-                # the dominant per-call cost on remote-attached hosts).
+                # device-to-host round-trip, not W.
                 # End-to-end deadlines: cancel expired resident rows
                 # NOW, before admission — their pages free this tick,
                 # so dead work never holds a decode slot a live arrival

@@ -56,7 +56,7 @@ def make_train_step(loss_fn: Callable, optimizer: optax.GradientTransformation,
     ``steps_per_call > 1`` compiles a ``lax.scan`` of that many optimizer
     steps into ONE dispatch: batch leaves carry a leading ``[steps_per_call,
     ...]`` dim and the host pays one round-trip per K steps — the dominant
-    cost for small models on remote-attached or latency-bound runtimes.
+    cost for small models.
     Returned metrics are the last step's.
 
     ``scan_unroll`` unrolls the fused-step ``lax.scan`` body that many
@@ -297,8 +297,7 @@ def evaluate(eval_step: Callable, params, batches: Iterator,
     acc: Dict[str, list] = {}
     for _ in range(num_batches):
         # Keep device arrays: no host sync inside the loop, so batch N+1
-        # dispatches while batch N still runs (matters on remote-attached
-        # runtimes where each fetch is a full round-trip).
+        # dispatches while batch N still runs.
         for k, v in eval_step(params, next(batches)).items():
             acc.setdefault(k, []).append(v)
     return {k: float(sum(jnp.stack(vs)) / num_batches)
