@@ -3052,3 +3052,239 @@ def test_rid_seed_gives_disjoint_rid_streams(setup):
     with pytest.raises(ValueError):
         ContinuousBatcher(cfg, params, rows=2, max_len=64,
                           page_size=16, prefill_bucket=16, rid_seed=-1)
+
+
+# -- the tick recorder (docs/SERVING.md "Observability") ----------------------
+
+TICK_FIELDS = {"name", "tick", "batcher", "t", "wall_ms", "kind", "mode",
+               "k", "rows", "dur", "admitted", "prefill_tokens", "phases",
+               "idle_ms", "compiles", "compile_s"}
+TICK_PHASES = {"batcher.pull", "batcher.admit", "batcher.prefill_sync",
+               "batcher.prep", "batcher.dispatch", "batcher.readback",
+               "batcher.retire", "batcher.emit"}
+#: step mode -> (constructor arguments, the modes its blocks may carry,
+#: the mode at least one block must carry)
+TICK_MODES = {
+    "sync": ({}, {"sync"}, "sync"),
+    "overlap": ({"overlap": True}, {"overlap"}, "overlap"),
+    "pipelined": ({"pipeline_depth": 1}, {"pipelined"}, "pipelined"),
+    "chunked": ({"prefill_chunk": 8}, {"sync"}, "sync"),
+    "fused": ({"prefill_chunk": 8, "fused_prefill": True},
+              {"sync", "fused"}, "fused"),
+    "spec": ("spec", {"spec"}, "spec"),
+    "spec_overlap": ("spec_overlap", {"spec_overlap"}, "spec_overlap"),
+    "import": ({}, {"sync"}, "sync"),
+    "session": ("session", {"sync"}, "sync"),
+}
+
+
+def _check_ticks(batcher, traces, modes, must):
+    """What every step mode's tick records have in common."""
+    recs = batcher.flight.snapshot()
+    assert recs
+    for r in recs:
+        assert set(r) == TICK_FIELDS
+        assert set(r["phases"]) <= TICK_PHASES
+        assert all(ms >= 0.0 for ms in r["phases"].values())
+        assert sum(r["phases"].values()) <= r["wall_ms"] + 1e-6
+        assert 0.0 <= r["idle_ms"] <= r["phases"].get("batcher.pull",
+                                                      0.0) + 1e-9
+        assert r["kind"] in ("decode", "prefill", "mixed", "fused", "idle")
+        assert (r["name"] == "decode.block") == (
+            r["kind"] in ("decode", "mixed", "fused"))
+    ticks = [r["tick"] for r in recs]
+    assert all(b > a for a, b in zip(ticks, ticks[1:]))
+    blocks = [r for r in recs if r["name"] == "decode.block"]
+    assert blocks and {r["mode"] for r in blocks} <= modes
+    assert any(r["mode"] == must for r in blocks)
+    assert all(r["rows"] >= 1 and r["k"] >= 1 and r["dur"] >= 0.0
+               for r in blocks)
+    by_tick = {r["tick"]: r for r in recs}
+    for tr in traces:       # the admit event names the tick that caused it
+        # (an import's trace begins with the exporter's admit, made
+        # outside any serve loop: tick -1)
+        ev = [s for s in tr.export()
+              if s["name"] in ("admit", "import", "session_resume")][-1]
+        rec = by_tick[ev["tick"]]
+        assert rec["admitted"] >= 1 and rec["phases"]["batcher.admit"] > 0
+    return recs
+
+
+@pytest.mark.parametrize("mode", sorted(TICK_MODES))
+def test_tick_records_every_step_mode(setup, draft_setup, mode):
+    """Every step mode records through the one helper: one record per
+    pass of the serve loop, the same fields, flat phases inside the
+    tick's wall time, and the request's admit event naming its tick."""
+    from tfmesos_tpu.fleet.tracing import TraceContext
+    from tfmesos_tpu.serving import Prefilled
+
+    cfg, params = setup
+    extra, modes, must = TICK_MODES[mode]
+    kw = dict(rows=2, max_len=64, page_size=16, prefill_bucket=16)
+    if extra in ("spec", "spec_overlap"):
+        dcfg, dparams = draft_setup
+        kw.update(draft_cfg=dcfg, draft_params=dparams, n_draft=3,
+                  overlap=extra == "spec_overlap")
+    elif extra == "session":
+        kw.update(kv_tier=_tier(), max_len=128)
+    else:
+        kw.update(extra)
+    batcher = ContinuousBatcher(cfg, params, **kw)
+    reqs, traces = [], []
+    for p in _prompts(cfg, 5, seed=29):
+        r = Request(prompt=p, max_new_tokens=6)
+        r.trace = TraceContext(detailed=True)
+        reqs.append(r)
+        traces.append(r.trace)
+    if mode == "import":
+        pre = ContinuousBatcher(cfg, params, **kw)
+        items = [Prefilled(r, pre.export_kv(r)) for r in reqs]
+        assert pre.flight.snapshot() == []      # no serve loop, no tick
+        done = list(batcher.run(items))
+    elif mode == "session":
+        hist = list(reqs[0].prompt)
+        (c,) = batcher.run([Request(np.asarray(hist, np.int32), 6,
+                                    session_id="conv")])
+        turn = Request(np.asarray(hist + list(c.tokens) + [5, 9, 3],
+                                  np.int32), 6, session_id="conv")
+        turn.trace = TraceContext(detailed=True)
+        traces = [turn.trace]
+        done = list(batcher.run([turn]))
+        assert any(s["name"] == "session_resume"
+                   for s in turn.trace.export())
+    else:
+        done = list(batcher.run(reqs))
+    assert done
+    recs = _check_ticks(batcher, traces, modes, must)
+    assert sum(r["admitted"] for r in recs) >= len(traces)
+    if mode in ("import", "session"):
+        assert any(r["admitted"] for r in recs)
+    else:
+        assert sum(r["prefill_tokens"] for r in recs) >= sum(
+            int(r.prompt.size) for r in reqs)
+    # another batcher of the process leaves this one's share alone
+    other = ContinuousBatcher(cfg, params, rows=2, max_len=64,
+                              page_size=16, prefill_bucket=16)
+    list(other.run([Request(prompt=reqs[0].prompt, max_new_tokens=2)]))
+    assert batcher.flight.snapshot() == recs
+    assert {r["batcher"] for r in other.flight.snapshot()}.isdisjoint(
+        r["batcher"] for r in recs)
+
+
+def test_tick_ring_outlives_the_batcher_and_idles(setup):
+    """The ring is the process's: a reader with no handle to the batcher
+    takes it by name after the batcher is gone.  An online loop with
+    nothing to do sleeps in an idle pull, which is not host time."""
+    import threading
+    import time as _time
+
+    from tfmesos_tpu import serving
+    from tfmesos_tpu.fleet.tracing import flight
+
+    cfg, params = setup
+    batcher = ContinuousBatcher(cfg, params, rows=2, max_len=64,
+                                page_size=16, prefill_bucket=16)
+    done = []
+    th = threading.Thread(target=lambda: done.extend(batcher.serve()))
+    th.start()
+    _time.sleep(0.3)                    # idle: blocked in pull()
+    batcher.submit(Request(prompt=_prompts(cfg, 1)[0], max_new_tokens=3))
+    batcher.close()
+    th.join(120.0)
+    assert not th.is_alive() and len(done) == 1
+    bid = batcher.flight.value
+    del batcher
+    ring = flight(serving.TICK_COMPONENT)
+    assert ring.capacity >= 4096
+    recs = [r for r in ring.snapshot() if r["batcher"] == bid]
+    idle = [r for r in recs if r["kind"] == "idle" and r["idle_ms"] > 200.0]
+    assert idle and idle[0]["wall_ms"] >= idle[0]["idle_ms"]
+    assert any(r["name"] == "decode.block" for r in recs)
+
+
+def test_tick_counts_a_compile_forced_inside_it(setup):
+    """A jit cache miss inside a tick shows in that tick's ``compiles``
+    (here forced from a token callback, which runs in batcher.emit); the
+    same requests again, every shape warm, compile nothing."""
+    cfg, params = setup
+    batcher = ContinuousBatcher(cfg, params, rows=2, max_len=64,
+                                page_size=16, prefill_bucket=16)
+    prompts = _prompts(cfg, 2, seed=17)
+    list(batcher.run([Request(prompt=p, max_new_tokens=4)
+                      for p in prompts]))
+    warm_until = batcher.flight.snapshot()[-1]["tick"]
+
+    list(batcher.run([Request(prompt=p, max_new_tokens=4)
+                      for p in prompts]))
+    again = [r for r in batcher.flight.snapshot()
+             if r["tick"] > warm_until]
+    assert again and sum(r["compiles"] for r in again) == 0
+    warm_until = again[-1]["tick"]
+
+    fired = []
+
+    def miss(toks, off):
+        if not fired:       # a program no one has compiled yet
+            fired.append(jax.jit(lambda x: x * 3 + len(prompts))(
+                jnp.ones((7,))).block_until_ready())
+
+    req = Request(prompt=prompts[0], max_new_tokens=4)
+    req.on_tokens = miss
+    list(batcher.run([req]))
+    forced = [r for r in batcher.flight.snapshot()
+              if r["tick"] > warm_until]
+    assert fired and sum(r["compiles"] for r in forced) >= 1
+    hit = next(r for r in forced if r["compiles"])
+    assert hit["compile_s"] > 0.0 and "batcher.emit" in hit["phases"]
+
+
+def test_profile_has_flat_batcher_phases_and_named_programs(setup, tmp_path):
+    """Under the benchmark's profiler options a batcher served from a
+    worker thread leaves ``batcher.*`` spans with a ``tick`` stat on a
+    host line, none nested in another, and the host's own dispatch spans
+    name the program (``PjitFunction(decode_block)``), none ``fn``."""
+    import glob
+    import threading
+
+    from jax.profiler import ProfileData
+
+    cfg, params = setup
+    batcher = ContinuousBatcher(cfg, params, rows=2, max_len=64,
+                                page_size=16, prefill_bucket=16)
+    mk = lambda: [Request(prompt=p, max_new_tokens=5)
+                  for p in _prompts(cfg, 3, seed=41)]
+    list(batcher.run(mk()))                     # compile outside the trace
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    done = []
+    th = threading.Thread(target=lambda: done.extend(batcher.run(mk())))
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        th.start()
+        th.join(120.0)
+    finally:
+        jax.profiler.stop_trace()
+    assert not th.is_alive() and len(done) == 3
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    host = next(p for p in ProfileData.from_file(path).planes
+                if p.name == "/host:CPU")
+    lines = [[(e.name, e.start_ns, e.start_ns + e.duration_ns,
+               dict(e.stats)) for e in ln.events] for ln in host.lines]
+    (line,) = [evs for evs in lines
+               if any(n.startswith("batcher.") for n, *_ in evs)]
+    spans = sorted((s, e, n, st) for n, s, e, st in line
+                   if n.startswith("batcher."))
+    assert {n for _, _, n, _ in spans} >= {
+        "batcher.pull", "batcher.admit", "batcher.prefill_sync",
+        "batcher.prep", "batcher.dispatch", "batcher.readback",
+        "batcher.retire", "batcher.emit"}
+    assert all("tick" in st for *_, st in spans)
+    for (_, e0, n0, _), (s1, _, n1, _) in zip(spans, spans[1:]):
+        assert s1 >= e0, f"{n1} starts inside {n0}"
+    ticks = {r["tick"] for r in batcher.flight.snapshot()}
+    assert {int(st["tick"]) for *_, st in spans} <= ticks
+    programs = {n for n, *_ in line if n.startswith("PjitFunction(")}
+    assert {"PjitFunction(decode_block)", "PjitFunction(prefill)"} <= programs
+    assert "PjitFunction(fn)" not in programs

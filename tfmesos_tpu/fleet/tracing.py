@@ -48,9 +48,9 @@ import time
 from collections import OrderedDict, deque
 from typing import Any, Dict, List, Optional
 
-__all__ = ["FlightRecorder", "TraceContext", "TraceBook", "new_trace_id",
-           "activate", "current", "cur_event", "cur_elapsed", "cur_span",
-           "flight", "format_waterfall"]
+__all__ = ["FlightRecorder", "FlightView", "TraceContext", "TraceBook",
+           "new_trace_id", "activate", "current", "cur_event",
+           "cur_elapsed", "cur_span", "flight", "format_waterfall"]
 
 
 def new_trace_id() -> str:
@@ -93,6 +93,35 @@ class FlightRecorder:
         with self._lock:
             self._ring.clear()
 
+    def grow(self, capacity: int) -> None:
+        with self._lock:
+            self.capacity = int(capacity)
+            self._ring = deque(self._ring, maxlen=self.capacity)
+
+
+class FlightView:
+    """One writer's share of a recorder that several writers of a
+    component fill (the batchers of one process): ``record`` stamps
+    ``entry[key] = value`` on its way into the shared ring, ``snapshot``
+    returns that writer's entries only.  The ring outlives the writer —
+    a reader with no handle to it takes :func:`flight` by name and
+    tells the writers apart by the stamp."""
+
+    __slots__ = ("ring", "key", "value")
+
+    def __init__(self, ring: FlightRecorder, key: str, value: Any):
+        self.ring = ring
+        self.key = key
+        self.value = value
+
+    def record(self, entry: Dict[str, Any]) -> None:
+        entry[self.key] = self.value
+        self.ring.record(entry)
+
+    def snapshot(self) -> List[Dict[str, Any]]:
+        return [e for e in self.ring.snapshot()
+                if e.get(self.key) == self.value]
+
 
 # Process-global per-component recorders: components grab theirs by
 # name (``flight("router")``) so recording never needs plumbing.
@@ -100,12 +129,16 @@ _FLIGHTS: Dict[str, FlightRecorder] = {}
 _FLIGHTS_LOCK = threading.Lock()
 
 
-def flight(component: str) -> FlightRecorder:
-    """The process-global flight recorder for ``component``."""
+def flight(component: str, capacity: int = 256) -> FlightRecorder:
+    """The process-global flight recorder for ``component``, holding at
+    least ``capacity`` entries (a reader that asks first, with the
+    default, does not shrink what the writer asks for)."""
     with _FLIGHTS_LOCK:
         rec = _FLIGHTS.get(component)
         if rec is None:
-            rec = _FLIGHTS[component] = FlightRecorder()
+            rec = _FLIGHTS[component] = FlightRecorder(capacity)
+        elif capacity > rec.capacity:
+            rec.grow(capacity)
         return rec
 
 

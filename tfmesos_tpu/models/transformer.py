@@ -590,25 +590,32 @@ def _block(cfg: TransformerConfig, mesh: Optional[Mesh], x, lp, positions,
     outer AD, or the K/V-gather form under ``inbody_ad`` (the 1F1B
     tick's branches — see ``_sp_gather_attention``)."""
     b, t, d = x.shape
-    h = rms_norm(x, lp["attn_norm"].astype(cfg.dtype))
-    q = _qmm(h, lp["wq"], cfg.dtype).reshape(b, t, cfg.n_heads, cfg.head_dim)
-    k = _qmm(h, lp["wk"], cfg.dtype).reshape(b, t, cfg.kv_heads, cfg.head_dim)
-    v = _qmm(h, lp["wv"], cfg.dtype).reshape(b, t, cfg.kv_heads, cfg.head_dim)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
-    if sp_axis is not None:
-        o = _sp_attend(cfg, q, k, v, sp_axis, inbody_ad)
-    else:
-        # GQA (kv_heads < n_heads) flows through attend() at kv width:
-        # the flash kernels map q head h -> kv head h // (H/KV) in their
-        # index maps, so training never materializes the repeated K/V;
-        # the sp impls broadcast up internally.
-        o = attend(q, k, v, mesh=mesh, causal=True, sp_impl=cfg.sp_impl,
-                   window=cfg.window)
-    x = x + _qmm(o.reshape(b, t, -1), lp["wo"], cfg.dtype)
-    h = rms_norm(x, lp["mlp_norm"].astype(cfg.dtype))
-    ffn, aux = _ffn(cfg, mesh, lp, h, ep_axis=ep_axis, inbody_ad=inbody_ad)
-    return x + ffn, aux
+    with jax.named_scope("attention"):
+        h = rms_norm(x, lp["attn_norm"].astype(cfg.dtype))
+        q = _qmm(h, lp["wq"], cfg.dtype).reshape(b, t, cfg.n_heads,
+                                                 cfg.head_dim)
+        k = _qmm(h, lp["wk"], cfg.dtype).reshape(b, t, cfg.kv_heads,
+                                                 cfg.head_dim)
+        v = _qmm(h, lp["wv"], cfg.dtype).reshape(b, t, cfg.kv_heads,
+                                                 cfg.head_dim)
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
+        if sp_axis is not None:
+            o = _sp_attend(cfg, q, k, v, sp_axis, inbody_ad)
+        else:
+            # GQA (kv_heads < n_heads) flows through attend() at kv
+            # width: the flash kernels map q head h -> kv head
+            # h // (H/KV) in their index maps, so training never
+            # materializes the repeated K/V; the sp impls broadcast up
+            # internally.
+            o = attend(q, k, v, mesh=mesh, causal=True, sp_impl=cfg.sp_impl,
+                       window=cfg.window)
+        x = x + _qmm(o.reshape(b, t, -1), lp["wo"], cfg.dtype)
+    with jax.named_scope("mlp"):
+        h = rms_norm(x, lp["mlp_norm"].astype(cfg.dtype))
+        ffn, aux = _ffn(cfg, mesh, lp, h, ep_axis=ep_axis,
+                        inbody_ad=inbody_ad)
+        return x + ffn, aux
 
 
 def forward(cfg: TransformerConfig, params, tokens, mesh: Optional[Mesh] = None,
@@ -1305,7 +1312,22 @@ def _prefill_kernel_kwargs(cfg: TransformerConfig, mesh: Optional[Mesh],
 def _block_decode(cfg: TransformerConfig, x, lp, ck, cv, li, positions,
                   pos, sharded: bool = False, mesh: Optional[Mesh] = None,
                   pages=None):
-    """One block over a token chunk with cached history.
+    """One block over a token chunk with cached history: the attention
+    half (:func:`_attend_decode`, which also owns the cache) and the MLP,
+    each under its ``named_scope`` so a profile names the parts."""
+    with jax.named_scope("attention"):
+        x, ck, cv, chunk = _attend_decode(cfg, x, lp, ck, cv, li, positions,
+                                          pos, sharded, mesh, pages)
+    with jax.named_scope("mlp"):
+        h = rms_norm(x, lp["mlp_norm"].astype(cfg.dtype))
+        ffn, _ = _ffn(cfg, None, lp, h)
+        return x + ffn, ck, cv, chunk
+
+
+def _attend_decode(cfg: TransformerConfig, x, lp, ck, cv, li, positions,
+                   pos, sharded: bool, mesh: Optional[Mesh], pages):
+    """The attention half of a block over a token chunk with cached
+    history; returns ``(x, ck, cv, deferred chunk or None)``.
 
     ``x``: [B, t, d] (t = chunk length; 1 in steady-state decode);
     ``ck``/``cv``: the STACKED cache ([L, B, KV, M, Dh], or the paged
@@ -1357,14 +1379,16 @@ def _block_decode(cfg: TransformerConfig, x, lp, ck, cv, li, positions,
         # indirection cannot be GSPMD-partitioned; everything around it
         # stays plain einsums).  Prefill-from-empty writes in the island
         # and attends chunk-to-chunk outside it.
-        o_paged, ck, cv = _sharded_paged_step(
-            cfg, mesh, q, k, v, ck, cv, li, pages, positions,
-            attend=not self_attn_prefill)
+        with jax.named_scope("paged_attention"):
+            o_paged, ck, cv = _sharded_paged_step(
+                cfg, mesh, q, k, v, ck, cv, li, pages, positions,
+                attend=not self_attn_prefill)
     elif pages is not None:
         pass    # single-host paged: deferred — decode_step commits
     else:
-        ck = _cache_write(ck, k, li, pos, rolling=rolling)
-        cv = _cache_write(cv, v, li, pos, rolling=rolling)
+        with jax.named_scope("cache_write"):
+            ck = _cache_write(ck, k, li, pos, rolling=rolling)
+            cv = _cache_write(cv, v, li, pos, rolling=rolling)
     kv = cfg.kv_heads
     g = cfg.n_heads // kv
     if t > 1 and isinstance(pos, int) and pos == 0:
@@ -1411,13 +1435,15 @@ def _block_decode(cfg: TransformerConfig, x, lp, ck, cv, li, positions,
             else:
                 self_kv = (k, v)
         kw = _decode_kernel_kwargs(cfg, m, t, False)
-        if kw is not None:
-            o = flash_decode_paged(q, ck, cv, pages, positions[:, 0],
-                                   layer=li, self_kv=self_kv, **kw)
-        else:
-            o = _paged_decode_reference(
-                q, ck, cv, pages, positions[:, 0],
-                1.0 / math.sqrt(cfg.head_dim), layer=li, self_kv=self_kv)
+        with jax.named_scope("paged_attention"):
+            if kw is not None:
+                o = flash_decode_paged(q, ck, cv, pages, positions[:, 0],
+                                       layer=li, self_kv=self_kv, **kw)
+            else:
+                o = _paged_decode_reference(
+                    q, ck, cv, pages, positions[:, 0],
+                    1.0 / math.sqrt(cfg.head_dim), layer=li,
+                    self_kv=self_kv)
     elif (kernel_kw := _decode_kernel_kwargs(cfg, m, t, sharded, mesh,
                                              batch=b)) is not None:
         # Cache-bounded flash-decode kernel (t=1 steps and short chunks —
@@ -1465,9 +1491,7 @@ def _block_decode(cfg: TransformerConfig, x, lp, ck, cv, li, positions,
         probs = jax.nn.softmax(s, axis=-1).astype(cv_r.dtype)
         o = jnp.einsum("bkgtm,bkmd->btkgd", probs, cv_r)
     x = x + _qmm(o.reshape(b, t, -1), lp["wo"], cfg.dtype)
-    h = rms_norm(x, lp["mlp_norm"].astype(cfg.dtype))
-    ffn, _ = _ffn(cfg, None, lp, h)
-    return x + ffn, ck, cv, ((k, v) if defer else None)
+    return x, ck, cv, ((k, v) if defer else None)
 
 
 def decode_step(cfg: TransformerConfig, params, cache, tokens, pos,
@@ -1506,7 +1530,8 @@ def decode_step(cfg: TransformerConfig, params, cache, tokens, pos,
     than the mismatch.
     """
     b, t = tokens.shape
-    x = _embed_lookup(params["embed"], tokens, cfg.dtype)
+    with jax.named_scope("embed"):
+        x = _embed_lookup(params["embed"], tokens, cfg.dtype)
     ragged = getattr(pos, "ndim", 0) == 1
     if ragged and cfg.window is not None:
         raise ValueError("ragged positions do not compose with "
@@ -1551,10 +1576,12 @@ def decode_step(cfg: TransformerConfig, params, cache, tokens, pos,
     if chunks is not None:
         # Deferred single-token paged writes (see _block_decode): commit
         # every layer's chunk in one scatter per pool leaf.
-        new_k = _paged_cache_write_all(new_k, chunks[0], pages, pos)
-        new_v = _paged_cache_write_all(new_v, chunks[1], pages, pos)
-    x = rms_norm(x, params["norm_f"].astype(cfg.dtype))
-    logits = _qmm(x, params["head"], cfg.dtype)
+        with jax.named_scope("paged_cache_write"):
+            new_k = _paged_cache_write_all(new_k, chunks[0], pages, pos)
+            new_v = _paged_cache_write_all(new_v, chunks[1], pages, pos)
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["norm_f"].astype(cfg.dtype))
+        logits = _qmm(x, params["head"], cfg.dtype)
     out_cache = {"k": new_k, "v": new_v}
     if pages is not None:
         out_cache["pages"] = pages

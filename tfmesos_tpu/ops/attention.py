@@ -237,6 +237,7 @@ def _flash_forward(cfg: _FlashCfg, q, k, v):
         out_shape=[jax.ShapeDtypeStruct(qt.shape, q.dtype),
                    jax.ShapeDtypeStruct((b, h, t, 1), jnp.float32)],
         interpret=cfg.interpret,
+        name="flash_attention_fwd",
         compiler_params=None if cfg.interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         cost_estimate=pl.CostEstimate(
@@ -422,6 +423,7 @@ def _mha_bwd_pallas(cfg: _FlashCfg, q, k, v, o, lse, do, out_dtype=None):
         out_specs=outer_spec(bq, d),
         out_shape=jax.ShapeDtypeStruct(qt.shape, jnp.float32),
         interpret=cfg.interpret,
+        name="flash_attention_dq",
         compiler_params=params,
         cost_estimate=pl.CostEstimate(
             flops=flops_half,
@@ -452,6 +454,7 @@ def _mha_bwd_pallas(cfg: _FlashCfg, q, k, v, o, lse, do, out_dtype=None):
         out_shape=[jax.ShapeDtypeStruct(kt.shape, jnp.float32),
                    jax.ShapeDtypeStruct(vt.shape, jnp.float32)],
         interpret=cfg.interpret,
+        name="flash_attention_dkv",
         compiler_params=params,
         cost_estimate=pl.CostEstimate(
             flops=flops_half,
@@ -820,6 +823,7 @@ def flash_decode(q, k_cache, v_cache, pos, scale: Optional[float] = None,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
         interpret=interpret,
+        name="flash_decode",
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         cost_estimate=pl.CostEstimate(
@@ -1151,6 +1155,7 @@ def flash_decode_paged(q, k_pool, v_pool, page_table, pos,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
         interpret=interpret,
+        name="flash_decode_paged",
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         cost_estimate=pl.CostEstimate(

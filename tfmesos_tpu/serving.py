@@ -33,6 +33,7 @@ construction.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import queue as _queue
 import threading
 import time
@@ -47,13 +48,14 @@ import jax.numpy as jnp
 from jax import shard_map
 
 from tfmesos_tpu import prefixhash as _ph
-from tfmesos_tpu.fleet.tracing import FlightRecorder
+from tfmesos_tpu.fleet.tracing import FlightView, flight
 from tfmesos_tpu.models.transformer import (PageAllocator, TransformerConfig,
                                             decode_step,
                                             greedy_accept_counts,
                                             init_paged_cache,
                                             rejection_accept, sample_logits)
 from tfmesos_tpu.ops.quant import QTensor
+from tfmesos_tpu.utils.profiling import annotate
 
 __all__ = ["Request", "Completion", "Suspended", "Expired",
            "ContinuousBatcher", "SubmissionQueue", "Prefilled",
@@ -1197,6 +1199,55 @@ def _copy_page(pool, src, dst):
         lambda buf: buf.at[:, dst].set(buf[:, src]), pool)
 
 
+#: The tick ring (docs/SERVING.md "Observability"): one record per pass
+#: of every batcher's serve loop in this process, in the process-global
+#: ``flight(TICK_COMPONENT)`` so that it outlives the batcher.  4096
+#: records are ~3 minutes of 50 ms ticks, under 2 MB.
+TICK_COMPONENT = "batcher.tick"
+TICK_RING = 4096
+
+_BATCHER_IDS = itertools.count()
+#: backend compiles that finished in this process, and their seconds: a
+#: tick record carries the difference across the tick (``compiles``)
+_COMPILES = [0, 0.0]
+
+
+def _count_compile(event: str, duration: float, **_kw) -> None:
+    # fires once per jit cache miss that reaches the backend, whether XLA
+    # compiled or the persistent cache answered
+    if event == "/jax/core/compile/backend_compile_duration":
+        _COMPILES[0] += 1
+        _COMPILES[1] += duration
+
+
+jax.monitoring.register_event_duration_secs_listener(_count_compile)
+
+
+class _Phase:
+    """One phase of a tick: a span on the serve thread's line of a
+    profiler trace (``annotate``, with the tick's number) whose
+    ``perf_counter`` duration is added to the tick record on exit."""
+
+    __slots__ = ("tick", "name", "span", "t0", "ms")
+
+    def __init__(self, tick: Dict[str, Any], name: str, stats):
+        self.tick = tick
+        self.name = name
+        self.span = annotate(name, tick=tick["tick"], **stats)
+
+    def __enter__(self):
+        self.span.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.ms = (time.perf_counter() - self.t0) * 1e3
+        self.span.__exit__(*exc)
+        phases = self.tick["phases"]
+        phases[self.name] = phases.get(self.name, 0.0) + self.ms
+        return False
+
+
 class ContinuousBatcher:
     """Admit a stream of :class:`Request`\\ s into a persistent paged
     decode of ``rows`` concurrent sequences.
@@ -1667,12 +1718,19 @@ class ContinuousBatcher:
         self.fused_ticks = 0          # fused prefill+decode dispatches
         self.fused_chunk_tokens = 0   # prefill tokens they coalesced
         self.fused_decode_tokens = 0  # decode tokens they covered
-        # The batcher's flight recorder (docs/SERVING.md
-        # "Observability"): a bounded ring of recent component events —
-        # notably per-block decode timing from every step mode,
-        # pipelined included — that survives even when no request-level
-        # trace was retained.
-        self.flight = FlightRecorder(256)
+        # The tick recorder (docs/SERVING.md "Observability"): every
+        # pass of the serve loop leaves one record, written by
+        # _tick_roll, in the process-global tick ring; ``flight`` is
+        # this batcher's share of it (records stamped with its id).
+        self.flight = FlightView(flight(TICK_COMPONENT, TICK_RING),
+                                 "batcher", next(_BATCHER_IDS))
+        self._mode = ("spec_overlap" if draft_cfg is not None and self.overlap
+                      else "spec" if draft_cfg is not None
+                      else "pipelined" if self._pipelined
+                      else "overlap" if self.overlap else "sync")
+        self._tick_n = 0
+        self._tick_c0 = (0, 0.0)
+        self._tick = self._tick_open(None)
         if prefix_np is not None:
             self._init_prefix(prefix_np)
         # Cross-request prefix cache (prefix_cache_pages > 0 enables;
@@ -1723,6 +1781,56 @@ class ContinuousBatcher:
                         "page": self.page_size,
                         "first": self._pcache.first,
                         "seed": self._pcache.seed.hex()}
+
+    # -- the tick recorder ------------------------------------------------
+
+    def _tick_open(self, t: Optional[float]) -> Dict[str, Any]:
+        """A new tick record.  ``t`` None is the placeholder that takes
+        the phases of work outside a serve loop (export_kv) and is never
+        recorded."""
+        if t is not None:
+            self._tick_n += 1
+            self._tick_c0 = tuple(_COMPILES)
+        return {"name": "tick", "tick": self._tick_n if t is not None else -1,
+                "t": t, "wall_ms": 0.0, "kind": "idle", "mode": self._mode,
+                "k": 0, "rows": 0, "dur": 0.0, "admitted": 0,
+                "prefill_tokens": 0, "phases": {}, "idle_ms": 0.0,
+                "compiles": 0, "compile_s": 0.0}
+
+    def _tick_roll(self, more: bool = True) -> None:
+        """The one recorder write of the serve loop: close the open tick
+        into the ring and open the next (``more``), at every pass's top
+        and in the loop's ``finally``.  Ticks tile the loop's time: a
+        tick's ``wall_ms`` runs to the next one's start, the consumer's
+        time at a ``yield`` included."""
+        now = time.perf_counter()
+        t = self._tick
+        if t["t"] is not None:
+            ph = t["phases"]
+            t["wall_ms"] = (now - t["t"]) * 1e3
+            t["dur"] = round(ph.get("batcher.dispatch", 0.0)
+                             + ph.get("batcher.readback", 0.0), 3)
+            t["compiles"] = _COMPILES[0] - self._tick_c0[0]
+            t["compile_s"] = _COMPILES[1] - self._tick_c0[1]
+            block = t["name"] == "decode.block"
+            fill = t["prefill_tokens"] or t["admitted"]
+            t["kind"] = ("fused" if t["mode"] == "fused"
+                         else "mixed" if block and fill
+                         else "decode" if block
+                         else "prefill" if fill else "idle")
+            self.flight.record(t)
+        self._tick = self._tick_open(now if more else None)
+
+    def _phase(self, name: str, **stats) -> _Phase:
+        """Open one phase of the current tick (``with``): flat siblings
+        on the serve thread, never nested and never across a ``yield``."""
+        return _Phase(self._tick, name, stats)
+
+    def _tick_block(self, mode: str, rows: int, k: int) -> None:
+        """This tick ran a decode block (or speculative round)."""
+        t = self._tick
+        t["name"], t["mode"], t["rows"], t["k"] = "decode.block", mode, \
+            rows, k
 
     @property
     def prefix_cache_active(self) -> bool:
@@ -2098,6 +2206,7 @@ class ContinuousBatcher:
         return jax.lax.with_sharding_constraint(
             x, NamedSharding(self.mesh, P()))
 
+    @jax.named_scope("sampling")
     def _sample(self, last, rids, steps):
         """[n, V] logits -> [n] int32 tokens; sampling keys are folded
         in-graph per (rid, step) so the host loop never dispatches
@@ -2159,8 +2268,9 @@ class ContinuousBatcher:
             # rows never reach the clamp (their reservations cap pos at
             # max_len).
             @partial(jax.jit, donate_argnums=1)
-            def fn(params, pool, table, use_host, toks, positions, steps,
-                   carry_tok, carry_pos, carry_steps, rids):
+            def decode_block_pipelined(params, pool, table, use_host, toks,
+                                       positions, steps, carry_tok,
+                                       carry_pos, carry_steps, rids):
                 tok0 = jnp.where(use_host, toks, carry_tok)
                 pos0 = jnp.where(use_host, positions, carry_pos)
                 stp0 = jnp.where(use_host, steps, carry_steps)
@@ -2171,29 +2281,29 @@ class ContinuousBatcher:
                         jnp.minimum(pos0 + K, cap),
                         jnp.minimum(stp0 + K, cap))
 
-            return fn
+            return decode_block_pipelined
 
         if self.overlap:
             # Double-buffered blocks: rows in the previous dispatch chain
             # from its device-resident LAST token; the host never waits
             # on it before dispatching the next block.
             @partial(jax.jit, donate_argnums=1)
-            def fn(params, pool, table, toks, prev, use_dev, positions,
-                   rids, steps):
+            def decode_block_overlap(params, pool, table, toks, prev,
+                                     use_dev, positions, rids, steps):
                 merged = jnp.where(use_dev, prev[:, -1], toks)
                 pool, out = block(params, pool, table, merged, positions,
                                   rids, steps)
                 return pool, self._host_read(out)
 
-            return fn
+            return decode_block_overlap
 
         @partial(jax.jit, donate_argnums=1)
-        def fn(params, pool, table, toks, positions, rids, steps):
+        def decode_block(params, pool, table, toks, positions, rids, steps):
             pool, out = block(params, pool, table, toks, positions, rids,
                               steps)
             return pool, self._host_read(out)
 
-        return fn
+        return decode_block
 
     def _make_spec_round(self):
         """Jitted speculative round: k batched draft steps over the
@@ -2299,8 +2409,8 @@ class ContinuousBatcher:
             R = max(1, self._spec_rounds)
 
             @partial(jax.jit, donate_argnums=(1, 3))
-            def fn(params, pool, dparams, dpool, table, dtable, toks,
-                   positions, rids, steps):
+            def spec_round(params, pool, dparams, dpool, table, dtable,
+                           toks, positions, rids, steps):
                 if R == 1:
                     pool_out, dpool_out, g, counts = body(
                         params, pool, dparams, dpool, table, dtable,
@@ -2324,7 +2434,7 @@ class ContinuousBatcher:
                 return (pool, dpool, self._host_read(jnp.stack(gs)),
                         self._host_read(jnp.stack(ns)))
 
-            return fn
+            return spec_round
 
         # Overlap variant: rows that were in the PREVIOUS round continue
         # from its DEVICE outputs — the last committed token is
@@ -2334,9 +2444,9 @@ class ContinuousBatcher:
         # host values; the merged positions/steps return as the carry
         # for round t+1.
         @partial(jax.jit, donate_argnums=(1, 3))
-        def fn_ov(params, pool, dparams, dpool, table, dtable, toks,
-                  positions, rids, steps, use_dev, prev_g, prev_nc,
-                  prev_pos, prev_steps):
+        def spec_round_overlap(params, pool, dparams, dpool, table, dtable,
+                               toks, positions, rids, steps, use_dev,
+                               prev_g, prev_nc, prev_pos, prev_steps):
             last_idx = jnp.maximum(prev_nc - 1, 0)
             dev_tok = jnp.take_along_axis(prev_g, last_idx[:, None],
                                           axis=1)[:, 0]
@@ -2350,7 +2460,7 @@ class ContinuousBatcher:
                     self._host_read(counts), self._host_read(positions),
                     self._host_read(steps))
 
-        return fn_ov
+        return spec_round_overlap
 
     def _make_draft_chunk(self):
         """Jitted DRAFT prompt writer over the draft's paged pool: serves
@@ -2362,13 +2472,13 @@ class ContinuousBatcher:
         sharded = self.mesh is not None
 
         @partial(jax.jit, donate_argnums=1)
-        def fn(dparams, dpool, t, chunk, pos):
+        def draft_chunk(dparams, dpool, t, chunk, pos):
             cache = dict(dpool, pages=t)
             _, cache = decode_step(self.draft_cfg, dparams, cache, chunk,
                                    pos, sharded=sharded, mesh=self.mesh)
             return {"k": cache["k"], "v": cache["v"]}
 
-        return fn
+        return draft_chunk
 
     def _one_hot_call(self, side: _PagedSide, row: int, chunk: np.ndarray):
         """(shard, [nd, w] tokens, [nd, np] table) for a per-row model
@@ -2395,7 +2505,7 @@ class ContinuousBatcher:
         sharded = self.mesh is not None
 
         @partial(jax.jit, donate_argnums=1)
-        def fn(params, pool, table, chunk, pos, cap_idx, rid):
+        def chunk_prefill(params, pool, table, chunk, pos, cap_idx, rid):
             cache = dict(pool, pages=table)
             logits, cache = decode_step(self.cfg, params, cache, chunk,
                                         pos, sharded=sharded,
@@ -2406,7 +2516,7 @@ class ContinuousBatcher:
             nxt = self._sample(last, rid, jnp.zeros_like(rid))
             return {"k": cache["k"], "v": cache["v"]}, self._host_read(nxt)
 
-        return fn
+        return chunk_prefill
 
     def _make_fused_step(self):
         """ONE jitted program per tick over the ragged [decode rows |
@@ -2429,8 +2539,8 @@ class ContinuousBatcher:
         max_len = self.max_len
 
         @partial(jax.jit, donate_argnums=1)
-        def fn(params, pool, table, toks, positions, rids, steps,
-               ctable, chunks, cpos, caps, crids):
+        def fused_tick(params, pool, table, toks, positions, rids, steps,
+                       ctable, chunks, cpos, caps, crids):
             # Chunk slots first (mirroring the phase-split tick's
             # chunk-then-block order — the sets touch disjoint pages,
             # but the donated pool threads through in program order).
@@ -2460,7 +2570,7 @@ class ContinuousBatcher:
             return (pool, self._host_read(toks_all.T),
                     self._host_read(first))
 
-        return fn
+        return fused_tick
 
     def _fused_slot_buckets(self) -> List[int]:
         """Every chunk-slot count the fused dispatch can pad to (powers
@@ -2475,7 +2585,7 @@ class ContinuousBatcher:
             sharded = self.mesh is not None
 
             @partial(jax.jit, donate_argnums=1)
-            def fn(params, pool, table, prompt, length, rid):
+            def prefill(params, pool, table, prompt, length, rid):
                 cache = dict(pool, pages=table)
                 # With a shared prefix the chunk prefills AT OFFSET
                 # prefix_len: rope positions, causal bounds, and page
@@ -2490,7 +2600,7 @@ class ContinuousBatcher:
                 return {"k": cache["k"], "v": cache["v"]}, \
                     self._host_read(nxt)
 
-            self._prefill_fns[width] = fn
+            self._prefill_fns[width] = prefill
         return self._prefill_fns[width]
 
     # -- host-side bookkeeping --------------------------------------------
@@ -3278,9 +3388,11 @@ class ContinuousBatcher:
         t_admit = time.perf_counter()
         art = pre.artifact
         req = pre.request
+        self._tick["admitted"] += 1
         self._trace_event(req, "import", rid=int(art.get("rid", -1)),
                           row=row, pos=int(art.get("pos", 0)),
-                          resumed=int(art.get("step", 1)) > 1)
+                          resumed=int(art.get("step", 1)) > 1,
+                          tick=self._tick["tick"])
         side = self.t_side
         n = art["k"].shape[1]
         side.ensure(row, side.shared_len + n * self.page_size)
@@ -3673,9 +3785,10 @@ class ContinuousBatcher:
         t_admit = time.perf_counter()
         side = self.t_side
         n = art["k"].shape[1]
+        self._tick["admitted"] += 1
         self._trace_event(req, "session_resume", rid=rid, row=row,
                           session=str(req.session_id),
-                          covered=int(art["pos"]))
+                          covered=int(art["pos"]), tick=self._tick["tick"])
         # The artifact's first own page embeds any shared-prefix tail
         # template (the parking row's copy), so the plain ensure is
         # right — no template re-copy, exactly like _admit_import.
@@ -3716,6 +3829,7 @@ class ContinuousBatcher:
         # reserved-but-unread slots or sink columns (the cold path's
         # prompt padding discipline).
         side.ensure(row, min(ts + w, need))
+        self._tick["prefill_tokens"] += w
         padded = np.zeros((1, w), np.int32)
         padded[0, :tlen] = req.prompt[req.prompt.size - tlen:]
         s, toks, table = self._one_hot_call(side, row, padded)
@@ -3874,23 +3988,27 @@ class ContinuousBatcher:
             nonlocal exhausted
             if exhausted:
                 return
-            if incremental:
-                want_block = block and not pending
-                while True:
-                    item = requests.poll(want_block)
-                    want_block = False
-                    if item is _CLOSED:
+            # ``idle``: the pull of the idle branch, which may sleep
+            # until an arrival — kept out of the tick's host time.
+            with self._phase("batcher.pull", idle=int(block)) as ph:
+                if incremental:
+                    want_block = block and not pending
+                    while True:
+                        item = requests.poll(want_block)
+                        want_block = False
+                        if item is _CLOSED:
+                            exhausted = True
+                            break
+                        if item is None:
+                            break
+                        rank_insert(item)
+                elif not pending:
+                    try:
+                        pending.append(next(source))
+                    except StopIteration:
                         exhausted = True
-                        return
-                    if item is None:
-                        return
-                    rank_insert(item)
-            if pending:
-                return
-            try:
-                pending.append(next(source))
-            except StopIteration:
-                exhausted = True
+            if block:
+                self._tick["idle_ms"] += ph.ms
 
         # Fences export_kv's row borrowing: taken under _export_lock so
         # the check-then-borrow in export_kv and this set cannot
@@ -3900,6 +4018,7 @@ class ContinuousBatcher:
             self._loop_active = True
         try:
             while True:
+                self._tick_roll()
                 if self._preempt_event.is_set():
                     # Drain-migration: every in-flight request (resident
                     # rows, parked artifacts, queued arrivals) is given
@@ -3948,10 +4067,11 @@ class ContinuousBatcher:
                         if hreq.priority > pre.request.priority:
                             break
                     try:
-                        wt, wd, need = self._worst_pages(pre.request)
-                        row, _ = self._admit_row(free_rows, active, wt,
-                                                 wd, pre.request,
-                                                 use_cache=False)
+                        with self._phase("batcher.admit"):
+                            wt, wd, need = self._worst_pages(pre.request)
+                            row, _ = self._admit_row(free_rows, active, wt,
+                                                     wd, pre.request,
+                                                     use_cache=False)
                     except RuntimeError:
                         # The resume can never fit this pool (e.g. the
                         # original admission rode a prefix-cache plan
@@ -3969,8 +4089,9 @@ class ContinuousBatcher:
                     self._parked.popleft()
                     self.resumes += 1
                     self._trace_event(pre.request, "resume")
-                    burst.append(self._admit_import(row, pre, wt, wd,
-                                                    need, active))
+                    with self._phase("batcher.admit"):
+                        burst.append(self._admit_import(row, pre, wt, wd,
+                                                        need, active))
                 while free_rows and bad_request is None \
                         and not self._weight_updates:
                     # (A pending weight update gates NEW admissions —
@@ -4006,65 +4127,66 @@ class ContinuousBatcher:
                                  if imported else -1),
                             request=req0)
                         continue
-                    try:
-                        wt, wd, need = self._worst_pages(req0)
+                    with self._phase("batcher.admit"):
+                        try:
+                            wt, wd, need = self._worst_pages(req0)
+                            if imported:
+                                self._validate_artifact(item.artifact, req0)
+                        except ValueError as e:
+                            bad_request = e     # raise after draining
+                            break
+                        # KV tier: a usable parked session artifact takes
+                        # the resume path instead of prefilling the whole
+                        # history — checked FIRST, because a resume
+                        # installs those positions from the artifact and
+                        # promoting their spilled prefix pages too would
+                        # be a second, unused device install.  Otherwise,
+                        # promote any spilled prefix pages this prompt
+                        # could map (they re-enter the trie as zero-ref
+                        # nodes, so the prefix plan below sees them).
+                        sess_art = (None if imported
+                                    else self._session_lookup(req0))
+                        if not imported and sess_art is None:
+                            self._tier_promote(req0)
+                        # Imports (and session resumes) skip the
+                        # prefix-plan mapping: their pages arrive in the
+                        # payload (installing everything, then publishing,
+                        # is what keeps import admission one code path
+                        # with local prefill).
+                        row, plan = self._admit_row(
+                            free_rows, active, wt, wd, req0,
+                            use_cache=not imported and sess_art is None)
+                        if row is None:
+                            # Allocation pressure: a strictly-higher-
+                            # priority head may suspend the lowest-priority
+                            # resident row (its pages free, its artifact
+                            # parks for resumption) and retry.
+                            if self._maybe_preempt(req0.priority, active,
+                                                   free_rows):
+                                continue
+                            break   # wait for an in-flight row to finish
+                        pending.popleft()
                         if imported:
-                            self._validate_artifact(item.artifact, req0)
-                    except ValueError as e:
-                        bad_request = e     # raise after draining
-                        break
-                    # KV tier: a usable parked session artifact takes
-                    # the resume path instead of prefilling the whole
-                    # history — checked FIRST, because a resume
-                    # installs those positions from the artifact and
-                    # promoting their spilled prefix pages too would
-                    # be a second, unused device install.  Otherwise,
-                    # promote any spilled prefix pages this prompt
-                    # could map (they re-enter the trie as zero-ref
-                    # nodes, so the prefix plan below sees them).
-                    sess_art = (None if imported
-                                else self._session_lookup(req0))
-                    if not imported and sess_art is None:
-                        self._tier_promote(req0)
-                    # Imports (and session resumes) skip the
-                    # prefix-plan mapping: their pages arrive in the
-                    # payload (installing everything, then publishing,
-                    # is what keeps import admission one code path
-                    # with local prefill).
-                    row, plan = self._admit_row(
-                        free_rows, active, wt, wd, req0,
-                        use_cache=not imported and sess_art is None)
-                    if row is None:
-                        # Allocation pressure: a strictly-higher-
-                        # priority head may suspend the lowest-priority
-                        # resident row (its pages free, its artifact
-                        # parks for resumption) and retry.
-                        if self._maybe_preempt(req0.priority, active,
-                                               free_rows):
-                            continue
-                        break   # wait for an in-flight row to finish
-                    pending.popleft()
-                    if imported:
-                        # Imports keep their exporter's rid (the
-                        # sampling folds must continue that stream) —
-                        # the local counter is neither consulted nor
-                        # burned.
-                        res = self._admit_import(row, item, wt, wd,
-                                                 need, active)
-                    elif sess_art is not None:
-                        rid = self._next_rid
-                        self._next_rid += 1
-                        res = self._admit_session(row, rid, item, wt,
-                                                  wd, need, active,
-                                                  sess_art)
-                    else:
-                        rid = self._next_rid
-                        self._next_rid += 1
-                        res = self._admit_dispatch(row, rid, item, wt,
-                                                   wd, need, active,
-                                                   plan)
-                    if res is not None:
-                        burst.append(res)
+                            # Imports keep their exporter's rid (the
+                            # sampling folds must continue that stream) —
+                            # the local counter is neither consulted nor
+                            # burned.
+                            res = self._admit_import(row, item, wt, wd,
+                                                     need, active)
+                        elif sess_art is not None:
+                            rid = self._next_rid
+                            self._next_rid += 1
+                            res = self._admit_session(row, rid, item, wt,
+                                                      wd, need, active,
+                                                      sess_art)
+                        else:
+                            rid = self._next_rid
+                            self._next_rid += 1
+                            res = self._admit_dispatch(row, rid, item, wt,
+                                                       wd, need, active,
+                                                       plan)
+                        if res is not None:
+                            burst.append(res)
                 # Every row busy: an incremental arrival of strictly
                 # higher priority must not wait a full request behind
                 # lower-priority residents — one eager non-blocking
@@ -4079,8 +4201,10 @@ class ContinuousBatcher:
                         it0 = pending[0]
                         r0 = it0.request if isinstance(it0, Prefilled) \
                             else it0
-                        if self._maybe_preempt(r0.priority, active,
-                                               free_rows):
+                        with self._phase("batcher.admit"):
+                            preempted = self._maybe_preempt(
+                                r0.priority, active, free_rows)
+                        if preempted:
                             yield from self._finalize_burst(
                                 burst, active, free_rows)
                             continue
@@ -4118,9 +4242,10 @@ class ContinuousBatcher:
                 if self._chunk_prefill is not None:
                     done_row = self._advance_prefill(active)
                     if done_row is not None:
-                        done = self._completion(active[done_row])
-                        self._finish_completed(done_row, active,
-                                               free_rows)
+                        with self._phase("batcher.retire"):
+                            done = self._completion(active[done_row])
+                            self._finish_completed(done_row, active,
+                                                   free_rows)
                         yield done
                 if any(row.decoding for row in active.values()):
                     if self.draft_cfg is not None and self.overlap:
@@ -4149,6 +4274,7 @@ class ContinuousBatcher:
             self._parked.clear()    # pages already released at suspend
             for row in list(active):
                 self._finish(row, active, free_rows)
+            self._tick_roll(more=False)
             # Dropped only after the rows are released, so an export
             # admitted the instant the fence clears can never borrow a
             # row the dying loop still owns.  Weight updates still
@@ -4168,20 +4294,21 @@ class ContinuousBatcher:
         stream when they RETIRE, exactly when the host learns them.  A
         raising callback is disarmed: a broken consumer costs its
         stream, never the request or the loop."""
-        for row in active.values():
-            cb = row.req.on_tokens
-            if cb is None:
-                continue
-            n = len(row.out)
-            if n <= row.streamed:
-                continue
-            chunk = [int(t) for t in row.out[row.streamed:n]]
-            off = row.streamed
-            row.streamed = n
-            try:
-                cb(chunk, off)
-            except Exception:
-                row.req.on_tokens = None
+        with self._phase("batcher.emit"):
+            for row in active.values():
+                cb = row.req.on_tokens
+                if cb is None:
+                    continue
+                n = len(row.out)
+                if n <= row.streamed:
+                    continue
+                chunk = [int(t) for t in row.out[row.streamed:n]]
+                off = row.streamed
+                row.streamed = n
+                try:
+                    cb(chunk, off)
+                except Exception:
+                    row.req.on_tokens = None
 
     def _ensure_sides(self, row: int, length: int) -> None:
         """Back ABSOLUTE positions [0, length) of ``row`` on the target
@@ -4217,9 +4344,11 @@ class ContinuousBatcher:
         shard)`` for run()'s burst finalize — ``None`` in chunked mode,
         which makes no model call here."""
         t_admit = time.perf_counter()
+        tick = self._tick
+        tick["admitted"] += 1
         self._trace_event(req, "admit", rid=rid, row=row,
                           prompt_len=int(req.prompt.size),
-                          cached=plan is not None)
+                          cached=plan is not None, tick=tick["tick"])
         length = req.prompt.size
         width = -(-length // self.prefill_bucket) * self.prefill_bucket
         if plan is not None:
@@ -4257,6 +4386,7 @@ class ContinuousBatcher:
         if plan is not None:
             return self._admit_cached(row, rid, req, wt, wd, need,
                                       active, plan, t_admit)
+        tick["prefill_tokens"] += width
         self._ensure_sides(row, self.prefix_len + width)
         padded = np.zeros((1, width), np.int32)
         padded[0, :length] = req.prompt
@@ -4325,6 +4455,7 @@ class ContinuousBatcher:
         # cold path's prompt padding behaves identically), and
         # allocations beyond ``worst_pages`` would corrupt headroom().
         self._ensure_sides(row, min(ts + w, need))
+        self._tick["prefill_tokens"] += w
         padded = np.zeros((1, w), np.int32)
         padded[0, :tlen] = req.prompt[req.prompt.size - tlen:]
         s, toks, table = self._one_hot_call(side, row, padded)
@@ -4386,12 +4517,17 @@ class ContinuousBatcher:
         (the async transfers have been in flight since dispatch, so
         these mostly find the data ready) and yield any instant
         completions.  Clears ``burst`` in place."""
+        finished = []
         for row, state, tok, s in burst:
-            done = self._admit_finalize(state, int(np.asarray(tok)[s]))
-            if done is not None:
-                self._finish_completed(row, active, free_rows)
-                yield done
+            with self._phase("batcher.prefill_sync"):
+                first = int(np.asarray(tok)[s])
+            with self._phase("batcher.retire"):
+                done = self._admit_finalize(state, first)
+                if done is not None:
+                    self._finish_completed(row, active, free_rows)
+                    finished.append(done)
         burst.clear()
+        yield from finished
 
     def _advance_prefill(self, active: Dict[int, _Row]) -> Optional[int]:
         """Write ONE chunk of the oldest still-prefilling row; flips the
@@ -4402,40 +4538,44 @@ class ContinuousBatcher:
                    if not row.decoding]
         if not filling:
             return None
-        _, r = min(filling)
-        row = active[r]
-        c = self.prefill_chunk
-        chunk = row.padded[:, row.filled:row.filled + c]
-        length = row.req.prompt.size
-        cap = length - 1 - row.filled       # in-range only on last chunk
-        s, ctoks, table = self._one_hot_call(self.t_side, r, chunk)
-        caps = np.full((self.n_shards,), -1, np.int32)
-        caps[s] = cap
-        rids = np.zeros((self.n_shards,), np.int32)
-        rids[s] = row.rid
-        self.pool, tok = self._chunk_prefill(
-            self.params, self.pool, table, ctoks,
-            jnp.asarray(self.prefix_len + row.filled, jnp.int32),
-            jnp.asarray(caps), jnp.asarray(rids))
-        if self.d_side is not None:
-            # The draft's prompt chunks advance in lockstep so it is
-            # ready to propose the moment the row flips to decoding.
-            _, dtoks, dtable = self._one_hot_call(self.d_side, r, chunk)
-            self.d_side.pool = self._draft_chunk(
-                self.draft_params, self.d_side.pool, dtable, dtoks,
-                jnp.asarray(self.prefix_len + row.filled, jnp.int32))
-        row.filled += c
+        with self._phase("batcher.admit"):
+            _, r = min(filling)
+            row = active[r]
+            c = self.prefill_chunk
+            self._tick["prefill_tokens"] += c
+            chunk = row.padded[:, row.filled:row.filled + c]
+            length = row.req.prompt.size
+            cap = length - 1 - row.filled   # in-range only on last chunk
+            s, ctoks, table = self._one_hot_call(self.t_side, r, chunk)
+            caps = np.full((self.n_shards,), -1, np.int32)
+            caps[s] = cap
+            rids = np.zeros((self.n_shards,), np.int32)
+            rids[s] = row.rid
+            self.pool, tok = self._chunk_prefill(
+                self.params, self.pool, table, ctoks,
+                jnp.asarray(self.prefix_len + row.filled, jnp.int32),
+                jnp.asarray(caps), jnp.asarray(rids))
+            if self.d_side is not None:
+                # The draft's prompt chunks advance in lockstep so it is
+                # ready to propose the moment the row flips to decoding.
+                _, dtoks, dtable = self._one_hot_call(self.d_side, r, chunk)
+                self.d_side.pool = self._draft_chunk(
+                    self.draft_params, self.d_side.pool, dtable, dtoks,
+                    jnp.asarray(self.prefix_len + row.filled, jnp.int32))
+            row.filled += c
         if row.filled < row.padded.shape[1]:
             return None
-        tok = int(np.asarray(tok)[s])       # the capture chunk's sample
-        row.t_first = time.perf_counter()
-        row.last = tok
-        row.out.append(tok)
-        row.decoding = True
-        # Publish the now fully-dispatched prompt pages; chunked mode
-        # must wait until here — at admission the chunks had not been
-        # written, and a concurrent hit would have mapped garbage.
-        self._pcache_insert(r, row)
+        with self._phase("batcher.prefill_sync"):
+            tok = int(np.asarray(tok)[s])   # the capture chunk's sample
+        with self._phase("batcher.retire"):
+            row.t_first = time.perf_counter()
+            row.last = tok
+            row.out.append(tok)
+            row.decoding = True
+            # Publish the now fully-dispatched prompt pages; chunked mode
+            # must wait until here — at admission the chunks had not been
+            # written, and a concurrent hit would have mapped garbage.
+            self._pcache_insert(r, row)
         if tok == row.req.stop_token or row.req.max_new_tokens == 1:
             return r
         return None
@@ -4457,82 +4597,85 @@ class ContinuousBatcher:
         the stream is unchanged); decode commits mirror :meth:`_step`."""
         K = self.multi_step
         c = self.prefill_chunk
-        decoding = {r: row for r, row in active.items() if row.decoding}
-        filling = sorted((row.rid, r) for r, row in active.items()
-                         if not row.decoding)
-        slots = max(1, (self.tokens_per_tick - len(decoding) * K) // c)
-        picks = [r for _, r in filling[:slots]]
-        S = self._pow2(len(picks))
-        ctable = np.full((S, self.t_side.np_max), self.t_side.sink,
-                         np.int32)
-        chunks = np.zeros((S, c), np.int32)
-        cpos = np.zeros((S,), np.int32)
-        caps = np.full((S,), -1, np.int32)
-        crids = np.zeros((S,), np.int32)
-        tbl = self.t_side.table_np()
-        for i, r in enumerate(picks):
-            row = active[r]
-            ctable[i] = tbl[r]
-            chunks[i] = row.padded[0, row.filled:row.filled + c]
-            cpos[i] = self.prefix_len + row.filled
-            caps[i] = row.req.prompt.size - 1 - row.filled
-            crids[i] = row.rid
-        toks = np.zeros((self.rows,), np.int32)
-        positions = np.zeros((self.rows,), np.int32)
-        rids = np.zeros((self.rows,), np.int32)
-        steps = np.zeros((self.rows,), np.int32)
-        for r, row in decoding.items():
-            self._ensure_sides(r, min(row.pos + K, row.limit))
-            toks[r] = row.last
-            positions[r] = row.pos
-            rids[r] = row.rid
-            steps[r] = row.step
-        table = self.t_side.decode_table(active, decoding)
-        tb0 = time.perf_counter()
-        self.pool, nxt, first = self._fused_step(
-            self.params, self.pool, table, jnp.asarray(toks),
-            jnp.asarray(positions), jnp.asarray(rids),
-            jnp.asarray(steps), jnp.asarray(ctable),
-            jnp.asarray(chunks), jnp.asarray(cpos), jnp.asarray(caps),
-            jnp.asarray(crids))
-        nxt = np.asarray(nxt)       # ONE sync covers chunks AND block
-        first = np.asarray(first)
+        with self._phase("batcher.prep"):
+            decoding = {r: row for r, row in active.items()
+                        if row.decoding}
+            filling = sorted((row.rid, r) for r, row in active.items()
+                             if not row.decoding)
+            slots = max(1, (self.tokens_per_tick - len(decoding) * K) // c)
+            picks = [r for _, r in filling[:slots]]
+            S = self._pow2(len(picks))
+            ctable = np.full((S, self.t_side.np_max), self.t_side.sink,
+                             np.int32)
+            chunks = np.zeros((S, c), np.int32)
+            cpos = np.zeros((S,), np.int32)
+            caps = np.full((S,), -1, np.int32)
+            crids = np.zeros((S,), np.int32)
+            tbl = self.t_side.table_np()
+            for i, r in enumerate(picks):
+                row = active[r]
+                ctable[i] = tbl[r]
+                chunks[i] = row.padded[0, row.filled:row.filled + c]
+                cpos[i] = self.prefix_len + row.filled
+                caps[i] = row.req.prompt.size - 1 - row.filled
+                crids[i] = row.rid
+            toks = np.zeros((self.rows,), np.int32)
+            positions = np.zeros((self.rows,), np.int32)
+            rids = np.zeros((self.rows,), np.int32)
+            steps = np.zeros((self.rows,), np.int32)
+            for r, row in decoding.items():
+                self._ensure_sides(r, min(row.pos + K, row.limit))
+                toks[r] = row.last
+                positions[r] = row.pos
+                rids[r] = row.rid
+                steps[r] = row.step
+            table = self.t_side.decode_table(active, decoding)
+        with self._phase("batcher.dispatch"):
+            self.pool, nxt, first = self._fused_step(
+                self.params, self.pool, table, jnp.asarray(toks),
+                jnp.asarray(positions), jnp.asarray(rids),
+                jnp.asarray(steps), jnp.asarray(ctable),
+                jnp.asarray(chunks), jnp.asarray(cpos), jnp.asarray(caps),
+                jnp.asarray(crids))
+        with self._phase("batcher.readback"):
+            nxt = np.asarray(nxt)   # ONE sync covers chunks AND block
+            first = np.asarray(first)
         self.fused_ticks += 1
         self.fused_chunk_tokens += len(picks) * c
         self.fused_decode_tokens += len(decoding) * K
-        self.flight.record(
-            {"name": "decode.block", "mode": "fused",
-             "dur": round((time.perf_counter() - tb0) * 1000.0, 3),
-             "rows": len(decoding), "k": K, "chunks": len(picks)})
-        for i, r in enumerate(picks):
-            row = active[r]
-            row.filled += c
-            if row.filled < row.padded.shape[1]:
-                continue
-            tok = int(first[i])     # the capture chunk's sample
-            row.t_first = time.perf_counter()
-            row.last = tok
-            row.out.append(tok)
-            row.decoding = True
-            self._pcache_insert(r, row)
-            if tok == row.req.stop_token or row.req.max_new_tokens == 1:
-                done = self._completion(row)
-                self._finish_completed(r, active, free_rows)
-                yield done
-        for r in list(decoding):
-            row = active[r]
-            for j in range(K):
-                tok = int(nxt[r, j])
-                row.out.append(tok)
-                row.step += 1
-                row.pos += 1
+        self._tick_block("fused", len(decoding), K)
+        self._tick["prefill_tokens"] += len(picks) * c
+        finished = []
+        with self._phase("batcher.retire"):
+            for i, r in enumerate(picks):
+                row = active[r]
+                row.filled += c
+                if row.filled < row.padded.shape[1]:
+                    continue
+                tok = int(first[i])     # the capture chunk's sample
+                row.t_first = time.perf_counter()
                 row.last = tok
-                if tok == row.req.stop_token or row.step >= \
-                        row.req.max_new_tokens:
-                    done = self._completion(row)
+                row.out.append(tok)
+                row.decoding = True
+                self._pcache_insert(r, row)
+                if tok == row.req.stop_token \
+                        or row.req.max_new_tokens == 1:
+                    finished.append(self._completion(row))
                     self._finish_completed(r, active, free_rows)
-                    yield done
-                    break
+            for r in list(decoding):
+                row = active[r]
+                for j in range(K):
+                    tok = int(nxt[r, j])
+                    row.out.append(tok)
+                    row.step += 1
+                    row.pos += 1
+                    row.last = tok
+                    if tok == row.req.stop_token or row.step >= \
+                            row.req.max_new_tokens:
+                        finished.append(self._completion(row))
+                        self._finish_completed(r, active, free_rows)
+                        break
+        yield from finished
 
     def _step(self, active: Dict[int, _Row],
               free_rows: List[int]) -> Iterator[Completion]:
@@ -4547,41 +4690,44 @@ class ContinuousBatcher:
         keeps still-filling rows out: their table rows mask to the sink
         so the batched scatter cannot touch their pages.)"""
         K = self.multi_step
-        toks = np.zeros((self.rows,), np.int32)
-        positions = np.zeros((self.rows,), np.int32)
-        rids = np.zeros((self.rows,), np.int32)
-        steps = np.zeros((self.rows,), np.int32)
-        decoding = {r: row for r, row in active.items() if row.decoding}
-        for r, row in decoding.items():
-            self._ensure_sides(r, min(row.pos + K, row.limit))
-            toks[r] = row.last
-            positions[r] = row.pos
-            rids[r] = row.rid
-            steps[r] = row.step
-        table = self.t_side.decode_table(active, decoding)
-        tb0 = time.perf_counter()
-        self.pool, nxt = self._decode(
-            self.params, self.pool, table, jnp.asarray(toks),
-            jnp.asarray(positions), jnp.asarray(rids), jnp.asarray(steps))
-        nxt = np.asarray(nxt)               # ONE host sync per K tokens
-        self.flight.record(
-            {"name": "decode.block", "mode": "sync",
-             "dur": round((time.perf_counter() - tb0) * 1000.0, 3),
-             "rows": len(decoding), "k": K})
-        for r in list(decoding):
-            row = active[r]
-            for j in range(K):
-                tok = int(nxt[r, j])
-                row.out.append(tok)
-                row.step += 1
-                row.pos += 1
-                row.last = tok
-                if tok == row.req.stop_token or row.step >= \
-                        row.req.max_new_tokens:
-                    done = self._completion(row)
-                    self._finish_completed(r, active, free_rows)
-                    yield done
-                    break
+        with self._phase("batcher.prep"):
+            toks = np.zeros((self.rows,), np.int32)
+            positions = np.zeros((self.rows,), np.int32)
+            rids = np.zeros((self.rows,), np.int32)
+            steps = np.zeros((self.rows,), np.int32)
+            decoding = {r: row for r, row in active.items()
+                        if row.decoding}
+            for r, row in decoding.items():
+                self._ensure_sides(r, min(row.pos + K, row.limit))
+                toks[r] = row.last
+                positions[r] = row.pos
+                rids[r] = row.rid
+                steps[r] = row.step
+            table = self.t_side.decode_table(active, decoding)
+        with self._phase("batcher.dispatch"):
+            self.pool, nxt = self._decode(
+                self.params, self.pool, table, jnp.asarray(toks),
+                jnp.asarray(positions), jnp.asarray(rids),
+                jnp.asarray(steps))
+        with self._phase("batcher.readback"):
+            nxt = np.asarray(nxt)           # ONE host sync per K tokens
+        self._tick_block("sync", len(decoding), K)
+        finished = []
+        with self._phase("batcher.retire"):
+            for r in list(decoding):
+                row = active[r]
+                for j in range(K):
+                    tok = int(nxt[r, j])
+                    row.out.append(tok)
+                    row.step += 1
+                    row.pos += 1
+                    row.last = tok
+                    if tok == row.req.stop_token or row.step >= \
+                            row.req.max_new_tokens:
+                        finished.append(self._completion(row))
+                        self._finish_completed(r, active, free_rows)
+                        break
+        yield from finished
 
     def _step_overlap(self, active: Dict[int, _Row],
                       free_rows: List[int]) -> Iterator[Completion]:
@@ -4605,34 +4751,38 @@ class ContinuousBatcher:
                     if row.decoding and row.step < row.req.max_new_tokens}
         prev = self._inflight
         if dispatch:
-            toks = np.zeros((self.rows,), np.int32)
-            use_dev = np.zeros((self.rows,), bool)
-            positions = np.zeros((self.rows,), np.int32)
-            rids = np.zeros((self.rows,), np.int32)
-            steps = np.zeros((self.rows,), np.int32)
-            prev_ticket = {} if prev is None else prev[1]
-            for r, row in dispatch.items():
-                self._ensure_sides(r, min(row.pos + K, row.limit))
-                if prev_ticket.get(r) == row.rid:
-                    use_dev[r] = True   # token = prev block's last output
-                else:
-                    toks[r] = row.last  # fresh admission / chunk flip
-                positions[r] = row.pos
-                rids[r] = row.rid
-                steps[r] = row.step
-            table = self.t_side.decode_table(active, dispatch)
-            prev_nxt = (prev[0] if prev is not None
-                        else jnp.zeros((self.rows, K), jnp.int32))
-            self.pool, nxt = self._decode(
-                self.params, self.pool, table, jnp.asarray(toks),
-                prev_nxt, jnp.asarray(use_dev), jnp.asarray(positions),
-                jnp.asarray(rids), jnp.asarray(steps))
-            nxt.copy_to_host_async()    # transfer overlaps the block
+            with self._phase("batcher.prep"):
+                toks = np.zeros((self.rows,), np.int32)
+                use_dev = np.zeros((self.rows,), bool)
+                positions = np.zeros((self.rows,), np.int32)
+                rids = np.zeros((self.rows,), np.int32)
+                steps = np.zeros((self.rows,), np.int32)
+                prev_ticket = {} if prev is None else prev[1]
+                for r, row in dispatch.items():
+                    self._ensure_sides(r, min(row.pos + K, row.limit))
+                    if prev_ticket.get(r) == row.rid:
+                        use_dev[r] = True   # prev block's last output
+                    else:
+                        toks[r] = row.last  # fresh admission / chunk flip
+                    positions[r] = row.pos
+                    rids[r] = row.rid
+                    steps[r] = row.step
+                table = self.t_side.decode_table(active, dispatch)
+            with self._phase("batcher.dispatch"):
+                prev_nxt = (prev[0] if prev is not None
+                            else jnp.zeros((self.rows, K), jnp.int32))
+                self.pool, nxt = self._decode(
+                    self.params, self.pool, table, jnp.asarray(toks),
+                    prev_nxt, jnp.asarray(use_dev),
+                    jnp.asarray(positions), jnp.asarray(rids),
+                    jnp.asarray(steps))
+                nxt.copy_to_host_async()    # transfer overlaps the block
             self._inflight = (nxt,
                               {r: row.rid for r, row in dispatch.items()})
             for row in dispatch.values():
                 row.pos += K
                 row.step += K
+            self._tick_block("overlap", len(dispatch), K)
         else:
             self._inflight = None
         if prev is not None:
@@ -4664,49 +4814,55 @@ class ContinuousBatcher:
                     if row.decoding and row.step < row.req.max_new_tokens}
         prev = self._inflight
         if dispatch:
-            prev_ticket = {} if prev is None else prev[1]
-            ticket = {r: row.rid for r, row in dispatch.items()}
-            # Rows entering this block from HOST values: fresh
-            # admissions, chunked-prefill flips, re-admissions into a
-            # freed row — anything the device carry does not cover.
-            fresh = frozenset(r for r, rid in ticket.items()
-                              if prev_ticket.get(r) != rid)
-            for r, row in dispatch.items():
-                self._ensure_sides(r, min(row.pos + K, row.limit))
-            table = self.t_side.decode_table(active, dispatch)
-            key = (tuple(sorted(ticket.items())), fresh)
-            host = self._pipe_host
-            if host is None or host[0] != key:
-                toks = np.zeros((self.rows,), np.int32)
-                use_host = np.zeros((self.rows,), bool)
-                positions = np.zeros((self.rows,), np.int32)
-                steps = np.zeros((self.rows,), np.int32)
-                rids = np.zeros((self.rows,), np.int32)
+            with self._phase("batcher.prep"):
+                prev_ticket = {} if prev is None else prev[1]
+                ticket = {r: row.rid for r, row in dispatch.items()}
+                # Rows entering this block from HOST values: fresh
+                # admissions, chunked-prefill flips, re-admissions into
+                # a freed row — anything the device carry does not cover.
+                fresh = frozenset(r for r, rid in ticket.items()
+                                  if prev_ticket.get(r) != rid)
                 for r, row in dispatch.items():
-                    rids[r] = row.rid
-                    if r in fresh:
-                        use_host[r] = True
-                        toks[r] = row.last
-                        positions[r] = row.pos
-                        steps[r] = row.step
-                host = (key, jnp.asarray(use_host), jnp.asarray(toks),
-                        jnp.asarray(positions), jnp.asarray(steps),
-                        jnp.asarray(rids))
-                self._pipe_host = host
-            carry = self._pipe_carry
-            if carry is None:       # pipeline start: fresh rows only
-                carry = (jnp.zeros((self.rows,), jnp.int32),
-                         jnp.zeros((self.rows,), jnp.int32),
-                         jnp.zeros((self.rows,), jnp.int32))
-            self.pool, nxt, ct, cp, cs = self._decode(
-                self.params, self.pool, table, host[1], host[2], host[3],
-                host[4], carry[0], carry[1], carry[2], host[5])
-            nxt.copy_to_host_async()    # transfer overlaps the block
+                    self._ensure_sides(r, min(row.pos + K, row.limit))
+                table = self.t_side.decode_table(active, dispatch)
+                key = (tuple(sorted(ticket.items())), fresh)
+                host = self._pipe_host
+                stale = host is None or host[0] != key
+                if stale:
+                    toks = np.zeros((self.rows,), np.int32)
+                    use_host = np.zeros((self.rows,), bool)
+                    positions = np.zeros((self.rows,), np.int32)
+                    steps = np.zeros((self.rows,), np.int32)
+                    rids = np.zeros((self.rows,), np.int32)
+                    for r, row in dispatch.items():
+                        rids[r] = row.rid
+                        if r in fresh:
+                            use_host[r] = True
+                            toks[r] = row.last
+                            positions[r] = row.pos
+                            steps[r] = row.step
+            with self._phase("batcher.dispatch"):
+                if stale:
+                    host = (key, jnp.asarray(use_host), jnp.asarray(toks),
+                            jnp.asarray(positions), jnp.asarray(steps),
+                            jnp.asarray(rids))
+                    self._pipe_host = host
+                carry = self._pipe_carry
+                if carry is None:       # pipeline start: fresh rows only
+                    carry = (jnp.zeros((self.rows,), jnp.int32),
+                             jnp.zeros((self.rows,), jnp.int32),
+                             jnp.zeros((self.rows,), jnp.int32))
+                self.pool, nxt, ct, cp, cs = self._decode(
+                    self.params, self.pool, table, host[1], host[2],
+                    host[3], host[4], carry[0], carry[1], carry[2],
+                    host[5])
+                nxt.copy_to_host_async()    # transfer overlaps the block
             self._pipe_carry = (ct, cp, cs)
             self._inflight = (nxt, ticket)
             for row in dispatch.values():
                 row.pos += K
                 row.step += K
+            self._tick_block("pipelined", len(dispatch), K)
         else:
             self._inflight = None
             self._pipe_carry = self._pipe_host = None
@@ -4720,33 +4876,32 @@ class ContinuousBatcher:
         previous retire (or were re-admitted since) fail the rid check
         and their block is dropped."""
         nxt, ticket = inflight
-        tb0 = time.perf_counter()
-        nxt = np.asarray(nxt)           # host sync: one block behind
-        # The lagged-block sync time IS the pipelined loop's per-block
-        # cost (dispatch is a non-blocking enqueue): one flight entry
-        # per block, like _step's synchronous one.
-        self.flight.record(
-            {"name": "decode.block",
-             "mode": "pipelined" if self._pipelined else "overlap",
-             "dur": round((time.perf_counter() - tb0) * 1000.0, 3),
-             "rows": len(ticket), "k": self.multi_step})
-        for r, rid in ticket.items():
-            row = active.get(r)
-            if row is None or row.rid != rid:
-                continue                # overshoot block of a freed row
-            for j in range(self.multi_step):
-                tok = int(nxt[r, j])
-                row.out.append(tok)
-                row.last = tok
-                if (tok == row.req.stop_token
-                        or len(row.out) >= row.req.max_new_tokens):
-                    done = self._completion(row)
-                    # _finish_completed parks session KV first: the
-                    # export clamps to the committed boundary, so the
-                    # lagged host view cannot overshoot the artifact.
-                    self._finish_completed(r, active, free_rows)
-                    yield done
-                    break
+        # The lagged-block sync IS the pipelined loop's per-block wait
+        # (dispatch is a non-blocking enqueue).
+        with self._phase("batcher.readback"):
+            nxt = np.asarray(nxt)       # host sync: one block behind
+        if self._tick["name"] != "decode.block":    # the draining tick
+            self._tick_block(self._mode, len(ticket), self.multi_step)
+        finished = []
+        with self._phase("batcher.retire"):
+            for r, rid in ticket.items():
+                row = active.get(r)
+                if row is None or row.rid != rid:
+                    continue            # overshoot block of a freed row
+                for j in range(self.multi_step):
+                    tok = int(nxt[r, j])
+                    row.out.append(tok)
+                    row.last = tok
+                    if (tok == row.req.stop_token
+                            or len(row.out) >= row.req.max_new_tokens):
+                        finished.append(self._completion(row))
+                        # _finish_completed parks session KV first: the
+                        # export clamps to the committed boundary, so
+                        # the lagged host view cannot overshoot the
+                        # artifact.
+                        self._finish_completed(r, active, free_rows)
+                        break
+        yield from finished
 
     def _step_spec(self, active: Dict[int, _Row],
                    free_rows: List[int]) -> Iterator[Completion]:
@@ -4756,60 +4911,72 @@ class ContinuousBatcher:
         (R = _spec_rounds > 1), committed round-by-round so stop/quota
         truncation is exact per round."""
         R = max(1, self._spec_rounds)
-        toks = np.zeros((self.rows,), np.int32)
-        # Rows with no live request still run the jitted round: park their
-        # positions at max_len (within the draft cache's +n_draft slack,
-        # clamped onto the sink page in the paged target) so their dummy
-        # draft writes can never clobber the broadcast prefix at positions
-        # 0..n_draft-1 of a draft-cache row a future request will reuse.
-        positions = np.full((self.rows,), self.max_len, np.int32)
-        rids = np.zeros((self.rows,), np.int32)
-        steps = np.zeros((self.rows,), np.int32)
-        decoding = {r: row for r, row in active.items() if row.decoding}
-        for r, row in decoding.items():
-            # The verify chunk writes positions [pos, pos + n_draft] (and
-            # the draft's k+1 scan steps write the same range of ITS
-            # pool); R fused rounds extend the worst case to
-            # R*(n_draft+1), clamped at limit — past-limit writes land
-            # on sink-clamped columns and their tokens are discarded at
-            # commit (same overrun argument as plain multi_step).
-            self._ensure_sides(r, min(row.pos + R * (self.n_draft + 1),
-                                      row.limit))
-            toks[r] = row.last
-            positions[r] = row.pos
-            rids[r] = row.rid
-            steps[r] = row.step
-        table = self.t_side.decode_table(active, decoding)
-        dtable = self.d_side.decode_table(active, decoding)
-        self.pool, self.d_side.pool, g, n_commit = self._spec_round(
-            self.params, self.pool, self.draft_params, self.d_side.pool,
-            table, dtable, jnp.asarray(toks), jnp.asarray(positions),
-            jnp.asarray(rids), jnp.asarray(steps))
-        g = np.asarray(g)
-        n_commit = np.asarray(n_commit)
+        with self._phase("batcher.prep"):
+            toks = np.zeros((self.rows,), np.int32)
+            # Rows with no live request still run the jitted round: park
+            # their positions at max_len (within the draft cache's
+            # +n_draft slack, clamped onto the sink page in the paged
+            # target) so their dummy draft writes can never clobber the
+            # broadcast prefix at positions 0..n_draft-1 of a draft-cache
+            # row a future request will reuse.
+            positions = np.full((self.rows,), self.max_len, np.int32)
+            rids = np.zeros((self.rows,), np.int32)
+            steps = np.zeros((self.rows,), np.int32)
+            decoding = {r: row for r, row in active.items()
+                        if row.decoding}
+            for r, row in decoding.items():
+                # The verify chunk writes positions [pos, pos + n_draft]
+                # (and the draft's k+1 scan steps write the same range of
+                # ITS pool); R fused rounds extend the worst case to
+                # R*(n_draft+1), clamped at limit — past-limit writes
+                # land on sink-clamped columns and their tokens are
+                # discarded at commit (same overrun argument as plain
+                # multi_step).
+                self._ensure_sides(r, min(row.pos + R * (self.n_draft + 1),
+                                          row.limit))
+                toks[r] = row.last
+                positions[r] = row.pos
+                rids[r] = row.rid
+                steps[r] = row.step
+            table = self.t_side.decode_table(active, decoding)
+            dtable = self.d_side.decode_table(active, decoding)
+        with self._phase("batcher.dispatch"):
+            self.pool, self.d_side.pool, g, n_commit = self._spec_round(
+                self.params, self.pool, self.draft_params,
+                self.d_side.pool, table, dtable, jnp.asarray(toks),
+                jnp.asarray(positions), jnp.asarray(rids),
+                jnp.asarray(steps))
+        with self._phase("batcher.readback"):
+            g = np.asarray(g)
+            n_commit = np.asarray(n_commit)
+        self._tick_block("spec", len(decoding), R * (self.n_draft + 1))
         if R == 1:
             g, n_commit = g[None], n_commit[None]   # [R=1, rows, ...]
         # Observability: the acceptance rate is THE speculative-serving
         # health number (a weak draft only costs rate, never correctness).
         self.spec_rounds += R
-        for i in range(R):
-            live = [r for r in decoding if r in active]
-            if not live:
-                break
-            self.spec_committed += int(sum(int(n_commit[i, r])
-                                           for r in live))
-            self.spec_row_rounds += len(live)
-            yield from self._commit_rows(g[i], n_commit[i], live, active,
-                                         free_rows)
+        finished = []
+        with self._phase("batcher.retire"):
+            for i in range(R):
+                live = [r for r in decoding if r in active]
+                if not live:
+                    break
+                self.spec_committed += int(sum(int(n_commit[i, r])
+                                               for r in live))
+                self.spec_row_rounds += len(live)
+                finished += self._commit_rows(g[i], n_commit[i], live,
+                                              active, free_rows)
+        yield from finished
 
     def _commit_rows(self, g, nc, rows, active: Dict[int, _Row],
-                     free_rows: List[int]) -> Iterator[Completion]:
+                     free_rows: List[int]) -> List[Completion]:
         """Commit one speculative round's outputs to ``rows`` — ONE code
         path for the sync (_step_spec) and overlap (_retire_spec) loops,
         so their truncation/finish semantics cannot diverge.  Quota and
         stop truncation: either way the row FINISHES, so the committed-
         stream/cache (and overlap device-carry) consistency question is
-        moot."""
+        moot.  Returns the rows' completions, in finish order."""
+        finished = []
         for r in rows:
             row = active[r]
             emit = list(g[r, :int(nc[r])])
@@ -4826,9 +4993,9 @@ class ContinuousBatcher:
                     or (row.req.stop_token is not None
                         and row.out and row.out[-1]
                         == row.req.stop_token)):
-                done = self._completion(row)
+                finished.append(self._completion(row))
                 self._finish_completed(r, active, free_rows)
-                yield done
+        return finished
 
     def _step_spec_overlap(self, active: Dict[int, _Row],
                            free_rows: List[int]) -> Iterator[Completion]:
@@ -4846,38 +5013,42 @@ class ContinuousBatcher:
                     if row.decoding and row.step < row.req.max_new_tokens}
         prev = self._inflight
         if dispatch:
-            toks = np.zeros((self.rows,), np.int32)
-            positions = np.full((self.rows,), self.max_len, np.int32)
-            steps = np.zeros((self.rows,), np.int32)
-            rids = np.zeros((self.rows,), np.int32)
-            use_dev = np.zeros((self.rows,), bool)
-            prev_ticket = {} if prev is None else prev[4]
-            for r, row in dispatch.items():
-                self._ensure_sides(r, min(row.pos + 2 * k1, self.max_len))
-                if prev_ticket.get(r) == row.rid:
-                    use_dev[r] = True   # continue from device carry
+            with self._phase("batcher.prep"):
+                toks = np.zeros((self.rows,), np.int32)
+                positions = np.full((self.rows,), self.max_len, np.int32)
+                steps = np.zeros((self.rows,), np.int32)
+                rids = np.zeros((self.rows,), np.int32)
+                use_dev = np.zeros((self.rows,), bool)
+                prev_ticket = {} if prev is None else prev[4]
+                for r, row in dispatch.items():
+                    self._ensure_sides(r, min(row.pos + 2 * k1,
+                                              self.max_len))
+                    if prev_ticket.get(r) == row.rid:
+                        use_dev[r] = True   # continue from device carry
+                    else:
+                        toks[r] = row.last
+                        positions[r] = row.pos
+                        steps[r] = row.step
+                    rids[r] = row.rid
+                table = self.t_side.decode_table(active, dispatch)
+                dtable = self.d_side.decode_table(active, dispatch)
+            with self._phase("batcher.dispatch"):
+                if prev is None:
+                    z = jnp.zeros((self.rows,), jnp.int32)
+                    carry = (jnp.zeros((self.rows, k1), jnp.int32), z, z, z)
                 else:
-                    toks[r] = row.last
-                    positions[r] = row.pos
-                    steps[r] = row.step
-                rids[r] = row.rid
-            table = self.t_side.decode_table(active, dispatch)
-            dtable = self.d_side.decode_table(active, dispatch)
-            if prev is None:
-                z = jnp.zeros((self.rows,), jnp.int32)
-                carry = (jnp.zeros((self.rows, k1), jnp.int32), z, z, z)
-            else:
-                carry = prev[:4]
-            (self.pool, self.d_side.pool, g, nc, pos_d,
-             steps_d) = self._spec_round(
-                self.params, self.pool, self.draft_params,
-                self.d_side.pool, table, dtable, jnp.asarray(toks),
-                jnp.asarray(positions), jnp.asarray(rids),
-                jnp.asarray(steps), jnp.asarray(use_dev), *carry)
-            g.copy_to_host_async()      # transfers overlap the round
-            nc.copy_to_host_async()
+                    carry = prev[:4]
+                (self.pool, self.d_side.pool, g, nc, pos_d,
+                 steps_d) = self._spec_round(
+                    self.params, self.pool, self.draft_params,
+                    self.d_side.pool, table, dtable, jnp.asarray(toks),
+                    jnp.asarray(positions), jnp.asarray(rids),
+                    jnp.asarray(steps), jnp.asarray(use_dev), *carry)
+                g.copy_to_host_async()      # transfers overlap the round
+                nc.copy_to_host_async()
             self._inflight = (g, nc, pos_d, steps_d,
                               {r: row.rid for r, row in dispatch.items()})
+            self._tick_block("spec_overlap", len(dispatch), k1)
         else:
             self._inflight = None
         if prev is not None:
@@ -4893,14 +5064,19 @@ class ContinuousBatcher:
         device-side commit count and the host view stays consistent
         with the in-graph position/step carry."""
         g, nc, _, _, ticket = inflight
-        g = np.asarray(g)       # host sync: one round behind dispatch
-        nc = np.asarray(nc)
-        live = [r for r, rid in ticket.items()
-                if r in active and active[r].rid == rid]
-        self.spec_rounds += 1
-        self.spec_row_rounds += len(live)
-        self.spec_committed += int(sum(int(nc[r]) for r in live))
-        yield from self._commit_rows(g, nc, live, active, free_rows)
+        with self._phase("batcher.readback"):
+            g = np.asarray(g)   # host sync: one round behind dispatch
+            nc = np.asarray(nc)
+        if self._tick["name"] != "decode.block":    # the draining tick
+            self._tick_block(self._mode, len(ticket), self.n_draft + 1)
+        with self._phase("batcher.retire"):
+            live = [r for r, rid in ticket.items()
+                    if r in active and active[r].rid == rid]
+            self.spec_rounds += 1
+            self.spec_row_rounds += len(live)
+            self.spec_committed += int(sum(int(nc[r]) for r in live))
+            finished = self._commit_rows(g, nc, live, active, free_rows)
+        yield from finished
 
     # -- end-to-end deadlines ----------------------------------------------
 

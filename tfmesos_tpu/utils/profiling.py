@@ -5,6 +5,12 @@ Thin wrappers over the JAX profiler so traces can be captured on any task
 and inspected with Perfetto/TensorBoard.  Enable globally by exporting
 ``TPUMESOS_TRACE_DIR`` — the trainer and node runtime leave these off by
 default (profiling is opt-in; it perturbs step timing).
+
+:func:`annotate` is what ``ContinuousBatcher`` opens every phase of a
+serve-loop tick with (``batcher.pull``, ``batcher.admit``, ... each with
+its ``tick`` number; docs/SERVING.md "Observability"): a trace taken here
+or by the benchmark shows what the host was doing in every gap between
+device programs.
 """
 
 from __future__ import annotations
@@ -35,7 +41,9 @@ def trace(logdir: Optional[str] = None) -> Iterator[Optional[str]]:
         jax.profiler.stop_trace()
 
 
-def annotate(name: str):
-    """Named region inside a trace (shows up on the Perfetto timeline)."""
+def annotate(name: str, **stats):
+    """Named region on the calling thread's host line of a profiler
+    trace, on the device planes' clock; ``stats`` (``tick=17``) ride
+    the event.  Outside a profiler session entering one is a flag test."""
     import jax
-    return jax.profiler.TraceAnnotation(name)
+    return jax.profiler.TraceAnnotation(name, **stats)
