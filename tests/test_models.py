@@ -1655,6 +1655,94 @@ def test_int8_paged_generate_matches_contiguous():
                                rtol=2e-4, atol=2e-4)
 
 
+def _commit_reference(pool, chunks, page_table, pos):
+    """``_paged_cache_write_all`` as it was before PR 25 (slices over layer
+    and KV around the indexed page and offset): the ground truth of WHICH
+    slot gets WHICH value, kept here because the chip relayouts the whole
+    pool around this formulation."""
+    from tfmesos_tpu.ops.quant import QTensor, quantize_int8_reference
+
+    L, b, t, kvh, dh = chunks.shape
+    ps = (pool.values if isinstance(pool, QTensor) else pool).shape[3]
+    posv = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
+    lpos = posv[:, None] + jnp.arange(t, dtype=jnp.int32)[None]
+    blk = jnp.minimum(lpos // ps, page_table.shape[1] - 1)
+    pages = jnp.take_along_axis(page_table, blk, axis=1).reshape(-1)
+    offs = (lpos % ps).reshape(-1)
+    x = chunks.transpose(1, 2, 0, 3, 4).reshape(b * t, L, kvh, dh)
+    if isinstance(pool, QTensor):
+        vals, scale = quantize_int8_reference(x)
+        return QTensor(pool.values.at[:, pages, :, offs].set(vals),
+                       pool.scales.at[:, pages, :, 0, offs].set(scale[..., 0]))
+    return pool.at[:, pages, :, offs].set(x.astype(pool.dtype))
+
+
+_COMMIT_SINK = 19
+# name -> (page table, t, pos, pos is traced).  Page 8, table width 4, so
+# max_len is 32 and a row parked there clamps from block 4 onto column 3.
+_COMMIT_CASES = {
+    # The steady-state token: ragged rows, two of them parked at max_len
+    # over all-sink table rows (they collide on the sink, nowhere else).
+    "t1_ragged_parked": ([[3, 7, 11, 2], [5, 9, 0, 13],
+                          [_COMMIT_SINK] * 4, [17, 1, 4, 6],
+                          [_COMMIT_SINK] * 4], 1, [5, 16, 32, 31, 32], True),
+    # Chunked prefill / speculative verify: a traced start inside a page,
+    # the chunk crossing page boundaries.
+    "t5_traced_unaligned": ([[3, 7, 11, 2], [5, 9, 0, 13]], 5, [3, 13],
+                            True),
+    # The same chunk at a STATIC start that is not page-aligned (a shared
+    # prefix of 13 tokens): rows form too.
+    "t16_static_unaligned": ([[3, 7, 11, 2], [5, 9, 0, 13]], 16, 13, False),
+    # Prefill: whole pages at a static aligned start; the padded tail runs
+    # past row 1's pages onto the sink, and block 4 clamps onto column 3.
+    "pages_padded_tail": ([[3, 7, 11, _COMMIT_SINK],
+                           [5, 9, _COMMIT_SINK, _COMMIT_SINK]], 32, 8, False),
+    "pages_from_zero": ([[3, 7, 11, 2], [5, 9, 0, 13]], 16, 0, False),
+}
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("case", sorted(_COMMIT_CASES))
+def test_paged_commit_equals_reference_formulation(case, quantized):
+    """The deferred commit writes the pool in the pool's own layout (rows
+    or whole-page windows, PR 25): bit for bit the slots and values of the
+    old formulation, values and scales, on every page but the sink (where
+    parked rows and padded tails collide by design and nothing reads)."""
+    from tfmesos_tpu.ops.quant import QTensor
+
+    table, t, pos, traced = _COMMIT_CASES[case]
+    table = jnp.asarray(table, jnp.int32)
+    L, kvh, ps, dh, n_pages = 3, 2, 8, 16, 20
+    rng = np.random.default_rng(sorted(_COMMIT_CASES).index(case))
+    shape = (L, n_pages, kvh, ps, dh)
+    if quantized:
+        pool = QTensor(
+            jnp.asarray(rng.integers(-127, 128, shape), jnp.int8),
+            jnp.asarray(rng.uniform(0.5, 2.0, shape[:3] + (1, ps)),
+                        jnp.float32))
+    else:
+        pool = jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+    chunks = jnp.asarray(rng.normal(size=(L, table.shape[0], t, kvh, dh)),
+                         jnp.bfloat16)
+    if traced:
+        run = lambda fn: jax.jit(fn)(pool, chunks, table,
+                                     jnp.asarray(pos, jnp.int32))
+    else:
+        run = lambda fn: jax.jit(
+            lambda p, c, tb: fn(p, c, tb, pos))(pool, chunks, table)
+    got = run(transformer._paged_cache_write_all)
+    want = run(_commit_reference)
+    keep = np.arange(n_pages) != _COMMIT_SINK
+    compared = lambda leaf: np.asarray(leaf, np.float32)[:, keep]
+    changed = False
+    for g, w, before in zip(*map(jax.tree_util.tree_leaves,
+                                 (got, want, pool))):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(compared(g), compared(w))
+        changed |= bool((compared(g) != compared(before)).any())
+    assert changed      # the case wrote somewhere that is compared
+
+
 def test_speculative_over_paged_cache():
     """Speculative decoding with a paged TARGET cache (verify chunks write
     and read through the page table) is bitwise the plain speculative

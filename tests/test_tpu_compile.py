@@ -14,6 +14,8 @@ the ``jax.default_backend()`` gates see the CPU here.
 """
 
 import os
+import re
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -180,3 +182,71 @@ def test_kernel_compiles_for_v5e(topo, name):
 
     compiled = jax.jit(make(mesh)).lower(*map(arg, args)).compile()
     assert compiled.as_text().count("tpu_custom_call") >= n_kernels
+
+
+# -- the deferred K/V commit keeps the pool's layout (PR 25) ------------------
+#
+# ``decode_step`` over a donated page pool, compiled whole for the described
+# v5e with the kernel path forced, at the widths of the benchmark's cells
+# (Mistral-7B's, 16 layers, 1300 pages of 64, 32 rows, table width 128: a
+# small pool is placed in another memory space and says nothing).  The commit
+# after the layer scan (``_paged_cache_write_all``) must scatter into the
+# pool in the pool's own layout: a scatter the TPU compiler gives another
+# operand layout comes wrapped in two copies of the WHOLE pool per leaf,
+# which cost 33 ms of a 53 ms decode block on the chip.  name -> (int8 pool,
+# rows, t, start position: "ragged" a traced [B] vector, "traced" a traced
+# scalar, or a static int).
+COMMIT_CASES = {
+    "t1_ragged": (False, 32, 1, "ragged"),
+    "t8_traced_start": (False, 32, 8, "traced"),
+    "prefill_t704": (False, 1, 704, 0),
+    "int8_t1_ragged": (True, 32, 1, "ragged"),
+    "int8_prefill_t704": (True, 1, 704, 0),
+}
+
+
+@pytest.mark.parametrize("name", sorted(COMMIT_CASES))
+def test_paged_commit_never_relayouts_the_pool(topo, monkeypatch, name):
+    from tfmesos_tpu.models import transformer
+    from tfmesos_tpu.ops.attention import attend
+
+    quantized, rows, t, start = COMMIT_CASES[name]
+    cfg = transformer.TransformerConfig(
+        vocab_size=32768, d_model=4096, n_layers=16, n_heads=32,
+        n_kv_heads=8, d_ff=14336, max_seq_len=8192, rope_theta=1e6,
+        dtype=BF16, param_dtype=BF16)
+    n_pages, width = 1300, 128
+    # The gates ask jax.default_backend(), which is the CPU here.
+    monkeypatch.setattr(transformer, "_decode_kernel_kwargs",
+                        lambda *a, **k: {"use_pallas": True})
+    monkeypatch.setattr(transformer, "attend",
+                        partial(attend, use_pallas=True))
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def struct(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(struct, jax.eval_shape(
+        lambda: transformer.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = jax.tree_util.tree_map(struct, jax.eval_shape(
+        lambda: dict(transformer.init_paged_cache(cfg, n_pages, PAGE,
+                                                  quantized=quantized),
+                     pages=jnp.zeros((rows, width), I32))))
+    tokens = jax.ShapeDtypeStruct((rows, t), I32, sharding=one_chip)
+    # A static start is closed over; a traced one is the step's last argument.
+    traced = () if isinstance(start, int) else (jax.ShapeDtypeStruct(
+        (rows,) if start == "ragged" else (), I32, sharding=one_chip),)
+    step = jax.jit(lambda p, c, tok, *pos: transformer.decode_step(
+        cfg, p, c, tok, *(pos or (start,))), donate_argnums=1)
+    text = step.lower(params, cache, tokens, *traced).compile().as_text()
+    assert "tpu_custom_call" in text            # the kernel path was taken
+    assert " scatter(" in text                  # and the commit is in there
+    # The K/V leaf ([L, P, KV, page, Dh]; an int8 pool's ``values``).  The
+    # int8 pool's small lane-major scales leaf is not held to this: the
+    # paged kernel takes it in another layout than the program's
+    # parameters have, whatever the commit does.
+    leaf = (f"{'s8' if quantized else 'bf16'}[{cfg.n_layers},{n_pages},"
+            f"{cfg.kv_heads},{PAGE},{cfg.head_dim}]")
+    moved = re.findall(r"= " + re.escape(leaf)
+                       + r"\S* (?:copy|transpose)\([^)]*\)", text)
+    assert not moved, f"{leaf} is relayouted: {moved[:2]}"

@@ -920,9 +920,13 @@ def _paged_cache_write(pool, chunk, li, page_table, pos):
     pool ([L, P, KV, page, Dh]; int8 QTensors quantize per position on
     the way in) at logical positions ``pos..pos+t-1`` per row (``pos``
     scalar or [B]): one scatter over (page, offset) pairs chased through
-    the table.  The pool is a layer-scan CARRY, so the scatter updates
-    it in place — per-step traffic is the written slots, never the
-    pool."""
+    the table.  The pool is a layer-scan CARRY and the scatter is meant to
+    update it in place.  Only the mesh path (``_sharded_paged_step``)
+    still writes per layer through here.  It has the window shape (a slice
+    over KV between the indexed page and offset) that made the TPU
+    compiler relayout the whole pool around ``_paged_cache_write_all``'s
+    scatter on the single-host path until PR 25; whether it does so per
+    shard too is unmeasured (PERF.md §7)."""
     b, t = chunk.shape[:2]
     ps = (pool.values if isinstance(pool, QTensor) else pool).shape[3]
     posv = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
@@ -953,37 +957,65 @@ def _paged_cache_write(pool, chunk, li, page_table, pos):
 
 def _paged_cache_write_all(pool, chunks, page_table, pos):
     """Commit ALL layers' deferred chunks ([L, B, t, KV, Dh], stacked by
-    the decode layer scan) in ONE scatter per pool leaf — 2L scatters
-    per step become 2 (one scatter op costs ~0.5 ms on TPU regardless
-    of payload, so the op COUNT is the serving decode's write cost).
-    t = 1 is the steady-state deferred token; t > 1 the fused
-    multi-row step (speculative verify / chunked-prefill tails), whose
-    per-token (page, offset) pairs chase the table exactly like the
-    per-layer ``_paged_cache_write`` — same index math (sink clamp
-    included) and same per-row absmax int8 rule."""
+    the decode layer scan) into the page pool ([L, P, KV, page, Dh]) with
+    one scatter per pool leaf, expressed IN THE POOL'S OWN LAYOUT.
+
+    The layout rule: every pool dim in front of the scatter's window is
+    indexed, and the window is a trailing slab of the pool
+    ([Dh], or [page, Dh]).  The TPU compiler then scatters into a bitcast
+    of the donated pool, in place.  A window that skips dims (slices over
+    layer and KV around the indexed page and offset, as this function had
+    it before) makes the compiler pick another operand layout and wrap
+    the scatter in two whole-pool copies per leaf: on the v5e those four
+    copies of a 2.7 GB leaf were 33 ms of a 53 ms decode block (PERF.md,
+    PR 25) — the cost was the relayout, never the scatter.
+
+    Two windows, chosen from static shapes:
+
+    * rows — window [Dh], (layer, page, kv, offset) indexed per token: t = 1
+      (the steady-state deferred token) and any chunk whose start is traced
+      or not page-aligned (chunked prefill, fused tick, speculative verify);
+    * pages — window [page, Dh], (layer, page, kv) indexed per block: a
+      chunk of whole pages at a static page-aligned ``pos`` (prefill).  A
+      TPU scatter walks its index rows one by one, and a prefill in the
+      rows form has t*L*KV of them.
+
+    Same index math (sink clamp included) and the same per-row absmax
+    int8 rule as the per-layer ``_paged_cache_write``; the lane-major
+    scales leaf ([L, P, KV, 1, page]) follows the same rule with a scalar
+    or a [1, page] window."""
     L, b, t, kvh, dh = chunks.shape
     ps = (pool.values if isinstance(pool, QTensor) else pool).shape[3]
-    posv = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
-    lpos = posv[:, None] + jnp.arange(t, dtype=jnp.int32)[None]   # [B, t]
-    blk = jnp.minimum(lpos // ps, page_table.shape[1] - 1)
-    pages = jnp.take_along_axis(page_table, blk, axis=1).reshape(-1)
-    offs = (lpos % ps).reshape(-1)
-    # [L, B, t, KV, Dh] -> [B*t, L, KV, Dh] update rows, (page, offset)
-    # indexed per (row, token).
-    x = chunks.transpose(1, 2, 0, 3, 4).reshape(b * t, L, kvh, dh)
-
-    def put(buf, x):
-        # Advanced indices (pages, offs) around the slices front the
-        # row dim: updates arrive [B*t, L, KV, Dh'].
-        return buf.at[:, pages, :, offs].set(x.astype(buf.dtype))
+    last = page_table.shape[1] - 1
+    li = jnp.arange(L, dtype=jnp.int32)[None, :, None]
+    ki = jnp.arange(kvh, dtype=jnp.int32)[None, None, :]
+    whole_pages = isinstance(pos, int) and pos % ps == 0 and t % ps == 0
+    if whole_pages:
+        nb = t // ps
+        blk = jnp.minimum(pos // ps + jnp.arange(nb, dtype=jnp.int32), last)
+        at = scale_at = (li, page_table[:, blk].reshape(-1, 1, 1), ki)
+        # [L, B, nb, page, KV, Dh] -> [B*nb, L, KV, page, Dh] page windows.
+        x = chunks.reshape(L, b, nb, ps, kvh, dh).transpose(
+            1, 2, 0, 4, 3, 5).reshape(b * nb, L, kvh, ps, dh)
+    else:
+        posv = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
+        lpos = posv[:, None] + jnp.arange(t, dtype=jnp.int32)[None]  # [B, t]
+        blk = jnp.minimum(lpos // ps, last)
+        pages = jnp.take_along_axis(page_table, blk, axis=1)
+        at = (li, pages.reshape(-1, 1, 1), ki, (lpos % ps).reshape(-1, 1, 1))
+        scale_at = at[:3] + (0, at[3])
+        # [L, B, t, KV, Dh] -> [B*t, L, KV, Dh] update rows.
+        x = chunks.transpose(1, 2, 0, 3, 4).reshape(b * t, L, kvh, dh)
 
     if isinstance(pool, QTensor):
         from tfmesos_tpu.ops.quant import quantize_int8_reference
         vals, scale = quantize_int8_reference(x)
-        scales = pool.scales.at[:, pages, :, 0, offs].set(
-            scale[..., 0])
-        return QTensor(put(pool.values, vals), scales)
-    return put(pool, x)
+        # [..., page, 1] -> the scales leaf's [1, page] window (pages), or
+        # the scalar at (.., 0, offset) (rows).
+        scale = jnp.swapaxes(scale, -1, -2) if whole_pages else scale[..., 0]
+        return QTensor(pool.values.at[at].set(vals),
+                       pool.scales.at[scale_at].set(scale))
+    return pool.at[at].set(x.astype(pool.dtype))
 
 
 def _cache_write(cache, chunk, li, pos, rolling: bool = False):
@@ -1334,7 +1366,10 @@ def _attend_decode(cfg: TransformerConfig, x, lp, ck, cv, li, positions,
     pool [L, P, KV, page, Dh]) carried through the layer scan, with
     ``li`` this block's layer index — writes update one slot in place at
     the index and the kernels read O(pos) at the index through their
-    scalar prefetch, so the full buffer is never restacked or sliced;
+    scalar prefetch, so the full buffer is never restacked or sliced
+    (the single-host paged pool is not written here at all: its chunk
+    comes back deferred and ``decode_step`` commits every layer's at once,
+    in the pool's own layout — ``_paged_cache_write_all``);
     ``positions``: [B, t] per-row global positions of the chunk (rows
     differ in the ragged case); ``pos``: first chunk position — scalar
     (python int or traced) or [B] vector, as handed to ``_cache_write``.
@@ -1361,14 +1396,15 @@ def _attend_decode(cfg: TransformerConfig, x, lp, ck, cv, li, positions,
     rolling = cfg.window is not None
     self_attn_prefill = t > 1 and isinstance(pos, int) and pos == 0
     o_paged = None
-    # Single-host paged steps DEFER their pool commit: one XLA scatter
-    # costs ~0.5 ms regardless of size (measured, v5e), so the
-    # per-layer write-then-attend order would spend 2L scatters per
-    # step.  Instead the chunk rides into attention as a SELF operand
-    # (kernel: a [head_block, t, d] block accumulated at the last page
-    # step, causal across the chunk's own tokens; reference: written
-    # into the gathered view) and decode_step commits ALL layers'
-    # chunks in one scatter per pool leaf after the scan.  t > 1 is the
+    # Single-host paged steps DEFER their pool commit: the per-layer
+    # write-then-attend order would spend 2L scatters per step, each
+    # with its own launch.  Instead the chunk rides into attention as a
+    # SELF operand (kernel: a [head_block, t, d] block accumulated at
+    # the last page step, causal across the chunk's own tokens;
+    # reference: written into the gathered view) and decode_step
+    # commits ALL layers' chunks in one scatter per pool leaf after the
+    # scan (_paged_cache_write_all: in the pool's own layout, 0.35 ms
+    # per leaf at 32 rows on the v5e).  t > 1 is the
     # fused multi-row step (speculative verify / chunked-prefill
     # tails): t rows retire through ONE attention launch per layer and
     # one commit pair per dispatch, instead of per-layer write-then-

@@ -1201,10 +1201,11 @@ def _copy_page(pool, src, dst):
 
 #: The tick ring (docs/SERVING.md "Observability"): one record per pass
 #: of every batcher's serve loop in this process, in the process-global
-#: ``flight(TICK_COMPONENT)`` so that it outlives the batcher.  4096
-#: records are ~3 minutes of 50 ms ticks, under 2 MB.
+#: ``flight(TICK_COMPONENT)`` so that it outlives the batcher.  16384
+#: records are ~5 minutes of 20 ms ticks (a decode block at Mistral-7B
+#: widths on the v5e since PR 25), under 8 MB once full.
 TICK_COMPONENT = "batcher.tick"
-TICK_RING = 4096
+TICK_RING = 16384
 
 _BATCHER_IDS = itertools.count()
 #: backend compiles that finished in this process, and their seconds: a
