@@ -7,13 +7,13 @@ window at the cell's own load).
 
 For a served model the control is int8 for the bf16 the configuration
 states.  By default it is the plain reference computed with int8 weights
-(``reference.py``): at every position of the sampled requests it reads the
-reference-logit gap of the token the control puts first, in the same run
-as the program's own reading.  With ``--program-int8 1`` it is the program
-itself serving the cell from its weight-only int8 path
-(``transformer.quantize_params``): the run's ``correct`` then has to come
-out false.  One JSON line per seed goes to stdout and to
-``benchmark_out/control/<workload>.jsonl``.  The benchmark's own runs never
+(the adapter's ``served_gaps(..., control=True)``): at every position of
+the sampled requests it reads the reference-logit gap of the token the
+control puts first, in the same run as the program's own reading.  With
+``--program-int8 1`` it is the program itself serving the cell from its
+weight-only int8 path (the adapter's ``int8_program_weights``): the run's
+``correct`` then has to come out false.  One JSON line per seed goes to
+stdout and to ``benchmark_out/control/<workload>.jsonl``.  The benchmark's own runs never
 run this; the limits in the configuration files were set from its output
 (PERF.md gives the readings).
 """

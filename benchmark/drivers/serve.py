@@ -4,7 +4,8 @@
 One process holds the chip.  Order of a run:
 
   set-up   JAX up, the chip looked for, the weights made on the device from
-           ``--seed`` (``weights.py``), the batcher built, every shape this
+           ``--seed`` (the adapter's ``make_weights``), the batcher built
+           from the adapter's ``program_config``, every shape this
            cell's traffic uses warmed (its padded prompt widths through
            ``run()``, the decode widths through ``warmup()``), then the
            unmeasured ramp: the generator (or the backlog) runs until the
@@ -21,6 +22,11 @@ defaults of ``fleet/replica.py``'s own argument parser, read at run time, so
 that a PR which changes what ``tfserve`` does by default is measured
 without touching the benchmark.  Only ``rows``, ``max_len``, ``page_size``
 and ``n_pages`` come from the configuration file.
+
+What belongs to one model family (how the program is configured, the
+weights, the plain reference, the shapes the readers look for) is the
+configuration's adapter, ``models/<model>.py``, found by
+``harness.load_model``; the readers get it as ``run["model"]``.
 """
 
 from __future__ import annotations
@@ -35,7 +41,7 @@ from typing import Any, Callable, Dict, List
 
 import numpy as np
 
-from benchmark import harness, reference, traffic_gen, window
+from benchmark import harness, traffic_gen, window
 from benchmark.window import Served
 
 #: seconds of the window that a ``--trace 1`` run records with the profiler
@@ -74,32 +80,17 @@ def batcher_options() -> Dict[str, Any]:
     }
 
 
-def model_config(config: Dict[str, Any], max_len: int):
-    import jax.numpy as jnp
-    from tfmesos_tpu.models.transformer import TransformerConfig
-    if config.get("sliding_window"):
-        raise SystemExit("paged serving does not take a sliding window")
-    dtype = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[
-        config["torch_dtype"]]
-    return TransformerConfig(
-        vocab_size=config["vocab_size"], d_model=config["hidden_size"],
-        n_layers=config["num_hidden_layers"],
-        n_heads=config["num_attention_heads"],
-        n_kv_heads=config["num_key_value_heads"],
-        d_ff=config["intermediate_size"], max_seq_len=max_len,
-        rope_theta=float(config["rope_theta"]), dtype=dtype,
-        param_dtype=dtype)
-
-
-def check_served(weights, config, records: List[Served], seed: int,
+def check_served(model, weights, config, records: List[Served], seed: int,
                  limits: Dict[str, float], n_sample: int,
                  control: bool = False, out: Callable = print
                  ) -> Dict[str, Any]:
     """``correct`` for a served model: on a sample of the finished
     requests, drawn from the seed and with the longest in it, the plain
-    reference reads every served token (teacher-forced) and reports how
-    far its logit lies below the reference's best.  Greedy serving only.
-    Every number is printed beside its limit."""
+    reference (the adapter's ``served_gaps``) reads every served token
+    (teacher-forced) and reports how far its logit lies below the
+    reference's best.  Greedy serving only.  The numbers compared come
+    back under ``compared``, each beside its limit (``run.py`` prints
+    them); a reading without a limit is printed here."""
     limits = {"length_mismatches": 0, "token_ids_out_of_range": 0, **limits}
     done = [r for r in records if r.done is not None and r.tokens]
     vocab = config["vocab_size"]
@@ -115,8 +106,8 @@ def check_served(weights, config, records: List[Served], seed: int,
         rest = [r for r in done if r is not longest]
         pick = [longest] + [rest[i] for i in rng.permutation(len(rest))
                             [:max(0, n_sample - 1)]]
-        got = [reference.served_gaps(weights, config, r.prompt, r.tokens,
-                                     control=control) for r in pick]
+        got = [model.served_gaps(weights, config, r.prompt, r.tokens,
+                                  control=control) for r in pick]
         gap = np.concatenate([g["gap"] for g in got])
         readings.update(
             sampled_requests=len(pick), served_tokens=int(gap.size),
@@ -128,16 +119,16 @@ def check_served(weights, config, records: List[Served], seed: int,
             readings.update(control_max_gap=float(cg.max()),
                             control_mean_gap=float(cg.mean()),
                             control_off_best_share=float(np.mean(cg > 0)))
-    ok = bool(done) and all(k in readings for k in limits)
-    for key, lim in limits.items():
-        if key in readings:
-            ok = ok and readings[key] <= lim
-            out(f"correct: {key} = {readings[key]:.6g} (limit {lim})")
+    compared = {k: {"value": readings[k], "limit": lim}
+                for k, lim in limits.items() if k in readings}
+    ok = bool(done) and len(compared) == len(limits) and all(
+        c["value"] <= c["limit"] for c in compared.values())
     for key in ("max_gap", "mean_gap", "off_best_share", "control_max_gap",
                 "control_mean_gap", "control_off_best_share"):
         if key in readings and key not in limits:
             out(f"correct: {key} = {readings[key]:.6g} (no limit)")
     readings["correct"] = bool(ok)
+    readings["compared"] = compared
     return readings
 
 
@@ -173,26 +164,25 @@ def run_cell(spec: Dict[str, Any], cell: Dict[str, Any],
 
     ``control`` also reads the reference with int8 weights on the sampled
     requests.  ``program_int8`` serves from the program's own weight-only
-    int8 path (``transformer.quantize_params``) while the reference keeps
-    the weights as made: the control that has to come out not correct.
+    int8 path (the adapter's ``int8_program_weights``) while the reference
+    keeps the weights as made: the control that has to come out not correct.
     ``schedule`` is for ``sweep.py`` (the cell's schedule at another
     rate).  A benchmark run sets none of the three."""
     import jax
+    model = harness.load_model(config)      # fails before the chip is sought
     harness.enable_compile_cache()
     device = harness.accelerator(int(cell["chips"]), require_chip)
     from tfmesos_tpu.serving import ContinuousBatcher, Request
-    from benchmark import weights as weights_mod
 
     dep = config["deployment"]
-    cfg = model_config(config, int(dep["max_len"]))
-    w = weights_mod.make_weights(config, seed, dtype=cfg.dtype)
+    cfg = model.program_config(config, int(dep["max_len"]))
+    w = model.make_weights(config, seed, dtype=cfg.dtype)
     jax.block_until_ready(w)
     served_w = w
     if program_int8:
         # int8 weights, the bf16 ones and the pool do not fit together:
         # the reference's weights are made again after the batcher is freed
-        from tfmesos_tpu.models.transformer import quantize_params
-        served_w = jax.block_until_ready(quantize_params(cfg, w))
+        served_w = jax.block_until_ready(model.int8_program_weights(cfg, w))
         del w
     opts = batcher_options()
     batcher = ContinuousBatcher(
@@ -348,8 +338,9 @@ def run_cell(spec: Dict[str, Any], cell: Dict[str, Any],
     _dump_records(cell["name"], seed, trace, records, t0, t1, e2e)
     run: Dict[str, Any] = {
         "records": records, "t0": t0, "t1": t1, "seconds": float(seconds),
-        "schedule": sched, "config": config, "device": device,
-        "trace": None, "trace_window": (tw0, tw1), "e2e": e2e,
+        "schedule": sched, "config": config, "model": model,
+        "device": device, "trace": None, "trace_window": (tw0, tw1),
+        "e2e": e2e,
         "counters": {"rows": int(dep["rows"]),
                      "n_pages": int(dep["n_pages"]),
                      "page_size": int(dep["page_size"])},
@@ -372,12 +363,12 @@ def run_cell(spec: Dict[str, Any], cell: Dict[str, Any],
     gc.unfreeze()
     gc.collect()
     if program_int8:
-        w = weights_mod.make_weights(config, seed, dtype=cfg.dtype)
+        w = model.make_weights(config, seed, dtype=cfg.dtype)
     in_run = [r for r in records
               if r.done is not None and r.done >= t0 and r.done <= t_end]
     chk = config["correct"]
     t_chk = time.perf_counter()
-    check = check_served(w, config, in_run, seed, chk["limits"],
+    check = check_served(model, w, config, in_run, seed, chk["limits"],
                          int(chk["sample_requests"]), control=control,
                          out=out)
     failed += check["length_mismatches"] + check["token_ids_out_of_range"]

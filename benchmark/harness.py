@@ -54,9 +54,25 @@ def find_cell(spec: Dict[str, Any], name: str) -> Dict[str, Any]:
                      f"(known: {[w['name'] for w in spec['workloads']]})")
 
 
+def load_model(config: Dict[str, Any]):
+    """The configuration's model adapter, ``models/<model>.py``: the module
+    that knows the block (README.md lists its functions).  A configuration
+    names it with ``"model"``; there is no default."""
+    name = config.get("model")
+    if not name:
+        raise SystemExit("benchmark: the configuration names no \"model\" "
+                         "(the adapter under benchmark/models/)")
+    if not os.path.exists(os.path.join(HERE, "models", name + ".py")):
+        raise SystemExit(f"benchmark: no model adapter {name!r} under "
+                         f"{os.path.join(HERE, 'models')}")
+    return importlib.import_module("benchmark.models." + name)
+
+
 def load_cell(workload: str):
     """Everything one cell is made of, found by name: ``(spec, cell,
-    config, traffic, driver module)``."""
+    config, traffic, driver module)``.  The driver finds the
+    configuration's model adapter the same way (``load_model``), before it
+    looks for the chip."""
     from benchmark import traffic_gen
     require_program()
     spec = load_spec()
@@ -163,10 +179,21 @@ def per_layer(spec: Dict[str, Any], cell: str, run: Dict[str, Any]
 
 def result_line(*, correct: bool, attempted: int, failed: int,
                 metrics: Dict[str, Dict[str, Any]], device: Dict[str, Any],
-                breakdown: Optional[Dict[str, Any]] = None) -> str:
+                breakdown: Optional[Dict[str, Any]] = None,
+                compared: Optional[Dict[str, Dict[str, float]]] = None
+                ) -> str:
+    """The result: the keys the driver reads, then ``compared``, the
+    numbers ``correct`` was decided from, each with its limit, last."""
     line: Dict[str, Any] = {
         "correct": bool(correct), "attempted": int(attempted),
         "failed": int(failed), "metrics": metrics, "device": device}
     if breakdown is not None:
         line["breakdown"] = breakdown
+    if compared is not None:
+        line["compared"] = compared
     return json.dumps(line)
+
+
+def compared_lines(compared: Dict[str, Dict[str, float]]) -> List[str]:
+    return [f"correct: {k} = {v['value']:.6g} (limit {v['limit']})"
+            for k, v in compared.items()]

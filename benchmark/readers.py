@@ -1,9 +1,10 @@
 """The reductions the per-layer readers share.  A reader is
 ``layer_metrics/<metric>.py`` with ``read(run) -> number | None``; most are
 one line that names a function of this module.  ``run`` is what a driver
-hands over: the client-side records, the window, the configuration, the
-device with its peaks, and in a traced run the reduced trace.  A reader
-that finds nothing to read returns None.
+hands over: the client-side records, the window, the configuration and
+its model adapter (``run["model"]``: the shapes and bytes that belong to one
+block), the device with its peaks, and in a traced run the reduced trace.
+A reader that finds nothing to read returns None.
 """
 
 from __future__ import annotations
@@ -11,7 +12,7 @@ from __future__ import annotations
 import statistics
 from typing import List, Optional
 
-from benchmark import costs, trace_reduce, window
+from benchmark import trace_reduce, window
 
 
 def _due_in_window(run):
@@ -34,12 +35,12 @@ def decode_rows_mean(run) -> Optional[float]:
 
 
 def pool_fill(run) -> Optional[float]:
-    """Share of the paged pool's token slots (n_pages x page_size) that
-    held a live request's context, time-averaged over the window.  The
-    pool is reserved whole whatever the traffic; this says how much of the
-    reservation the cell's traffic uses."""
-    c = run["counters"]
-    slots = c["n_pages"] * c["page_size"]
+    """Share of the paged pool's token slots (the adapter's count; for a
+    homogeneous stack n_pages x page_size) that held a live request's
+    context, time-averaged over the window.  The pool is reserved whole
+    whatever the traffic; this says how much of the reservation the cell's
+    traffic uses."""
+    slots = run["model"].token_slots(run["config"], run["counters"])
     return 100.0 * window.live_tokens_mean(
         run["records"], run["t0"], run["t1"]) / slots
 
@@ -48,9 +49,8 @@ def _module_durations(run, kind: str) -> List[float]:
     tr = run.get("trace")
     if tr is None:
         return []
-    runs = trace_reduce.module_runs(tr, run["config"]["hidden_size"],
-                                    run["counters"]["rows"])
-    return [r["dur"] for r in runs if r["kind"] == kind]
+    return [r["dur"] for r in trace_reduce.module_runs(tr)
+            if r["kind"] == kind]
 
 
 def prefill_p50_ms(run) -> Optional[float]:
@@ -68,21 +68,18 @@ def _dims(text_shape: str) -> List[int]:
     return [int(x) for x in inner.split(",") if x]
 
 
-def _paged_kernel_shape(run) -> List[int]:
-    m = costs.dims(run["config"])
-    return [run["counters"]["rows"], m.kv, m.heads // m.kv, m.hd]
-
-
 def paged_decode_roofline(run) -> Optional[float]:
     """Bytes of cached K and V the decode steps of the traced window had
-    to read for their live contexts (costs.py), over the device time of
-    the paged-decode kernel (the Pallas custom-call whose output is
-    [rows, kv_heads, q_per_kv, head_dim]) and the chip's HBM bandwidth."""
+    to read for their live contexts (the adapter's bytes per context
+    token), over the device time of the paged-decode kernel (the Pallas
+    custom-call whose output has the adapter's ``paged_kernel_shape``) and
+    the chip's HBM bandwidth."""
     tr = run.get("trace")
     tw0, tw1 = run.get("trace_window", (None, None))
     if tr is None or tw0 is None or not tr.devices:
         return None
-    want = _paged_kernel_shape(run)
+    model = run["model"]
+    want = model.paged_kernel_shape(run["config"], run["counters"]["rows"])
     kernel_s = 0.0
     for name, _, d in tr.devices[0].ops:
         p = trace_reduce.parse_op(name)
@@ -93,7 +90,7 @@ def paged_decode_roofline(run) -> Optional[float]:
         return None
     nbytes = window.decode_read_bytes(
         run["records"], tw0, tw1,
-        costs.kv_bytes_per_context_token(run["config"]))
+        model.kv_bytes_per_context_token(run["config"]))
     floor_s = nbytes / run["device"]["peaks"]["hbm_bytes_per_s"]
     return 100.0 * floor_s / kernel_s
 
@@ -109,14 +106,13 @@ def attn_kernel_share(run) -> Optional[float]:
 
 def pool_copy_share(run) -> Optional[float]:
     """Share of device busy time in ``copy`` and dynamic-slice instructions
-    whose result is the paged pool ([L, pages, kv, page, head_dim]) or one
-    layer of it: whole-pool traffic that serves no token."""
+    whose result has one of the adapter's ``pool_leaf_shapes`` (a leaf of
+    the paged pool or one layer of it): whole-pool traffic that serves no
+    token."""
     tr = run.get("trace")
     if tr is None:
         return None
-    m = costs.dims(run["config"])
-    pool = [m.layers, run["counters"]["n_pages"], m.kv,
-            run["counters"]["page_size"], m.hd]
+    pool = run["model"].pool_leaf_shapes(run["config"], run["counters"])
 
     def pick(p, text):
         if p["shape"] == "(tuple)" or not p["shape"]:
@@ -124,6 +120,6 @@ def pool_copy_share(run) -> Optional[float]:
         if not (p["opcode"] in ("copy", "dynamic-slice")
                 or (p["opcode"] == "fusion" and "dynamic-slice" in p["name"])):
             return False
-        return _dims(p["shape"]) in (pool, pool[1:])
+        return _dims(p["shape"]) in pool
 
     return trace_reduce.share_of_busy(tr, pick)

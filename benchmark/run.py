@@ -5,11 +5,13 @@
 
 Runs from the root of a checkout, on the machine it is started on, in one
 new process.  The last line of its standard output is one JSON object:
-``correct``, ``attempted``, ``failed``, ``metrics``, ``device`` and, in a
-traced run, ``breakdown``.  With ``--trace 0`` the metrics are the cell's
-end-to-end metrics, with ``--trace 1`` its per-layer metrics.  It exits
-non-zero, and prints no result, where JAX finds no TPU, fewer chips than the
-cell asks for, or a device kind that ``peaks.json`` does not list.
+``correct``, ``attempted``, ``failed``, ``metrics``, ``device``, in a
+traced run ``breakdown``, and last ``compared`` (each number ``correct``
+was decided from, with its limit; the same as the last lines of standard
+error).  With ``--trace 0`` the metrics are the cell's end-to-end metrics,
+with ``--trace 1`` its per-layer metrics.  It exits non-zero, and prints no
+result, where JAX finds no TPU, fewer chips than the cell asks for, or a
+device kind that ``peaks.json`` does not list.
 
 Everything that belongs to one configuration, one traffic mix or one
 per-layer metric is a file found by its name (see README.md).
@@ -42,10 +44,15 @@ def main(argv=None) -> int:
                           seconds=args.seconds, trace=bool(args.trace),
                           t_start=T_START)
     sys.stdout.flush()
+    compared = res["check"]["compared"]
+    # each number compared beside its limit: the last lines on standard
+    # error, and the last key of the result
+    print("\n".join(harness.compared_lines(compared)), file=sys.stderr,
+          flush=True)
     print(harness.result_line(
         correct=res["correct"], attempted=res["attempted"],
         failed=res["failed"], metrics=res["metrics"], device=res["device"],
-        breakdown=res.get("breakdown")), flush=True)
+        breakdown=res.get("breakdown"), compared=compared), flush=True)
     return 0
 
 

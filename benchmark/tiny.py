@@ -8,6 +8,7 @@ TINY_CONFIG = {
     "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
     "vocab_size": 256, "rms_norm_eps": 1e-5, "rope_theta": 1e6,
     "sliding_window": None, "torch_dtype": "float32", "driver": "serve",
+    "model": "mistral",
     "deployment": {"chips": 1, "rows": 4, "max_len": 256, "page_size": 16,
                    "n_pages": 80},
     "correct": {"sample_requests": 12, "limits": {"max_gap": 1e-3}},

@@ -6,12 +6,19 @@ with the lines ``XLA Modules`` (one event per run of a compiled program,
 named ``jit_<fn>(<fingerprint>)``), ``XLA Ops`` (one event per HLO
 instruction run, named by the instruction's whole text, control flow such
 as ``while`` included as an event that spans its body) and ``Async XLA
-Ops``; and the plane ``/host:CPU`` whose line ``python`` carries JAX's own
-host spans (``np.asarray(jax.Array)``, ``PjitFunction(fn)``, ...).  All on
-one clock, nanoseconds.
+Ops``; and the plane ``/host:CPU`` with one line per host thread, called
+after the thread's ``comm`` (``python3`` under the benchmark's command,
+``python`` under another).  The serve thread's line carries the program's
+own ``batcher.*`` spans, one per phase of a tick, around JAX's host spans
+(``np.asarray(jax.Array)``, ``PjitFunction(fn)``, ...).  All on one clock,
+nanoseconds.
 
-The program names nothing itself (no ``named_scope`` in ``serving.py`` or
-``ops/``), so the readers go by XLA's own instruction text and shapes.
+What the program names (since PR 24): its jitted entry points
+(``jit_decode_block``, ``jit_prefill``, ...: the module names), its kernels
+(``flash_decode_paged.N``, ``flash_attention_fwd.N``) and the phases of a
+tick.  Programs are told by those names (``module_runs``), the serve
+thread's line by its spans (``load``); single instructions by XLA's own
+text and shapes.
 """
 
 from __future__ import annotations
@@ -29,7 +36,14 @@ Event = Tuple[str, float, float]        # name, start_s, duration_s
 
 #: instructions that only wrap others: their time is their children's
 CONTROL_FLOW = {"while", "conditional", "call"}
+#: the program's host spans: a host line that carries one is the serve
+#: thread's, whatever the profiler calls it
+HOST_SPANS = "batcher."
+#: module name (before the fingerprint) -> what the program run is
+PROGRAMS = (("jit_decode_block", "decode"), ("jit_prefill", "prefill"))
 GAP_FLOOR_S = 20e-6
+#: a run that ends within this of the device's last event ended with the trace
+EDGE_S = 1e-6
 
 
 @dataclasses.dataclass
@@ -42,7 +56,7 @@ class Device:
 @dataclasses.dataclass
 class Trace:
     devices: List[Device]
-    host: List[Event]               # the host plane's "python" line
+    host: List[Event]               # the serve thread's host line
     t_min: float
     t_max: float
 
@@ -92,8 +106,8 @@ def load(path: str) -> Trace:
                 ops=events(lines["XLA Ops"]) if "XLA Ops" in lines else []))
         elif plane.name == "/host:CPU":
             for ln in plane.lines:
-                if ln.name == "python":
-                    host = events(ln)
+                if any(e.name.startswith(HOST_SPANS) for e in ln.events):
+                    host.extend(events(ln))
     if t_min > t_max:
         t_min = t_max = 0.0
     return Trace(devices=devices, host=host, t_min=t_min, t_max=t_max)
@@ -234,34 +248,44 @@ def breakdown(trace: Trace) -> Dict[str, object]:
 
 # -- programs ---------------------------------------------------------------
 
-def module_runs(trace: Trace, d_model: int, rows: int
-                ) -> List[Dict[str, object]]:
-    """Each run of a compiled program on the first chip, with what it is.
-    Both of the batcher's programs are called ``jit_fn``, so a run is told
-    by the layer scan inside it: a ``while`` that carries activations
-    ``[rows, 1, d_model]`` is a decode block, ``[1, W, d_model]`` with
-    W > 1 a prefill at padded width W."""
+_PROMPT = re.compile(r"[su]\d+\[1,(\d+)\]\S* %prompt(?:\.\d+)?[,)]")
+
+
+def module_runs(trace: Trace) -> List[Dict[str, object]]:
+    """Each whole run of a compiled program on the first chip, with what
+    it is, by the name the program gave its entry point: a module whose
+    name starts ``jit_decode_block`` is a decode block, ``jit_prefill`` a
+    prefill, any other ``other``.  A prefill's padded width W comes from
+    the first instruction under the run that takes the entry parameter
+    ``%prompt`` (``s32[1,W]``) as an operand; None where there is none.
+
+    The run that ends with the device's last event is left out, whole or
+    not: the profiler closes a run still going when the trace stops at
+    that moment (docqa_batch, PR 27: a ``jit_prefill`` 6,208 wide cut to
+    610 ms, no layer-scan ``while`` under it, because an instruction's
+    event is written when it ends).  A run going when the trace starts is
+    not in it at all (both traces looked at begin with a whole run)."""
     if not trace.devices:
         return []
     dev = trace.devices[0]
-    whiles = sorted((s, name) for name, s, _ in dev.ops
-                    if parse_op(name)["opcode"] == "while")
-    carried = re.compile(r"\[(\d+),(\d+),%d\]" % d_model)
-    starts = [w[0] for w in whiles]
+    ops = sorted((s, name) for name, s, _ in dev.ops)
+    starts = [o[0] for o in ops]
+    t_last = max(s + d for _, s, d in dev.modules + dev.ops) \
+        if dev.modules else 0.0
     out = []
     for name, s, d in dev.modules:
-        kind, width = "other", None
-        i = bisect.bisect_left(starts, s)
-        while i < len(whiles) and whiles[i][0] < s + d and kind == "other":
-            for b, t in carried.findall(whiles[i][1]):
-                b, t = int(b), int(t)
-                if t == 1 and b == rows:
-                    kind = "decode"
-                elif b == 1 and t > 1:
-                    kind, width = "prefill", t
-                if kind != "other":
-                    break
-            i += 1
+        if s + d >= t_last - EDGE_S:
+            continue
+        base = name.split("(", 1)[0]
+        kind = next((k for prefix, k in PROGRAMS if base.startswith(prefix)),
+                    "other")
+        width = None
+        if kind == "prefill":
+            i = bisect.bisect_left(starts, s)
+            while i < len(ops) and ops[i][0] < s + d and width is None:
+                m = _PROMPT.search(ops[i][1])
+                width = int(m.group(1)) if m else None
+                i += 1
         out.append({"name": name, "start": s, "dur": d, "kind": kind,
                     "width": width})
     return out

@@ -6,9 +6,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark import reference, weights
-from benchmark.drivers import serve
-from benchmark import tiny
+from benchmark import reference, tiny, weights
+from benchmark.models import mistral
 
 
 @pytest.fixture(scope="module")
@@ -24,7 +23,7 @@ def setup():
 
 def program_logits(config, w, tokens):
     from tfmesos_tpu.models import transformer
-    cfg = serve.model_config(config, 1024)
+    cfg = mistral.program_config(config, 1024)
     with jax.default_matmul_precision("highest"):
         return transformer.forward(cfg, w, jnp.asarray(tokens)[None])[0]
 

@@ -63,13 +63,20 @@ def test_open_loop_rehearsal_is_correct_and_counts_its_samples():
     assert set(res["metrics"]) == {"ttft_p90_ms", "setup_s"} \
         or set(res["metrics"]) == {"ttft_p90_ms", "tpot_p90_ms", "setup_s"}
     assert any(ln.startswith("samples: due_requests=") for ln in lines)
-    assert any(ln.startswith("correct: max_gap = ") and "limit" in ln
-               for ln in lines)
+    compared = res["check"]["compared"]
     line = json.loads(harness.result_line(
         correct=res["correct"], attempted=res["attempted"],
-        failed=res["failed"], metrics=res["metrics"], device=res["device"]))
-    assert set(line) == {"correct", "attempted", "failed", "metrics",
-                         "device"}
+        failed=res["failed"], metrics=res["metrics"], device=res["device"],
+        compared=compared))
+    # the numbers compared, each beside its limit, come last in the line
+    assert list(line) == ["correct", "attempted", "failed", "metrics",
+                          "device", "compared"]
+    assert line["compared"]["max_gap"] == {
+        "value": res["check"]["max_gap"], "limit": 1e-3}
+    assert set(compared) == {"length_mismatches", "token_ids_out_of_range",
+                             "max_gap"}
+    assert harness.compared_lines(compared)[0] == \
+        "correct: length_mismatches = 0 (limit 0)"
     assert line["device"]["platform"] == "cpu"      # and so never a result
 
 
