@@ -2669,23 +2669,36 @@ def test_bypass_registry_audit(setup):
                                      compute_bypass_reasons)
 
     reachable = {k: set() for k in BYPASS_ALLOWLIST}
-    for spec_on, shards, q, dq, pd, ov, ms in itertools.product(
-            (False, True), (1, 2, 4), (False, True), (False, True),
-            (0, 1), (False, True), (1, 2, 8)):
+    eva_reach = {k: set() for k in BYPASS_ALLOWLIST}
+    for eva, spec_on, shards, q, dq, pd, ov, ms in itertools.product(
+            (False, True), (False, True), (1, 2, 4), (False, True),
+            (False, True), (0, 1), (False, True), (1, 2, 8)):
         reasons = compute_bypass_reasons(
             speculative=spec_on, n_shards=shards, quantized_cache=q,
             draft_quantized_cache=dq, pipeline_depth=pd, overlap=ov,
-            multi_step=ms)
+            multi_step=ms, eva=eva)
         assert set(reasons) == set(BYPASS_ALLOWLIST)
         for reg, val in reasons.items():
             if val is not None:
-                reachable[reg].add(val)
-    for reg, vals in reachable.items():
-        extra = vals - set(BYPASS_ALLOWLIST[reg])
+                (eva_reach if eva else reachable)[reg].add(val)
+    for reg in BYPASS_ALLOWLIST:
+        extra = (reachable[reg] | eva_reach[reg]) - set(BYPASS_ALLOWLIST[reg])
         assert not extra, (
             f"bypass registry {reg!r} reaches undocumented reasons "
             f"{sorted(extra)} — add a burn-down plan or remove the "
             f"bypass (BYPASS_ALLOWLIST is the contract)")
+    # EVA attention (pages of summaries and one window, PR 28): every
+    # surface that shares, moves or snapshots pages by position is closed
+    # with ONE reason, the lagged loops with another; nothing of it is
+    # reachable without EVA, whose registries read as before below.
+    for reg in ("prefix_cache", "kv_tier", "suspend", "speculative",
+                "kv_export"):
+        assert "eva summary pages" in eva_reach[reg], reg
+        assert "eva summary pages" not in reachable[reg], reg
+    assert "eva window close" in eva_reach["overlap"] & eva_reach["pipeline"]
+    assert not reachable["speculative"] and not reachable["kv_export"]
+    plain_eva = compute_bypass_reasons(eva=True, multi_step=4)
+    assert plain_eva["multi_step"] is None and plain_eva["overlap"] is None
     # The burn-down, pinned: spec composes with the prefix cache and
     # the KV tier now.
     assert "speculative decoding" not in reachable["prefix_cache"]

@@ -250,3 +250,80 @@ def test_paged_commit_never_relayouts_the_pool(topo, monkeypatch, name):
     moved = re.findall(r"= " + re.escape(leaf)
                        + r"\S* (?:copy|transpose)\([^)]*\)", text)
     assert not moved, f"{leaf} is relayouted: {moved[:2]}"
+
+
+# -- EVA attention at EvaByte's widths (PR 28) ---------------------------------
+#
+# The programs ``ContinuousBatcher`` dispatches under ``attention="eva"``,
+# compiled whole for the described v5e at the benchmark's widths (hidden
+# 4096, 32 heads of 128 over 32 K/V heads, 16 layers, 372 pages of 64
+# entries, 16 rows, a table of 62 pages): the paged kernel at
+# ``q_per_kv = 1``, a window's prefill (the flash kernel over the chunk, the
+# summaries before it in XLA blocks, its K/V committed layer by layer as
+# whole pages) and the program that closes a window.  Each donates the pool
+# and must leave it where it is.  name -> (what, rows, t).
+EVA_CASES = {
+    "decode_t1": ("step", 16, 1),
+    "prefill_window_2048": ("step", 1, 2048),
+    "prefill_tail_256": ("step", 1, 256),
+    "close_window": ("close", 1, 0),
+}
+
+
+@pytest.mark.parametrize("name", sorted(EVA_CASES))
+def test_eva_programs_compile_and_keep_the_pool_in_place(topo, monkeypatch,
+                                                         name):
+    from tfmesos_tpu.models import transformer
+    from tfmesos_tpu.ops import attention
+
+    what, rows, t = EVA_CASES[name]
+    cfg = transformer.TransformerConfig(
+        vocab_size=320, d_model=4096, n_layers=16, n_heads=32, n_kv_heads=32,
+        d_ff=11008, max_seq_len=32768, rope_theta=1e5, dtype=BF16,
+        param_dtype=BF16, attention="eva", eva_chunk=16, eva_window=2048,
+        norm_eps=1e-5, norm_offset=True, residual_dtype=F32,
+        logits_dtype=F32, n_pred_heads=8)
+    n_pages, width = 372, 62
+    assert width == -(-cfg.cache_entries_peak(0, 32768) // PAGE)
+    monkeypatch.setattr(transformer, "_decode_kernel_kwargs",
+                        lambda *a, **k: {"use_pallas": True})
+    # the chunk's own part through the flash kernel, as on the chip
+    monkeypatch.setattr(attention.jax, "default_backend", lambda: "tpu")
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def struct(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(struct, jax.eval_shape(
+        lambda: transformer.init_params(cfg, jax.random.PRNGKey(0))))
+    pool = jax.tree_util.tree_map(struct, jax.eval_shape(
+        lambda: transformer.init_paged_cache(cfg, n_pages, PAGE)))
+    table = jax.ShapeDtypeStruct((rows, width), I32, sharding=one_chip)
+    scalar = jax.ShapeDtypeStruct((), I32, sharding=one_chip)
+    if what == "close":
+        fn = jax.jit(lambda p, c, tb, w: transformer.eva_close_window(
+            cfg, p, c, tb, w), donate_argnums=1)
+        args = (params, pool, table, scalar)
+    else:
+        pos = scalar if t > 1 else jax.ShapeDtypeStruct((rows,), I32,
+                                                        sharding=one_chip)
+        fn = jax.jit(lambda p, c, tb, tok, at: transformer.decode_step(
+            cfg, p, dict(c, pages=tb), tok, at), donate_argnums=1)
+        args = (params, pool, table,
+                jax.ShapeDtypeStruct((rows, t), I32, sharding=one_chip), pos)
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    assert " scatter(" in text
+    if what == "step":
+        assert "tpu_custom_call" in text
+        # decode: flash_decode_paged; prefill: flash_attention_fwd
+        assert ("flash_decode_paged" if t == 1 else
+                "flash_attention_fwd") in text
+    leaf = f"bf16[{cfg.n_layers},{n_pages},{cfg.kv_heads},{PAGE},128]"
+    moved = re.findall(r"= " + re.escape(leaf)
+                       + r"\S* (?:copy|transpose)\([^)]*\)", text)
+    assert not moved, f"{leaf} is relayouted: {moved[:2]}"
+    mem = compiled.memory_analysis()
+    # beside 6.5 GB of weights and 6.2 GB of pool: a window's prefill keeps
+    # its temporaries under half a gigabyte (its K/V is committed per layer)
+    assert mem.temp_size_in_bytes < 0.5e9, mem.temp_size_in_bytes

@@ -77,6 +77,19 @@ def _embed_lookup(p, tokens, dtype):
     return p.astype(dtype)[tokens]
 
 
+def _norm(cfg, x, gain):
+    """RMSNorm of the residual stream ``x`` (the compute dtype, or float32
+    under ``residual_dtype``; statistics in float32 either way) with the
+    block's gain, coming back in the compute dtype.  A configuration that
+    states neither an epsilon nor the unit offset passes ``rms_norm``
+    nothing more than it always did."""
+    g = gain.astype(x.dtype)
+    if cfg.norm_offset:
+        g = 1 + g
+    kw = {} if cfg.norm_eps is None else {"eps": cfg.norm_eps}
+    return rms_norm(x, g, **kw).astype(cfg.dtype)
+
+
 @dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32000
@@ -139,6 +152,34 @@ class TransformerConfig:
     # paths (unfused, fused-dense, dp-sharded, tp vocab-parallel)
     # implement it identically.
     z_loss: float = 0.0
+    # Attention kind.  "full": causal softmax over every earlier position
+    # (with ``window``: the last ``window`` of them).  "eva": EVA chunked
+    # attention (Zheng et al., "Efficient Attention via Control Variates";
+    # EvaByte): a query attends exactly to the positions of its own window
+    # of ``eva_window`` and to ONE summary per chunk of ``eva_chunk``
+    # positions of every earlier window, under one softmax normaliser
+    # (``eva_summarize`` has the pooling).  Serving path only: the paged
+    # cache then holds ENTRIES, not positions (``cache_entries``): summaries
+    # that only grow, and the current window's exact K/V.
+    attention: str = "full"
+    eva_chunk: int = 16
+    eva_window: int = 2048
+    # RMSNorm as the configuration states it: ``norm_eps`` None keeps
+    # ``rms_norm``'s own epsilon (nothing is passed, so programs that do not
+    # state one are unchanged); ``norm_offset`` scales by (1 + g) and
+    # initialises g at 0.
+    norm_eps: Optional[float] = None
+    norm_offset: bool = False
+    # dtype of the residual stream and its adds (None: the compute dtype),
+    # and of the logits (None: the compute dtype).  Honoured by the decode
+    # path (``decode_step``); ``forward`` refuses configurations that set
+    # them.
+    residual_dtype: Any = None
+    logits_dtype: Any = None
+    # Multi-token prediction heads on the output: the head matrix is
+    # [d_model, n_pred_heads * vocab_size], head 0 (the first vocab_size
+    # columns) is the next token.  Decoding computes head 0 only.
+    n_pred_heads: int = 1
 
     @property
     def head_dim(self) -> int:
@@ -148,6 +189,48 @@ class TransformerConfig:
         if self.window is not None and self.window < 1:
             raise ValueError(f"window must be >= 1, got {self.window} "
                              f"(use None for full causal attention)")
+        if self.attention not in ("full", "eva"):
+            raise ValueError(f"attention must be 'full' or 'eva', got "
+                             f"{self.attention!r}")
+        if self.attention == "eva":
+            if self.window is not None:
+                raise ValueError("attention='eva' has its own window "
+                                 "(eva_window); leave window None")
+            if (self.eva_chunk < 1 or self.eva_window < self.eva_chunk
+                    or self.eva_window % self.eva_chunk):
+                raise ValueError(
+                    f"eva_window ({self.eva_window}) must be a positive "
+                    f"multiple of eva_chunk ({self.eva_chunk})")
+        if self.n_pred_heads < 1:
+            raise ValueError(f"n_pred_heads must be >= 1, got "
+                             f"{self.n_pred_heads}")
+
+    @property
+    def eva_summaries(self) -> int:
+        """Summary entries one closed window leaves in the cache."""
+        return self.eva_window // self.eva_chunk
+
+    def cache_entries(self, length):
+        """Cache entries a row at context ``length`` holds (int or array):
+        under EVA, ``eva_summaries`` per closed window plus the current
+        window's positions; one per position otherwise.  Also the entry
+        index at which position ``length`` is written."""
+        if self.attention != "eva":
+            return length
+        return (length // self.eva_window * self.eva_summaries
+                + length % self.eva_window)
+
+    def cache_entries_peak(self, lo: int, hi: int) -> int:
+        """The most entries a row holds while its context grows from ``lo``
+        to ``hi``: a window is held exactly until it is closed, so a row
+        that crosses a window's end peaks just before the close."""
+        if self.attention != "eva":
+            return hi
+        w = hi // self.eva_window
+        peak = self.cache_entries(hi)
+        if w > lo // self.eva_window:
+            peak = max(peak, (w - 1) * self.eva_summaries + self.eva_window)
+        return peak
 
     @property
     def kv_heads(self) -> int:
@@ -172,14 +255,22 @@ def init_params(cfg: TransformerConfig, rng) -> Dict[str, Any]:
         return (jax.random.normal(next(keys), shape, cfg.param_dtype)
                 * scale).astype(cfg.param_dtype)
 
+    # a gain g with the unit offset scales by (1 + g): the identity is 0
+    gain = jnp.zeros if cfg.norm_offset else jnp.ones
     layers = {
-        "attn_norm": jnp.ones((l, d), cfg.param_dtype),
+        "attn_norm": gain((l, d), cfg.param_dtype),
         "wq": norm((l, d, hd), 1 / math.sqrt(d)),
         "wk": norm((l, d, kvd), 1 / math.sqrt(d)),
         "wv": norm((l, d, kvd), 1 / math.sqrt(d)),
         "wo": norm((l, hd, d), 1 / math.sqrt(hd) / math.sqrt(2 * l)),
-        "mlp_norm": jnp.ones((l, d), cfg.param_dtype),
+        "mlp_norm": gain((l, d), cfg.param_dtype),
     }
+    if cfg.attention == "eva":
+        # the chunk pooling's query and the summaries' key offset, per
+        # layer and head (unit scale: pooling weights far from uniform)
+        layers.update(
+            eva_phi=norm((l, cfg.kv_heads, cfg.head_dim), 1.0),
+            eva_mu=norm((l, cfg.kv_heads, cfg.head_dim), 1.0))
     if cfg.n_experts:
         e = cfg.n_experts
         layers.update(
@@ -205,8 +296,9 @@ def init_params(cfg: TransformerConfig, rng) -> Dict[str, Any]:
     return {
         "embed": norm((cfg.vocab_size, d), 1.0),
         "layers": layers,
-        "norm_f": jnp.ones((d,), cfg.param_dtype),
-        "head": norm((d, cfg.vocab_size), 1 / math.sqrt(d)),
+        "norm_f": gain((d,), cfg.param_dtype),
+        "head": norm((d, cfg.n_pred_heads * cfg.vocab_size),
+                     1 / math.sqrt(d)),
     }
 
 
@@ -497,7 +589,7 @@ def _block_manual_tp(cfg: TransformerConfig, x, lp, positions,
     else:
         fan = lambda v_: v_
         red = lambda v_: jax.lax.psum(v_, tp_axis)
-    h = fan(rms_norm(x, lp["attn_norm"].astype(cfg.dtype)))
+    h = fan(_norm(cfg, x, lp["attn_norm"]))
     q = _qmm(h, lp["wq"], cfg.dtype).reshape(b, t, heads_loc, cfg.head_dim)
     k = _qmm(h, lp["wk"], cfg.dtype).reshape(b, t, kv_loc, cfg.head_dim)
     v = _qmm(h, lp["wv"], cfg.dtype).reshape(b, t, kv_loc, cfg.head_dim)
@@ -511,7 +603,7 @@ def _block_manual_tp(cfg: TransformerConfig, x, lp, positions,
         o = attend(q, k, v, mesh=None, causal=True,
                    window=cfg.window)  # local heads
     x = x + red(_qmm(o.reshape(b, t, -1), lp["wo"], cfg.dtype))
-    h = rms_norm(x, lp["mlp_norm"].astype(cfg.dtype))
+    h = _norm(cfg, x, lp["mlp_norm"])
     if cfg.n_experts:
         # The MoE half fans/reduces internally (over ep AND tp — the f/g
         # pair when inbody_ad, plain psum otherwise).
@@ -591,7 +683,7 @@ def _block(cfg: TransformerConfig, mesh: Optional[Mesh], x, lp, positions,
     tick's branches — see ``_sp_gather_attention``)."""
     b, t, d = x.shape
     with jax.named_scope("attention"):
-        h = rms_norm(x, lp["attn_norm"].astype(cfg.dtype))
+        h = _norm(cfg, x, lp["attn_norm"])
         q = _qmm(h, lp["wq"], cfg.dtype).reshape(b, t, cfg.n_heads,
                                                  cfg.head_dim)
         k = _qmm(h, lp["wk"], cfg.dtype).reshape(b, t, cfg.kv_heads,
@@ -612,7 +704,7 @@ def _block(cfg: TransformerConfig, mesh: Optional[Mesh], x, lp, positions,
                        window=cfg.window)
         x = x + _qmm(o.reshape(b, t, -1), lp["wo"], cfg.dtype)
     with jax.named_scope("mlp"):
-        h = rms_norm(x, lp["mlp_norm"].astype(cfg.dtype))
+        h = _norm(cfg, x, lp["mlp_norm"])
         ffn, aux = _ffn(cfg, mesh, lp, h, ep_axis=ep_axis,
                         inbody_ad=inbody_ad)
         return x + ffn, aux
@@ -638,6 +730,14 @@ def forward_hidden(cfg: TransformerConfig, params, tokens,
     ring attention receives the full logical sequence sharded along T, and
     rope positions follow the global index.
     """
+    if (cfg.attention != "full" or cfg.n_pred_heads != 1
+            or cfg.residual_dtype is not None
+            or cfg.logits_dtype is not None):
+        raise NotImplementedError(
+            "forward() runs full attention, one output head and a residual "
+            "stream in the compute dtype; attention='eva', n_pred_heads, "
+            "residual_dtype and logits_dtype are the serving path's "
+            "(decode_step through a paged cache)")
     b, t = tokens.shape
     x = _embed_lookup(params["embed"], tokens, cfg.dtype)
     positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
@@ -748,7 +848,7 @@ def forward_hidden(cfg: TransformerConfig, params, tokens,
         x, stacked_aux = jax.lax.scan(body, x, params["layers"])
         aux = jax.tree_util.tree_map(jnp.mean, stacked_aux)
 
-    return rms_norm(x, params["norm_f"].astype(cfg.dtype)), aux
+    return _norm(cfg, x, params["norm_f"]), aux
 
 
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
@@ -776,6 +876,10 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
     per-step cache bandwidth are O(window) regardless of how long
     generation runs.
     """
+    if cfg.attention == "eva":
+        raise ValueError("attention='eva' keeps summaries and a window in "
+                         "a paged cache (init_paged_cache); it has no "
+                         "linear one")
     if cfg.window is not None:
         max_len = min(max_len, cfg.window)
     shape = (cfg.n_layers, batch, cfg.kv_heads, max_len, cfg.head_dim)
@@ -815,6 +919,17 @@ def init_paged_cache(cfg: TransformerConfig, n_pages: int,
     if cfg.window is not None:
         raise ValueError("paged caches do not compose with sliding-window "
                          "configs (rolling caches address by slot)")
+    if cfg.attention == "eva":
+        # A row's table is [summary pages | window pages]: a closed window
+        # leaves whole pages of summaries, so every window starts a page.
+        if cfg.eva_summaries % page_size:
+            raise ValueError(
+                f"attention='eva' pages need eva_window / eva_chunk "
+                f"({cfg.eva_summaries} summaries a window) to be a "
+                f"multiple of page_size ({page_size})")
+        if quantized:
+            raise ValueError("attention='eva' summaries are pooled from "
+                             "the cached entries: an int8 pool is refused")
     if page_size % 8 or page_size > 1024:
         raise ValueError(f"page_size ({page_size}) must be a multiple of "
                          f"8 and <= 1024 (the kernel's tile shape)")
@@ -884,6 +999,17 @@ class PageAllocator:
 
     def release(self, row: int) -> None:
         self.free.extend(reversed(self.rows.pop(row, [])))
+
+    def trim(self, row: int, length: int) -> int:
+        """Free ``row``'s pages behind the first ``length`` entries (an
+        EVA window closed: its summaries stay, its pages go); returns how
+        many went."""
+        keep = -(-int(length) // self.page_size)
+        pages = self.rows.get(row, [])
+        gone = pages[keep:]
+        del pages[keep:]
+        self.free.extend(reversed(gone))
+        return len(gone)
 
     def reserve_page(self) -> int:
         """Permanently take one page out of circulation and return its id
@@ -955,7 +1081,8 @@ def _paged_cache_write(pool, chunk, li, page_table, pos):
     return put(pool, chunk)
 
 
-def _paged_cache_write_all(pool, chunks, page_table, pos):
+def _paged_cache_write_all(pool, chunks, page_table, pos,
+                           aligned: bool = False, layer0=0):
     """Commit ALL layers' deferred chunks ([L, B, t, KV, Dh], stacked by
     the decode layer scan) into the page pool ([L, P, KV, page, Dh]) with
     one scatter per pool leaf, expressed IN THE POOL'S OWN LAYOUT.
@@ -983,13 +1110,18 @@ def _paged_cache_write_all(pool, chunks, page_table, pos):
     Same index math (sink clamp included) and the same per-row absmax
     int8 rule as the per-layer ``_paged_cache_write``; the lane-major
     scales leaf ([L, P, KV, 1, page]) follows the same rule with a scalar
-    or a [1, page] window."""
+    or a [1, page] window.  ``aligned`` vouches that a TRACED scalar
+    ``pos`` is page-aligned (an EVA window's first entry), which opens the
+    pages form to it; ``layer0`` (traced OK) is the pool layer of
+    ``chunks[0]``, for a caller inside the layer scan that commits its own
+    layer's chunk ([1, B, t, KV, Dh]) instead of stacking it."""
     L, b, t, kvh, dh = chunks.shape
     ps = (pool.values if isinstance(pool, QTensor) else pool).shape[3]
     last = page_table.shape[1] - 1
-    li = jnp.arange(L, dtype=jnp.int32)[None, :, None]
+    li = (layer0 + jnp.arange(L, dtype=jnp.int32))[None, :, None]
     ki = jnp.arange(kvh, dtype=jnp.int32)[None, None, :]
-    whole_pages = isinstance(pos, int) and pos % ps == 0 and t % ps == 0
+    whole_pages = t % ps == 0 and (
+        aligned or (isinstance(pos, int) and pos % ps == 0))
     if whole_pages:
         nb = t // ps
         blk = jnp.minimum(pos // ps + jnp.arange(nb, dtype=jnp.int32), last)
@@ -1343,21 +1475,22 @@ def _prefill_kernel_kwargs(cfg: TransformerConfig, mesh: Optional[Mesh],
 
 def _block_decode(cfg: TransformerConfig, x, lp, ck, cv, li, positions,
                   pos, sharded: bool = False, mesh: Optional[Mesh] = None,
-                  pages=None):
+                  pages=None, kpos=None):
     """One block over a token chunk with cached history: the attention
     half (:func:`_attend_decode`, which also owns the cache) and the MLP,
     each under its ``named_scope`` so a profile names the parts."""
     with jax.named_scope("attention"):
         x, ck, cv, chunk = _attend_decode(cfg, x, lp, ck, cv, li, positions,
-                                          pos, sharded, mesh, pages)
+                                          pos, sharded, mesh, pages, kpos)
     with jax.named_scope("mlp"):
-        h = rms_norm(x, lp["mlp_norm"].astype(cfg.dtype))
+        h = _norm(cfg, x, lp["mlp_norm"])
         ffn, _ = _ffn(cfg, None, lp, h)
-        return x + ffn, ck, cv, chunk
+        return x + ffn.astype(x.dtype), ck, cv, chunk
 
 
 def _attend_decode(cfg: TransformerConfig, x, lp, ck, cv, li, positions,
-                   pos, sharded: bool, mesh: Optional[Mesh], pages):
+                   pos, sharded: bool, mesh: Optional[Mesh], pages,
+                   kpos=None):
     """The attention half of a block over a token chunk with cached
     history; returns ``(x, ck, cv, deferred chunk or None)``.
 
@@ -1380,10 +1513,16 @@ def _attend_decode(cfg: TransformerConfig, x, lp, ck, cv, li, positions,
     ``_decode_kernel_kwargs`` opens the gate — directly, or per shard via
     ``sharded_flash_decode`` when a mesh is given — and otherwise fall to
     the dense einsum over the cache with an offset causal mask.
+
+    ``kpos`` ([B], EVA only): the cache ENTRY at which each row's chunk
+    starts (``cfg.cache_entries`` of its position): what the paged kernel
+    bounds its reads by, while ``positions`` keep feeding RoPE.  A
+    multi-token EVA chunk lies inside one window and attends the summaries
+    in front of it together with itself (``eva_prefill_attention``).
     """
     b, t, _ = x.shape
     m = _cache_logical_len(ck, pages)
-    h = rms_norm(x, lp["attn_norm"].astype(cfg.dtype))
+    h = _norm(cfg, x, lp["attn_norm"])
     q = _qmm(h, lp["wq"], cfg.dtype).reshape(b, t, cfg.n_heads,
                                              cfg.head_dim)
     k = _qmm(h, lp["wk"], cfg.dtype).reshape(b, t, cfg.kv_heads,
@@ -1427,7 +1566,20 @@ def _attend_decode(cfg: TransformerConfig, x, lp, ck, cv, li, positions,
             cv = _cache_write(cv, v, li, pos, rolling=rolling)
     kv = cfg.kv_heads
     g = cfg.n_heads // kv
-    if t > 1 and isinstance(pos, int) and pos == 0:
+    if cfg.attention == "eva" and t > 1:
+        from tfmesos_tpu.ops.attention import eva_prefill_attention
+        with jax.named_scope("eva_prefill_attention"):
+            o = eva_prefill_attention(q, k, v, ck, cv, li, pages, kpos)
+        # A window's chunk is committed here, layer by layer, in the pool's
+        # own layout (whole pages at the window's first entry): stacked
+        # over the layers for one commit after the scan, 2048 positions of
+        # 32 K/V heads would stand beside the pool as half a gigabyte.
+        with jax.named_scope("paged_cache_write"):
+            ck, cv = (_paged_cache_write_all(c, x[None], pages, kpos[0],
+                                             aligned=True, layer0=li)
+                      for c, x in ((ck, k), (cv, v)))
+        defer = False
+    elif t > 1 and isinstance(pos, int) and pos == 0:
         # Prefill from an empty cache: the chunk only attends to itself —
         # [t, t] instead of a [t, M] score tensor over the (mostly empty)
         # cache.  GQA stays at kv width (both impls group internally).
@@ -1471,13 +1623,14 @@ def _attend_decode(cfg: TransformerConfig, x, lp, ck, cv, li, positions,
             else:
                 self_kv = (k, v)
         kw = _decode_kernel_kwargs(cfg, m, t, False)
+        at = positions[:, 0] if kpos is None else kpos
         with jax.named_scope("paged_attention"):
             if kw is not None:
-                o = flash_decode_paged(q, ck, cv, pages, positions[:, 0],
-                                       layer=li, self_kv=self_kv, **kw)
+                o = flash_decode_paged(q, ck, cv, pages, at, layer=li,
+                                       self_kv=self_kv, **kw)
             else:
                 o = _paged_decode_reference(
-                    q, ck, cv, pages, positions[:, 0],
+                    q, ck, cv, pages, at,
                     1.0 / math.sqrt(cfg.head_dim), layer=li,
                     self_kv=self_kv)
     elif (kernel_kw := _decode_kernel_kwargs(cfg, m, t, sharded, mesh,
@@ -1526,7 +1679,7 @@ def _attend_decode(cfg: TransformerConfig, x, lp, ck, cv, li, positions,
         s = jnp.where(bad[:, None, None], -jnp.inf, s)
         probs = jax.nn.softmax(s, axis=-1).astype(cv_r.dtype)
         o = jnp.einsum("bkgtm,bkmd->btkgd", probs, cv_r)
-    x = x + _qmm(o.reshape(b, t, -1), lp["wo"], cfg.dtype)
+    x = x + _qmm(o.reshape(b, t, -1), lp["wo"], cfg.dtype).astype(x.dtype)
     return x, ck, cv, ((k, v) if defer else None)
 
 
@@ -1568,6 +1721,8 @@ def decode_step(cfg: TransformerConfig, params, cache, tokens, pos,
     b, t = tokens.shape
     with jax.named_scope("embed"):
         x = _embed_lookup(params["embed"], tokens, cfg.dtype)
+        if cfg.residual_dtype is not None:
+            x = x.astype(cfg.residual_dtype)
     ragged = getattr(pos, "ndim", 0) == 1
     if ragged and cfg.window is not None:
         raise ValueError("ragged positions do not compose with "
@@ -1578,6 +1733,19 @@ def decode_step(cfg: TransformerConfig, params, cache, tokens, pos,
         (pos_arr[:, None] if ragged else pos_arr) + offs, (b, t))
 
     pages = cache.get("pages")
+    kpos = None
+    if cfg.attention == "eva":
+        # The cache holds entries, not positions: a row's chunk is read up
+        # to, and committed at, the entry of its first position.  A
+        # multi-token chunk lies inside one window (its entries are as
+        # consecutive as its positions).
+        if pages is None or sharded:
+            raise ValueError("attention='eva' decodes through a single-"
+                             "host paged cache (init_paged_cache)")
+        if t > 1 and ragged:
+            raise ValueError("an EVA chunk of several tokens starts at one "
+                             "position for every row")
+        kpos = jnp.broadcast_to(cfg.cache_entries(pos_arr), (b,))
     if pages is not None and sharded:
         # Multi-chip paged serving: pool placed per paged_cache_specs
         # (pages over the data axes with shard-local table ids, kv heads
@@ -1598,7 +1766,7 @@ def decode_step(cfg: TransformerConfig, params, cache, tokens, pos,
         li, lp = layer
         x, ck, cv, chunks = _block_decode(cfg, x, lp, ck, cv, li,
                                           positions, pos, sharded=sharded,
-                                          mesh=mesh, pages=pages)
+                                          mesh=mesh, pages=pages, kpos=kpos)
         return (x, ck, cv), chunks
 
     # Long-buffer decode gains ~40% from a 2-wide unroll (cross-layer DMA
@@ -1613,15 +1781,91 @@ def decode_step(cfg: TransformerConfig, params, cache, tokens, pos,
         # Deferred single-token paged writes (see _block_decode): commit
         # every layer's chunk in one scatter per pool leaf.
         with jax.named_scope("paged_cache_write"):
-            new_k = _paged_cache_write_all(new_k, chunks[0], pages, pos)
-            new_v = _paged_cache_write_all(new_v, chunks[1], pages, pos)
+            at = pos
+            if kpos is not None:    # EVA: the entry of each row's position
+                at = kpos if ragged else kpos[0]
+            new_k = _paged_cache_write_all(new_k, chunks[0], pages, at)
+            new_v = _paged_cache_write_all(new_v, chunks[1], pages, at)
     with jax.named_scope("lm_head"):
-        x = rms_norm(x, params["norm_f"].astype(cfg.dtype))
-        logits = _qmm(x, params["head"], cfg.dtype)
+        x = _norm(cfg, x, params["norm_f"])
+        logits = _head_logits(cfg, x, params["head"])
     out_cache = {"k": new_k, "v": new_v}
     if pages is not None:
         out_cache["pages"] = pages
     return logits, out_cache
+
+
+def _head_logits(cfg: TransformerConfig, x, head):
+    """Next-token logits: head 0 of ``n_pred_heads`` (the first
+    ``vocab_size`` columns of the head matrix), in ``logits_dtype`` where
+    the configuration states one (bf16 operands, accumulated and kept in
+    float32)."""
+    if cfg.n_pred_heads > 1:
+        v = cfg.vocab_size
+        head = (QTensor(head.values[:, :v], head.scales)
+                if isinstance(head, QTensor) else head[:, :v])
+    if cfg.logits_dtype is None:
+        return _qmm(x, head, cfg.dtype)
+    if isinstance(head, QTensor):
+        s = head.scales.reshape(head.scales.shape[:-2] + (-1,))
+        x, head = x * s.astype(cfg.dtype), head.values
+    return jnp.matmul(x, head.astype(cfg.dtype),
+                      preferred_element_type=cfg.logits_dtype)
+
+
+def eva_summarize(cfg: TransformerConfig, k, v, phi, mu):
+    """One summary per chunk of ``eva_chunk`` entries: with ``a`` the
+    softmax over a chunk's entries of ``head_dim**-0.5 * phi . k``, the
+    summary's key is ``sum a k + mu`` and its value ``sum a v``.  ``k``,
+    ``v``: [L, B, n, KV, Dh] (rotated keys); ``phi``, ``mu``: [L, KV, Dh].
+    Returns ([L, B, n / eva_chunk, KV, Dh],) x 2 in ``k``'s dtype, the
+    arithmetic in float32."""
+    L, b, n, kvh, dh = k.shape
+    c = cfg.eva_chunk
+    kf = k.astype(jnp.float32).reshape(L, b, n // c, c, kvh, dh)
+    vf = v.astype(jnp.float32).reshape(L, b, n // c, c, kvh, dh)
+    phi = phi.astype(jnp.float32)[:, None, None, None]
+    a = jax.nn.softmax(
+        jnp.sum(kf * phi, axis=-1) / math.sqrt(dh), axis=3)[..., None]
+    ks = jnp.sum(a * kf, axis=3) + mu.astype(jnp.float32)[:, None, None]
+    vs = jnp.sum(a * vf, axis=3)
+    return ks.astype(k.dtype), vs.astype(v.dtype)
+
+
+def eva_close_window(cfg: TransformerConfig, params, pool, pages, window):
+    """Close window ``window`` (scalar int32) of the rows whose table rows
+    are ``pages`` ([B, NP]): read its ``eva_window`` exact entries from the
+    pages, pool them (:func:`eva_summarize`) and write the
+    ``eva_summaries`` results over the window's first entries, where the
+    next window's exact entries will follow them.  The pages behind are
+    the caller's to free.  A window's first entry is page-aligned
+    (``init_paged_cache`` checks), so both sides move whole pages; one
+    layer is read at a time."""
+    ps = pool["k"].shape[3]
+    s_ent, w_ent = cfg.eva_summaries, cfg.eva_window
+    cols = window * (s_ent // ps) + jnp.arange(w_ent // ps, dtype=jnp.int32)
+    pg = pages[:, cols]                                     # [B, W/ps]
+    lay = params["layers"]
+
+    def one_layer(li):
+        def entries(leaf):
+            # [B, W/ps, KV, ps, Dh] -> [1, B, W, KV, Dh]
+            x = leaf[li, pg]
+            b, n, kvh, _, dh = x.shape
+            return x.transpose(0, 1, 3, 2, 4).reshape(1, b, n * ps, kvh, dh)
+
+        ks, vs = eva_summarize(cfg, entries(pool["k"]), entries(pool["v"]),
+                               lay["eva_phi"][li][None],
+                               lay["eva_mu"][li][None])
+        return ks[0], vs[0]
+
+    ks, vs = jax.lax.map(one_layer,
+                         jnp.arange(cfg.n_layers, dtype=jnp.int32))
+    at = window * s_ent
+    return {"k": _paged_cache_write_all(pool["k"], ks, pages, at,
+                                        aligned=True),
+            "v": _paged_cache_write_all(pool["v"], vs, pages, at,
+                                        aligned=True)}
 
 
 def _check_sampling_args(top_k: Optional[int], top_p: Optional[float]):
@@ -2409,7 +2653,7 @@ def train_step_1f1b(cfg: TransformerConfig, params, batch,
                 broadcast_replicated_grad, psum_replicated_grad)
             tail = jax.tree_util.tree_map(
                 lambda w: broadcast_replicated_grad(w, sp_axis), tail)
-        x = rms_norm(h, tail["norm_f"].astype(cfg.dtype))
+        x = _norm(cfg, h, tail["norm_f"])
         if vocab_parallel_tail:
             loss = vocab_parallel_ce_inbody(x, tail["head"], tgt_mb,
                                             "tp", cfg.z_loss,
@@ -2487,6 +2731,8 @@ def partition_specs(cfg: TransformerConfig, mesh: Mesh) -> Dict[str, Any]:
         "wo": P(None, "tp", "fsdp"),
         "mlp_norm": P(None, None),
     }
+    if cfg.attention == "eva":
+        layer.update(eva_phi=P(None, "tp", None), eva_mu=P(None, "tp", None))
     if cfg.n_experts:
         layer.update(
             router=P(None, "fsdp", None),

@@ -539,6 +539,90 @@ def flash_attention(q, k, v, causal: bool = False, scale: Optional[float] = None
     return _flash(cfg, q, k, v)
 
 
+def _causal_with_lse(q, k, v, scale: float, interpret: bool = False):
+    """Causal attention of a chunk over itself, with every query's
+    log-sum-exp of the scaled scores ([B, H, t, 1], float32): the flash
+    kernel's forward on TPU, the dense form elsewhere."""
+    t = q.shape[1]
+    blk = _pick_block(t, 512)
+    if blk <= 1024 and (interpret or jax.default_backend() == "tpu"):
+        return _flash_forward(
+            _FlashCfg(causal=True, scale=float(scale), block_q=blk,
+                      block_k=blk, interpret=bool(interpret),
+                      q_per_kv=q.shape[2] // k.shape[2]), q, k, v)
+    b, _, h, d = q.shape
+    kv = k.shape[2]
+    q5 = q.reshape(b, t, kv, h // kv, d)
+    s = jnp.einsum("bqkgd,bmkd->bkgqm", q5, k).astype(jnp.float32) * scale
+    qpos = jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)
+    kpos = jax.lax.broadcasted_iota(jnp.int32, (t, t), 1)
+    s = jnp.where(kpos > qpos, NEG_INF, s)
+    lse = jax.nn.logsumexp(s, axis=-1, keepdims=True)
+    o = jnp.einsum("bkgqm,bmkd->bqkgd", jnp.exp(s - lse).astype(v.dtype), v)
+    return o.reshape(b, t, h, d), lse.reshape(b, h, t, 1)
+
+
+#: pages of cached summaries one XLA block of ``eva_prefill_attention`` reads
+EVA_BLOCK_PAGES = 8
+
+
+def eva_prefill_attention(q, k, v, k_pool, v_pool, layer, page_table,
+                          n_cached, scale: Optional[float] = None,
+                          interpret: bool = False):
+    """EVA attention of a chunk that starts a window: query ``i`` attends
+    the chunk's own tokens ``<= i`` exactly, together with the first
+    ``n_cached`` ([B] int32) entries of its row's paged cache (the
+    summaries of every earlier window), under ONE softmax normaliser.
+
+    The chunk's own part is the flash kernel (``_causal_with_lse``); the
+    cached part continues its online softmax in plain XLA over blocks of
+    ``EVA_BLOCK_PAGES`` pages, as many as the longest row needs: the running
+    maximum starts at the chunk's log-sum-exp with a weight of one.
+    ``q``/``k``/``v``: [B, t, H|KV, D]; pools: the stacked
+    [L, P, KV, page, D] with ``layer``; ``page_table``: [B, NP]."""
+    b, t, h, d = q.shape
+    kv, ps = k_pool.shape[2], k_pool.shape[3]
+    g = h // kv
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
+    o, lse = _causal_with_lse(q, k, v, scale, interpret)
+    np_ = page_table.shape[1]
+    bp = min(EVA_BLOCK_PAGES, np_)
+    q5 = q.reshape(b, t, kv, g, d)
+    n_cached = jnp.broadcast_to(jnp.asarray(n_cached, jnp.int32), (b,))
+
+    def body(i, carry):
+        o, m, l = carry
+        col = i * bp + jnp.arange(bp, dtype=jnp.int32)
+        pg = page_table[:, jnp.minimum(col, np_ - 1)]               # [B, bp]
+        # [B, bp, KV, page, D] -> [B, KV, bp * page, D]
+        blk = lambda pool: pool[layer, pg].transpose(0, 2, 1, 3, 4).reshape(
+            b, kv, bp * ps, d)
+        s = jnp.einsum("btkgd,bkmd->btkgm", q5, blk(k_pool).astype(q.dtype),
+                       preferred_element_type=jnp.float32) * scale
+        # entries by their UNclamped column: a clamped one lies past
+        # every row's n_cached
+        ent = (col[:, None] * ps
+               + jnp.arange(ps, dtype=jnp.int32)[None]).reshape(-1)
+        live = ent[None] < n_cached[:, None]                        # [B, m]
+        s = jnp.where(live[:, None, None, None], s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m - m_new)
+        l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
+        o = o * corr + jnp.einsum(
+            "btkgm,bkmd->btkgd", p.astype(q.dtype),
+            blk(v_pool).astype(q.dtype), preferred_element_type=jnp.float32)
+        return o, m_new, l
+
+    m0 = lse.reshape(b, kv, g, t, 1).transpose(0, 3, 1, 2, 4)
+    carry = (o.reshape(b, t, kv, g, d).astype(jnp.float32), m0,
+             jnp.ones_like(m0))
+    n_blk = -(-jnp.max(n_cached) // (bp * ps))
+    o, _, l = jax.lax.fori_loop(0, n_blk, body, carry)
+    return (o / l).reshape(b, t, h, d).astype(q.dtype)
+
+
 def _decode_reference(q, k_cache, v_cache, pos, scale):
     """Dense masked attention of a query chunk over a KV cache (ground
     truth / non-TPU path for ``flash_decode``).  Grouped einsum: the cache

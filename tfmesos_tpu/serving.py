@@ -90,10 +90,18 @@ BYPASS_ALLOWLIST = {
     # bit-stable against the cold fused prefill, so shared pages could
     # break the warm==cold equivalence bar; the draft pool's int8 mode
     # shares the same writer, hence the same reason.
-    "prefix_cache": ("quantized kv cache",),
+    # (and under EVA attention — TransformerConfig.attention == "eva" — a
+    # row's pages hold summaries and one window's exact entries, not 64
+    # consecutive positions each: nothing that shares, moves or snapshots
+    # pages by position carries that layout yet.  One reason string for
+    # every surface it closes, "eva summary pages"; the lagged loops are
+    # closed by "eva window close", because the host closes a window
+    # between blocks from its own up-to-date view of every row.)
+    "prefix_cache": ("quantized kv cache", "eva summary pages"),
     # Mesh data shards pin pages locally (no single-shard scatter to
     # move), and the int8 tail recompute above breaks resume==cold.
-    "kv_tier": ("mesh data sharding", "quantized kv cache"),
+    "kv_tier": ("mesh data sharding", "quantized kv cache",
+                "eva summary pages"),
     # Speculative overlap already carries its round state on device —
     # measured equal-or-better than the pipelined carry would be on
     # the same workload (bench_serving_spec_compose's overlap arm vs
@@ -101,14 +109,14 @@ BYPASS_ALLOWLIST = {
     # a spec round retires up to n_draft+1 tokens per sync where the
     # pipelined carry retires multi_step) — so pipeline_depth on a
     # speculative batcher records this instead of double-carrying.
-    "pipeline": ("speculative decoding",),
+    "pipeline": ("speculative decoding", "eva window close"),
     # pipeline_depth=1's device-resident carry already removes the
     # host round-trip overlap double-buffers away (measured: the
     # pipelined inter-token p50 is asserted strictly below the
     # synchronous loop's in bench_serving_pipeline, the same sync
     # overlap hides), so overlap under an active pipeline is redundant
     # — recorded, not rejected.
-    "overlap": ("pipelined decode carry",),
+    "overlap": ("pipelined decode carry", "eva window close"),
     # Speculative overlap rounds already fuse n_draft+1 tokens per
     # dispatch AND hide the host sync behind the next round
     # (bench_serving_spec_compose measures the round itself at one
@@ -122,7 +130,8 @@ BYPASS_ALLOWLIST = {
     # view lags one block behind (the lag IS the measured win:
     # bench_serving_pipeline's p50 gap), and mesh data shards pin
     # pages locally like the kv_tier/export surface.
-    "suspend": ("mesh data sharding", "lagged decode carry"),
+    "suspend": ("mesh data sharding", "lagged decode carry",
+                "eva summary pages"),
     # Stall-free fused prefill+decode ticks (one dispatch covers the
     # decode block AND a budgeted batch of prefill chunk slots).  Mesh
     # data shards dispatch chunks one-hot per shard (the fused slot
@@ -134,6 +143,12 @@ BYPASS_ALLOWLIST = {
     # a chunk slot's first-token sample is host-synchronous by design.
     "fused_prefill": ("mesh data sharding", "speculative decoding",
                       "lagged decode carry"),
+    # REFUSALS, not bypasses: a batcher asked for these raises with the
+    # reason (at construction: a draft model; at validate()/submit() and
+    # export_kv(): a KV artifact).  EVA's 8 prediction heads are the
+    # multi-byte self-speculation a later PR routes through _step_spec.
+    "speculative": ("eva summary pages",),
+    "kv_export": ("eva summary pages",),
 }
 
 
@@ -143,7 +158,8 @@ def compute_bypass_reasons(*, speculative: bool = False,
                            draft_quantized_cache: bool = False,
                            pipeline_depth: int = 0,
                            overlap: bool = False,
-                           multi_step: int = 1
+                           multi_step: int = 1,
+                           eva: bool = False
                            ) -> Dict[str, Optional[str]]:
     """The ``*_bypass_reason`` values a :class:`ContinuousBatcher`
     built from these mode flags records — ONE pure function, used by
@@ -154,26 +170,37 @@ def compute_bypass_reasons(*, speculative: bool = False,
     out: Dict[str, Optional[str]] = {
         "prefix_cache": None, "kv_tier": None, "pipeline": None,
         "overlap": None, "multi_step": None, "suspend": None,
-        "fused_prefill": None}
-    if quant:
+        "fused_prefill": None, "speculative": None, "kv_export": None}
+    if eva:
+        out["prefix_cache"] = out["speculative"] = out["kv_export"] = \
+            "eva summary pages"
+    elif quant:
         out["prefix_cache"] = "quantized kv cache"
     if n_shards != 1:
         out["kv_tier"] = "mesh data sharding"
+    elif eva:
+        out["kv_tier"] = "eva summary pages"
     elif quant:
         out["kv_tier"] = "quantized kv cache"
     if pipeline_depth and speculative:
         out["pipeline"] = "speculative decoding"
+    elif pipeline_depth and eva:
+        out["pipeline"] = "eva window close"
     # Effective lag modes AFTER the cross-bypasses above: overlap
     # yields to an ACTIVE pipeline (non-spec), and the pipeline itself
     # yields to speculation.
-    pipelined = bool(pipeline_depth) and not speculative
+    pipelined = bool(pipeline_depth) and out["pipeline"] is None
     if pipelined and overlap:
         out["overlap"] = "pipelined decode carry"
+    elif overlap and eva:
+        out["overlap"] = "eva window close"
     overlap_eff = overlap and out["overlap"] is None
     if speculative and multi_step > 1 and overlap_eff:
         out["multi_step"] = "speculative overlap round carry"
     if n_shards != 1:
         out["suspend"] = "mesh data sharding"
+    elif eva:
+        out["suspend"] = "eva summary pages"
     elif overlap_eff or pipelined:
         out["suspend"] = "lagged decode carry"
     if n_shards != 1:
@@ -587,6 +614,9 @@ class _ShardedAlloc:
     def release(self, row: int) -> None:
         self.shards[self.shard_of(row)].release(row)
 
+    def trim(self, row: int, length: int) -> int:
+        return self.shards[self.shard_of(row)].trim(row, length)
+
     def allocated(self, row: int) -> int:
         return self.shards[self.shard_of(row)].allocated(row)
 
@@ -670,6 +700,11 @@ class _PagedSide:
             self.pcache.release_row(row)
         self.alloc.release(row)
         self.dirty()
+
+    def trim(self, row: int, length: int) -> None:
+        """Keep the pages behind ``row``'s first ``length`` entries only."""
+        if self.alloc.trim(row, length):
+            self.dirty()
 
     def headroom(self, active: Dict[int, _Row], worst_of,
                  shard: int) -> int:
@@ -1509,12 +1544,34 @@ class ContinuousBatcher:
         # All ``*_bypass_reason`` registries come from ONE pure
         # helper (compute_bypass_reasons) so the audit test can
         # enumerate every reachable value against BYPASS_ALLOWLIST.
+        eva = cfg.attention == "eva"
         self._bypass = compute_bypass_reasons(
             speculative=draft_cfg is not None, n_shards=self.n_shards,
             quantized_cache=quantized_cache,
             draft_quantized_cache=draft_quantized_cache,
             pipeline_depth=pipeline_depth,
-            overlap=overlap, multi_step=multi_step)
+            overlap=overlap, multi_step=multi_step, eva=eva)
+        if eva:
+            # What EVA's pages cannot do yet is refused here, before any
+            # device state exists (the registries above bypass the rest).
+            if draft_cfg is not None:
+                raise ValueError(
+                    f"speculative decoding is refused under "
+                    f"attention='eva': {self._bypass['speculative']}")
+            for what, given in (("a mesh", mesh is not None),
+                                ("a shared prefix", prefix is not None),
+                                ("prefill_chunk", prefill_chunk is not None),
+                                ("quantized_cache", quantized_cache)):
+                if given:
+                    raise ValueError(
+                        f"{what} does not compose with attention='eva' "
+                        f"(summaries and one window's exact entries in "
+                        f"one page list per row)")
+            if cfg.eva_window % int(prefill_bucket):
+                raise ValueError(
+                    f"eva_window ({cfg.eva_window}) must be a multiple of "
+                    f"prefill_bucket ({prefill_bucket}): a prompt is "
+                    f"prefilled window by window, then a padded tail")
         self.pipeline_bypass_reason: Optional[str] = \
             self._bypass["pipeline"]
         # overlap+pipeline and spec-overlap+multi_step are BYPASSES
@@ -1545,7 +1602,10 @@ class ContinuousBatcher:
             raise ValueError(f"max_len ({self.max_len}) exceeds the "
                              f"config's max_seq_len ({cfg.max_seq_len})")
         self.page_size = int(page_size)
-        self.np_max = -(-self.max_len // self.page_size)
+        # A row's table covers the most cache ENTRIES a context of max_len
+        # can hold (one per position, but for EVA: cfg.cache_entries).
+        self.np_max = -(-cfg.cache_entries_peak(0, self.max_len)
+                        // self.page_size)
         # Default pool: every row's worst case (max_len minus whatever a
         # shared prefix covers read-only) + the prefix's reserved pages +
         # one inactive-row write sink — so the default always fully backs
@@ -1556,7 +1616,8 @@ class ContinuousBatcher:
         shared_full = (0 if prefix_np is None else
                        (int(prefix_np.size) // self.page_size)
                        * self.page_size)
-        own_max = -(-(self.max_len - shared_full) // self.page_size)
+        own_max = (self.np_max if eva else
+                   -(-(self.max_len - shared_full) // self.page_size))
         # Default pool: per data shard, its row block's worst case plus
         # the shard's own prefix + sink reservations (reservations are
         # PER SHARD — every sub-pool carries the prefix and a sink).
@@ -1608,6 +1669,14 @@ class ContinuousBatcher:
         self.prefix_len = 0
         self._prefill_fns: Dict[int, Any] = {}
         self._decode = self._make_decode()
+        # EVA: a window is closed by the host BETWEEN blocks
+        # (_eva_roll_row), so a K-step block must not carry a row across a
+        # window's end: the tick before one runs single steps (_decode1).
+        self._decode1 = (self._make_decode(1)
+                         if eva and self.multi_step > 1 else self._decode)
+        self._eva_roll = self._make_eva_roll() if eva else None
+        self.eva_rolls = 0             # observability: windows closed
+        self._eva_live = (0, 0, 0)      # (summary, window) entries; pages
         self._chunk_prefill = (self._make_chunk_prefill()
                                if prefill_chunk is not None else None)
         self._fused_step = self._make_fused_step() if self._fused else None
@@ -1792,11 +1861,16 @@ class ContinuousBatcher:
         if t is not None:
             self._tick_n += 1
             self._tick_c0 = tuple(_COMPILES)
-        return {"name": "tick", "tick": self._tick_n if t is not None else -1,
-                "t": t, "wall_ms": 0.0, "kind": "idle", "mode": self._mode,
-                "k": 0, "rows": 0, "dur": 0.0, "admitted": 0,
-                "prefill_tokens": 0, "phases": {}, "idle_ms": 0.0,
-                "compiles": 0, "compile_s": 0.0}
+        rec = {"name": "tick", "tick": self._tick_n if t is not None else -1,
+               "t": t, "wall_ms": 0.0, "kind": "idle", "mode": self._mode,
+               "k": 0, "rows": 0, "dur": 0.0, "admitted": 0,
+               "prefill_tokens": 0, "phases": {}, "idle_ms": 0.0,
+               "compiles": 0, "compile_s": 0.0}
+        if self._eva_roll is not None:
+            # windows closed in this tick; entries live at its end
+            rec.update(eva_rolls=0, eva_summary_entries=0,
+                       eva_window_entries=0, eva_pages=0)
+        return rec
 
     def _tick_roll(self, more: bool = True) -> None:
         """The one recorder write of the serve loop: close the open tick
@@ -1813,6 +1887,9 @@ class ContinuousBatcher:
                              + ph.get("batcher.readback", 0.0), 3)
             t["compiles"] = _COMPILES[0] - self._tick_c0[0]
             t["compile_s"] = _COMPILES[1] - self._tick_c0[1]
+            if self._eva_roll is not None:
+                (t["eva_summary_entries"], t["eva_window_entries"],
+                 t["eva_pages"]) = self._eva_live
             block = t["name"] == "decode.block"
             fill = t["prefill_tokens"] or t["admitted"]
             t["kind"] = ("fused" if t["mode"] == "fused"
@@ -2223,7 +2300,7 @@ class ContinuousBatcher:
 
         return jax.vmap(one)(last, rids, steps)
 
-    def _make_decode(self):
+    def _make_decode(self, K: Optional[int] = None):
         """K decode steps fused into ONE dispatch (``lax.scan``): the host
         syncs a [rows, K] token block instead of one [rows] vector per
         token, so the per-dispatch + device-to-host round-trip cost
@@ -2238,7 +2315,7 @@ class ContinuousBatcher:
         order, only the host sync point moves.  ``multi_step=1`` is the
         classic per-token tick (a length-1 scan)."""
         sharded = self.mesh is not None
-        K = self.multi_step
+        K = self.multi_step if K is None else K
         max_len = self.max_len
 
         def block(params, pool, table, tok0, positions, rids, steps):
@@ -2481,6 +2558,42 @@ class ContinuousBatcher:
 
         return draft_chunk
 
+    def _make_eva_roll(self):
+        """The program that closes one row's window (``jit_eva_roll``:
+        a module of its own in a device trace): its exact entries pooled
+        into summaries, in place (``transformer.eva_close_window``)."""
+        from tfmesos_tpu.models.transformer import eva_close_window
+
+        @partial(jax.jit, donate_argnums=1)
+        def eva_roll(params, pool, table, window):
+            return eva_close_window(self.cfg, params, pool, table, window)
+
+        return eva_roll
+
+    def _eva_roll_row(self, row: int, window: int) -> None:
+        """Close ``row``'s window ``window`` (all of its positions are in
+        the pool): dispatch the pooling, then hand the pages behind the
+        new summaries back to the allocator — in this tick."""
+        side = self.t_side
+        self.pool = self._eva_roll(
+            self.params, self.pool,
+            jnp.asarray(side.table_np()[row:row + 1]),
+            jnp.asarray(window, jnp.int32))
+        side.trim(row, (window + 1) * self.cfg.eva_summaries)
+        self.eva_rolls += 1
+        self._tick["eva_rolls"] += 1
+
+    def _eva_account(self, active: Dict[int, "_Row"]) -> None:
+        """For the tick record: the entries the live rows hold, by kind
+        (from their positions), and the pages the allocator holds for
+        them (pages that ``trim`` left behind would show as
+        ``eva_pages * page_size`` running away from the entries)."""
+        w, s_ent = self.cfg.eva_window, self.cfg.eva_summaries
+        self._eva_live = (
+            sum(row.pos // w * s_ent for row in active.values()),
+            sum(row.pos % w for row in active.values()),
+            sum(self.alloc.allocated(r) for r in active))
+
     def _one_hot_call(self, side: _PagedSide, row: int, chunk: np.ndarray):
         """(shard, [nd, w] tokens, [nd, np] table) for a per-row model
         call batched one row per mesh data shard: the admitted row's
@@ -2582,6 +2695,22 @@ class ContinuousBatcher:
     def _prefill_fn(self, width: int):
         """Jitted prefill at one padded-width bucket, batched one row per
         mesh data shard (``_one_hot_call``)."""
+        if width not in self._prefill_fns and self._eva_roll is not None:
+            # EVA: one program per padded width up to a window; ``start``
+            # (traced) is the position of the window the chunk begins, so
+            # a long prompt is whole windows of the widest program and
+            # one tail, whatever max_len is.
+            @partial(jax.jit, donate_argnums=1)
+            def prefill(params, pool, table, prompt, length, rid, start):
+                cache = dict(pool, pages=table)
+                logits, cache = decode_step(self.cfg, params, cache, prompt,
+                                            start)
+                last = jnp.take_along_axis(
+                    logits, (length - 1)[:, None, None], axis=1)[:, 0]
+                nxt = self._sample(last, rid, jnp.zeros_like(rid))
+                return {"k": cache["k"], "v": cache["v"]}, nxt
+
+            self._prefill_fns[width] = prefill
         if width not in self._prefill_fns:
             sharded = self.mesh is not None
 
@@ -2643,7 +2772,10 @@ class ContinuousBatcher:
                 f"{self.prefix_len} + prompt {req.prompt.size} padded to "
                 f"{width}, plus {req.max_new_tokens} new tokens) > "
                 f"max_len ({self.max_len})")
-        wt = -(-(need_len - self.t_side.shared_len) // self.page_size)
+        # pages by the ENTRIES the context can hold on its way to need_len
+        # (EVA: summaries and one window; otherwise one per position)
+        wt = -(-(self.cfg.cache_entries_peak(0, need_len)
+                 - self.t_side.shared_len) // self.page_size)
         wd = 0
         if self.d_side is not None:
             wd = -(-(need_len - self.d_side.shared_len) // self.page_size)
@@ -2800,6 +2932,7 @@ class ContinuousBatcher:
         request is rejected immediately instead of via run()'s
         drain-then-raise path."""
         if isinstance(req, Prefilled):
+            self._check_disagg_mode("importing a KV artifact")
             self._worst_pages(req.request)
             self._validate_artifact(req.artifact, req.request)
             return
@@ -2827,6 +2960,8 @@ class ContinuousBatcher:
         mode has ONE chunk width and doesn't use this.)"""
         b = self.prefill_bucket
         cap = ((self.max_len - self.prefix_len) // b) * b
+        if self._eva_roll is not None:
+            cap = min(cap, self.cfg.eva_window)     # windows, then a tail
         return list(range(b, cap + 1, b)) or [b]
 
     def warmup(self, decode: bool = True,
@@ -2875,14 +3010,22 @@ class ContinuousBatcher:
                 return jnp.asarray(np.full((nd, side.np_max), side.sink,
                                            np.int32))
 
+            eva = self._eva_roll is not None
             if prefill and self._chunk_prefill is None:
                 for w in self._prefill_widths():
                     self.pool, tok = self._prefill_fn(w)(
                         self.params, self.pool, sink_table(self.t_side),
                         jnp.asarray(np.zeros((nd, w), np.int32)),
-                        jnp.asarray(np.ones((nd,), np.int32)), zrow)
+                        jnp.asarray(np.ones((nd,), np.int32)), zrow,
+                        *([jnp.asarray(0, jnp.int32)] if eva else []))
                     np.asarray(tok)
                     compiled.append(f"prefill[{w}]")
+            if eva and self.max_len >= self.cfg.eva_window:
+                self.pool = self._eva_roll(
+                    self.params, self.pool, sink_table(self.t_side),
+                    jnp.asarray(0, jnp.int32))
+                jax.block_until_ready(self.pool)
+                compiled.append("eva_roll")
             cfn = self._chunk_prefill or self._tail_prefill
             if prefill and cfn is not None:
                 # The chunk loop always feeds the fixed chunk width,
@@ -2949,9 +3092,10 @@ class ContinuousBatcher:
                     np.asarray(out)
                     compiled.append(f"decode[{w}]")
                 else:
-                    self.pool, out = self._decode(
-                        self.params, self.pool, table, zt, zt, zt, zt)
-                    np.asarray(out)
+                    for fn in dict.fromkeys((self._decode, self._decode1)):
+                        self.pool, out = fn(
+                            self.params, self.pool, table, zt, zt, zt, zt)
+                        np.asarray(out)
                     compiled.append(f"decode[{w}]")
                 if self._fused:
                     # The fused tick's (decode width x slot bucket)
@@ -3036,6 +3180,10 @@ class ContinuousBatcher:
         if self.n_shards != 1:
             raise ValueError(f"{what} requires a single-shard pool "
                              f"(mesh data shards pin pages locally)")
+        if self._bypass["kv_export"] is not None:
+            raise ValueError(f"{what} is refused: "
+                             f"{self._bypass['kv_export']} (a KV artifact "
+                             f"lists pages of consecutive positions)")
 
     def kv_headroom(self) -> int:
         """Free KV pool pages this batcher could hand to a new request
@@ -3921,6 +4069,8 @@ class ContinuousBatcher:
         local pool and the row enters decode directly — the decode half
         of disaggregated serving."""
         if prefilled is not None:
+            if self._bypass["kv_export"] is not None:
+                self._check_disagg_mode("submit(prefilled=...)")
             request = Prefilled(request, prefilled)
         self._submission_source().submit(request)
 
@@ -4311,12 +4461,14 @@ class ContinuousBatcher:
                 except Exception:
                     row.req.on_tokens = None
 
-    def _ensure_sides(self, row: int, length: int) -> None:
+    def _ensure_sides(self, row: int, length: int, start: int = 0) -> None:
         """Back ABSOLUTE positions [0, length) of ``row`` on the target
         (and, speculative mode, draft) side.  The first time a row gains
         own pages, a partially-shared prefix tail page is copied into its
         first own page (copy-on-write) before any row write can land in
-        it."""
+        it.  Under EVA the pages back entries: the most the row holds
+        while its context grows from ``start`` to ``length``."""
+        length = self.cfg.cache_entries_peak(start, length)
         sides = ([self.t_side] if self.d_side is None
                  else [self.t_side, self.d_side])
         for side in sides:
@@ -4388,6 +4540,19 @@ class ContinuousBatcher:
             return self._admit_cached(row, rid, req, wt, wd, need,
                                       active, plan, t_admit)
         tick["prefill_tokens"] += width
+        if self._eva_roll is not None:
+            tok = self._eva_prefill(row, rid, req.prompt)
+            tok.copy_to_host_async()
+            # The prompt's windows are closed: from here the row can hold
+            # no more than its decode still adds (a whole window is held
+            # only across a close), and the reservation shrinks to that.
+            wt = -(-self.cfg.cache_entries_peak(int(length), need)
+                   // self.page_size)
+            state = _Row(rid=rid, req=req, pos=int(length), step=1, last=0,
+                         out=[], worst_pages=wt, t_admit=t_admit, limit=need)
+            active[row] = state
+            self._eva_account(active)
+            return row, state, tok, 0
         self._ensure_sides(row, self.prefix_len + width)
         padded = np.zeros((1, width), np.int32)
         padded[0, :length] = req.prompt
@@ -4411,6 +4576,31 @@ class ContinuousBatcher:
         active[row] = state
         self._pcache_insert(row, state)
         return row, state, tok, s
+
+    def _eva_prefill(self, row: int, rid: int, prompt: np.ndarray):
+        """Prefill ``prompt`` into ``row`` window by window: each chunk
+        attends the summaries so far and itself, writes its exact entries
+        behind them, and a whole window is closed at once (its pages but
+        the summaries' return to the allocator before the next window
+        takes them).  Returns the device token sampled at the prompt's
+        last position; nothing here waits for the device."""
+        w = self.cfg.eva_window
+        length, bucket = int(prompt.size), self.prefill_bucket
+        tok = None
+        for start in range(0, length, w):
+            n = min(w, length - start)
+            width = -(-n // bucket) * bucket
+            self._ensure_sides(row, start + width, start=start)
+            padded = np.zeros((1, width), np.int32)
+            padded[0, :n] = prompt[start:start + n]
+            self.pool, tok = self._prefill_fn(width)(
+                self.params, self.pool,
+                jnp.asarray(self.t_side.table_np()[row:row + 1]),
+                jnp.asarray(padded), jnp.asarray([n], jnp.int32),
+                jnp.asarray([rid], jnp.int32), jnp.asarray(start, jnp.int32))
+            if n == w:
+                self._eva_roll_row(row, start // w)
+        return tok
 
     def _admit_cached(self, row: int, rid: int, req: Request, wt: int,
                       wd: int, need: int, active: Dict[int, _Row],
@@ -4691,6 +4881,8 @@ class ContinuousBatcher:
         keeps still-filling rows out: their table rows mask to the sink
         so the batched scatter cannot touch their pages.)"""
         K = self.multi_step
+        decode = self._decode
+        eva_w = self.cfg.eva_window if self._eva_roll is not None else 0
         with self._phase("batcher.prep"):
             toks = np.zeros((self.rows,), np.int32)
             positions = np.zeros((self.rows,), np.int32)
@@ -4698,15 +4890,22 @@ class ContinuousBatcher:
             steps = np.zeros((self.rows,), np.int32)
             decoding = {r: row for r, row in active.items()
                         if row.decoding}
+            if eva_w and K > 1 and any(row.pos % eva_w + K > eva_w
+                                       for row in decoding.values()):
+                # a window ends inside this block for some row: single
+                # steps until the host has closed it (streams do not
+                # depend on K)
+                K, decode = 1, self._decode1
             for r, row in decoding.items():
-                self._ensure_sides(r, min(row.pos + K, row.limit))
+                self._ensure_sides(r, min(row.pos + K, row.limit),
+                                   start=row.pos)
                 toks[r] = row.last
                 positions[r] = row.pos
                 rids[r] = row.rid
                 steps[r] = row.step
             table = self.t_side.decode_table(active, decoding)
         with self._phase("batcher.dispatch"):
-            self.pool, nxt = self._decode(
+            self.pool, nxt = decode(
                 self.params, self.pool, table, jnp.asarray(toks),
                 jnp.asarray(positions), jnp.asarray(rids),
                 jnp.asarray(steps))
@@ -4728,6 +4927,12 @@ class ContinuousBatcher:
                         finished.append(self._completion(row))
                         self._finish_completed(r, active, free_rows)
                         break
+                else:
+                    if eva_w and row.pos % eva_w == 0:
+                        # the block's last step filled the row's window
+                        self._eva_roll_row(r, row.pos // eva_w - 1)
+            if eva_w:
+                self._eva_account(active)
         yield from finished
 
     def _step_overlap(self, active: Dict[int, _Row],
