@@ -263,6 +263,180 @@ def test_pick_block_legal_divisors():
     assert _pick_block(8) == 8
 
 
+def _prefill_widths(cell, window=None, bucket=64):
+    """Every prefill width of a benchmark cell's committed schedule: prompts
+    padded to the batcher's ``prefill_bucket``; an EVA prompt is prefilled
+    in windows of ``window`` and a tail."""
+    from benchmark import traffic_gen
+
+    sched = traffic_gen.make_schedule(traffic_gen.load_traffic(cell), 1, 51,
+                                      320)
+    widths = []
+    for r in sched.requests:
+        w = -(-r.prompt.size // bucket) * bucket
+        if window:
+            widths += [window] * (w // window) + [w % window] * bool(w % window)
+        else:
+            widths.append(w)
+    return widths
+
+
+@pytest.mark.parametrize("cell,window,under_256_before", [
+    ("docqa_batch", None, 1.0),         # every width an odd multiple of 64
+    ("chat_steady", None, 0.648),
+    ("longdoc_batch", 2048, 0.0),       # windows of 2048, tails of k x 256
+])
+def test_flash_tiles_over_the_committed_traffic(cell, window,
+                                                under_256_before):
+    """The counter of the forward's tile rule is a count over the traffic
+    the benchmark commits: the share of prefill attention work (width
+    squared) whose q tile has fewer than 256 rows.  The divisor rule
+    (``_pick_block``, which the backward and the ring keep) left all of
+    docqa_batch there, at 64 x 64; ``_flash_tiles`` leaves only the widths
+    that are under 256 themselves."""
+    from tfmesos_tpu.ops.attention import _flash_tiles, _pick_block
+
+    widths = _prefill_widths(cell, window)
+    work = float(sum(w * w for w in widths))
+    before = sum(w * w for w in widths if _pick_block(w, 512) < 256) / work
+    assert before == pytest.approx(under_256_before, abs=2e-3)
+    for w in widths:
+        bq, bk = _flash_tiles(w, w, 128, 2)
+        assert min(bq, bk) >= min(w, 256), (w, bq, bk)
+    if not window:
+        assert min(widths) % 64 == 0 and max(widths) <= 8192
+    if cell == "docqa_batch":
+        assert all(w // 64 % 2 for w in widths)     # odd multiples of 64
+
+
+@pytest.mark.parametrize("itemsize,head_dim,max_len", [
+    (2, 128, 8192), (2, 64, 8192), (1, 128, 8192),
+    (4, 128, 4096),     # float32: a KV head's K/V, resident, fits to here
+])
+def test_flash_tiles_legal_and_within_vmem(itemsize, head_dim, max_len):
+    """Every width the batcher can offer (multiples of the prefill bucket
+    up to ``max_len``) gets a Mosaic-legal tile near the target whose
+    reservation (a KV head's K and V resident, double-buffered, the q/o
+    blocks, the score block) stays under the chip's scoped VMEM limit."""
+    from tfmesos_tpu.ops.attention import (_VMEM_SCOPED_LIMIT,
+                                           _flash_tiles, _flash_vmem_bytes)
+
+    sub = 8 * max(1, 4 // itemsize)
+    for t in range(64, max_len + 1, 64):
+        bq, bk = _flash_tiles(t, t, head_dim, itemsize)
+        assert bq <= 512 and bk <= 512
+        # a block is the whole (padded) length or lands on the tiling: the
+        # dtype's sublanes for q rows, whole 128-lane rows of scores for k
+        assert bq == t or bq % sub == 0, (t, bq)
+        assert bk == t or bk % 128 == 0, (t, bk)
+        assert bq >= min(t, 256) and bk >= min(t, 256), (t, bq, bk)
+        n_q = -(-t // bq)
+        assert n_q == -(-t // 512) and n_q * bq - t < n_q * sub, (t, bq)
+        assert -(-t // bk) * bk - t < bk
+        assert _flash_vmem_bytes(bq, bk, t, head_dim,
+                                 itemsize) <= _VMEM_SCOPED_LIMIT, (t, bq, bk)
+    # the caller's block arguments stay targets
+    assert _flash_tiles(128, 128, 32, 4, 32, 64) == (32, 64)
+    assert _flash_tiles(704, 704, 32, 4, 128, 128) == (120, 128)
+
+
+def _dense_attention(q, k, v, causal, window=None, q_offset=0):
+    """(o, lse) with query i at global position ``i + q_offset``; a row that
+    sees no key reads zero and -inf, as the kernel's window form does."""
+    g = q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, g, axis=2), jnp.repeat(v, g, axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    qpos = q_offset + jnp.arange(q.shape[1])[:, None]
+    kpos = jnp.arange(k.shape[1])[None]
+    ok = jnp.ones_like(kpos > qpos) if not causal else kpos <= qpos
+    if window is not None:
+        ok = ok & (kpos >= qpos - (window - 1))
+    s = jnp.where(ok, s, -jnp.inf)
+    lse = jax.nn.logsumexp(s, axis=-1, keepdims=True)
+    p = jnp.where(ok, jnp.exp(s - jnp.where(jnp.isinf(lse), 0.0, lse)), 0.0)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v), lse
+
+
+# name -> (t_q, t_k, q heads, kv heads, causal, window, q_offset,
+#          (block_q, block_k) targets): every one a width its tile does not
+# divide, so a block of zero padding is read, masked and cut off again.
+RAGGED = {
+    "causal_192_one_block": (192, 192, 8, 2, True, None, 0, (512, 512)),
+    "causal_704": (704, 704, 8, 2, True, None, 0, (512, 512)),
+    "causal_1088": (1088, 1088, 8, 2, True, None, 0, (512, 512)),
+    "causal_704_small_tiles": (704, 704, 4, 1, True, None, 0, (128, 128)),
+    "full_320_over_704": (320, 704, 4, 2, False, None, 0, (128, 256)),
+    "full_704_last_block_all_padding_but_64": (64, 704, 2, 2, False, None, 0,
+                                               (64, 128)),
+    "window_100": (704, 704, 4, 2, True, 100, 0, (128, 256)),
+    "window_ring_step_1": (192, 192, 4, 4, True, 256, 192, (128, 128)),
+    "window_ring_step_2_rows_see_nothing": (192, 192, 2, 2, True, 300, 384,
+                                            (128, 128)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(RAGGED))
+def test_flash_forward_at_ragged_widths(name):
+    """``flash_attention_fwd`` at widths its tile does not divide, against
+    the dense form: o and the log-sum-exp the backward and EVA continue
+    from, in the caller's shapes, with no NaN from the padded tail."""
+    from tfmesos_tpu.ops.attention import (_FlashCfg, _flash_forward,
+                                           _flash_tiles)
+
+    t, tk, h, kv, causal, window, q_offset, targets = RAGGED[name]
+    ks = jax.random.split(jax.random.PRNGKey(len(name)), 3)
+    q = jax.random.normal(ks[0], (1, t, h, 32))
+    k = jax.random.normal(ks[1], (1, tk, kv, 32))
+    v = jax.random.normal(ks[2], (1, tk, kv, 32))
+    bq, bk = _flash_tiles(t, tk, 32, 4, *targets)
+    assert (t % bq or tk % bk) or name.endswith("one_block")
+    cfg = _FlashCfg(causal=causal, scale=32 ** -0.5, block_q=bq, block_k=bk,
+                    interpret=True, q_per_kv=h // kv, window=window,
+                    q_offset=q_offset)
+    o, lse = _flash_forward(cfg, q, k, v)
+    want_o, want_lse = _dense_attention(q, k, v, causal, window, q_offset)
+    assert o.shape == q.shape and lse.shape == (1, h, t, 1)
+    assert not np.isnan(np.asarray(o)).any()
+    np.testing.assert_allclose(np.asarray(o), np.asarray(want_o),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse),
+                               rtol=2e-5, atol=2e-5)
+    if not q_offset:
+        got = flash_attention(q, k, v, causal=causal, window=window,
+                              block_q=targets[0], block_k=targets[1],
+                              use_pallas=True, interpret=True)
+        np.testing.assert_allclose(
+            np.asarray(got),
+            np.asarray(mha_reference(q, k, v, causal=causal, window=window)),
+            rtol=2e-5, atol=2e-5)
+
+
+def test_flash_gradient_at_a_ragged_width():
+    """t = 320 under 128-targets: the forward pads q to 3 x 112 rows and K/V
+    to 3 x 128, the backward tiles 320 by its own divisor (64) and is
+    driven by the forward's ``lse`` in the caller's [B, H, T, 1]."""
+    b, t, h, kvh, d = 1, 320, 4, 2, 32
+    ks = jax.random.split(jax.random.PRNGKey(29), 3)
+    q = jax.random.normal(ks[0], (b, t, h, d))
+    k = jax.random.normal(ks[1], (b, t, kvh, d))
+    v = jax.random.normal(ks[2], (b, t, kvh, d))
+
+    def loss_flash(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True, block_q=128,
+                                       block_k=128, use_pallas=True,
+                                       interpret=True) ** 2)
+
+    def loss_ref(q, k, v):
+        return jnp.sum(mha_reference(q, k, v, causal=True) ** 2)
+
+    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    for a, e in zip(gf, gr):
+        assert not np.isnan(np.asarray(a)).any()
+        np.testing.assert_allclose(np.asarray(a), np.asarray(e),
+                                   rtol=2e-4, atol=2e-4)
+
+
 def test_default_blocks_gradient_long_seq():
     """t=1024 exercises the 512-block backward grid (multiple q/k blocks per
     axis plus causal block skipping) in interpret mode."""

@@ -361,12 +361,17 @@ def test_all_eight_heads_and_the_int8_control(params):
 
 # -- the pieces ---------------------------------------------------------------
 
+@pytest.mark.parametrize("t", [64, 576])
 def test_eva_prefill_attention_through_the_flash_kernel_interpreted(
-        monkeypatch):
+        monkeypatch, t):
     """The chunk's own part through ``flash_attention_fwd`` (interpret
     mode) and the cached summaries in XLA blocks, against the dense form;
-    a clamped block column lies past every row's entries."""
-    t, h, d, ps, n_pages = 64, 4, 16, 8, 12
+    a clamped block column lies past every row's entries.  576 is a tail
+    width its tile does not divide (288 rows against K blocks of 384, the
+    second mostly padding): the summaries continue from a log-sum-exp that
+    must not have seen the padding."""
+    h, d, ps, n_pages = 4, 16, 8, 12
+    assert A._flash_tiles(576, 576, d, 4) == (288, 384)
     monkeypatch.setattr(A, "EVA_BLOCK_PAGES", 4)    # 10 pages: 3 blocks
     q, k, v, _, _ = _qkv(t, h, d, seed=3)
     pool = jax.random.normal(jax.random.PRNGKey(4), (2, 2, n_pages, h, ps, d))

@@ -123,13 +123,65 @@ class _FlashCfg(NamedTuple):
     q_offset: int = 0
 
 
+#: VMEM one Pallas call may claim on a v5e without asking for more
+#: (Mosaic's scoped default); the forward's tile rule stays under it.
+_VMEM_SCOPED_LIMIT = 16 * 2 ** 20
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def _flash_vmem_bytes(block_q: int, block_k: int, t_k: int, head_dim: int,
+                      itemsize: int) -> int:
+    """VMEM the forward kernel reserves at a tile: one KV head's K and V
+    whole (padded to ``block_k``) and the q / o / lse blocks, each
+    double-buffered by the pipeline, plus the float32 score block, its
+    probabilities in the operand dtype, and the (o, m, l) accumulators
+    ([rows, 1] columns occupy whole 128-lane rows)."""
+    kv = 2 * 2 * _round_up(t_k, block_k) * head_dim * itemsize
+    qo = 2 * 2 * block_q * head_dim * itemsize + 2 * block_q * 128 * 4
+    scores = block_q * block_k * (2 * 4 + itemsize)
+    acc = block_q * (head_dim + 2 * 128) * 4
+    return kv + qo + scores + acc
+
+
+def _flash_tiles(t_q: int, t_k: int, head_dim: int, itemsize: int,
+                 target_q: int = 512, target_k: int = 512):
+    """(block_q, block_k) of the forward kernel, from the chip and not from
+    the divisors of the lengths: a length at or under its target is one
+    block; a longer one is cut into ``ceil(t / target)`` equal blocks,
+    rounded up to the dtype's sublane tile (q) or to 128 lanes (k), and the
+    ragged tail is padded and masked by ``_flash_forward``.  The larger
+    block halves, down to 256, while :func:`_flash_vmem_bytes` passes the
+    scoped limit (past that a KV head's resident K/V is what does not fit)."""
+    sub = 8 * max(1, 4 // itemsize)
+
+    def cut(t, target, align):
+        target = max(sub, target // sub * sub)
+        if t <= target:
+            return t
+        return min(_round_up(-(-t // -(-t // target)), align), target)
+
+    bq, bk = cut(t_q, target_q, sub), cut(t_k, target_k, 128)
+    while (max(bq, bk) > 256 and _flash_vmem_bytes(
+            bq, bk, t_k, head_dim, itemsize) > _VMEM_SCOPED_LIMIT):
+        if bk >= bq:
+            bk = _round_up(bk // 2, 128)
+        else:
+            bq = _round_up(bq // 2, sub)
+    return bq, bk
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, cfg: _FlashCfg,
                   seq_len: int):
-    """One (batch, q-block, head) grid cell: stream K/V blocks with online
+    """One (batch, head, q-block) grid cell: stream K/V blocks with online
     softmax.  Accumulation in fp32; output cast back at the end.
 
     Refs are laid out ``[1, 1, T, D]`` — (seq, head_dim) must be the trailing
-    dims so blocks land on the TPU's (8, 128) tiling.
+    dims so blocks land on the TPU's (8, 128) tiling.  ``seq_len`` is the
+    number of real keys: the K/V refs are padded with zeros to a whole
+    number of ``block_k`` blocks, and the padding is masked by position.
 
     Operands stay in their input dtype (bf16 runs the MXU at full rate) with
     fp32 accumulation via ``preferred_element_type``; softmax statistics are
@@ -137,45 +189,54 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, cfg: _FlashCfg,
     """
     q = q_ref[0, 0, :, :]  # [bq, d], input dtype
     bq, bk = cfg.block_q, cfg.block_k
-    qi = pl.program_id(1)
-    nk = seq_len // bk
-    lo = 0
+    q_lo = pl.program_id(2) * bq + cfg.q_offset  # first row's position
+    nk = -(-seq_len // bk)
+    ragged = seq_len % bk != 0
+    # Blocks [0, n_clear) need no mask; [n_clear, hi) cross the causal
+    # diagonal, a window's edge or the end of the keys and are masked.
+    n_clear, hi = seq_len // bk, nk
     if cfg.causal:
         # Blocks strictly above the diagonal contribute nothing: bound the
         # loop instead of masking them (halves the FLOPs on average).
-        nk = jnp.minimum(nk, pl.cdiv((qi + 1) * bq + cfg.q_offset, bk))
+        hi = jnp.minimum(nk, pl.cdiv(q_lo + bq, bk))
+        n_clear = jnp.minimum(n_clear, (q_lo + 1) // bk)
         if cfg.window is not None:
             # Sliding window: blocks entirely below every query's window
-            # start also contribute nothing — total work is O(T·W).
-            lo = jnp.maximum(
-                0, (qi * bq + cfg.q_offset - (cfg.window - 1)) // bk)
+            # start also contribute nothing — total work is O(T·W) — and
+            # every block a window reaches is masked.
+            n_clear = jnp.maximum(0, (q_lo - (cfg.window - 1)) // bk)
 
-    def body(j, carry):
+    def step(masked, j, carry):
         o, m, l = carry
-        if bk == seq_len:
-            # One K block (a length with no 8-aligned divisor lands here):
-            # read it whole — Mosaic cannot prove a dynamic start of
-            # j * bk tile-aligned when bk is not a multiple of 8.
+        if nk == 1:
+            # One K block (whatever its length): read it whole — Mosaic
+            # cannot prove a dynamic start of j * bk tile-aligned when bk
+            # is not a multiple of 8.
             k_blk, v_blk = k_ref[0, 0, :, :], v_ref[0, 0, :, :]
         else:
-            k_blk = k_ref[0, 0, pl.ds(j * bk, bk), :]  # [bk, d]
-            v_blk = v_ref[0, 0, pl.ds(j * bk, bk), :]
+            at = pl.ds(pl.multiple_of(j * bk, bk), bk)
+            k_blk, v_blk = k_ref[0, 0, at, :], v_ref[0, 0, at, :]  # [bk, d]
         s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # [bq, bk]
         s = s * cfg.scale
-        if cfg.causal:
-            qpos = (qi * bq + cfg.q_offset
-                    + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0))
+        if masked:
             kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            bad = kpos > qpos
-            if cfg.window is not None:
-                bad = bad | (kpos < qpos - (cfg.window - 1))
+            # Keys past the array are zeros, not absent: mask them by
+            # position, whatever the causal structure says.
+            bad = kpos >= seq_len if ragged else None
+            if cfg.causal:
+                qpos = q_lo + jax.lax.broadcasted_iota(
+                    jnp.int32, (bq, bk), 0)
+                above = kpos > qpos
+                if cfg.window is not None:
+                    above = above | (kpos < qpos - (cfg.window - 1))
+                bad = above if bad is None else bad | above
             s = jnp.where(bad, NEG_INF, s)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         if cfg.window is not None:
             # A q row can be ENTIRELY outside the window in this k block
-            # (the loop's lo bound fits the block's lowest row, not all of
-            # them): m_new stays -inf there and exp(-inf - -inf) is NaN.
+            # (the loop's lower bound fits the block's lowest row, not all
+            # of them): m_new stays -inf there and exp(-inf - -inf) is NaN.
             # Zero those entries explicitly — plain causal never hits this
             # (block 0 is valid for every row).
             p = jnp.where(s == NEG_INF, 0.0, jnp.exp(s - m_new))
@@ -190,13 +251,19 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, cfg: _FlashCfg,
         return o_new, m_new, l_new
 
     d = q.shape[-1]
-    o0 = jnp.zeros((bq, d), jnp.float32)
-    m0 = jnp.full((bq, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((bq, 1), jnp.float32)
-    o, m, l = jax.lax.fori_loop(lo, nk, body, (o0, m0, l0))
+    carry = (jnp.zeros((bq, d), jnp.float32),
+             jnp.full((bq, 1), NEG_INF, jnp.float32),
+             jnp.zeros((bq, 1), jnp.float32))
+    if cfg.window is None and (cfg.causal or n_clear):
+        carry = jax.lax.fori_loop(0, n_clear, functools.partial(step, False),
+                                  carry)
+    if cfg.causal or ragged:
+        carry = jax.lax.fori_loop(n_clear, hi, functools.partial(step, True),
+                                  carry)
+    o, m, l = carry
     if cfg.window is not None:
-        # With an offset window a whole q row (or the whole block: lo >=
-        # nk) can see NO key in this shard: emit a clean zero/-inf
+        # With an offset window a whole q row (or the whole block: an
+        # empty loop) can see NO key in this shard: emit a clean zero/-inf
         # partial instead of 0/0 NaNs, so the ring's lse merge drops it.
         empty = l == 0.0
         o_ref[0, 0, :, :] = jnp.where(
@@ -212,41 +279,60 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, cfg: _FlashCfg,
 
 
 def _flash_forward(cfg: _FlashCfg, q, k, v):
+    """The forward kernel at ``cfg``'s tile, which need not divide either
+    length: q is padded to whole ``block_q`` blocks and K/V to whole
+    ``block_k`` blocks with zeros (so a masked score never meets garbage
+    in ``p @ v``), inside the transposes this wrapper makes anyway; the
+    kernel masks keys past the array by position and the rows past ``T``
+    are cut off again.  Returns ``o`` [B, T, H, D] and ``lse`` [B, H, T, 1]."""
     b, t, h, d = q.shape
+    tk = k.shape[1]
     g = h // k.shape[2]  # q heads per kv head (1 = plain MHA)
-    # [B, T, H, D] -> [B, H, T, D]: (seq, head_dim) trailing for TPU tiling.
-    qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
-    grid = (b, t // cfg.block_q, h)
-    q_spec = pl.BlockSpec((1, 1, cfg.block_q, d),
-                          lambda bi, qi, hi: (bi, hi, qi, 0),
+    bq, bk = cfg.block_q, cfg.block_k
+    t_pad = _round_up(t, bq)
+
+    def blocked(x, block):
+        # [B, T, H, D] -> [B, H, T, D]: (seq, head_dim) trailing for TPU
+        # tiling; zeros up to a whole number of blocks.
+        x = x.transpose(0, 2, 1, 3)
+        pad = _round_up(x.shape[2], block) - x.shape[2]
+        return jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0))) if pad else x
+
+    qt, kt, vt = blocked(q, bq), blocked(k, bk), blocked(v, bk)
+    # The q block innermost: a KV head's K and V stay resident across its
+    # q blocks and its group's q heads (an unchanged block is not fetched
+    # again), one fetch per KV head instead of one per q block per group.
+    grid = (b, h, t_pad // bq)
+    q_spec = pl.BlockSpec((1, 1, bq, d),
+                          lambda bi, hi, qi: (bi, hi, qi, 0),
                           memory_space=pltpu.VMEM)
     # GQA without materializing the repeat: q head hi reads kv head hi//g
     # straight from the narrow K/V arrays via the index map.
-    kv_spec = pl.BlockSpec((1, 1, k.shape[1], d),
-                           lambda bi, qi, hi: (bi, hi // g, 0, 0),
+    kv_spec = pl.BlockSpec((1, 1, kt.shape[2], d),
+                           lambda bi, hi, qi: (bi, hi // g, 0, 0),
                            memory_space=pltpu.VMEM)
-    lse_spec = pl.BlockSpec((1, 1, cfg.block_q, 1),
-                            lambda bi, qi, hi: (bi, hi, qi, 0),
+    lse_spec = pl.BlockSpec((1, 1, bq, 1),
+                            lambda bi, hi, qi: (bi, hi, qi, 0),
                             memory_space=pltpu.VMEM)
-    kernel = functools.partial(_flash_kernel, cfg=cfg, seq_len=k.shape[1])
+    kernel = functools.partial(_flash_kernel, cfg=cfg, seq_len=tk)
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=[q_spec, lse_spec],
         out_shape=[jax.ShapeDtypeStruct(qt.shape, q.dtype),
-                   jax.ShapeDtypeStruct((b, h, t, 1), jnp.float32)],
+                   jax.ShapeDtypeStruct((b, h, t_pad, 1), jnp.float32)],
         interpret=cfg.interpret,
         name="flash_attention_fwd",
         compiler_params=None if cfg.interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         cost_estimate=pl.CostEstimate(
-            flops=4 * b * h * t * k.shape[1] * d,
+            flops=4 * b * h * t * tk * d,
             bytes_accessed=(q.size + k.size + v.size + q.size) * q.dtype.itemsize,
-            transcendentals=b * h * t * k.shape[1],
+            transcendentals=b * h * t * tk,
         ),
     )(qt, kt, vt)
-    return out.transpose(0, 2, 1, 3), lse
+    return out[:, :, :t].transpose(0, 2, 1, 3), lse[:, :, :t]
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -500,6 +586,12 @@ def flash_attention(q, k, v, causal: bool = False, scale: Optional[float] = None
     Grouped-query attention: ``k``/``v`` may carry ``H // g`` heads for any
     integer ``g``; the kernels map q head ``h`` to kv head ``h // g`` via
     their index maps, so the repeat is never materialized.
+
+    The forward's tile (``_flash_tiles``): a length at or under its block
+    target is one block; a longer one is cut into ``ceil(t / target)`` equal
+    blocks (q rounded up to the dtype's sublanes, k to 128 lanes) and the
+    tail is zero-padded and masked by position, so no length degenerates to
+    the small blocks its divisors would allow (704 = 64 x 11 runs 352 x 384).
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
@@ -509,16 +601,17 @@ def flash_attention(q, k, v, causal: bool = False, scale: Optional[float] = None
             raise ValueError("window requires causal=True")
         if window < 1:
             raise ValueError(f"window must be >= 1, got {window}")
-    t = q.shape[1]
-    # Treat the block arguments as targets: run with the largest Mosaic-legal
-    # (8-aligned or full-dim) divisor at or under each — so t=1280 still gets
-    # 256-blocks rather than falling off the kernel path.  A dim with no
-    # 8-aligned divisor comes back as the full dim (legal, single block); cap
-    # that at 1024 so a huge unaligned seq falls back to XLA instead of
-    # dragging a whole [t, t] score block through VMEM.
-    block_q = _pick_block(t, block_q)
-    block_k = _pick_block(k.shape[1], block_k)
-    aligned = block_q <= 1024 and block_k <= 1024
+    t, tk = q.shape[1], k.shape[1]
+    # The block arguments are targets.  The forward takes its tile from
+    # ``_flash_tiles`` (any length, the ragged tail padded and masked); the
+    # backward takes the largest Mosaic-legal (8-aligned or full-dim)
+    # divisor of each length itself.  A dim with no 8-aligned divisor comes
+    # back as the full dim (legal, single block): past 1024 such a seq falls
+    # back to XLA instead of dragging a whole [t, t] score block through
+    # VMEM in the backward.
+    aligned = _pick_block(t) <= 1024 and _pick_block(tk) <= 1024
+    block_q, block_k = _flash_tiles(t, tk, q.shape[-1], q.dtype.itemsize,
+                                    block_q, block_k)
     if use_pallas is None:
         on_tpu = jax.default_backend() == "tpu"
         use_pallas = aligned and (on_tpu or interpret)
@@ -526,9 +619,8 @@ def flash_attention(q, k, v, causal: bool = False, scale: Optional[float] = None
         # Fail fast on a forced-pallas misuse rather than dragging an
         # unaligned [t, t] score block through VMEM.
         raise ValueError(
-            f"flash_attention(use_pallas=True): seq lens {t}/{k.shape[1]} "
-            f"have no Mosaic-legal block tiling at or under "
-            f"({block_q}, {block_k})")
+            f"flash_attention(use_pallas=True): seq lens {t}/{tk} have no "
+            f"Mosaic-legal block tiling for the backward kernels")
     if not use_pallas:
         return mha_reference(q, k, v, causal=causal, scale=scale,
                              window=window)
@@ -544,11 +636,11 @@ def _causal_with_lse(q, k, v, scale: float, interpret: bool = False):
     log-sum-exp of the scaled scores ([B, H, t, 1], float32): the flash
     kernel's forward on TPU, the dense form elsewhere."""
     t = q.shape[1]
-    blk = _pick_block(t, 512)
-    if blk <= 1024 and (interpret or jax.default_backend() == "tpu"):
+    if interpret or jax.default_backend() == "tpu":
+        bq, bk = _flash_tiles(t, t, q.shape[-1], q.dtype.itemsize)
         return _flash_forward(
-            _FlashCfg(causal=True, scale=float(scale), block_q=blk,
-                      block_k=blk, interpret=bool(interpret),
+            _FlashCfg(causal=True, scale=float(scale), block_q=bq,
+                      block_k=bk, interpret=bool(interpret),
                       q_per_kv=q.shape[2] // k.shape[2]), q, k, v)
     b, _, h, d = q.shape
     kv = k.shape[2]
