@@ -551,15 +551,6 @@ def bench_serving_continuous(n_requests=32, rows=8, tiny=False):
     # reference even when the pipeline section is skipped.
     decode_itl_p50_ms = _itl_p50_ms(done)
 
-    # Overlap mode: tick t+1 dispatched before tick t's tokens sync —
-    # the win is one host round-trip per generated token.
-    ob = ContinuousBatcher(cfg, params, rows=rows, max_len=max_len,
-                           overlap=True)
-    list(ob.run(reqs(2)))
-    t0 = time.perf_counter()
-    odone = list(ob.run(reqs(n_requests)))
-    overlap_rps = len(odone) / (time.perf_counter() - t0)
-
     # Multi-step blocks: K decode steps fused into ONE dispatch, one
     # host sync per [rows, K] token block.  Round-5 TPU profiling showed
     # per-tick dispatch+sync dominating the batcher — this is the fix.
@@ -569,15 +560,7 @@ def bench_serving_continuous(n_requests=32, rows=8, tiny=False):
     t0 = time.perf_counter()
     mdone = list(ms.run(reqs(n_requests)))
     multistep_rps = len(mdone) / (time.perf_counter() - t0)
-
-    mo = ContinuousBatcher(cfg, params, rows=rows, max_len=max_len,
-                           multi_step=16, overlap=True)
-    list(mo.run(reqs(2)))
-    t0 = time.perf_counter()
-    modone = list(mo.run(reqs(n_requests)))
-    multistep_overlap_rps = len(modone) / (time.perf_counter() - t0)
-    return (n_requests / dt, mean_ttft_ms, overlap_rps, multistep_rps,
-            multistep_overlap_rps, decode_itl_p50_ms)
+    return n_requests / dt, mean_ttft_ms, multistep_rps, decode_itl_p50_ms
 
 
 def _itl_p50_ms(completions) -> float:
@@ -1429,7 +1412,7 @@ def bench_serving_longctx(n_requests=8, rows=4, max_len=8192,
     carried cache, bucketed decode tables, and deferred pool commits
     were built for (an 8k-slot paged pool per row).  Reports generated
     tokens/s across the stream and mean TTFT, with multi_step=16 +
-    overlap (the production setting); same protocol/scaffolding as the
+    pipeline_depth=1; same protocol/scaffolding as the
     headline serving bench (``_serving_bench_setup``; ``tiny=True`` is
     the CI smoke — same call path at toy sizes)."""
     from tfmesos_tpu.serving import ContinuousBatcher
@@ -1437,7 +1420,8 @@ def bench_serving_longctx(n_requests=8, rows=4, max_len=8192,
     cfg, params, reqs, max_len, new = _serving_bench_setup(
         tiny, max_len=max_len, plen=plen, new=new)
     b = ContinuousBatcher(cfg, params, rows=rows, max_len=max_len,
-                          multi_step=2 if tiny else 16, overlap=True)
+                          multi_step=2 if tiny else 16,
+                          pipeline_depth=1)
     list(b.run(reqs(2)))    # warm the compiles outside the timed region
     t0 = time.perf_counter()
     done = list(b.run(reqs(n_requests)))
@@ -3635,13 +3619,10 @@ def main():
         flush_partial()
     sv = attempts(bench_serving_continuous, "continuous serving bench", n=1)
     if sv:
-        rps, ttft_ms, overlap_rps, ms_rps, mso_rps, itl_p50 = sv[0]
+        rps, ttft_ms, ms_rps, itl_p50 = sv[0]
         out["serving_requests_per_sec"] = round(rps, 2)
         out["serving_mean_ttft_ms"] = round(ttft_ms, 2)
-        out["serving_overlap_requests_per_sec"] = round(overlap_rps, 2)
         out["serving_multistep_requests_per_sec"] = round(ms_rps, 2)
-        out["serving_multistep_overlap_requests_per_sec"] = round(
-            mso_rps, 2)
         out["serving_decode_p50_intertoken_ms"] = round(itl_p50, 3)
         flush_partial()
     pc = attempts(bench_decode_paged_call, "paged decode call bench", n=1)

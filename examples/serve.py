@@ -73,11 +73,6 @@ def main():
                    help="decode K tokens per dispatch in continuous mode "
                         "(one host sync per [rows, K] block; stops act "
                         "at block granularity, token streams identical)")
-    p.add_argument("--overlap", action="store_true",
-                   help="double-buffered decode (with --continuous): "
-                        "dispatch tick t+1 before syncing tick t's "
-                        "tokens — hides per-token host round-trips; "
-                        "token streams identical to non-overlap")
     p.add_argument("--pipeline-depth", type=int, default=0,
                    choices=(0, 1), dest="pipeline_depth",
                    help="pipelined device-resident decode (with "
@@ -85,7 +80,7 @@ def main():
                         "previous block's on-device tokens/positions/"
                         "steps and syncs one block behind — token "
                         "streams identical to 0 (the synchronous "
-                        "default); mutually exclusive with --overlap")
+                        "default)")
     p.add_argument("--warmup", action="store_true",
                    help="compile every jitted serving entry point "
                         "before the stream starts (with --continuous; "
@@ -106,16 +101,12 @@ def main():
     if args.multi_step != 1 and not args.continuous:
         p.error("--multi-step is a continuous-batching feature; "
                 "add --continuous")
-    if args.overlap and not args.continuous:
-        p.error("--overlap is a continuous-batching feature; "
-                "add --continuous")
     if args.pipeline_depth and not args.continuous:
         p.error("--pipeline-depth is a continuous-batching feature; "
                 "add --continuous")
-    # --multi-step with --speculative and --pipeline-depth with
-    # --overlap both construct now: the batcher composes the former (R
-    # fused speculative rounds per dispatch) and records an enforced
-    # bypass for the latter (overlap_bypass_reason) — see
+    # --multi-step composes with --speculative (R fused speculative
+    # rounds per dispatch); --pipeline-depth with --speculative serves
+    # synchronously and records pipeline_bypass_reason — see
     # serving.BYPASS_ALLOWLIST.
     if args.warmup and not args.continuous:
         p.error("--warmup is a continuous-batching feature; "
@@ -195,17 +186,11 @@ def main():
         # -1 in spec mode: the draft's backfill step writes one past the
         # proposals (ContinuousBatcher's depth check).
         ml = cfg.max_seq_len - (nd + 1 if nd else 0)
-        # Overlap/pipelined endings surface late, so admission reserves
-        # extra cache positions: a full overshoot round in speculative
-        # mode, one position for a plain stop.  (Speculative decoding
-        # bypasses --pipeline-depth explicitly, so its reservation only
-        # follows --overlap.)
-        ov = 0
-        if args.overlap:
-            ov = ((nd + 1) if args.speculative
-                  else (1 if args.stop_token is not None else 0))
-        elif args.pipeline_depth and not args.speculative:
-            ov = 1 if args.stop_token is not None else 0
+        # A pipelined stop surfaces one block late, so admission
+        # reserves one more cache position.  (Speculative decoding
+        # bypasses --pipeline-depth explicitly.)
+        ov = int(bool(args.pipeline_depth) and not args.speculative
+                 and args.stop_token is not None)
         climit = min((ml - nd - ov) // bucket * bucket,
                      ml - nd - ov - args.new_tokens + 1)
         if any(len(t) > climit for t in prompts):
@@ -238,7 +223,7 @@ def main():
             quantized_cache=args.int8_kv,
             prefill_chunk=args.prefill_chunk,
             draft_cfg=draft_cfg, draft_params=draft_params,
-            n_draft=SPEC_N_DRAFT, mesh=mesh, overlap=args.overlap,
+            n_draft=SPEC_N_DRAFT, mesh=mesh,
             draft_quantized_cache=args.int8_draft_kv,
             multi_step=args.multi_step,
             prefix_cache_pages=args.prefix_cache,

@@ -124,7 +124,7 @@ _HEAVY_MULTICHIP = {
     # Budget headroom for the fleet-autoscaler e2e pair (PR 6): the
     # heaviest sibling-covered variants move to the full suite — one
     # representative of each family ([False] serve example, the other
-    # mesh/overlap/multistep batcher axes, the remaining moe
+    # mesh/pipelined/multistep batcher axes, the remaining moe
     # shared-expert/aux tests, the short-context decode benches) stays
     # in tier-1.
     "test_serve_example_end_to_end[True]",
@@ -132,8 +132,7 @@ _HEAVY_MULTICHIP = {
     "test_shared_experts_add_dense_ffn",
     "test_mesh_batcher_token_identical[axes2-spec_chunk_prefix]",
     "test_switch_moe_topk_aux_metrics_in_loss",
-    "test_multistep_batcher_token_identical[2-overlap_mesh]",
-    "test_overlap_batcher_token_identical[spec_mesh]",
+    "test_multistep_batcher_token_identical[2-pipelined_mesh]",
     "test_staggered_stream_matches_offline",
     "test_speculative_batcher_sampled_invariance_and_prefix_equality",
     "test_shared_prefix_matches_generate[21]",
@@ -142,21 +141,15 @@ _HEAVY_MULTICHIP = {
     # Budget headroom for the preempt/resume matrix + migration tests
     # (PR 7): sibling-covered parametrized duplicates move to the full
     # suite — the k=2 multistep variants (plus [4-base]) keep every
-    # axis in tier-1, overlap/pipelined/mesh/spec families each keep
+    # axis in tier-1, pipelined/mesh/spec families each keep
     # representatives of the moved variants' axes.
     "test_multistep_batcher_token_identical[4-staggered]",
     "test_multistep_batcher_token_identical[4-stop]",
     "test_multistep_batcher_token_identical[4-sampled]",
     "test_multistep_batcher_token_identical[4-prefix]",
     "test_multistep_batcher_token_identical[4-mesh]",
-    "test_multistep_batcher_token_identical[4-overlap]",
-    "test_multistep_batcher_token_identical[4-overlap_stop]",
-    "test_multistep_batcher_token_identical[4-overlap_mesh]",
-    "test_overlap_batcher_token_identical[staggered]",
-    "test_overlap_batcher_token_identical[spec_stop]",
-    "test_pipelined_batcher_token_identical[staggered]",
-    "test_pipelined_batcher_token_identical[multistep_stop]",
-    "test_pipelined_batcher_token_identical_heavy[mesh]",
+    "test_multistep_batcher_token_identical[4-pipelined_stop]",
+    "test_multistep_batcher_token_identical[4-pipelined_mesh]",
     "test_mesh_batcher_token_identical[axes1-base]",
     "test_speculative_batcher_with_shared_prefix[13]",
     "test_speculative_batcher_with_shared_prefix[21]",
@@ -166,8 +159,6 @@ _HEAVY_MULTICHIP = {
     "test_shared_prefix_matches_generate[11]",
     "test_prefix_cache_composes_with_global_prefix[11]",
     "test_mesh_batcher_token_identical[axes3-sampled]",
-    "test_overlap_batcher_token_identical[stop]",
-    "test_overlap_batcher_token_identical[spec_sampled]",
     # Budget headroom offsetting PR 8's new containment/deadline tests
     # (all tier-1): sibling-covered preempt-matrix variants move to the
     # full suite — greedy + sampled keep the resume-stream contract in

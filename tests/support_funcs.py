@@ -267,19 +267,19 @@ def _cb_workload():
     return cfg, params, reqs, kw
 
 
-def continuous_batching_mesh(ctx, axes, overlap=False):
+def continuous_batching_mesh(ctx, axes, pipeline_depth=0):
     """Multi-chip continuous batching across the cross-process mesh: every
     process runs the identical admission loop, decode rides the dp x tp
     sharded paged pool (shard-local page tables), and host-read tokens are
     replicated — each process must yield the same completions.
-    ``overlap=True`` additionally double-buffers the decode dispatch."""
+    ``pipeline_depth=1`` additionally runs the lagged (device-carry) loop."""
     import jax
     from tfmesos_tpu.parallel.mesh import build_mesh
     from tfmesos_tpu.serving import ContinuousBatcher
 
     cfg, params, reqs, kw = _cb_workload()
     b = ContinuousBatcher(cfg, params, mesh=build_mesh(axes),
-                          overlap=overlap, **kw)
+                          pipeline_depth=pipeline_depth, **kw)
     done = {c.rid: c.tokens for c in b.run(reqs)}
     return {"process_count": jax.process_count(),
             "device_count": jax.device_count(),

@@ -260,18 +260,17 @@ def test_what_eva_pages_cannot_do_is_refused_with_a_registered_reason(
     for reg in ("prefix_cache", "kv_tier", "suspend", "speculative",
                 "kv_export"):
         assert reason in BYPASS_ALLOWLIST[reg]
-    for reg in ("overlap", "pipeline"):
-        assert "eva window close" in BYPASS_ALLOWLIST[reg]
+    assert "eva window close" in BYPASS_ALLOWLIST["pipeline"]
     with pytest.raises(ValueError, match=f"speculative.*{reason}"):
         batcher(cfg, params, draft_cfg=cfg, draft_params=params)
     from tfmesos_tpu.fleet.kvtier import KVTierStore
-    b = batcher(cfg, params, prefix_cache_pages=8, overlap=True,
-                pipeline_depth=1, kv_tier=KVTierStore(1 << 20))
+    b = batcher(cfg, params, prefix_cache_pages=8, pipeline_depth=1,
+                kv_tier=KVTierStore(1 << 20))
     assert b.prefix_cache_bypass_reason == reason and b._pcache is None
     assert b.kv_tier_bypass_reason == reason and not b._tier_active
     assert b.suspend_bypass_reason == reason and not b.preemptible
     assert b.pipeline_bypass_reason == "eva window close"
-    assert not b.overlap and not b._pipelined
+    assert not b._pipelined
     # sessions park through the tier: a labeled request is served cold
     req = Request(prompt=prompt_of(40), max_new_tokens=3, session_id="s")
     done, seen = run_logged(b, [req])

@@ -186,10 +186,10 @@ def test_cross_process_continuous_batching():
                  start_timeout=180.0, env=env) as c:
         rs = c.run_all("support_funcs:continuous_batching_mesh",
                        {"dp": 2, "tp": 4})
-        # The overlap (double-buffered) loop over the SAME cross-process
-        # mesh: still lockstep, still the same tokens.
+        # The pipelined (one block of lag) loop over the SAME
+        # cross-process mesh: still lockstep, still the same tokens.
         ov = c.run("support_funcs:continuous_batching_mesh",
-                   {"dp": 2, "tp": 4}, overlap=True)
+                   {"dp": 2, "tp": 4}, pipeline_depth=1)
     assert len(rs) == 2
     for r in rs:
         assert r["process_count"] == 2 and r["device_count"] == 8, r

@@ -503,107 +503,12 @@ def test_mesh_batcher_token_identical(mesh_setup, axes, variant):
                 side.n_pages // b.n_shards - n_res
 
 
-@pytest.mark.parametrize("variant", [
-    "base", "staggered", "stop", "sampled", "chunked", "prefix", "mesh",
-    "spec", "spec_sampled", "spec_stop", "spec_mesh",
-])
-def test_overlap_batcher_token_identical(setup, mesh_setup, draft_setup,
-                                         variant):
-    """overlap=True (tick t+1 dispatched before tick t's host sync) must
-    produce IDENTICAL token streams to the plain batcher across the
-    matrix — stop tokens act one tick late but the overshoot tick's
-    output is discarded, sampled keys are unchanged, the mesh path
-    composes, and SPECULATIVE rounds carry token/position/step on
-    device (commit counts never round-trip before the next dispatch)."""
-    if variant in ("mesh", "spec_mesh"):
-        cfg, params, dcfg, dparams = mesh_setup
-    else:
-        cfg, params = setup
-        dcfg, dparams = draft_setup
-    rng = np.random.RandomState(67)
-    prompts = [rng.randint(0, cfg.vocab_size, size=n).astype(np.int32)
-               for n in (3, 8, 13, 19, 16, 5)]
-    mk = lambda: [Request(prompt=p, max_new_tokens=2 + (i % 5))
-                  for i, p in enumerate(prompts)]
-    kw = dict(rows=4, max_len=96, page_size=16, prefill_bucket=16)
-    if variant == "sampled":
-        kw.update(temperature=0.8, top_k=20, rng=jax.random.PRNGKey(3))
-    elif variant == "chunked":
-        kw.update(prefill_chunk=8)
-    elif variant == "prefix":
-        kw.update(prefix=rng.randint(0, cfg.vocab_size,
-                                     size=13).astype(np.int32))
-    elif variant == "mesh":
-        kw.update(mesh=_mesh({"dp": 2, "tp": 2}))
-    elif variant == "spec_mesh":
-        kw.update(mesh=_mesh({"dp": 2, "tp": 2}), draft_cfg=dcfg,
-                  draft_params=dparams, n_draft=3)
-    elif variant == "spec":
-        kw.update(draft_cfg=dcfg, draft_params=dparams, n_draft=3)
-    elif variant == "spec_sampled":
-        kw.update(draft_cfg=dcfg, draft_params=dparams, n_draft=3,
-                  temperature=0.8, top_k=20, rng=jax.random.PRNGKey(9))
-    elif variant in ("stop", "spec_stop"):
-        if variant == "spec_stop":
-            kw.update(draft_cfg=dcfg, draft_params=dparams, n_draft=4)
-        # Find a token each prompt actually emits so stops trigger.
-        probe = ContinuousBatcher(cfg, params, **kw)
-        outs = {c.rid: c.tokens for c in probe.run(mk())}
-        stops = {rid: t[min(1, len(t) - 1)] for rid, t in outs.items()}
-        mk = lambda: [Request(prompt=p, max_new_tokens=2 + (i % 5),
-                              stop_token=stops[i])
-                      for i, p in enumerate(prompts)]
-    if variant == "staggered":
-        # Real staggering: fewer rows than requests forces mid-flight
-        # admission into freed rows, and the lazy pull is asserted.
-        kw["rows"] = 2
-
-        def feed(reqs, done):
-            for r in reqs:
-                assert len(done) <= len(reqs)   # pull stays lazy
-                yield r
-    else:
-        feed = lambda reqs, done: iter(reqs)
-    plain = ContinuousBatcher(cfg, params, **kw)
-    want = {}
-    for c in plain.run(feed(mk(), want)):
-        want[c.rid] = c.tokens
-    ob = ContinuousBatcher(cfg, params, overlap=True, **kw)
-    got = {}
-    for c in ob.run(feed(mk(), got)):
-        got[c.rid] = c.tokens
-    assert got == want
-    assert ob._inflight is None             # loop drained
-    for side in filter(None, (ob.t_side, ob.d_side)):
-        assert side.alloc.rows == {}        # nothing leaked
-
-
-def test_overlap_speculative_perfect_draft(setup):
-    """overlap x speculative with a PERFECT draft: acceptance rate is
-    exactly 1.0 and outputs equal the offline reference — the
-    device-carried position/step stream stays consistent through full
-    (k+1)-token commits round after round."""
-    cfg, params = setup
-    b = ContinuousBatcher(cfg, params, rows=1, max_len=64, page_size=16,
-                          prefill_bucket=16, draft_cfg=cfg,
-                          draft_params=params, n_draft=3, overlap=True)
-    req = Request(prompt=_prompts(cfg, 1, seed=61)[0], max_new_tokens=13)
-    done = list(b.run([req]))
-    assert done[0].tokens == _offline(cfg, params, req)
-    assert b.acceptance_rate == 1.0
-    # Exactly the minimal retired-round count — the overshoot dispatch
-    # (issued before the quota finish surfaced) must never be retired
-    # into the counters.
-    assert b.spec_rounds == -(-(13 - 1) // (3 + 1))
-    assert b.alloc.rows == {}
-
-
 # -- pipelined device-resident decode (pipeline_depth=1) --------------------
 
 
 @pytest.mark.parametrize("variant", [
     "base", "staggered", "stop", "sampled", "chunked", "multistep",
-    "multistep_stop", "int8",
+    "multistep_stop", "int8", "prefix",
 ])
 def test_pipelined_batcher_token_identical(setup, variant):
     """pipeline_depth=1 (block N+1 dispatched from the DEVICE-resident
@@ -616,7 +521,8 @@ def test_pipelined_batcher_token_identical(setup, variant):
     fail the rid-checked ticket; sampled (rid, step) key folds are
     unchanged; chunked prefill flips and mid-stream re-admissions
     re-enter through the host-merge mask; the int8 pool pair compares
-    int8-to-int8."""
+    int8-to-int8; a static shared ``prefix`` offsets every carried
+    position."""
     cfg, params = setup
     rng = np.random.RandomState(71)
     prompts = [rng.randint(0, cfg.vocab_size, size=n).astype(np.int32)
@@ -632,6 +538,9 @@ def test_pipelined_batcher_token_identical(setup, variant):
         kw.update(multi_step=4)
     elif variant == "int8":
         kw.update(quantized_cache=True)
+    elif variant == "prefix":
+        kw.update(prefix=rng.randint(0, cfg.vocab_size,
+                                     size=13).astype(np.int32))
     if variant in ("stop", "multistep_stop"):
         # Find a token each prompt actually emits so stops trigger (and
         # land mid-block in the multistep case).
@@ -701,10 +610,9 @@ def test_pipelined_batcher_token_identical_heavy(setup, mesh_setup,
 def test_pipelined_spec_bypass_reason_and_validation(setup, draft_setup):
     """Speculative decoding BYPASSES pipelining explicitly — the
     recorded reason makes the bypass observable (like
-    prefix_cache_bypass_reason) and the spec loop runs unchanged;
-    overlap=True + pipeline_depth=1 is a recorded BYPASS now (the
-    pipelined carry already double-buffers, so overlap collapses),
-    and depths outside {0, 1} stay rejected."""
+    prefix_cache_bypass_reason) and the spec loop runs unchanged; a
+    plain pipelined batcher is lagged, hence not suspendable; and
+    depths outside {0, 1} stay rejected."""
     cfg, params = setup
     dcfg, dparams = draft_setup
     b = ContinuousBatcher(cfg, params, rows=2, max_len=64, page_size=16,
@@ -722,23 +630,42 @@ def test_pipelined_spec_bypass_reason_and_validation(setup, draft_setup):
     want = {c.rid: c.tokens for c in plain.run(list(reqs))}
     got = {c.rid: c.tokens for c in b.run(list(reqs))}
     assert got == want
-    ov = ContinuousBatcher(cfg, params, rows=2, max_len=64, page_size=16,
-                           prefill_bucket=16, overlap=True,
-                           pipeline_depth=1)
-    assert ov.overlap_bypass_reason == "pipelined decode carry"
-    assert ov.overlap is False and ov._pipelined
+    pb = ContinuousBatcher(cfg, params, rows=2, max_len=64, page_size=16,
+                           prefill_bucket=16, pipeline_depth=1)
+    assert pb._pipelined and pb.pipeline_bypass_reason is None
     # The pipelined carry still lags the host view: not suspendable.
-    assert ov.suspend_bypass_reason == "lagged decode carry"
-    assert not ov.preemptible
+    assert pb.suspend_bypass_reason == "lagged decode carry"
+    assert not pb.preemptible
     # Greedy speculative decode is lossless, so the spec `want` doubles
     # as the plain-greedy ground truth the pipelined run must match.
-    got = {c.rid: c.tokens for c in ov.run(
+    got = {c.rid: c.tokens for c in pb.run(
         [Request(prompt=p, max_new_tokens=4)
          for p in _prompts(cfg, 3, seed=77)])}
     assert got == want
     with pytest.raises(ValueError, match="pipeline_depth"):
         ContinuousBatcher(cfg, params, rows=2, max_len=64, page_size=16,
                           pipeline_depth=2)
+
+
+def test_one_lag_policy_overlap_is_gone(setup):
+    """``pipeline_depth`` is the batcher's ONE lag policy: the former
+    ``overlap=`` argument gets Python's own TypeError (no shim, no
+    alias), the tick's modes are sync / pipelined / spec (and fused),
+    and the serve loop has four tick loops."""
+    import inspect
+
+    cfg, params = setup
+    with pytest.raises(TypeError, match="overlap"):
+        ContinuousBatcher(cfg, params, overlap=True)
+    sig = inspect.signature(ContinuousBatcher.__init__)
+    assert "overlap" not in sig.parameters
+    assert sorted(n for n in vars(ContinuousBatcher)
+                  if n.startswith("_step")) == [
+        "_step", "_step_fused", "_step_pipelined", "_step_spec"]
+    b = ContinuousBatcher(cfg, params, rows=2, max_len=64, page_size=16,
+                          prefill_bucket=16)
+    assert b._mode == "sync" and not hasattr(b, "overlap")
+    assert not hasattr(b, "overlap_bypass_reason")
 
 
 # -- ahead-of-time warmup ---------------------------------------------------
@@ -992,16 +919,25 @@ def test_pool_too_small_raises_not_hangs(setup):
         list(batcher.run([req]))
 
 
-def test_abandoned_run_releases_pages(setup):
+#: Both settings of the batcher's one lag policy: the host reads
+#: tokens in step, or one dispatch behind through the device carry.
+LAG = pytest.mark.parametrize("lag", [{}, {"pipeline_depth": 1}],
+                              ids=["sync", "pipelined"])
+
+
+@LAG
+def test_abandoned_run_releases_pages(setup, lag):
     """Breaking out of run() mid-stream must not leak in-flight rows'
-    pages; the batcher stays usable for a fresh run."""
+    pages (nor, pipelined, the block in flight and its device carry);
+    the batcher stays usable for a fresh run."""
     cfg, params = setup
     mk = lambda: [Request(prompt=p, max_new_tokens=8)
                   for p in _prompts(cfg, 6, seed=13)]
     batcher = ContinuousBatcher(cfg, params, rows=3, max_len=64,
-                                page_size=16, prefill_bucket=16)
+                                page_size=16, prefill_bucket=16, **lag)
     for c in batcher.run(mk()):
         break               # abandon with rows still decoding
+    assert batcher._inflight is None and batcher._pipe_carry is None
     assert batcher.alloc.rows == {}
     assert batcher.alloc.free_count() == batcher.n_pages - 1  # sink stays
     done = list(batcher.run(mk()))
@@ -1112,7 +1048,7 @@ def test_tpu_shaped_serving_geometry(setup):
 def test_int8_draft_pool_composes(setup, draft_setup):
     """draft_quantized_cache=True serves draft proposals from an int8
     page pool (halving draft HBM); outputs stay valid and the combo
-    with an int8 TARGET pool and the overlap loop also runs."""
+    with an int8 TARGET pool also runs."""
     cfg, params = setup
     dcfg, dparams = draft_setup
     reqs = lambda: [Request(prompt=p, max_new_tokens=4)
@@ -1126,12 +1062,12 @@ def test_int8_draft_pool_composes(setup, draft_setup):
     for c in done.values():
         assert all(0 <= t < cfg.vocab_size for t in c.tokens)
     assert b.d_side.alloc.rows == {}
-    # Full quantized stack: int8 target + int8 draft + overlap.
+    # Full quantized stack: int8 target + int8 draft.
     b2 = ContinuousBatcher(cfg, params, rows=2, max_len=64, page_size=16,
                            prefill_bucket=16, draft_cfg=dcfg,
                            draft_params=dparams, n_draft=3,
                            quantized_cache=True,
-                           draft_quantized_cache=True, overlap=True)
+                           draft_quantized_cache=True)
     assert len(list(b2.run(reqs()))) == 4
 
 
@@ -1151,7 +1087,7 @@ def test_int8_kv_pool_composes(setup):
 
 @pytest.mark.parametrize("variant", [
     "base", "staggered", "stop", "sampled", "chunked", "prefix", "mesh",
-    "overlap", "overlap_stop", "overlap_mesh",
+    "pipelined", "pipelined_stop", "pipelined_mesh",
 ])
 @pytest.mark.parametrize("k", [2, 4])
 def test_multistep_batcher_token_identical(setup, mesh_setup, variant, k):
@@ -1161,8 +1097,8 @@ def test_multistep_batcher_token_identical(setup, mesh_setup, variant, k):
     endings mid-block discard the rest of the block, in-block overshoot
     writes stay inside the reservation clamp or land on sink columns,
     sampled keys fold per (rid, step) exactly as before, and the mesh +
-    overlap paths compose."""
-    if variant in ("mesh", "overlap_mesh"):
+    pipelined (pipeline_depth=1) paths compose."""
+    if variant in ("mesh", "pipelined_mesh"):
         cfg, params, _, _ = mesh_setup
     else:
         cfg, params = setup
@@ -1180,11 +1116,11 @@ def test_multistep_batcher_token_identical(setup, mesh_setup, variant, k):
     elif variant == "prefix":
         kw.update(prefix=rng.randint(0, cfg.vocab_size,
                                      size=13).astype(np.int32))
-    elif variant in ("mesh", "overlap_mesh"):
+    elif variant in ("mesh", "pipelined_mesh"):
         mkw.update(mesh=_mesh({"dp": 2, "tp": 2}))
-    if variant.startswith("overlap"):
-        mkw.update(overlap=True)
-    if variant in ("stop", "overlap_stop"):
+    if variant.startswith("pipelined"):
+        mkw.update(pipeline_depth=1)
+    if variant in ("stop", "pipelined_stop"):
         probe = ContinuousBatcher(cfg, params, **kw)
         outs = {c.rid: c.tokens for c in probe.run(mk())}
         stops = {rid: t[min(1, len(t) - 1)] for rid, t in outs.items()}
@@ -1208,7 +1144,7 @@ def test_multistep_batcher_token_identical(setup, mesh_setup, variant, k):
     got = {}
     for c in mb.run(feed(mk(), got)):
         got[c.rid] = c.tokens
-    if variant in ("mesh", "overlap_mesh"):
+    if variant in ("mesh", "pipelined_mesh"):
         for rid in want:
             _assert_tokens_match_modulo_ties(
                 cfg, params, kw.get("prefix"), prompts[rid], got[rid],
@@ -1233,18 +1169,16 @@ def test_multistep_validation(setup, draft_setup):
     dcfg, dparams = draft_setup
     with pytest.raises(ValueError, match="multi_step"):
         ContinuousBatcher(cfg, params, multi_step=0)
-    # spec+multi_step COMPOSES synchronously now: R in-graph rounds per
-    # dispatch, R = ceil(multi_step / (n_draft+1)).
+    # spec+multi_step COMPOSES: R in-graph rounds per dispatch,
+    # R = ceil(multi_step / (n_draft+1)) — asked for a lagged carry or
+    # not (the pipelined carry has no speculative form).
     kw = dict(rows=2, max_len=64, page_size=16, draft_cfg=dcfg,
               draft_params=dparams, n_draft=3)
-    b = ContinuousBatcher(cfg, params, multi_step=8, **kw)
-    assert b.multi_step_bypass_reason is None
-    assert b._spec_rounds == 2
-    # ... but under speculative overlap the round carry supersedes it.
-    ov = ContinuousBatcher(cfg, params, multi_step=8, overlap=True, **kw)
-    assert ov.multi_step_bypass_reason == \
-        "speculative overlap round carry"
-    assert ov._spec_rounds == 1
+    for pd in (0, 1):
+        b = ContinuousBatcher(cfg, params, multi_step=8,
+                              pipeline_depth=pd, **kw)
+        assert b._spec_rounds == 2 and not b._pipelined
+        assert not hasattr(b, "multi_step_bypass_reason")
 
 
 def test_spec_multistep_token_identical(setup, draft_setup):
@@ -1578,10 +1512,10 @@ def test_prefix_cache_with_chunked_prefill(setup):
     assert warm.prefix_cache_stats()["hits"] >= st["hits"] + 5
 
 
-def test_prefix_cache_with_overlap_and_multistep(setup):
+def test_prefix_cache_with_pipelined_and_multistep(setup):
     cfg, params = setup
     kw = dict(rows=2, max_len=96, page_size=16, prefill_bucket=16,
-              overlap=True, multi_step=2)
+              pipeline_depth=1, multi_step=2)
     cold = ContinuousBatcher(cfg, params, **kw)
     warm = ContinuousBatcher(cfg, params, prefix_cache_pages=8, **kw)
     want = _tokens_in_order(cold, _shared_prefix_reqs(cfg, 5, new=6))
@@ -2033,20 +1967,23 @@ def test_deadline_expired_arrival_shed_before_prefill(setup):
     assert b.deadline_cancels == 1
 
 
-def test_deadline_cancels_resident_row_and_frees_slot(setup):
+@LAG
+def test_deadline_cancels_resident_row_and_frees_slot(setup, lag):
     """THE in-batcher deadline acceptance, rows=1: a resident decoding
     row whose deadline passes is cancelled like a finished one — pages
     freed immediately, Expired yielded — and the next request admits
-    into the freed slot and completes exactly.  The expiry is forced
-    deterministically (the deadline attribute is host state the loop
-    re-reads every tick), not timed."""
+    into the freed slot and completes exactly (pipelined: the cancelled
+    row's block in flight fails the rid-checked ticket, and the slot's
+    next tenant enters the carry from host values).  The expiry is
+    forced deterministically (the deadline attribute is host state the
+    loop re-reads every tick), not timed."""
     import threading
     import time as _time
 
     from tfmesos_tpu.serving import Expired
 
     cfg, params = setup
-    b = ContinuousBatcher(cfg, params, rows=1)
+    b = ContinuousBatcher(cfg, params, rows=1, **lag)
     ps = _prompts(cfg, 2, seed=6)
     doomed = Request(prompt=ps[0], max_new_tokens=64,
                      deadline_ms=3_600_000.0)      # far future, for now
@@ -2178,12 +2115,14 @@ def test_batcher_trace_events_and_flight_recorder(setup):
 # -- per-token incremental streaming (Request.on_tokens) ---------------------
 
 
-def test_streaming_callback_chunks_match_stream(setup):
+@LAG
+def test_streaming_callback_chunks_match_stream(setup, lag):
     """Request.on_tokens receives contiguous, correctly-offset chunks
     whose concatenation is a PREFIX of the completion (rows finishing
     inside a block keep their tail for the Completion), token streams
     byte-identical to non-streaming, and a raising callback costs its
-    stream, never the request."""
+    stream, never the request.  Pipelined, the chunks arrive one block
+    late (at retire) and stay contiguous."""
     cfg, params = setup
     reqs = [Request(prompt=p, max_new_tokens=5 + (i % 6))
             for i, p in enumerate(_prompts(cfg, 6, seed=7))]
@@ -2203,7 +2142,7 @@ def test_streaming_callback_chunks_match_stream(setup):
         raise RuntimeError("broken consumer")
     reqs[3].on_tokens = boom
     batcher = ContinuousBatcher(cfg, params, rows=2, max_len=64,
-                                page_size=16, prefill_bucket=16)
+                                page_size=16, prefill_bucket=16, **lag)
     done = {c.rid: c for c in batcher.run(reqs)}
     assert len(done) == len(reqs)
     for rid, req in enumerate(reqs):
@@ -2454,17 +2393,18 @@ def test_spec_session_park_resume_token_identical(setup):
 
 
 def test_session_park_resume_lagged_modes(setup):
-    """PR 13 follow-up regression: the lagged decode modes
-    (overlap / pipeline_depth=1) used to silently MISS parking — their
-    host view overshoots at finish — so every next turn re-prefilled
-    cold.  The export now clamps to the committed boundary
-    (_export_row(final=True)), so parking works in EVERY mode and
-    resumed turns stay token-identical to the cold full-history
-    prefill."""
+    """PR 13 follow-up regression: the lagged decode loop
+    (pipeline_depth=1, alone and with multi_step blocks) used to
+    silently MISS parking — its host view overshoots at finish — so
+    every next turn re-prefilled cold.  The export now clamps to the
+    committed boundary (_export_row(final=True)), so parking works in
+    EVERY mode and resumed turns stay token-identical to the cold
+    full-history prefill."""
     cfg, params = setup
     base = dict(rows=2, max_len=128, page_size=16, prefill_bucket=16)
     cold = ContinuousBatcher(cfg, params, **base)
-    for mode_kw in ({"pipeline_depth": 1}, {"overlap": True}):
+    for mode_kw in ({"pipeline_depth": 1},
+                    {"pipeline_depth": 1, "multi_step": 2}):
         tier = _tier()
         warm = ContinuousBatcher(cfg, params, kv_tier=tier, **base,
                                  **mode_kw)
@@ -2662,21 +2602,28 @@ def test_bypass_registry_audit(setup):
     itself uses, and fail on any value outside the documented
     allowlist — the burn-down is enforceable, not aspirational.  Also
     pins the burn-down itself: 'speculative decoding' is no longer
-    reachable in the prefix_cache or kv_tier registries."""
+    reachable in the prefix_cache or kv_tier registries, and the
+    'overlap' / 'multi_step' registries are gone with the loops that
+    needed them (PR 30: one lag policy, pipeline_depth)."""
     import itertools
 
     from tfmesos_tpu.serving import (BYPASS_ALLOWLIST,
                                      compute_bypass_reasons)
 
+    assert set(BYPASS_ALLOWLIST) == {
+        "prefix_cache", "kv_tier", "pipeline", "suspend", "fused_prefill",
+        "speculative", "kv_export"}
+    for gone in ("overlap", "multi_step"):
+        with pytest.raises(TypeError, match=gone):
+            compute_bypass_reasons(**{gone: 1})
     reachable = {k: set() for k in BYPASS_ALLOWLIST}
     eva_reach = {k: set() for k in BYPASS_ALLOWLIST}
-    for eva, spec_on, shards, q, dq, pd, ov, ms in itertools.product(
+    for eva, spec_on, shards, q, dq, pd in itertools.product(
             (False, True), (False, True), (1, 2, 4), (False, True),
-            (False, True), (0, 1), (False, True), (1, 2, 8)):
+            (False, True), (0, 1)):
         reasons = compute_bypass_reasons(
             speculative=spec_on, n_shards=shards, quantized_cache=q,
-            draft_quantized_cache=dq, pipeline_depth=pd, overlap=ov,
-            multi_step=ms, eva=eva)
+            draft_quantized_cache=dq, pipeline_depth=pd, eva=eva)
         assert set(reasons) == set(BYPASS_ALLOWLIST)
         for reg, val in reasons.items():
             if val is not None:
@@ -2689,37 +2636,36 @@ def test_bypass_registry_audit(setup):
             f"bypass (BYPASS_ALLOWLIST is the contract)")
     # EVA attention (pages of summaries and one window, PR 28): every
     # surface that shares, moves or snapshots pages by position is closed
-    # with ONE reason, the lagged loops with another; nothing of it is
+    # with ONE reason, the lagged loop with another; nothing of it is
     # reachable without EVA, whose registries read as before below.
     for reg in ("prefix_cache", "kv_tier", "suspend", "speculative",
                 "kv_export"):
         assert "eva summary pages" in eva_reach[reg], reg
         assert "eva summary pages" not in reachable[reg], reg
-    assert "eva window close" in eva_reach["overlap"] & eva_reach["pipeline"]
+    assert "eva window close" in eva_reach["pipeline"]
+    assert compute_bypass_reasons(
+        eva=True, pipeline_depth=1)["pipeline"] == "eva window close"
+    assert reachable["pipeline"] == {"speculative decoding"}
     assert not reachable["speculative"] and not reachable["kv_export"]
-    plain_eva = compute_bypass_reasons(eva=True, multi_step=4)
-    assert plain_eva["multi_step"] is None and plain_eva["overlap"] is None
     # The burn-down, pinned: spec composes with the prefix cache and
     # the KV tier now.
     assert "speculative decoding" not in reachable["prefix_cache"]
     assert "speculative decoding" not in reachable["kv_tier"]
-    # The former constructor REJECTIONS are enumerable mode gates now:
-    # each is reachable with exactly its documented reason, and
-    # spec+multi_step (sync) reaches NO reason — it composes.
-    assert reachable["overlap"] == {"pipelined decode carry"}
-    assert reachable["multi_step"] == {"speculative overlap round carry"}
+    # Suspend-under-lag is an enumerable mode gate, reachable with
+    # exactly its documented reasons; a speculative batcher asked for
+    # the pipelined carry serves synchronously, so it stays suspendable.
     assert reachable["suspend"] == {"mesh data sharding",
                                     "lagged decode carry"}
-    sync_ms = compute_bypass_reasons(speculative=True, multi_step=8)
-    assert sync_ms["multi_step"] is None
+    assert compute_bypass_reasons(speculative=True,
+                                  pipeline_depth=1)["suspend"] is None
     # Fused prefill+decode ticks: every documented reason reachable,
     # nothing else; int8 / multi_step / prefix-cache configs compose
     # (reason None), and the lagged + sharded + spec modes bypass.
     assert reachable["fused_prefill"] == {"mesh data sharding",
                                           "speculative decoding",
                                           "lagged decode carry"}
-    assert compute_bypass_reasons(quantized_cache=True,
-                                  multi_step=8)["fused_prefill"] is None
+    assert compute_bypass_reasons(
+        quantized_cache=True)["fused_prefill"] is None
     # And __init__ really uses the helper (spot-check: a live batcher's
     # attributes equal the helper's output for its config).
     cfg, params = setup
@@ -2732,8 +2678,6 @@ def test_bypass_registry_audit(setup):
     assert b.prefix_cache_bypass_reason == want["prefix_cache"]
     assert b.kv_tier_bypass_reason == want["kv_tier"]
     assert b.pipeline_bypass_reason == want["pipeline"]
-    assert b.overlap_bypass_reason == want["overlap"]
-    assert b.multi_step_bypass_reason == want["multi_step"]
     assert b.suspend_bypass_reason == want["suspend"]
     # The suspend gate IS the preemptible property.
     assert b.preemptible == (b.suspend_bypass_reason is None)
@@ -2745,10 +2689,10 @@ def test_bypass_registry_audit(setup):
                               if k != "prefix_cache_pages"})
     assert bf.fused_prefill_bypass_reason is None
     bs = ContinuousBatcher(cfg, params, fused_prefill=True,
-                           prefill_chunk=16, overlap=True,
+                           prefill_chunk=16, pipeline_depth=1,
                            rows=2, max_len=64, page_size=16,
                            prefill_bucket=16)
-    want = compute_bypass_reasons(overlap=True)
+    want = compute_bypass_reasons(pipeline_depth=1)
     assert bs.fused_prefill_bypass_reason == want["fused_prefill"] \
         == "lagged decode carry"
 
@@ -3079,13 +3023,13 @@ TICK_PHASES = {"batcher.pull", "batcher.admit", "batcher.prefill_sync",
 #: the mode at least one block must carry)
 TICK_MODES = {
     "sync": ({}, {"sync"}, "sync"),
-    "overlap": ({"overlap": True}, {"overlap"}, "overlap"),
     "pipelined": ({"pipeline_depth": 1}, {"pipelined"}, "pipelined"),
+    "pipelined_multistep": ({"pipeline_depth": 1, "multi_step": 2},
+                            {"pipelined"}, "pipelined"),
     "chunked": ({"prefill_chunk": 8}, {"sync"}, "sync"),
     "fused": ({"prefill_chunk": 8, "fused_prefill": True},
               {"sync", "fused"}, "fused"),
     "spec": ("spec", {"spec"}, "spec"),
-    "spec_overlap": ("spec_overlap", {"spec_overlap"}, "spec_overlap"),
     "import": ({}, {"sync"}, "sync"),
     "session": ("session", {"sync"}, "sync"),
 }
@@ -3134,10 +3078,9 @@ def test_tick_records_every_step_mode(setup, draft_setup, mode):
     cfg, params = setup
     extra, modes, must = TICK_MODES[mode]
     kw = dict(rows=2, max_len=64, page_size=16, prefill_bucket=16)
-    if extra in ("spec", "spec_overlap"):
+    if extra == "spec":
         dcfg, dparams = draft_setup
-        kw.update(draft_cfg=dcfg, draft_params=dparams, n_draft=3,
-                  overlap=extra == "spec_overlap")
+        kw.update(draft_cfg=dcfg, draft_params=dparams, n_draft=3)
     elif extra == "session":
         kw.update(kv_tier=_tier(), max_len=128)
     else:
@@ -3169,6 +3112,8 @@ def test_tick_records_every_step_mode(setup, draft_setup, mode):
         done = list(batcher.run(reqs))
     assert done
     recs = _check_ticks(batcher, traces, modes, must)
+    assert {r["k"] for r in recs if r["name"] == "decode.block"} == {
+        batcher.n_draft + 1 if extra == "spec" else batcher.multi_step}
     assert sum(r["admitted"] for r in recs) >= len(traces)
     if mode in ("import", "session"):
         assert any(r["admitted"] for r in recs)

@@ -78,13 +78,12 @@ _KICK = object()
 #: reachable :class:`ContinuousBatcher` config through
 #: :func:`compute_bypass_reasons` and fails on any value not listed
 #: here, so a new bypass cannot land silently and a removed one cannot
-#: regress.  History: "speculative decoding" was burned out of the
-#: ``prefix_cache`` and ``kv_tier`` registries (spec rows are
-#: first-class citizens of the paged-KV machinery now), and the former
-#: constructor REJECTIONS became composition or enforced entries here:
-#: spec+multi_step now COMPOSES (R spec rounds per dispatch — see
-#: ``_make_spec_round``), overlap+pipeline and suspend-under-lag are
-#: enforced bypasses below.
+#: regress.  A reason is a property of the design: "speculative
+#: decoding" was burned out of the ``prefix_cache`` and ``kv_tier``
+#: registries (spec rows are first-class citizens of the paged-KV
+#: machinery now), spec+multi_step COMPOSES (R spec rounds per
+#: dispatch — see ``_make_spec_round``), and suspend-under-lag is an
+#: enforced bypass below.
 BYPASS_ALLOWLIST = {
     # An int8 pool's tail-recompute path (chunk writer) is not
     # bit-stable against the cold fused prefill, so shared pages could
@@ -94,7 +93,7 @@ BYPASS_ALLOWLIST = {
     # row's pages hold summaries and one window's exact entries, not 64
     # consecutive positions each: nothing that shares, moves or snapshots
     # pages by position carries that layout yet.  One reason string for
-    # every surface it closes, "eva summary pages"; the lagged loops are
+    # every surface it closes, "eva summary pages"; the lagged loop is
     # closed by "eva window close", because the host closes a window
     # between blocks from its own up-to-date view of every row.)
     "prefix_cache": ("quantized kv cache", "eva summary pages"),
@@ -102,34 +101,16 @@ BYPASS_ALLOWLIST = {
     # move), and the int8 tail recompute above breaks resume==cold.
     "kv_tier": ("mesh data sharding", "quantized kv cache",
                 "eva summary pages"),
-    # Speculative overlap already carries its round state on device —
-    # measured equal-or-better than the pipelined carry would be on
-    # the same workload (bench_serving_spec_compose's overlap arm vs
-    # bench_serving_pipeline: both remove the per-block host sync, and
-    # a spec round retires up to n_draft+1 tokens per sync where the
-    # pipelined carry retires multi_step) — so pipeline_depth on a
-    # speculative batcher records this instead of double-carrying.
+    # The pipelined carry (tokens, positions, steps on device, one
+    # block of lag) has no speculative form: a round's commit counts
+    # decide the next round's positions, and _step_spec reads them on
+    # the host.  A speculative batcher asked for pipeline_depth=1
+    # serves synchronously and records the reason.
     "pipeline": ("speculative decoding", "eva window close"),
-    # pipeline_depth=1's device-resident carry already removes the
-    # host round-trip overlap double-buffers away (measured: the
-    # pipelined inter-token p50 is asserted strictly below the
-    # synchronous loop's in bench_serving_pipeline, the same sync
-    # overlap hides), so overlap under an active pipeline is redundant
-    # — recorded, not rejected.
-    "overlap": ("pipelined decode carry", "eva window close"),
-    # Speculative overlap rounds already fuse n_draft+1 tokens per
-    # dispatch AND hide the host sync behind the next round
-    # (bench_serving_spec_compose measures the round itself at one
-    # verify launch per layer); folding extra sync rounds under the
-    # in-graph carry would lag commits R rounds behind the host for
-    # no additional sync savings, so multi_step collapses to the
-    # round's natural width there.
-    "multi_step": ("speculative overlap round carry",),
     # Per-row suspend/export needs a host-synchronous row snapshot;
-    # overlap/pipelined modes carry in-flight device state the host
-    # view lags one block behind (the lag IS the measured win:
-    # bench_serving_pipeline's p50 gap), and mesh data shards pin
-    # pages locally like the kv_tier/export surface.
+    # the pipelined carry holds in-flight device state the host view
+    # lags one block behind, and mesh data shards pin pages locally
+    # like the kv_tier/export surface.
     "suspend": ("mesh data sharding", "lagged decode carry",
                 "eva summary pages"),
     # Stall-free fused prefill+decode ticks (one dispatch covers the
@@ -139,8 +120,9 @@ BYPASS_ALLOWLIST = {
     # is the verify program — its chunk writes advance the DRAFT pool
     # in lockstep, a second fused surface the single-program layout
     # does not cover yet (burn-down: fold the chunk writes into
-    # _make_spec_round's body); lagged modes retire a block behind and
-    # a chunk slot's first-token sample is host-synchronous by design.
+    # _make_spec_round's body); the pipelined loop retires a block
+    # behind and a chunk slot's first-token sample is host-synchronous
+    # by design.
     "fused_prefill": ("mesh data sharding", "speculative decoding",
                       "lagged decode carry"),
     # REFUSALS, not bypasses: a batcher asked for these raises with the
@@ -157,8 +139,6 @@ def compute_bypass_reasons(*, speculative: bool = False,
                            quantized_cache: bool = False,
                            draft_quantized_cache: bool = False,
                            pipeline_depth: int = 0,
-                           overlap: bool = False,
-                           multi_step: int = 1,
                            eva: bool = False
                            ) -> Dict[str, Optional[str]]:
     """The ``*_bypass_reason`` values a :class:`ContinuousBatcher`
@@ -169,8 +149,8 @@ def compute_bypass_reasons(*, speculative: bool = False,
     quant = quantized_cache or (speculative and draft_quantized_cache)
     out: Dict[str, Optional[str]] = {
         "prefix_cache": None, "kv_tier": None, "pipeline": None,
-        "overlap": None, "multi_step": None, "suspend": None,
-        "fused_prefill": None, "speculative": None, "kv_export": None}
+        "suspend": None, "fused_prefill": None, "speculative": None,
+        "kv_export": None}
     if eva:
         out["prefix_cache"] = out["speculative"] = out["kv_export"] = \
             "eva summary pages"
@@ -186,28 +166,19 @@ def compute_bypass_reasons(*, speculative: bool = False,
         out["pipeline"] = "speculative decoding"
     elif pipeline_depth and eva:
         out["pipeline"] = "eva window close"
-    # Effective lag modes AFTER the cross-bypasses above: overlap
-    # yields to an ACTIVE pipeline (non-spec), and the pipeline itself
-    # yields to speculation.
+    # The one lag mode in effect AFTER the bypasses above.
     pipelined = bool(pipeline_depth) and out["pipeline"] is None
-    if pipelined and overlap:
-        out["overlap"] = "pipelined decode carry"
-    elif overlap and eva:
-        out["overlap"] = "eva window close"
-    overlap_eff = overlap and out["overlap"] is None
-    if speculative and multi_step > 1 and overlap_eff:
-        out["multi_step"] = "speculative overlap round carry"
     if n_shards != 1:
         out["suspend"] = "mesh data sharding"
     elif eva:
         out["suspend"] = "eva summary pages"
-    elif overlap_eff or pipelined:
+    elif pipelined:
         out["suspend"] = "lagged decode carry"
     if n_shards != 1:
         out["fused_prefill"] = "mesh data sharding"
     elif speculative:
         out["fused_prefill"] = "speculative decoding"
-    elif overlap_eff or pipelined:
+    elif pipelined:
         out["fused_prefill"] = "lagged decode carry"
     return out
 
@@ -794,10 +765,10 @@ class _PagedSide:
         columns: the plain cached table when every active row
         participates; otherwise a masked variant with non-participating
         rows' entries pinned to the sink (still-filling rows' chunked
-        prefill owns their pages; overlap mode's quota-finished rows
-        await retire).  Cached keyed on (masked set, width) until the
-        allocation changes — steady-state decode must neither re-upload
-        nor re-slice the table every block."""
+        prefill owns their pages; the pipelined loop's quota-finished
+        rows await retire).  Cached keyed on (masked set, width) until
+        the allocation changes — steady-state decode must neither
+        re-upload nor re-slice the table every block."""
         w = self.bucket_width()
         masked = (frozenset() if len(decoding) == len(active)
                   else frozenset(r for r in active if r not in decoding))
@@ -1330,51 +1301,34 @@ class ContinuousBatcher:
     greedy outputs can differ from the unchunked batcher only by
     float-tie argmax flips.
 
-    ``overlap=True`` double-buffers the decode loop: tick t+1 is
-    dispatched BEFORE tick t's tokens are synced to the host (rows feed
-    the previous dispatch's device output straight back in), so the
-    device never idles on a per-token host round-trip — the dominant
-    serving cost when dispatch latency is high.  Stop tokens and
-    admission act one tick late (a stopped row's extra tick writes one
-    reserved position past the stop and is discarded); token streams
-    are identical to ``overlap=False``.  Composes with SPECULATIVE
-    decoding: continuing rows' token/position/step ride on device
-    (commit counts are computed in-graph), the host's view lags one
-    retire behind for page backing, and ANY ending — quota included —
-    surfaces one round late with the overshoot round's up-to-
-    ``n_draft+1`` extra positions reserved per row.
-
     ``pipeline_depth=1`` PIPELINES the decode loop with a
-    device-resident carry: where ``overlap`` still re-uploads the
-    per-row token/position/step vectors every block, the pipelined loop
-    feeds block N+1 straight from the previous dispatch's device
-    outputs (tokens, positions, AND steps stay on device; the page
-    table and the small host-merge inputs are refreshed only when
-    admission/prefill/finish actually changed the dispatch set) and
-    syncs block N's tokens one block behind via the in-flight async
-    transfer.  Host-side stop/quota detection lags one block; the
-    overshoot block's writes land inside the row's clamped reservation
-    or on sink columns — the exact mid-block-stop discard semantics
-    ``_step`` documents — so token streams are IDENTICAL to
-    ``pipeline_depth=0`` (greedy AND sampled: the (rid, step) key folds
-    are unchanged).  Composes with ``multi_step``, chunked prefill,
-    int8 pools, ``mesh``, ``prefix``, and the prefix cache; speculative
-    decoding BYPASSES explicitly (``pipeline_bypass_reason`` — its
-    overlap mode already carries state on device), and ``overlap=True``
-    plus ``pipeline_depth=1`` records ``overlap_bypass_reason`` (the
-    pipelined carry already double-buffers) with overlap collapsing to
-    off.  ``0`` preserves the synchronous loop exactly.
+    device-resident carry — the batcher's ONE lag policy: block N+1 is
+    dispatched BEFORE block N's tokens are synced to the host, fed
+    straight from the previous dispatch's device outputs (tokens,
+    positions, AND steps stay on device; the page table and the small
+    host-merge inputs are refreshed only when admission/prefill/finish
+    actually changed the dispatch set), and block N's tokens are synced
+    one block behind via the in-flight async transfer, so the device
+    never idles on a per-block host round-trip.  Host-side stop/quota
+    detection and admission act one block late; the overshoot block's
+    writes land inside the row's clamped reservation or on sink columns
+    — the exact mid-block-stop discard semantics ``_step`` documents —
+    so token streams are IDENTICAL to ``pipeline_depth=0`` (greedy AND
+    sampled: the (rid, step) key folds are unchanged).  Composes with
+    ``multi_step``, chunked prefill, int8 pools, ``mesh``, ``prefix``,
+    and the prefix cache; speculative decoding BYPASSES explicitly
+    (``pipeline_bypass_reason``: the carry has no speculative form, a
+    speculative batcher serves synchronously).  ``0`` preserves the
+    synchronous loop exactly.
 
-    ``multi_step`` composes with speculative decoding synchronously: R
-    = ceil(multi_step / (n_draft+1)) rounds fuse into ONE dispatch,
+    ``multi_step`` composes with speculative decoding: R =
+    ceil(multi_step / (n_draft+1)) rounds fuse into ONE dispatch,
     chained in-graph from each round's commit counts, committed
-    round-by-round on the host.  Under speculative ``overlap`` the
-    round carry supersedes it (``multi_step_bypass_reason``).  The
-    ``suspend`` registry gates :attr:`preemptible` the same enumerable
-    way: per-row suspend/export needs the host-synchronous single-shard
-    loop, so overlap/pipelined (lagged carry) and mesh-sharded
-    batchers record ``suspend_bypass_reason`` and requeue on
-    preemption instead of exporting.
+    round-by-round on the host.  The ``suspend`` registry gates
+    :attr:`preemptible` the same enumerable way: per-row suspend/export
+    needs the host-synchronous single-shard loop, so pipelined (lagged
+    carry) and mesh-sharded batchers record ``suspend_bypass_reason``
+    and requeue on preemption instead of exporting.
 
     :meth:`warmup` compiles every jitted entry point the configured
     mode can dispatch (admission prefill, chunk prefill, decode block
@@ -1427,10 +1381,11 @@ class ContinuousBatcher:
     completions match cold-prefill completions exactly up to float-tie
     argmax flips (the tail prefill runs cache-attention, like chunked
     prefill; bit-identical in practice on the CPU test config).
-    Composes with ``prefill_chunk``, ``overlap``, ``multi_step``,
-    ``mesh``, ``prefix``, and SPECULATIVE decoding — a spec batcher's
-    trie couples every target page with its draft-pool twin (one
-    refcount, COW on both deepest pages, twin publish after prefill),
+    Composes with ``prefill_chunk``, ``pipeline_depth``,
+    ``multi_step``, ``mesh``, ``prefix``, and SPECULATIVE decoding — a
+    spec batcher's trie couples every target page with its draft-pool
+    twin (one refcount, COW on both deepest pages, twin publish after
+    prefill),
     so a warm hit maps BOTH pools and prefills only the uncached tail
     through each side's chunk writer; ``quantized_cache`` (either
     pool's) BYPASSES sharing explicitly
@@ -1466,7 +1421,6 @@ class ContinuousBatcher:
                  draft_cfg: Optional[TransformerConfig] = None,
                  draft_params=None, n_draft: int = 4,
                  draft_n_pages: Optional[int] = None, mesh=None,
-                 overlap: bool = False,
                  draft_quantized_cache: bool = False,
                  multi_step: int = 1,
                  prefix_cache_pages: int = 0,
@@ -1498,24 +1452,20 @@ class ContinuousBatcher:
             raise ValueError(f"tokens_per_tick must be >= 1, got "
                              f"{tokens_per_tick}")
         self.multi_step = int(multi_step)
-        self.overlap = bool(overlap)
         # Pipelined device-resident decode (pipeline_depth=1): block N+1
         # is dispatched from the device-side carry — tokens, positions,
         # AND steps never round-trip to the host between blocks — and
         # block N's tokens are synced one block behind.  Speculative
-        # decoding bypasses explicitly (a round already carries its
-        # state on device under overlap=True); the recorded reason makes
-        # the bypass observable, like prefix_cache_bypass_reason.  The
-        # ``*_bypass_reason`` registries themselves are computed after
-        # the mesh parse below (the shard count participates).
+        # decoding bypasses explicitly (the carry has no speculative
+        # form); the recorded reason makes the bypass observable, like
+        # prefix_cache_bypass_reason.  The ``*_bypass_reason``
+        # registries themselves are computed after the mesh parse below
+        # (the shard count participates).
         self.pipeline_depth = int(pipeline_depth)
         self._pipe_carry = None     # device (tok, pos, step) carry
         self._pipe_host = None      # cached host-side dispatch inputs
-        # Overlap mode: (device outputs of the in-flight dispatch,
-        # {row: rid} ticket).  Speculative overlap additionally carries
-        # the device-side (positions, steps) the next round continues
-        # from — commit counts are decided in-graph, so the host's
-        # row.pos/step view lags one retire behind.
+        # The block in flight: (its device token output, {row: rid}
+        # ticket), retired one dispatch later.
         self._inflight = None
         self.cfg = cfg
         self.params = params
@@ -1549,8 +1499,7 @@ class ContinuousBatcher:
             speculative=draft_cfg is not None, n_shards=self.n_shards,
             quantized_cache=quantized_cache,
             draft_quantized_cache=draft_quantized_cache,
-            pipeline_depth=pipeline_depth,
-            overlap=overlap, multi_step=multi_step, eva=eva)
+            pipeline_depth=pipeline_depth, eva=eva)
         if eva:
             # What EVA's pages cannot do yet is refused here, before any
             # device state exists (the registries above bypass the rest).
@@ -1574,29 +1523,12 @@ class ContinuousBatcher:
                     f"prefilled window by window, then a padded tail")
         self.pipeline_bypass_reason: Optional[str] = \
             self._bypass["pipeline"]
-        # overlap+pipeline and spec-overlap+multi_step are BYPASSES
-        # now, not constructor rejections: the requested flag is
-        # recorded with its measured reason and the effective mode
-        # collapses to the carry that already covers it.
-        self.overlap_bypass_reason: Optional[str] = \
-            self._bypass["overlap"]
-        self.multi_step_bypass_reason: Optional[str] = \
-            self._bypass["multi_step"]
         self.suspend_bypass_reason: Optional[str] = \
             self._bypass["suspend"]
-        if self.overlap_bypass_reason is not None:
-            self.overlap = False
-        # Speculative multi_step>1: under overlap the round carry
-        # supersedes it (bypass above); synchronously it composes as R
-        # fused rounds per dispatch (see _make_spec_round).
-        if draft_cfg is not None:
-            if self.multi_step_bypass_reason is not None:
-                self._spec_rounds = 1
-            else:
-                self._spec_rounds = max(
-                    1, -(-self.multi_step // max(1, n_draft + 1)))
-        else:
-            self._spec_rounds = 0
+        # Speculative multi_step>1 composes as R fused rounds per
+        # dispatch (see _make_spec_round).
+        self._spec_rounds = (0 if draft_cfg is None else max(
+            1, -(-self.multi_step // max(1, n_draft + 1))))
         self.max_len = int(max_len or cfg.max_seq_len)
         if self.max_len > cfg.max_seq_len:
             raise ValueError(f"max_len ({self.max_len}) exceeds the "
@@ -1794,10 +1726,8 @@ class ContinuousBatcher:
         # this batcher's share of it (records stamped with its id).
         self.flight = FlightView(flight(TICK_COMPONENT, TICK_RING),
                                  "batcher", next(_BATCHER_IDS))
-        self._mode = ("spec_overlap" if draft_cfg is not None and self.overlap
-                      else "spec" if draft_cfg is not None
-                      else "pipelined" if self._pipelined
-                      else "overlap" if self.overlap else "sync")
+        self._mode = ("spec" if draft_cfg is not None
+                      else "pipelined" if self._pipelined else "sync")
         self._tick_n = 0
         self._tick_c0 = (0, 0.0)
         self._tick = self._tick_open(None)
@@ -1929,8 +1859,8 @@ class ContinuousBatcher:
         (a suspended request IS a KV export — a speculative batcher's
         export carries the draft pool's paired payload, so spec rows
         suspend like any other), and a host-synchronous decode loop —
-        overlap/pipelined modes carry in-flight device state the host
-        view lags behind, so their rows cannot be snapshotted between
+        the pipelined mode carries in-flight device state the host
+        view lags behind, so its rows cannot be snapshotted between
         blocks.  Non-preemptible batchers still honor
         :meth:`preempt_all`, by REQUEUEING every in-flight request
         (lossless through deterministic re-execution) instead of
@@ -2361,20 +2291,6 @@ class ContinuousBatcher:
 
             return decode_block_pipelined
 
-        if self.overlap:
-            # Double-buffered blocks: rows in the previous dispatch chain
-            # from its device-resident LAST token; the host never waits
-            # on it before dispatching the next block.
-            @partial(jax.jit, donate_argnums=1)
-            def decode_block_overlap(params, pool, table, toks, prev,
-                                     use_dev, positions, rids, steps):
-                merged = jnp.where(use_dev, prev[:, -1], toks)
-                pool, out = block(params, pool, table, merged, positions,
-                                  rids, steps)
-                return pool, self._host_read(out)
-
-            return decode_block_overlap
-
         @partial(jax.jit, donate_argnums=1)
         def decode_block(params, pool, table, toks, positions, rids, steps):
             pool, out = block(params, pool, table, toks, positions, rids,
@@ -2473,72 +2389,44 @@ class ContinuousBatcher:
             vals = jnp.where(j == a[:, None], repl[:, None], cand)
             return pool_out, dpool, vals, a + 1
 
-        if not self.overlap:
-            # multi_step>1 composes with synchronous speculation as R =
-            # ceil(multi_step/(k+1)) rounds fused in ONE dispatch: each
-            # round chains from the previous round's last-committed
-            # token/positions IN-GRAPH (the same take_along_axis chain
-            # the overlap carry uses), so the host syncs once per R
-            # rounds.  Rows that finish (stop/quota) mid-dispatch keep
-            # executing later rounds on device; their writes land on
-            # sink-clamped table columns and the host discards their
-            # tokens at commit — the same overrun argument the plain
-            # multi_step path documents at _worst_pages.
-            R = max(1, self._spec_rounds)
+        # multi_step>1 composes with speculation as R =
+        # ceil(multi_step/(k+1)) rounds fused in ONE dispatch: each
+        # round chains from the previous round's last-committed
+        # token/positions IN-GRAPH (take_along_axis over its commit
+        # counts), so the host syncs once per R rounds.  Rows that
+        # finish (stop/quota) mid-dispatch keep executing later rounds
+        # on device; their writes land on sink-clamped table columns and
+        # the host discards their tokens at commit — the same overrun
+        # argument the plain multi_step path documents at _worst_pages.
+        R = max(1, self._spec_rounds)
 
-            @partial(jax.jit, donate_argnums=(1, 3))
-            def spec_round(params, pool, dparams, dpool, table, dtable,
-                           toks, positions, rids, steps):
-                if R == 1:
-                    pool_out, dpool_out, g, counts = body(
-                        params, pool, dparams, dpool, table, dtable,
-                        toks, positions, rids, steps)
-                    return (pool_out, dpool_out, self._host_read(g),
-                            self._host_read(counts))
-                gs, ns = [], []
-                for _ in range(R):
-                    pool, dpool, g, counts = body(
-                        params, pool, dparams, dpool, table, dtable,
-                        toks, positions, rids, steps)
-                    gs.append(g)
-                    ns.append(counts)
-                    last = jnp.maximum(counts - 1, 0)
-                    toks = jnp.take_along_axis(
-                        g, last[:, None], axis=1)[:, 0]
-                    positions = positions + counts
-                    steps = steps + counts
-                # [R, rows, k+1] / [R, rows] — _step_spec commits
-                # round-by-round so quota/stop truncation stays exact.
-                return (pool, dpool, self._host_read(jnp.stack(gs)),
-                        self._host_read(jnp.stack(ns)))
-
-            return spec_round
-
-        # Overlap variant: rows that were in the PREVIOUS round continue
-        # from its DEVICE outputs — the last committed token is
-        # prev_g[r, prev_nc-1], and positions/steps advance by prev_nc,
-        # all computed in-graph (commit counts never round-trip to the
-        # host before the next dispatch).  Freshly admitted rows take
-        # host values; the merged positions/steps return as the carry
-        # for round t+1.
         @partial(jax.jit, donate_argnums=(1, 3))
-        def spec_round_overlap(params, pool, dparams, dpool, table, dtable,
-                               toks, positions, rids, steps, use_dev,
-                               prev_g, prev_nc, prev_pos, prev_steps):
-            last_idx = jnp.maximum(prev_nc - 1, 0)
-            dev_tok = jnp.take_along_axis(prev_g, last_idx[:, None],
-                                          axis=1)[:, 0]
-            toks = jnp.where(use_dev, dev_tok, toks)
-            positions = jnp.where(use_dev, prev_pos + prev_nc, positions)
-            steps = jnp.where(use_dev, prev_steps + prev_nc, steps)
-            pool_out, dpool_out, g, counts = body(
-                params, pool, dparams, dpool, table, dtable, toks,
-                positions, rids, steps)
-            return (pool_out, dpool_out, self._host_read(g),
-                    self._host_read(counts), self._host_read(positions),
-                    self._host_read(steps))
+        def spec_round(params, pool, dparams, dpool, table, dtable,
+                       toks, positions, rids, steps):
+            if R == 1:
+                pool_out, dpool_out, g, counts = body(
+                    params, pool, dparams, dpool, table, dtable,
+                    toks, positions, rids, steps)
+                return (pool_out, dpool_out, self._host_read(g),
+                        self._host_read(counts))
+            gs, ns = [], []
+            for _ in range(R):
+                pool, dpool, g, counts = body(
+                    params, pool, dparams, dpool, table, dtable,
+                    toks, positions, rids, steps)
+                gs.append(g)
+                ns.append(counts)
+                last = jnp.maximum(counts - 1, 0)
+                toks = jnp.take_along_axis(
+                    g, last[:, None], axis=1)[:, 0]
+                positions = positions + counts
+                steps = steps + counts
+            # [R, rows, k+1] / [R, rows] — _step_spec commits
+            # round-by-round so quota/stop truncation stays exact.
+            return (pool, dpool, self._host_read(jnp.stack(gs)),
+                    self._host_read(jnp.stack(ns)))
 
-        return spec_round_overlap
+        return spec_round
 
     def _make_draft_chunk(self):
         """Jitted DRAFT prompt writer over the draft's paged pool: serves
@@ -2749,23 +2637,15 @@ class ContinuousBatcher:
             # (k+1)-token chunk: its writes overshoot by up to n_draft
             # (and the draft's k+1 scan steps write the same positions).
             need_len += self.n_draft
-        if self.overlap or self._pipelined:
-            if self.draft_cfg is not None:
-                # Speculative overlap: ANY ending (quota included —
-                # commit counts are decided on device) surfaces one
-                # ROUND late, and the overshoot round writes up to
-                # n_draft+1 positions past the end.
-                need_len += self.n_draft + 1
-            elif req.stop_token is not None:
-                # A stop is detected one block late (overlap and
-                # pipelined modes alike): reserve one position
-                # past the stop so the overshoot write can land in an own
-                # page.  With multi_step > 1 the overshoot can reach K-1
-                # further positions (and quota overruns up to K-1 exist
-                # too) — those are NOT reserved here: the ensure() clamp
-                # at _Row.limit keeps allocations within this
-                # reservation, and writes past it land on sink columns.
-                need_len += 1
+        if self._pipelined and req.stop_token is not None:
+            # A stop is detected one block late: reserve one position
+            # past the stop so the overshoot write can land in an own
+            # page.  With multi_step > 1 the overshoot can reach K-1
+            # further positions (and quota overruns up to K-1 exist
+            # too) — those are NOT reserved here: the ensure() clamp
+            # at _Row.limit keeps allocations within this
+            # reservation, and writes past it land on sink columns.
+            need_len += 1
         if need_len > self.max_len:
             raise ValueError(
                 f"request needs {need_len} cache positions (prefix "
@@ -3060,35 +2940,16 @@ class ContinuousBatcher:
                         (self.rows, w), self.d_side.sink, np.int32))
                     parked = jnp.asarray(np.full(
                         (self.rows,), self.max_len, np.int32))
-                    if self.overlap:
-                        k1 = self.n_draft + 1
-                        carry = (jnp.zeros((self.rows, k1), jnp.int32),
-                                 zt, zt, zt)
-                        (self.pool, self.d_side.pool, g, nc, _,
-                         _) = self._spec_round(
-                            self.params, self.pool, self.draft_params,
-                            self.d_side.pool, table, dtable, zt, parked,
-                            zt, zt, no_host, *carry)
-                    else:
-                        (self.pool, self.d_side.pool, g,
-                         nc) = self._spec_round(
-                            self.params, self.pool, self.draft_params,
-                            self.d_side.pool, table, dtable, zt, parked,
-                            zt, zt)
+                    self.pool, self.d_side.pool, g, nc = self._spec_round(
+                        self.params, self.pool, self.draft_params,
+                        self.d_side.pool, table, dtable, zt, parked,
+                        zt, zt)
                     np.asarray(nc)
                     compiled.append(f"spec_round[{w}]")
                 elif self._pipelined:
                     self.pool, out, _, _, _ = self._decode(
                         self.params, self.pool, table, no_host, zt, zt,
                         zt, zt, zt, zt, zt)
-                    np.asarray(out)
-                    compiled.append(f"decode[{w}]")
-                elif self.overlap:
-                    prev = jnp.zeros((self.rows, self.multi_step),
-                                     jnp.int32)
-                    self.pool, out = self._decode(
-                        self.params, self.pool, table, zt, prev, no_host,
-                        zt, zt, zt)
                     np.asarray(out)
                     compiled.append(f"decode[{w}]")
                 else:
@@ -3313,15 +3174,14 @@ class ContinuousBatcher:
         one per exact count; the artifact itself is unchanged.
 
         ``final=True`` exports a FINISHED row at its COMMITTED
-        boundary: the lagged decode modes (overlap/pipelined, spec
-        rounds mid-flight) advance ``pos``/``step`` at dispatch, so a
-        finished row's host view can overshoot the committed stream by
-        the in-flight block — but every position below
+        boundary: the pipelined loop advances ``pos``/``step`` at
+        dispatch, so a finished row's host view can overshoot the
+        committed stream by the in-flight block — but every position below
         ``prefix + prompt + len(out) - 1`` was written exactly once
         with the true token sequence (positions only move forward), so
         clamping there exports exactly the resumable state.  This is
         what lets session parking work in every decode mode instead of
-        silently missing cold in the lagged ones."""
+        silently missing cold in the lagged one."""
         side = self.t_side
         ps = self.page_size
         E = state.pos
@@ -4014,12 +3874,11 @@ class ContinuousBatcher:
         plus the full conversation history, so the next turn can resume
         from it on this replica — or, through a shared disk tier, on
         any same-weights replica of the host.  EVERY decode mode parks
-        — the lagged ones (overlap/pipelined, spec) export at the
-        COMMITTED boundary (``_export_row(final=True)`` clamps the
-        overshooting host view to ``prefix + prompt + len(out) - 1``,
-        below which every position holds the true stream), fixing the
-        PR 13 gap where they silently missed cold.  A full tier is an
-        explicit rejected park, never a failed request."""
+        — the pipelined loop exports at the COMMITTED boundary
+        (``_export_row(final=True)`` clamps the overshooting host view
+        to ``prefix + prompt + len(out) - 1``, below which every
+        position holds the true stream).  A full tier is an explicit
+        rejected park, never a failed request."""
         if not self._tier_active:
             return
         sid = state.req.session_id
@@ -4399,15 +4258,10 @@ class ContinuousBatcher:
                                                    free_rows)
                         yield done
                 if any(row.decoding for row in active.values()):
-                    if self.draft_cfg is not None and self.overlap:
-                        yield from self._step_spec_overlap(active,
-                                                           free_rows)
-                    elif self.draft_cfg is not None:
+                    if self.draft_cfg is not None:
                         yield from self._step_spec(active, free_rows)
                     elif self._pipelined:
                         yield from self._step_pipelined(active, free_rows)
-                    elif self.overlap:
-                        yield from self._step_overlap(active, free_rows)
                     else:
                         yield from self._step(active, free_rows)
                     # Streaming flush point 2: this block's tokens, one
@@ -4418,8 +4272,8 @@ class ContinuousBatcher:
                     self._flush_streams(active)
         finally:
             # A consumer that stops early (break / close) must not leak
-            # the in-flight rows' pages (or a stale overlap/pipelined
-            # dispatch and its device carry).
+            # the in-flight rows' pages (or a stale pipelined dispatch
+            # and its device carry).
             self._inflight = None
             self._pipe_carry = self._pipe_host = None
             self._parked.clear()    # pages already released at suspend
@@ -4441,10 +4295,10 @@ class ContinuousBatcher:
         its ``Request.on_tokens`` callback (per-token incremental
         replies on the serving path).  Token STREAMS are not touched —
         this only reads ``out`` — so every mode's equivalence contract
-        is unaffected; in the lagged modes (overlap/pipelined) tokens
-        stream when they RETIRE, exactly when the host learns them.  A
-        raising callback is disarmed: a broken consumer costs its
-        stream, never the request or the loop."""
+        is unaffected; under the pipelined loop tokens stream when they
+        RETIRE, exactly when the host learns them.  A raising callback
+        is disarmed: a broken consumer costs its stream, never the
+        request or the loop."""
         with self._phase("batcher.emit"):
             for row in active.values():
                 cb = row.req.on_tokens
@@ -4935,78 +4789,23 @@ class ContinuousBatcher:
                 self._eva_account(active)
         yield from finished
 
-    def _step_overlap(self, active: Dict[int, _Row],
-                      free_rows: List[int]) -> Iterator[Completion]:
-        """One OVERLAP K-block tick (K=1 = the classic double-buffered
-        tick): dispatch the next K-step block without waiting for the
-        previous one — rows in the previous dispatch chain from its
-        device-resident LAST token (``use_dev``), so the device never
-        idles on a host round-trip — then retire the previous block
-        (host bookkeeping one block late).  Deterministic state (pos,
-        step) advances at dispatch; token-dependent state (out, last,
-        stop detection) at retire.  Stops surface one block late: the
-        extra dispatched block's writes stay inside the row's
-        reservation clamp or on sink columns and its tokens fail the
-        rid-checked ticket.  Quota gating at dispatch uses
-        dispatched-token counts, so a block may overrun a quota by up to
-        K-1 tokens; retire truncates.  Token streams are IDENTICAL to
-        the non-overlapping batcher's — same ops, same inputs, only the
-        sync point moves."""
-        K = self.multi_step
-        dispatch = {r: row for r, row in active.items()
-                    if row.decoding and row.step < row.req.max_new_tokens}
-        prev = self._inflight
-        if dispatch:
-            with self._phase("batcher.prep"):
-                toks = np.zeros((self.rows,), np.int32)
-                use_dev = np.zeros((self.rows,), bool)
-                positions = np.zeros((self.rows,), np.int32)
-                rids = np.zeros((self.rows,), np.int32)
-                steps = np.zeros((self.rows,), np.int32)
-                prev_ticket = {} if prev is None else prev[1]
-                for r, row in dispatch.items():
-                    self._ensure_sides(r, min(row.pos + K, row.limit))
-                    if prev_ticket.get(r) == row.rid:
-                        use_dev[r] = True   # prev block's last output
-                    else:
-                        toks[r] = row.last  # fresh admission / chunk flip
-                    positions[r] = row.pos
-                    rids[r] = row.rid
-                    steps[r] = row.step
-                table = self.t_side.decode_table(active, dispatch)
-            with self._phase("batcher.dispatch"):
-                prev_nxt = (prev[0] if prev is not None
-                            else jnp.zeros((self.rows, K), jnp.int32))
-                self.pool, nxt = self._decode(
-                    self.params, self.pool, table, jnp.asarray(toks),
-                    prev_nxt, jnp.asarray(use_dev),
-                    jnp.asarray(positions), jnp.asarray(rids),
-                    jnp.asarray(steps))
-                nxt.copy_to_host_async()    # transfer overlaps the block
-            self._inflight = (nxt,
-                              {r: row.rid for r, row in dispatch.items()})
-            for row in dispatch.values():
-                row.pos += K
-                row.step += K
-            self._tick_block("overlap", len(dispatch), K)
-        else:
-            self._inflight = None
-        if prev is not None:
-            yield from self._retire(prev, active, free_rows)
-
     def _step_pipelined(self, active: Dict[int, _Row],
                         free_rows: List[int]) -> Iterator[Completion]:
         """One PIPELINED K-block tick (``pipeline_depth=1``): dispatch
-        block N+1 BEFORE syncing block N, like :meth:`_step_overlap`,
-        but with the whole decode carry — last token, positions, AND
-        steps — resident on device: the jitted block returns them as
-        outputs that feed the next dispatch directly, so a steady-state
-        block uploads nothing at all (the overlap path re-uploads four
-        [rows] vectors per block).  Host-side inputs (fresh admissions'
-        token/position/step, the rid vector, the ``use_host`` merge
-        mask) are rebuilt only when the dispatch set actually changed —
-        admission, a finish, a chunked-prefill flip — exactly like the
-        page table, and are cached device constants otherwise.
+        block N+1 BEFORE syncing block N, with the whole decode carry —
+        last token, positions, AND steps — resident on device: the
+        jitted block returns them as outputs that feed the next dispatch
+        directly, so a steady-state block uploads nothing at all, and
+        retire the previous block (host bookkeeping one block late).
+        Deterministic state (pos, step) advances at dispatch;
+        token-dependent state (out, last, stop detection) at retire.
+        Quota gating at dispatch uses dispatched-token counts, so a
+        block may overrun a quota by up to K-1 tokens; retire truncates.
+        Host-side inputs (fresh admissions' token/position/step, the rid
+        vector, the ``use_host`` merge mask) are rebuilt only when the
+        dispatch set actually changed — admission, a finish, a
+        chunked-prefill flip — exactly like the page table, and are
+        cached device constants otherwise.
 
         Stop/quota detection lags one block; the overshoot block's
         writes land inside the row's clamped reservation or on sink
@@ -5077,7 +4876,7 @@ class ContinuousBatcher:
 
     def _retire(self, inflight, active: Dict[int, _Row],
                 free_rows: List[int]) -> Iterator[Completion]:
-        """Sync ONE overlap K-block (a block behind the newest) and do
+        """Sync ONE pipelined K-block (a block behind the newest) and do
         its token-dependent bookkeeping; rows that stopped at the
         previous retire (or were re-admitted since) fail the rid check
         and their block is dropped."""
@@ -5177,11 +4976,10 @@ class ContinuousBatcher:
     def _commit_rows(self, g, nc, rows, active: Dict[int, _Row],
                      free_rows: List[int]) -> List[Completion]:
         """Commit one speculative round's outputs to ``rows`` — ONE code
-        path for the sync (_step_spec) and overlap (_retire_spec) loops,
-        so their truncation/finish semantics cannot diverge.  Quota and
-        stop truncation: either way the row FINISHES, so the committed-
-        stream/cache (and overlap device-carry) consistency question is
-        moot.  Returns the rows' completions, in finish order."""
+        path for every round of a dispatch (_step_spec).  Quota and stop
+        truncation: either way the row FINISHES, so the committed-
+        stream/cache consistency question is moot.  Returns the rows'
+        completions, in finish order."""
         finished = []
         for r in rows:
             row = active[r]
@@ -5203,87 +5001,6 @@ class ContinuousBatcher:
                 self._finish_completed(r, active, free_rows)
         return finished
 
-    def _step_spec_overlap(self, active: Dict[int, _Row],
-                           free_rows: List[int]) -> Iterator[Completion]:
-        """One OVERLAP speculative round: dispatch round t WITHOUT
-        syncing round t-1 — continuing rows' token/position/step carry
-        on device (commit counts are computed in-graph), the host's
-        row.pos/step view lags one retire behind and only backs pages
-        (worst case: the un-retired round advanced n_draft+1 and this
-        round writes n_draft+1 more).  Endings (stop AND quota — counts
-        are device-decided) surface one round late; the overshoot
-        round's output is dropped by the rid-checked ticket and its
-        writes land in the row's reserved overshoot pages / the sink."""
-        k1 = self.n_draft + 1
-        dispatch = {r: row for r, row in active.items()
-                    if row.decoding and row.step < row.req.max_new_tokens}
-        prev = self._inflight
-        if dispatch:
-            with self._phase("batcher.prep"):
-                toks = np.zeros((self.rows,), np.int32)
-                positions = np.full((self.rows,), self.max_len, np.int32)
-                steps = np.zeros((self.rows,), np.int32)
-                rids = np.zeros((self.rows,), np.int32)
-                use_dev = np.zeros((self.rows,), bool)
-                prev_ticket = {} if prev is None else prev[4]
-                for r, row in dispatch.items():
-                    self._ensure_sides(r, min(row.pos + 2 * k1,
-                                              self.max_len))
-                    if prev_ticket.get(r) == row.rid:
-                        use_dev[r] = True   # continue from device carry
-                    else:
-                        toks[r] = row.last
-                        positions[r] = row.pos
-                        steps[r] = row.step
-                    rids[r] = row.rid
-                table = self.t_side.decode_table(active, dispatch)
-                dtable = self.d_side.decode_table(active, dispatch)
-            with self._phase("batcher.dispatch"):
-                if prev is None:
-                    z = jnp.zeros((self.rows,), jnp.int32)
-                    carry = (jnp.zeros((self.rows, k1), jnp.int32), z, z, z)
-                else:
-                    carry = prev[:4]
-                (self.pool, self.d_side.pool, g, nc, pos_d,
-                 steps_d) = self._spec_round(
-                    self.params, self.pool, self.draft_params,
-                    self.d_side.pool, table, dtable, jnp.asarray(toks),
-                    jnp.asarray(positions), jnp.asarray(rids),
-                    jnp.asarray(steps), jnp.asarray(use_dev), *carry)
-                g.copy_to_host_async()      # transfers overlap the round
-                nc.copy_to_host_async()
-            self._inflight = (g, nc, pos_d, steps_d,
-                              {r: row.rid for r, row in dispatch.items()})
-            self._tick_block("spec_overlap", len(dispatch), k1)
-        else:
-            self._inflight = None
-        if prev is not None:
-            yield from self._retire_spec(prev, active, free_rows)
-
-    def _retire_spec(self, inflight, active: Dict[int, _Row],
-                     free_rows: List[int]) -> Iterator[Completion]:
-        """Sync ONE overlap speculative round (a round behind the
-        newest) and do its token-dependent bookkeeping — the same commit
-        semantics as _step_spec, rid-gated so a finished row's overshoot
-        round is dropped.  Truncation (quota or stop) only ever happens
-        on a FINISHING row, so continuing rows advance by exactly the
-        device-side commit count and the host view stays consistent
-        with the in-graph position/step carry."""
-        g, nc, _, _, ticket = inflight
-        with self._phase("batcher.readback"):
-            g = np.asarray(g)   # host sync: one round behind dispatch
-            nc = np.asarray(nc)
-        if self._tick["name"] != "decode.block":    # the draining tick
-            self._tick_block(self._mode, len(ticket), self.n_draft + 1)
-        with self._phase("batcher.retire"):
-            live = [r for r, rid in ticket.items()
-                    if r in active and active[r].rid == rid]
-            self.spec_rounds += 1
-            self.spec_row_rounds += len(live)
-            self.spec_committed += int(sum(int(nc[r]) for r in live))
-            finished = self._commit_rows(g, nc, live, active, free_rows)
-        yield from finished
-
     # -- end-to-end deadlines ----------------------------------------------
 
     def _cancel_expired(self, active: Dict[int, _Row],
@@ -5291,8 +5008,8 @@ class ContinuousBatcher:
         """Cancel every resident row whose deadline has passed —
         exactly like a finish (pages released, row freed for the next
         admission) except an :class:`Expired` is yielded instead of a
-        Completion.  Lag modes (overlap/pipelined) may have one more
-        block in flight for the row; its writes land inside the clamped
+        Completion.  The pipelined loop may have one more block in
+        flight for the row; its writes land inside the clamped
         reservation or on sink columns and its tokens fail the
         rid-checked retire ticket, the same discard semantics a
         mid-block stop already has."""
@@ -5394,7 +5111,7 @@ class ContinuousBatcher:
                 if item is None or item is _CLOSED:
                     break
                 pending.append(item)
-        # Stale overlap/pipeline device state dies with its rows.
+        # Stale pipelined device state dies with its rows.
         self._inflight = None
         self._pipe_carry = self._pipe_host = None
         for r in sorted(active):
