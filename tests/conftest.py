@@ -173,9 +173,9 @@ _HEAVY_MULTICHIP = {
     # the heaviest interpret-mode cells (big page / int8 fused) move to
     # the full suite; every axis — head-blocked kv, q_per_kv, int8,
     # fused multi-row K — keeps a tier-1 representative.
-    "test_flash_decode_paged_equivalence_matrix[128-2-2-False-8]",
-    "test_flash_decode_paged_equivalence_matrix[16-2-2-True-8]",
-    "test_flash_decode_paged_equivalence_matrix[32-4-2-True-4]",
+    "test_flash_decode_paged_equivalence_matrix[128-2-2-False-8-None]",
+    "test_flash_decode_paged_equivalence_matrix[16-2-2-True-8-None]",
+    "test_flash_decode_paged_equivalence_matrix[32-4-2-True-4-None]",
 }
 
 

@@ -791,51 +791,114 @@ def test_flash_decode_paged_deferred_self():
                                    rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("ps,kv,g,quantized,self_t", [
+# What a K/V block of several pages can get wrong (PR 31).  name ->
+# (table width, rows' positions, scrambled page ids).  The block is held to
+# 4 pages of 16 here (the rule's cap is patched, as is the budget where a
+# case wants head_block < kv), so the widths are below a block (2), a block
+# (4), not whole blocks (6, 10) and whole blocks (8); positions put a row's
+# bound on a block's first page, on its last page, on the table's last
+# entry, and at 0 -- a row with no live page (its self chunk alone) beside
+# full rows; one table is wider than its longest row needs.
+_PAGED_TABLES = {
+    "w2": (2, (5, 0, 30), False),
+    "w4_block": (4, (2, 62, 17), False),
+    "w6_tail": (6, (65, 0, 94), False),
+    "w6_scrambled": (6, (70, 94, 0, 33), True),
+    "w8_two_blocks": (8, (63, 64, 126), True),
+    "w10_scrambled": (10, (0, 158, 66, 127), True),
+    "w10_short_rows": (10, (5, 70, 0), True),     # 1 + 2 + 1 steps of 9
+}
+
+
+@pytest.mark.parametrize("ps,kv,g,quantized,self_t,table", [
     # (page_size, kv heads, q_per_kv, int8 pools, fused self rows; 0 =
-    # committed t=1 step).  Sweeps the head-blocked grid (kv=1..4 hits
-    # head_block 1, 2, and 4 under the VMEM guard) and the fused
-    # multi-row step (K=4/8 — the speculative-verify shape).
-    (16, 1, 4, False, 0),
-    (16, 2, 2, False, 1),
-    (16, 2, 2, False, 8),
-    (32, 4, 1, False, 4),
-    (16, 2, 2, True, 1),
-    (16, 2, 2, True, 8),
-    (32, 4, 2, True, 4),
-    (128, 2, 2, False, 8),
-])
+    # committed t=1 step; table: None = 4 pages in order, one block, else
+    # a name in _PAGED_TABLES).  Sweeps the head-blocked grid (kv=1..4
+    # hits head_block 1, 2, and 4 under the VMEM guard), the fused
+    # multi-row step (K=4/8 — the speculative-verify shape) and blocks of
+    # several pages over every table above.
+    (16, 1, 4, False, 0, None),
+    (16, 2, 2, False, 1, None),
+    (16, 2, 2, False, 8, None),
+    (32, 4, 1, False, 4, None),
+    (16, 2, 2, True, 1, None),
+    (16, 2, 2, True, 8, None),
+    (32, 4, 2, True, 4, None),
+    (128, 2, 2, False, 8, None),
+    (16, 2, 2, False, 1, "w2"),
+    (16, 2, 2, False, 1, "w4_block"),
+    (16, 2, 2, False, 1, "w6_tail"),
+    (16, 2, 2, False, 0, "w6_tail"),
+    (16, 2, 2, False, 4, "w6_scrambled"),
+    (16, 2, 2, True, 1, "w6_scrambled"),
+    (16, 2, 2, True, 4, "w8_two_blocks"),
+    (16, 2, 2, False, 1, "w8_two_blocks"),
+    (16, 4, 1, False, 1, "w10_scrambled"),
+    (16, 4, 1, False, 4, "w10_scrambled"),
+    (16, 2, 2, False, 1, "w10_short_rows"),
+    (16, 2, 2, True, 4, "w10_short_rows"),
+    (16, 4, 2, False, 1, "w10_scrambled-hb2"),
+    (16, 4, 2, True, 4, "w10_scrambled-hb2"),
+], ids=lambda v: v if isinstance(v, str) else None if v is None else str(v))
 def test_flash_decode_paged_equivalence_matrix(ps, kv, g, quantized,
-                                               self_t):
-    """The restructured paged kernel (head-parallel grid + fused
-    multi-row steps) vs the gather-the-pages reference across the
+                                               self_t, table, monkeypatch):
+    """The paged kernel (head-parallel grid, K/V blocks of several pages,
+    fused multi-row steps) vs the gather-the-pages reference across the
     config matrix: every cell must agree on the SAME pool — the
     bit-exactness bar every serving caller (int8, GQA, self_kv
     deferred decode, spec verify chunks) rides on."""
+    from tfmesos_tpu.ops import attention
     from tfmesos_tpu.ops.attention import (_paged_decode_reference,
                                            flash_decode_paged)
     from tfmesos_tpu.ops.quant import (QTensor, quantize_int8_reference,
                                        quantize_tensor)
 
     ks = jax.random.split(jax.random.PRNGKey(11), 5)
-    b, d, npg = 2, 32, 4
-    h, m = kv * g, ps * npg
-    t = max(1, self_t)
+    d, t = 32, max(1, self_t)
+    h = kv * g
+    if table is None:
+        b, npg, positions, scrambled = 2, 4, None, False
+    else:
+        name, _, small_hb = table.partition("-")
+        npg, positions, scrambled = _PAGED_TABLES[name]
+        b = len(positions)
+        monkeypatch.setattr(attention, "_PAGED_BLOCK_POSITIONS", 4 * ps)
+        if small_hb:    # two heads' blocks of 4 pages fill the budget
+            monkeypatch.setattr(attention, "_PAGED_VMEM_BUDGET",
+                                4 * 2 * 4 * (ps * d * (1 if quantized else 4)
+                                             + 4096 * quantized))
+            assert attention._paged_block(
+                kv, ps, d, 1 if quantized else 4, npg, quantized) == (2, 4)
+        else:
+            assert attention._paged_block(
+                kv, ps, d, 1 if quantized else 4, npg,
+                quantized)[1] == min(4, npg)
+    m = ps * npg
     q = jax.random.normal(ks[0], (b, t, h, d), jnp.float32)
     kc = jax.random.normal(ks[1], (b, kv, m, d), jnp.float32)
     vc = jax.random.normal(ks[2], (b, kv, m, d), jnp.float32)
-    pool = lambda c: c.reshape(b, kv, npg, ps, d).transpose(
-        0, 2, 1, 3, 4).reshape(b * npg, kv, ps, d)
+    # Row i's logical page j lives at pool page ids[i, j]: in order, or
+    # scattered over a pool with pages to spare, so the pages of one
+    # block are neither adjacent nor ascending.
+    pool_n = b * npg + (7 if scrambled else 0)
+    ids = (np.random.RandomState(3).permutation(pool_n)[:b * npg]
+           if scrambled else np.arange(b * npg)).reshape(b, npg)
+
+    def pool(c, width):     # [b, kv, m, width] -> [pool_n, kv, ps, width]
+        out = np.zeros((pool_n, kv, ps, width), np.asarray(c).dtype)
+        out[ids.reshape(-1)] = np.asarray(c).reshape(
+            b, kv, npg, ps, width).transpose(0, 2, 1, 3, 4).reshape(
+            b * npg, kv, ps, width)
+        return jnp.asarray(out)
+
     if quantized:
         qt_k, qt_v = quantize_tensor(kc), quantize_tensor(vc)
-        lane = lambda qt: (qt.scales[..., 0].reshape(b, kv, npg, ps)
-                           .transpose(0, 2, 1, 3)
-                           .reshape(b * npg, kv, ps)[:, :, None, :])
-        k_pool = QTensor(pool(qt_k.values), jnp.asarray(lane(qt_k)))
-        v_pool = QTensor(pool(qt_v.values), jnp.asarray(lane(qt_v)))
+        lane = lambda qt: jnp.swapaxes(pool(qt.scales, 1), -1, -2)
+        k_pool = QTensor(pool(qt_k.values, d), lane(qt_k))
+        v_pool = QTensor(pool(qt_v.values, d), lane(qt_v))
     else:
-        k_pool, v_pool = pool(kc), pool(vc)
-    pt = jnp.asarray(np.arange(b * npg, dtype=np.int32).reshape(b, npg))
+        k_pool, v_pool = pool(kc, d), pool(vc, d)
+    pt = jnp.asarray(ids, jnp.int32)
     if self_t:
         rq = lambda c: (lambda v_, s_: v_.astype(jnp.float32)
                         * s_.astype(jnp.float32))(
@@ -847,8 +910,13 @@ def test_flash_decode_paged_equivalence_matrix(ps, kv, g, quantized,
     else:
         self_kv = None
     hi = m - t if self_t else m - t - 1
-    for pos in (jnp.array([0 if self_t else 1, hi], jnp.int32),
-                min(ps + 1, hi)):
+    if positions is None:
+        sweep = (jnp.array([0 if self_t else 1, hi], jnp.int32),
+                 min(ps + 1, hi))
+    else:       # a committed step attends its own slot: at least 1 position
+        sweep = (jnp.asarray(np.clip(positions, 0 if self_t else 1, hi),
+                             jnp.int32),)
+    for pos in sweep:
         ref = _paged_decode_reference(q, k_pool, v_pool, pt, pos,
                                       d ** -0.5, self_kv=self_kv)
         got = flash_decode_paged(q, k_pool, v_pool, pt, pos,
@@ -856,6 +924,79 @@ def test_flash_decode_paged_equivalence_matrix(ps, kv, g, quantized,
                                  self_kv=self_kv)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("name,call,expect", [
+    # (kv, page, head_dim, itemsize, table width[, int8]) -> (head block,
+    # pages per block): 512 positions, the head block shrinking until
+    # they fit the budget.
+    ("mistral_docqa", (8, 64, 128, 2, 128), (8, 8)),     # 4 MB of blocks
+    ("mistral_chat_w16", (8, 64, 128, 2, 16), (8, 8)),
+    ("evabyte", (32, 64, 128, 2, 64), (16, 8)),          # 8 MB: the budget
+    ("width_2", (8, 64, 128, 2, 2), (8, 2)),             # one step a row
+    ("width_1", (8, 64, 128, 2, 1), (8, 1)),
+    ("page_1024", (8, 1024, 128, 2, 8), (8, 1)),         # a value, not a mode
+    ("page_128", (8, 128, 128, 2, 64), (8, 4)),
+    ("page_16_f32", (2, 16, 32, 4, 4), (2, 4)),          # the tests' pools
+    ("mistral_int8", (8, 64, 128, 1, 128, True), (8, 8)),
+    ("evabyte_int8", (32, 64, 128, 1, 64, True), (16, 8)),
+    ("wide_heads_f32", (8, 256, 256, 4, 32), (4, 2)),    # head block gives way
+    ("huge_page_f32", (4, 1024, 256, 4, 8), (2, 1)),
+    ("mqa", (1, 64, 128, 2, 128), (1, 8)),
+])
+def test_paged_block_rule(name, call, expect):
+    """``_paged_block`` is a pure function of what a call sees; the
+    double-buffered K + V blocks it picks always fit the budget."""
+    from tfmesos_tpu.ops import attention
+
+    hb, ppb = attention._paged_block(*call)
+    assert (hb, ppb) == expect
+    kv, ps, d, itemsize, width = call[:5]
+    assert kv % hb == 0 and 1 <= ppb <= max(1, width)
+    assert ppb * ps <= max(ps, attention._PAGED_BLOCK_POSITIONS)
+    assert 4 * hb * ppb * ps * d * itemsize <= attention._PAGED_VMEM_BUDGET
+
+
+@pytest.mark.parametrize("width,ppb,live", [
+    (8, 4, (8, 0, 3, 0, 0, 5)),     # idle rows between live ones
+    (6, 4, (6, 1, 0, 4)),           # a table not whole blocks wide
+    (2, 2, (0, 2, 1)),              # the walk starts on an idle row
+    (16, 8, (0, 0, 0)),             # nothing live: a step a row, no copy
+    (5, 1, (5, 2, 0, 1)),           # one page a step
+])
+def test_paged_walk_moves_only_live_pages(width, ppb, live):
+    """The grid walks a row's live blocks and one step of a row that has
+    none, in row order, and flags a row's last step.  A slot's entry is
+    the row's page while the row is live there and otherwise repeats
+    what the slot fetched last on the walk, so the pipeline (which
+    copies a slot when its index changes) moves each live page once and
+    nothing else."""
+    from tfmesos_tpu.ops.attention import _paged_walk
+
+    rows = len(live)
+    table = np.random.RandomState(5).permutation(rows * width).reshape(
+        rows, width).astype(np.int32) + 1
+    walk, fetch, total = _paged_walk(jnp.asarray(table),
+                                     jnp.asarray(live, jnp.int32), ppb)
+    n = rows * -(-width // ppb)
+    walk, fetch = np.asarray(walk), np.asarray(fetch).reshape(n, ppb)
+    assert walk.shape == (3, n)
+    expect = [(r, j, j == max(1, -(-live[r] // ppb)) - 1)
+              for r in range(rows)
+              for j in range(max(1, -(-live[r] // ppb)))]
+    assert int(total) == len(expect)
+    held = [table[0, min(i, width - 1)] for i in range(ppb)]
+    copies = 0
+    for at, (r, j, last) in enumerate(expect):
+        assert tuple(walk[:, at]) == (r, j, last)
+        for i in range(ppb):
+            e = j * ppb + i
+            if e < live[r]:
+                assert fetch[at, i] == table[r, e]
+                copies += fetch[at, i] != held[i]
+                held[i] = table[r, e]
+            assert fetch[at, i] == held[i]     # dead: no change, no copy
+    assert copies <= sum(live)
 
 
 def test_stacked_cache_static_zero_layer_with_4d_cache():

@@ -86,6 +86,17 @@ def _qtensor(shape):
 _POS, _LAYER, _TABLE = ((B,), I32), ((), I32), ((B, NP), I32)
 _POOL = ((L, POOL, H, PAGE, D), BF16)
 
+
+def _cell_paged(rows, kv, g, width, pages, t, int8=False):
+    """A decode step of a benchmark cell: 16 layers, heads of 128, page 64."""
+    shape = (16, pages, kv, PAGE, 128)
+    pool = _qtensor(shape) if int8 else (shape, BF16)
+    return (None, lambda m: _paged,
+            [((rows, t, kv * g, 128), BF16), pool, pool,
+             ((rows, width), I32), ((rows,), I32), _LAYER]
+            + [((rows, t, kv, 128), BF16)] * 2, 1)
+
+
 # name -> (mesh axes or None, fn(mesh), argument (shape, dtype[, spec])s,
 #          kernels expected in the program)
 CASES = {
@@ -143,6 +154,18 @@ CASES = {
                           + [((L, POOL, 2, PAGE, D), BF16)] * 2
                           + [_TABLE, _POS, _LAYER]
                           + [((B, 1, 2, D), BF16)] * 2, 1),
+    # The benchmark's decode steps (PR 31): the paged kernel's K/V block is
+    # several pages, each a page slot of the pipeline's, and its VMEM is worked
+    # out from these shapes.  Mistral-7B's (32 rows, 8 KV heads x 4 queries
+    # of 128, table width 128: 8 heads x 8 pages a step) and EvaByte's (16
+    # rows, 32 heads x 1, width 64: 16 heads x 8 pages), the 16-layer pools
+    # of their cells, the deferred self operand; a speculative chunk
+    # (t = 4) and an int8 pool.
+    "paged_mistral_w128_self": _cell_paged(32, 8, 4, 128, 1300, 1),
+    "paged_evabyte_w64_self": _cell_paged(16, 32, 1, 64, 372, 1),
+    "paged_mistral_w128_t4_self": _cell_paged(32, 8, 4, 128, 1300, 4),
+    "paged_mistral_w128_int8_self": _cell_paged(32, 8, 4, 128, 1300, 1,
+                                                int8=True),
     "quantize_int8": (None, lambda m: lambda x: quantize_int8(
         x, use_pallas=True), [((4096, 512), F32)], 1),
     "quantize_int8_stochastic": (None, lambda m: lambda x: quantize_int8(

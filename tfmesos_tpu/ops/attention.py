@@ -35,24 +35,83 @@ NEG_INF = float("-inf")
 #: the dispatch structure.
 PAGED_CALL_STATS = {"calls": 0, "kernel_calls": 0}
 
-#: Per-core VMEM bytes the paged kernel's K + V slabs may claim
-#: (double-buffered pair of each, leaving headroom for q, the self
-#: operands and the softmax scratch in the ~16 MB core budget).
+#: Per-core VMEM bytes the paged kernel's K + V blocks may claim, both
+#: double-buffered by the pipeline (headroom for q, the self operands,
+#: the softmax scratch and the score tile in the ~16 MB scoped budget).
 _PAGED_VMEM_BUDGET = 8 * 2 ** 20
 
+#: Positions one K/V block of the paged kernel covers at most.  On the
+#: v5e a block of 512 is where a step's copies take as long as its heads'
+#: softmax chains (~0.3 us a head and step whatever the block, PR 31's
+#: sweep: PERF.md section 6): 87-91% of the HBM roofline over live pages,
+#: against 51-59% at 128-256 positions; 1024 reads 3% better again and
+#: doubles the VMEM and the operands.
+_PAGED_BLOCK_POSITIONS = 512
 
-def _paged_head_block(kv: int, ps: int, d: int, itemsize: int) -> int:
-    """Heads per paged-kernel grid cell: the largest divisor of ``kv``
-    whose [head_block, page, d] K + V slabs, double-buffered, fit
-    :data:`_PAGED_VMEM_BUDGET` — every head in one cell when it fits
-    (head grid dimension 1, the common case), falling back to smaller
-    head blocks for huge page x head_dim products rather than losing
-    the kernel eligibility outright."""
+
+def _paged_block(kv: int, ps: int, d: int, itemsize: int, width: int,
+                 quantized: bool = False) -> tuple[int, int]:
+    """``(head_block, pages_per_block)`` of the paged decode kernel, from
+    what a call sees and nothing else.  One grid step covers a K/V block
+    of ``pages_per_block`` pool pages of ``head_block`` heads each.  The
+    block wants :data:`_PAGED_BLOCK_POSITIONS` positions, never more
+    pages than the table is wide; the head block is the largest divisor
+    of ``kv`` whose K + V blocks of that many pages, double-buffered, fit
+    :data:`_PAGED_VMEM_BUDGET` -- the head block shrinks before the block
+    does (EvaByte's 32 heads: 16 x 8 pages ran 26% faster than 32 x 4).
+    Where one head's block does not fit either, one head takes the pages
+    that do: a page that fills the budget alone (page 1024) yields one
+    page a step, a value of the rule and not a mode.  An int8 pool's
+    page carries one (8, 128)-padded float32 scale tile per 128
+    positions and head."""
+    page_bytes = ps * d * itemsize
+    if quantized:
+        page_bytes += 8 * 128 * 4 * -(-ps // 128)
+    pages = max(1, min(_PAGED_BLOCK_POSITIONS // ps, width))
+    fit = lambda hb: _PAGED_VMEM_BUDGET // (4 * hb * page_bytes)
     for hb in range(kv, 0, -1):
-        if kv % hb == 0 and 4 * hb * ps * d * itemsize <= \
-                _PAGED_VMEM_BUDGET:
-            return hb
-    return 1
+        if kv % hb == 0 and fit(hb) >= pages:
+            return hb, pages
+    return 1, max(1, fit(1))
+
+
+def _paged_walk(page_table, live_pages, pages_per_block: int):
+    """The paged kernel's grid, flattened over the steps that have work:
+    ``(walk, fetch, total)``.  Row after row, a row takes one step per
+    K/V block that holds a live page of it (``live_pages[row]`` of its
+    table's entries are live) and one step when it has none (its self
+    operand and its output still want one), so the grid is ``total``
+    steps long and no step is a dead one: a table is a power of two
+    wider than its widest row and idle rows ride in every batch, and a
+    step costs the pipeline its bookkeeping per operand whether it
+    computes or not (PERF.md section 6, PR 31).
+
+    ``walk`` [3, rows * steps] int32: the row of step ``s``, the K/V
+    block of the row it covers, and whether it is the row's last.
+    ``fetch`` [rows * steps * pages_per_block] int32: the pool page
+    that page SLOT ``i`` of the block holds at step ``s``, at
+    ``s * pages_per_block + i``.  For a live entry that is the row's
+    own page; for an entry at or past the row's live pages it is the
+    page the slot fetched LAST on the walk.  The pipeline copies a slot
+    only when its index changes, so a dead entry moves nothing: only
+    live pages move, each once (head blocks aside, which take the walk
+    again).  Entries past ``total`` are never read."""
+    b, width = page_table.shape
+    ppb = pages_per_block
+    steps = -(-width // ppb)
+    count = jnp.clip(-(-live_pages // ppb), 1, steps)
+    ends = jnp.cumsum(count)
+    at = jnp.arange(b * steps, dtype=jnp.int32)
+    row = jnp.minimum(jnp.sum(at[:, None] >= ends[None, :], axis=1,
+                              dtype=jnp.int32), b - 1)
+    block = jnp.minimum(at - (ends - count)[row], steps - 1)
+    entry = block[:, None] * ppb + jnp.arange(ppb, dtype=jnp.int32)
+    live = entry < live_pages[row][:, None]
+    pages = page_table[row[:, None], jnp.minimum(entry, width - 1)]
+    last_live = jax.lax.cummax(jnp.where(live, at[:, None], 0), axis=0)
+    fetch = jnp.take_along_axis(pages, last_live, axis=0)
+    walk = jnp.stack([row, block, (block == count[row] - 1).astype(jnp.int32)])
+    return walk, fetch.reshape(-1), ends[-1]
 
 
 def _check_gqa_heads(q, k, v):
@@ -753,7 +812,7 @@ def _decode_block_scores(q, k_blk, scale, ks_row=None):
                             preferred_element_type=jnp.float32)
     s = s * scale
     if ks_row is not None:
-        s = s * ks_row[None, :]
+        s = s * ks_row.reshape(1, -1)
     return s
 
 
@@ -768,7 +827,7 @@ def _decode_accumulate(s, v_blk, acc, vs_row=None):
     corr = jnp.where(m_prev == NEG_INF, 0.0, jnp.exp(m_prev - m_new))
     l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
     if vs_row is not None:
-        p = p * vs_row[None, :]
+        p = p * vs_row.reshape(1, -1)
     if v_blk.dtype == jnp.int8:
         v_blk = v_blk.astype(jnp.float32)
     o_new = o_prev * corr + jax.lax.dot_general(
@@ -1053,35 +1112,50 @@ def _paged_decode_reference(q, k_pool, v_pool, page_table, pos, scale,
     return _decode_reference(q, k_view, v_view, pos, scale)
 
 
-def _flash_decode_paged_kernel(s_ref, pt_ref, q_ref, k_ref, v_ref, *rest,
-                               block_m: int, scale: float, quantized: bool,
-                               q_per_kv: int, head_block: int,
-                               self_attend: bool = False):
-    """One (batch, head-block, logical-page) grid step of paged decode.
+def _flash_decode_paged_kernel(s_ref, walk_ref, fetch_ref, q_ref, *rest,
+                               page: int, pages_per_block: int,
+                               scale: float, quantized: bool, q_per_kv: int,
+                               head_block: int, self_attend: bool = False):
+    """One (head-block, step of the walk) grid step of paged decode.
 
-    Grid iterations cost ~2.3 µs each even when the per-row bound skips
-    their DMA (the scalar-table index map defeats cheap elision —
-    measured, v5e round 5), so KV heads are FOLDED into the block in
-    slabs of ``head_block`` heads: one iteration fetches a page's
-    [head_block, page, d] slab (contiguous in the pool layout) and runs
-    the online-softmax body per head against per-head slices of the
-    shared scratch.  The head-block dimension is PARALLEL
-    (``dimension_semantics`` — head blocks share no accumulator state,
-    so Mosaic may split them across megacore) while pages stay
-    sequential for the scratch accumulation; when one slab holds every
-    head (the ``_paged_head_block`` common case) the head dimension is
-    size 1 and the layout degenerates to the fully kv-folded grid.
+    A step's K/V block is ``pages_per_block`` pages of one row: the pool
+    rides in once per page slot, each slot a BlockSpec whose index map
+    chases the scalar-prefetched fetch table, so a step brings
+    ``pages_per_block`` dense [head_block, page, d] slabs (each
+    contiguous in the pool's own layout, scattered in the pool) and
+    joins them in VMEM into one block of ``pages_per_block * page``
+    positions.  Per head the online softmax then runs ONCE over the
+    block: a [t*g, d] x [d, block] product, one max/exp/sum, a
+    [t*g, block] x [block, d] product and one update of the head's
+    slice of the shared scratch -- the per-step costs nothing amortises
+    (the step itself, the copies' issue, the MXU's latency on a thin
+    product, three scratch read-modify-writes) are paid per block, not
+    per page (v5e, PR 31: PERF.md section 6).  The block's size is
+    ``_paged_block``'s, from the call's shapes and the VMEM budget.
 
-    Index maps chase this row's physical page id through the
-    scalar-prefetched page table, so each row's cache lives in scattered
-    pool pages and rows share one physical pool; ``s_ref`` rows are
-    (n_live_blocks, position bound, layer index), as in
-    ``_flash_decode_kernel``, whose per-head math (including the
-    quantized scale folds) this kernel reproduces slice for slice.
+    The grid's second dimension is ``_paged_walk``'s: the steps that
+    have work, row after row -- a row's live blocks, or one step of a
+    row that has none -- and no other, its length a traced scalar.
+    ``walk_ref`` names each step's row, its block of the row and
+    whether it is the row's last; the scratch is zeroed on a row's
+    block 0 and the row's output written on its last step.  The
+    head-block dimension is PARALLEL (``dimension_semantics`` -- head
+    blocks share no accumulator state, so Mosaic may split them across
+    megacore) and walks the rows again; when one slab holds every head
+    (the common case) it is size 1.
+
+    ``s_ref`` rows are (live pages, position bound, layer index).  A
+    slot past the row's live pages (the tail of its last block, the
+    one step of an idle row) holds the page the slot fetched last, so
+    the pipeline's unchanged-index elision moves no byte for it: only
+    live pages move, each once.  The position bound masks such a slot
+    like the tail of the last live page.  The per-head math, int8
+    scale folds included, is ``_flash_decode_kernel``'s slice for slice
+    (a page's lane-major scales ride with it and join along the lanes).
 
     ``self_attend`` (deferred-write decode, a paged-only feature): the
     uncommitted chunk's K/V rides in as a [head_block, t, d] fp operand
-    accumulated at the last page step.  The pool bound is then
+    accumulated at the row's last step.  The pool bound is then
     EXCLUSIVE and token-independent — ``kpos > bound`` with
     bound = pos - 1, because the pool only holds committed positions
     < pos and the slots at [pos, pos + t - 1] are stale for EVERY chunk
@@ -1091,21 +1165,36 @@ def _flash_decode_paged_kernel(s_ref, pt_ref, q_ref, k_ref, v_ref, *rest,
     chunked-prefill tail) retires t decode rows through ONE launch per
     layer, the page table scalar-prefetched once for the whole chunk
     instead of once per step."""
-    del pt_ref  # consumed by the index maps
+    del fetch_ref  # consumed by the slots' index maps
+    ppb = pages_per_block
     it = list(rest)
-    ks_ref = vs_ref = kself_ref = vself_ref = None
+    k_refs, v_refs, it = it[:ppb], it[ppb:2 * ppb], it[2 * ppb:]
+    ks_refs = vs_refs = kself_ref = vself_ref = None
     if quantized:
-        ks_ref, vs_ref = it[0], it[1]
-        it = it[2:]
+        ks_refs, vs_refs, it = it[:ppb], it[ppb:2 * ppb], it[2 * ppb:]
     if self_attend:
         kself_ref, vself_ref = it[0], it[1]
         it = it[2:]
     o_ref, o_acc, m_acc, l_acc = it
-    bi = pl.program_id(0)
-    j = pl.program_id(2)
+    at = pl.program_id(1)
+    bi, j, last = walk_ref[0, at], walk_ref[1, at], walk_ref[2, at] == 1
     nb = s_ref[0, bi]
     bound = s_ref[1, bi]
-    tg = q_ref.shape[2]                         # t * g rows per head
+
+    def heads(body):
+        # One head of the slab after another, the head an index into the
+        # leading dim of every block and of the scratch.  The loop is
+        # UNROLLED by Mosaic (the heads' chains of product, softmax,
+        # product and scratch update overlap: a rolled loop read 30%
+        # slower at every shape) but its body is traced once: a Python
+        # loop's head_block x 2 x pages_per_block block loads cost a
+        # decode program over a second of tracing each on the serving
+        # host (PERF.md section 6, PR 31).
+        def step(h, carry):
+            body(h)
+            return carry
+
+        jax.lax.fori_loop(0, head_block, step, 0, unroll=True)
 
     @pl.when(j == 0)
     def _init():
@@ -1113,15 +1202,18 @@ def _flash_decode_paged_kernel(s_ref, pt_ref, q_ref, k_ref, v_ref, *rest,
         m_acc[...] = jnp.full_like(m_acc, NEG_INF)
         l_acc[...] = jnp.zeros_like(l_acc)
 
-    @pl.when(j < nb)
+    @pl.when(nb > 0)       # the walk's one step of a row with no live page
     def _step():
-        kpos0 = j * block_m
-        for h in range(head_block):
-            sl = slice(h * tg, (h + 1) * tg)
-            q = q_ref[0, h, :, :]               # [tg, d]
+        kpos0 = j * (ppb * page)
+
+        def head(h):
+            # The block of head h: its pages' [page, d] slabs joined
+            # over the positions (scales: [1, page] rows over the lanes).
+            join = lambda refs, axis: jnp.concatenate(
+                [r[0, 0, h] for r in refs], axis=axis)
             s = _decode_block_scores(
-                q, k_ref[0, 0, h, :, :], scale,
-                ks_ref[0, 0, h, 0, :] if quantized else None)
+                q_ref[0, h], join(k_refs, 0), scale,    # q: [t*g, d]
+                join(ks_refs, 1) if quantized else None)
             kpos = kpos0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
             if self_attend:
                 # Committed positions only, for every chunk token: the
@@ -1132,16 +1224,17 @@ def _flash_decode_paged_kernel(s_ref, pt_ref, q_ref, k_ref, v_ref, *rest,
                 tt = jax.lax.broadcasted_iota(jnp.int32, s.shape,
                                               0) // q_per_kv
                 s = jnp.where(kpos > bound + tt, NEG_INF, s)
-            m_acc[sl], l_acc[sl], o_acc[sl] = _decode_accumulate(
-                s, v_ref[0, 0, h, :, :], (m_acc[sl], l_acc[sl], o_acc[sl]),
-                vs_ref[0, 0, h, 0, :] if quantized else None)
+            m_acc[h], l_acc[h], o_acc[h] = _decode_accumulate(
+                s, join(v_refs, 0), (m_acc[h], l_acc[h], o_acc[h]),
+                join(vs_refs, 1) if quantized else None)
+
+        heads(head)
 
     if self_attend:
-        @pl.when(j == pl.num_programs(2) - 1)
+        @pl.when(last)
         def _self():
-            for h in range(head_block):
-                sl = slice(h * tg, (h + 1) * tg)
-                q = q_ref[0, h, :, :]
+            def head(h):
+                q = q_ref[0, h]
                 if kself_ref.shape[2] == 1:
                     # A one-token chunk makes this a [tg, d] x [d, 1]
                     # product, which Mosaic lowers as a broadcast
@@ -1149,7 +1242,7 @@ def _flash_decode_paged_kernel(s_ref, pt_ref, q_ref, k_ref, v_ref, *rest,
                     # first (bf16 -> f32 in the broadcast is refused for
                     # grouped queries, tg > 1).
                     q = q.astype(jnp.float32)
-                s = _decode_block_scores(q, kself_ref[0, h, :, :], scale)
+                s = _decode_block_scores(q, kself_ref[0, h], scale)
                 # Intra-chunk causality: self slot ss holds chunk token
                 # ss's K/V, and row tt attends slots <= tt (t = 1 masks
                 # nothing — the single-token deferred step unchanged).
@@ -1157,15 +1250,14 @@ def _flash_decode_paged_kernel(s_ref, pt_ref, q_ref, k_ref, v_ref, *rest,
                 tt = jax.lax.broadcasted_iota(jnp.int32, s.shape,
                                               0) // q_per_kv
                 s = jnp.where(ss > tt, NEG_INF, s)
-                m_acc[sl], l_acc[sl], o_acc[sl] = _decode_accumulate(
-                    s, vself_ref[0, h, :, :],
-                    (m_acc[sl], l_acc[sl], o_acc[sl]))
+                m_acc[h], l_acc[h], o_acc[h] = _decode_accumulate(
+                    s, vself_ref[0, h], (m_acc[h], l_acc[h], o_acc[h]))
 
-    @pl.when(j == pl.num_programs(2) - 1)
+            heads(head)
+
+    @pl.when(last)
     def _finish():
-        for h in range(head_block):
-            sl = slice(h * tg, (h + 1) * tg)
-            o_ref[0, h, :, :] = (o_acc[sl] / l_acc[sl]).astype(o_ref.dtype)
+        o_ref[0] = (o_acc[...] / l_acc[...]).astype(o_ref.dtype)
 
 
 def flash_decode_paged(q, k_pool, v_pool, page_table, pos,
@@ -1178,8 +1270,9 @@ def flash_decode_paged(q, k_pool, v_pool, page_table, pos,
     ``pool[page_table[b, j]]``), so mixed-length sequences share memory
     without per-row max_len buffers — the PagedAttention layout, realized
     on TPU by routing the page id through the kernel's scalar-prefetched
-    BlockSpec index maps (block fetches chase the table; out-of-range
-    blocks pin to the last live page and are never re-fetched).
+    BlockSpec index maps (block fetches chase the table, several pages
+    to one K/V block: ``_paged_block``; the grid walks the blocks that
+    hold a live page and no other: ``_paged_walk``).
 
     ``q``: [B, H, D] or [B, t, H, D]; ``k_pool``/``v_pool``:
     [P, KV, page, D] (page and head_dim trailing — the pool's NATIVE
@@ -1213,12 +1306,10 @@ def flash_decode_paged(q, k_pool, v_pool, page_table, pos,
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     g = h // kv
-    # Blocks carry a page's [head_block, page, d] slab per grid cell
-    # (head-blocked grid, head_block | kv): eligibility only requires
-    # the SINGLE-head slab to fit the VMEM budget — _paged_head_block
-    # then folds as many heads per cell as the budget allows (all of
-    # them in the common case), so big kv x page x d products shrink
-    # the head block instead of losing the kernel.
+    # Eligibility only requires ONE head's page to fit the VMEM budget
+    # double-buffered: _paged_block then takes as many pages and heads
+    # per grid step as the budget allows, so big kv x page x d products
+    # shrink the block instead of losing the kernel.
     aligned = (ps % 8 == 0 and ps <= 1024
                and 4 * ps * d * kp.dtype.itemsize <= _PAGED_VMEM_BUDGET)
     if use_pallas is None:
@@ -1254,39 +1345,36 @@ def flash_decode_paged(q, k_pool, v_pool, page_table, pos,
         b, kv, t * g, d)
 
     PAGED_CALL_STATS["kernel_calls"] += 1
-    # KV heads are FOLDED into the block in head_block slabs (grid
-    # (b, kv // head_block, page)): a grid iteration costs ~2.3 us even
-    # when skipped, so per-head page loops multiplied pure overhead by
-    # KV.  One iteration fetches a page's [head_block, page, d] slab —
-    # contiguous in the pool layout, so the DMA stays one dense block —
-    # and the head dimension is PARALLEL: blocks share no accumulator,
-    # so when VMEM forces head_block < kv the per-slab work spreads
-    # across megacore instead of serializing inside one cell.
-    head_block = _paged_head_block(kv, ps, d, kp.dtype.itemsize)
-    n_hb = kv // head_block
+    # Grid (kv // head_block, the walk's steps): a step covers
+    # pages_per_block pages of head_block heads of one row, and the walk
+    # holds the steps that have work and no other (_paged_walk).  The
+    # pool rides in once per page SLOT of the block, each slot's index
+    # map chasing the fetch table, so a page's [head_block, page, d]
+    # slab -- contiguous in the pool's layout -- stays one dense copy,
+    # the pipeline double-buffers every slot and starts the next step's
+    # (the next row's first) copies under this step's compute.
+    np_ = page_table.shape[1]
+    head_block, ppb = _paged_block(kv, ps, d, kp.dtype.itemsize, np_,
+                                   quantized)
+    walk, fetch, total = _paged_walk(page_table, nb, ppb)
     q_spec = pl.BlockSpec((1, head_block, t * g, d),
-                          lambda bi, hi, j, s, pt: (bi, hi, 0, 0),
+                          lambda hi, at, s, wk, ft: (wk[0, at], hi, 0, 0),
                           memory_space=pltpu.VMEM)
-    kv_spec = pl.BlockSpec(
-        (1, 1, head_block, ps, d),
-        lambda bi, hi, j, s, pt: (
-            s[2, 0], pt[bi, jnp.maximum(jnp.minimum(j, s[0, bi] - 1), 0)],
-            hi, 0, 0),
-        memory_space=pltpu.VMEM)
-    in_specs = [q_spec, kv_spec, kv_spec]
-    operands = [qt, kp, vp]     # pools already (page, head_dim)-trailing
+
+    def slot_specs(block_shape):
+        return [pl.BlockSpec(
+            block_shape,
+            lambda hi, at, s, wk, ft, i=i: (s[2, 0], ft[at * ppb + i],
+                                            hi, 0, 0),
+            memory_space=pltpu.VMEM) for i in range(ppb)]
+
+    in_specs = [q_spec] + 2 * slot_specs((1, 1, head_block, ps, d))
+    operands = [qt] + [kp] * ppb + [vp] * ppb   # pools (page, d)-trailing
     if quantized:
-        # Scales as [L, P, KV, 1, page]: positions on the lane dim, same
-        # page-chasing index map as their values.
-        sc_spec = pl.BlockSpec(
-            (1, 1, head_block, 1, ps),
-            lambda bi, hi, j, s, pt: (
-                s[2, 0],
-                pt[bi, jnp.maximum(jnp.minimum(j, s[0, bi] - 1), 0)],
-                hi, 0, 0),
-            memory_space=pltpu.VMEM)
-        in_specs += [sc_spec, sc_spec]
-        operands += [ksc, vsc]                      # already lane-major
+        # Scales as [L, P, KV, 1, page]: positions on the lane dim, each
+        # page's beside its values under the same index map.
+        in_specs += 2 * slot_specs((1, 1, head_block, 1, ps))
+        operands += [ksc] * ppb + [vsc] * ppb       # already lane-major
     if self_kv is not None:
         # [B, t, KV, D] model-layout chunks -> [B, KV, t, D] t-slot fp
         # blocks (int8 pools: the caller pre-quantize-dequantizes so
@@ -1294,29 +1382,29 @@ def flash_decode_paged(q, k_pool, v_pool, page_table, pos,
         kself, vself = (c.transpose(0, 2, 1, 3).astype(q.dtype)
                         for c in self_kv)
         self_spec = pl.BlockSpec((1, head_block, t, d),
-                                 lambda bi, hi, j, s, pt: (bi, hi, 0, 0),
+                                 lambda hi, at, s, wk, ft: (wk[0, at], hi,
+                                                            0, 0),
                                  memory_space=pltpu.VMEM)
         in_specs += [self_spec, self_spec]
         operands += [kself, vself]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, n_hb, page_table.shape[1]),
+        num_scalar_prefetch=3,
+        grid=(kv // head_block, total),
         in_specs=in_specs,
         out_specs=q_spec,
-        scratch_shapes=[pltpu.VMEM((head_block * t * g, d), jnp.float32),
-                        pltpu.VMEM((head_block * t * g, 1), jnp.float32),
-                        pltpu.VMEM((head_block * t * g, 1), jnp.float32)])
-    # Static cost estimate for the head-blocked grid.  bytes_accessed
-    # charges the slabs this call can actually DMA — b rows x live
-    # pages x one K + one V [KV, page, d] slab — never the WHOLE pool
+        scratch_shapes=[pltpu.VMEM((head_block, t * g, d), jnp.float32),
+                        pltpu.VMEM((head_block, t * g, 1), jnp.float32),
+                        pltpu.VMEM((head_block, t * g, 1), jnp.float32)])
+    # Static cost estimate.  bytes_accessed charges the slabs this
+    # call can actually DMA — b rows x live pages x one K + one V
+    # [KV, page, d] slab — never the WHOLE pool
     # (the old estimate charged pool bytes: a 1000-page pool serving 4
     # rows x 16 live pages overstated the traffic ~30x and mis-ranked
     # the kernel for the XLA scheduler).  flops/transcendentals use the
     # per-row block bound when ``pos`` is concrete (direct calls,
     # tests, benches); under jit the bound is traced and the TABLE
-    # width is the static ceiling — the in-kernel bound still skips the
-    # dead iterations either way.
-    np_ = page_table.shape[1]
+    # width is the static ceiling — the walk still holds the live
+    # steps only either way.
     try:
         est_nb = int(jnp.max(nb))
     except jax.errors.ConcretizationTypeError:
@@ -1324,22 +1412,23 @@ def flash_decode_paged(q, k_pool, v_pool, page_table, pos,
     est_nb = max(1, min(est_nb, np_))
     slab_bytes = kv * ps * d * kp.dtype.itemsize
     out = pl.pallas_call(
-        functools.partial(_flash_decode_paged_kernel, block_m=ps,
-                          scale=float(scale), quantized=quantized,
-                          q_per_kv=g, head_block=head_block,
+        functools.partial(_flash_decode_paged_kernel, page=ps,
+                          pages_per_block=ppb, scale=float(scale),
+                          quantized=quantized, q_per_kv=g,
+                          head_block=head_block,
                           self_attend=self_kv is not None),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
         interpret=interpret,
         name="flash_decode_paged",
         compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary")),
         cost_estimate=pl.CostEstimate(
             flops=4 * b * t * h * est_nb * ps * d,
             bytes_accessed=(2 * b * est_nb * slab_bytes
                             + 2 * q.size * q.dtype.itemsize),
             transcendentals=b * t * h * est_nb * ps),
-    )(scalars, page_table, *operands)
+    )(scalars, walk, fetch, *operands)
     out = out.reshape(b, kv, t, g, d).transpose(0, 2, 1, 3, 4).reshape(
         b, t, h, d)
     return out[:, 0] if squeeze else out

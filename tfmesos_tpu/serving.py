@@ -728,11 +728,14 @@ class _PagedSide:
     def bucket_width(self) -> int:
         """Smallest power-of-two table width covering every allocated
         row (shared prefix pages + own pages), capped at ``np_max``.
-        The paged kernel's grid iterates the TABLE WIDTH per (row, page)
-        — kv heads are folded into each block, and skipped entries still
-        cost a grid step through the scalar-prefetched index map — so
-        dispatching at the worst-case
-        width makes short-lived requests on a long-max_len pool pay for
+        The table's width sizes what every decode dispatch carries: the
+        paged kernel's scalar-prefetched tables and the XLA ops that
+        build them, and the gather path (no TPU, or pages the kernel
+        does not take), which reads the whole width.  The kernel's own
+        grid walks the blocks that hold a live page and no other since
+        PR 31 (``_paged_walk``; PERF.md section 6), so there the width
+        no longer costs a step; on the grid of round 5 the worst-case
+        width made short-lived requests on a long-max_len pool pay for
         context they don't have (measured 3.4x on an 8k pool early in
         generation, v5e round 5).  Power-of-two bucketing bounds the
         jit cache at log2(np_max) decode variants.  Safety: every
