@@ -1,0 +1,32 @@
+"""The one-token SSM update's share of its HBM roofline (percent): bytes of
+recurrent state the traced window's decode steps had to read and write (each
+row that decodes a token moves its float32 state of every mamba layer once in
+and once out: the adapter's ``ssm_step_bytes``), over the device time of the
+instructions that produce the state inside the decode programs and the chip's
+HBM bandwidth.  Those are XLA instructions, told by shape: a leaf instruction
+of a ``jit_decode_block`` run whose text names the stacked state store,
+``f32[mamba layers, rows, heads * head size, state]`` (the adapter's
+``ssm_state_shape``), as its result or as an operand.  ~2 flops a byte: the
+bytes bound it.  Nothing to read where the adapter counts no state, or no
+such instruction ran (a program without the row state).
+Source: device trace."""
+
+from benchmark import hybrid_readers
+
+
+def read(run):
+    tr = run.get("trace")
+    tw0, tw1 = run.get("trace_window") or (None, None)
+    model = run["model"]
+    if tr is None or tw0 is None or not tr.devices \
+            or not hasattr(model, "ssm_step_bytes"):
+        return None
+    update_s = sum(d for _, d in hybrid_readers.state_ops(run, "decode"))
+    if update_s <= 0:
+        return None
+    row_steps = sum(1 for r in run["records"]
+                    for k, t in enumerate(r.token_times)
+                    if k >= 1 and tw0 <= t < tw1)
+    nbytes = row_steps * model.ssm_step_bytes(run["config"], 1)
+    return 100.0 * nbytes / run["device"]["peaks"]["hbm_bytes_per_s"] \
+        / update_s

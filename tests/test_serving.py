@@ -2618,18 +2618,24 @@ def test_bypass_registry_audit(setup):
             compute_bypass_reasons(**{gone: 1})
     reachable = {k: set() for k in BYPASS_ALLOWLIST}
     eva_reach = {k: set() for k in BYPASS_ALLOWLIST}
-    for eva, spec_on, shards, q, dq, pd in itertools.product(
-            (False, True), (False, True), (1, 2, 4), (False, True),
-            (False, True), (0, 1)):
+    rec_reach = {k: set() for k in BYPASS_ALLOWLIST}
+    for eva, rec, spec_on, shards, q, dq, pd in itertools.product(
+            (False, True), (False, True), (False, True), (1, 2, 4),
+            (False, True), (False, True), (0, 1)):
+        if eva and rec:
+            continue    # a typed stack runs full attention (config check)
         reasons = compute_bypass_reasons(
             speculative=spec_on, n_shards=shards, quantized_cache=q,
-            draft_quantized_cache=dq, pipeline_depth=pd, eva=eva)
+            draft_quantized_cache=dq, pipeline_depth=pd, eva=eva,
+            recurrent=rec)
         assert set(reasons) == set(BYPASS_ALLOWLIST)
         for reg, val in reasons.items():
             if val is not None:
-                (eva_reach if eva else reachable)[reg].add(val)
+                (eva_reach if eva else rec_reach if rec
+                 else reachable)[reg].add(val)
     for reg in BYPASS_ALLOWLIST:
-        extra = (reachable[reg] | eva_reach[reg]) - set(BYPASS_ALLOWLIST[reg])
+        extra = (reachable[reg] | eva_reach[reg] | rec_reach[reg]) \
+            - set(BYPASS_ALLOWLIST[reg])
         assert not extra, (
             f"bypass registry {reg!r} reaches undocumented reasons "
             f"{sorted(extra)} — add a burn-down plan or remove the "
@@ -2643,6 +2649,20 @@ def test_bypass_registry_audit(setup):
         assert "eva summary pages" in eva_reach[reg], reg
         assert "eva summary pages" not in reachable[reg], reg
     assert "eva window close" in eva_reach["pipeline"]
+    # A recurrent row state (a typed stack's mamba layers, PR 32): a row is
+    # its pages AND a state no page holds, so the same five surfaces close
+    # with ONE reason of their own; the pipelined carry composes (the state
+    # store rides the donated pool), so the lag registry gains nothing.
+    for reg in ("prefix_cache", "kv_tier", "suspend", "speculative",
+                "kv_export"):
+        assert "recurrent row state" in rec_reach[reg], reg
+        assert "recurrent row state" not in reachable[reg], reg
+        assert "recurrent row state" not in eva_reach[reg], reg
+    assert rec_reach["pipeline"] == {"speculative decoding"}
+    assert compute_bypass_reasons(
+        recurrent=True, pipeline_depth=1)["pipeline"] is None
+    assert compute_bypass_reasons(
+        recurrent=True, pipeline_depth=1)["suspend"] == "recurrent row state"
     assert compute_bypass_reasons(
         eva=True, pipeline_depth=1)["pipeline"] == "eva window close"
     assert reachable["pipeline"] == {"speculative decoding"}

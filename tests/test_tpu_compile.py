@@ -87,6 +87,31 @@ _POS, _LAYER, _TABLE = ((B,), I32), ((), I32), ((B, NP), I32)
 _POOL = ((L, POOL, H, PAGE, D), BF16)
 
 
+def _grouped(tile):
+    """The grouped expert layer's two kernels over one sorted buffer, the
+    expert stacks whole and the layer index riding the scalar prefetch."""
+    from tfmesos_tpu.ops import moe
+
+    def fn(x, wg, wu, wd, tile_expert, live, layer):
+        mid = moe.grouped_swiglu(x, wg, wu, tile_expert, live, tile,
+                                 layer=layer, use_pallas=True)
+        return moe.grouped_matmul(mid, wd, tile_expert, live, tile,
+                                  layer=layer, use_pallas=True)
+    return fn
+
+
+def _cell_experts(tokens):
+    """A step of ``granite4h.gen_batch``: 36 held experts of 10 layers,
+    4096 -> 768 -> 4096, ``tokens`` x top-10 assignments over 72."""
+    from tfmesos_tpu.ops import moe
+    tile = moe.pick_tile(tokens * 10, 72)
+    rows = -(-tokens * 10 // tile) * tile + 36 * tile
+    return (None, lambda m: _grouped(tile),
+            [((rows, 4096), BF16), ((10, 36, 4096, 768), BF16),
+             ((10, 36, 4096, 768), BF16), ((10, 36, 768, 4096), BF16),
+             ((rows // tile,), I32), ((1,), I32), ((), I32)], 2)
+
+
 def _cell_paged(rows, kv, g, width, pages, t, int8=False):
     """A decode step of a benchmark cell: 16 layers, heads of 128, page 64."""
     shape = (16, pages, kv, PAGE, 128)
@@ -161,6 +186,17 @@ CASES = {
     # rows, 32 heads x 1, width 64: 16 heads x 8 pages), the 16-layer pools
     # of their cells, the deferred self operand; a speculative chunk
     # (t = 4) and an int8 pool.
+    # granite4h.gen_batch: the grouped expert kernels at a decode step's 64
+    # rows (tiles of 16), a mid prefill (tiles of 32) and the widest (128),
+    # and the paged kernel at 64 rows over the one-layer pool
+    "moe_grouped_decode_r64": _cell_experts(64),
+    "moe_grouped_prefill_t512": _cell_experts(512),
+    "moe_grouped_prefill_t4096": _cell_experts(4096),
+    "paged_granite_w128_self": (None, lambda m: _paged,
+                                [((64, 1, 32, 128), BF16)]
+                                + [((1, 4096, 8, PAGE, 128), BF16)] * 2
+                                + [((64, 128), I32), ((64,), I32), _LAYER]
+                                + [((64, 1, 8, 128), BF16)] * 2, 1),
     "paged_mistral_w128_self": _cell_paged(32, 8, 4, 128, 1300, 1),
     "paged_evabyte_w64_self": _cell_paged(16, 32, 1, 64, 372, 1),
     "paged_mistral_w128_t4_self": _cell_paged(32, 8, 4, 128, 1300, 4),
@@ -284,6 +320,76 @@ def test_paged_commit_never_relayouts_the_pool(topo, monkeypatch, name):
     moved = re.findall(r"= " + re.escape(leaf)
                        + r"\S* (?:copy|transpose)\([^)]*\)", text)
     assert not moved, f"{leaf} is relayouted: {moved[:2]}"
+
+
+# -- a typed stack's state store and pool stay where they are (PR 32) ---------
+#
+# ``decode_step`` over a typed stack at Granite-4.0-H-Small's widths (10
+# layers, 9 of them Mamba-2: a 2.4 GB float32 row-state store beside a
+# one-layer page pool), compiled whole for the described v5e with the kernel
+# paths forced.  The store and the pool are donated and must be updated in
+# place: with heads and head channels as two dims of the store the compiler
+# gave it the layout the SSD scan's last einsum liked and wrapped the
+# prefill's state write in two copies of the WHOLE store.
+
+@pytest.mark.parametrize("name,rows,t", [("decode_r64", 64, 1),
+                                         ("prefill_t1024", 1, 1024)])
+def test_typed_step_never_relayouts_the_state_store(topo, monkeypatch, name,
+                                                    rows, t):
+    from tfmesos_tpu.models import transformer
+    from tfmesos_tpu.ops import moe
+    from tfmesos_tpu.ops.attention import attend
+
+    kinds = ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
+    cfg = transformer.TransformerConfig(
+        vocab_size=100352, d_model=4096, n_layers=10, n_heads=32,
+        n_kv_heads=8, d_ff=768, max_seq_len=8192,
+        dtype=BF16, param_dtype=BF16, layer_types=kinds, mamba_heads=128,
+        mamba_head_dim=64, mamba_state=128, rope=False,
+        attn_scale=0.0078125, embed_scale=12.0, residual_scale=0.22,
+        logits_scale=16.0, tie_embeddings=True, norm_eps=1e-5,
+        logits_dtype=F32, n_experts=72, top_k=10, moe_impl="grouped",
+        experts_held=36, shared_d_ff=1536)
+    monkeypatch.setattr(transformer, "_decode_kernel_kwargs",
+                        lambda *a, **k: {"use_pallas": True})
+    monkeypatch.setattr(transformer, "attend",
+                        partial(attend, use_pallas=True))
+    monkeypatch.setattr(moe, "_on_tpu", lambda use: True)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def struct(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    slots = 64
+    params = jax.tree_util.tree_map(struct, jax.eval_shape(
+        lambda: transformer.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = dict(jax.eval_shape(
+        lambda: transformer.init_paged_cache(cfg, 4096, PAGE)))
+    cache["state"] = jax.eval_shape(
+        lambda: transformer.init_row_state(cfg, slots))
+    cache["pages"] = jnp.zeros((rows, 128), I32)
+    if t > 1:
+        cache["slots"] = jnp.zeros((rows,), I32)
+        cache["valid"] = jnp.zeros((rows,), I32)
+    cache = jax.tree_util.tree_map(struct, cache)
+    tokens = jax.ShapeDtypeStruct((rows, t), I32, sharding=one_chip)
+    if t == 1:
+        pos = (jax.ShapeDtypeStruct((rows,), I32, sharding=one_chip),)
+        step = jax.jit(lambda p, c, tok, at: transformer.decode_step(
+            cfg, p, c, tok, at), donate_argnums=1)
+    else:
+        pos = ()
+        step = jax.jit(lambda p, c, tok: transformer.decode_step(
+            cfg, p, c, tok, 0), donate_argnums=1)
+    text = step.lower(params, cache, tokens, *pos).compile().as_text()
+    for kernel in ("moe_grouped_swiglu", "moe_grouped_matmul"):
+        assert kernel in text, kernel
+    assert ("flash_decode_paged" if t == 1 else "flash_attention_fwd") in text
+    for leaf in (f"f32[9,{slots},8192,128]", f"bf16[1,4096,8,{PAGE},128]",
+                 "bf16[10,36,4096,768]", "bf16[10,36,768,4096]"):
+        moved = re.findall(r"= " + re.escape(leaf)
+                           + r"\S* (?:copy|transpose|slice)\([^)]*\)", text)
+        assert not moved, f"{leaf} is copied: {moved[:2]}"
 
 
 # -- EVA attention at EvaByte's widths (PR 28) ---------------------------------

@@ -333,13 +333,14 @@ def build_serve_parser() -> argparse.ArgumentParser:
                         "prefill only their uncached tail, and the "
                         "gateway routes shared prefixes to the replica "
                         "already holding them (prefix-affinity)")
-    p.add_argument("--pipeline-depth", type=int, default=0,
+    p.add_argument("--pipeline-depth", type=int, default=None,
                    choices=(0, 1), dest="pipeline_depth",
                    help="1 pipelines each replica's decode loop with a "
                         "device-resident carry (dispatch block N+1 "
                         "before syncing block N's tokens; token "
                         "streams identical to 0, the synchronous "
-                        "default — docs/SERVING.md)")
+                        "loop); not given, each replica's batcher "
+                        "chooses (docs/SERVING.md)")
     p.add_argument("--fused-prefill", action="store_true",
                    dest="fused_prefill",
                    help="stall-free decode ticks: fuse a token-budgeted "

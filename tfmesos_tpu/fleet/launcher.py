@@ -117,7 +117,7 @@ class FleetServer:
                  prefill_bucket: Optional[int] = None,
                  multi_step: int = 1,
                  prefix_cache_pages: int = 0,
-                 pipeline_depth: int = 0,
+                 pipeline_depth: Optional[int] = None,
                  fused_prefill: bool = False,
                  tokens_per_tick: Optional[int] = None,
                  draft: bool = False,
@@ -285,7 +285,9 @@ class FleetServer:
         self.prefill_bucket = prefill_bucket
         self.multi_step = int(multi_step)
         self.prefix_cache_pages = int(prefix_cache_pages)
-        self.pipeline_depth = int(pipeline_depth)
+        #: None: not passed on, each replica's batcher chooses
+        self.pipeline_depth = (None if pipeline_depth is None
+                               else int(pipeline_depth))
         #: stall-free fused scheduling per replica (docs/SERVING.md
         #: "Stall-free fused scheduling"): one dispatch per tick covers
         #: the decode block AND a budgeted batch of prefill chunk
@@ -534,7 +536,7 @@ class FleetServer:
             parts += ["--multi-step", str(self.multi_step)]
         if self.prefix_cache_pages:
             parts += ["--prefix-cache-pages", str(self.prefix_cache_pages)]
-        if self.pipeline_depth:
+        if self.pipeline_depth is not None:
             parts += ["--pipeline-depth", str(self.pipeline_depth)]
         if self.fused_prefill:
             parts.append("--fused-prefill")

@@ -1302,14 +1302,16 @@ def build_parser() -> argparse.ArgumentParser:
                         "heartbeat KV-tier occupancy so parks avoid "
                         "peers whose tiers are nearly full "
                         "(docs/SERVING.md 'Cross-host KV fabric')")
-    p.add_argument("--pipeline-depth", type=int, default=0,
+    p.add_argument("--pipeline-depth", type=int, default=None,
                    choices=(0, 1), dest="pipeline_depth",
                    help="1 pipelines the decode loop with a device-"
                         "resident carry: block N+1 dispatches from the "
                         "previous block's on-device outputs and block "
                         "N's tokens sync one block behind — token "
-                        "streams identical to 0 (the default, fully "
-                        "synchronous; docs/SERVING.md)")
+                        "streams identical to 0 (fully synchronous).  "
+                        "Not given, the batcher chooses: 1 for a model "
+                        "whose rows keep a recurrent state, else 0 "
+                        "(docs/SERVING.md)")
     p.add_argument("--draft", action="store_true",
                    help="serve with a DRAFT model (speculative "
                         "decoding): each tick the draft proposes "

@@ -22,7 +22,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -180,6 +180,52 @@ class TransformerConfig:
     # [d_model, n_pred_heads * vocab_size], head 0 (the first vocab_size
     # columns) is the next token.  Decoding computes head 0 only.
     n_pred_heads: int = 1
+    # A LAYER PATTERN (None: one homogeneous stack of attention blocks, as
+    # ever).  One entry per layer, "attention" | "mamba": the mixer of each
+    # block; every block's second half is the same feed-forward / expert
+    # layer.  The stack is scanned over periods of the pattern (the shortest
+    # prefix that repeats) and, inside a period, over each run of layers of
+    # one kind.  Parameters of a typed stack: the leaves every layer has
+    # (norms, feed-forward, experts) stacked [L, ...] as before, the mixers'
+    # stacked by kind under ``layers["attention"]`` / ``layers["mamba"]``.
+    # Serving path only (``decode_step`` through a paged cache): K/V pages
+    # for the attention layers (the pool's leading dim is their count) and
+    # a per-row recurrent state for the mamba layers (``init_row_state``).
+    layer_types: Optional[Tuple[str, ...]] = None
+    # The Mamba-2 (SSD) mixer: ``mamba_heads`` heads of ``mamba_head_dim``
+    # (d_inner = their product), a state of ``mamba_state`` per head
+    # channel, ONE B/C group shared by every head, a causal depthwise conv
+    # of ``mamba_conv`` taps over [x | B | C], prefill in chunks of
+    # ``mamba_chunk``.  The row state is float32 (``init_row_state``): a
+    # bfloat16 state is a different result, not a faster one.
+    mamba_heads: int = 0
+    mamba_head_dim: int = 64
+    mamba_state: int = 128
+    mamba_conv: int = 4
+    mamba_chunk: int = 256
+    # Attention as a configuration states it: ``rope`` False applies no
+    # positional embedding; ``attn_scale`` is the softmax scale (None:
+    # head_dim ** -0.5).
+    rope: bool = True
+    attn_scale: Optional[float] = None
+    # Multipliers (None: absent): the embedding's output is scaled by
+    # ``embed_scale``, every block adds ``residual_scale`` times its mixer
+    # / feed-forward output, the logits are divided by ``logits_scale``.
+    embed_scale: Optional[float] = None
+    residual_scale: Optional[float] = None
+    logits_scale: Optional[float] = None
+    # The head is the embedding's transpose (no ``head`` leaf).
+    tie_embeddings: bool = False
+    # Experts held HERE, beside ``n_experts`` (the router's width): the
+    # expert leaves hold ``experts_held`` experts (None: all), the experts
+    # ``expert_offset .. expert_offset + experts_held - 1``.  The router
+    # still picks over all ``n_experts``; assignments that fall on experts
+    # held elsewhere add nothing here.  ``moe_impl="grouped"`` only.
+    experts_held: Optional[int] = None
+    expert_offset: int = 0
+    # Width of the always-on shared MLP where the configuration states it
+    # by itself (None: n_shared_experts * d_ff).
+    shared_d_ff: Optional[int] = None
 
     @property
     def head_dim(self) -> int:
@@ -204,6 +250,94 @@ class TransformerConfig:
         if self.n_pred_heads < 1:
             raise ValueError(f"n_pred_heads must be >= 1, got "
                              f"{self.n_pred_heads}")
+        if self.moe_impl not in ("dense", "switch", "grouped"):
+            raise ValueError(f"moe_impl must be 'dense', 'switch' or "
+                             f"'grouped', got {self.moe_impl!r}")
+        if self.experts_held is not None or self.expert_offset:
+            if self.moe_impl != "grouped":
+                raise ValueError("experts_held / expert_offset need "
+                                 "moe_impl='grouped' (the expert layer that "
+                                 "is told which experts it holds)")
+            if not (0 <= self.expert_offset
+                    and self.expert_offset + self.held_experts
+                    <= self.n_experts and self.held_experts >= 1):
+                raise ValueError(
+                    f"experts {self.expert_offset}..+{self.held_experts} "
+                    f"are not among the router's {self.n_experts}")
+        if self.layer_types is not None:
+            object.__setattr__(self, "layer_types", tuple(self.layer_types))
+            bad = set(self.layer_types) - {"attention", "mamba"}
+            if bad or len(self.layer_types) != self.n_layers:
+                raise ValueError(
+                    f"layer_types takes n_layers ({self.n_layers}) entries "
+                    f"of 'attention' | 'mamba', got {self.layer_types!r}")
+            if self.attention != "full" or self.window is not None:
+                raise ValueError("layer_types composes with full attention "
+                                 "only (no window, no EVA)")
+            if "mamba" in self.layer_types and (
+                    self.mamba_heads < 1 or self.mamba_conv < 2):
+                raise ValueError("mamba layers need mamba_heads >= 1 and "
+                                 "mamba_conv >= 2")
+
+    @property
+    def held_experts(self) -> int:
+        """Experts whose weights this shard holds."""
+        return (self.n_experts if self.experts_held is None
+                else self.experts_held)
+
+    @property
+    def shared_width(self) -> int:
+        """Width of the always-on shared MLP (0: none)."""
+        if self.shared_d_ff is not None:
+            return self.shared_d_ff
+        return self.n_shared_experts * self.d_ff
+
+    @property
+    def layer_kinds(self) -> Tuple[str, ...]:
+        return self.layer_types or ("attention",) * self.n_layers
+
+    @property
+    def n_attn_layers(self) -> int:
+        """Layers that keep K/V: the paged pool's leading dim."""
+        return self.layer_kinds.count("attention")
+
+    @property
+    def n_mamba_layers(self) -> int:
+        """Layers that keep a recurrent row state."""
+        return self.layer_kinds.count("mamba")
+
+    @property
+    def layer_period(self) -> int:
+        """Length of the shortest prefix of ``layer_types`` that, repeated,
+        gives the whole pattern."""
+        kinds = self.layer_kinds
+        return next(p for p in range(1, len(kinds) + 1)
+                    if len(kinds) % p == 0
+                    and kinds == kinds[:p] * (len(kinds) // p))
+
+    @property
+    def layer_runs(self):
+        """One period as runs of layers of one kind: ``(kind, first layer
+        in the period, layers, first index among the period's layers of
+        that kind)``."""
+        period = self.layer_kinds[:self.layer_period]
+        runs, seen = [], {"attention": 0, "mamba": 0}
+        for j, kind in enumerate(period):
+            if runs and runs[-1][0] == kind:
+                runs[-1][2] += 1
+            else:
+                runs.append([kind, j, 1, seen[kind]])
+            seen[kind] += 1
+        return tuple(tuple(r) for r in runs)
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_heads * self.mamba_head_dim
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """Channels of the conv: [x | B | C], B and C of one group."""
+        return self.mamba_inner + 2 * self.mamba_state
 
     @property
     def eva_summaries(self) -> int:
@@ -242,14 +376,14 @@ class TransformerConfig:
 
 
 def init_params(cfg: TransformerConfig, rng) -> Dict[str, Any]:
-    if cfg.n_shared_experts and not cfg.n_experts:
+    if cfg.shared_width and not cfg.n_experts:
         raise ValueError(
             "n_shared_experts requires n_experts > 0 — without routed "
             "experts there is nothing to share beside; widen d_ff instead")
     d, f, l = cfg.d_model, cfg.d_ff, cfg.n_layers
     hd = cfg.n_heads * cfg.head_dim
     kvd = cfg.kv_heads * cfg.head_dim
-    keys = iter(jax.random.split(rng, 16))
+    keys = iter(jax.random.split(rng, 32 if cfg.layer_types else 16))
 
     def norm(shape, scale):
         return (jax.random.normal(next(keys), shape, cfg.param_dtype)
@@ -257,14 +391,43 @@ def init_params(cfg: TransformerConfig, rng) -> Dict[str, Any]:
 
     # a gain g with the unit offset scales by (1 + g): the identity is 0
     gain = jnp.zeros if cfg.norm_offset else jnp.ones
-    layers = {
-        "attn_norm": gain((l, d), cfg.param_dtype),
-        "wq": norm((l, d, hd), 1 / math.sqrt(d)),
-        "wk": norm((l, d, kvd), 1 / math.sqrt(d)),
-        "wv": norm((l, d, kvd), 1 / math.sqrt(d)),
-        "wo": norm((l, hd, d), 1 / math.sqrt(hd) / math.sqrt(2 * l)),
-        "mlp_norm": gain((l, d), cfg.param_dtype),
+    la = cfg.n_attn_layers
+    attn = {
+        "wq": norm((la, d, hd), 1 / math.sqrt(d)),
+        "wk": norm((la, d, kvd), 1 / math.sqrt(d)),
+        "wv": norm((la, d, kvd), 1 / math.sqrt(d)),
+        "wo": norm((la, hd, d), 1 / math.sqrt(hd) / math.sqrt(2 * l)),
     }
+    layers = {"attn_norm": gain((l, d), cfg.param_dtype),
+              "mlp_norm": gain((l, d), cfg.param_dtype)}
+    if cfg.layer_types is None:
+        layers.update(attn)
+    else:
+        # typed stack: the mixers' leaves by kind, [layers of that kind, ..]
+        if la:
+            layers["attention"] = attn
+        lm = cfg.n_mamba_layers
+        if lm:
+            di, nh, cd = cfg.mamba_inner, cfg.mamba_heads, cfg.mamba_conv_dim
+            u = lambda shape, lo, hi: jax.random.uniform(
+                next(keys), shape, jnp.float32, lo, hi)
+            # dt = softplus(dt_bias + ...) spread over 1e-3 .. 1e-1 and
+            # A = -exp(A_log) over -1 .. -16: steps and decays far from 0/1
+            dt0 = jnp.exp(u((lm, nh), math.log(1e-3), math.log(1e-1)))
+            layers["mamba"] = {
+                "in_proj": norm((lm, d, di + cd + nh), 1 / math.sqrt(d)),
+                "conv_w": norm((lm, cfg.mamba_conv, cd),
+                               1 / math.sqrt(cfg.mamba_conv)),
+                "conv_b": norm((lm, cd), 0.1),
+                "dt_bias": (dt0 + jnp.log(-jnp.expm1(-dt0))
+                            ).astype(cfg.param_dtype),
+                "A_log": jnp.log(u((lm, nh), 1.0, 16.0)
+                                 ).astype(cfg.param_dtype),
+                "D": jnp.ones((lm, nh), cfg.param_dtype),
+                "norm": jnp.ones((lm, di), cfg.param_dtype),
+                "out_proj": norm((lm, di, d),
+                                 1 / math.sqrt(di) / math.sqrt(2 * l)),
+            }
     if cfg.attention == "eva":
         # the chunk pooling's query and the summaries' key offset, per
         # layer and head (unit scale: pooling weights far from uniform)
@@ -272,15 +435,15 @@ def init_params(cfg: TransformerConfig, rng) -> Dict[str, Any]:
             eva_phi=norm((l, cfg.kv_heads, cfg.head_dim), 1.0),
             eva_mu=norm((l, cfg.kv_heads, cfg.head_dim), 1.0))
     if cfg.n_experts:
-        e = cfg.n_experts
+        e, eh = cfg.n_experts, cfg.held_experts
         layers.update(
             router=norm((l, d, e), 1 / math.sqrt(d)),
-            e_gate=norm((l, e, d, f), 1 / math.sqrt(d)),
-            e_up=norm((l, e, d, f), 1 / math.sqrt(d)),
-            e_down=norm((l, e, f, d), 1 / math.sqrt(f) / math.sqrt(2 * l)),
+            e_gate=norm((l, eh, d, f), 1 / math.sqrt(d)),
+            e_up=norm((l, eh, d, f), 1 / math.sqrt(d)),
+            e_down=norm((l, eh, f, d), 1 / math.sqrt(f) / math.sqrt(2 * l)),
         )
-        if cfg.n_shared_experts:
-            sf = cfg.n_shared_experts * f
+        if cfg.shared_width:
+            sf = cfg.shared_width
             layers.update(
                 s_gate=norm((l, d, sf), 1 / math.sqrt(d)),
                 s_up=norm((l, d, sf), 1 / math.sqrt(d)),
@@ -293,20 +456,24 @@ def init_params(cfg: TransformerConfig, rng) -> Dict[str, Any]:
             w_up=norm((l, d, f), 1 / math.sqrt(d)),
             w_down=norm((l, f, d), 1 / math.sqrt(f) / math.sqrt(2 * l)),
         )
-    return {
+    params = {
         "embed": norm((cfg.vocab_size, d), 1.0),
         "layers": layers,
         "norm_f": gain((d,), cfg.param_dtype),
         "head": norm((d, cfg.n_pred_heads * cfg.vocab_size),
                      1 / math.sqrt(d)),
     }
+    if cfg.tie_embeddings:
+        del params["head"]
+    return params
 
 
 #: weight leaves worth quantizing — the big matmul operands.  Norms are
 #: tiny and precision-critical; the router is tiny and decides routing.
 _QUANT_KEYS = frozenset(
     {"wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
-     "e_gate", "e_up", "e_down", "s_gate", "s_up", "s_down"})
+     "e_gate", "e_up", "e_down", "s_gate", "s_up", "s_down",
+     "in_proj", "out_proj"})
 
 
 def _quantizable(cfg: TransformerConfig, key: str) -> bool:
@@ -331,14 +498,20 @@ def quantize_params(cfg: TransformerConfig, params) -> Dict[str, Any]:
     steady-state decode at t=1 is weight-bandwidth-bound, and int8 halves
     the bytes per step vs bf16 (~4x vs these fp32 master params).
     """
-    layers = {k: (quantize_tensor(v) if _quantizable(cfg, k) else v)
-              for k, v in params["layers"].items()}
-    return {
+    def quantize(tree):
+        # a typed stack keeps its mixers' leaves one level down, by kind
+        return {k: (quantize(v) if isinstance(v, dict) else
+                    quantize_tensor(v) if _quantizable(cfg, k) else v)
+                for k, v in tree.items()}
+
+    out = {
         "embed": quantize_tensor(params["embed"]),
-        "layers": layers,
+        "layers": quantize(params["layers"]),
         "norm_f": params["norm_f"],
-        "head": quantize_tensor(params["head"]),
     }
+    if "head" in params:
+        out["head"] = quantize_tensor(params["head"])
+    return out
 
 
 def _qswiglu(h, w_gate, w_up, w_down, dtype):
@@ -451,10 +624,58 @@ def _moe(cfg: TransformerConfig, lp, h, ep_axis: Optional[str] = None,
     return out, aux
 
 
+#: the routed experts' leaves: [L, held, ...] stacks that the grouped kernels
+#: index by layer themselves
+_EXPERT_LEAVES = ("e_gate", "e_up", "e_down")
+
+
+def _moe_grouped(cfg: TransformerConfig, lp, h,
+                 ep_axis: Optional[str] = None, layer=None):
+    """Top-k routed experts in the sorted, grouped, drop-free form
+    (``ops/moe.py``): the router picks over all ``n_experts``, the
+    assignments that fall on the experts THIS shard holds (``held_experts``
+    of them from ``expert_offset``; under a manual ``ep_axis`` the shard's
+    index moves the offset on) are sorted by expert and one grouped matmul
+    runs over them: no capacity, no drops, no every-expert-every-token.
+    What the experts held elsewhere would add is another shard's partial
+    sum: joined by one psum under ``ep_axis``, left out without one.
+    ``layer`` (traced OK): the expert leaves are whole ``[L, held, ...]``
+    stacks and this is the layer to run (the kernels index it through their
+    scalar prefetch; no layer's experts are copied out).  Returns (out,
+    aux); ``aux["expert_counts"]`` [held] int32 is how many assignments
+    each held expert took."""
+    from tfmesos_tpu.ops.moe import grouped_experts
+    b, t, d = h.shape
+    flat = h.reshape(b * t, d)
+    # float32 logits (bf16 operands, accumulated and kept in float32): a
+    # logit rounded to bf16 ties with its neighbours and moves the top-k
+    logits = jnp.dot(flat, lp["router"].astype(cfg.dtype),
+                     preferred_element_type=jnp.float32)
+    held = cfg.held_experts
+    offset = jnp.asarray(cfg.expert_offset, jnp.int32)
+    if ep_axis is not None:
+        offset = offset + jax.lax.axis_index(ep_axis) * held
+    ws = [lp[k] for k in _EXPERT_LEAVES]
+    if layer is not None and isinstance(ws[0], QTensor):
+        # int8 experts dequantize on use: one layer's, not the stack's
+        ws = [jax.tree_util.tree_map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, False), w)
+            for w in ws]
+        layer = None
+    out, counts = grouped_experts(
+        flat, logits, *(_wt(w, cfg.dtype) for w in ws), offset, layer,
+        top_k=cfg.top_k, held=held)
+    if ep_axis is not None:
+        out = jax.lax.psum(out, ep_axis)
+    return out.reshape(b, t, d), {**_zero_aux(), "expert_counts": counts}
+
+
 def _ffn(cfg: TransformerConfig, mesh, lp, h, ep_axis: Optional[str] = None,
-         tp_axis: Optional[str] = None, inbody_ad: bool = False):
-    """The block's feed-forward dispatch (dense / switch / dense-MoE) —
-    shared by the train and decode paths so they cannot drift.
+         tp_axis: Optional[str] = None, inbody_ad: bool = False,
+         expert_layer=None):
+    """The block's feed-forward dispatch (dense / switch / dense-MoE /
+    grouped) — shared by the train and decode paths so they cannot drift.
+    ``expert_layer``: see ``_moe_grouped``'s ``layer``.
 
     ``ep_axis``/``tp_axis`` select the manual-collective MoE forms for use
     inside a pipeline stage's shard_map body (tokens replicated over
@@ -464,7 +685,14 @@ def _ffn(cfg: TransformerConfig, mesh, lp, h, ep_axis: Optional[str] = None,
     dispatch path still assumes outer differentiation)."""
     if not cfg.n_experts:
         return _mlp(cfg, lp, h), _zero_aux()
-    if ep_axis is not None or tp_axis is not None:
+    if cfg.moe_impl == "grouped" and (ep_axis is not None
+                                      or tp_axis is not None):
+        if tp_axis is not None or inbody_ad:
+            raise ValueError("moe_impl='grouped' shards whole experts over "
+                             "ep; it has no tp or in-body-AD form")
+        out, aux = _moe_grouped(cfg, lp, h, ep_axis=ep_axis,
+                                layer=expert_layer)
+    elif ep_axis is not None or tp_axis is not None:
         if cfg.moe_impl == "switch":
             if inbody_ad:
                 raise ValueError(
@@ -486,9 +714,11 @@ def _ffn(cfg: TransformerConfig, mesh, lp, h, ep_axis: Optional[str] = None,
         # Same model function with or without a mesh (switch_moe falls back
         # to its single-device reference when the ep axis is absent).
         out, aux = _moe_switch(cfg, mesh, lp, h)
+    elif cfg.moe_impl == "grouped":
+        out, aux = _moe_grouped(cfg, lp, h, layer=expert_layer)
     else:
         out, aux = _moe(cfg, lp, h)
-    if cfg.n_shared_experts:
+    if cfg.shared_width:
         # Always-on shared expert(s): dense FFN added to the routed output.
         # The shared weights replicate over ep; under manual tp their width
         # shards like the dense MLP's, so the partial needs its own psum
@@ -730,14 +960,25 @@ def forward_hidden(cfg: TransformerConfig, params, tokens,
     ring attention receives the full logical sequence sharded along T, and
     rope positions follow the global index.
     """
+    if cfg.layer_types is not None or cfg.moe_impl == "grouped":
+        raise NotImplementedError(
+            "forward() runs one homogeneous stack with the trainer's expert "
+            "forms; layer_types and moe_impl='grouped' are the serving "
+            "path's (decode_step through a paged cache)")
     if (cfg.attention != "full" or cfg.n_pred_heads != 1
             or cfg.residual_dtype is not None
-            or cfg.logits_dtype is not None):
+            or cfg.logits_dtype is not None
+            or not cfg.rope or cfg.tie_embeddings
+            or any(v is not None for v in (
+                cfg.attn_scale, cfg.embed_scale, cfg.residual_scale,
+                cfg.logits_scale))):
         raise NotImplementedError(
-            "forward() runs full attention, one output head and a residual "
-            "stream in the compute dtype; attention='eva', n_pred_heads, "
-            "residual_dtype and logits_dtype are the serving path's "
-            "(decode_step through a paged cache)")
+            "forward() runs full attention with rope at head_dim ** -0.5, "
+            "one untied output head and a residual stream in the compute "
+            "dtype; attention='eva', n_pred_heads, residual_dtype, "
+            "logits_dtype, rope=False, attn_scale, the multipliers and "
+            "tie_embeddings are the serving path's (decode_step through a "
+            "paged cache)")
     b, t = tokens.shape
     x = _embed_lookup(params["embed"], tokens, cfg.dtype)
     positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
@@ -880,6 +1121,10 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
         raise ValueError("attention='eva' keeps summaries and a window in "
                          "a paged cache (init_paged_cache); it has no "
                          "linear one")
+    if cfg.layer_types is not None:
+        raise ValueError("a typed stack (layer_types) keeps pages and row "
+                         "states (init_paged_cache, init_row_state); it has "
+                         "no linear cache")
     if cfg.window is not None:
         max_len = min(max_len, cfg.window)
     shape = (cfg.n_layers, batch, cfg.kv_heads, max_len, cfg.head_dim)
@@ -937,7 +1182,7 @@ def init_paged_cache(cfg: TransformerConfig, n_pages: int,
         if dtype is not None:
             raise ValueError("init_paged_cache: dtype and quantized=True "
                              "conflict (an int8 pool's dtypes are fixed)")
-        shape = (cfg.n_layers, n_pages, cfg.kv_heads, page_size,
+        shape = (cfg.n_attn_layers, n_pages, cfg.kv_heads, page_size,
                  cfg.head_dim)
 
         def buf():
@@ -954,8 +1199,29 @@ def init_paged_cache(cfg: TransformerConfig, n_pages: int,
     dtype = dtype or cfg.dtype
     # (page, head_dim) trailing — the kernel's native layout, so serving
     # never transposes the shared pool.
-    shape = (cfg.n_layers, n_pages, cfg.kv_heads, page_size, cfg.head_dim)
+    shape = (cfg.n_attn_layers, n_pages, cfg.kv_heads, page_size,
+             cfg.head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+
+def init_row_state(cfg: TransformerConfig, rows: int) -> Dict[str, Any]:
+    """The recurrent state of ``rows`` row slots, for the layers that keep
+    no K/V (a typed stack's mamba layers): ``ssm`` [mamba layers, rows,
+    heads * head_dim, state] float32 (heads and head channels as one dim,
+    see ``_mamba_mixer``) and ``conv``
+    [mamba layers, rows, mamba_conv - 1, conv channels], the conv's inputs
+    before each row's next position, in the compute dtype.  Its size does
+    not depend on a row's context.  Pass it to ``decode_step`` under
+    ``cache["state"]`` beside the pool; a one-token step reads and writes
+    every slot, a prefill from position 0 starts from an empty state and
+    writes its rows' slots (``cache["slots"]``), whatever they held."""
+    lm = cfg.n_mamba_layers
+    return {
+        "ssm": jnp.zeros((lm, rows, cfg.mamba_inner, cfg.mamba_state),
+                         jnp.float32),
+        "conv": jnp.zeros((lm, rows, cfg.mamba_conv - 1, cfg.mamba_conv_dim),
+                          cfg.dtype),
+    }
 
 
 class PageAllocator:
@@ -1473,6 +1739,15 @@ def _prefill_kernel_kwargs(cfg: TransformerConfig, mesh: Optional[Mesh],
     return {}
 
 
+def _residual(cfg: TransformerConfig, x, y):
+    """``x + y`` in the residual stream's dtype, ``y`` scaled by the
+    configuration's residual multiplier where it states one."""
+    y = y.astype(x.dtype)
+    if cfg.residual_scale is not None:
+        y = y * jnp.asarray(cfg.residual_scale, x.dtype)
+    return x + y
+
+
 def _block_decode(cfg: TransformerConfig, x, lp, ck, cv, li, positions,
                   pos, sharded: bool = False, mesh: Optional[Mesh] = None,
                   pages=None, kpos=None):
@@ -1485,7 +1760,7 @@ def _block_decode(cfg: TransformerConfig, x, lp, ck, cv, li, positions,
     with jax.named_scope("mlp"):
         h = _norm(cfg, x, lp["mlp_norm"])
         ffn, _ = _ffn(cfg, None, lp, h)
-        return x + ffn.astype(x.dtype), ck, cv, chunk
+        return _residual(cfg, x, ffn), ck, cv, chunk
 
 
 def _attend_decode(cfg: TransformerConfig, x, lp, ck, cv, li, positions,
@@ -1530,8 +1805,11 @@ def _attend_decode(cfg: TransformerConfig, x, lp, ck, cv, li, positions,
     v = _qmm(h, lp["wv"], cfg.dtype).reshape(b, t, cfg.kv_heads,
                                              cfg.head_dim)
     pos_row = positions                                 # [b, t]
-    q = rope(q, pos_row, cfg.rope_theta)
-    k = rope(k, pos_row, cfg.rope_theta)
+    if cfg.rope:
+        q = rope(q, pos_row, cfg.rope_theta)
+        k = rope(k, pos_row, cfg.rope_theta)
+    # a stated softmax scale rides to whichever attention runs below
+    skw = {} if cfg.attn_scale is None else {"scale": cfg.attn_scale}
     rolling = cfg.window is not None
     self_attn_prefill = t > 1 and isinstance(pos, int) and pos == 0
     o_paged = None
@@ -1595,7 +1873,8 @@ def _attend_decode(cfg: TransformerConfig, x, lp, ck, cv, li, positions,
             else:
                 o = mha_reference(q, k, v, causal=True, window=cfg.window)
         else:
-            o = attend(q, k, v, mesh=None, causal=True, window=cfg.window)
+            o = attend(q, k, v, mesh=None, causal=True, window=cfg.window,
+                       **skw)
     elif o_paged is not None:
         o = o_paged
     elif pages is not None:
@@ -1627,12 +1906,12 @@ def _attend_decode(cfg: TransformerConfig, x, lp, ck, cv, li, positions,
         with jax.named_scope("paged_attention"):
             if kw is not None:
                 o = flash_decode_paged(q, ck, cv, pages, at, layer=li,
-                                       self_kv=self_kv, **kw)
+                                       self_kv=self_kv, **kw, **skw)
             else:
                 o = _paged_decode_reference(
                     q, ck, cv, pages, at,
-                    1.0 / math.sqrt(cfg.head_dim), layer=li,
-                    self_kv=self_kv)
+                    skw.get("scale", 1.0 / math.sqrt(cfg.head_dim)),
+                    layer=li, self_kv=self_kv)
     elif (kernel_kw := _decode_kernel_kwargs(cfg, m, t, sharded, mesh,
                                              batch=b)) is not None:
         # Cache-bounded flash-decode kernel (t=1 steps and short chunks —
@@ -1679,8 +1958,39 @@ def _attend_decode(cfg: TransformerConfig, x, lp, ck, cv, li, positions,
         s = jnp.where(bad[:, None, None], -jnp.inf, s)
         probs = jax.nn.softmax(s, axis=-1).astype(cv_r.dtype)
         o = jnp.einsum("bkgtm,bkmd->btkgd", probs, cv_r)
-    x = x + _qmm(o.reshape(b, t, -1), lp["wo"], cfg.dtype).astype(x.dtype)
+    x = _residual(cfg, x, _qmm(o.reshape(b, t, -1), lp["wo"], cfg.dtype))
     return x, ck, cv, ((k, v) if defer else None)
+
+
+def _embed_chunk(cfg: TransformerConfig, params, tokens, pos):
+    """A token chunk into the residual stream, and its positions: ``(x [B,
+    t, d], positions [B, t], ragged)``; ``pos`` is the chunk's first
+    position (scalar, or [B] for a ragged batch)."""
+    b, t = tokens.shape
+    with jax.named_scope("embed"):
+        x = _embed_lookup(params["embed"], tokens, cfg.dtype)
+        if cfg.embed_scale is not None:
+            x = x * jnp.asarray(cfg.embed_scale, cfg.dtype)
+        if cfg.residual_dtype is not None:
+            x = x.astype(cfg.residual_dtype)
+    ragged = getattr(pos, "ndim", 0) == 1
+    offs = jnp.arange(t, dtype=jnp.int32)
+    pos_arr = jnp.asarray(pos, jnp.int32)
+    positions = jnp.broadcast_to(
+        (pos_arr[:, None] if ragged else pos_arr) + offs, (b, t))
+    return x, positions, ragged
+
+
+def _final_logits(cfg: TransformerConfig, params, x):
+    """The last norm and the head (tied or not), divided by the
+    configuration's ``logits_scale`` where it states one."""
+    with jax.named_scope("lm_head"):
+        x = _norm(cfg, x, params["norm_f"])
+        logits = _head_logits(cfg, x, params.get("head"),
+                              embed=params["embed"])
+        if cfg.logits_scale is not None:
+            logits = logits / jnp.asarray(cfg.logits_scale, logits.dtype)
+    return logits
 
 
 def decode_step(cfg: TransformerConfig, params, cache, tokens, pos,
@@ -1718,19 +2028,17 @@ def decode_step(cfg: TransformerConfig, params, cache, tokens, pos,
     dropping tokens by batch-order competition at inference would be worse
     than the mismatch.
     """
+    if cfg.layer_types is not None:
+        if sharded or mesh is not None:
+            raise ValueError("a typed stack (layer_types) decodes on a "
+                             "single host")
+        return _typed_decode_step(cfg, params, cache, tokens, pos)
     b, t = tokens.shape
-    with jax.named_scope("embed"):
-        x = _embed_lookup(params["embed"], tokens, cfg.dtype)
-        if cfg.residual_dtype is not None:
-            x = x.astype(cfg.residual_dtype)
-    ragged = getattr(pos, "ndim", 0) == 1
+    x, positions, ragged = _embed_chunk(cfg, params, tokens, pos)
     if ragged and cfg.window is not None:
         raise ValueError("ragged positions do not compose with "
                          "sliding-window (rolling-cache) configs")
-    offs = jnp.arange(t, dtype=jnp.int32)
     pos_arr = jnp.asarray(pos, jnp.int32)
-    positions = jnp.broadcast_to(
-        (pos_arr[:, None] if ragged else pos_arr) + offs, (b, t))
 
     pages = cache.get("pages")
     kpos = None
@@ -1786,20 +2094,202 @@ def decode_step(cfg: TransformerConfig, params, cache, tokens, pos,
                 at = kpos if ragged else kpos[0]
             new_k = _paged_cache_write_all(new_k, chunks[0], pages, at)
             new_v = _paged_cache_write_all(new_v, chunks[1], pages, at)
-    with jax.named_scope("lm_head"):
-        x = _norm(cfg, x, params["norm_f"])
-        logits = _head_logits(cfg, x, params["head"])
+    logits = _final_logits(cfg, params, x)
     out_cache = {"k": new_k, "v": new_v}
     if pages is not None:
         out_cache["pages"] = pages
     return logits, out_cache
 
 
-def _head_logits(cfg: TransformerConfig, x, head):
+def _mamba_mixer(cfg: TransformerConfig, x, lp, ssm, conv, mi, slots, valid):
+    """The Mamba-2 mixer of one block over a token chunk; returns ``(x,
+    ssm, conv)`` with layer ``mi`` of the stacked row state updated.
+
+    ``x``: [B, t, d]; ``ssm`` [Lm, rows, H * P, N] float32 and ``conv`` [Lm,
+    rows, K - 1, C]: the state store (``init_row_state``), carried through
+    the layer scans.  ``t == 1``: every row is a slot (B == rows): one step
+    of the recurrence from the slot's state.  ``t > 1``: a prefill from an
+    EMPTY state (whatever the slots held), in chunks of ``mamba_chunk``;
+    ``valid`` [B] is each row's number of real positions (the rest is
+    bucket padding, which gets ``dt = 0`` and so leaves the state alone; the
+    conv tail is taken at the true end) and ``slots`` [B] the slots the
+    final states are written to."""
+    from tfmesos_tpu.ops.ssm import (causal_conv, conv_tail, ssd_scan,
+                                     ssm_update)
+    b, t, _ = x.shape
+    f32 = jnp.float32
+    nh, hp, ns = cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_state
+    di, cd, kc = cfg.mamba_inner, cfg.mamba_conv_dim, cfg.mamba_conv
+    h = _norm(cfg, x, lp["attn_norm"])
+    proj = _qmm(h, lp["in_proj"], cfg.dtype)
+    z, xbc, dt = proj[..., :di], proj[..., di:di + cd], proj[..., di + cd:]
+    dt = jax.nn.softplus(dt.astype(f32) + lp["dt_bias"].astype(f32))
+    a = -jnp.exp(lp["A_log"].astype(f32))
+    if t == 1:
+        act, xp = causal_conv(xbc, lp["conv_w"], lp["conv_b"], tail=conv[mi])
+        new_tail = xp[:, 1:]
+    else:
+        live = jnp.arange(t, dtype=jnp.int32)[None] < valid[:, None]
+        dt = jnp.where(live[..., None], dt, 0.0)
+        act, xp = causal_conv(xbc, lp["conv_w"], lp["conv_b"])
+        new_tail = conv_tail(xp, valid, kc)
+    act = jax.nn.silu(act).astype(cfg.dtype)
+    xs = act[..., :di].reshape(b, t, nh, hp)
+    bm, cm = act[..., di:di + ns], act[..., di + ns:]
+    if t == 1:
+        y, new = ssm_update(ssm[mi].reshape(b, nh, hp, ns), xs[:, 0],
+                            dt[:, 0], a, bm[:, 0], cm[:, 0])
+        y = y[:, None]
+        ssm = ssm.at[mi].set(new.reshape(b, nh * hp, ns))
+        conv = conv.at[mi].set(new_tail.astype(conv.dtype))
+    else:
+        y, new = ssd_scan(xs, dt, a, bm, cm,
+                          jnp.zeros((b, nh, hp, ns), f32), cfg.mamba_chunk)
+        # (layer, slot) indexed, the window a trailing slab of the store.
+        # The store keeps heads and head channels as ONE dim: with them
+        # apart the compiler gave the store the layout the scan's last
+        # einsum liked (heads and channels swapped) and relayouted all of
+        # it around this write, two 2.4 GB copies a prefill; now only the
+        # update (4 MB) can be relayouted.
+        ssm = ssm.at[mi, slots].set(
+            new.reshape(b, nh * hp, ns).astype(ssm.dtype))
+        conv = conv.at[mi, slots].set(new_tail.astype(conv.dtype))
+    y = y + lp["D"].astype(f32)[:, None] * xs.astype(f32)
+    # gated RMSNorm, the gate before the norm, one group over d_inner
+    y = y.reshape(b, t, di) * jax.nn.silu(z.astype(f32))
+    kw = {} if cfg.norm_eps is None else {"eps": cfg.norm_eps}
+    y = rms_norm(y, lp["norm"].astype(f32), **kw).astype(cfg.dtype)
+    return _residual(cfg, x, _qmm(y, lp["out_proj"], cfg.dtype)), ssm, conv
+
+
+def _typed_decode_step(cfg: TransformerConfig, params, cache, tokens, pos):
+    """``decode_step`` for a typed stack (``layer_types``): a single-host
+    paged cache for the attention layers and a row-state store for the
+    mamba layers, scanned over the pattern's periods and, inside one, over
+    each run of layers of a kind.
+
+    ``cache``: ``k``/``v`` ([attention layers, P, KV, page, Dh]), ``pages``,
+    ``state`` (``init_row_state``) and, for a prefill (t > 1, which starts
+    at position 0 from an empty state), ``slots`` [B] (the row slots to
+    fill) and ``valid`` [B] (real positions per row; the rest is padding).
+    With ``valid`` the logits come back at each row's LAST real position
+    only ([B, 1, V]): the head is not run over a prompt.  Returns (logits,
+    cache); the cache gains ``expert_counts`` [L, held] int32 where the
+    expert layer is the grouped one (assignments per held expert, this
+    step)."""
+    b, t = tokens.shape
+    pages, state = cache.get("pages"), cache.get("state")
+    if pages is None or (cfg.n_mamba_layers and state is None):
+        raise ValueError("a typed stack decodes through a paged cache and, "
+                         "with mamba layers, a row state (init_paged_cache, "
+                         "init_row_state)")
+    if t > 1 and not (isinstance(pos, int) and pos == 0):
+        raise ValueError("a typed stack's chunk of several tokens is a "
+                         "prefill from position 0")
+    slots, valid = cache.get("slots"), cache.get("valid")
+    if t > 1 and cfg.n_mamba_layers:
+        if slots is None:
+            raise ValueError("a prefill names the row slots it fills "
+                             "(cache['slots'])")
+        if valid is None:
+            valid = jnp.full((b,), t, jnp.int32)
+    x, positions, _ = _embed_chunk(cfg, params, tokens, pos)
+
+    per, runs = cfg.layer_period, cfg.layer_runs
+    n_per = cfg.n_layers // per
+    per_kind = {kind: sum(r[2] for r in runs if r[0] == kind)
+                for kind in ("attention", "mamba")}
+    grouped = bool(cfg.n_experts) and cfg.moe_impl == "grouped"
+    lay = params["layers"]
+    # The stacks stay whole and a layer is INDEXED out of them (a slice of
+    # a stack, e.g. a run's layers as a scan's xs, is a copy of its
+    # weights); the grouped expert kernels take the whole expert stacks
+    # and the layer index (``_EXPERT_LEAVES``: no copy of a layer's experts
+    # in front of a kernel either).
+    common = {k: v for k, v in lay.items()
+              if k not in ("attention", "mamba")}
+    experts = ({k: common.pop(k) for k in _EXPERT_LEAVES} if grouped
+               else {})
+
+    def at(tree, i):
+        return jax.tree_util.tree_map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
+            tree)
+
+    def one_layer(kind, carry, li, ki):
+        """Layer ``li`` of the stack, the ``ki``-th of its kind (a pool /
+        state layer index)."""
+        x, ck, cv, ssm, conv = carry
+        lp = {**at(common, li), **at(lay[kind], ki)}
+        if kind == "attention":
+            with jax.named_scope("attention"):
+                x, ck, cv, chunk = _attend_decode(
+                    cfg, x, lp, ck, cv, ki, positions, pos, False, None,
+                    pages)
+                # committed here, layer by layer, in the pool's own layout:
+                # attention layers are few among the pattern's
+                with jax.named_scope("paged_cache_write"):
+                    ck = _paged_cache_write_all(ck, chunk[0][None], pages,
+                                                pos, layer0=ki)
+                    cv = _paged_cache_write_all(cv, chunk[1][None], pages,
+                                                pos, layer0=ki)
+        else:
+            with jax.named_scope("mamba"):
+                x, ssm, conv = _mamba_mixer(cfg, x, lp, ssm, conv, ki,
+                                            slots, valid)
+        with jax.named_scope("mlp"):
+            h = _norm(cfg, x, lp["mlp_norm"])
+            ffn, aux = _ffn(cfg, None, {**lp, **experts}, h,
+                            expert_layer=li if grouped else None)
+            x = _residual(cfg, x, ffn)
+        counts = (aux["expert_counts"] if grouped
+                  else jnp.zeros((0,), jnp.int32))
+        return (x, ck, cv, ssm, conv), counts
+
+    def period(carry, pi):
+        counts = []
+        for kind, j0, n, k0 in runs:
+            li0, ki0 = pi * per + j0, pi * per_kind[kind] + k0
+            carry, c = jax.lax.scan(
+                lambda cr, i, kind=kind, li0=li0, ki0=ki0: one_layer(
+                    kind, cr, li0 + i, ki0 + i),
+                carry, jnp.arange(n, dtype=jnp.int32))
+            counts.append(c)
+        return carry, jnp.concatenate(counts, axis=0)
+
+    ssm = conv = None
+    if cfg.n_mamba_layers:
+        ssm, conv = state["ssm"], state["conv"]
+    (x, new_k, new_v, ssm, conv), counts = jax.lax.scan(
+        period, (x, cache["k"], cache["v"], ssm, conv),
+        jnp.arange(n_per, dtype=jnp.int32))
+    if valid is not None and t > 1:     # the head at the last real position
+        x = jnp.take_along_axis(x, (valid - 1)[:, None, None], axis=1)
+    logits = _final_logits(cfg, params, x)
+    out_cache = {"k": new_k, "v": new_v, "pages": pages}
+    if cfg.n_mamba_layers:
+        out_cache["state"] = {"ssm": ssm, "conv": conv}
+    if grouped:
+        out_cache["expert_counts"] = counts.reshape(cfg.n_layers, -1)
+    return logits, out_cache
+
+
+def _head_logits(cfg: TransformerConfig, x, head, embed=None):
     """Next-token logits: head 0 of ``n_pred_heads`` (the first
     ``vocab_size`` columns of the head matrix), in ``logits_dtype`` where
     the configuration states one (bf16 operands, accumulated and kept in
-    float32)."""
+    float32).  With ``tie_embeddings`` the head is ``embed``'s transpose
+    (contracted in place, never transposed)."""
+    if cfg.tie_embeddings:
+        scales = None
+        if isinstance(embed, QTensor):      # per-row scales: per logit
+            embed, scales = embed.values, embed.scales[:, 0]
+        logits = jnp.einsum(
+            "...d,vd->...v", x, embed.astype(cfg.dtype),
+            preferred_element_type=cfg.logits_dtype or cfg.dtype)
+        if scales is not None:
+            logits = logits * scales.astype(logits.dtype)
+        return logits
     if cfg.n_pred_heads > 1:
         v = cfg.vocab_size
         head = (QTensor(head.values[:, :v], head.scales)
@@ -2723,14 +3213,27 @@ def partition_specs(cfg: TransformerConfig, mesh: Mesh) -> Dict[str, Any]:
         raise ValueError(
             f"partition_specs: tp ({tp}) must divide the GQA kv projection "
             f"width ({cfg.kv_heads} kv heads x {cfg.head_dim})")
-    layer = {
-        "attn_norm": P(None, None),
+    attn = {
         "wq": P(None, "fsdp", "tp"),
         "wk": P(None, "fsdp", "tp"),
         "wv": P(None, "fsdp", "tp"),
         "wo": P(None, "tp", "fsdp"),
-        "mlp_norm": P(None, None),
     }
+    layer = {"attn_norm": P(None, None), "mlp_norm": P(None, None)}
+    if cfg.layer_types is None:
+        layer.update(attn)
+    else:
+        # a typed stack: the mixers' leaves by kind; the mamba mixer's
+        # projections shard like a dense MLP's, its per-head leaves not
+        if cfg.n_attn_layers:
+            layer["attention"] = attn
+        if cfg.n_mamba_layers:
+            layer["mamba"] = {
+                "in_proj": P(None, "fsdp", None),
+                "out_proj": P(None, None, "fsdp"),
+                "conv_w": P(None, None, None), "conv_b": P(None, None),
+                "dt_bias": P(None, None), "A_log": P(None, None),
+                "D": P(None, None), "norm": P(None, None)}
     if cfg.attention == "eva":
         layer.update(eva_phi=P(None, "tp", None), eva_mu=P(None, "tp", None))
     if cfg.n_experts:
@@ -2740,7 +3243,7 @@ def partition_specs(cfg: TransformerConfig, mesh: Mesh) -> Dict[str, Any]:
             e_up=P(None, "ep", "fsdp", "tp"),
             e_down=P(None, "ep", "tp", "fsdp"),
         )
-        if cfg.n_shared_experts:
+        if cfg.shared_width:
             layer.update(
                 s_gate=P(None, "fsdp", "tp"),
                 s_up=P(None, "fsdp", "tp"),
@@ -2758,6 +3261,8 @@ def partition_specs(cfg: TransformerConfig, mesh: Mesh) -> Dict[str, Any]:
         "norm_f": P(None),
         "head": P("fsdp", "tp"),
     }
+    if cfg.tie_embeddings:
+        del tree["head"]
     return jax.tree_util.tree_map(
         lambda s: _filter_spec(s, mesh), tree,
         is_leaf=lambda s: isinstance(s, P))
@@ -2772,11 +3277,17 @@ def quantized_partition_specs(cfg: TransformerConfig, mesh: Mesh
     works exactly as with fp params (``decode_step(..., sharded=True)``).
     """
     specs = partition_specs(cfg, mesh)
-    layers = {k: (_quantized_spec(v) if _quantizable(cfg, k) else v)
-              for k, v in specs["layers"].items()}
-    return {
+
+    def quantized(tree):
+        return {k: (quantized(v) if isinstance(v, dict) else
+                    _quantized_spec(v) if _quantizable(cfg, k) else v)
+                for k, v in tree.items()}
+
+    out = {
         "embed": _quantized_spec(specs["embed"]),
-        "layers": layers,
+        "layers": quantized(specs["layers"]),
         "norm_f": specs["norm_f"],
-        "head": _quantized_spec(specs["head"]),
     }
+    if "head" in specs:
+        out["head"] = _quantized_spec(specs["head"])
+    return out

@@ -96,11 +96,19 @@ BYPASS_ALLOWLIST = {
     # every surface it closes, "eva summary pages"; the lagged loop is
     # closed by "eva window close", because the host closes a window
     # between blocks from its own up-to-date view of every row.)
-    "prefix_cache": ("quantized kv cache", "eva summary pages"),
+    # (and with a recurrent row state — a typed stack's mamba layers,
+    # TransformerConfig.layer_types — a row is its pages AND a state that
+    # no page holds: the state after position p cannot be cut back to an
+    # earlier position, shared between rows or rebuilt from pages, so
+    # everything built on "a row is its pages" is closed with ONE reason
+    # string, "recurrent row state".  The pipelined carry composes: the
+    # state store rides the donated pool through every block.)
+    "prefix_cache": ("quantized kv cache", "eva summary pages",
+                     "recurrent row state"),
     # Mesh data shards pin pages locally (no single-shard scatter to
     # move), and the int8 tail recompute above breaks resume==cold.
     "kv_tier": ("mesh data sharding", "quantized kv cache",
-                "eva summary pages"),
+                "eva summary pages", "recurrent row state"),
     # The pipelined carry (tokens, positions, steps on device, one
     # block of lag) has no speculative form: a round's commit counts
     # decide the next round's positions, and _step_spec reads them on
@@ -112,7 +120,7 @@ BYPASS_ALLOWLIST = {
     # lags one block behind, and mesh data shards pin pages locally
     # like the kv_tier/export surface.
     "suspend": ("mesh data sharding", "lagged decode carry",
-                "eva summary pages"),
+                "eva summary pages", "recurrent row state"),
     # Stall-free fused prefill+decode ticks (one dispatch covers the
     # decode block AND a budgeted batch of prefill chunk slots).  Mesh
     # data shards dispatch chunks one-hot per shard (the fused slot
@@ -129,8 +137,10 @@ BYPASS_ALLOWLIST = {
     # reason (at construction: a draft model; at validate()/submit() and
     # export_kv(): a KV artifact).  EVA's 8 prediction heads are the
     # multi-byte self-speculation a later PR routes through _step_spec.
-    "speculative": ("eva summary pages",),
-    "kv_export": ("eva summary pages",),
+    # A recurrent row state closes both too: a rejected draft token cannot
+    # be taken back out of a state, and a KV artifact carries pages only.
+    "speculative": ("eva summary pages", "recurrent row state"),
+    "kv_export": ("eva summary pages", "recurrent row state"),
 }
 
 
@@ -139,7 +149,8 @@ def compute_bypass_reasons(*, speculative: bool = False,
                            quantized_cache: bool = False,
                            draft_quantized_cache: bool = False,
                            pipeline_depth: int = 0,
-                           eva: bool = False
+                           eva: bool = False,
+                           recurrent: bool = False
                            ) -> Dict[str, Optional[str]]:
     """The ``*_bypass_reason`` values a :class:`ContinuousBatcher`
     built from these mode flags records — ONE pure function, used by
@@ -154,12 +165,17 @@ def compute_bypass_reasons(*, speculative: bool = False,
     if eva:
         out["prefix_cache"] = out["speculative"] = out["kv_export"] = \
             "eva summary pages"
+    elif recurrent:
+        out["prefix_cache"] = out["speculative"] = out["kv_export"] = \
+            "recurrent row state"
     elif quant:
         out["prefix_cache"] = "quantized kv cache"
     if n_shards != 1:
         out["kv_tier"] = "mesh data sharding"
     elif eva:
         out["kv_tier"] = "eva summary pages"
+    elif recurrent:
+        out["kv_tier"] = "recurrent row state"
     elif quant:
         out["kv_tier"] = "quantized kv cache"
     if pipeline_depth and speculative:
@@ -172,6 +188,8 @@ def compute_bypass_reasons(*, speculative: bool = False,
         out["suspend"] = "mesh data sharding"
     elif eva:
         out["suspend"] = "eva summary pages"
+    elif recurrent:
+        out["suspend"] = "recurrent row state"
     elif pipelined:
         out["suspend"] = "lagged decode carry"
     if n_shards != 1:
@@ -1181,6 +1199,13 @@ class _PrefixCache:
                     "stats": dict(self._stats)}
 
 
+def _pool_leaves(cache):
+    """What a program hands back as the donated pool, out of the cache
+    ``decode_step`` returned: the K/V pages and, where rows keep one, the
+    recurrent row state (``pool["state"]``)."""
+    return {k: cache[k] for k in ("k", "v", "state") if k in cache}
+
+
 @jax.jit
 def _gather_pages(pool, ids):
     """Gather pool pages ``ids`` (page axis 1) on every layer and leaf —
@@ -1322,7 +1347,9 @@ class ContinuousBatcher:
     and the prefix cache; speculative decoding BYPASSES explicitly
     (``pipeline_bypass_reason``: the carry has no speculative form, a
     speculative batcher serves synchronously).  ``0`` preserves the
-    synchronous loop exactly.
+    synchronous loop exactly.  ``None`` leaves the choice to the
+    batcher: ``1`` where rows keep a recurrent state (suspend, the one
+    surface the lag closes, is closed there already), else ``0``.
 
     ``multi_step`` composes with speculative decoding: R =
     ceil(multi_step / (n_draft+1)) rounds fuse into ONE dispatch,
@@ -1427,7 +1454,7 @@ class ContinuousBatcher:
                  draft_quantized_cache: bool = False,
                  multi_step: int = 1,
                  prefix_cache_pages: int = 0,
-                 pipeline_depth: int = 0,
+                 pipeline_depth: Optional[int] = 0,
                  kv_tier=None,
                  rid_seed: int = 0,
                  fused_prefill: bool = False,
@@ -1444,10 +1471,21 @@ class ContinuousBatcher:
                              f"{prefix_cache_pages}")
         if multi_step < 1:
             raise ValueError(f"multi_step must be >= 1, got {multi_step}")
+        if pipeline_depth is None:
+            # The lag policy left to the batcher (what ``fleet/replica.py``
+            # passes when ``--pipeline-depth`` is not given): one block of
+            # lag where rows keep a recurrent state, synchronous otherwise.
+            # What the lagged carry costs a deployment is ``suspend``
+            # ("lagged decode carry"), and a recurrent row state has
+            # closed that already; what it buys grows with the rows such
+            # a state lets a chip hold (64 rows: 2.4 ms of host phases
+            # and 3.3 ms of idle device behind every 30 ms block).
+            pipeline_depth = int(cfg.n_mamba_layers > 0)
         if pipeline_depth not in (0, 1):
             raise ValueError(f"pipeline_depth must be 0 (synchronous "
-                             f"host sync) or 1 (one block of device-"
-                             f"resident lag), got {pipeline_depth}")
+                             f"host sync), 1 (one block of device-"
+                             f"resident lag) or None (the batcher's "
+                             f"choice), got {pipeline_depth}")
         if fused_prefill and prefill_chunk is None:
             raise ValueError("fused_prefill requires prefill_chunk "
                              "(chunked prefill is the lane being fused)")
@@ -1498,11 +1536,32 @@ class ContinuousBatcher:
         # helper (compute_bypass_reasons) so the audit test can
         # enumerate every reachable value against BYPASS_ALLOWLIST.
         eva = cfg.attention == "eva"
+        # Rows that keep a recurrent state beside their pages (a typed
+        # stack's mamba layers): the row-slot state store lives in the
+        # donated pool (``pool["state"]``, init_row_state).
+        recurrent = cfg.n_mamba_layers > 0
+        self._recurrent = recurrent
         self._bypass = compute_bypass_reasons(
             speculative=draft_cfg is not None, n_shards=self.n_shards,
             quantized_cache=quantized_cache,
             draft_quantized_cache=draft_quantized_cache,
-            pipeline_depth=pipeline_depth, eva=eva)
+            pipeline_depth=pipeline_depth, eva=eva, recurrent=recurrent)
+        if cfg.layer_types is not None:
+            # What a typed stack cannot do yet is refused here, before any
+            # device state exists (the registries above bypass the rest).
+            if draft_cfg is not None:
+                raise ValueError(
+                    f"speculative decoding is refused with typed layers: "
+                    f"{self._bypass['speculative'] or 'one program per stack'}")
+            for what, given in (("a mesh", mesh is not None),
+                                ("a shared prefix", prefix is not None),
+                                ("prefill_chunk", prefill_chunk is not None),
+                                ("quantized_cache", quantized_cache)):
+                if given:
+                    raise ValueError(
+                        f"{what} does not compose with typed layers "
+                        f"(layer_types): a prompt is prefilled whole, from "
+                        f"position 0 and an empty row state, on one host")
         if eva:
             # What EVA's pages cannot do yet is refused here, before any
             # device state exists (the registries above bypass the rest).
@@ -1596,6 +1655,14 @@ class ContinuousBatcher:
                                  self.np_max, n_shards=self.n_shards)
         self.t_side.pool = init_paged_cache(
             cfg, self.n_pages, self.page_size, quantized=quantized_cache)
+        if recurrent:
+            from tfmesos_tpu.models.transformer import init_row_state
+            self.t_side.pool["state"] = init_row_state(cfg, rows)
+        # the grouped expert layer counts its assignments per held expert;
+        # a block's sums ride back with its tokens (see _make_decode)
+        self._moe_counts = bool(cfg.n_experts) and \
+            cfg.moe_impl == "grouped" and cfg.layer_types is not None
+        self._state_rows = 0            # slots that hold a live state
         if mesh is not None:
             from tfmesos_tpu.models.transformer import partition_specs
             self.params = self._place(params, partition_specs(cfg, mesh))
@@ -1803,6 +1870,14 @@ class ContinuousBatcher:
             # windows closed in this tick; entries live at its end
             rec.update(eva_rolls=0, eva_summary_entries=0,
                        eva_window_entries=0, eva_pages=0)
+        if self._recurrent:
+            rec["state_rows"] = 0       # slots with a live state, at its end
+        if self._moe_counts:
+            # assignments that fell on held experts in this tick's block,
+            # the most any one expert (of any layer) took of them, and the
+            # held experts of every layer and step that took at least one
+            rec.update(moe_assignments=0, moe_expert_max=0,
+                       moe_experts_touched=0)
         return rec
 
     def _tick_roll(self, more: bool = True) -> None:
@@ -1823,6 +1898,8 @@ class ContinuousBatcher:
             if self._eva_roll is not None:
                 (t["eva_summary_entries"], t["eva_window_entries"],
                  t["eva_pages"]) = self._eva_live
+            if self._recurrent:
+                t["state_rows"] = self._state_rows
             block = t["name"] == "decode.block"
             fill = t["prefill_tokens"] or t["admitted"]
             t["kind"] = ("fused" if t["mode"] == "fused"
@@ -1836,6 +1913,20 @@ class ContinuousBatcher:
         """Open one phase of the current tick (``with``): flat siblings
         on the serve thread, never nested and never across a ``yield``."""
         return _Phase(self._tick, name, stats)
+
+    def _tick_moe(self, block: np.ndarray) -> np.ndarray:
+        """A block as read back, ``[rows (+ 3), K]``: with a grouped expert
+        layer its last three rows are the block's expert counters
+        (``[assignments, most one expert took, experts touched]``, computed
+        beside its tokens), which go into the tick record.  Returns the
+        tokens, ``[rows, K]``."""
+        if self._moe_counts:
+            a, m, n = block[self.rows:, 0]
+            self._tick["moe_assignments"] += int(a)
+            self._tick["moe_expert_max"] = max(self._tick["moe_expert_max"],
+                                               int(m))
+            self._tick["moe_experts_touched"] += int(n)
+        return block[:self.rows]
 
     def _tick_block(self, mode: str, rows: int, k: int) -> None:
         """This tick ran a decode block (or speculative round)."""
@@ -2251,6 +2342,8 @@ class ContinuousBatcher:
         K = self.multi_step if K is None else K
         max_len = self.max_len
 
+        moe_counts = self._moe_counts
+
         def block(params, pool, table, tok0, positions, rids, steps):
             def body(carry, _):
                 pool, tok, pos, stp = carry
@@ -2260,11 +2353,24 @@ class ContinuousBatcher:
                     jnp.minimum(pos, max_len), sharded=sharded,
                     mesh=self.mesh)
                 nxt = self._sample(logits[:, -1], rids, stp)
-                pool = {"k": cache["k"], "v": cache["v"]}
-                return (pool, nxt, pos + 1, stp + 1), nxt
+                out = ((nxt, cache["expert_counts"]) if moe_counts
+                       else nxt)
+                return (_pool_leaves(cache), nxt, pos + 1, stp + 1), out
 
             (pool, _, _, _), toks_all = jax.lax.scan(
                 body, (pool, tok0, positions, steps), None, length=K)
+            if moe_counts:
+                # [assignments on held experts, the most one expert took,
+                # (step, layer, expert)s that took any] over the block, as
+                # three rows under the tokens: ONE array comes back to the
+                # host (a second one is a second round trip every tick)
+                toks_all, counts = toks_all             # [K, L, held]
+                touched = jnp.sum(counts > 0)
+                counts = jnp.sum(counts, axis=0)
+                stats = jnp.stack([jnp.sum(counts), jnp.max(counts),
+                                   touched]).astype(jnp.int32)
+                return pool, jnp.concatenate(
+                    [toks_all.T, jnp.broadcast_to(stats[:, None], (3, K))])
             return pool, toks_all.T                         # [rows, K]
 
         if self._pipelined:
@@ -2288,7 +2394,7 @@ class ContinuousBatcher:
                 pool, out = block(params, pool, table, tok0, pos0, rids,
                                   stp0)
                 cap = max_len + K
-                return (pool, self._host_read(out), out[:, -1],
+                return (pool, self._host_read(out), out[:self.rows, -1],
                         jnp.minimum(pos0 + K, cap),
                         jnp.minimum(stp0 + K, cap))
 
@@ -2602,6 +2708,21 @@ class ContinuousBatcher:
                 return {"k": cache["k"], "v": cache["v"]}, nxt
 
             self._prefill_fns[width] = prefill
+        if width not in self._prefill_fns and self.cfg.layer_types is not None:
+            # A typed stack: the prompt whole, from position 0 and an EMPTY
+            # row state whatever the slot held; ``slot`` is the row slot
+            # the final state is written to, ``length`` keeps the bucket's
+            # padding out of it, and the head runs at the last real
+            # position only.
+            @partial(jax.jit, donate_argnums=1)
+            def prefill(params, pool, table, prompt, length, rid, slot):
+                cache = dict(pool, pages=table, slots=slot, valid=length)
+                logits, cache = decode_step(self.cfg, params, cache, prompt,
+                                            0)
+                nxt = self._sample(logits[:, 0], rid, jnp.zeros_like(rid))
+                return _pool_leaves(cache), nxt
+
+            self._prefill_fns[width] = prefill
         if width not in self._prefill_fns:
             sharded = self.mesh is not None
 
@@ -2894,13 +3015,16 @@ class ContinuousBatcher:
                                            np.int32))
 
             eva = self._eva_roll is not None
+            # a window's start (EVA), or the row slot a typed stack's
+            # prefill fills (slot 0: no row is live during a warm-up)
+            more = ([jnp.asarray(0, jnp.int32)] if eva
+                    else [zrow] if self.cfg.layer_types is not None else [])
             if prefill and self._chunk_prefill is None:
                 for w in self._prefill_widths():
                     self.pool, tok = self._prefill_fn(w)(
                         self.params, self.pool, sink_table(self.t_side),
                         jnp.asarray(np.zeros((nd, w), np.int32)),
-                        jnp.asarray(np.ones((nd,), np.int32)), zrow,
-                        *([jnp.asarray(0, jnp.int32)] if eva else []))
+                        jnp.asarray(np.ones((nd,), np.int32)), zrow, *more)
                     np.asarray(tok)
                     compiled.append(f"prefill[{w}]")
             if eva and self.max_len >= self.cfg.eva_window:
@@ -3002,7 +3126,8 @@ class ContinuousBatcher:
                     side.pool = side.copy(side.pool, side.sink, dst)
                     jax.block_until_ready(side.pool)
                     compiled.append("page_copy")
-            if self.n_shards == 1:
+            if self.n_shards == 1 and not self._recurrent:
+                # (a recurrent row state refuses KV export: nothing to warm)
                 # The disaggregated surface (export gather + import
                 # scatter) — compiled at the one-page count; larger
                 # transfers trace lazily per page count.  A KV tier
@@ -4418,9 +4543,12 @@ class ContinuousBatcher:
         lengths[s] = length
         rids = np.zeros((self.n_shards,), np.int32)
         rids[s] = rid
+        # a typed stack's prefill fills row slot ``row`` from an empty state
+        slot = ([jnp.asarray([row], jnp.int32)]
+                if self.cfg.layer_types is not None else [])
         self.pool, tok = self._prefill_fn(width)(
             self.params, self.pool, table, toks,
-            jnp.asarray(lengths), jnp.asarray(rids))
+            jnp.asarray(lengths), jnp.asarray(rids), *slot)
         if self.d_side is not None:
             _, dtoks, dtable = self._one_hot_call(self.d_side, row, padded)
             self.d_side.pool = self._draft_chunk(
@@ -4739,6 +4867,7 @@ class ContinuousBatcher:
         so the batched scatter cannot touch their pages.)"""
         K = self.multi_step
         decode = self._decode
+        self._state_rows = len(active)
         eva_w = self.cfg.eva_window if self._eva_roll is not None else 0
         with self._phase("batcher.prep"):
             toks = np.zeros((self.rows,), np.int32)
@@ -4767,7 +4896,7 @@ class ContinuousBatcher:
                 jnp.asarray(positions), jnp.asarray(rids),
                 jnp.asarray(steps))
         with self._phase("batcher.readback"):
-            nxt = np.asarray(nxt)           # ONE host sync per K tokens
+            nxt = self._tick_moe(np.asarray(nxt))   # ONE host sync a block
         self._tick_block("sync", len(decoding), K)
         finished = []
         with self._phase("batcher.retire"):
@@ -4818,6 +4947,7 @@ class ContinuousBatcher:
         ``pipeline_depth=0`` (same ops, same (rid, step) sample folds,
         only the sync point moves)."""
         K = self.multi_step
+        self._state_rows = len(active)
         dispatch = {r: row for r, row in active.items()
                     if row.decoding and row.step < row.req.max_new_tokens}
         prev = self._inflight
@@ -4887,7 +5017,7 @@ class ContinuousBatcher:
         # The lagged-block sync IS the pipelined loop's per-block wait
         # (dispatch is a non-blocking enqueue).
         with self._phase("batcher.readback"):
-            nxt = np.asarray(nxt)       # host sync: one block behind
+            nxt = self._tick_moe(np.asarray(nxt))   # one block behind
         if self._tick["name"] != "decode.block":    # the draining tick
             self._tick_block(self._mode, len(ticket), self.multi_step)
         finished = []
@@ -5170,6 +5300,7 @@ class ContinuousBatcher:
     def _finish(self, row: int, active: Dict[int, _Row],
                 free_rows: List[int]) -> None:
         active.pop(row, None)
+        self._state_rows = len(active)      # a freed slot's state is dead
         self.t_side.release(row)
         if self.d_side is not None:
             self.d_side.release(row)
