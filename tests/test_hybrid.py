@@ -108,6 +108,153 @@ def test_conv_tail_is_taken_at_the_true_end():
     assert float(jnp.abs(step[0, 0] - full[0, -1]).max()) < 1e-6
 
 
+# -- the decode step's one-pass update (the Pallas kernel, interpret mode) --------
+#
+# name -> (mamba layers, rows, heads, head size, state, channels a block may
+# hold or None for what the budget gives, the layer updated).  The XLA
+# ``ssm_update`` on the layer's slice is the specification.
+UPDATE_CASES = {
+    "toy_one_tile": (2, 2, 8, 16, 16, None, 0),
+    "granite_head_two_blocks": (3, 3, 4, 64, 128, 128, 1),
+    "blocks_of_two_tiles": (3, 2, 16, 32, 128, 256, 2),
+    # six tiles a row, room for four: the rule takes three (it divides)
+    "budget_does_not_divide": (2, 2, 12, 64, 32, 512, 1),
+    "head_wider_than_a_tile": (2, 2, 2, 256, 16, 256, 0),
+    "head_of_one_sublane_group": (2, 1, 32, 8, 16, None, 1),
+}
+
+
+def _update_inputs(lm, rows, h, p, n, seed=0):
+    rng = np.random.default_rng(seed)
+    store = rng.normal(size=(lm, rows, h * p, n)).astype(np.float32)
+    x = rng.normal(size=(rows, h, p)).astype(np.float32)
+    dt = np.log1p(np.exp(rng.normal(size=(rows, h)) - 2)).astype(np.float32)
+    a = -np.exp(rng.uniform(0, 2.5, size=(h,))).astype(np.float32)
+    bm = rng.normal(size=(rows, n)).astype(np.float32)
+    cm = rng.normal(size=(rows, n)).astype(np.float32)
+    return map(jnp.asarray, (store, x, dt, a, bm, cm))
+
+
+def _hold_blocks_to(monkeypatch, channels, n):
+    if channels is not None:
+        monkeypatch.setattr(ssm, "_UPDATE_VMEM_BUDGET", 4 * channels * n * 4)
+
+
+@pytest.mark.parametrize("name", sorted(UPDATE_CASES))
+def test_update_kernel_is_the_xla_update_in_place(monkeypatch, name):
+    """The kernel over the stacked store against ``ssm_update`` on the
+    layer's slice: y and the layer's new state to float32 rounding (the
+    128-term sum in another order), every other layer's bytes as they were."""
+    lm, rows, h, p, n, channels, layer = UPDATE_CASES[name]
+    store, x, dt, a, bm, cm = _update_inputs(lm, rows, h, p, n)
+    _hold_blocks_to(monkeypatch, channels, n)
+    block = ssm._update_block(h * p, n)
+    assert (h * p) % block == 0 and block <= (channels or h * p)
+    if name == "budget_does_not_divide":
+        assert block == 3 * 128
+    y, new = ssm.ssm_update_stacked(store, jnp.asarray(layer), x, dt, a, bm,
+                                    cm, interpret=True)
+    want_y, want = ssm.ssm_update(store[layer].reshape(rows, h, p, n), x, dt,
+                                  a, bm, cm)
+    assert y.shape == (rows, h, p) and y.dtype == F32
+    assert float(jnp.abs(y - want_y).max()) <= 1e-5 * float(
+        jnp.abs(want_y).max())
+    assert float(jnp.abs(new[layer].reshape(want.shape) - want).max()) \
+        <= 1e-6 * float(jnp.abs(want).max())
+    for other in range(lm):
+        if other != layer:
+            np.testing.assert_array_equal(np.asarray(new[other]),
+                                          np.asarray(store[other]))
+
+
+@pytest.mark.parametrize("name", ["toy_one_tile", "blocks_of_two_tiles"])
+def test_update_kernel_with_dt_zero_leaves_a_row_bit_for_bit(monkeypatch,
+                                                             name):
+    lm, rows, h, p, n, channels, layer = UPDATE_CASES[name]
+    store, x, dt, a, bm, cm = _update_inputs(lm, rows, h, p, n, seed=1)
+    _hold_blocks_to(monkeypatch, channels, n)
+    dt = dt.at[rows - 1].set(0.0)
+    _, new = ssm.ssm_update_stacked(store, layer, x, dt, a, bm, cm,
+                                    interpret=True)
+    np.testing.assert_array_equal(np.asarray(new[layer, rows - 1]),
+                                  np.asarray(store[layer, rows - 1]))
+    assert float(jnp.abs(new[layer, 0] - store[layer, 0]).max()) > 0
+
+
+@pytest.mark.parametrize("hp,n,budget,want", [
+    (8192, 128, None, 8192),        # Granite: a row-layer (4 MiB) is a block
+    (8192, 128, 2 ** 21, 1024),
+    (16384 * 2, 16, None, 16384),   # at most a tile's lanes of tiles
+    (768, 32, 4 * 512 * 32 * 4, 384),
+    (128, 16, None, 128), (200, 16, None, None)])
+def test_update_block_rule(monkeypatch, hp, n, budget, want):
+    if budget is not None:
+        monkeypatch.setattr(ssm, "_UPDATE_VMEM_BUDGET", budget)
+    assert ssm._update_block(hp, n) == want
+
+
+def test_shapes_the_kernel_does_not_tile_take_the_xla_form():
+    """A row that is not whole 128-channel tiles, or a head size that is
+    not whole sublanes: the XLA form, whatever is forced."""
+    for h, p in ((5, 8), (32, 4)):
+        store, x, dt, a, bm, cm = _update_inputs(2, 2, h, p, 16)
+        y, new = ssm.ssm_update_stacked(store, 1, x, dt, a, bm, cm,
+                                        use_pallas=True)
+        want_y, want = ssm.ssm_update(store[1].reshape(2, h, p, 16), x, dt,
+                                      a, bm, cm)
+        np.testing.assert_array_equal(np.asarray(y), np.asarray(want_y))
+        np.testing.assert_array_equal(np.asarray(new[1]),
+                                      np.asarray(want.reshape(2, h * p, 16)))
+
+
+@pytest.mark.parametrize("kinds", ["mmam", "mm"])
+def test_typed_decode_step_with_the_kernel_forced(monkeypatch, kinds):
+    """One typed ``decode_step`` at the toy widths, a prefill and then two
+    one-token steps, with the update kernel forced (interpret) against the
+    XLA path: logits and the whole state store."""
+    cfg = typed_cfg(kinds)
+    params = init_params(cfg, jax.random.PRNGKey(3))
+    rows, t = 2, 16
+    rng_tokens = np.random.default_rng(5).integers(
+        0, 128, size=(rows, t + 2)).astype(np.int32)
+
+    xla_or_kernel = ssm.ssm_update_stacked
+    kernel_calls = []
+
+    def forced_update(*args):
+        kernel_calls.append(args[1])
+        return xla_or_kernel(*args, interpret=True)
+
+    def run(forced):
+        if forced:
+            monkeypatch.setattr(ssm, "ssm_update_stacked", forced_update)
+        cache = dict(tr.init_paged_cache(cfg, 8, 16),
+                     state=tr.init_row_state(cfg, rows),
+                     pages=jnp.arange(rows * 2, dtype=jnp.int32).reshape(
+                         rows, 2))
+        prompt = jnp.asarray(rng_tokens[:, :t])
+        _, cache = tr.decode_step(
+            cfg, params, dict(cache, slots=jnp.arange(rows, dtype=jnp.int32),
+                              valid=jnp.asarray([t, t - 5], jnp.int32)),
+            prompt, 0)
+        outs = []
+        for i, pos in enumerate(([t, t - 5], [t + 1, t - 4])):
+            cache = {k: cache[k] for k in ("k", "v", "pages", "state")}
+            logits, cache = tr.decode_step(
+                cfg, params, cache, jnp.asarray(rng_tokens[:, t + i:t + i + 1]),
+                jnp.asarray(pos, jnp.int32))
+            outs.append(logits)
+        return jnp.stack(outs), cache["state"]["ssm"]
+
+    want_logits, want_state = run(False)
+    logits, state = run(True)
+    assert kernel_calls and float(jnp.abs(state).max()) > 0
+    assert float(jnp.abs(logits - want_logits).max()) <= 1e-5 * float(
+        jnp.abs(want_logits).max())
+    assert float(jnp.abs(state - want_state).max()) <= 1e-5 * float(
+        jnp.abs(want_state).max())
+
+
 # -- the grouped expert layer ----------------------------------------------------
 
 def _experts(held=6, d=32, f=48, layers=None, seed=0):

@@ -330,14 +330,16 @@ def test_paged_commit_never_relayouts_the_pool(topo, monkeypatch, name):
 # paths forced.  The store and the pool are donated and must be updated in
 # place: with heads and head channels as two dims of the store the compiler
 # gave it the layout the SSD scan's last einsum liked and wrapped the
-# prefill's state write in two copies of the WHOLE store.
+# prefill's state write in two copies of the WHOLE store.  The decode step
+# updates a layer's state in ONE pass, the ``ssm_update`` kernel (PR 33): as
+# XLA compiled it, a second fusion read the store again for y.
 
 @pytest.mark.parametrize("name,rows,t", [("decode_r64", 64, 1),
                                          ("prefill_t1024", 1, 1024)])
 def test_typed_step_never_relayouts_the_state_store(topo, monkeypatch, name,
                                                     rows, t):
     from tfmesos_tpu.models import transformer
-    from tfmesos_tpu.ops import moe
+    from tfmesos_tpu.ops import moe, ssm
     from tfmesos_tpu.ops.attention import attend
 
     kinds = ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
@@ -355,6 +357,7 @@ def test_typed_step_never_relayouts_the_state_store(topo, monkeypatch, name,
     monkeypatch.setattr(transformer, "attend",
                         partial(attend, use_pallas=True))
     monkeypatch.setattr(moe, "_on_tpu", lambda use: True)
+    monkeypatch.setattr(ssm, "_on_tpu", lambda use: True)
     one_chip = SingleDeviceSharding(topo.devices[0])
 
     def struct(x):
@@ -390,6 +393,20 @@ def test_typed_step_never_relayouts_the_state_store(topo, monkeypatch, name,
         moved = re.findall(r"= " + re.escape(leaf)
                            + r"\S* (?:copy|transpose|slice)\([^)]*\)", text)
         assert not moved, f"{leaf} is copied: {moved[:2]}"
+    if t == 1:
+        # The one-token update is ONE pass (PR 33): the Pallas kernel takes
+        # the store and returns it, and no fusion reads it a second time
+        # (control flow, tuples and the kernel itself may name it).
+        store = f"f32[9,{slots},8192,128]"
+        calls = [ln for ln in text.splitlines()
+                 if " custom-call(" in ln and "ssm_update" in ln]
+        assert calls
+        for ln in calls:
+            result, operands = ln.split(" custom-call(", 1)
+            assert store in result and store in operands, ln[:200]
+        again = [ln.strip()[:160] for ln in text.splitlines()
+                 if re.search(r" fusion\(.*" + re.escape(store), ln)]
+        assert not again, f"a fusion reads the store: {again[:2]}"
 
 
 # -- EVA attention at EvaByte's widths (PR 28) ---------------------------------

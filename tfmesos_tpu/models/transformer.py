@@ -2115,7 +2115,7 @@ def _mamba_mixer(cfg: TransformerConfig, x, lp, ssm, conv, mi, slots, valid):
     conv tail is taken at the true end) and ``slots`` [B] the slots the
     final states are written to."""
     from tfmesos_tpu.ops.ssm import (causal_conv, conv_tail, ssd_scan,
-                                     ssm_update)
+                                     ssm_update_stacked)
     b, t, _ = x.shape
     f32 = jnp.float32
     nh, hp, ns = cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_state
@@ -2137,10 +2137,11 @@ def _mamba_mixer(cfg: TransformerConfig, x, lp, ssm, conv, mi, slots, valid):
     xs = act[..., :di].reshape(b, t, nh, hp)
     bm, cm = act[..., di:di + ns], act[..., di + ns:]
     if t == 1:
-        y, new = ssm_update(ssm[mi].reshape(b, nh, hp, ns), xs[:, 0],
-                            dt[:, 0], a, bm[:, 0], cm[:, 0])
+        # one pass over the layer's state, in place in the stacked store (a
+        # Pallas kernel on the TPU: ops/ssm.py)
+        y, ssm = ssm_update_stacked(ssm, mi, xs[:, 0], dt[:, 0], a,
+                                    bm[:, 0], cm[:, 0])
         y = y[:, None]
-        ssm = ssm.at[mi].set(new.reshape(b, nh * hp, ns))
         conv = conv.at[mi].set(new_tail.astype(conv.dtype))
     else:
         y, new = ssd_scan(xs, dt, a, bm, cm,
