@@ -15,7 +15,7 @@ sys.path.insert(0, ROOT)
 
 def main() -> int:
     import jax.numpy as jnp
-    from benchmark import harness, reference, tiny, window
+    from benchmark import harness, reference, tiny, traffic_gen, window
     from benchmark.models import mistral
     from benchmark.drivers import serve
     from tfmesos_tpu import serving
@@ -27,12 +27,13 @@ def main() -> int:
     reference.served_gaps = not_this_reference
     config = harness.load_json("configs", "tinyhybrid.json")
     spec = tiny.tiny_spec()
+    traffic = traffic_gen.load_traffic("tinyhybrid_batch")
     seed = 2 ** 31 + 177
     lines = []
 
     def run(**kw):
         return serve.run_cell(spec, spec["workloads"][1], config,
-                              tiny.TINY_BACKLOG, seed=seed, seconds=3,
+                              traffic, seed=seed, seconds=3,
                               t_start=0.0, require_chip=False,
                               out=lines.append, **kw)
 

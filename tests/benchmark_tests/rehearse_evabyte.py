@@ -35,36 +35,14 @@ TRAFFIC = {
 }
 
 
-#: the four entries of this cell whose readers this PR brings
-#: (``layer_metrics/eva_*.py``), as a ``benchmark`` PR would append them to
-#: ``per_layer``.  BENCHMARK.json does not list them yet: the older
-#: ``test_benchmark_tick_readers.py`` holds the tick ring's eight entries to
-#: be the LAST eight of the list, so nothing can be appended behind them
-#: until that assertion goes (PERF.md, section 7).
-EVA_ENTRIES = [
-    {"name": "eva_pool_fill", "unit": "%", "better": "higher",
-     "source": "program_counter", "layer": "batcher", "moves": "tok_s",
-     "workloads": [CELL]},
-    {"name": "eva_decode_roofline", "unit": "%", "better": "higher",
-     "source": "device_trace", "layer": "kernels", "moves": "tok_s",
-     "workloads": [CELL]},
-    {"name": "eva_cache_ratio", "unit": "x", "better": "higher",
-     "source": "program_counter", "layer": "batcher", "moves": "tok_s",
-     "workloads": [CELL]},
-    {"name": "eva_roll_share", "unit": "%", "better": "lower",
-     "source": "device_trace", "layer": "model step", "moves": "tok_s",
-     "workloads": [CELL]},
-]
-
-
 def spec():
-    """The cell's per-layer entries of BENCHMARK.json and ``EVA_ENTRIES``,
-    under a cell name of this process's (the driver keeps a run's trace in a
+    """The cell's per-layer entries as BENCHMARK.json lists them, under a
+    cell name of this process's (the driver keeps a run's trace in a
     directory named after cell and seed: two rehearsals at once must not
     share it)."""
     full = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
     name = f"{CELL}.{os.getpid()}"
-    mine = [dict(m, workloads=[name]) for m in full["per_layer"] + EVA_ENTRIES
+    mine = [dict(m, workloads=[name]) for m in full["per_layer"]
             if CELL in m.get("workloads", [])]
     return {"workloads": [{"name": name, "config": "tiny", "traffic": "tiny",
                            "chips": 1, "why": "rehearsal"}],

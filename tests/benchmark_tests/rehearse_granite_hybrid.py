@@ -27,62 +27,14 @@ TRAFFIC = {
                "max": 32},
 }
 
-def _entry(name, unit, better, source, layer):
-    return {"name": name, "unit": unit, "better": better, "source": source,
-            "layer": layer, "moves": "tok_s", "workloads": [CELL]}
-
-
-#: this cell's twelve entries as a ``benchmark`` PR would give them to
-#: ``per_layer``: the seven twins (read by their base names' files; unit,
-#: better, source and layer as their ``.docqa`` or chat twins state them)
-#: and the five readers this PR brings (``layer_metrics/ssm_*.py``,
-#: ``moe_*.py``).  BENCHMARK.json does not list them: a program PR may only
-#: APPEND to ``per_layer``, and the older ``test_benchmark_tick_readers.py``
-#: holds the tick ring's eight entries to be the last eight of the list, so
-#: nothing can be appended behind them until that assertion goes (PERF.md,
-#: section 7).  The cell joins ``pool_fill.docqa``'s list meanwhile.
-HYBRID_ENTRIES = [
-    _entry("gen_late_p99_ms.hybrid", "ms", "lower", "host_clock",
-           "load generator"),
-    _entry("decode_rows_mean.hybrid", "rows", "higher", "program_counter",
-           "batcher"),
-    _entry("prefill_p50_ms.hybrid", "ms", "lower", "device_trace",
-           "model step"),
-    _entry("decode_block_ms_p50.hybrid", "ms", "lower", "device_trace",
-           "model step"),
-    _entry("attn_kernel_share.hybrid", "%", "lower", "device_trace",
-           "kernels"),
-    _entry("pool_copy_share.hybrid", "%", "lower", "device_trace",
-           "device copies"),
-    _entry("paged_decode_roofline.hybrid", "%", "higher", "device_trace",
-           "kernels"),
-    _entry("ssm_state_roofline", "%", "higher", "device_trace", "kernels"),
-    _entry("moe_expert_roofline", "%", "higher", "device_trace", "kernels"),
-    _entry("ssm_share", "%", "lower", "device_trace", "kernels"),
-    _entry("moe_share", "%", "lower", "device_trace", "kernels"),
-    _entry("moe_load_max_over_mean", "x", "lower", "program_counter",
-           "batcher"),
-]
-
-#: and the tick ring's four, which that PR would list under ``.hybrid``
-#: too; read here so that ``compiles_in_window`` is seen to be 0
-RING_ENTRIES = [
-    _entry(n + ".hybrid", u, "lower", s, l)
-    for n, u, s, l in (
-        ("tick_host_ms_p50", "ms", "program_span", "batcher"),
-        ("host_gap_share", "%", "program_span", "batcher"),
-        ("prefill_stall_share", "%", "program_span", "batcher"),
-        ("compiles_in_window", "compiles", "program_counter", "model step"))]
-
 
 def spec():
-    """The cell's per-layer entries of BENCHMARK.json, ``HYBRID_ENTRIES``
-    and ``RING_ENTRIES``, under a cell name of this process's (a run's trace
-    is kept in a directory named after cell and seed)."""
+    """The cell's per-layer entries as BENCHMARK.json lists them, under a
+    cell name of this process's (a run's trace is kept in a directory named
+    after cell and seed)."""
     full = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
     name = f"{CELL}.{os.getpid()}"
-    mine = [dict(m, workloads=[name]) for m in
-            full["per_layer"] + HYBRID_ENTRIES + RING_ENTRIES
+    mine = [dict(m, workloads=[name]) for m in full["per_layer"]
             if CELL in m.get("workloads", [])]
     return {"workloads": [{"name": name, "config": "tiny", "traffic": "tiny",
                            "chips": 1, "why": "rehearsal"}],

@@ -220,51 +220,49 @@ def test_the_configuration_is_the_catalogs_row_but_the_three_cuts():
     assert (dm.experts, dm.held, dm.offset, dm.top_k) == (72, 36, 0, 10)
 
 
-def test_the_cell_and_its_entries():
-    spec = harness.load_spec()
+#: the accepted ``tok_s`` lists the cell joined (a suffixed name is read by
+#: its base name's file), and the six entries of its own
+JOINED = ("gen_late_p99_ms", "decode_rows_mean", "pool_fill",
+          "prefill_p50_ms", "decode_block_ms_p50", "attn_kernel_share",
+          "pool_copy_share", "tick_host_ms_p50", "host_gap_share",
+          "prefill_stall_share", "compiles_in_window")
+OWN = ("paged_decode_roofline.hybrid", "ssm_state_roofline",
+       "moe_expert_roofline", "ssm_share", "moe_share",
+       "moe_load_max_over_mean")
+
+
+@pytest.fixture(scope="module")
+def spec():
+    return harness.load_spec()
+
+
+def test_the_cell_and_its_entries(spec):
     cell = harness.find_cell(spec, CELL)
     assert cell == {**cell, "config": CONFIG, "traffic": "gen_batch",
                     "chips": 1}
     e2e = {m["name"] for m in harness.cell_metrics(spec, CELL, "end_to_end")}
     assert e2e == {"tok_s", "setup_s"}
-    # In BENCHMARK.json the cell has no entry of its own: a program PR may
-    # only append to ``per_layer`` and nothing can be appended behind the
-    # tick ring's eight (test_benchmark_tick_readers.py).  It joins the one
-    # accepted ``tok_s`` list that no other test pins, ``pool_fill.docqa``
-    # (the attention layer's pool holds a position a token, as Mistral's).
-    names = [m["name"] for m in harness.cell_metrics(spec, CELL, "per_layer")]
-    assert names == ["pool_fill.docqa"]
+    mine = {m["name"]: m
+            for m in harness.cell_metrics(spec, CELL, "per_layer")}
+    assert set(mine) >= {n + ".docqa" for n in JOINED} | set(OWN)
+    for m in mine.values():
+        assert m["moves"] == "tok_s"
+    # the accepted lists it joined are the batch cells' (the attention
+    # layer's pool holds a position a token, as Mistral's)
+    for n in JOINED:
+        assert {"mistral7b.docqa_batch", CELL} <= set(
+            mine[n + ".docqa"]["workloads"])
+    # its own six: each lists this cell, has a reader and names a layer
+    # the benchmark has; the paged kernel's twin states what chat's does
     by = {m["name"]: m for m in spec["per_layer"]}
-    assert by["pool_fill.docqa"]["workloads"] == ["mistral7b.docqa_batch",
-                                                  CELL]
-    assert by["pool_fill.docqa"]["moves"] == "tok_s"
-    assert [m["name"] for m in spec["per_layer"]][-8:] == [
-        n + s for n in ("tick_host_ms_p50", "host_gap_share",
-                        "prefill_stall_share", "compiles_in_window")
-        for s in ("", ".docqa")]
-    # Its twelve entries wait in the rehearsal's spec for a ``benchmark``
-    # PR: each lists this cell alone, moves tok_s, has a reader and names a
-    # layer the benchmark has; the twins state what their accepted twins do.
-    import rehearse_granite_hybrid as rh
-    assert [m["name"] for m in rh.HYBRID_ENTRIES] == [
-        "gen_late_p99_ms.hybrid", "decode_rows_mean.hybrid",
-        "prefill_p50_ms.hybrid", "decode_block_ms_p50.hybrid",
-        "attn_kernel_share.hybrid", "pool_copy_share.hybrid",
-        "paged_decode_roofline.hybrid", "ssm_state_roofline",
-        "moe_expert_roofline", "ssm_share", "moe_share",
-        "moe_load_max_over_mean"]
-    layers = {m["layer"] for m in spec["per_layer"]}
-    for m in rh.HYBRID_ENTRIES + rh.RING_ENTRIES:
-        n = m["name"]
-        assert n not in by and m["layer"] in layers
-        assert set(m) == set(by["pool_fill.docqa"])
-        assert m["workloads"] == [CELL] and m["moves"] == "tok_s"
+    layers = {m["layer"] for m in spec["per_layer"] if m["name"] not in OWN}
+    for n in OWN:
+        assert mine[n]["workloads"] == [CELL] and mine[n]["layer"] in layers
+        assert set(mine[n]) == set(by["pool_fill.docqa"])
         assert harness.load_reader(n) is not None
-        if n.endswith(".hybrid"):
-            twin = by.get(n[:-len(".hybrid")] + ".docqa") or by[
-                n[:-len(".hybrid")]]
-            assert {k: m[k] for k in ("unit", "better", "source", "layer")} \
-                == {k: twin[k] for k in ("unit", "better", "source", "layer")}
+    fields = ("unit", "better", "source", "layer")
+    assert [mine["paged_decode_roofline.hybrid"][k] for k in fields] == [
+        by["paged_decode_roofline"][k] for k in fields]
 
 
 def test_gen_batch_offers_thirty_two_widths_in_a_fixed_order():
@@ -382,16 +380,15 @@ def test_rehearsal_serves_correctly_and_the_controls_fail(rehearsal):
 
 def test_rehearsal_reports_the_cells_entries_and_the_ring(rehearsal):
     metrics = rehearsal["sound"]["metrics"]
-    import rehearse_granite_hybrid as rh
-    assert rehearsal["per_layer"] == ["pool_fill.docqa"] + [
-        m["name"] for m in rh.HYBRID_ENTRIES + rh.RING_ENTRIES]
-    for name in ("gen_late_p99_ms.hybrid", "decode_rows_mean.hybrid",
+    assert set(rehearsal["per_layer"]) >= {n + ".docqa" for n in JOINED} | set(
+        OWN)
+    for name in ("gen_late_p99_ms.docqa", "decode_rows_mean.docqa",
                  "moe_load_max_over_mean", "pool_fill.docqa"):
         assert name in metrics, name
     assert 0 < metrics["pool_fill.docqa"]["value"] <= 100
-    assert metrics["compiles_in_window.hybrid"]["value"] == 0
+    assert metrics["compiles_in_window.docqa"]["value"] == 0
     assert 1.0 <= metrics["moe_load_max_over_mean"]["value"] <= 20
-    assert 1.5 <= metrics["decode_rows_mean.hybrid"]["value"] <= 3
+    assert 1.5 <= metrics["decode_rows_mean.docqa"]["value"] <= 3
     ring = rehearsal["ring"]
     # 3 rows, all live at some tick; every block's assignments on the 4
     # held experts of 5 layers: at most rows x top-3 x layers a block

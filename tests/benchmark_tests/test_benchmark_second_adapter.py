@@ -6,7 +6,9 @@ copy of ``benchmark/`` without replacing a file, and the copy's own
 ``correct`` by the adapter's reference, ``pool_fill`` on the adapter's own
 count of token slots, and not correct with the sampler broken.  Also: the
 seeded weights are the arrays they were before the adapter stood between
-the driver and ``weights.py``."""
+the driver and ``weights.py``.  And the rule of README.md's "How a later
+PR adds a cell and its entries", held: with that PR's cell and entries in a
+copy of BENCHMARK.json, no structural check of any cell's test fails."""
 
 import json
 import os
@@ -18,32 +20,90 @@ import pytest
 
 from benchmark import harness, tiny
 
-ADDED = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                     "added_files")
+HERE = os.path.dirname(os.path.abspath(__file__))
+ADDED = os.path.join(HERE, "added_files")
+CELL = "tinyhybrid.batch"
 
 
 @pytest.fixture(scope="module")
-def rehearsal(tmp_path_factory):
+def checkout(tmp_path_factory):
+    """A copy of ``benchmark/`` with ``added_files/`` laid over it."""
     root = str(tmp_path_factory.mktemp("checkout"))
-    copy = os.path.join(root, "benchmark")
-    shutil.copytree(harness.HERE, copy,
+    bench = os.path.join(root, "benchmark")
+    shutil.copytree(harness.HERE, bench,
                     ignore=shutil.ignore_patterns("__pycache__"))
-    before = {os.path.relpath(os.path.join(d, f), copy)
-              for d, _, files in os.walk(copy) for f in files}
+    before = {os.path.relpath(os.path.join(d, f), bench)
+              for d, _, files in os.walk(bench) for f in files}
     added = {os.path.relpath(os.path.join(d, f), ADDED)
              for d, _, files in os.walk(ADDED) for f in files
              if not f.endswith(".pyc")}
     assert added and not added & before        # files added, none replaced
-    shutil.copytree(ADDED, copy, dirs_exist_ok=True,
+    shutil.copytree(ADDED, bench, dirs_exist_ok=True,
                     ignore=shutil.ignore_patterns("__pycache__"))
-    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=harness.ROOT)
-    p = subprocess.run(
-        [sys.executable, os.path.join(copy, "rehearse_tinyhybrid.py")],
-        cwd=root, env=env, capture_output=True, text=True, timeout=600)
+    return root
+
+
+def run_in(root, script):
+    """``script`` from the copy: its ``benchmark`` is the copy's, the
+    program the checkout's.  The last line of its output, parsed."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=root + os.pathsep + harness.ROOT)
+    p = subprocess.run([sys.executable, script], cwd=root, env=env,
+                       capture_output=True, text=True, timeout=600)
     assert p.returncode == 0, p.stderr[-4000:]
     out = json.loads(p.stdout.splitlines()[-1])
-    assert out["benchmark"] == copy         # the copy's harness, not ours
+    assert out["benchmark"] == os.path.join(root, "benchmark")
     return out
+
+
+@pytest.fixture(scope="module")
+def rehearsal(checkout):
+    return run_in(checkout, os.path.join(checkout, "benchmark",
+                                         "rehearse_tinyhybrid.py"))
+
+
+def with_a_later_prs_cell(spec):
+    """What a program PR may do to BENCHMARK.json, and all it may: a
+    configuration and a cell added, the cell JOINED to the end-to-end metric
+    it reports and to two accepted per-layer lists of that metric, one
+    per-layer entry APPENDED behind the last.  Changes ``spec``."""
+    with open(os.path.join(ADDED, "configs", "tinyhybrid.json")) as f:
+        config = json.load(f)
+    spec["configs"].append({
+        "name": "tinyhybrid", "source": config["source"],
+        "file": "benchmark/configs/tinyhybrid.json",
+        "reduced": config["reduced"], "why": "K/V in one layer of two"})
+    spec["workloads"].append({
+        "name": CELL, "config": "tinyhybrid", "traffic": "tinyhybrid_batch",
+        "chips": 1, "why": "a backlog of short prompts; the rehearsal's"})
+    by = {m["name"]: m for m in spec["end_to_end"] + spec["per_layer"]}
+    for name in ("tok_s", "gen_late_p99_ms.docqa", "decode_rows_mean.docqa"):
+        by[name]["workloads"].append(CELL)
+    spec["per_layer"].append({
+        "name": "pool_fill.tinyhybrid", "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "batcher", "moves": "tok_s",
+        "workloads": [CELL]})
+    return spec
+
+
+def test_a_later_prs_cell_and_entries_fail_no_structural_check(checkout):
+    """README.md, "How a later PR adds a cell and its entries": a cell's
+    test asserts what its own cell's entries contain, never a list's exact
+    value, a count of ``per_layer`` or a position in it.  So every
+    structural check (``spec_checks.py``) holds on the copy too."""
+    spec = harness.load_spec()
+    listed = len(spec["per_layer"])
+    with open(os.path.join(checkout, "BENCHMARK.json"), "w") as f:
+        json.dump(with_a_later_prs_cell(spec), f, indent=1)
+    out = run_in(checkout, os.path.join(HERE, "spec_checks.py"))
+    assert out["cells"][-1] == CELL
+    assert out["per_layer"][-1] == "pool_fill.tinyhybrid"
+    assert len(out["per_layer"]) == listed + 1
+    files = {name.split("::")[0] for name in out["ran"]}
+    assert files >= {"test_benchmark_spec", "test_benchmark_tick_readers",
+                     "test_benchmark_evabyte", "test_benchmark_granite_hybrid"}
+    assert "test_benchmark_spec::test_metrics" in out["ran"]
+    assert not out["failed"], "\n".join(out["failed"].values())
 
 
 def test_second_adapter_is_served_and_read_by_its_own_reference(rehearsal):

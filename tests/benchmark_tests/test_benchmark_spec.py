@@ -1,6 +1,12 @@
 """BENCHMARK.json against the contract's static rules, and against the files
 it names: every cell finds its configuration, its traffic mix, its driver,
-its model adapter and the readers of its per-layer metrics."""
+its model adapter and the readers of its per-layer metrics.
+
+A test whose one argument is ``spec`` is a structural check: it reads
+nothing of BENCHMARK.json but through that argument, so that ``spec_checks.
+py`` can hold it against a copy to which a later PR's cell and entries were
+added (test_benchmark_second_adapter.py; README.md, "How a later PR adds a
+cell and its entries")."""
 
 import importlib
 import json
@@ -179,9 +185,9 @@ def test_metrics(spec):
         assert len(harness.cell_metrics(spec, c, "per_layer")) >= 1
 
 
-def test_files_under_paths_are_named_from_names():
+def test_files_under_paths_are_named_from_names(spec):
     ok = re.compile(r"^[A-Za-z0-9_.\-/]+$")
-    for path in harness.load_spec()["paths"]:
+    for path in spec["paths"]:
         for root, dirs, files in os.walk(os.path.join(harness.ROOT, path)):
             dirs[:] = [d for d in dirs if d != "__pycache__"]
             for f in files:
