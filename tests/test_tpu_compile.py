@@ -409,6 +409,87 @@ def test_typed_step_never_relayouts_the_state_store(topo, monkeypatch, name,
         assert not again, f"a fusion reads the store: {again[:2]}"
 
 
+# -- a KDA stack's state store stays where it is (PR 37) -----------------------
+#
+# ``decode_step`` over a typed stack at Solar-Open2's widths as the cell
+# ``solar2.reason_batch`` runs it (one period ``a k k k``: a 2.4 GB float32
+# KDA state store over 192 row slots beside a one-layer page pool, 40 held
+# experts of 320 a layer, a sliced vocabulary), compiled whole for the
+# described v5e with the kernel paths forced.  The store, the pool and the
+# expert stacks must be updated or read in place: no copy, transpose or slice
+# of any of them.  The one-token update is XLA's (``ops/kda.py``): it reads
+# the layer's state and writes it back under the donated store's own buffer.
+
+@pytest.mark.parametrize("name,rows,t", [("decode_r192", 192, 1),
+                                         ("prefill_t1024", 1, 1024)])
+def test_kda_step_never_relayouts_the_state_store(topo, monkeypatch, name,
+                                                  rows, t):
+    from tfmesos_tpu.models import transformer
+    from tfmesos_tpu.ops import moe
+    from tfmesos_tpu.ops.attention import attend
+
+    cfg = transformer.TransformerConfig(
+        vocab_size=24576, d_model=4096, n_layers=4, n_heads=64,
+        n_kv_heads=8, attn_head_dim=128, d_ff=1280, max_seq_len=8192,
+        dtype=BF16, param_dtype=BF16,
+        layer_types=("attention", "kda", "kda", "kda"), kda_heads=64,
+        kda_head_dim=128, kda_neg_eigval=True, rope=False, attn_gate=True,
+        norm_eps=1e-5, logits_dtype=F32, n_experts=320, top_k=8,
+        moe_impl="grouped", experts_held=40, shared_d_ff=1280,
+        router_score="sigmoid")
+    monkeypatch.setattr(transformer, "_decode_kernel_kwargs",
+                        lambda *a, **k: {"use_pallas": True})
+    monkeypatch.setattr(transformer, "attend",
+                        partial(attend, use_pallas=True))
+    monkeypatch.setattr(moe, "_on_tpu", lambda use: True)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def struct(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    slots, n_pages = 192, 9216
+    params = jax.tree_util.tree_map(struct, jax.eval_shape(
+        lambda: transformer.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = dict(jax.eval_shape(
+        lambda: transformer.init_paged_cache(cfg, n_pages, PAGE)))
+    cache["state"] = jax.eval_shape(
+        lambda: transformer.init_row_state(cfg, slots))
+    assert set(cache["state"]) == {"kda_s", "kda_conv"}
+    cache["pages"] = jnp.zeros((rows, 128), I32)
+    if t > 1:
+        cache["slots"] = jnp.zeros((rows,), I32)
+        cache["valid"] = jnp.zeros((rows,), I32)
+    cache = jax.tree_util.tree_map(struct, cache)
+    tokens = jax.ShapeDtypeStruct((rows, t), I32, sharding=one_chip)
+    if t == 1:
+        pos = (jax.ShapeDtypeStruct((rows,), I32, sharding=one_chip),)
+        step = jax.jit(lambda p, c, tok, at: transformer.decode_step(
+            cfg, p, c, tok, at), donate_argnums=1)
+    else:
+        pos = ()
+        step = jax.jit(lambda p, c, tok: transformer.decode_step(
+            cfg, p, c, tok, 0), donate_argnums=1)
+    compiled = step.lower(params, cache, tokens, *pos).compile()
+    text = compiled.as_text()
+    for kernel in ("moe_grouped_swiglu", "moe_grouped_matmul"):
+        assert kernel in text, kernel
+    assert ("flash_decode_paged" if t == 1 else "flash_attention_fwd") in text
+    store = f"f32[3,{slots},8192,128]"
+    assert store in text
+    for leaf in (store, f"f32[3,{slots},64,128,128]",
+                 f"bf16[1,{n_pages},8,{PAGE},128]",
+                 "bf16[4,40,4096,1280]", "bf16[4,40,1280,4096]"):
+        moved = re.findall(r"= " + re.escape(leaf)
+                           + r"\S* (?:copy|transpose|slice)\([^)]*\)", text)
+        assert not moved, f"{leaf} is copied: {moved[:2]}"
+    # beside 6.6 GB of weights, 2.5 GB of state and 2.4 GB of pool: a step's
+    # temporaries (a decode step's decayed state of one layer; a prefill's
+    # activations and one chunk's [64, 64, 128] decay terms a head) leave
+    # the chip a gigabyte
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2.5e9, mem.temp_size_in_bytes
+
+
 # -- EVA attention at EvaByte's widths (PR 28) ---------------------------------
 #
 # The programs ``ContinuousBatcher`` dispatches under ``attention="eva"``,

@@ -90,6 +90,10 @@ def _norm(cfg, x, gain):
     return rms_norm(x, g, **kw).astype(cfg.dtype)
 
 
+#: the kinds of mixer a typed stack's ``layer_types`` may name
+LAYER_KINDS = ("attention", "mamba", "kda")
+
+
 @dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32000
@@ -181,16 +185,18 @@ class TransformerConfig:
     # columns) is the next token.  Decoding computes head 0 only.
     n_pred_heads: int = 1
     # A LAYER PATTERN (None: one homogeneous stack of attention blocks, as
-    # ever).  One entry per layer, "attention" | "mamba": the mixer of each
-    # block; every block's second half is the same feed-forward / expert
-    # layer.  The stack is scanned over periods of the pattern (the shortest
-    # prefix that repeats) and, inside a period, over each run of layers of
-    # one kind.  Parameters of a typed stack: the leaves every layer has
-    # (norms, feed-forward, experts) stacked [L, ...] as before, the mixers'
-    # stacked by kind under ``layers["attention"]`` / ``layers["mamba"]``.
+    # ever).  One entry per layer, "attention" | "mamba" | "kda": the mixer
+    # of each block; every block's second half is the same feed-forward /
+    # expert layer.  The stack is scanned over periods of the pattern (the
+    # shortest prefix that repeats) and, inside a period, over each run of
+    # layers of one kind.  Parameters of a typed stack: the leaves every
+    # layer has (norms, feed-forward, experts) stacked [L, ...] as before,
+    # the mixers' stacked by kind under ``layers["attention"]`` /
+    # ``layers["mamba"]`` / ``layers["kda"]``.
     # Serving path only (``decode_step`` through a paged cache): K/V pages
     # for the attention layers (the pool's leading dim is their count) and
-    # a per-row recurrent state for the mamba layers (``init_row_state``).
+    # a per-row recurrent state for the mamba and kda layers
+    # (``init_row_state``: the leaves of the kinds present).
     layer_types: Optional[Tuple[str, ...]] = None
     # The Mamba-2 (SSD) mixer: ``mamba_heads`` heads of ``mamba_head_dim``
     # (d_inner = their product), a state of ``mamba_state`` per head
@@ -203,11 +209,29 @@ class TransformerConfig:
     mamba_state: int = 128
     mamba_conv: int = 4
     mamba_chunk: int = 256
+    # The KDA mixer (Kimi Delta Attention, ``ops/kda.py``): a gated delta
+    # rule over a matrix state per head with a decay per key channel.
+    # ``kda_heads`` heads of ``kda_head_dim`` (keys and values alike), a
+    # causal depthwise conv of ``kda_conv`` taps over [q | k | v], the decay
+    # and the output gate through low-rank projections of the head's
+    # size, prefill in chunks of ``kda_chunk``; ``kda_neg_eigval`` doubles ``beta`` (steps in (0, 2):
+    # the transition may have negative eigenvalues).  The row state is
+    # float32, [heads * head_dim, head_dim] a layer (``init_row_state``).
+    kda_heads: int = 0
+    kda_head_dim: int = 128
+    kda_conv: int = 4
+    kda_chunk: int = 64
+    kda_neg_eigval: bool = False
     # Attention as a configuration states it: ``rope`` False applies no
     # positional embedding; ``attn_scale`` is the softmax scale (None:
-    # head_dim ** -0.5).
+    # head_dim ** -0.5); ``attn_head_dim`` the head size (None: d_model /
+    # n_heads); ``attn_gate`` multiplies the attention's output, before
+    # ``wo``, by ``sigmoid(x W_g)`` taken from the block's normed input (an
+    # elementwise output gate: the leaf ``wg`` beside ``wq``).
     rope: bool = True
     attn_scale: Optional[float] = None
+    attn_head_dim: Optional[int] = None
+    attn_gate: bool = False
     # Multipliers (None: absent): the embedding's output is scaled by
     # ``embed_scale``, every block adds ``residual_scale`` times its mixer
     # / feed-forward output, the logits are divided by ``logits_scale``.
@@ -226,9 +250,19 @@ class TransformerConfig:
     # Width of the always-on shared MLP where the configuration states it
     # by itself (None: n_shared_experts * d_ff).
     shared_d_ff: Optional[int] = None
+    # How the router's logits become gates (``moe_impl="grouped"``).
+    # "softmax": the top-k largest logits, softmax over those kept.
+    # "sigmoid": scores ``s = sigmoid(logits)``; the top-k largest ``s + b``
+    # (``b``: the leaf ``router_bias`` [L, E] float32, a selection bias
+    # that is no part of the gate); gates ``s_i / sum of the chosen s``,
+    # times ``routed_scale``.
+    router_score: str = "softmax"
+    routed_scale: float = 1.0
 
     @property
     def head_dim(self) -> int:
+        if self.attn_head_dim is not None:
+            return self.attn_head_dim
         return self.d_model // self.n_heads
 
     def __post_init__(self):
@@ -266,11 +300,12 @@ class TransformerConfig:
                     f"are not among the router's {self.n_experts}")
         if self.layer_types is not None:
             object.__setattr__(self, "layer_types", tuple(self.layer_types))
-            bad = set(self.layer_types) - {"attention", "mamba"}
+            bad = set(self.layer_types) - set(LAYER_KINDS)
             if bad or len(self.layer_types) != self.n_layers:
                 raise ValueError(
                     f"layer_types takes n_layers ({self.n_layers}) entries "
-                    f"of 'attention' | 'mamba', got {self.layer_types!r}")
+                    f"of 'attention' | 'mamba' | 'kda', got "
+                    f"{self.layer_types!r}")
             if self.attention != "full" or self.window is not None:
                 raise ValueError("layer_types composes with full attention "
                                  "only (no window, no EVA)")
@@ -278,6 +313,19 @@ class TransformerConfig:
                     self.mamba_heads < 1 or self.mamba_conv < 2):
                 raise ValueError("mamba layers need mamba_heads >= 1 and "
                                  "mamba_conv >= 2")
+            if "kda" in self.layer_types and (
+                    self.kda_heads < 1 or self.kda_conv < 2
+                    or self.kda_chunk < 1):
+                raise ValueError("kda layers need kda_heads >= 1, kda_conv "
+                                 ">= 2 and kda_chunk >= 1")
+        if self.router_score not in ("softmax", "sigmoid"):
+            raise ValueError(f"router_score must be 'softmax' or 'sigmoid', "
+                             f"got {self.router_score!r}")
+        if ((self.router_score != "softmax" or self.routed_scale != 1.0)
+                and self.moe_impl != "grouped"):
+            raise ValueError("router_score / routed_scale need "
+                             "moe_impl='grouped' (the one expert layer that "
+                             "knows more than one gate form)")
 
     @property
     def held_experts(self) -> int:
@@ -303,8 +351,19 @@ class TransformerConfig:
 
     @property
     def n_mamba_layers(self) -> int:
-        """Layers that keep a recurrent row state."""
+        """Mamba-2 layers: an SSM state and a conv tail a row."""
         return self.layer_kinds.count("mamba")
+
+    @property
+    def n_kda_layers(self) -> int:
+        """KDA layers: a matrix state a head and a conv tail a row."""
+        return self.layer_kinds.count("kda")
+
+    @property
+    def keeps_row_state(self) -> bool:
+        """Rows keep a recurrent state beside their pages, whatever the kind
+        of layer that keeps it (``init_row_state``)."""
+        return any(kind != "attention" for kind in self.layer_kinds)
 
     @property
     def layer_period(self) -> int:
@@ -321,7 +380,7 @@ class TransformerConfig:
         in the period, layers, first index among the period's layers of
         that kind)``."""
         period = self.layer_kinds[:self.layer_period]
-        runs, seen = [], {"attention": 0, "mamba": 0}
+        runs, seen = [], dict.fromkeys(LAYER_KINDS, 0)
         for j, kind in enumerate(period):
             if runs and runs[-1][0] == kind:
                 runs[-1][2] += 1
@@ -338,6 +397,10 @@ class TransformerConfig:
     def mamba_conv_dim(self) -> int:
         """Channels of the conv: [x | B | C], B and C of one group."""
         return self.mamba_inner + 2 * self.mamba_state
+
+    @property
+    def kda_inner(self) -> int:
+        return self.kda_heads * self.kda_head_dim
 
     @property
     def eva_summaries(self) -> int:
@@ -398,6 +461,8 @@ def init_params(cfg: TransformerConfig, rng) -> Dict[str, Any]:
         "wv": norm((la, d, kvd), 1 / math.sqrt(d)),
         "wo": norm((la, hd, d), 1 / math.sqrt(hd) / math.sqrt(2 * l)),
     }
+    if cfg.attn_gate:
+        attn["wg"] = norm((la, d, hd), 1 / math.sqrt(d))
     layers = {"attn_norm": gain((l, d), cfg.param_dtype),
               "mlp_norm": gain((l, d), cfg.param_dtype)}
     if cfg.layer_types is None:
@@ -406,11 +471,11 @@ def init_params(cfg: TransformerConfig, rng) -> Dict[str, Any]:
         # typed stack: the mixers' leaves by kind, [layers of that kind, ..]
         if la:
             layers["attention"] = attn
+        u = lambda shape, lo, hi: jax.random.uniform(
+            next(keys), shape, jnp.float32, lo, hi)
         lm = cfg.n_mamba_layers
         if lm:
             di, nh, cd = cfg.mamba_inner, cfg.mamba_heads, cfg.mamba_conv_dim
-            u = lambda shape, lo, hi: jax.random.uniform(
-                next(keys), shape, jnp.float32, lo, hi)
             # dt = softplus(dt_bias + ...) spread over 1e-3 .. 1e-1 and
             # A = -exp(A_log) over -1 .. -16: steps and decays far from 0/1
             dt0 = jnp.exp(u((lm, nh), math.log(1e-3), math.log(1e-1)))
@@ -428,6 +493,30 @@ def init_params(cfg: TransformerConfig, rng) -> Dict[str, Any]:
                 "out_proj": norm((lm, di, d),
                                  1 / math.sqrt(di) / math.sqrt(2 * l)),
             }
+        lk = cfg.n_kda_layers
+        if lk:
+            nh, hk, r = cfg.kda_heads, cfg.kda_inner, cfg.kda_head_dim
+            # softplus(dt_bias + ...) spread over 1e-3 .. 1e-1 and
+            # -exp(A_log) over -1 .. -8: a step's log-decay far from 0
+            dt0 = jnp.exp(u((lk, hk), math.log(1e-3), math.log(1e-1)))
+            layers["kda"] = {
+                # [q | k | v], each heads * head_dim wide
+                "in_proj": norm((lk, d, 3 * hk), 1 / math.sqrt(d)),
+                "conv_w": norm((lk, cfg.kda_conv, 3 * hk),
+                               1 / math.sqrt(cfg.kda_conv)),
+                "f_down": norm((lk, d, r), 1 / math.sqrt(d)),
+                "f_up": norm((lk, r, hk), 1 / math.sqrt(r)),
+                "dt_bias": (dt0 + jnp.log(-jnp.expm1(-dt0))
+                            ).astype(cfg.param_dtype),
+                "A_log": jnp.log(u((lk, nh), 1.0, 8.0)
+                                 ).astype(cfg.param_dtype),
+                "b_proj": norm((lk, d, nh), 1 / math.sqrt(d)),
+                "g_down": norm((lk, d, r), 1 / math.sqrt(d)),
+                "g_up": norm((lk, r, hk), 1 / math.sqrt(r)),
+                "norm": jnp.ones((lk, cfg.kda_head_dim), cfg.param_dtype),
+                "out_proj": norm((lk, hk, d),
+                                 1 / math.sqrt(hk) / math.sqrt(2 * l)),
+            }
     if cfg.attention == "eva":
         # the chunk pooling's query and the summaries' key offset, per
         # layer and head (unit scale: pooling weights far from uniform)
@@ -442,6 +531,9 @@ def init_params(cfg: TransformerConfig, rng) -> Dict[str, Any]:
             e_up=norm((l, eh, d, f), 1 / math.sqrt(d)),
             e_down=norm((l, eh, f, d), 1 / math.sqrt(f) / math.sqrt(2 * l)),
         )
+        if cfg.router_score == "sigmoid":
+            # the selection bias: float32 whatever the parameters' dtype
+            layers["router_bias"] = jnp.zeros((l, e), jnp.float32)
         if cfg.shared_width:
             sf = cfg.shared_width
             layers.update(
@@ -471,7 +563,7 @@ def init_params(cfg: TransformerConfig, rng) -> Dict[str, Any]:
 #: weight leaves worth quantizing — the big matmul operands.  Norms are
 #: tiny and precision-critical; the router is tiny and decides routing.
 _QUANT_KEYS = frozenset(
-    {"wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
+    {"wq", "wk", "wv", "wo", "wg", "w_gate", "w_up", "w_down",
      "e_gate", "e_up", "e_down", "s_gate", "s_up", "s_down",
      "in_proj", "out_proj"})
 
@@ -664,7 +756,8 @@ def _moe_grouped(cfg: TransformerConfig, lp, h,
         layer = None
     out, counts = grouped_experts(
         flat, logits, *(_wt(w, cfg.dtype) for w in ws), offset, layer,
-        top_k=cfg.top_k, held=held)
+        top_k=cfg.top_k, held=held, score=cfg.router_score,
+        bias=lp.get("router_bias"), scale=cfg.routed_scale)
     if ep_axis is not None:
         out = jax.lax.psum(out, ep_axis)
     return out.reshape(b, t, d), {**_zero_aux(), "expert_counts": counts}
@@ -968,7 +1061,8 @@ def forward_hidden(cfg: TransformerConfig, params, tokens,
     if (cfg.attention != "full" or cfg.n_pred_heads != 1
             or cfg.residual_dtype is not None
             or cfg.logits_dtype is not None
-            or not cfg.rope or cfg.tie_embeddings
+            or not cfg.rope or cfg.tie_embeddings or cfg.attn_gate
+            or cfg.attn_head_dim is not None
             or any(v is not None for v in (
                 cfg.attn_scale, cfg.embed_scale, cfg.residual_scale,
                 cfg.logits_scale))):
@@ -1206,22 +1300,34 @@ def init_paged_cache(cfg: TransformerConfig, n_pages: int,
 
 def init_row_state(cfg: TransformerConfig, rows: int) -> Dict[str, Any]:
     """The recurrent state of ``rows`` row slots, for the layers that keep
-    no K/V (a typed stack's mamba layers): ``ssm`` [mamba layers, rows,
-    heads * head_dim, state] float32 (heads and head channels as one dim,
-    see ``_mamba_mixer``) and ``conv``
-    [mamba layers, rows, mamba_conv - 1, conv channels], the conv's inputs
-    before each row's next position, in the compute dtype.  Its size does
-    not depend on a row's context.  Pass it to ``decode_step`` under
+    no K/V: the leaves of the kinds of layer present (``_ROW_STATE`` names
+    each kind's, in the order the layer scans carry them).  Mamba layers:
+    ``ssm`` [mamba layers, rows, heads * head_dim, state] float32 (heads and
+    head channels as one dim, see ``_mamba_mixer``) and ``conv`` [mamba
+    layers, rows, mamba_conv - 1, conv channels], the conv's inputs before
+    each row's next position, in the compute dtype.  KDA layers: ``kda_s``
+    [kda layers, rows, heads * head_dim, head_dim] float32 (heads and key
+    channels as one dim) and ``kda_conv`` [kda layers, rows, kda_conv - 1,
+    3 * heads * head_dim], the inputs of the conv over [q | k | v].  Its
+    size does not depend on a row's context.  Pass it to ``decode_step`` under
     ``cache["state"]`` beside the pool; a one-token step reads and writes
     every slot, a prefill from position 0 starts from an empty state and
     writes its rows' slots (``cache["slots"]``), whatever they held."""
-    lm = cfg.n_mamba_layers
-    return {
-        "ssm": jnp.zeros((lm, rows, cfg.mamba_inner, cfg.mamba_state),
-                         jnp.float32),
-        "conv": jnp.zeros((lm, rows, cfg.mamba_conv - 1, cfg.mamba_conv_dim),
-                          cfg.dtype),
-    }
+    state = {}
+    lm, lk = cfg.n_mamba_layers, cfg.n_kda_layers
+    if lm:
+        state.update(
+            ssm=jnp.zeros((lm, rows, cfg.mamba_inner, cfg.mamba_state),
+                          jnp.float32),
+            conv=jnp.zeros((lm, rows, cfg.mamba_conv - 1, cfg.mamba_conv_dim),
+                           cfg.dtype))
+    if lk:
+        state.update(
+            kda_s=jnp.zeros((lk, rows, cfg.kda_inner, cfg.kda_head_dim),
+                            jnp.float32),
+            kda_conv=jnp.zeros(
+                (lk, rows, cfg.kda_conv - 1, 3 * cfg.kda_inner), cfg.dtype))
+    return state
 
 
 class PageAllocator:
@@ -1958,7 +2064,11 @@ def _attend_decode(cfg: TransformerConfig, x, lp, ck, cv, li, positions,
         s = jnp.where(bad[:, None, None], -jnp.inf, s)
         probs = jax.nn.softmax(s, axis=-1).astype(cv_r.dtype)
         o = jnp.einsum("bkgtm,bkmd->btkgd", probs, cv_r)
-    x = _residual(cfg, x, _qmm(o.reshape(b, t, -1), lp["wo"], cfg.dtype))
+    o = o.reshape(b, t, -1)
+    if cfg.attn_gate:
+        with jax.named_scope("attention.gate"):
+            o = o * jax.nn.sigmoid(_qmm(h, lp["wg"], cfg.dtype))
+    x = _residual(cfg, x, _qmm(o, lp["wo"], cfg.dtype))
     return x, ck, cv, ((k, v) if defer else None)
 
 
@@ -2101,9 +2211,10 @@ def decode_step(cfg: TransformerConfig, params, cache, tokens, pos,
     return logits, out_cache
 
 
-def _mamba_mixer(cfg: TransformerConfig, x, lp, ssm, conv, mi, slots, valid):
+def _mamba_mixer(cfg: TransformerConfig, x, lp, state, mi, slots, valid):
     """The Mamba-2 mixer of one block over a token chunk; returns ``(x,
-    ssm, conv)`` with layer ``mi`` of the stacked row state updated.
+    (ssm, conv))`` with layer ``mi`` of the stacked row state ``state =
+    (ssm, conv)`` updated.
 
     ``x``: [B, t, d]; ``ssm`` [Lm, rows, H * P, N] float32 and ``conv`` [Lm,
     rows, K - 1, C]: the state store (``init_row_state``), carried through
@@ -2116,6 +2227,7 @@ def _mamba_mixer(cfg: TransformerConfig, x, lp, ssm, conv, mi, slots, valid):
     final states are written to."""
     from tfmesos_tpu.ops.ssm import (causal_conv, conv_tail, ssd_scan,
                                      ssm_update_stacked)
+    ssm, conv = state
     b, t, _ = x.shape
     f32 = jnp.float32
     nh, hp, ns = cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_state
@@ -2160,19 +2272,89 @@ def _mamba_mixer(cfg: TransformerConfig, x, lp, ssm, conv, mi, slots, valid):
     y = y.reshape(b, t, di) * jax.nn.silu(z.astype(f32))
     kw = {} if cfg.norm_eps is None else {"eps": cfg.norm_eps}
     y = rms_norm(y, lp["norm"].astype(f32), **kw).astype(cfg.dtype)
-    return _residual(cfg, x, _qmm(y, lp["out_proj"], cfg.dtype)), ssm, conv
+    return _residual(cfg, x, _qmm(y, lp["out_proj"], cfg.dtype)), (ssm, conv)
+
+
+def _kda_mixer(cfg: TransformerConfig, x, lp, state, ki, slots, valid):
+    """The KDA mixer of one block over a token chunk (``ops/kda.py`` has the
+    recurrence); returns ``(x, (s, conv))`` with layer ``ki`` of the stacked
+    row state ``state = (s, conv)`` updated.
+
+    ``x``: [B, t, d]; ``s`` [Lk, rows, H * dk, dv] float32 and ``conv`` [Lk,
+    rows, K - 1, 3 * H * dk]: the state store (``init_row_state``).  ``t ==
+    1``: every row is a slot: one step of the recurrence from the slot's
+    state.  ``t > 1``: a prefill from an EMPTY state, in chunks of
+    ``kda_chunk``; bucket padding (positions from ``valid`` [B] on) gets
+    ``g = 0`` and ``beta = 0`` and so leaves the state alone, the conv tail
+    is taken at the true end, and the final states go to ``slots`` [B]."""
+    from tfmesos_tpu.ops.kda import (kda_chunk_scan, kda_update_stacked,
+                                     l2norm)
+    from tfmesos_tpu.ops.ssm import causal_conv, conv_tail
+    s, conv = state
+    b, t, _ = x.shape
+    f32 = jnp.float32
+    nh, dk, hk, kc = (cfg.kda_heads, cfg.kda_head_dim, cfg.kda_inner,
+                      cfg.kda_conv)
+    h = _norm(cfg, x, lp["attn_norm"])
+    qkv = _qmm(h, lp["in_proj"], cfg.dtype)
+    # the log-decay per head and key channel, and the step per head: float32
+    f = _qmm(_qmm(h, lp["f_down"], cfg.dtype), lp["f_up"], cfg.dtype)
+    g = jax.nn.softplus(f.astype(f32) + lp["dt_bias"].astype(f32)).reshape(
+        b, t, nh, dk) * -jnp.exp(lp["A_log"].astype(f32))[:, None]
+    beta = jax.nn.sigmoid(_qmm(h, lp["b_proj"], cfg.dtype).astype(f32))
+    if cfg.kda_neg_eigval:
+        beta = 2.0 * beta
+    if t == 1:
+        act, xp = causal_conv(qkv, lp["conv_w"], None, tail=conv[ki])
+        new_tail = xp[:, 1:]
+    else:
+        live = jnp.arange(t, dtype=jnp.int32)[None] < valid[:, None]
+        g = jnp.where(live[..., None, None], g, 0.0)
+        beta = jnp.where(live[..., None], beta, 0.0)
+        act, xp = causal_conv(qkv, lp["conv_w"], None)
+        new_tail = conv_tail(xp, valid, kc)
+    act = jax.nn.silu(act).astype(cfg.dtype).reshape(b, t, 3, nh, dk)
+    q = l2norm(act[:, :, 0]) * dk ** -0.5
+    k, v = l2norm(act[:, :, 1]), act[:, :, 2]
+    if t == 1:
+        o, s = kda_update_stacked(s, ki, q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                  beta[:, 0])
+        o = o[:, None]
+        conv = conv.at[ki].set(new_tail.astype(conv.dtype))
+    else:
+        o, new = kda_chunk_scan(q, k, v, g, beta,
+                                jnp.zeros((b, nh, dk, dk), f32),
+                                cfg.kda_chunk)
+        # (layer, slot) indexed; heads and key channels ONE dim of the
+        # store, as the mamba store's (see _mamba_mixer)
+        s = s.at[ki, slots].set(new.reshape(b, hk, dk).astype(s.dtype))
+        conv = conv.at[ki, slots].set(new_tail.astype(conv.dtype))
+    # RMSNorm per head, then the low-rank sigmoid gate
+    kw = {} if cfg.norm_eps is None else {"eps": cfg.norm_eps}
+    gate = _qmm(_qmm(h, lp["g_down"], cfg.dtype), lp["g_up"], cfg.dtype)
+    o = rms_norm(o, lp["norm"].astype(f32), **kw) * jax.nn.sigmoid(
+        gate.astype(f32)).reshape(b, t, nh, dk)
+    o = o.reshape(b, t, hk).astype(cfg.dtype)
+    return _residual(cfg, x, _qmm(o, lp["out_proj"], cfg.dtype)), (s, conv)
+
+
+#: the leaves of a kind's row state (``init_row_state``), in the order the
+#: layer scans carry them, and the kind's mixer
+_ROW_STATE = {"mamba": ("ssm", "conv"), "kda": ("kda_s", "kda_conv")}
+_MIXERS = {"mamba": _mamba_mixer, "kda": _kda_mixer}
 
 
 def _typed_decode_step(cfg: TransformerConfig, params, cache, tokens, pos):
     """``decode_step`` for a typed stack (``layer_types``): a single-host
     paged cache for the attention layers and a row-state store for the
-    mamba layers, scanned over the pattern's periods and, inside one, over
-    each run of layers of a kind.
+    mamba and kda layers, scanned over the pattern's periods and, inside one,
+    over each run of layers of a kind.
 
     ``cache``: ``k``/``v`` ([attention layers, P, KV, page, Dh]), ``pages``,
-    ``state`` (``init_row_state``) and, for a prefill (t > 1, which starts
-    at position 0 from an empty state), ``slots`` [B] (the row slots to
-    fill) and ``valid`` [B] (real positions per row; the rest is padding).
+    ``state`` (``init_row_state``: each kind's leaves) and, for a prefill
+    (t > 1, which starts at position 0 from an empty state), ``slots`` [B]
+    (the row slots to fill) and ``valid`` [B] (real positions per row; the
+    rest is padding).
     With ``valid`` the logits come back at each row's LAST real position
     only ([B, 1, V]): the head is not run over a prompt.  Returns (logits,
     cache); the cache gains ``expert_counts`` [L, held] int32 where the
@@ -2180,15 +2362,15 @@ def _typed_decode_step(cfg: TransformerConfig, params, cache, tokens, pos):
     step)."""
     b, t = tokens.shape
     pages, state = cache.get("pages"), cache.get("state")
-    if pages is None or (cfg.n_mamba_layers and state is None):
+    if pages is None or (cfg.keeps_row_state and state is None):
         raise ValueError("a typed stack decodes through a paged cache and, "
-                         "with mamba layers, a row state (init_paged_cache, "
-                         "init_row_state)")
+                         "with mamba or kda layers, a row state "
+                         "(init_paged_cache, init_row_state)")
     if t > 1 and not (isinstance(pos, int) and pos == 0):
         raise ValueError("a typed stack's chunk of several tokens is a "
                          "prefill from position 0")
     slots, valid = cache.get("slots"), cache.get("valid")
-    if t > 1 and cfg.n_mamba_layers:
+    if t > 1 and cfg.keeps_row_state:
         if slots is None:
             raise ValueError("a prefill names the row slots it fills "
                              "(cache['slots'])")
@@ -2199,7 +2381,7 @@ def _typed_decode_step(cfg: TransformerConfig, params, cache, tokens, pos):
     per, runs = cfg.layer_period, cfg.layer_runs
     n_per = cfg.n_layers // per
     per_kind = {kind: sum(r[2] for r in runs if r[0] == kind)
-                for kind in ("attention", "mamba")}
+                for kind in LAYER_KINDS}
     grouped = bool(cfg.n_experts) and cfg.moe_impl == "grouped"
     lay = params["layers"]
     # The stacks stay whole and a layer is INDEXED out of them (a slice of
@@ -2207,8 +2389,7 @@ def _typed_decode_step(cfg: TransformerConfig, params, cache, tokens, pos):
     # weights); the grouped expert kernels take the whole expert stacks
     # and the layer index (``_EXPERT_LEAVES``: no copy of a layer's experts
     # in front of a kernel either).
-    common = {k: v for k, v in lay.items()
-              if k not in ("attention", "mamba")}
+    common = {k: v for k, v in lay.items() if k not in LAYER_KINDS}
     experts = ({k: common.pop(k) for k in _EXPERT_LEAVES} if grouped
                else {})
 
@@ -2220,7 +2401,7 @@ def _typed_decode_step(cfg: TransformerConfig, params, cache, tokens, pos):
     def one_layer(kind, carry, li, ki):
         """Layer ``li`` of the stack, the ``ki``-th of its kind (a pool /
         state layer index)."""
-        x, ck, cv, ssm, conv = carry
+        x, ck, cv, st = carry
         lp = {**at(common, li), **at(lay[kind], ki)}
         if kind == "attention":
             with jax.named_scope("attention"):
@@ -2235,9 +2416,10 @@ def _typed_decode_step(cfg: TransformerConfig, params, cache, tokens, pos):
                     cv = _paged_cache_write_all(cv, chunk[1][None], pages,
                                                 pos, layer0=ki)
         else:
-            with jax.named_scope("mamba"):
-                x, ssm, conv = _mamba_mixer(cfg, x, lp, ssm, conv, ki,
-                                            slots, valid)
+            with jax.named_scope(kind):
+                x, new = _MIXERS[kind](cfg, x, lp, st[kind], ki, slots,
+                                       valid)
+                st = {**st, kind: new}
         with jax.named_scope("mlp"):
             h = _norm(cfg, x, lp["mlp_norm"])
             ffn, aux = _ffn(cfg, None, {**lp, **experts}, h,
@@ -2245,7 +2427,7 @@ def _typed_decode_step(cfg: TransformerConfig, params, cache, tokens, pos):
             x = _residual(cfg, x, ffn)
         counts = (aux["expert_counts"] if grouped
                   else jnp.zeros((0,), jnp.int32))
-        return (x, ck, cv, ssm, conv), counts
+        return (x, ck, cv, st), counts
 
     def period(carry, pi):
         counts = []
@@ -2258,18 +2440,20 @@ def _typed_decode_step(cfg: TransformerConfig, params, cache, tokens, pos):
             counts.append(c)
         return carry, jnp.concatenate(counts, axis=0)
 
-    ssm = conv = None
-    if cfg.n_mamba_layers:
-        ssm, conv = state["ssm"], state["conv"]
-    (x, new_k, new_v, ssm, conv), counts = jax.lax.scan(
-        period, (x, cache["k"], cache["v"], ssm, conv),
+    # the row state rides the scans as one tuple of leaves a kind
+    st = {kind: tuple(state[leaf] for leaf in leaves)
+          for kind, leaves in _ROW_STATE.items() if kind in cfg.layer_kinds}
+    (x, new_k, new_v, st), counts = jax.lax.scan(
+        period, (x, cache["k"], cache["v"], st),
         jnp.arange(n_per, dtype=jnp.int32))
     if valid is not None and t > 1:     # the head at the last real position
         x = jnp.take_along_axis(x, (valid - 1)[:, None, None], axis=1)
     logits = _final_logits(cfg, params, x)
     out_cache = {"k": new_k, "v": new_v, "pages": pages}
-    if cfg.n_mamba_layers:
-        out_cache["state"] = {"ssm": ssm, "conv": conv}
+    if cfg.keeps_row_state:
+        out_cache["state"] = {
+            leaf: new for kind, leaves in st.items()
+            for leaf, new in zip(_ROW_STATE[kind], leaves)}
     if grouped:
         out_cache["expert_counts"] = counts.reshape(cfg.n_layers, -1)
     return logits, out_cache
@@ -3220,6 +3404,8 @@ def partition_specs(cfg: TransformerConfig, mesh: Mesh) -> Dict[str, Any]:
         "wv": P(None, "fsdp", "tp"),
         "wo": P(None, "tp", "fsdp"),
     }
+    if cfg.attn_gate:
+        attn["wg"] = P(None, "fsdp", "tp")
     layer = {"attn_norm": P(None, None), "mlp_norm": P(None, None)}
     if cfg.layer_types is None:
         layer.update(attn)
@@ -3235,6 +3421,16 @@ def partition_specs(cfg: TransformerConfig, mesh: Mesh) -> Dict[str, Any]:
                 "conv_w": P(None, None, None), "conv_b": P(None, None),
                 "dt_bias": P(None, None), "A_log": P(None, None),
                 "D": P(None, None), "norm": P(None, None)}
+        if cfg.n_kda_layers:
+            layer["kda"] = {
+                "in_proj": P(None, "fsdp", None),
+                "out_proj": P(None, None, "fsdp"),
+                "conv_w": P(None, None, None),
+                "f_down": P(None, None, None), "f_up": P(None, None, None),
+                "g_down": P(None, None, None), "g_up": P(None, None, None),
+                "b_proj": P(None, None, None),
+                "dt_bias": P(None, None), "A_log": P(None, None),
+                "norm": P(None, None)}
     if cfg.attention == "eva":
         layer.update(eva_phi=P(None, "tp", None), eva_mu=P(None, "tp", None))
     if cfg.n_experts:
@@ -3244,6 +3440,8 @@ def partition_specs(cfg: TransformerConfig, mesh: Mesh) -> Dict[str, Any]:
             e_up=P(None, "ep", "fsdp", "tp"),
             e_down=P(None, "ep", "tp", "fsdp"),
         )
+        if cfg.router_score == "sigmoid":
+            layer["router_bias"] = P(None, None)
         if cfg.shared_width:
             layer.update(
                 s_gate=P(None, "fsdp", "tp"),

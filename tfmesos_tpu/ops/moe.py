@@ -209,25 +209,43 @@ def grouped_swiglu(x, w_gate, w_up, tile_expert, live_tiles, tile: int,
     return (jax.nn.silu(g) * u).reshape(x.shape[0], -1).astype(x.dtype)
 
 
+def route(router_logits, top_k: int, score: str = "softmax", bias=None,
+          scale: float = 1.0):
+    """(gates [T, k] float32, expert ids [T, k]) from float32 router logits
+    [T, E].  ``score="softmax"``: the ``top_k`` largest logits, softmax over
+    those kept.  ``score="sigmoid"``: scores ``s = sigmoid(logits)``, the
+    ``top_k`` largest ``s + bias`` (``bias`` [E]: a selection bias, no part
+    of the gate), gates ``s_i / sum of the chosen s`` times ``scale``."""
+    if score == "softmax":
+        top_vals, top_idx = jax.lax.top_k(router_logits, top_k)
+        return jax.nn.softmax(top_vals, axis=-1), top_idx
+    s = jax.nn.sigmoid(router_logits)
+    _, top_idx = jax.lax.top_k(s if bias is None else s + bias, top_k)
+    kept = jnp.take_along_axis(s, top_idx, axis=-1)
+    return kept / jnp.sum(kept, axis=-1, keepdims=True) * scale, top_idx
+
+
 @functools.partial(jax.jit, static_argnames=("top_k", "held", "tile",
-                                             "use_pallas", "interpret"))
+                                             "use_pallas", "interpret",
+                                             "score", "scale"))
 def grouped_experts(h, router_logits, w_gate, w_up, w_down, offset,
                     layer=None, *,
                     top_k: int, held: int, tile: Optional[int] = None,
                     use_pallas: Optional[bool] = None,
-                    interpret: bool = False):
+                    interpret: bool = False, score: str = "softmax",
+                    bias=None, scale: float = 1.0):
     """The held experts' part of a top-k routed expert layer.
 
     ``h``: [T, d] tokens; ``router_logits``: [T, E] float32 over ALL experts;
     ``w_gate``/``w_up``: [held, d, f], ``w_down``: [held, f, d]: the experts
     ``offset .. offset + held - 1`` (or whole ``[L, held, ..]`` stacks and
     ``layer``).  The gates are the softmax over the
-    ``top_k`` kept logits.  Returns (out [T, d] in ``h``'s dtype: the sum
+    ``top_k`` kept logits, or :func:`route`'s other form (``score``,
+    ``bias``, ``scale``).  Returns (out [T, d] in ``h``'s dtype: the sum
     over a token's assignments that fall on held experts of gate * expert
     output, accumulated in float32; counts [held] int32)."""
     t, d = h.shape
-    top_vals, top_idx = jax.lax.top_k(router_logits, top_k)
-    gates = jax.nn.softmax(top_vals, axis=-1)               # [T, k] float32
+    gates, top_idx = route(router_logits, top_k, score, bias, scale)
     tile = tile or pick_tile(t * top_k, router_logits.shape[-1])
     lay = grouped_layout(top_idx, held, offset, tile)
     kw = dict(tile=tile, layer=layer, use_pallas=use_pallas,

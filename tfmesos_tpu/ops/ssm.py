@@ -75,8 +75,9 @@ _UPDATE_VMEM_LIMIT = 32 * 2 ** 20
 
 def causal_conv(x, w, b, tail=None):
     """Causal depthwise conv over time.  ``x``: [B, T, C]; ``w``: [K, C]
-    (tap ``K - 1`` multiplies the current position); ``b``: [C]; ``tail``:
-    [B, K - 1, C], the inputs before the chunk (zeros when None).  Returns
+    (tap ``K - 1`` multiplies the current position); ``b``: [C] (None: no
+    bias); ``tail``: [B, K - 1, C], the inputs before the chunk (zeros when
+    None).  Returns
     (out [B, T, C] float32, the padded input [B, K - 1 + T, C] from which
     :func:`conv_tail` takes the next tail)."""
     bsz, t, c = x.shape
@@ -85,7 +86,7 @@ def causal_conv(x, w, b, tail=None):
         tail = jnp.zeros((bsz, k - 1, c), x.dtype)
     xp = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
     wf = w.astype(jnp.float32)
-    out = b.astype(jnp.float32)
+    out = 0.0 if b is None else b.astype(jnp.float32)
     for j in range(k):
         out = out + xp[:, j:j + t].astype(jnp.float32) * wf[j]
     return out, xp

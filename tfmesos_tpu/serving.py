@@ -1480,7 +1480,7 @@ class ContinuousBatcher:
             # closed that already; what it buys grows with the rows such
             # a state lets a chip hold (64 rows: 2.4 ms of host phases
             # and 3.3 ms of idle device behind every 30 ms block).
-            pipeline_depth = int(cfg.n_mamba_layers > 0)
+            pipeline_depth = int(cfg.keeps_row_state)
         if pipeline_depth not in (0, 1):
             raise ValueError(f"pipeline_depth must be 0 (synchronous "
                              f"host sync), 1 (one block of device-"
@@ -1537,9 +1537,9 @@ class ContinuousBatcher:
         # enumerate every reachable value against BYPASS_ALLOWLIST.
         eva = cfg.attention == "eva"
         # Rows that keep a recurrent state beside their pages (a typed
-        # stack's mamba layers): the row-slot state store lives in the
-        # donated pool (``pool["state"]``, init_row_state).
-        recurrent = cfg.n_mamba_layers > 0
+        # stack's mamba or kda layers): the row-slot state store lives in
+        # the donated pool (``pool["state"]``, init_row_state).
+        recurrent = cfg.keeps_row_state
         self._recurrent = recurrent
         self._bypass = compute_bypass_reasons(
             speculative=draft_cfg is not None, n_shards=self.n_shards,
