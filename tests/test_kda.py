@@ -1,7 +1,8 @@
 """KDA layers (a gated delta rule with a decay per key channel) in a typed
 stack, the attention output gate and the sigmoid router, program side: the
 chunkwise form against the token-by-token recurrence, the stacked in-place
-update against the specification, the router's gate forms against hand
+update against the specification (the XLA form, and the Pallas kernel in
+interpret mode), the router's gate forms against hand
 arithmetic, and ``ContinuousBatcher`` over a stack with KDA layers (what it
 refuses and bypasses, the lag and block modes token for token, the tick
 ring's fields, the named scopes).  The float32 reference of the whole model
@@ -161,6 +162,202 @@ def test_the_update_reads_the_state_before_it_writes_it():
     full = (np.eye(dk) - beta * np.outer(k, k)) @ (np.exp(g)[:, None] * s) \
         + beta * np.outer(k, v)
     np.testing.assert_allclose(want, full, rtol=1e-12)
+
+
+# -- the decode step's one-pass update (the Pallas kernel, interpret mode) -----
+#
+# name -> (kda layers, rows, heads, key size, value size, channels a block may
+# hold or None for what the budget gives, the layer updated).  ``kda_update``
+# on the layer's slice is the specification.
+UPDATE_CASES = {
+    "heads_inside_one_tile": (3, 2, 8, 16, 128, None, 1),
+    "a_head_a_tile_fewer_than_lanes": (3, 3, 3, 128, 128, None, 2),
+    "blocks_of_two_heads": (2, 2, 4, 128, 128, 256, 1),
+    # six heads of half a tile, room for two tiles: the rule takes one
+    # (two do not divide three)
+    "budget_does_not_divide": (2, 2, 6, 64, 128, 256, 0),
+    "head_wider_than_a_tile": (2, 1, 2, 256, 128, None, 1),
+    "value_of_two_lane_tiles": (2, 2, 4, 32, 256, None, 0),
+}
+
+
+def _update_inputs(lk, rows, h, dk, dv, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    store = jax.random.normal(ks[0], (lk, rows, h * dk, dv))
+    q = kda.l2norm(jax.random.normal(ks[1], (rows, h, dk))) * dk ** -0.5
+    k = kda.l2norm(jax.random.normal(ks[2], (rows, h, dk)))
+    v = jax.random.normal(ks[3], (rows, h, dv))
+    g = -jax.random.uniform(ks[4], (rows, h, dk))
+    beta = 2 * jax.random.uniform(ks[5], (rows, h))
+    return store, q, k, v, g, beta
+
+
+def _hold_blocks_to(monkeypatch, channels, dv):
+    if channels is not None:
+        monkeypatch.setattr(kda, "_UPDATE_VMEM_BUDGET", 4 * channels * dv * 4)
+
+
+@pytest.mark.parametrize("name", sorted(UPDATE_CASES))
+def test_update_kernel_is_the_specification_in_place(monkeypatch, name):
+    """The kernel over the stacked store against ``kda_update`` on the
+    layer's slice: o and the layer's new state to float32 rounding (the
+    sums over the key channels in another order), every other layer's bytes
+    as they were."""
+    lk, rows, h, dk, dv, channels, layer = UPDATE_CASES[name]
+    store, q, k, v, g, beta = _update_inputs(lk, rows, h, dk, dv)
+    _hold_blocks_to(monkeypatch, channels, dv)
+    block = kda._update_block(h, dk, dv)
+    assert (h * dk) % block == 0 and block % dk == 0 and block % 128 == 0
+    assert block <= (channels or h * dk)
+    if channels is not None:
+        assert block < h * dk       # more than one block a row
+    if name == "budget_does_not_divide":
+        assert block == 128
+    o, new = kda.kda_update_stacked(store, jnp.asarray(layer), q, k, v, g,
+                                    beta, interpret=True)
+    want_o, want = kda.kda_update(store[layer].reshape(rows, h, dk, dv), q,
+                                  k, v, g, beta)
+    assert o.shape == (rows, h, dv) and o.dtype == F32
+    assert float(jnp.abs(o - want_o).max()) <= 1e-5 * float(
+        jnp.abs(want_o).max())
+    assert float(jnp.abs(new[layer].reshape(want.shape) - want).max()) \
+        <= 1e-6 * float(jnp.abs(want).max())
+    for other in set(range(lk)) - {layer}:
+        np.testing.assert_array_equal(np.asarray(new[other]),
+                                      np.asarray(store[other]))
+
+
+@pytest.mark.parametrize("h,dk,channels,group_tiles,group", [
+    (16, 128, None, 8, 8),      # a whole row, two iterations of eight heads
+    (8, 128, 512, 2, 2),        # two blocks a row, two iterations a block
+    (32, 16, None, 2, 16),      # eight heads a tile: two tiles an iteration
+    (4, 256, None, 2, 1),       # a head of two tiles is an iteration
+    (6, 128, None, 4, 3)])      # four tiles do not divide six: three
+def test_update_kernel_loops_over_groups_of_heads(monkeypatch, h, dk,
+                                                  channels, group_tiles,
+                                                  group):
+    """More heads a block than one loop iteration unrolls: the iterations
+    roll their own tiles' columns into place and index the state, v, o and
+    ``beta k . q`` from the iteration's number."""
+    monkeypatch.setattr(kda, "_GROUP_TILES", group_tiles)
+    _hold_blocks_to(monkeypatch, channels, 128)
+    heads = kda._update_block(h, dk, 128) // dk
+    assert kda._update_group(heads, dk) == group and heads > group
+    store, q, k, v, g, beta = _update_inputs(2, 2, h, dk, 128, seed=h)
+    o, new = kda.kda_update_stacked(store, jnp.asarray(1), q, k, v, g, beta,
+                                    interpret=True)
+    want_o, want = kda.kda_update(store[1].reshape(2, h, dk, 128), q, k, v,
+                                  g, beta)
+    assert float(jnp.abs(o - want_o).max()) <= 1e-5 * float(
+        jnp.abs(want_o).max())
+    assert float(jnp.abs(new[1].reshape(want.shape) - want).max()) \
+        <= 1e-6 * float(jnp.abs(want).max())
+    np.testing.assert_array_equal(np.asarray(new[0]), np.asarray(store[0]))
+
+
+@pytest.mark.parametrize("name", ["heads_inside_one_tile",
+                                  "blocks_of_two_heads"])
+def test_update_kernel_leaves_a_padding_row_bit_for_bit(monkeypatch, name):
+    """A row with ``g = 0`` and ``beta = 0`` (bucket padding, an idle slot)
+    comes back as it was, to the bit; the other rows move."""
+    lk, rows, h, dk, dv, channels, layer = UPDATE_CASES[name]
+    store, q, k, v, g, beta = _update_inputs(lk, rows, h, dk, dv, seed=1)
+    _hold_blocks_to(monkeypatch, channels, dv)
+    g, beta = g.at[rows - 1].set(0.0), beta.at[rows - 1].set(0.0)
+    _, new = kda.kda_update_stacked(store, layer, q, k, v, g, beta,
+                                    interpret=True)
+    np.testing.assert_array_equal(np.asarray(new[layer, rows - 1]),
+                                  np.asarray(store[layer, rows - 1]))
+    assert float(jnp.abs(new[layer, 0] - store[layer, 0]).max()) > 0
+
+
+@pytest.mark.parametrize("h,dk,dv,budget,want", [
+    (64, 128, 128, None, 8192),     # the cell: a row-layer (4 MiB) is a block
+    (64, 128, 128, 2 ** 21, 1024),
+    (256, 128, 128, None, 8192),
+    (256, 128, 128, 2 ** 26, 16384),    # at most a tile's lanes of tiles
+    (6, 64, 128, 4 * 256 * 128 * 4, 128),
+    (3, 256, 128, None, 768),       # whole heads of two tiles each
+    (3, 256, 128, 4 * 512 * 128 * 4, 256),
+    (8, 16, 128, None, 128),
+    (5, 16, 128, None, None),       # a row that is not whole tiles
+    (32, 4, 128, None, None),       # a key size that is not whole sublanes
+    (8, 16, 64, None, None),        # a value size that is not whole lanes
+    (3, 64, 128, None, None),       # whole tiles would split a head
+    (8, 128, 128, 4 * 64 * 128 * 4, None)])     # one head is over the budget
+def test_update_block_rule(monkeypatch, h, dk, dv, budget, want):
+    if budget is not None:
+        monkeypatch.setattr(kda, "_UPDATE_VMEM_BUDGET", budget)
+    assert kda._update_block(h, dk, dv) == want
+
+
+@pytest.mark.parametrize("h,dk,dv", [(5, 16, 128), (32, 4, 128),
+                                     (8, 16, 64)])
+def test_shapes_the_kernel_does_not_tile_take_the_xla_form(h, dk, dv):
+    """A row that is not whole 128-channel tiles, a key size that is not
+    whole sublanes, a value size that is not whole lanes: the XLA form,
+    whatever is forced (the kernel would not lower, and nothing raises)."""
+    store, q, k, v, g, beta = _update_inputs(2, 2, h, dk, dv)
+    o, new = kda.kda_update_stacked(store, 1, q, k, v, g, beta,
+                                    use_pallas=True)
+    want_o, want = kda.kda_update_stacked(store, 1, q, k, v, g, beta,
+                                          use_pallas=False)
+    np.testing.assert_array_equal(np.asarray(o), np.asarray(want_o))
+    np.testing.assert_array_equal(np.asarray(new), np.asarray(want))
+    text = jax.jit(lambda st: kda.kda_update_stacked(
+        st, 1, q, k, v, g, beta, use_pallas=True)).lower(store).as_text()
+    assert "kda_update" not in text
+
+
+@pytest.mark.parametrize("kinds", ["akkk", "kk"])
+def test_typed_decode_step_with_the_kernel_forced(monkeypatch, kinds):
+    """One typed ``decode_step`` at toy widths the kernel tiles (8 heads of
+    128: a mixer's keys and values have one size), a prefill and then two
+    one-token steps, with the update kernel forced (interpret) against the
+    XLA form: logits and the whole state store."""
+    cfg = kda_cfg(kinds, kda_heads=8, kda_head_dim=128, d_model=64)
+    assert kda._update_block(8, 128, 128) == 1024
+    params = init_params(cfg, jax.random.PRNGKey(3))
+    rows, t = 2, 16
+    rng_tokens = np.random.default_rng(5).integers(
+        0, 128, size=(rows, t + 2)).astype(np.int32)
+
+    xla_or_kernel = kda.kda_update_stacked
+    kernel_calls = []
+
+    def forced_update(*args):
+        kernel_calls.append(args[1])
+        return xla_or_kernel(*args, interpret=True)
+
+    def run(forced):
+        if forced:
+            monkeypatch.setattr(kda, "kda_update_stacked", forced_update)
+        cache = dict(tr.init_paged_cache(cfg, 8, 16),
+                     state=tr.init_row_state(cfg, rows),
+                     pages=jnp.arange(rows * 2, dtype=jnp.int32).reshape(
+                         rows, 2))
+        prompt = jnp.asarray(rng_tokens[:, :t])
+        _, cache = tr.decode_step(
+            cfg, params, dict(cache, slots=jnp.arange(rows, dtype=jnp.int32),
+                              valid=jnp.asarray([t, t - 5], jnp.int32)),
+            prompt, 0)
+        outs = []
+        for i, pos in enumerate(([t, t - 5], [t + 1, t - 4])):
+            cache = {k: cache[k] for k in ("k", "v", "pages", "state")}
+            logits, cache = tr.decode_step(
+                cfg, params, cache,
+                jnp.asarray(rng_tokens[:, t + i:t + i + 1]),
+                jnp.asarray(pos, jnp.int32))
+            outs.append(logits)
+        return jnp.stack(outs), cache["state"]["kda_s"]
+
+    want_logits, want_state = run(False)
+    logits, state = run(True)
+    assert kernel_calls and float(jnp.abs(state).max()) > 0
+    assert float(jnp.abs(logits - want_logits).max()) <= 1e-5 * float(
+        jnp.abs(want_logits).max())
+    assert float(jnp.abs(state - want_state).max()) <= 1e-5 * float(
+        jnp.abs(want_state).max())
 
 
 # -- the router's gate forms --------------------------------------------------

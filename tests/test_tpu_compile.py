@@ -417,15 +417,18 @@ def test_typed_step_never_relayouts_the_state_store(topo, monkeypatch, name,
 # experts of 320 a layer, a sliced vocabulary), compiled whole for the
 # described v5e with the kernel paths forced.  The store, the pool and the
 # expert stacks must be updated or read in place: no copy, transpose or slice
-# of any of them.  The one-token update is XLA's (``ops/kda.py``): it reads
-# the layer's state and writes it back under the donated store's own buffer.
+# of any of them.  The one-token update is ONE pass, the ``kda_update`` kernel
+# (PR 38; ``ops/kda.py``): it takes the donated store and returns it, and no
+# fusion names the store or one whole layer of it.  As XLA compiled the
+# update, it copied the layer out of the store, reduced the copy and then
+# read and wrote the layer in place: five passes for two.
 
 @pytest.mark.parametrize("name,rows,t", [("decode_r192", 192, 1),
                                          ("prefill_t1024", 1, 1024)])
 def test_kda_step_never_relayouts_the_state_store(topo, monkeypatch, name,
                                                   rows, t):
     from tfmesos_tpu.models import transformer
-    from tfmesos_tpu.ops import moe
+    from tfmesos_tpu.ops import kda, moe
     from tfmesos_tpu.ops.attention import attend
 
     cfg = transformer.TransformerConfig(
@@ -442,6 +445,7 @@ def test_kda_step_never_relayouts_the_state_store(topo, monkeypatch, name,
     monkeypatch.setattr(transformer, "attend",
                         partial(attend, use_pallas=True))
     monkeypatch.setattr(moe, "_on_tpu", lambda use: True)
+    monkeypatch.setattr(kda, "_on_tpu", lambda use: True)
     one_chip = SingleDeviceSharding(topo.devices[0])
 
     def struct(x):
@@ -482,12 +486,30 @@ def test_kda_step_never_relayouts_the_state_store(topo, monkeypatch, name,
         moved = re.findall(r"= " + re.escape(leaf)
                            + r"\S* (?:copy|transpose|slice)\([^)]*\)", text)
         assert not moved, f"{leaf} is copied: {moved[:2]}"
+    if t == 1:
+        # The one-token update is ONE pass (PR 38): the Pallas kernel takes
+        # the store and returns it, and no fusion names the store or one
+        # whole layer of it in any view (control flow, tuples, bitcasts and
+        # the kernel itself may).
+        calls = [ln for ln in text.splitlines()
+                 if " custom-call(" in ln and "kda_update" in ln]
+        assert calls
+        for ln in calls:
+            result, operands = ln.split(" custom-call(", 1)
+            assert store in result and store in operands, ln[:200]
+        views = (store, f"f32[3,{slots},64,128,128]",
+                 f"f32[1,{slots},8192,128]", f"f32[{slots},8192,128]",
+                 f"f32[{slots},64,128,128]")
+        again = [ln.strip()[:160] for ln in text.splitlines()
+                 if " fusion(" in ln and any(v in ln for v in views)]
+        assert not again, f"a fusion passes the state: {again[:2]}"
     # beside 6.6 GB of weights, 2.5 GB of state and 2.4 GB of pool: a step's
-    # temporaries (a decode step's decayed state of one layer; a prefill's
-    # activations and one chunk's [64, 64, 128] decay terms a head) leave
-    # the chip a gigabyte
+    # temporaries (a decode step holds no layer of the state since PR 38:
+    # its activations at 192 rows; a prefill's activations and one chunk's
+    # [64, 64, 128] decay terms a head)
     mem = compiled.memory_analysis()
-    assert mem.temp_size_in_bytes < 2.5e9, mem.temp_size_in_bytes
+    assert mem.temp_size_in_bytes < (1.5e8 if t == 1 else 2.5e9), \
+        mem.temp_size_in_bytes
 
 
 # -- EVA attention at EvaByte's widths (PR 28) ---------------------------------
