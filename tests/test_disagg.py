@@ -459,6 +459,8 @@ def test_fleet_disagg_round_trip_real_model(setup):
                 f"request {i} diverged through the disagg fleet"
             assert results[i]["total_ms"] >= results[i]["ttft_ms"] >= 0
             assert "decode_ms" in results[i]
+            # the decode replica's own queue, beside and not inside ttft_ms
+            assert results[i]["queue_ms"] >= 0
         c = metrics.snapshot()["counters"]
         assert c["disagg_prefills"] >= len(reqs)
         assert c["disagg_decodes"] >= len(reqs)

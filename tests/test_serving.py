@@ -3035,7 +3035,12 @@ def test_rid_seed_gives_disjoint_rid_streams(setup):
 
 TICK_FIELDS = {"name", "tick", "batcher", "t", "wall_ms", "kind", "mode",
                "k", "rows", "dur", "admitted", "prefill_tokens", "phases",
-               "idle_ms", "compiles", "compile_s"}
+               "idle_ms", "compiles", "compile_s", "gc_ms", "gc_n",
+               "gc_gen2", "thread_cpu_ms", "ctx_invol", "cpu_ms", "majflt",
+               "cpu_span_ms", "ready"}
+REQUEST_FIELDS = {"name", "rid", "batcher", "status", "t_submit", "t_admit",
+                  "t_first", "t_done", "prompt_tokens", "prefill_tokens",
+                  "out_tokens", "admit_tick", "first_tick", "done_tick"}
 TICK_PHASES = {"batcher.pull", "batcher.admit", "batcher.prefill_sync",
                "batcher.prep", "batcher.dispatch", "batcher.readback",
                "batcher.retire", "batcher.emit"}
@@ -3069,6 +3074,22 @@ def _check_ticks(batcher, traces, modes, must):
         assert r["kind"] in ("decode", "prefill", "mixed", "fused", "idle")
         assert (r["name"] == "decode.block") == (
             r["kind"] in ("decode", "mixed", "fused"))
+        # what held the tick: differences of counters that only grow
+        assert all(r[k] >= 0 for k in ("gc_ms", "gc_n", "gc_gen2",
+                                       "thread_cpu_ms", "ctx_invol"))
+        assert r["gc_gen2"] <= r["gc_n"] and (r["gc_ms"] > 0) == (
+            r["gc_n"] > 0)
+        # the process-wide ones where the tick closed with a read of them,
+        # over a span that ends with it: every tick of a quarter second
+        process = [r[k] for k in ("cpu_ms", "majflt", "cpu_span_ms")]
+        if r["wall_ms"] >= 250.0 or process != [None] * 3:
+            assert r["cpu_ms"] >= 0 and r["majflt"] >= 0
+            assert r["cpu_span_ms"] >= max(250.0, r["wall_ms"] - 1e-6)
+        # the pipelined loop read a lagged block back, ready or not; a
+        # synchronous loop does not ask (it has only just dispatched)
+        assert r["ready"] in ((0, 1) if r["mode"] == "pipelined"
+                              and "batcher.readback" in r["phases"]
+                              else (None,))
     ticks = [r["tick"] for r in recs]
     assert all(b > a for a, b in zip(ticks, ticks[1:]))
     blocks = [r for r in recs if r["name"] == "decode.block"]
@@ -3266,3 +3287,288 @@ def test_profile_has_flat_batcher_phases_and_named_programs(setup, tmp_path):
     programs = {n for n, *_ in line if n.startswith("PjitFunction(")}
     assert {"PjitFunction(decode_block)", "PjitFunction(prefill)"} <= programs
     assert "PjitFunction(fn)" not in programs
+
+
+# -- the request ring and the stall rule (docs/SERVING.md "Observability") ----
+
+
+def _check_requests(batcher, recs):
+    """What every request record has in common: the fields, stamps in
+    order on the one clock, and edges made by ticks the tick ring has."""
+    ticks = {r["tick"]: r for r in batcher.flight.snapshot()}
+    for r in recs:
+        assert set(r) == REQUEST_FIELDS and r["name"] == "request"
+        assert r["status"] in ("completed", "expired", "shed", "suspended",
+                               "abandoned")
+        stamps = [r[k] for k in ("t_submit", "t_admit", "t_first", "t_done")
+                  if r[k] is not None]
+        assert stamps == sorted(stamps) and len(stamps) >= 2
+        assert (r["t_admit"] is None) == (r["admit_tick"] is None)
+        assert (r["t_first"] is None) == (r["first_tick"] is None)
+        assert r["t_first"] is None or r["t_admit"] is not None
+        for edge, t in (("admit_tick", r["t_admit"]),
+                        ("first_tick", r["t_first"]),
+                        ("done_tick", r["t_done"])):
+            if r[edge] is not None:
+                tick = ticks[r[edge]]   # it exists, and the edge lies in it
+                assert tick["t"] <= t <= tick["t"] + tick["wall_ms"] / 1e3 \
+                    + 1e-6
+        assert r["prompt_tokens"] >= 1 and r["prefill_tokens"] >= 0
+        assert r["out_tokens"] >= (1 if r["status"] == "completed" else 0)
+
+
+@pytest.mark.parametrize("mode", sorted(TICK_MODES))
+def test_request_ring_has_one_record_for_every_exit(setup, draft_setup, mode):
+    """In every step mode, through the one helper: a request that
+    finishes, expires in a row, is shed in the queue, is given back or is
+    left behind leaves exactly one record with its stamps in order;
+    ``Completion.queue_s`` and the trace's ``batcher.queue`` span are the
+    record's; ``run(iterable)`` stamps the submission at the pull."""
+    import collections
+    import time as _time
+
+    from tfmesos_tpu.fleet.tracing import TraceContext
+    from tfmesos_tpu.serving import Expired, Prefilled, Suspended
+
+    cfg, params = setup
+    extra = TICK_MODES[mode][0]
+    kw = dict(rows=2, max_len=64, page_size=16, prefill_bucket=16)
+    if extra == "spec":
+        dcfg, dparams = draft_setup
+        kw.update(draft_cfg=dcfg, draft_params=dparams, n_draft=3)
+    elif extra == "session":
+        kw.update(kv_tier=_tier(), max_len=128)
+    else:
+        kw.update(extra)
+    batcher = ContinuousBatcher(cfg, params, **kw)
+    pre = ContinuousBatcher(cfg, params, **kw) if mode == "import" else None
+    prompts = _prompts(cfg, 12, seed=31)
+
+    def wrap(req):
+        return Prefilled(req, pre.export_kv(req)) if pre else req
+
+    # -- run(iterable): completions, stamped at the pull -------------------
+    reqs, pulled = [], {}
+    for p in prompts[:4]:
+        r = Request(prompt=p, max_new_tokens=5)
+        r.trace = TraceContext(detailed=True)
+        reqs.append(r)
+    if mode == "session":       # the second turn resumes the first
+        (c,) = batcher.run([Request(prompts[0], 5, session_id="conv")])
+        turn = Request(np.asarray(list(prompts[0]) + list(c.tokens)
+                                  + [5, 9, 3], np.int32), 5,
+                       session_id="conv")
+        turn.trace = TraceContext(detailed=True)
+        reqs = [turn] + reqs[1:]
+
+    def source():
+        for r in reqs:
+            pulled[id(r)] = _time.perf_counter()
+            yield wrap(r)
+
+    before = len(batcher.requests.snapshot())
+    done = list(batcher.run(source()))
+    recs = batcher.requests.snapshot()[before:]
+    assert len(done) == len(recs) == len(reqs)
+    assert {r["status"] for r in recs} == {"completed"}
+    by_rid = {r["rid"]: r for r in recs}
+    assert len(by_rid) == len(recs)
+    for c in done:
+        rec = by_rid[c.rid]
+        assert rec["t_submit"] >= pulled[id(c.request)]
+        assert c.queue_s == rec["t_admit"] - rec["t_submit"] >= 0.0
+        assert c.ttft_s == rec["t_first"] - rec["t_admit"]
+        assert rec["out_tokens"] == len(c.tokens)
+        assert rec["prompt_tokens"] == c.request.prompt.size
+        spans = [s for s in c.request.trace.export()
+                 if s["name"] in ("queue", "prefill", "decode")]
+        assert [s["name"] for s in spans] == ["queue", "prefill", "decode"]
+        assert spans[0]["component"] == "batcher"
+        assert spans[0]["dur"] == pytest.approx(c.queue_s * 1e3, abs=1e-3)
+        assert spans[0]["t0"] + spans[0]["dur"] == pytest.approx(
+            spans[1]["t0"], abs=2e-3)
+    # the padded width dispatched: nothing for an import, the new turn's
+    # tail for a session's resume, the whole prompt otherwise
+    bucket = (pre or batcher).prefill_bucket
+    width = lambda n: -(-n // bucket) * bucket
+    for c in done:
+        rec, n = by_rid[c.rid], int(c.request.prompt.size)
+        if mode == "import":
+            assert rec["prefill_tokens"] == 0
+        elif c.request.session_id:
+            assert 0 < rec["prefill_tokens"] < width(n)
+        else:
+            assert rec["prefill_tokens"] >= width(n)
+    if pre is not None:         # an export is its batcher's to record
+        exported = pre.requests.snapshot()
+        assert len(exported) == len(reqs)
+        assert {(r["status"], r["admit_tick"], r["done_tick"])
+                for r in exported} == {("suspended", -1, -1)}
+        assert all(r["t_first"] >= r["t_admit"] == r["t_submit"]
+                   and r["prefill_tokens"] >= width(r["prompt_tokens"])
+                   for r in exported)
+
+    # -- serve(): shed, completed, expired, suspended ----------------------
+    before = len(batcher.requests.snapshot())
+    shed = Request(prompts[4], 4, deadline_ms=0.001)
+    short = Request(prompts[5], 2)
+    doomed = Request(prompts[6], 40, deadline_ms=3.6e6)
+    rest = [Request(p, 40) for p in prompts[7:10]]
+    for r in [shed, short, doomed] + rest:
+        item = wrap(r)
+        if pre:
+            batcher.submit(item.request, prefilled=item.artifact)
+        else:
+            batcher.submit(r)
+    t_submitted = _time.perf_counter()
+    got = collections.Counter()
+    it = batcher.serve()
+    for item in it:
+        got[type(item).__name__, item.request is shed] += 1
+        if isinstance(item, Completion):
+            assert item.request is short
+            doomed.deadline = 0.0           # passed, while it holds a row
+        elif isinstance(item, Expired) and item.request is doomed:
+            batcher.preempt_all()           # two resident, one queued
+        elif isinstance(item, Suspended):
+            batcher.close()
+    assert got == {("Expired", True): 1, ("Completion", False): 1,
+                   ("Expired", False): 1, ("Suspended", False): 3}
+    recs = batcher.requests.snapshot()[before:]
+    assert collections.Counter(r["status"] for r in recs) == {
+        "shed": 1, "completed": 1, "expired": 1, "suspended": 3}
+    for r in recs:
+        assert r["t_submit"] <= t_submitted     # SubmissionQueue.submit's
+        if r["status"] == "shed":
+            assert r["t_admit"] is None and r["t_first"] is None
+        if r["status"] in ("completed", "expired"):
+            # (a chunked prefill may expire before its first token)
+            assert r["t_admit"] is not None
+            assert r["t_first"] is not None or r["status"] == "expired"
+    queued = [r for r in recs if r["status"] == "suspended"
+              and r["t_admit"] is None]
+    assert len(queued) == 1 and queued[0]["out_tokens"] == 0
+
+    # -- a consumer that stops early leaves the rest behind ----------------
+    before = len(batcher.requests.snapshot())
+    it = batcher.run(wrap(Request(p, 30)) for p in prompts[8:12])
+    it.close()                              # before its first pass: nothing
+    assert batcher.requests.snapshot()[before:] == []
+    it = batcher.run([wrap(Request(prompts[5], 2))]
+                     + [wrap(Request(p, 30)) for p in prompts[8:12]])
+    first = next(it)
+    it.close()
+    recs = batcher.requests.snapshot()[before:]
+    assert recs[0]["status"] == "completed" and recs[0]["rid"] == first.rid
+    # the row beside it, and what the loop had pulled and not admitted
+    assert 1 <= len(recs[1:]) <= 2
+    assert {r["status"] for r in recs[1:]} == {"abandoned"}
+    assert any(r["t_admit"] is not None for r in recs[1:])
+    _check_requests(batcher, batcher.requests.snapshot())
+    # another batcher of the process leaves this one's share alone
+    assert {r["batcher"] for r in batcher.requests.snapshot()} == {
+        batcher.flight.value}
+
+
+def test_tick_counts_a_collection_forced_inside_it(setup):
+    """A collector pause that ends inside a tick shows in that tick's
+    ``gc_ms`` (here a full collection forced from a token callback)."""
+    import gc
+
+    cfg, params = setup
+    batcher = ContinuousBatcher(cfg, params, rows=2, max_len=64,
+                                page_size=16, prefill_bucket=16)
+    fired = []
+
+    def collect(toks, off):
+        if not fired:
+            fired.append(gc.collect())
+
+    req = Request(prompt=_prompts(cfg, 1, seed=3)[0], max_new_tokens=4)
+    req.on_tokens = collect
+    list(batcher.run([req]))
+    recs = batcher.flight.snapshot()
+    hit = [r for r in recs if r["gc_gen2"]]
+    assert fired and len(hit) >= 1
+    assert hit[0]["gc_ms"] > 0.0 and hit[0]["gc_n"] >= hit[0]["gc_gen2"]
+    assert hit[0]["gc_ms"] <= hit[0]["phases"]["batcher.emit"] + 1e-6 \
+        or hit[0]["gc_n"] > 1
+    assert "batcher.emit" in hit[0]["phases"]
+
+
+def test_a_held_tick_makes_one_stall_record_and_one_line(setup, caplog):
+    """A tick held for 0.4 s (a token callback that sleeps) is a stall:
+    the whole tick goes into the stall ring with what it was judged by
+    and ONE warning names its phases and counters; a second stall within
+    the second is recorded and not logged; a run that does not stall logs
+    nothing, and a long admission is no stall."""
+    import logging
+    import time as _time
+
+    from tfmesos_tpu import serving
+    from tfmesos_tpu.fleet.tracing import flight
+
+    cfg, params = setup
+    batcher = ContinuousBatcher(cfg, params, rows=2, max_len=64,
+                                page_size=16, prefill_bucket=16)
+    prompts = _prompts(cfg, 2, seed=23)
+    mk = lambda: [Request(prompt=p, max_new_tokens=12) for p in prompts]
+    caplog.set_level(logging.WARNING, logger="tfmesos_tpu.serving")
+    list(batcher.run(mk()))         # cold: ticks that compile are no stalls
+    list(batcher.run(mk()))         # warm: nothing to say
+    assert batcher.stalls.snapshot() == []
+    assert not [r for r in caplog.records if "stalled" in r.getMessage()]
+
+    naps = []
+
+    def nap(toks, off):
+        if off in (3, 6):           # two ticks, well inside one second
+            naps.append(off)
+            _time.sleep(0.4)
+
+    reqs = mk()
+    reqs[0].on_tokens = nap
+    list(batcher.run(reqs))
+    assert naps == [3, 6]
+    stalls = batcher.stalls.snapshot()
+    assert len(stalls) == 2
+    ticks = {r["tick"]: r for r in batcher.flight.snapshot()}
+    for st in stalls:
+        assert set(st) == TICK_FIELDS | {"held_ms", "median_ms"}
+        assert {k: v for k, v in st.items()
+                if k not in ("held_ms", "median_ms")} == ticks[st["tick"]]
+        assert st["phases"]["batcher.emit"] >= 400.0
+        assert st["held_ms"] >= 400.0 > serving.STALL_MIN_MS
+        assert 0.0 < st["median_ms"] * serving.STALL_FACTOR < st["held_ms"]
+        # the serve thread slept: neither it nor the collector ran; a
+        # tick this long closes with a read of the process's counters
+        assert st["thread_cpu_ms"] < 200.0 and st["gc_ms"] < 200.0
+        assert st["cpu_ms"] >= 0.0 and st["majflt"] >= 0
+        assert st["wall_ms"] <= st["cpu_span_ms"] < st["wall_ms"] + 300.0
+    lines = [r.getMessage() for r in caplog.records
+             if "stalled" in r.getMessage()]
+    assert len(lines) == 1
+    for word in (f"tick {stalls[0]['tick']} ", "kind ", "rows ", "wall_ms ",
+                 "median decode tick", "'emit': ", "gc_ms ",
+                 "thread_cpu_ms ", "ctx_invol ", " cpu_ms ", "majflt ",
+                 "(the process, over ", "ready "):
+        assert word in lines[0], (word, lines[0])
+    # the ring is the process's, found by name with no handle to the batcher
+    assert [r for r in flight(serving.STALL_COMPONENT).snapshot()
+            if r["batcher"] == batcher.flight.value] == stalls
+
+    # honest work is not a stall: the same 0.4 s inside an admission
+    real = batcher._worst_pages
+
+    def slow_worst_pages(req):
+        _time.sleep(0.4)
+        return real(req)
+
+    batcher._worst_pages = slow_worst_pages
+    _time.sleep(1.1)                # a line would be allowed again
+    list(batcher.run(mk()[:1]))
+    slow = [r for r in batcher.flight.snapshot()
+            if r["phases"].get("batcher.admit", 0.0) >= 400.0]
+    assert slow and batcher.stalls.snapshot() == stalls
+    assert len([r for r in caplog.records
+                if "stalled" in r.getMessage()]) == 1

@@ -261,21 +261,16 @@ class FaultPlan:
     def _execute(self, f: Fault, site: str, key: str) -> None:
         # Attribution: every firing lands on the ACTIVE request trace
         # (thread-local — the router activates one around its routing
-        # loop) plus the chaos flight recorder, so a soak anomaly maps
-        # to the exact injected fault instead of "something was slow".
-        # Lazy import: chaos must stay importable without the fleet
-        # package.
+        # loop), so a soak anomaly maps to the exact injected fault
+        # instead of "something was slow"; ``fired`` keeps every firing,
+        # traced or not.  Lazy import: chaos must stay importable
+        # without the fleet package.
         try:
             from tfmesos_tpu.fleet import tracing as _tracing
             attrs = {"site": site, "key": key, "action": f.action}
             if f.action in ("delay", "slow_task"):
                 attrs["delay_s"] = f.delay_s
-            if _tracing.current() is not None:
-                # cur_event copies into the chaos flight recorder too.
-                _tracing.cur_event("chaos", "fault", **attrs)
-            else:
-                _tracing.flight("chaos").record(
-                    dict(attrs, name="fault"))
+            _tracing.cur_event("chaos", "fault", **attrs)
         except Exception:       # tracing must never break injection
             pass
         if f.action == "kill_task":

@@ -761,6 +761,7 @@ def batcher_handler(serving: BatcherServing, generation: int = 0,
             reply(_attach_trace(
                 {"op": "completion", "id": mid,
                  "tokens": [int(t) for t in comp.tokens],
+                 "queue_ms": round(comp.queue_s * 1000.0, 3),
                  "ttft_ms": round(comp.ttft_s * 1000.0, 3),
                  "total_ms": round(comp.total_s * 1000.0, 3)}, tr))
 

@@ -25,8 +25,8 @@ correct at fleet scale:
   hop-locally: spans piggyback when detail was requested, the hop
   failed, or the hop exceeded the threshold the gateway forwarded.
 * **Bounded everything.**  Spans per trace, traces per book, and every
-  per-component :class:`FlightRecorder` ring buffer are capped — a
-  30-day soak holds the same memory as a 30-second one.
+  :class:`FlightRecorder` ring buffer are capped — a 30-day soak holds
+  the same memory as a 30-second one.
 
 The ``current trace`` is thread-local (:func:`activate`): the router
 activates a request's trace around its routing loop so deep helpers —
@@ -50,7 +50,7 @@ from typing import Any, Dict, List, Optional
 
 __all__ = ["FlightRecorder", "FlightView", "TraceContext", "TraceBook",
            "new_trace_id", "activate", "current", "cur_event",
-           "cur_elapsed", "cur_span", "flight", "format_waterfall"]
+           "cur_elapsed", "flight", "format_waterfall"]
 
 
 def new_trace_id() -> str:
@@ -60,10 +60,13 @@ def new_trace_id() -> str:
 
 
 class FlightRecorder:
-    """A bounded, lock-cheap ring buffer of recent span/event dicts —
-    one per component, so "what did the batcher just do" survives even
-    when no request-level trace was retained.  Appends are one lock
-    acquire and one deque append; the ring drops oldest-first."""
+    """A bounded, lock-cheap ring buffer of recent record dicts, so
+    "what did the batcher just do" survives even when no request-level
+    trace was retained: the serve loop's ticks, the requests that left
+    it and the ticks that stalled (``serving.TICK_COMPONENT``,
+    ``REQUEST_COMPONENT``, ``STALL_COMPONENT``; docs/SERVING.md
+    "Observability").  Appends are one lock acquire and one deque
+    append; the ring drops oldest-first."""
 
     def __init__(self, capacity: int = 256):
         if capacity < 1:
@@ -88,10 +91,6 @@ class FlightRecorder:
     def snapshot(self) -> List[Dict[str, Any]]:
         with self._lock:
             return list(self._ring)
-
-    def clear(self) -> None:
-        with self._lock:
-            self._ring.clear()
 
     def grow(self, capacity: int) -> None:
         with self._lock:
@@ -123,8 +122,8 @@ class FlightView:
                 if e.get(self.key) == self.value]
 
 
-# Process-global per-component recorders: components grab theirs by
-# name (``flight("router")``) so recording never needs plumbing.
+# Process-global recorders: a writer and its readers grab one by name
+# (``flight("batcher.tick")``) so recording never needs plumbing.
 _FLIGHTS: Dict[str, FlightRecorder] = {}
 _FLIGHTS_LOCK = threading.Lock()
 
@@ -193,9 +192,6 @@ class TraceContext:
                 self.dropped += 1
                 return
             self.spans.append(span)
-        # The component's flight recorder sees every span too (with the
-        # trace id, so a recorder entry leads back to its request).
-        flight(component).record(dict(span, trace_id=self.trace_id))
 
     def event(self, component: str, name: str, **attrs: Any) -> None:
         """A zero-duration span at "now"."""
@@ -290,18 +286,10 @@ def cur_event(component: str, name: str, **attrs: Any) -> None:
 
 def cur_elapsed() -> Optional[float]:
     """The current trace's elapsed ms, or None — capture before a call
-    to later :func:`cur_span` its duration."""
+    to give the span that records it (``TraceContext.add``) its
+    start."""
     tr = current()
     return tr.elapsed_ms() if tr is not None else None
-
-
-def cur_span(component: str, name: str, t0_ms: Optional[float],
-             **attrs: Any) -> None:
-    """Close a span opened at :func:`cur_elapsed`'s reading (no-op when
-    either side had no trace)."""
-    tr = current()
-    if tr is not None and t0_ms is not None:
-        tr.add(component, name, t0_ms, tr.elapsed_ms() - t0_ms, **attrs)
 
 
 # -- the gateway's trace store ----------------------------------------------

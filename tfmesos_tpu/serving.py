@@ -33,8 +33,11 @@ construction.
 from __future__ import annotations
 
 import dataclasses
+import gc
 import itertools
+import logging
 import queue as _queue
+import statistics
 import threading
 import time
 from collections import deque
@@ -56,6 +59,14 @@ from tfmesos_tpu.models.transformer import (PageAllocator, TransformerConfig,
                                             rejection_accept, sample_logits)
 from tfmesos_tpu.ops.quant import QTensor
 from tfmesos_tpu.utils.profiling import annotate
+
+try:
+    import resource
+except ImportError:         # no such counts on this platform: they read 0
+    resource = None
+_RUSAGE_THREAD = getattr(resource, "RUSAGE_THREAD", None)
+
+log = logging.getLogger(__name__)
 
 __all__ = ["Request", "Completion", "Suspended", "Expired",
            "ContinuousBatcher", "SubmissionQueue", "Prefilled",
@@ -201,6 +212,17 @@ def compute_bypass_reasons(*, speculative: bool = False,
     return out
 
 
+def _request_of(item) -> "Request":
+    """The request a queued item is or, a ``Prefilled``, carries."""
+    return item.request if isinstance(item, Prefilled) else item
+
+
+def _stamp_submit(item) -> None:
+    """A request enters a batcher's queue: ``Request.t_submit``, from
+    which the request ring counts its wait."""
+    _request_of(item).t_submit = time.perf_counter()
+
+
 class SubmissionQueue:
     """Thread-safe incremental :class:`Request` source for
     :meth:`ContinuousBatcher.run` — the online front door's adapter
@@ -225,6 +247,7 @@ class SubmissionQueue:
         with self._lock:
             if self._closed:
                 raise RuntimeError("submission queue is closed")
+            _stamp_submit(request)
             self._q.put(request)
 
     def close(self) -> None:
@@ -330,6 +353,10 @@ class Request:
         # request.  None (the default) costs one attribute read per
         # block.
         self.on_tokens = None
+        # ``perf_counter`` when the request entered a batcher's queue
+        # (``SubmissionQueue.submit``, or the pull from ``run``'s
+        # iterable): the start of the wait the request ring records.
+        self.t_submit: Optional[float] = None
         self.deadline: Optional[float] = None
         if self.deadline_ms is not None:
             if not self.deadline_ms > 0:
@@ -464,13 +491,15 @@ class Completion:
     (including the stop token when one was emitted), ``rid`` the
     admission-order id the batcher assigned.  ``ttft_s`` is wall time
     from admission (prefill start) to the first token; ``total_s`` to
-    the last."""
+    the last; ``queue_s`` the wait before admission, from submission
+    (neither of the other two includes it)."""
 
     rid: int
     request: Request
     tokens: List[int]
     ttft_s: float = 0.0
     total_s: float = 0.0
+    queue_s: float = 0.0
 
 
 @dataclasses.dataclass
@@ -523,6 +552,14 @@ class _Row:
     worst_draft: int = 0    # ... and the draft pool's, in speculative mode
     t_admit: float = 0.0    # perf_counter at prefill start
     t_first: float = 0.0    # ... at first-token availability
+    # What the request ring records beside the two stamps: the ticks
+    # that admitted the row and read its first token (-1 outside a
+    # serve loop) and the padded prompt width dispatched for it, chunks
+    # summed (a prefix or session hit dispatches less than the prompt,
+    # an import nothing).
+    admit_tick: int = -1
+    first_tick: int = -1
+    prefill_tokens: int = 0
     # Chunked-prefill state (prefill_chunk mode): the padded prompt and
     # how much of it has been written; rows decode only once filled.
     padded: Optional[np.ndarray] = None
@@ -538,6 +575,12 @@ class _Row:
     # tokens have been flushed to the callback so far — the serve loop
     # pushes the [streamed:] suffix once per block.
     streamed: int = 0
+
+    def __post_init__(self):
+        # the request's own stamp; admission itself where nothing queued
+        # it (export_kv)
+        t = self.req.t_submit
+        self.t_submit = self.t_admit if t is None else t
 
 
 @dataclasses.dataclass
@@ -1241,10 +1284,34 @@ def _copy_page(pool, src, dst):
 TICK_COMPONENT = "batcher.tick"
 TICK_RING = 16384
 
+#: The request ring: one record per request, written once, when it
+#: leaves a batcher (``_request_done``).  4096 records: the longest
+#: backlog any cell submits is 2048.
+REQUEST_COMPONENT = "batcher.request"
+REQUEST_RING = 4096
+#: The stall ring: the ticks ``_tick_roll`` called stalls, whole, where
+#: the tick ring's turnover cannot flush them.
+STALL_COMPONENT = "batcher.stall"
+STALL_RING = 64
+#: A tick is a stall where the time nothing accounts for (``held_ms``)
+#: is over both STALL_FACTOR x the running median wall time of decode
+#: ticks (the last STALL_WINDOW of them) and STALL_MIN_MS.
+STALL_FACTOR = 8.0
+STALL_MIN_MS = 250.0
+STALL_WINDOW = 64
+#: Seconds between two reads of the process-wide counters: the stall
+#: rule's floor, so that a tick long enough to be a stall always closes
+#: with a read, over itself and under a quarter second before it.
+PROCESS_SAMPLE_S = STALL_MIN_MS / 1e3
+
 _BATCHER_IDS = itertools.count()
 #: backend compiles that finished in this process, and their seconds: a
 #: tick record carries the difference across the tick (``compiles``)
 _COMPILES = [0, 0.0]
+#: collector pauses that ended in this process, on any thread (a pause
+#: holds the interpreter lock whoever took it): their ms, their count,
+#: the full collections among them, and the start of the one running
+_GC = [0.0, 0, 0, 0.0]
 
 
 def _count_compile(event: str, duration: float, **_kw) -> None:
@@ -1255,7 +1322,43 @@ def _count_compile(event: str, duration: float, **_kw) -> None:
         _COMPILES[1] += duration
 
 
+def _count_gc(phase: str, info: Dict[str, Any]) -> None:
+    # runs only when the collector does, under the interpreter lock
+    if phase == "start":
+        _GC[3] = time.perf_counter()
+    else:
+        _GC[0] += (time.perf_counter() - _GC[3]) * 1e3
+        _GC[1] += 1
+        _GC[2] += info["generation"] == 2
+
+
 jax.monitoring.register_event_duration_secs_listener(_count_compile)
+gc.callbacks.append(_count_gc)
+
+
+def _tick_counters() -> tuple:
+    """What a tick record carries the difference of, read once at every
+    roll on the serve thread, ~1 us: backend compiles and their seconds,
+    collector pauses (ms, count, full ones), this thread's CPU seconds
+    and its involuntary context switches (0 where the platform does not
+    count them)."""
+    invol = 0
+    if _RUSAGE_THREAD is not None:
+        invol = resource.getrusage(_RUSAGE_THREAD).ru_nivcsw
+    return (_COMPILES[0], _COMPILES[1], _GC[0], _GC[1], _GC[2],
+            time.thread_time(), invol)
+
+
+def _process_counters() -> tuple:
+    """CPU seconds and major page faults of the whole process.  The
+    kernel walks every thread for either (the runtime keeps hundreds,
+    and reading a running one takes its run queue's lock), so a roll
+    reads them only where PROCESS_SAMPLE_S have passed since it last
+    did."""
+    majflt = 0
+    if resource is not None:
+        majflt = resource.getrusage(resource.RUSAGE_SELF).ru_majflt
+    return time.process_time(), majflt
 
 
 class _Phase:
@@ -1794,12 +1897,26 @@ class ContinuousBatcher:
         # pass of the serve loop leaves one record, written by
         # _tick_roll, in the process-global tick ring; ``flight`` is
         # this batcher's share of it (records stamped with its id).
+        bid = next(_BATCHER_IDS)
         self.flight = FlightView(flight(TICK_COMPONENT, TICK_RING),
-                                 "batcher", next(_BATCHER_IDS))
+                                 "batcher", bid)
+        #: this batcher's share of the request ring and of the stall
+        #: ring (_request_done, _tick_roll)
+        self.requests = FlightView(flight(REQUEST_COMPONENT, REQUEST_RING),
+                                   "batcher", bid)
+        self.stalls = FlightView(flight(STALL_COMPONENT, STALL_RING),
+                                 "batcher", bid)
         self._mode = ("spec" if draft_cfg is not None
                       else "pipelined" if self._pipelined else "sync")
         self._tick_n = 0
-        self._tick_c0 = (0, 0.0)
+        self._tick_c0 = _tick_counters()
+        # the last read of the process-wide counters, and when
+        self._proc_c0 = (0.0, 0)
+        self._proc_t = 0.0
+        # the stall rule: the last decode ticks' wall time (its median
+        # is taken when a tick is long enough to ask), the last line
+        self._decode_walls: deque = deque(maxlen=STALL_WINDOW)
+        self._stall_logged = 0.0
         self._tick = self._tick_open(None)
         if prefix_np is not None:
             self._init_prefix(prefix_np)
@@ -1860,12 +1977,21 @@ class ContinuousBatcher:
         recorded."""
         if t is not None:
             self._tick_n += 1
-            self._tick_c0 = tuple(_COMPILES)
         rec = {"name": "tick", "tick": self._tick_n if t is not None else -1,
                "t": t, "wall_ms": 0.0, "kind": "idle", "mode": self._mode,
                "k": 0, "rows": 0, "dur": 0.0, "admitted": 0,
                "prefill_tokens": 0, "phases": {}, "idle_ms": 0.0,
-               "compiles": 0, "compile_s": 0.0}
+               "compiles": 0, "compile_s": 0.0,
+               # what held the tick (docs/SERVING.md "Observability"):
+               # from _tick_counters, over the tick
+               "gc_ms": 0.0, "gc_n": 0, "gc_gen2": 0, "thread_cpu_ms": 0.0,
+               "ctx_invol": 0,
+               # from _process_counters, over the ``cpu_span_ms`` that end
+               # with the tick, where the tick closed with a read of them
+               "cpu_ms": None, "majflt": None, "cpu_span_ms": None,
+               # the pipelined loop: had the lagged block finished on the
+               # device before the serve thread asked for it
+               "ready": None}
         if self._eva_roll is not None:
             # windows closed in this tick; entries live at its end
             rec.update(eva_rolls=0, eva_summary_entries=0,
@@ -1887,14 +2013,18 @@ class ContinuousBatcher:
         tick's ``wall_ms`` runs to the next one's start, the consumer's
         time at a ``yield`` included."""
         now = time.perf_counter()
+        c1 = _tick_counters()
         t = self._tick
         if t["t"] is not None:
             ph = t["phases"]
+            c0 = self._tick_c0
             t["wall_ms"] = (now - t["t"]) * 1e3
             t["dur"] = round(ph.get("batcher.dispatch", 0.0)
                              + ph.get("batcher.readback", 0.0), 3)
-            t["compiles"] = _COMPILES[0] - self._tick_c0[0]
-            t["compile_s"] = _COMPILES[1] - self._tick_c0[1]
+            (t["compiles"], t["compile_s"], t["gc_ms"], t["gc_n"],
+             t["gc_gen2"], thread_s,
+             t["ctx_invol"]) = (b - a for a, b in zip(c0, c1))
+            t["thread_cpu_ms"] = thread_s * 1e3
             if self._eva_roll is not None:
                 (t["eva_summary_entries"], t["eva_window_entries"],
                  t["eva_pages"]) = self._eva_live
@@ -1906,8 +2036,60 @@ class ContinuousBatcher:
                          else "mixed" if block and fill
                          else "decode" if block
                          else "prefill" if fill else "idle")
+            if now - self._proc_t >= PROCESS_SAMPLE_S:
+                t["cpu_span_ms"] = (now - self._proc_t) * 1e3
+                p0, p1 = self._proc_c0, self._tick_process(now)
+                t["cpu_ms"] = (p1[0] - p0[0]) * 1e3
+                t["majflt"] = p1[1] - p0[1]
             self.flight.record(t)
+            self._tick_stall(t, now)
+        else:
+            self._tick_process(now)     # the loop starts: a fresh baseline
+        self._tick_c0 = c1
         self._tick = self._tick_open(now if more else None)
+
+    def _tick_process(self, now: float) -> tuple:
+        """Read the process-wide counters: the next read's baseline."""
+        self._proc_t = now
+        self._proc_c0 = _process_counters()
+        return self._proc_c0
+
+    def _tick_stall(self, t: Dict[str, Any], now: float) -> None:
+        """The stall rule, on the tick just closed.  ``held_ms`` is the
+        tick's wall time less what is honest work or no work at all: the
+        idle pull, and admission with its prefill and the wait for its
+        first token (a 7 k-token prefill is a third of a second).  A tick
+        held for more than STALL_FACTOR x the running median of decode
+        ticks and STALL_MIN_MS, in which nothing compiled (``compiles``
+        names that time), goes whole into the stall ring with the two
+        numbers it was judged by, and at most once a second into the log
+        with the counters that tell the causes apart.  The median is of
+        the decode ticks before this one."""
+        ph = t["phases"]
+        walls = self._decode_walls
+        held = (t["wall_ms"] - t["idle_ms"] - ph.get("batcher.admit", 0.0)
+                - ph.get("batcher.prefill_sync", 0.0))
+        stall = held > STALL_MIN_MS and not t["compiles"]
+        if stall:       # the one comparison a tick that did not stall pays
+            median = statistics.median(walls) if walls else 0.0
+            stall = held > STALL_FACTOR * median
+        if t["kind"] == "decode":
+            walls.append(t["wall_ms"])
+        if not stall:
+            return
+        self.stalls.record(dict(t, held_ms=held, median_ms=median))
+        if now - self._stall_logged < 1.0:
+            return
+        self._stall_logged = now
+        log.warning(
+            "batcher %d tick %d stalled: kind %s rows %d wall_ms %.1f "
+            "(held %.1f, median decode tick %.1f) phases %s gc_ms %.1f "
+            "thread_cpu_ms %.1f ctx_invol %d cpu_ms %.1f majflt %d (the "
+            "process, over %.1f ms) ready %s", t["batcher"], t["tick"],
+            t["kind"], t["rows"], t["wall_ms"], held, median,
+            {k.split(".", 1)[1]: round(v, 1) for k, v in ph.items()
+             if v > 1.0}, t["gc_ms"], t["thread_cpu_ms"], t["ctx_invol"],
+            t["cpu_ms"], t["majflt"], t["cpu_span_ms"], t["ready"])
 
     def _phase(self, name: str, **stats) -> _Phase:
         """Open one phase of the current tick (``with``): flat siblings
@@ -1933,6 +2115,11 @@ class ContinuousBatcher:
         t = self._tick
         t["name"], t["mode"], t["rows"], t["k"] = "decode.block", mode, \
             rows, k
+
+    def _stamp_first(self, state: _Row) -> None:
+        """``state``'s first token has reached the host."""
+        state.t_first = time.perf_counter()
+        state.first_tick = self._tick["tick"]
 
     @property
     def prefix_cache_active(self) -> bool:
@@ -3228,7 +3415,7 @@ class ContinuousBatcher:
                 state = active[row]
                 if res is not None:
                     _, st, tok, s = res
-                    st.t_first = time.perf_counter()
+                    self._stamp_first(st)
                     first = int(np.asarray(tok)[s])
                     st.last = first
                     st.out = [first]
@@ -3239,7 +3426,9 @@ class ContinuousBatcher:
                     while not state.decoding:
                         if self._advance_prefill(active) is not None:
                             break
-                return self._export_row(row, state)
+                art = self._export_row(row, state)
+                self._request_done("suspended", request, state)
+                return art
             finally:
                 # Unconditional: a failed dispatch may have allocated
                 # pages before raising, and _finish releases safely
@@ -3568,7 +3757,8 @@ class ContinuousBatcher:
         state = _Row(rid=int(art["rid"]), req=req, pos=int(art["pos"]),
                      step=step, last=(toks[-1] if resumed else 0),
                      out=(list(toks) if resumed else []), worst_pages=wt,
-                     worst_draft=wd, t_admit=t_admit, limit=need)
+                     worst_draft=wd, t_admit=t_admit,
+                     admit_tick=self._tick["tick"], limit=need)
         active[row] = state
         self._pcache_insert(row, state)
         return row, state, np.asarray([int(art["first_token"])]), 0
@@ -3991,6 +4181,7 @@ class ContinuousBatcher:
         tok.copy_to_host_async()    # transfer overlaps later dispatches
         state = _Row(rid=rid, req=req, pos=E, step=1, last=0, out=[],
                      worst_pages=wt, worst_draft=wd, t_admit=t_admit,
+                     admit_tick=self._tick["tick"], prefill_tokens=w,
                      limit=need)
         active[row] = state
         self._pcache_insert(row, state)
@@ -4097,8 +4288,7 @@ class ContinuousBatcher:
         bad_request: Optional[Exception] = None
 
         def rank_of(item):
-            return (item.request if isinstance(item, Prefilled)
-                    else item).priority
+            return _request_of(item).priority
 
         def rank_insert(item):
             # Class-aware admission order (the batcher-side twin of the
@@ -4145,6 +4335,8 @@ class ContinuousBatcher:
                         pending.append(next(source))
                     except StopIteration:
                         exhausted = True
+                    else:
+                        _stamp_submit(pending[-1])
             if block:
                 self._tick["idle_ms"] += ph.ms
 
@@ -4194,6 +4386,7 @@ class ContinuousBatcher:
                         self.deadline_cancels += 1
                         self._trace_event(pre.request, "deadline_cancel",
                                           where="parked")
+                        self._request_done("expired", pre)
                         yield Expired(rid=int(pre.artifact.get("rid",
                                                                -1)),
                                       request=pre.request)
@@ -4260,6 +4453,7 @@ class ContinuousBatcher:
                         self.deadline_cancels += 1
                         self._trace_event(req0, "deadline_cancel",
                                           where="queued")
+                        self._request_done("shed", item)
                         yield Expired(
                             rid=(int(item.artifact.get("rid", -1))
                                  if imported else -1),
@@ -4404,8 +4598,12 @@ class ContinuousBatcher:
             # and its device carry).
             self._inflight = None
             self._pipe_carry = self._pipe_host = None
+            # Whatever the consumer left behind leaves the batcher here.
+            for item in itertools.chain(self._parked, pending):
+                self._request_done("abandoned", item)
             self._parked.clear()    # pages already released at suspend
-            for row in list(active):
+            for row, state in list(active.items()):
+                self._request_done("abandoned", state.req, state)
                 self._finish(row, active, free_rows)
             self._tick_roll(more=False)
             # Dropped only after the rows are released, so an export
@@ -4512,7 +4710,8 @@ class ContinuousBatcher:
             padded[0, :length] = req.prompt
             state = _Row(rid=rid, req=req, pos=self.prefix_len + length,
                          step=1, last=0, out=[], worst_pages=wt,
-                         worst_draft=wd, t_admit=t_admit, padded=padded,
+                         worst_draft=wd, t_admit=t_admit,
+                         admit_tick=tick["tick"], padded=padded,
                          filled=(0 if plan is None
                                  else plan.tail_start - self.prefix_len),
                          decoding=False, limit=need)
@@ -4531,7 +4730,9 @@ class ContinuousBatcher:
             wt = -(-self.cfg.cache_entries_peak(int(length), need)
                    // self.page_size)
             state = _Row(rid=rid, req=req, pos=int(length), step=1, last=0,
-                         out=[], worst_pages=wt, t_admit=t_admit, limit=need)
+                         out=[], worst_pages=wt, t_admit=t_admit,
+                         admit_tick=tick["tick"], prefill_tokens=width,
+                         limit=need)
             active[row] = state
             self._eva_account(active)
             return row, state, tok, 0
@@ -4557,7 +4758,8 @@ class ContinuousBatcher:
         tok.copy_to_host_async()    # transfer overlaps later dispatches
         state = _Row(rid=rid, req=req, pos=self.prefix_len + length, step=1,
                      last=0, out=[], worst_pages=wt, worst_draft=wd,
-                     t_admit=t_admit, limit=need)
+                     t_admit=t_admit, admit_tick=tick["tick"],
+                     prefill_tokens=width, limit=need)
         active[row] = state
         self._pcache_insert(row, state)
         return row, state, tok, s
@@ -4656,6 +4858,7 @@ class ContinuousBatcher:
         tok.copy_to_host_async()    # transfer overlaps later dispatches
         state = _Row(rid=rid, req=req, pos=E, step=1, last=0, out=[],
                      worst_pages=wt, worst_draft=wd, t_admit=t_admit,
+                     admit_tick=self._tick["tick"], prefill_tokens=w,
                      limit=need)
         active[row] = state
         self._pcache_insert(row, state)
@@ -4675,7 +4878,7 @@ class ContinuousBatcher:
                         tok: int) -> Optional[Completion]:
         """Record a burst-synced first token; Completion when it already
         finishes the request."""
-        state.t_first = time.perf_counter()
+        self._stamp_first(state)
         if state.out:
             # Resumed suspended import: the stream up to the suspension
             # point is already in place (and a finished row is never
@@ -4719,6 +4922,7 @@ class ContinuousBatcher:
             row = active[r]
             c = self.prefill_chunk
             self._tick["prefill_tokens"] += c
+            row.prefill_tokens += c
             chunk = row.padded[:, row.filled:row.filled + c]
             length = row.req.prompt.size
             cap = length - 1 - row.filled   # in-range only on last chunk
@@ -4744,7 +4948,7 @@ class ContinuousBatcher:
         with self._phase("batcher.prefill_sync"):
             tok = int(np.asarray(tok)[s])   # the capture chunk's sample
         with self._phase("batcher.retire"):
-            row.t_first = time.perf_counter()
+            self._stamp_first(row)
             row.last = tok
             row.out.append(tok)
             row.decoding = True
@@ -4826,10 +5030,11 @@ class ContinuousBatcher:
             for i, r in enumerate(picks):
                 row = active[r]
                 row.filled += c
+                row.prefill_tokens += c
                 if row.filled < row.padded.shape[1]:
                     continue
                 tok = int(first[i])     # the capture chunk's sample
-                row.t_first = time.perf_counter()
+                self._stamp_first(row)
                 row.last = tok
                 row.out.append(tok)
                 row.decoding = True
@@ -5016,6 +5221,12 @@ class ContinuousBatcher:
         nxt, ticket = inflight
         # The lagged-block sync IS the pipelined loop's per-block wait
         # (dispatch is a non-blocking enqueue).
+        # Had the device finished the lagged block before the host asked
+        # for it?  Ready on arrival, the device was waiting for the host;
+        # not ready, the readback long and every stall counter near 0,
+        # the host was waiting for the device.  (A synchronous loop asks
+        # right after it dispatches: never ready, so it does not ask.)
+        self._tick["ready"] = int(nxt.is_ready())
         with self._phase("batcher.readback"):
             nxt = self._tick_moe(np.asarray(nxt))   # one block behind
         if self._tick["name"] != "decode.block":    # the draining tick
@@ -5154,6 +5365,7 @@ class ContinuousBatcher:
             rid, req = row.rid, row.req
             self._trace_event(req, "deadline_cancel", rid=rid,
                               where="resident", step=row.step)
+            self._request_done("expired", req, row)
             self._finish(r, active, free_rows)
             yield Expired(rid=rid, request=req)
 
@@ -5177,6 +5389,7 @@ class ContinuousBatcher:
         batcher."""
         state = active[r]
         art = self._export_row(r, state)
+        self._request_done("suspended", state.req, state)
         self._finish(r, active, free_rows)
         return art
 
@@ -5222,6 +5435,7 @@ class ContinuousBatcher:
                           priority=req.priority)
         art = self._suspend_row(r, active, free_rows)
         self._parked.append(Prefilled(req, art))
+        _stamp_submit(req)      # its second wait starts here
         self.preemptions += 1
         return True
 
@@ -5255,14 +5469,12 @@ class ContinuousBatcher:
             rid = state.rid
             self._trace_event(req, "suspend", rid=rid,
                               exported=art is not None)
+            self._request_done("suspended", req, state)
             self._finish(r, active, free_rows)
             yield Suspended(rid=rid, request=req, artifact=art)
-        while self._parked:
-            pre = self._parked.popleft()
-            yield Suspended(rid=int(pre.artifact.get("rid", -1)),
-                            request=pre.request, artifact=pre.artifact)
-        while pending:
-            item = pending.popleft()
+        while self._parked or pending:
+            item = (self._parked or pending).popleft()
+            self._request_done("suspended", item)
             if isinstance(item, Prefilled):
                 yield Suspended(rid=int(item.artifact.get("rid", -1)),
                                 request=item.request,
@@ -5279,23 +5491,60 @@ class ContinuousBatcher:
         if tr is not None:
             tr.event("batcher", name, **attrs)
 
+    def _request_done(self, status: str, item, row: Optional[_Row] = None,
+                      now: Optional[float] = None) -> None:
+        """The one write of the request ring: ``item`` (a request, or the
+        ``Prefilled`` that carries it) leaves this batcher, as ``status``
+        says: ``completed``, ``expired`` (its deadline passed while it
+        held or had held a row), ``shed`` (it passed in the queue),
+        ``suspended`` (given back: preempted, drained, exported) or
+        ``abandoned`` (the consumer closed the loop).  ``row`` is its
+        row's state where it held one; without one the admit and first
+        stamps and their ticks are None.  Every time is a
+        ``perf_counter`` reading, the clock of the tick ring's ``t``; a
+        tick is the one whose pass of the loop made the edge, -1 outside
+        a loop (docs/SERVING.md "Observability")."""
+        req = _request_of(item)
+        now = time.perf_counter() if now is None else now
+        rec = {"name": "request", "status": status,
+               "rid": (-1 if item is req
+                       else int(item.artifact.get("rid", -1))),
+               "t_submit": now if req.t_submit is None else req.t_submit,
+               "t_admit": None, "t_first": None, "t_done": now,
+               "prompt_tokens": int(req.prompt.size), "prefill_tokens": 0,
+               "out_tokens": 0, "admit_tick": None, "first_tick": None,
+               "done_tick": self._tick["tick"]}
+        if row is not None:
+            rec.update(rid=row.rid, t_submit=row.t_submit,
+                       t_admit=row.t_admit, admit_tick=row.admit_tick,
+                       prefill_tokens=row.prefill_tokens,
+                       out_tokens=len(row.out))
+            if row.t_first > 0:
+                rec.update(t_first=row.t_first, first_tick=row.first_tick)
+        self.requests.record(rec)
+
     def _completion(self, row: _Row) -> Completion:
         now = time.perf_counter()
         tr = getattr(row.req, "trace", None)
         if tr is not None:
-            # The two phase spans every waterfall wants: admission ->
-            # first token (prefill + queue-for-burst) and first token
-            # -> finish (decode), from the row's own perf_counter
-            # stamps — hop-local by construction.
+            # The three phase spans every waterfall wants: submission ->
+            # admission (this batcher's own queue), admission -> first
+            # token (prefill + queue-for-burst) and first token ->
+            # finish (decode), from the row's own perf_counter stamps —
+            # hop-local by construction.
+            tr.span_between("batcher", "queue", row.t_submit, row.t_admit,
+                            rid=row.rid)
             tr.span_between("batcher", "prefill", row.t_admit,
                             max(row.t_first, row.t_admit), rid=row.rid)
             tr.span_between("batcher", "decode",
                             max(row.t_first, row.t_admit), now,
                             rid=row.rid, tokens=len(row.out))
+        self._request_done("completed", row.req, row, now=now)
         return Completion(rid=row.rid, request=row.req,
                           tokens=list(row.out),
                           ttft_s=row.t_first - row.t_admit,
-                          total_s=now - row.t_admit)
+                          total_s=now - row.t_admit,
+                          queue_s=row.t_admit - row.t_submit)
 
     def _finish(self, row: int, active: Dict[int, _Row],
                 free_rows: List[int]) -> None:
