@@ -5,6 +5,8 @@
 reference's full forward pass in LOGITS, wherever a prompt or a decode ends
 relative to a chunk and a window; the pool holds ``E(T)`` entries per row,
 and what EVA's pages cannot do yet is refused with a registered reason.
+The lagged carry, which an EVA stack takes of itself, is held in
+``tests/test_eva_carry.py``.
 
 Small size, seeded random weights, float32, CPU: hidden 64, 4 heads of 16,
 chunks of 4, windows of 32 (8 summaries a window = one page of 8), 3 layers,
@@ -40,13 +42,11 @@ MODEL = {"hidden_size": 64, "num_attention_heads": 4,
 TOL = 1e-4
 
 
-@pytest.fixture(scope="module")
-def cfg():
+def make_cfg():
     return evabyte.program_config(MODEL, 256)
 
 
-@pytest.fixture(scope="module")
-def params(cfg):
+def make_params(cfg):
     p = T.init_params(cfg, jax.random.PRNGKey(7))
     # gains away from the identity, so that a forgotten unit offset shows
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(8), 3)
@@ -56,6 +56,16 @@ def params(cfg):
         k2, p["layers"]["mlp_norm"].shape)
     p["norm_f"] = 0.2 * jax.random.normal(k3, p["norm_f"].shape)
     return p
+
+
+@pytest.fixture(scope="module")
+def cfg():
+    return make_cfg()
+
+
+@pytest.fixture(scope="module")
+def params(cfg):
+    return make_params(cfg)
 
 
 def batcher(cfg, params, **kw):
@@ -176,7 +186,15 @@ def test_entries_held_follow_E_of_T_at_every_step(cfg, params, multi_step):
     holds exactly E(T) entries, its pages are ceil(E(T) / page), the tick
     ring's counters say the same, and the adapter's ``cache_entries``
     agrees with the program's."""
-    b = batcher(cfg, params, rows=1, multi_step=multi_step)
+    check_entries_follow_E_of_T(cfg, params, multi_step, lag=0)
+
+
+def check_entries_follow_E_of_T(cfg, params, multi_step, lag):
+    """``lag``: ``pipeline_depth`` (tests/test_eva_carry.py runs the same
+    check under the carry, where the host's view is the dispatched one)."""
+    b = batcher(cfg, params, rows=1, multi_step=multi_step,
+                pipeline_depth=lag)
+    assert b._pipelined == (lag is None)
     seen = []
     account = b._eva_account
 
@@ -260,7 +278,9 @@ def test_what_eva_pages_cannot_do_is_refused_with_a_registered_reason(
     for reg in ("prefix_cache", "kv_tier", "suspend", "speculative",
                 "kv_export"):
         assert reason in BYPASS_ALLOWLIST[reg]
-    assert "eva window close" in BYPASS_ALLOWLIST["pipeline"]
+    # the lagged carry is NOT among them: a close hangs on a position,
+    # and positions advance at dispatch
+    assert BYPASS_ALLOWLIST["pipeline"] == ("speculative decoding",)
     with pytest.raises(ValueError, match=f"speculative.*{reason}"):
         batcher(cfg, params, draft_cfg=cfg, draft_params=params)
     from tfmesos_tpu.fleet.kvtier import KVTierStore
@@ -269,8 +289,7 @@ def test_what_eva_pages_cannot_do_is_refused_with_a_registered_reason(
     assert b.prefix_cache_bypass_reason == reason and b._pcache is None
     assert b.kv_tier_bypass_reason == reason and not b._tier_active
     assert b.suspend_bypass_reason == reason and not b.preemptible
-    assert b.pipeline_bypass_reason == "eva window close"
-    assert not b._pipelined
+    assert b.pipeline_bypass_reason is None and b._pipelined
     # sessions park through the tier: a labeled request is served cold
     req = Request(prompt=prompt_of(40), max_new_tokens=3, session_id="s")
     done, seen = run_logged(b, [req])
@@ -299,12 +318,23 @@ def test_what_eva_pages_cannot_do_is_refused_with_a_registered_reason(
 
 
 def test_warmup_compiles_what_the_loop_dispatches(cfg, params):
-    b = batcher(cfg, params, multi_step=4)
+    check_warmup_compiles_what_the_loop_dispatches(cfg, params, lag=0)
+
+
+def check_warmup_compiles_what_the_loop_dispatches(cfg, params, lag):
+    """Either loop (``lag``: ``pipeline_depth``): K = 4 blocks, the single
+    steps before a window's end (``_decode1``), every prefill width up to a
+    window and the close."""
+    b = batcher(cfg, params, multi_step=4, pipeline_depth=lag)
     names = b.warmup()["compiled"]
     assert "eva_roll" in names and "prefill[32]" in names
     assert "prefill[40]" not in names           # a window, then a tail
-    list(b.run([Request(prompt=prompt_of(75), max_new_tokens=30)]))
-    assert sum(r["compiles"] for r in b.flight.snapshot()) == 0
+    list(b.run([Request(prompt=prompt_of(75), max_new_tokens=30),
+                Request(prompt=prompt_of(9), max_new_tokens=5)]))
+    recs = b.flight.snapshot()
+    assert sum(r["compiles"] for r in recs) == 0
+    assert {r["mode"] for r in recs if r["name"] == "decode.block"} == {
+        "sync" if lag == 0 else "pipelined"}
 
 
 # -- the reference itself ------------------------------------------------------

@@ -647,6 +647,122 @@ def test_pipelined_spec_bypass_reason_and_validation(setup, draft_setup):
                           pipeline_depth=2)
 
 
+def _lag_stack(setup, stack):
+    """(cfg, params, batcher keywords) of a plain stack asked for the
+    carry, or of one of the two kinds that take it of themselves."""
+    if stack == "plain":
+        return (*setup, dict(pipeline_depth=1, page_size=16,
+                             prefill_bucket=16))
+    if stack == "eva":
+        cfg = transformer.TransformerConfig(
+            vocab_size=97, d_model=32, n_layers=2, n_heads=4, d_ff=64,
+            max_seq_len=128, dtype=jnp.float32, attention="eva",
+            eva_chunk=4, eva_window=32)
+        kw = dict(pipeline_depth=None, page_size=8, prefill_bucket=8)
+    else:
+        cfg = transformer.TransformerConfig(
+            vocab_size=97, d_model=32, n_layers=2, n_heads=4, n_kv_heads=2,
+            d_ff=32, max_seq_len=128, dtype=jnp.float32,
+            layer_types=("mamba", "attention"), mamba_heads=4,
+            mamba_head_dim=16, mamba_state=16, mamba_chunk=16, rope=False)
+        kw = dict(pipeline_depth=None, page_size=16, prefill_bucket=16)
+    return cfg, transformer.init_params(cfg, jax.random.PRNGKey(3)), kw
+
+
+@pytest.mark.parametrize("stack", ["plain", "eva", "typed"])
+def test_rows_outside_the_dispatch_do_not_ride_the_carry(setup, stack):
+    """A row outside a pipelined block's dispatch (free, or parked: its
+    quota dispatched, its last block not yet retired) enters the program
+    from host zeros (``use_host`` set; token, position and step 0), as the
+    synchronous loop's idle rows do.  Left on the device carry its position
+    grew by K every block, up to ``max_len``, and the paged kernel walked a
+    context of that length over sink pages for it in every layer of every
+    block.  The program's arguments are spied on; the streams are the
+    synchronous loop's."""
+    cfg, params, kw = _lag_stack(setup, stack)
+    kw = dict(kw, rows=4, max_len=96)
+    rng = np.random.RandomState(41)
+    # six requests through four rows, quotas that end in different blocks:
+    # rows park one at a time, two are taken again at once, and at the tail
+    # rows stay free while the others decode on
+    plan = ((9, 4), (40, 14), (21, 9), (5, 6), (33, 12), (12, 20))
+    prompts = [rng.randint(0, cfg.vocab_size, size=n).astype(np.int32)
+               for n, _ in plan]
+    mk = lambda: [Request(prompt=p, max_new_tokens=m)
+                  for p, (_, m) in zip(prompts, plan)]
+    b = ContinuousBatcher(cfg, params, **kw)
+    assert b._pipelined and b.pipeline_bypass_reason is None
+    seen, now = [], {}
+    step, decode = b._step_pipelined, b._decode
+
+    def spy_step(active, free_rows):
+        # the host's view as the block is built: who is parked, who is free
+        now["parked"] = {r for r, row in active.items()
+                         if row.step >= row.req.max_new_tokens}
+        now["free"] = set(range(b.rows)) - set(active)
+        return step(active, free_rows)
+
+    def spy_decode(params_, pool, table, use_host, toks, positions, steps,
+                   carry_tok, carry_pos, carry_steps, rids):
+        seen.append(dict(now, table=np.asarray(table),
+                         use_host=np.asarray(use_host),
+                         toks=np.asarray(toks), pos=np.asarray(positions),
+                         steps=np.asarray(steps),
+                         carry_pos=np.asarray(carry_pos)))
+        return decode(params_, pool, table, use_host, toks, positions,
+                      steps, carry_tok, carry_pos, carry_steps, rids)
+
+    b._step_pipelined, b._decode = spy_step, spy_decode
+    got = {c.rid: list(c.tokens) for c in b.run(mk())}
+    want = {c.rid: list(c.tokens) for c in ContinuousBatcher(
+        cfg, params, **dict(kw, pipeline_depth=0)).run(mk())}
+    assert got == want and len(got) == len(plan)
+    outside = 0
+    for rec in seen:
+        out = rec["parked"] | rec["free"]
+        for r in range(b.rows):
+            sink = bool((rec["table"][r] == b.t_side.sink).all())
+            assert sink == (r in out), (r, rec["parked"], rec["free"])
+            if r in out:
+                outside += 1
+                assert rec["use_host"][r]
+                assert rec["toks"][r] == rec["pos"][r] == rec["steps"][r] == 0
+    assert any(rec["parked"] for rec in seen)
+    assert any(rec["free"] for rec in seen) and outside >= 12
+    # the carry is used: rows that stay in the dispatch ride it
+    assert any((~rec["use_host"]).any() for rec in seen)
+    # and a position on it is a dispatched row's: bounded by its context
+    assert max(int(rec["carry_pos"].max()) for rec in seen) <= 40 + 14
+
+
+@pytest.mark.parametrize("stack", ["plain", "eva", "typed"])
+def test_the_carry_reserves_what_the_synchronous_loop_reserves(setup, stack):
+    """A stop token costs the carry no position: the block dispatched
+    after a stop the host has not read yet is one the quota had let
+    through, and writes where the same request without a stop writes.  So
+    a request that fills ``max_len`` exactly is admitted by both loops
+    under the same reservation (the carry used to ask for one position
+    more and refuse it), and stops where it stops."""
+    cfg, params, kw = _lag_stack(setup, stack)
+    kw = dict(kw, rows=2, max_len=64)
+    prompt = np.random.RandomState(7).randint(
+        0, cfg.vocab_size, size=40).astype(np.int32)
+    sync = ContinuousBatcher(cfg, params, **dict(kw, pipeline_depth=0))
+    (probe,) = sync.run([Request(prompt=prompt, max_new_tokens=25)])
+    at = next(i for i in range(3, 25)
+              if probe.tokens[i] not in probe.tokens[:i])
+    mk = lambda: Request(prompt=prompt, max_new_tokens=25,
+                         stop_token=int(probe.tokens[at]))
+    lag = ContinuousBatcher(cfg, params, **kw)
+    assert lag._pipelined
+    assert lag._worst_pages(mk()) == sync._worst_pages(mk())
+    assert lag._worst_pages(mk())[2] == 64 == lag.max_len
+    (want,), (got,) = sync.run([mk()]), lag.run([mk()])
+    assert list(got.tokens) == list(want.tokens) == \
+        list(probe.tokens[:at + 1])
+    assert lag.alloc.rows == {} and lag._inflight is None
+
+
 def test_one_lag_policy_overlap_is_gone(setup):
     """``pipeline_depth`` is the batcher's ONE lag policy: the former
     ``overlap=`` argument gets Python's own TypeError (no shim, no
@@ -2642,13 +2758,17 @@ def test_bypass_registry_audit(setup):
             f"bypass (BYPASS_ALLOWLIST is the contract)")
     # EVA attention (pages of summaries and one window, PR 28): every
     # surface that shares, moves or snapshots pages by position is closed
-    # with ONE reason, the lagged loop with another; nothing of it is
-    # reachable without EVA, whose registries read as before below.
+    # with ONE reason; nothing of it is reachable without EVA, whose
+    # registries read as before below.  The lagged carry composes (a
+    # window is closed where its last position is dispatched, PR 41), so
+    # the lag registry gains nothing: a draft model is refused under EVA
+    # at construction, and the helper says what it would have said.
     for reg in ("prefix_cache", "kv_tier", "suspend", "speculative",
                 "kv_export"):
         assert "eva summary pages" in eva_reach[reg], reg
         assert "eva summary pages" not in reachable[reg], reg
-    assert "eva window close" in eva_reach["pipeline"]
+    assert eva_reach["pipeline"] == {"speculative decoding"}
+    assert BYPASS_ALLOWLIST["pipeline"] == ("speculative decoding",)
     # A recurrent row state (a typed stack's mamba layers, PR 32): a row is
     # its pages AND a state no page holds, so the same five surfaces close
     # with ONE reason of their own; the pipelined carry composes (the state
@@ -2664,7 +2784,12 @@ def test_bypass_registry_audit(setup):
     assert compute_bypass_reasons(
         recurrent=True, pipeline_depth=1)["suspend"] == "recurrent row state"
     assert compute_bypass_reasons(
-        eva=True, pipeline_depth=1)["pipeline"] == "eva window close"
+        eva=True, pipeline_depth=1)["pipeline"] is None
+    assert compute_bypass_reasons(
+        eva=True, pipeline_depth=1)["suspend"] == "eva summary pages"
+    # The lag left to the batcher (pipeline_depth=None) reads THIS table:
+    # the carry where the model's own cache has closed suspend already.
+    assert compute_bypass_reasons()["suspend"] is None
     assert reachable["pipeline"] == {"speculative decoding"}
     assert not reachable["speculative"] and not reachable["kv_export"]
     # The burn-down, pinned: spec composes with the prefix cache and

@@ -587,3 +587,53 @@ def test_eva_programs_compile_and_keep_the_pool_in_place(topo, monkeypatch,
     # beside 6.5 GB of weights and 6.2 GB of pool: a window's prefill keeps
     # its temporaries under half a gigabyte (its K/V is committed per layer)
     assert mem.temp_size_in_bytes < 0.5e9, mem.temp_size_in_bytes
+
+
+def test_eva_pipelined_decode_program_compiles_for_v5e(topo, monkeypatch):
+    """The program the EVA stack's batcher dispatches since PR 41 (the
+    lagged carry: ``decode_block_pipelined``, what ``pipeline_depth=None``
+    resolves to under EVA), lowered from a tiny batcher built here on the
+    CPU and compiled for the described v5e with the paged kernel forced:
+    the carry's three vectors come back beside the pool and the tokens, the
+    pool is donated and updated in place."""
+    from tfmesos_tpu.models import transformer
+    from tfmesos_tpu.serving import ContinuousBatcher
+
+    cfg = transformer.TransformerConfig(
+        vocab_size=320, d_model=256, n_layers=2, n_heads=2, n_kv_heads=2,
+        d_ff=512, max_seq_len=2048, rope_theta=1e5, dtype=BF16,
+        param_dtype=BF16, attention="eva", eva_chunk=16, eva_window=1024,
+        norm_eps=1e-5, norm_offset=True, residual_dtype=F32,
+        logits_dtype=F32, n_pred_heads=8)
+    rows, n_pages = 4, 24
+    b = ContinuousBatcher(
+        cfg, transformer.init_params(cfg, jax.random.PRNGKey(0)), rows=rows,
+        max_len=2048, page_size=PAGE, n_pages=n_pages, prefill_bucket=PAGE,
+        pipeline_depth=None)
+    assert b._pipelined and b._decode.__name__ == "decode_block_pipelined"
+    monkeypatch.setattr(transformer, "_decode_kernel_kwargs",
+                        lambda *a, **k: {"use_pallas": True})
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def struct(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    vec = jax.ShapeDtypeStruct((rows,), I32, sharding=one_chip)
+    mask = jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=one_chip)
+    width = b._decode_widths()[-1]
+    assert width == -(-cfg.cache_entries_peak(0, 2048) // PAGE) == 17
+    table = jax.ShapeDtypeStruct((rows, width), I32, sharding=one_chip)
+    compiled = b._decode.lower(
+        jax.tree_util.tree_map(struct, b.params),
+        jax.tree_util.tree_map(struct, b.pool), table, mask, vec, vec, vec,
+        vec, vec, vec, vec).compile()
+    text = compiled.as_text()
+    assert "flash_decode_paged" in text and "tpu_custom_call" in text
+    out = jax.tree_util.tree_leaves(compiled.output_shardings)
+    assert len(out) == len(jax.tree_util.tree_leaves(b.pool)) + 4
+    leaf = f"bf16[{cfg.n_layers},{n_pages},{cfg.kv_heads},{PAGE},128]"
+    assert leaf in text
+    moved = re.findall(r"= " + re.escape(leaf)
+                       + r"\S* (?:copy|transpose)\([^)]*\)", text)
+    assert not moved, f"{leaf} is relayouted: {moved[:2]}"
+

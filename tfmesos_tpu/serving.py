@@ -104,9 +104,11 @@ BYPASS_ALLOWLIST = {
     # row's pages hold summaries and one window's exact entries, not 64
     # consecutive positions each: nothing that shares, moves or snapshots
     # pages by position carries that layout yet.  One reason string for
-    # every surface it closes, "eva summary pages"; the lagged loop is
-    # closed by "eva window close", because the host closes a window
-    # between blocks from its own up-to-date view of every row.)
+    # every surface it closes, "eva summary pages".  The lagged carry
+    # composes: a window's close depends on no token, only on a position,
+    # and positions advance at dispatch, so the close is enqueued behind
+    # the block that fills the window and the donated pool orders it
+    # before the next one.)
     # (and with a recurrent row state — a typed stack's mamba layers,
     # TransformerConfig.layer_types — a row is its pages AND a state that
     # no page holds: the state after position p cannot be cut back to an
@@ -125,7 +127,7 @@ BYPASS_ALLOWLIST = {
     # decide the next round's positions, and _step_spec reads them on
     # the host.  A speculative batcher asked for pipeline_depth=1
     # serves synchronously and records the reason.
-    "pipeline": ("speculative decoding", "eva window close"),
+    "pipeline": ("speculative decoding",),
     # Per-row suspend/export needs a host-synchronous row snapshot;
     # the pipelined carry holds in-flight device state the host view
     # lags one block behind, and mesh data shards pin pages locally
@@ -191,8 +193,6 @@ def compute_bypass_reasons(*, speculative: bool = False,
         out["kv_tier"] = "quantized kv cache"
     if pipeline_depth and speculative:
         out["pipeline"] = "speculative decoding"
-    elif pipeline_depth and eva:
-        out["pipeline"] = "eva window close"
     # The one lag mode in effect AFTER the bypasses above.
     pipelined = bool(pipeline_depth) and out["pipeline"] is None
     if n_shards != 1:
@@ -1451,8 +1451,9 @@ class ContinuousBatcher:
     (``pipeline_bypass_reason``: the carry has no speculative form, a
     speculative batcher serves synchronously).  ``0`` preserves the
     synchronous loop exactly.  ``None`` leaves the choice to the
-    batcher: ``1`` where rows keep a recurrent state (suspend, the one
-    surface the lag closes, is closed there already), else ``0``.
+    batcher: the carry where it costs no surface, i.e. ``1`` where the
+    model's own cache has already closed suspend, the one surface the
+    lag closes (a recurrent row state, EVA's entry pages), else ``0``.
 
     ``multi_step`` composes with speculative decoding: R =
     ceil(multi_step / (n_draft+1)) rounds fuse into ONE dispatch,
@@ -1574,17 +1575,7 @@ class ContinuousBatcher:
                              f"{prefix_cache_pages}")
         if multi_step < 1:
             raise ValueError(f"multi_step must be >= 1, got {multi_step}")
-        if pipeline_depth is None:
-            # The lag policy left to the batcher (what ``fleet/replica.py``
-            # passes when ``--pipeline-depth`` is not given): one block of
-            # lag where rows keep a recurrent state, synchronous otherwise.
-            # What the lagged carry costs a deployment is ``suspend``
-            # ("lagged decode carry"), and a recurrent row state has
-            # closed that already; what it buys grows with the rows such
-            # a state lets a chip hold (64 rows: 2.4 ms of host phases
-            # and 3.3 ms of idle device behind every 30 ms block).
-            pipeline_depth = int(cfg.keeps_row_state)
-        if pipeline_depth not in (0, 1):
+        if pipeline_depth not in (0, 1, None):
             raise ValueError(f"pipeline_depth must be 0 (synchronous "
                              f"host sync), 1 (one block of device-"
                              f"resident lag) or None (the batcher's "
@@ -1604,12 +1595,12 @@ class ContinuousBatcher:
         # form); the recorded reason makes the bypass observable, like
         # prefix_cache_bypass_reason.  The ``*_bypass_reason``
         # registries themselves are computed after the mesh parse below
-        # (the shard count participates).
-        self.pipeline_depth = int(pipeline_depth)
+        # (the shard count participates), and with them the depth where
+        # it was left to the batcher (None).
         self._pipe_carry = None     # device (tok, pos, step) carry
         self._pipe_host = None      # cached host-side dispatch inputs
         # The block in flight: (its device token output, {row: rid}
-        # ticket), retired one dispatch later.
+        # ticket, its K), retired one dispatch later.
         self._inflight = None
         self.cfg = cfg
         self.params = params
@@ -1644,6 +1635,20 @@ class ContinuousBatcher:
         # the donated pool (``pool["state"]``, init_row_state).
         recurrent = cfg.keeps_row_state
         self._recurrent = recurrent
+        if pipeline_depth is None:
+            # The lag policy left to the batcher (what ``fleet/replica.py``
+            # passes when ``--pipeline-depth`` is not given): the carry
+            # where it costs no surface.  What one block of lag costs a
+            # deployment is ``suspend`` ("lagged decode carry"); where the
+            # model's own cache has closed that already (a recurrent row
+            # state, EVA's entry pages) the carry is free, and what it
+            # buys is the host's tick behind every block (64 rows: 2.4 ms
+            # of host phases and 3.3 ms of idle device behind a 30 ms
+            # block).  A plain stack keeps its suspend and stays
+            # synchronous.
+            pipeline_depth = int(compute_bypass_reasons(
+                eva=eva, recurrent=recurrent)["suspend"] is not None)
+        self.pipeline_depth = int(pipeline_depth)
         self._bypass = compute_bypass_reasons(
             speculative=draft_cfg is not None, n_shards=self.n_shards,
             quantized_cache=quantized_cache,
@@ -1774,9 +1779,10 @@ class ContinuousBatcher:
         self.prefix_len = 0
         self._prefill_fns: Dict[int, Any] = {}
         self._decode = self._make_decode()
-        # EVA: a window is closed by the host BETWEEN blocks
-        # (_eva_roll_row), so a K-step block must not carry a row across a
-        # window's end: the tick before one runs single steps (_decode1).
+        # EVA: a window is closed by a program of its own, enqueued
+        # behind the block that fills it (_eva_roll_row), so a K-step
+        # block must not carry a row across a window's end: the tick
+        # before one runs single steps (_decode1).
         self._decode1 = (self._make_decode(1)
                          if eva and self.multi_step > 1 else self._decode)
         self._eva_roll = self._make_eva_roll() if eva else None
@@ -2565,12 +2571,11 @@ class ContinuousBatcher:
             # steps ride the carry the previous dispatch returned, so a
             # steady-state block uploads NOTHING — the host merges fresh
             # admissions in via ``use_host`` (a cached device constant
-            # while the dispatch set is unchanged) and reads block N's
-            # tokens one block behind.  Carries clamp at max_len + K so
-            # a parked (finished) row's garbage positions saturate
-            # instead of overflowing int32 in a long-lived server; live
-            # rows never reach the clamp (their reservations cap pos at
-            # max_len).
+            # while the dispatch set is unchanged; rows outside the
+            # dispatch enter through it too, as zeros) and reads block
+            # N's tokens one block behind.  Carries clamp at max_len + K;
+            # live rows never reach the clamp (their reservations cap pos
+            # at max_len).
             @partial(jax.jit, donate_argnums=1)
             def decode_block_pipelined(params, pool, table, use_host, toks,
                                        positions, steps, carry_tok,
@@ -2753,6 +2758,18 @@ class ContinuousBatcher:
             return eva_close_window(self.cfg, params, pool, table, window)
 
         return eva_roll
+
+    def _block_of(self, rows) -> tuple:
+        """``(K, program)`` of a decode block over ``rows``: ``multi_step``
+        steps, but single steps (``_decode1``) where an EVA window ends
+        inside the block for some row, until it has been closed (streams
+        do not depend on K).  In the pipelined loop the positions are the
+        dispatched ones, and either program takes the other's carry."""
+        K, w = self.multi_step, self.cfg.eva_window
+        if self._eva_roll is not None and K > 1 and any(
+                row.pos % w + K > w for row in rows):
+            return 1, self._decode1
+        return K, self._decode
 
     def _eva_roll_row(self, row: int, window: int) -> None:
         """Close ``row``'s window ``window`` (all of its positions are in
@@ -2948,15 +2965,14 @@ class ContinuousBatcher:
             # (k+1)-token chunk: its writes overshoot by up to n_draft
             # (and the draft's k+1 scan steps write the same positions).
             need_len += self.n_draft
-        if self._pipelined and req.stop_token is not None:
-            # A stop is detected one block late: reserve one position
-            # past the stop so the overshoot write can land in an own
-            # page.  With multi_step > 1 the overshoot can reach K-1
-            # further positions (and quota overruns up to K-1 exist
-            # too) — those are NOT reserved here: the ensure() clamp
-            # at _Row.limit keeps allocations within this
-            # reservation, and writes past it land on sink columns.
-            need_len += 1
+        # The lagged carry reserves nothing more.  A stop is detected one
+        # block late, but the overshoot block is one the quota had let
+        # through at dispatch (``row.step < max_new_tokens``): it writes
+        # where the same request without a stop token writes.  With
+        # multi_step > 1 a block may overrun a quota by up to K-1
+        # positions, stop token or not — those are NOT reserved either:
+        # the ensure() clamp at _Row.limit keeps allocations within this
+        # reservation, and writes past it land on sink columns.
         if need_len > self.max_len:
             raise ValueError(
                 f"request needs {need_len} cache positions (prefix "
@@ -3261,10 +3277,11 @@ class ContinuousBatcher:
                     np.asarray(nc)
                     compiled.append(f"spec_round[{w}]")
                 elif self._pipelined:
-                    self.pool, out, _, _, _ = self._decode(
-                        self.params, self.pool, table, no_host, zt, zt,
-                        zt, zt, zt, zt, zt)
-                    np.asarray(out)
+                    for fn in dict.fromkeys((self._decode, self._decode1)):
+                        self.pool, out, _, _, _ = fn(
+                            self.params, self.pool, table, no_host, zt, zt,
+                            zt, zt, zt, zt, zt)
+                        np.asarray(out)
                     compiled.append(f"decode[{w}]")
                 else:
                     for fn in dict.fromkeys((self._decode, self._decode1)):
@@ -5070,8 +5087,6 @@ class ContinuousBatcher:
         chunked-prefill advance happen between blocks.  (Chunked prefill
         keeps still-filling rows out: their table rows mask to the sink
         so the batched scatter cannot touch their pages.)"""
-        K = self.multi_step
-        decode = self._decode
         self._state_rows = len(active)
         eva_w = self.cfg.eva_window if self._eva_roll is not None else 0
         with self._phase("batcher.prep"):
@@ -5081,12 +5096,7 @@ class ContinuousBatcher:
             steps = np.zeros((self.rows,), np.int32)
             decoding = {r: row for r, row in active.items()
                         if row.decoding}
-            if eva_w and K > 1 and any(row.pos % eva_w + K > eva_w
-                                       for row in decoding.values()):
-                # a window ends inside this block for some row: single
-                # steps until the host has closed it (streams do not
-                # depend on K)
-                K, decode = 1, self._decode1
+            K, decode = self._block_of(decoding.values())
             for r, row in decoding.items():
                 self._ensure_sides(r, min(row.pos + K, row.limit),
                                    start=row.pos)
@@ -5150,11 +5160,20 @@ class ContinuousBatcher:
         ticket — the discard semantics ``_step`` already documents for
         mid-block stops — so token streams are IDENTICAL to
         ``pipeline_depth=0`` (same ops, same (rid, step) sample folds,
-        only the sync point moves)."""
-        K = self.multi_step
+        only the sync point moves).
+
+        Under EVA a window is closed where its last position is
+        DISPATCHED: a close takes the pool, the row's table and a window
+        number, never a token, and the position it hangs on advanced just
+        above, so ``jit_eva_roll`` is enqueued behind the block that fills
+        the window and the row's pages are trimmed in the same tick
+        (:meth:`_retire` closes nothing).  The ring's ``eva_*`` fields
+        are the host's view, which here is the dispatched one."""
         self._state_rows = len(active)
+        eva_w = self.cfg.eva_window if self._eva_roll is not None else 0
         dispatch = {r: row for r, row in active.items()
                     if row.decoding and row.step < row.req.max_new_tokens}
+        K, decode = self._block_of(dispatch.values())
         prev = self._inflight
         if dispatch:
             with self._phase("batcher.prep"):
@@ -5166,24 +5185,32 @@ class ContinuousBatcher:
                 fresh = frozenset(r for r, rid in ticket.items()
                                   if prev_ticket.get(r) != rid)
                 for r, row in dispatch.items():
-                    self._ensure_sides(r, min(row.pos + K, row.limit))
+                    self._ensure_sides(r, min(row.pos + K, row.limit),
+                                       start=row.pos)
                 table = self.t_side.decode_table(active, dispatch)
                 key = (tuple(sorted(ticket.items())), fresh)
                 host = self._pipe_host
                 stale = host is None or host[0] != key
                 if stale:
+                    # Rows outside the dispatch (free, or parked until
+                    # their last block retires) enter from the host too,
+                    # at position 0 like ``_step``'s: left on the carry
+                    # their positions would grow by K every block, and
+                    # the paged kernel would walk a context of that
+                    # length over sink pages for each, in every layer.
                     toks = np.zeros((self.rows,), np.int32)
-                    use_host = np.zeros((self.rows,), bool)
+                    use_host = np.ones((self.rows,), bool)
                     positions = np.zeros((self.rows,), np.int32)
                     steps = np.zeros((self.rows,), np.int32)
                     rids = np.zeros((self.rows,), np.int32)
                     for r, row in dispatch.items():
                         rids[r] = row.rid
                         if r in fresh:
-                            use_host[r] = True
                             toks[r] = row.last
                             positions[r] = row.pos
                             steps[r] = row.step
+                        else:
+                            use_host[r] = False
             with self._phase("batcher.dispatch"):
                 if stale:
                     host = (key, jnp.asarray(use_host), jnp.asarray(toks),
@@ -5192,25 +5219,36 @@ class ContinuousBatcher:
                     self._pipe_host = host
                 carry = self._pipe_carry
                 if carry is None:       # pipeline start: fresh rows only
-                    carry = (jnp.zeros((self.rows,), jnp.int32),
-                             jnp.zeros((self.rows,), jnp.int32),
-                             jnp.zeros((self.rows,), jnp.int32))
-                self.pool, nxt, ct, cp, cs = self._decode(
+                    carry = (jnp.asarray(np.zeros((self.rows,),
+                                                  np.int32)),) * 3
+                self.pool, nxt, ct, cp, cs = decode(
                     self.params, self.pool, table, host[1], host[2],
                     host[3], host[4], carry[0], carry[1], carry[2],
                     host[5])
                 nxt.copy_to_host_async()    # transfer overlaps the block
-            self._pipe_carry = (ct, cp, cs)
-            self._inflight = (nxt, ticket)
-            for row in dispatch.values():
-                row.pos += K
-                row.step += K
+                self._pipe_carry = (ct, cp, cs)
+                self._inflight = (nxt, ticket, K)
+                for r, row in dispatch.items():
+                    row.pos += K
+                    row.step += K
+                    if (eva_w and row.pos % eva_w == 0
+                            and row.step < row.req.max_new_tokens):
+                        # The block just enqueued fills the row's window
+                        # and the row is owed more: close it NOW, behind
+                        # that block (the donated pool orders the two, and
+                        # the next block after them).  No token enters a
+                        # close, so nothing here waits for the readback; a
+                        # row that stops on a token still in flight gets a
+                        # close it did not need, over pages of its own.
+                        self._eva_roll_row(r, row.pos // eva_w - 1)
             self._tick_block("pipelined", len(dispatch), K)
         else:
             self._inflight = None
             self._pipe_carry = self._pipe_host = None
         if prev is not None:
             yield from self._retire(prev, active, free_rows)
+        if eva_w:
+            self._eva_account(active)
 
     def _retire(self, inflight, active: Dict[int, _Row],
                 free_rows: List[int]) -> Iterator[Completion]:
@@ -5218,7 +5256,7 @@ class ContinuousBatcher:
         its token-dependent bookkeeping; rows that stopped at the
         previous retire (or were re-admitted since) fail the rid check
         and their block is dropped."""
-        nxt, ticket = inflight
+        nxt, ticket, K = inflight
         # The lagged-block sync IS the pipelined loop's per-block wait
         # (dispatch is a non-blocking enqueue).
         # Had the device finished the lagged block before the host asked
@@ -5230,14 +5268,14 @@ class ContinuousBatcher:
         with self._phase("batcher.readback"):
             nxt = self._tick_moe(np.asarray(nxt))   # one block behind
         if self._tick["name"] != "decode.block":    # the draining tick
-            self._tick_block(self._mode, len(ticket), self.multi_step)
+            self._tick_block(self._mode, len(ticket), K)
         finished = []
         with self._phase("batcher.retire"):
             for r, rid in ticket.items():
                 row = active.get(r)
                 if row is None or row.rid != rid:
                     continue            # overshoot block of a freed row
-                for j in range(self.multi_step):
+                for j in range(K):
                     tok = int(nxt[r, j])
                     row.out.append(tok)
                     row.last = tok
