@@ -637,3 +637,98 @@ def test_eva_pipelined_decode_program_compiles_for_v5e(topo, monkeypatch):
                        + r"\S* (?:copy|transpose)\([^)]*\)", text)
     assert not moved, f"{leaf} is relayouted: {moved[:2]}"
 
+
+
+# -- window layers beside full ones at Laguna-XS.2's widths (PR 42) -----------
+#
+# The programs ``ContinuousBatcher`` dispatches for the typed stack [full |
+# window x 3 | full] with a leading dense layer, compiled whole for the
+# described v5e at the benchmark's widths (hidden 2048, 48 / 64 query heads
+# over 8 K/V heads of 128, 256 experts of 512 all held, 7168 pages of 64, 128
+# rows, rings of 512 positions): the paged kernel at 6 query heads a K/V
+# head, ``flash_decode`` over the rings at 8, the windowed flash kernel, and
+# a prompt past ``FLASH_MAX_KEYS`` (the segmented forward: whole, a KV head's
+# K and V asked 48.5 MB of VMEM).  Each donates pool and rings and must leave
+# them where they are.
+
+@pytest.mark.parametrize("name,rows,t", [("decode_r128", 128, 1),
+                                         ("prefill_t448", 1, 448),
+                                         ("prefill_t16256", 1, 16256)])
+def test_window_stack_compiles_and_keeps_the_rings_in_place(
+        topo, monkeypatch, name, rows, t):
+    from tfmesos_tpu.models import transformer
+    from tfmesos_tpu.ops import attention, moe
+    from tfmesos_tpu.ops.attention import attend
+
+    kinds = ("attention", "window", "window", "window", "attention")
+    cfg = transformer.TransformerConfig(
+        vocab_size=100352, d_model=2048, n_layers=5, n_heads=48,
+        n_kv_heads=8, attn_head_dim=128, d_ff=8192, max_seq_len=17408,
+        dtype=BF16, param_dtype=BF16, layer_types=kinds, window=512,
+        window_heads=64, window_rope=transformer.RopeSpec(theta=10000.0),
+        attn_rope=transformer.RopeSpec(theta=500000.0, fraction=0.5,
+                                       yarn=(64.0, 4096, 64.0, 1.0)),
+        ffn_types=("dense",) + ("sparse",) * 4, expert_d_ff=512,
+        attn_gate="head", norm_eps=1e-6, logits_dtype=F32, n_experts=256,
+        top_k=8, moe_impl="grouped", shared_d_ff=512,
+        router_score="sigmoid", routed_scale=2.5)
+    monkeypatch.setattr(transformer, "_decode_kernel_kwargs",
+                        lambda *a, **k: {"use_pallas": True})
+    monkeypatch.setattr(transformer, "attend",
+                        partial(attend, use_pallas=True))
+    monkeypatch.setattr(moe, "_on_tpu", lambda use: True)
+    # the window mixer calls flash_decode as the program does: by the backend
+    monkeypatch.setattr(attention.jax, "default_backend", lambda: "tpu")
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def struct(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    slots, n_pages = 128, 7168
+    params = jax.tree_util.tree_map(struct, jax.eval_shape(
+        lambda: transformer.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = dict(jax.eval_shape(
+        lambda: transformer.init_paged_cache(cfg, n_pages, PAGE)))
+    cache["state"] = jax.eval_shape(
+        lambda: transformer.init_row_state(cfg, slots))
+    assert set(cache["state"]) == {"swa_k", "swa_v"}
+    cache["pages"] = jnp.zeros((rows, 17408 // PAGE), I32)
+    if t > 1:
+        cache["slots"] = jnp.zeros((rows,), I32)
+        cache["valid"] = jnp.zeros((rows,), I32)
+    cache = jax.tree_util.tree_map(struct, cache)
+    tokens = jax.ShapeDtypeStruct((rows, t), I32, sharding=one_chip)
+    if t == 1:
+        pos = (jax.ShapeDtypeStruct((rows,), I32, sharding=one_chip),)
+        step = jax.jit(lambda p, c, tok, at: transformer.decode_step(
+            cfg, p, c, tok, at), donate_argnums=1)
+    else:
+        pos = ()
+        step = jax.jit(lambda p, c, tok: transformer.decode_step(
+            cfg, p, c, tok, 0), donate_argnums=1)
+    compiled = step.lower(params, cache, tokens, *pos).compile()
+    text = compiled.as_text()
+    for kernel in ("moe_grouped_swiglu", "moe_grouped_matmul"):
+        assert kernel in text, kernel
+    if t == 1:
+        # the full layers' kernel at 6 query heads a K/V head, the rings' at 8
+        assert re.search(r"%flash_decode_paged[.\d]* = bf16\[128,8,6,128\]",
+                         text)
+        assert re.search(r"%flash_decode[.\d]* = bf16\[128,8,8,128\]", text)
+    else:
+        # both kinds' prefill kernels, told apart by their query heads
+        assert re.search(r"%flash_attention_fwd[.\d]* = \(bf16\[1,48,", text)
+        assert re.search(r"%flash_attention_fwd[.\d]* = \(bf16\[1,64,", text)
+    ring = f"bf16[3,{slots},8,512,128]"
+    assert ring in text
+    for leaf in (ring, f"bf16[2,{n_pages},8,{PAGE},128]",
+                 "bf16[4,256,2048,512]", "bf16[4,256,512,2048]"):
+        moved = re.findall(r"= " + re.escape(leaf)
+                           + r"\S* (?:copy|transpose|slice)\([^)]*\)", text)
+        assert not moved, f"{leaf} is copied: {moved[:2]}"
+    # beside 7.7 GB of weights, 3.8 GB of pool and 0.8 GB of rings: a
+    # step's temporaries (a 16 k prompt's sorted expert rows and its
+    # attention operands; a decode step's activations at 128 rows)
+    mem = compiled.memory_analysis()
+    limit = {1: 5e7, 448: 1.5e8, 16256: 2.2e9}[t]
+    assert mem.temp_size_in_bytes < limit, mem.temp_size_in_bytes

@@ -31,7 +31,7 @@ from jax.lax import axis_size
 from jax.sharding import Mesh, PartitionSpec as P
 
 from tfmesos_tpu.ops.attention import attend, mha_reference
-from tfmesos_tpu.ops.layers import (cross_entropy_loss,
+from tfmesos_tpu.ops.layers import (cross_entropy_loss, yarn_inv_freq,
                                     data_parallel_fused_cross_entropy,
                                     fused_linear_cross_entropy, rms_norm,
                                     vocab_parallel_ce_inbody,
@@ -91,7 +91,43 @@ def _norm(cfg, x, gain):
 
 
 #: the kinds of mixer a typed stack's ``layer_types`` may name
-LAYER_KINDS = ("attention", "mamba", "kda")
+LAYER_KINDS = ("attention", "mamba", "kda", "window")
+#: what a layer's second half may be (``TransformerConfig.ffn_types``)
+FFN_KINDS = ("dense", "sparse")
+
+
+@dataclass(frozen=True)
+class RopeSpec:
+    """The rotary embedding a kind of attention layer states: ``theta``;
+    ``fraction`` of a head's channels rotated (the first ones; the rest pass
+    through); ``yarn`` = ``(factor, original_max_position_embeddings,
+    beta_fast, beta_slow)`` for YaRN's inverse frequencies (None: plain
+    ``theta ** (-2 i / D)``); ``attention_factor`` multiplies cos and sin
+    (None: YaRN's ``0.1 ln(factor) + 1``, 1 without YaRN)."""
+    theta: float = 10000.0
+    fraction: float = 1.0
+    yarn: Optional[Tuple[float, int, float, float]] = None
+    attention_factor: Optional[float] = None
+
+    def kwargs(self, head_dim: int) -> Dict[str, Any]:
+        """What ``ops/layers.rope`` takes beyond the positions; YaRN's
+        frequencies are computed here, once, in float32 on the host."""
+        rd = int(head_dim * self.fraction)
+        if rd < 2 or rd % 2 or rd > head_dim:
+            raise ValueError(f"rope fraction {self.fraction} of head_dim "
+                             f"{head_dim} rotates {rd} channels: need an "
+                             f"even count in 2..head_dim")
+        kw: Dict[str, Any] = {"theta": self.theta}
+        if rd != head_dim:
+            kw["rotary_dim"] = rd
+        factor = self.attention_factor
+        if self.yarn is not None:
+            kw["inv_freq"] = yarn_inv_freq(rd, self.theta, *self.yarn)
+            if factor is None:
+                factor = 0.1 * math.log(self.yarn[0]) + 1.0
+        if factor is not None and factor != 1.0:
+            kw["factor"] = float(factor)
+        return kw
 
 
 @dataclass(frozen=True)
@@ -198,6 +234,32 @@ class TransformerConfig:
     # a per-row recurrent state for the mamba and kda layers
     # (``init_row_state``: the leaves of the kinds present).
     layer_types: Optional[Tuple[str, ...]] = None
+    # "window": a second kind of attention layer beside "attention", with
+    # its own query heads (``window_heads``; None: n_heads; K/V heads and
+    # the head size are the stack's), its own rope (``window_rope``; None:
+    # plain rope at ``rope_theta``) and a sliding window: position t attends
+    # positions max(0, t - window + 1) .. t (``window``, which with
+    # ``layer_types`` is these layers' and no one else's).  Its mixer leaves
+    # are stacked under ``layers["window"]`` (wq / wo differ in shape from
+    # the "attention" kind's).  It keeps no pages: a row slot holds a RING
+    # of the last ``window`` positions' K and V a window layer
+    # (``init_row_state``: ``swa_k`` / ``swa_v``, slot = position mod
+    # window, keys after rope), O(window) a row whatever its context.
+    # ``attn_rope``: the rope of the "attention" kind (and of a homogeneous
+    # stack's decode path) where it is more than ``rope_theta``.
+    window_heads: Optional[int] = None
+    window_rope: Optional[RopeSpec] = None
+    attn_rope: Optional[RopeSpec] = None
+    # A FEED-FORWARD PATTERN beside the mixer pattern (None: every block's
+    # second half is the same).  One entry per layer, "dense" | "sparse":
+    # the LEADING layers may be dense (a SwiGLU of width ``d_ff``, leaves
+    # ``layers["dense"]`` stacked over them), every later one is sparse (the
+    # expert layer; its leaves, router and shared expert included, are
+    # stacked over the sparse layers only, the experts ``expert_d_ff`` wide,
+    # None: d_ff).  The leading layers run before the scanned periods, and
+    # ``layer_period`` / ``layer_runs`` describe the layers behind them.
+    ffn_types: Optional[Tuple[str, ...]] = None
+    expert_d_ff: Optional[int] = None
     # The Mamba-2 (SSD) mixer: ``mamba_heads`` heads of ``mamba_head_dim``
     # (d_inner = their product), a state of ``mamba_state`` per head
     # channel, ONE B/C group shared by every head, a causal depthwise conv
@@ -227,11 +289,12 @@ class TransformerConfig:
     # head_dim ** -0.5); ``attn_head_dim`` the head size (None: d_model /
     # n_heads); ``attn_gate`` multiplies the attention's output, before
     # ``wo``, by ``sigmoid(x W_g)`` taken from the block's normed input (an
-    # elementwise output gate: the leaf ``wg`` beside ``wq``).
+    # elementwise output gate: the leaf ``wg`` beside ``wq``, [d, heads *
+    # head_dim]; ``attn_gate="head"``: one gate a HEAD, ``wg`` [d, heads]).
     rope: bool = True
     attn_scale: Optional[float] = None
     attn_head_dim: Optional[int] = None
-    attn_gate: bool = False
+    attn_gate: Any = False
     # Multipliers (None: absent): the embedding's output is scaled by
     # ``embed_scale``, every block adds ``residual_scale`` times its mixer
     # / feed-forward output, the logits are divided by ``logits_scale``.
@@ -304,11 +367,19 @@ class TransformerConfig:
             if bad or len(self.layer_types) != self.n_layers:
                 raise ValueError(
                     f"layer_types takes n_layers ({self.n_layers}) entries "
-                    f"of 'attention' | 'mamba' | 'kda', got "
+                    f"of 'attention' | 'mamba' | 'kda' | 'window', got "
                     f"{self.layer_types!r}")
-            if self.attention != "full" or self.window is not None:
-                raise ValueError("layer_types composes with full attention "
-                                 "only (no window, no EVA)")
+            windowed = "window" in self.layer_types
+            if self.attention != "full" or (self.window is None) == windowed:
+                raise ValueError(
+                    "layer_types composes with full attention only (no "
+                    "EVA); window is the 'window' layers' and is stated "
+                    "with them and only with them")
+            if windowed and (self.window_heads or self.n_heads) % \
+                    self.kv_heads:
+                raise ValueError(
+                    f"window_heads ({self.window_heads}) must be a "
+                    f"multiple of the K/V heads ({self.kv_heads})")
             if "mamba" in self.layer_types and (
                     self.mamba_heads < 1 or self.mamba_conv < 2):
                 raise ValueError("mamba layers need mamba_heads >= 1 and "
@@ -318,6 +389,30 @@ class TransformerConfig:
                     or self.kda_chunk < 1):
                 raise ValueError("kda layers need kda_heads >= 1, kda_conv "
                                  ">= 2 and kda_chunk >= 1")
+        if any(v is not None for v in (self.window_heads, self.window_rope)) \
+                and "window" not in (self.layer_types or ()):
+            raise ValueError("window_heads / window_rope are the 'window' "
+                             "layers' (layer_types)")
+        if self.attn_gate not in (False, True, "head"):
+            raise ValueError(f"attn_gate must be False, True (elementwise) "
+                             f"or 'head', got {self.attn_gate!r}")
+        if self.ffn_types is not None:
+            object.__setattr__(self, "ffn_types", tuple(self.ffn_types))
+            lead = self.n_lead_layers
+            if (self.layer_types is None or not self.n_experts
+                    or self.moe_impl != "grouped"
+                    or len(self.ffn_types) != self.n_layers
+                    or set(self.ffn_types) - set(FFN_KINDS)
+                    or lead == self.n_layers
+                    or "dense" in self.ffn_types[lead:]):
+                raise ValueError(
+                    f"ffn_types takes n_layers ({self.n_layers}) entries, "
+                    f"'dense' for leading layers only and 'sparse' behind "
+                    f"them, in a typed stack (layer_types) with grouped "
+                    f"experts; got {self.ffn_types!r}")
+        elif self.expert_d_ff is not None:
+            raise ValueError("expert_d_ff is stated with ffn_types (the "
+                             "dense layers take d_ff)")
         if self.router_score not in ("softmax", "sigmoid"):
             raise ValueError(f"router_score must be 'softmax' or 'sigmoid', "
                              f"got {self.router_score!r}")
@@ -360,26 +455,65 @@ class TransformerConfig:
         return self.layer_kinds.count("kda")
 
     @property
+    def attn_window(self) -> Optional[int]:
+        """The window of the "attention" kind's layers: ``window`` for a
+        homogeneous stack, none in a typed one (there ``window`` is the
+        "window" kind's)."""
+        return None if self.layer_types is not None else self.window
+
+    @property
+    def n_window_layers(self) -> int:
+        """Sliding-window layers: a ring of K and V a row."""
+        return self.layer_kinds.count("window")
+
+    @property
     def keeps_row_state(self) -> bool:
-        """Rows keep a recurrent state beside their pages, whatever the kind
-        of layer that keeps it (``init_row_state``)."""
+        """Rows keep a state beside their pages whose size does not depend
+        on their context, whatever the kind of layer that keeps it
+        (``init_row_state``): a recurrent state, or a window layer's ring."""
         return any(kind != "attention" for kind in self.layer_kinds)
 
     @property
-    def layer_period(self) -> int:
-        """Length of the shortest prefix of ``layer_types`` that, repeated,
-        gives the whole pattern."""
-        kinds = self.layer_kinds
-        return next(p for p in range(1, len(kinds) + 1)
-                    if len(kinds) % p == 0
-                    and kinds == kinds[:p] * (len(kinds) // p))
+    def n_lead_layers(self) -> int:
+        """Leading layers whose feed-forward is dense (``ffn_types``): they
+        run before the scanned periods."""
+        kinds = self.ffn_types or ()
+        return next((i for i, k in enumerate(kinds) if k != "dense"),
+                    len(kinds))
 
     @property
-    def layer_runs(self):
-        """One period as runs of layers of one kind: ``(kind, first layer
-        in the period, layers, first index among the period's layers of
-        that kind)``."""
-        period = self.layer_kinds[:self.layer_period]
+    def n_sparse_layers(self) -> int:
+        """Layers that hold an expert layer: what the expert leaves, the
+        router and the shared expert are stacked over."""
+        return (self.n_layers - self.n_lead_layers) if self.n_experts else 0
+
+    @property
+    def expert_width(self) -> int:
+        return self.d_ff if self.expert_d_ff is None else self.expert_d_ff
+
+    def kind_heads(self, kind: str) -> int:
+        """Query heads of an attention layer of ``kind``."""
+        if kind == "window" and self.window_heads is not None:
+            return self.window_heads
+        return self.n_heads
+
+    @property
+    def layer_period(self) -> int:
+        """Length of the shortest prefix of the pattern behind the leading
+        layers (``n_lead_layers``; none: ``layer_types`` whole) that,
+        repeated, gives that pattern.  Behind leading layers the last
+        period may be partial (the published pattern starts at layer 0, so
+        a stack of whole periods of it ends the leading layers short of
+        whole periods behind them)."""
+        lead = self.n_lead_layers
+        kinds = self.layer_kinds[lead:]
+        n = len(kinds)
+        return next(p for p in range(1, n + 1)
+                    if (lead or n % p == 0)
+                    and kinds == (kinds[:p] * -(-n // p))[:n])
+
+    @staticmethod
+    def _runs(period):
         runs, seen = [], dict.fromkeys(LAYER_KINDS, 0)
         for j, kind in enumerate(period):
             if runs and runs[-1][0] == kind:
@@ -388,6 +522,14 @@ class TransformerConfig:
                 runs.append([kind, j, 1, seen[kind]])
             seen[kind] += 1
         return tuple(tuple(r) for r in runs)
+
+    @property
+    def layer_runs(self):
+        """One period as runs of layers of one kind: ``(kind, first layer
+        in the period, layers, first index among the period's layers of
+        that kind)``."""
+        lead = self.n_lead_layers
+        return self._runs(self.layer_kinds[lead:lead + self.layer_period])
 
     @property
     def mamba_inner(self) -> int:
@@ -444,9 +586,9 @@ def init_params(cfg: TransformerConfig, rng) -> Dict[str, Any]:
             "n_shared_experts requires n_experts > 0 — without routed "
             "experts there is nothing to share beside; widen d_ff instead")
     d, f, l = cfg.d_model, cfg.d_ff, cfg.n_layers
-    hd = cfg.n_heads * cfg.head_dim
     kvd = cfg.kv_heads * cfg.head_dim
-    keys = iter(jax.random.split(rng, 32 if cfg.layer_types else 16))
+    keys = iter(jax.random.split(rng, 48 if cfg.ffn_types else
+                                 32 if cfg.layer_types else 16))
 
     def norm(shape, scale):
         return (jax.random.normal(next(keys), shape, cfg.param_dtype)
@@ -454,15 +596,24 @@ def init_params(cfg: TransformerConfig, rng) -> Dict[str, Any]:
 
     # a gain g with the unit offset scales by (1 + g): the identity is 0
     gain = jnp.zeros if cfg.norm_offset else jnp.ones
+
+    def attn_leaves(la, heads):
+        """The leaves of ``la`` attention layers of ``heads`` query heads."""
+        hd = heads * cfg.head_dim
+        leaves = {
+            "wq": norm((la, d, hd), 1 / math.sqrt(d)),
+            "wk": norm((la, d, kvd), 1 / math.sqrt(d)),
+            "wv": norm((la, d, kvd), 1 / math.sqrt(d)),
+            "wo": norm((la, hd, d), 1 / math.sqrt(hd) / math.sqrt(2 * l)),
+        }
+        if cfg.attn_gate:
+            leaves["wg"] = norm(
+                (la, d, heads if cfg.attn_gate == "head" else hd),
+                1 / math.sqrt(d))
+        return leaves
+
     la = cfg.n_attn_layers
-    attn = {
-        "wq": norm((la, d, hd), 1 / math.sqrt(d)),
-        "wk": norm((la, d, kvd), 1 / math.sqrt(d)),
-        "wv": norm((la, d, kvd), 1 / math.sqrt(d)),
-        "wo": norm((la, hd, d), 1 / math.sqrt(hd) / math.sqrt(2 * l)),
-    }
-    if cfg.attn_gate:
-        attn["wg"] = norm((la, d, hd), 1 / math.sqrt(d))
+    attn = attn_leaves(la, cfg.n_heads)
     layers = {"attn_norm": gain((l, d), cfg.param_dtype),
               "mlp_norm": gain((l, d), cfg.param_dtype)}
     if cfg.layer_types is None:
@@ -471,6 +622,9 @@ def init_params(cfg: TransformerConfig, rng) -> Dict[str, Any]:
         # typed stack: the mixers' leaves by kind, [layers of that kind, ..]
         if la:
             layers["attention"] = attn
+        if cfg.n_window_layers:
+            layers["window"] = attn_leaves(cfg.n_window_layers,
+                                           cfg.kind_heads("window"))
         u = lambda shape, lo, hi: jax.random.uniform(
             next(keys), shape, jnp.float32, lo, hi)
         lm = cfg.n_mamba_layers
@@ -525,21 +679,33 @@ def init_params(cfg: TransformerConfig, rng) -> Dict[str, Any]:
             eva_mu=norm((l, cfg.kv_heads, cfg.head_dim), 1.0))
     if cfg.n_experts:
         e, eh = cfg.n_experts, cfg.held_experts
+        # the expert layer's leaves are stacked over the layers that have
+        # one (all of them without ``ffn_types``), ``expert_width`` wide
+        ls, fe = cfg.n_sparse_layers, cfg.expert_width
+        if cfg.n_lead_layers:
+            nl = cfg.n_lead_layers
+            layers["dense"] = {
+                "w_gate": norm((nl, d, f), 1 / math.sqrt(d)),
+                "w_up": norm((nl, d, f), 1 / math.sqrt(d)),
+                "w_down": norm((nl, f, d),
+                               1 / math.sqrt(f) / math.sqrt(2 * l)),
+            }
         layers.update(
-            router=norm((l, d, e), 1 / math.sqrt(d)),
-            e_gate=norm((l, eh, d, f), 1 / math.sqrt(d)),
-            e_up=norm((l, eh, d, f), 1 / math.sqrt(d)),
-            e_down=norm((l, eh, f, d), 1 / math.sqrt(f) / math.sqrt(2 * l)),
+            router=norm((ls, d, e), 1 / math.sqrt(d)),
+            e_gate=norm((ls, eh, d, fe), 1 / math.sqrt(d)),
+            e_up=norm((ls, eh, d, fe), 1 / math.sqrt(d)),
+            e_down=norm((ls, eh, fe, d),
+                        1 / math.sqrt(fe) / math.sqrt(2 * l)),
         )
         if cfg.router_score == "sigmoid":
             # the selection bias: float32 whatever the parameters' dtype
-            layers["router_bias"] = jnp.zeros((l, e), jnp.float32)
+            layers["router_bias"] = jnp.zeros((ls, e), jnp.float32)
         if cfg.shared_width:
             sf = cfg.shared_width
             layers.update(
-                s_gate=norm((l, d, sf), 1 / math.sqrt(d)),
-                s_up=norm((l, d, sf), 1 / math.sqrt(d)),
-                s_down=norm((l, sf, d),
+                s_gate=norm((ls, d, sf), 1 / math.sqrt(d)),
+                s_up=norm((ls, d, sf), 1 / math.sqrt(d)),
+                s_down=norm((ls, sf, d),
                             1 / math.sqrt(sf) / math.sqrt(2 * l)),
             )
     else:
@@ -1255,9 +1421,11 @@ def init_paged_cache(cfg: TransformerConfig, n_pages: int,
     into the score rows, so HBM streams int8 pages).  Windowed (rolling)
     configs address by slot and don't page.
     """
-    if cfg.window is not None:
+    if cfg.window is not None and cfg.layer_types is None:
         raise ValueError("paged caches do not compose with sliding-window "
-                         "configs (rolling caches address by slot)")
+                         "configs (rolling caches address by slot); a typed "
+                         "stack's 'window' layers keep a ring a row "
+                         "(init_row_state) beside the other layers' pages")
     if cfg.attention == "eva":
         # A row's table is [summary pages | window pages]: a closed window
         # leaves whole pages of summaries, so every window starts a page.
@@ -1314,7 +1482,13 @@ def init_row_state(cfg: TransformerConfig, rows: int) -> Dict[str, Any]:
     every slot, a prefill from position 0 starts from an empty state and
     writes its rows' slots (``cache["slots"]``), whatever they held."""
     state = {}
-    lm, lk = cfg.n_mamba_layers, cfg.n_kda_layers
+    lm, lk, lw = cfg.n_mamba_layers, cfg.n_kda_layers, cfg.n_window_layers
+    if lw:
+        # K and V of the last ``window`` positions (slot = position mod
+        # window, keys after rope), in the layout ``flash_decode`` reads
+        ring = (lw, rows, cfg.kv_heads, cfg.window, cfg.head_dim)
+        state.update(swa_k=jnp.zeros(ring, cfg.dtype),
+                     swa_v=jnp.zeros(ring, cfg.dtype))
     if lm:
         state.update(
             ssm=jnp.zeros((lm, rows, cfg.mamba_inner, cfg.mamba_state),
@@ -1781,7 +1955,7 @@ def _decode_kernel_kwargs(cfg: TransformerConfig, m: int, t: int,
     an explicit ``mesh`` whose axes are data + tp (the ``cache_specs``
     layout) the kernel runs per shard under a shard_map
     (``sharded_flash_decode``); other meshes keep the einsum."""
-    if (t > 64 or cfg.window is not None or m < 512
+    if (t > 64 or cfg.attn_window is not None or m < 512
             or jax.default_backend() != "tpu"):
         return None
     if not sharded:
@@ -1854,6 +2028,45 @@ def _residual(cfg: TransformerConfig, x, y):
     return x + y
 
 
+def _rope_kwargs(cfg: TransformerConfig, kind: str = "attention"):
+    """What ``rope`` takes for an attention layer of ``kind``: the kind's
+    :class:`RopeSpec` where the configuration states one, else plain rope
+    at ``rope_theta`` over every channel."""
+    spec = cfg.window_rope if kind == "window" else cfg.attn_rope
+    if spec is None:
+        return {"theta": cfg.rope_theta}
+    return spec.kwargs(cfg.head_dim)
+
+
+def _project_qkv(cfg: TransformerConfig, h, lp, positions,
+                 kind: str = "attention"):
+    """q [B, t, heads of ``kind``, Dh], k and v [B, t, KV, Dh] of the normed
+    input ``h``, q and k under the kind's rope (``cfg.rope``)."""
+    b, t, _ = h.shape
+    q = _qmm(h, lp["wq"], cfg.dtype).reshape(b, t, cfg.kind_heads(kind),
+                                             cfg.head_dim)
+    k = _qmm(h, lp["wk"], cfg.dtype).reshape(b, t, cfg.kv_heads,
+                                             cfg.head_dim)
+    v = _qmm(h, lp["wv"], cfg.dtype).reshape(b, t, cfg.kv_heads,
+                                             cfg.head_dim)
+    if cfg.rope:
+        rkw = _rope_kwargs(cfg, kind)
+        q = rope(q, positions, **rkw)
+        k = rope(k, positions, **rkw)
+    return q, k, v
+
+
+def _attn_gated(cfg: TransformerConfig, o, h, lp):
+    """``attn_gate``: the attention's output ``o`` [B, t, heads * head_dim]
+    times ``sigmoid(h W_g)``, elementwise or (``"head"``) one gate a head."""
+    gate = jax.nn.sigmoid(_qmm(h, lp["wg"], cfg.dtype))
+    if cfg.attn_gate == "head":
+        b, t, _ = o.shape
+        return (o.reshape(b, t, -1, cfg.head_dim)
+                * gate[..., None]).reshape(b, t, -1)
+    return o * gate
+
+
 def _block_decode(cfg: TransformerConfig, x, lp, ck, cv, li, positions,
                   pos, sharded: bool = False, mesh: Optional[Mesh] = None,
                   pages=None, kpos=None):
@@ -1904,19 +2117,10 @@ def _attend_decode(cfg: TransformerConfig, x, lp, ck, cv, li, positions,
     b, t, _ = x.shape
     m = _cache_logical_len(ck, pages)
     h = _norm(cfg, x, lp["attn_norm"])
-    q = _qmm(h, lp["wq"], cfg.dtype).reshape(b, t, cfg.n_heads,
-                                             cfg.head_dim)
-    k = _qmm(h, lp["wk"], cfg.dtype).reshape(b, t, cfg.kv_heads,
-                                             cfg.head_dim)
-    v = _qmm(h, lp["wv"], cfg.dtype).reshape(b, t, cfg.kv_heads,
-                                             cfg.head_dim)
-    pos_row = positions                                 # [b, t]
-    if cfg.rope:
-        q = rope(q, pos_row, cfg.rope_theta)
-        k = rope(k, pos_row, cfg.rope_theta)
+    q, k, v = _project_qkv(cfg, h, lp, positions)
     # a stated softmax scale rides to whichever attention runs below
     skw = {} if cfg.attn_scale is None else {"scale": cfg.attn_scale}
-    rolling = cfg.window is not None
+    rolling = cfg.attn_window is not None
     self_attn_prefill = t > 1 and isinstance(pos, int) and pos == 0
     o_paged = None
     # Single-host paged steps DEFER their pool commit: the per-layer
@@ -1975,12 +2179,13 @@ def _attend_decode(cfg: TransformerConfig, x, lp, ck, cv, li, positions,
                 from tfmesos_tpu.ops.attention import \
                     sharded_flash_attention
                 o = sharded_flash_attention(q, k, v, mesh, causal=True,
-                                            window=cfg.window, **pkw)
+                                            window=cfg.attn_window, **pkw)
             else:
-                o = mha_reference(q, k, v, causal=True, window=cfg.window)
+                o = mha_reference(q, k, v, causal=True,
+                                  window=cfg.attn_window)
         else:
-            o = attend(q, k, v, mesh=None, causal=True, window=cfg.window,
-                       **skw)
+            o = attend(q, k, v, mesh=None, causal=True,
+                       window=cfg.attn_window, forward_only=True, **skw)
     elif o_paged is not None:
         o = o_paged
     elif pages is not None:
@@ -2044,7 +2249,7 @@ def _attend_decode(cfg: TransformerConfig, x, lp, ck, cv, li, positions,
         q5 = q.reshape(b, t, kv, g, cfg.head_dim)
         s = jnp.einsum("btkgd,bkmd->bkgtm", q5, ck_r).astype(jnp.float32)
         s = s / math.sqrt(cfg.head_dim)
-        if cfg.window is not None:
+        if cfg.attn_window is not None:
             # Rolling cache: slot j holds global position p - ((p - j) % M)
             # (the latest position congruent to j not after p).  Negative
             # slot positions are not yet written; everything resident is
@@ -2056,7 +2261,7 @@ def _attend_decode(cfg: TransformerConfig, x, lp, ck, cv, li, positions,
             p0 = positions[0, 0]    # rolling caches are never ragged
             slot = jax.lax.broadcasted_iota(jnp.int32, (t, m), 1)
             spos = p0 - ((p0 - slot) % m)
-            bad = (spos < 0) | (spos < p0 - (cfg.window - 1))
+            bad = (spos < 0) | (spos < p0 - (cfg.attn_window - 1))
             bad = bad[None]
         else:
             kpos = jax.lax.broadcasted_iota(jnp.int32, (t, m), 1)
@@ -2067,7 +2272,7 @@ def _attend_decode(cfg: TransformerConfig, x, lp, ck, cv, li, positions,
     o = o.reshape(b, t, -1)
     if cfg.attn_gate:
         with jax.named_scope("attention.gate"):
-            o = o * jax.nn.sigmoid(_qmm(h, lp["wg"], cfg.dtype))
+            o = _attn_gated(cfg, o, h, lp)
     x = _residual(cfg, x, _qmm(o, lp["wo"], cfg.dtype))
     return x, ck, cv, ((k, v) if defer else None)
 
@@ -2211,7 +2416,8 @@ def decode_step(cfg: TransformerConfig, params, cache, tokens, pos,
     return logits, out_cache
 
 
-def _mamba_mixer(cfg: TransformerConfig, x, lp, state, mi, slots, valid):
+def _mamba_mixer(cfg: TransformerConfig, x, lp, state, mi, slots, valid,
+                 positions=None):
     """The Mamba-2 mixer of one block over a token chunk; returns ``(x,
     (ssm, conv))`` with layer ``mi`` of the stacked row state ``state =
     (ssm, conv)`` updated.
@@ -2275,7 +2481,8 @@ def _mamba_mixer(cfg: TransformerConfig, x, lp, state, mi, slots, valid):
     return _residual(cfg, x, _qmm(y, lp["out_proj"], cfg.dtype)), (ssm, conv)
 
 
-def _kda_mixer(cfg: TransformerConfig, x, lp, state, ki, slots, valid):
+def _kda_mixer(cfg: TransformerConfig, x, lp, state, ki, slots, valid,
+               positions=None):
     """The KDA mixer of one block over a token chunk (``ops/kda.py`` has the
     recurrence); returns ``(x, (s, conv))`` with layer ``ki`` of the stacked
     row state ``state = (s, conv)`` updated.
@@ -2338,10 +2545,79 @@ def _kda_mixer(cfg: TransformerConfig, x, lp, state, ki, slots, valid):
     return _residual(cfg, x, _qmm(o, lp["out_proj"], cfg.dtype)), (s, conv)
 
 
+def _window_mixer(cfg: TransformerConfig, x, lp, state, wi, slots, valid,
+                  positions):
+    """A sliding-window attention layer over a token chunk; returns ``(x,
+    (ring_k, ring_v))`` with layer ``wi`` of the stacked rings updated.
+
+    ``ring_k`` / ``ring_v`` [Lw, rows, KV, W, Dh] (``init_row_state``): slot
+    ``p mod W`` of a row holds position ``p``'s K / V (keys after rope), W =
+    ``cfg.window``, so a row that has written position ``t`` holds exactly
+    the window's positions ``max(0, t - W + 1) .. t`` in slots ``0 ..
+    min(t, W - 1)``: what is VALID is told by the position alone, and a slot
+    taken over from another request shows nothing of it (its prompt writes
+    the slots it fills; the others lie past ``min(t, W - 1)`` until the
+    row's own steps write them).  ``t == 1``: every row is a slot; the
+    step writes its position's K / V, then attends the ring through
+    ``flash_decode`` bounded at ``min(t, W - 1)`` (softmax does not mind the
+    ring's order).  ``t > 1``: a prefill from position 0, windowed flash
+    attention over the chunk itself; the last W real positions (``valid``
+    [B] each row's; the rest is padding) go to the rings of ``slots``."""
+    from tfmesos_tpu.ops.attention import flash_decode
+    rk, rv = state
+    b, t, _ = x.shape
+    kv, w = cfg.kv_heads, cfg.window
+    h = _norm(cfg, x, lp["attn_norm"])
+    q, k, v = _project_qkv(cfg, h, lp, positions, "window")
+    skw = {} if cfg.attn_scale is None else {"scale": cfg.attn_scale}
+    # The writes below are scatters IN THE RINGS' OWN LAYOUT, by the rule of
+    # ``_paged_cache_write_all``: every dim in front of the window is
+    # indexed and the window is a trailing slab ([Dh] a step, [W, Dh] a
+    # prompt), so the compiler scatters into the donated store in place (a
+    # per-row dynamic-update-slice under vmap carried the store rows-major
+    # through the layer scan and transposed all of it back a layer).
+    ki = jnp.arange(kv, dtype=jnp.int32)[None]
+    if t == 1:
+        pos = positions[:, 0]
+        with jax.named_scope("swa.write"):
+            at = (wi, jnp.arange(b, dtype=jnp.int32)[:, None], ki,
+                  (pos % w)[:, None])
+            rk = rk.at[at].set(k[:, 0].astype(rk.dtype))
+            rv = rv.at[at].set(v[:, 0].astype(rv.dtype))
+        with jax.named_scope("swa.decode"):
+            o = flash_decode(q, rk, rv, jnp.minimum(pos, w - 1), layer=wi,
+                             **skw)
+    else:
+        with jax.named_scope("swa.prefill"):
+            o = attend(q, k, v, mesh=None, causal=True, window=w,
+                       forward_only=True, **skw)
+        with jax.named_scope("swa.write"):
+            # slot s takes the last real position congruent to s (a prompt
+            # shorter than W leaves the slots past its end alone: whatever
+            # is gathered for them lies past the bound until overwritten)
+            last = valid[:, None] - 1
+            src = last - (last - jnp.arange(w, dtype=jnp.int32)[None]) % w
+            src = jnp.clip(src, 0, t - 1)[:, :, None, None]
+
+            def put(ring, c):
+                keep = jnp.take_along_axis(c, src, axis=1)  # [B, W, KV, Dh]
+                return ring.at[wi, slots[:, None], ki].set(
+                    keep.transpose(0, 2, 1, 3).astype(ring.dtype))
+
+            rk, rv = put(rk, k), put(rv, v)
+    o = o.reshape(b, t, -1)
+    if cfg.attn_gate:
+        with jax.named_scope("attention.gate"):
+            o = _attn_gated(cfg, o, h, lp)
+    return _residual(cfg, x, _qmm(o, lp["wo"], cfg.dtype)), (rk, rv)
+
+
 #: the leaves of a kind's row state (``init_row_state``), in the order the
 #: layer scans carry them, and the kind's mixer
-_ROW_STATE = {"mamba": ("ssm", "conv"), "kda": ("kda_s", "kda_conv")}
-_MIXERS = {"mamba": _mamba_mixer, "kda": _kda_mixer}
+_ROW_STATE = {"mamba": ("ssm", "conv"), "kda": ("kda_s", "kda_conv"),
+              "window": ("swa_k", "swa_v")}
+_MIXERS = {"mamba": _mamba_mixer, "kda": _kda_mixer,
+           "window": _window_mixer}
 
 
 def _typed_decode_step(cfg: TransformerConfig, params, cache, tokens, pos):
@@ -2379,7 +2655,8 @@ def _typed_decode_step(cfg: TransformerConfig, params, cache, tokens, pos):
     x, positions, _ = _embed_chunk(cfg, params, tokens, pos)
 
     per, runs = cfg.layer_period, cfg.layer_runs
-    n_per = cfg.n_layers // per
+    lead = cfg.n_lead_layers
+    n_per = (cfg.n_layers - lead) // per
     per_kind = {kind: sum(r[2] for r in runs if r[0] == kind)
                 for kind in LAYER_KINDS}
     grouped = bool(cfg.n_experts) and cfg.moe_impl == "grouped"
@@ -2389,20 +2666,30 @@ def _typed_decode_step(cfg: TransformerConfig, params, cache, tokens, pos):
     # weights); the grouped expert kernels take the whole expert stacks
     # and the layer index (``_EXPERT_LEAVES``: no copy of a layer's experts
     # in front of a kernel either).
-    common = {k: v for k, v in lay.items() if k not in LAYER_KINDS}
+    common = {k: v for k, v in lay.items()
+              if k not in LAYER_KINDS and k != "dense"}
     experts = ({k: common.pop(k) for k in _EXPERT_LEAVES} if grouped
                else {})
+    # behind leading dense layers (``ffn_types``) the expert layer's leaves
+    # are stacked over the sparse layers only; the norms over every layer
+    norms = ({k: common.pop(k) for k in ("attn_norm", "mlp_norm")}
+             if lead else {})
 
     def at(tree, i):
         return jax.tree_util.tree_map(
             lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
             tree)
 
-    def one_layer(kind, carry, li, ki):
+    def one_layer(kind, carry, li, ki, dense=False):
         """Layer ``li`` of the stack, the ``ki``-th of its kind (a pool /
-        state layer index)."""
+        state layer index); ``dense``: a leading layer, its second half the
+        dense feed-forward."""
         x, ck, cv, st = carry
-        lp = {**at(common, li), **at(lay[kind], ki)}
+        # its index among the sparse layers (no op where nothing leads)
+        si = li - lead if lead else li
+        lp = {**at(norms, li),
+              **at(lay["dense"] if dense else common, li if dense else si),
+              **at(lay[kind], ki)}
         if kind == "attention":
             with jax.named_scope("attention"):
                 x, ck, cv, chunk = _attend_decode(
@@ -2418,34 +2705,55 @@ def _typed_decode_step(cfg: TransformerConfig, params, cache, tokens, pos):
         else:
             with jax.named_scope(kind):
                 x, new = _MIXERS[kind](cfg, x, lp, st[kind], ki, slots,
-                                       valid)
+                                       valid, positions)
                 st = {**st, kind: new}
         with jax.named_scope("mlp"):
             h = _norm(cfg, x, lp["mlp_norm"])
-            ffn, aux = _ffn(cfg, None, {**lp, **experts}, h,
-                            expert_layer=li if grouped else None)
+            if dense:
+                ffn, aux = _mlp(cfg, lp, h), None
+            else:
+                ffn, aux = _ffn(cfg, None, {**lp, **experts}, h,
+                                expert_layer=si if grouped else None)
             x = _residual(cfg, x, ffn)
-        counts = (aux["expert_counts"] if grouped
+        counts = (aux["expert_counts"] if grouped and aux is not None
                   else jnp.zeros((0,), jnp.int32))
         return (x, ck, cv, st), counts
 
+    def run_of(carry, pi, run):
+        """One run of layers of a kind in period ``pi`` (traced OK)."""
+        kind, j0, n, k0 = run
+        li0, ki0 = pi * per + j0, pi * per_kind[kind] + k0
+        if lead:
+            li0, ki0 = li0 + lead, ki0 + lead_kind[kind]
+        return jax.lax.scan(
+            lambda cr, i: one_layer(kind, cr, li0 + i, ki0 + i),
+            carry, jnp.arange(n, dtype=jnp.int32))
+
     def period(carry, pi):
         counts = []
-        for kind, j0, n, k0 in runs:
-            li0, ki0 = pi * per + j0, pi * per_kind[kind] + k0
-            carry, c = jax.lax.scan(
-                lambda cr, i, kind=kind, li0=li0, ki0=ki0: one_layer(
-                    kind, cr, li0 + i, ki0 + i),
-                carry, jnp.arange(n, dtype=jnp.int32))
+        for run in runs:
+            carry, c = run_of(carry, pi, run)
             counts.append(c)
         return carry, jnp.concatenate(counts, axis=0)
 
     # the row state rides the scans as one tuple of leaves a kind
     st = {kind: tuple(state[leaf] for leaf in leaves)
           for kind, leaves in _ROW_STATE.items() if kind in cfg.layer_kinds}
-    (x, new_k, new_v, st), counts = jax.lax.scan(
-        period, (x, cache["k"], cache["v"], st),
-        jnp.arange(n_per, dtype=jnp.int32))
+    carry = (x, cache["k"], cache["v"], st)
+    # the leading layers, before the scanned periods: each the first so
+    # many of its kind (``lead_kind``: what the periods' indices start at)
+    lead_kind = dict.fromkeys(LAYER_KINDS, 0)
+    for li, kind in enumerate(cfg.layer_kinds[:lead]):
+        carry, _ = one_layer(kind, carry, li, lead_kind[kind], dense=True)
+        lead_kind[kind] += 1
+    carry, counts = jax.lax.scan(period, carry,
+                                 jnp.arange(n_per, dtype=jnp.int32))
+    # a last, partial period (behind leading layers only)
+    tail = []
+    for run in cfg._runs(cfg.layer_kinds[lead + n_per * per:]):
+        carry, c = run_of(carry, n_per, run)
+        tail.append(c)
+    x, new_k, new_v, st = carry
     if valid is not None and t > 1:     # the head at the last real position
         x = jnp.take_along_axis(x, (valid - 1)[:, None, None], axis=1)
     logits = _final_logits(cfg, params, x)
@@ -2454,8 +2762,10 @@ def _typed_decode_step(cfg: TransformerConfig, params, cache, tokens, pos):
         out_cache["state"] = {
             leaf: new for kind, leaves in st.items()
             for leaf, new in zip(_ROW_STATE[kind], leaves)}
-    if grouped:
-        out_cache["expert_counts"] = counts.reshape(cfg.n_layers, -1)
+    if grouped:     # [sparse layers, held]
+        counts = counts.reshape(n_per * per, -1)
+        out_cache["expert_counts"] = (
+            jnp.concatenate([counts] + tail) if tail else counts)
     return logits, out_cache
 
 

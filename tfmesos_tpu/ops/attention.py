@@ -634,7 +634,8 @@ def flash_attention(q, k, v, causal: bool = False, scale: Optional[float] = None
                     block_q: int = 512, block_k: int = 512,
                     use_pallas: Optional[bool] = None,
                     interpret: bool = False,
-                    window: Optional[int] = None):
+                    window: Optional[int] = None,
+                    forward_only: bool = False):
     """Blocked attention; Pallas kernel on TPU, reference math elsewhere.
 
     ``use_pallas=None`` auto-selects: the kernel runs when the default
@@ -651,6 +652,12 @@ def flash_attention(q, k, v, causal: bool = False, scale: Optional[float] = None
     blocks (q rounded up to the dtype's sublanes, k to 128 lanes) and the
     tail is zero-padded and masked by position, so no length degenerates to
     the small blocks its divisors would allow (704 = 64 x 11 runs 352 x 384).
+
+    ``forward_only``: the caller takes no gradient of the result (the
+    serving path's prefill states it).  Only then does a causal
+    self-attention past ``FLASH_MAX_KEYS`` keys run segment by segment
+    (``_flash_segmented``, which has no VJP); every other call goes through
+    the differentiable kernel pair at any length, as it always has.
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
@@ -683,11 +690,60 @@ def flash_attention(q, k, v, causal: bool = False, scale: Optional[float] = None
     if not use_pallas:
         return mha_reference(q, k, v, causal=causal, scale=scale,
                              window=window)
+    if forward_only and causal and t == tk and tk > FLASH_MAX_KEYS:
+        return _flash_segmented(q, k, v, float(scale), window,
+                                bool(interpret))
     cfg = _FlashCfg(causal=bool(causal), scale=float(scale),
                     block_q=block_q, block_k=block_k,
                     interpret=bool(interpret),
                     window=None if window is None else int(window))
     return _flash(cfg, q, k, v)
+
+
+#: keys one call of the forward kernel may hold where the caller states
+#: ``forward_only``: a KV head's K and V are whole in VMEM, and at 16,384
+#: keys of 128 channels Mosaic asked 48.5 MB of a v5e's 48
+FLASH_MAX_KEYS = 8192
+
+
+def _flash_segmented(q, k, v, scale: float, window: Optional[int],
+                     interpret: bool):
+    """Causal self-attention of a sequence longer than ``FLASH_MAX_KEYS``
+    (forward only, no VJP: the serving path's long prompts, which ask for it
+    with ``flash_attention(forward_only=True)``): the sequence is cut
+    into equal segments of at most that many positions, segment ``i``'s
+    queries attend each segment ``j <= i`` of the keys their window reaches
+    through the forward kernel at the static offset ``(i - j) * segment``,
+    and the normalized partials are merged by their log-sum-exps, in
+    float32."""
+    t = q.shape[1]
+    n = -(-t // FLASH_MAX_KEYS)
+    seg = min(_round_up(-(-t // n), 512), FLASH_MAX_KEYS)
+    outs = []
+    for i in range(n):
+        cut = lambda x, a: x[:, a * seg:(a + 1) * seg]
+        qi = cut(q, i)
+        first = 0 if window is None else max(
+            0, (i * seg - (window - 1)) // seg)
+        o = lse = None
+        for j in range(first, i + 1):
+            kj, vj = cut(k, j), cut(v, j)
+            bq, bk = _flash_tiles(qi.shape[1], kj.shape[1], q.shape[-1],
+                                  q.dtype.itemsize)
+            oj, lj = _flash_forward(
+                _FlashCfg(causal=True, scale=scale, block_q=bq, block_k=bk,
+                          interpret=interpret, window=window,
+                          q_offset=(i - j) * seg), qi, kj, vj)
+            oj = oj.astype(jnp.float32)
+            if o is None:
+                o, lse = oj, lj
+            else:       # weights [B, T, H, 1] from lse [B, H, T, 1]
+                new = jnp.logaddexp(lse, lj)
+                o = (o * jnp.exp(lse - new).transpose(0, 2, 1, 3)
+                     + oj * jnp.exp(lj - new).transpose(0, 2, 1, 3))
+                lse = new
+        outs.append(o.astype(q.dtype))
+    return jnp.concatenate(outs, axis=1)
 
 
 def _causal_with_lse(q, k, v, scale: float, interpret: bool = False):

@@ -9,6 +9,8 @@ preserved on the output.
 from __future__ import annotations
 
 import functools
+import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -28,19 +30,58 @@ def swiglu(x, w_gate, w_up, w_down):
     return (g * (x @ w_up)) @ w_down
 
 
-def rope(x, positions, theta: float = 10000.0):
+def yarn_inv_freq(rotary_dim: int, theta: float, factor: float,
+                  original_max: int, beta_fast: float = 32.0,
+                  beta_slow: float = 1.0):
+    """YaRN's inverse frequencies for ``rotary_dim`` rotated channels
+    (``rotary_dim // 2`` of them, float32, computed on the host): channel
+    pair ``i`` of plain rope turns at ``f_i = theta ** (-2 i / D)``; the pairs
+    that turn more than ``beta_fast`` times within ``original_max`` positions
+    keep ``f_i``, those that turn fewer than ``beta_slow`` times get ``f_i /
+    factor`` (interpolated), and a linear ramp over the pair index joins
+    them (Peng et al., arXiv:2309.00071)."""
+    import numpy as np
+    d = rotary_dim
+    f = theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
+
+    def pair_of(turns):     # the pair that makes ``turns`` turns in the span
+        return d * math.log(original_max / (2 * math.pi * turns)) / (
+            2 * math.log(theta))
+
+    lo = max(math.floor(pair_of(beta_fast)), 0)
+    hi = min(math.ceil(pair_of(beta_slow)), d - 1)
+    ramp = np.clip((np.arange(d // 2, dtype=np.float64) - lo)
+                   / max(hi - lo, 1e-3), 0.0, 1.0)
+    return (f / factor * ramp + f * (1.0 - ramp)).astype(np.float32)
+
+
+def rope(x, positions, theta: float = 10000.0, *, inv_freq=None,
+         rotary_dim: Optional[int] = None, factor: float = 1.0):
     """Rotary position embedding over the last (head_dim) axis.
 
-    ``x``: [..., T, H, D]; ``positions``: [..., T] int32.
+    ``x``: [..., T, H, D]; ``positions``: [..., T] int32.  What a layer kind
+    states beyond ``theta``: ``inv_freq`` [rotary_dim // 2] float32, the
+    inverse frequencies as data (:func:`yarn_inv_freq`; None: ``theta``'s);
+    ``rotary_dim``, the FIRST so many of a head's channels are rotated
+    (paired first half with second half inside them) and the rest pass
+    through (None: all); ``factor`` multiplies cos and sin both (YaRN's
+    attention factor).
     """
-    d = x.shape[-1]
+    d = x.shape[-1] if rotary_dim is None else rotary_dim
     half = d // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if inv_freq is None:
+        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    else:
+        freqs = jnp.asarray(inv_freq, jnp.float32)
     angles = positions[..., None].astype(jnp.float32) * freqs  # [..., T, half]
     cos = jnp.cos(angles)[..., None, :]  # broadcast over heads
     sin = jnp.sin(angles)[..., None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
+    x1, x2 = x[..., :half], x[..., half:d]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos,
+                           *([] if d == x.shape[-1] else [x[..., d:]])],
+                          axis=-1)
     return out.astype(x.dtype)
 
 

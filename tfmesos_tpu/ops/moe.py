@@ -47,6 +47,16 @@ def pick_tile(assignments: int, n_experts: int) -> int:
     return tile
 
 
+def tile_rows(counts, tokens: int, top_k: int, n_experts: int):
+    """Rows of the live tiles a step of ``tokens`` tokens is padded to:
+    ``counts`` [..., held] assignments an expert, each rounded up to whole
+    tiles of the step's ``pick_tile`` as ``grouped_layout`` lays them out
+    (``live_tiles * tile`` there; a test holds the two together).  What a
+    caller that has only the counts reports as the kernels' rows."""
+    tile = pick_tile(tokens * top_k, n_experts)
+    return jnp.sum(-(-counts // tile) * tile)
+
+
 def grouped_layout(top_idx, held: int, offset, tile: int):
     """Where each assignment goes.  ``top_idx``: [T, k] expert ids over ALL
     experts; this shard holds experts ``offset .. offset + held - 1``

@@ -57,6 +57,7 @@ from tfmesos_tpu.models.transformer import (PageAllocator, TransformerConfig,
                                             greedy_accept_counts,
                                             init_paged_cache,
                                             rejection_accept, sample_logits)
+from tfmesos_tpu.ops.moe import tile_rows as moe_tile_rows
 from tfmesos_tpu.ops.quant import QTensor
 from tfmesos_tpu.utils.profiling import annotate
 
@@ -116,12 +117,20 @@ BYPASS_ALLOWLIST = {
     # everything built on "a row is its pages" is closed with ONE reason
     # string, "recurrent row state".  The pipelined carry composes: the
     # state store rides the donated pool through every block.)
+    # (and with a sliding-window ring — a typed stack's "window" layers —
+    # a row is its pages AND, a window layer, the K/V of its last ``window``
+    # positions in a ring no page holds: the positions behind the window
+    # are gone, so a row can be neither rebuilt from pages nor cut back nor
+    # shared from a prefix's pages.  The same surfaces close with ONE reason
+    # of their own, "sliding-window ring"; a stack that keeps a recurrent
+    # state too gives that reason.  The carry composes as above.)
     "prefix_cache": ("quantized kv cache", "eva summary pages",
-                     "recurrent row state"),
+                     "recurrent row state", "sliding-window ring"),
     # Mesh data shards pin pages locally (no single-shard scatter to
     # move), and the int8 tail recompute above breaks resume==cold.
     "kv_tier": ("mesh data sharding", "quantized kv cache",
-                "eva summary pages", "recurrent row state"),
+                "eva summary pages", "recurrent row state",
+                "sliding-window ring"),
     # The pipelined carry (tokens, positions, steps on device, one
     # block of lag) has no speculative form: a round's commit counts
     # decide the next round's positions, and _step_spec reads them on
@@ -133,7 +142,8 @@ BYPASS_ALLOWLIST = {
     # lags one block behind, and mesh data shards pin pages locally
     # like the kv_tier/export surface.
     "suspend": ("mesh data sharding", "lagged decode carry",
-                "eva summary pages", "recurrent row state"),
+                "eva summary pages", "recurrent row state",
+                "sliding-window ring"),
     # Stall-free fused prefill+decode ticks (one dispatch covers the
     # decode block AND a budgeted batch of prefill chunk slots).  Mesh
     # data shards dispatch chunks one-hot per shard (the fused slot
@@ -152,8 +162,10 @@ BYPASS_ALLOWLIST = {
     # multi-byte self-speculation a later PR routes through _step_spec.
     # A recurrent row state closes both too: a rejected draft token cannot
     # be taken back out of a state, and a KV artifact carries pages only.
-    "speculative": ("eva summary pages", "recurrent row state"),
-    "kv_export": ("eva summary pages", "recurrent row state"),
+    "speculative": ("eva summary pages", "recurrent row state",
+                    "sliding-window ring"),
+    "kv_export": ("eva summary pages", "recurrent row state",
+                  "sliding-window ring"),
 }
 
 
@@ -163,7 +175,8 @@ def compute_bypass_reasons(*, speculative: bool = False,
                            draft_quantized_cache: bool = False,
                            pipeline_depth: int = 0,
                            eva: bool = False,
-                           recurrent: bool = False
+                           recurrent: bool = False,
+                           window: bool = False
                            ) -> Dict[str, Optional[str]]:
     """The ``*_bypass_reason`` values a :class:`ContinuousBatcher`
     built from these mode flags records — ONE pure function, used by
@@ -171,6 +184,9 @@ def compute_bypass_reasons(*, speculative: bool = False,
     enumerate every reachable config without building batchers.  Keys
     mirror :data:`BYPASS_ALLOWLIST`; ``None`` = the feature composes."""
     quant = quantized_cache or (speculative and draft_quantized_cache)
+    # what a row keeps beside its pages (``init_row_state``), as a reason
+    row_state = ("recurrent row state" if recurrent
+                 else "sliding-window ring" if window else None)
     out: Dict[str, Optional[str]] = {
         "prefix_cache": None, "kv_tier": None, "pipeline": None,
         "suspend": None, "fused_prefill": None, "speculative": None,
@@ -178,17 +194,17 @@ def compute_bypass_reasons(*, speculative: bool = False,
     if eva:
         out["prefix_cache"] = out["speculative"] = out["kv_export"] = \
             "eva summary pages"
-    elif recurrent:
+    elif row_state:
         out["prefix_cache"] = out["speculative"] = out["kv_export"] = \
-            "recurrent row state"
+            row_state
     elif quant:
         out["prefix_cache"] = "quantized kv cache"
     if n_shards != 1:
         out["kv_tier"] = "mesh data sharding"
     elif eva:
         out["kv_tier"] = "eva summary pages"
-    elif recurrent:
-        out["kv_tier"] = "recurrent row state"
+    elif row_state:
+        out["kv_tier"] = row_state
     elif quant:
         out["kv_tier"] = "quantized kv cache"
     if pipeline_depth and speculative:
@@ -199,8 +215,8 @@ def compute_bypass_reasons(*, speculative: bool = False,
         out["suspend"] = "mesh data sharding"
     elif eva:
         out["suspend"] = "eva summary pages"
-    elif recurrent:
-        out["suspend"] = "recurrent row state"
+    elif row_state:
+        out["suspend"] = row_state
     elif pipelined:
         out["suspend"] = "lagged decode carry"
     if n_shards != 1:
@@ -1633,8 +1649,12 @@ class ContinuousBatcher:
         # Rows that keep a recurrent state beside their pages (a typed
         # stack's mamba or kda layers): the row-slot state store lives in
         # the donated pool (``pool["state"]``, init_row_state).
+        # A window layer's ring is such a state too, with a reason of its
+        # own in the registries where no recurrent state gives one.
         recurrent = cfg.keeps_row_state
         self._recurrent = recurrent
+        modes = dict(eva=eva, window=cfg.n_window_layers > 0,
+                     recurrent=cfg.n_mamba_layers + cfg.n_kda_layers > 0)
         if pipeline_depth is None:
             # The lag policy left to the batcher (what ``fleet/replica.py``
             # passes when ``--pipeline-depth`` is not given): the carry
@@ -1647,13 +1667,13 @@ class ContinuousBatcher:
             # block).  A plain stack keeps its suspend and stays
             # synchronous.
             pipeline_depth = int(compute_bypass_reasons(
-                eva=eva, recurrent=recurrent)["suspend"] is not None)
+                **modes)["suspend"] is not None)
         self.pipeline_depth = int(pipeline_depth)
         self._bypass = compute_bypass_reasons(
             speculative=draft_cfg is not None, n_shards=self.n_shards,
             quantized_cache=quantized_cache,
             draft_quantized_cache=draft_quantized_cache,
-            pipeline_depth=pipeline_depth, eva=eva, recurrent=recurrent)
+            pipeline_depth=pipeline_depth, **modes)
         if cfg.layer_types is not None:
             # What a typed stack cannot do yet is refused here, before any
             # device state exists (the registries above bypass the rest).
@@ -1766,11 +1786,17 @@ class ContinuousBatcher:
         if recurrent:
             from tfmesos_tpu.models.transformer import init_row_state
             self.t_side.pool["state"] = init_row_state(cfg, rows)
+        #: bytes the row slots hold beside the pages, whatever the contexts
+        #: (recurrent states, window layers' rings)
+        self.row_state_bytes = sum(
+            leaf.nbytes for leaf in self.t_side.pool.get("state", {}).values())
         # the grouped expert layer counts its assignments per held expert;
         # a block's sums ride back with its tokens (see _make_decode)
         self._moe_counts = bool(cfg.n_experts) and \
             cfg.moe_impl == "grouped" and cfg.layer_types is not None
         self._state_rows = 0            # slots that hold a live state
+        #: the window of a typed stack's "window" layers (0: none)
+        self._swa = cfg.window if cfg.n_window_layers else 0
         if mesh is not None:
             from tfmesos_tpu.models.transformer import partition_specs
             self.params = self._place(params, partition_specs(cfg, mesh))
@@ -2008,8 +2034,14 @@ class ContinuousBatcher:
             # assignments that fell on held experts in this tick's block,
             # the most any one expert (of any layer) took of them, and the
             # held experts of every layer and step that took at least one
+            # and the rows of the live tiles those assignments were padded
+            # to (each (step, layer, expert)'s up to whole tiles)
             rec.update(moe_assignments=0, moe_expert_max=0,
-                       moe_experts_touched=0)
+                       moe_experts_touched=0, moe_tile_rows=0)
+        if self._swa:
+            # a decode block's rows: the contexts they reach, and the
+            # positions of them a window layer's ring holds
+            rec.update(ctx_positions=0, swa_positions=0)
         return rec
 
     def _tick_roll(self, more: bool = True) -> None:
@@ -2103,24 +2135,30 @@ class ContinuousBatcher:
         return _Phase(self._tick, name, stats)
 
     def _tick_moe(self, block: np.ndarray) -> np.ndarray:
-        """A block as read back, ``[rows (+ 3), K]``: with a grouped expert
-        layer its last three rows are the block's expert counters
-        (``[assignments, most one expert took, experts touched]``, computed
-        beside its tokens), which go into the tick record.  Returns the
-        tokens, ``[rows, K]``."""
+        """A block as read back, ``[rows (+ 4), K]``: with a grouped expert
+        layer its last four rows are the block's expert counters
+        (``[assignments, most one expert took, experts touched, rows of the
+        live tiles]``, computed beside its tokens), which go into the tick
+        record.  Returns the tokens, ``[rows, K]``."""
         if self._moe_counts:
-            a, m, n = block[self.rows:, 0]
+            a, m, n, tr = block[self.rows:, 0]
             self._tick["moe_assignments"] += int(a)
             self._tick["moe_expert_max"] = max(self._tick["moe_expert_max"],
                                                int(m))
             self._tick["moe_experts_touched"] += int(n)
+            self._tick["moe_tile_rows"] += int(tr)
         return block[:self.rows]
 
-    def _tick_block(self, mode: str, rows: int, k: int) -> None:
-        """This tick ran a decode block (or speculative round)."""
+    def _tick_block(self, mode: str, rows: int, k: int, ctx=()) -> None:
+        """This tick ran a decode block (or speculative round); ``ctx``:
+        the contexts its rows reach with it."""
         t = self._tick
         t["name"], t["mode"], t["rows"], t["k"] = "decode.block", mode, \
             rows, k
+        if self._swa:
+            ctx = list(ctx)
+            t["ctx_positions"] += sum(ctx)
+            t["swa_positions"] += sum(min(c, self._swa) for c in ctx)
 
     def _stamp_first(self, state: _Row) -> None:
         """``state``'s first token has reached the host."""
@@ -2554,16 +2592,20 @@ class ContinuousBatcher:
                 body, (pool, tok0, positions, steps), None, length=K)
             if moe_counts:
                 # [assignments on held experts, the most one expert took,
-                # (step, layer, expert)s that took any] over the block, as
-                # three rows under the tokens: ONE array comes back to the
+                # (step, layer, expert)s that took any, the rows of the
+                # tiles they filled (``grouped_layout`` pads each expert's
+                # to whole tiles)] over the block, as four rows under the
+                # tokens: ONE array comes back to the
                 # host (a second one is a second round trip every tick)
                 toks_all, counts = toks_all             # [K, L, held]
                 touched = jnp.sum(counts > 0)
+                tile_rows = moe_tile_rows(counts, self.rows, self.cfg.top_k,
+                                          self.cfg.n_experts)
                 counts = jnp.sum(counts, axis=0)
                 stats = jnp.stack([jnp.sum(counts), jnp.max(counts),
-                                   touched]).astype(jnp.int32)
+                                   touched, tile_rows]).astype(jnp.int32)
                 return pool, jnp.concatenate(
-                    [toks_all.T, jnp.broadcast_to(stats[:, None], (3, K))])
+                    [toks_all.T, jnp.broadcast_to(stats[:, None], (4, K))])
             return pool, toks_all.T                         # [rows, K]
 
         if self._pipelined:
@@ -5112,7 +5154,8 @@ class ContinuousBatcher:
                 jnp.asarray(steps))
         with self._phase("batcher.readback"):
             nxt = self._tick_moe(np.asarray(nxt))   # ONE host sync a block
-        self._tick_block("sync", len(decoding), K)
+        self._tick_block("sync", len(decoding), K,
+                         (row.pos + K for row in decoding.values()))
         finished = []
         with self._phase("batcher.retire"):
             for r in list(decoding):
@@ -5241,7 +5284,8 @@ class ContinuousBatcher:
                         # row that stops on a token still in flight gets a
                         # close it did not need, over pages of its own.
                         self._eva_roll_row(r, row.pos // eva_w - 1)
-            self._tick_block("pipelined", len(dispatch), K)
+            self._tick_block("pipelined", len(dispatch), K,
+                             (row.pos for row in dispatch.values()))
         else:
             self._inflight = None
             self._pipe_carry = self._pipe_host = None
