@@ -1827,3 +1827,17 @@ def test_page_allocator_randomized_stress():
         used = [p for r in alloc.rows.values() for p in r]
         assert len(used) == len(set(used))          # no sharing
         assert len(used) + len(alloc.free) == 64    # exact accounting
+
+
+def test_split_heads_is_a_reshape_behind_a_barrier():
+    """``_split_heads``: the values and gradient of a plain reshape, and the
+    barrier that keeps the TPU compiler from folding the split into the
+    projection's dot (``tests/test_tpu_compile.py`` holds what that buys)."""
+    y = jnp.arange(2 * 3 * 8, dtype=jnp.float32).reshape(2, 3, 8)
+    out = transformer._split_heads(y, 4)
+    assert out.shape == (2, 3, 4, 2)
+    np.testing.assert_array_equal(out, y.reshape(2, 3, 4, 2))
+    grad = jax.grad(lambda a: (transformer._split_heads(a, 4) ** 2).sum())(y)
+    np.testing.assert_array_equal(grad, 2 * y)
+    text = jax.jit(lambda a: transformer._split_heads(a, 4)).lower(y).as_text()
+    assert "optimization_barrier" in text

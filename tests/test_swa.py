@@ -472,22 +472,23 @@ MOE = dict(n_experts=8, top_k=2, moe_impl="grouped", experts_held=4,
            shared_d_ff=16, norm_eps=1e-5, logits_dtype=F32, rope=False)
 #: tiny stacks of the four accepted configurations' kinds, and the sha256
 #: (first 16 hex digits) of their decode and prefill programs' lowered text
-#: at the parent of PR 42 (commit 42f25c0), taken with this file's code
+#: at PR 44 (whose ``_split_heads`` barrier is in every stack's projections),
+#: taken with this file's code
 BEFORE = {
-    "plain": (dict(n_layers=2), "38703966326ce104", "7025845fb8b160f5"),
+    "plain": (dict(n_layers=2), "64ad7cf673e148b8", "ba12817767abb966"),
     "eva": (dict(n_layers=2, attention="eva", eva_chunk=2, eva_window=16,
                  n_pred_heads=2, residual_dtype=F32, logits_dtype=F32),
-            "be408f0934aa47cb", "8e5c5191498dddd2"),
+            "f3b30e9b8df5c8af", "f00af4f23c22b7fe"),
     "granite": (dict(n_layers=4, layer_types=("mamba",) * 3 + ("attention",),
                      mamba_heads=4, mamba_head_dim=8, mamba_state=8,
                      mamba_chunk=8, embed_scale=2.0, residual_scale=0.5,
                      logits_scale=3.0, tie_embeddings=True, **MOE),
-                "423b3a39e44b92f0", "b24ec3a6463a7ee7"),
+                "ceb3855bcd0409eb", "14b4081dff63eb58"),
     "solar": (dict(n_layers=4, layer_types=("attention",) + ("kda",) * 3,
                    kda_heads=2, kda_head_dim=8, kda_chunk=8,
                    kda_neg_eigval=True, attn_gate=True, attn_head_dim=8,
                    router_score="sigmoid", routed_scale=1.5, **MOE),
-              "7a61167a17e85c8c", "f98e0c087fce0205"),
+              "07188a8e83e898dd", "25b704156d285dbd"),
 }
 
 
@@ -522,7 +523,7 @@ def test_earlier_stacks_lower_to_the_same_text(name, what):
     got = hashlib.sha256(low.as_text().encode()).hexdigest()[:16]
     assert got == (decode if what == "decode" else prefill), (
         f"the {name} stack's {what} program lowers to other text than at "
-        f"PR 42's parent: what was changed reaches a stack it should not")
+        f"PR 44: what was changed reaches a stack it should not")
 
 
 # -- the expert layer's layout at many small experts ------------------------

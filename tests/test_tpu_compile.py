@@ -254,14 +254,140 @@ def test_kernel_compiles_for_v5e(topo, name):
     assert compiled.as_text().count("tpu_custom_call") >= n_kernels
 
 
+# -- whole steps at the benchmark cells' widths --------------------------------
+#
+# ``decode_step`` of each benchmark configuration, compiled whole for the
+# described v5e with the kernel paths forced (the gates ask
+# ``jax.default_backend()``, which is the CPU here), over donated caches at the
+# cells' sizes.  name -> (the program's configuration, pages in the pool, row
+# slots of recurrent state or rings (0: a plain stack), the gates to force).
+# A step is compiled once a file whichever tests read it.
+
+def _step_models():
+    from tfmesos_tpu.models import transformer
+    cfg = partial(transformer.TransformerConfig, dtype=BF16, param_dtype=BF16)
+    return {
+        # Mistral-7B's widths, 16 layers (a small pool is placed in another
+        # memory space and says nothing)
+        "mistral": (cfg(
+            vocab_size=32768, d_model=4096, n_layers=16, n_heads=32,
+            n_kv_heads=8, d_ff=14336, max_seq_len=8192, rope_theta=1e6),
+            1300, 0, ("attend",)),
+        # EvaByte's: 32 heads of 128 over 32 K/V heads, a float32 stream
+        "evabyte": (cfg(
+            vocab_size=320, d_model=4096, n_layers=16, n_heads=32,
+            n_kv_heads=32, d_ff=11008, max_seq_len=32768, rope_theta=1e5,
+            attention="eva", eva_chunk=16, eva_window=2048, norm_eps=1e-5,
+            norm_offset=True, residual_dtype=F32, logits_dtype=F32,
+            n_pred_heads=8),
+            372, 0, ("backend",)),
+        # Granite-4.0-H-Small's, one period: 9 Mamba-2 layers and one
+        # attention layer, 36 of 72 experts held
+        "granite": (cfg(
+            vocab_size=100352, d_model=4096, n_layers=10, n_heads=32,
+            n_kv_heads=8, d_ff=768, max_seq_len=8192,
+            layer_types=("mamba",) * 5 + ("attention",) + ("mamba",) * 4,
+            mamba_heads=128, mamba_head_dim=64, mamba_state=128, rope=False,
+            attn_scale=0.0078125, embed_scale=12.0, residual_scale=0.22,
+            logits_scale=16.0, tie_embeddings=True, norm_eps=1e-5,
+            logits_dtype=F32, n_experts=72, top_k=10, moe_impl="grouped",
+            experts_held=36, shared_d_ff=1536),
+            4096, 64, ("attend", "moe", "ssm")),
+        # Solar-Open2's, one period ``a k k k``, 40 of 320 experts held, a
+        # sliced vocabulary
+        "solar": (cfg(
+            vocab_size=24576, d_model=4096, n_layers=4, n_heads=64,
+            n_kv_heads=8, attn_head_dim=128, d_ff=1280, max_seq_len=8192,
+            layer_types=("attention", "kda", "kda", "kda"), kda_heads=64,
+            kda_head_dim=128, kda_neg_eigval=True, rope=False, attn_gate=True,
+            norm_eps=1e-5, logits_dtype=F32, n_experts=320, top_k=8,
+            moe_impl="grouped", experts_held=40, shared_d_ff=1280,
+            router_score="sigmoid"),
+            9216, 192, ("attend", "moe", "kda")),
+        # Laguna-XS.2's: [full | window x 3 | full] behind a leading dense
+        # layer, 256 experts of 512 all held
+        "laguna": (cfg(
+            vocab_size=100352, d_model=2048, n_layers=5, n_heads=48,
+            n_kv_heads=8, attn_head_dim=128, d_ff=8192, max_seq_len=17408,
+            layer_types=("attention", "window", "window", "window",
+                         "attention"),
+            window=512, window_heads=64,
+            window_rope=transformer.RopeSpec(theta=10000.0),
+            attn_rope=transformer.RopeSpec(theta=500000.0, fraction=0.5,
+                                           yarn=(64.0, 4096, 64.0, 1.0)),
+            ffn_types=("dense",) + ("sparse",) * 4, expert_d_ff=512,
+            attn_gate="head", norm_eps=1e-6, logits_dtype=F32, n_experts=256,
+            top_k=8, moe_impl="grouped", shared_d_ff=512,
+            router_score="sigmoid", routed_scale=2.5),
+            7168, 128, ("attend", "moe", "backend")),
+    }
+
+
+_STEPS = {}
+
+
+def _compiled_step(topo, model, rows, t, width, start=None, int8=False):
+    """``(cfg, params, compiled, text)`` of ``model``'s ``decode_step`` over
+    ``rows`` x ``t`` tokens and a page table ``width`` wide; ``params`` are
+    the parameters' shapes.  ``start``: ``"ragged"`` a traced [rows] vector
+    (what ``t == 1`` defaults to), ``"traced"`` a traced scalar, or a static
+    int (``t > 1``: 0, a prefill from an empty cache)."""
+    from tfmesos_tpu.models import transformer
+    from tfmesos_tpu.ops import attention, kda, moe, ssm
+
+    if start is None:
+        start = "ragged" if t == 1 else 0
+    key = (model, rows, t, width, start, int8)
+    if key in _STEPS:
+        return _STEPS[key]
+    cfg, n_pages, slots, gates = _step_models()[model]
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def struct(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.eval_shape(
+        lambda: transformer.init_params(cfg, jax.random.PRNGKey(0)))
+    cache = dict(jax.eval_shape(lambda: transformer.init_paged_cache(
+        cfg, n_pages, PAGE, quantized=int8)))
+    if slots:
+        cache["state"] = jax.eval_shape(
+            lambda: transformer.init_row_state(cfg, slots))
+        if t > 1:
+            cache["slots"] = jnp.zeros((rows,), I32)
+            cache["valid"] = jnp.zeros((rows,), I32)
+    cache["pages"] = jnp.zeros((rows, width), I32)
+    tokens = jax.ShapeDtypeStruct((rows, t), I32, sharding=one_chip)
+    # A static start is closed over; a traced one is the step's last argument.
+    traced = () if isinstance(start, int) else (jax.ShapeDtypeStruct(
+        (rows,) if start == "ragged" else (), I32, sharding=one_chip),)
+    step = jax.jit(lambda p, c, tok, *pos: transformer.decode_step(
+        cfg, p, c, tok, *(pos or (start,))), donate_argnums=1)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(transformer, "_decode_kernel_kwargs",
+                   lambda *a, **k: {"use_pallas": True})
+        if "attend" in gates:
+            mp.setattr(transformer, "attend",
+                       partial(attention.attend, use_pallas=True))
+        if "backend" in gates:
+            # the chunk's own part through the flash kernel and the window
+            # mixer's flash_decode, as on the chip: by the backend
+            mp.setattr(attention.jax, "default_backend", lambda: "tpu")
+        for name, mod in (("moe", moe), ("ssm", ssm), ("kda", kda)):
+            if name in gates:
+                mp.setattr(mod, "_on_tpu", lambda use: True)
+        compiled = step.lower(
+            *jax.tree_util.tree_map(struct, (params, cache)), tokens,
+            *traced).compile()
+    _STEPS[key] = cfg, params, compiled, compiled.as_text()
+    return _STEPS[key]
+
+
 # -- the deferred K/V commit keeps the pool's layout (PR 25) ------------------
 #
-# ``decode_step`` over a donated page pool, compiled whole for the described
-# v5e with the kernel path forced, at the widths of the benchmark's cells
-# (Mistral-7B's, 16 layers, 1300 pages of 64, 32 rows, table width 128: a
-# small pool is placed in another memory space and says nothing).  The commit
-# after the layer scan (``_paged_cache_write_all``) must scatter into the
-# pool in the pool's own layout: a scatter the TPU compiler gives another
+# Mistral's step over its donated page pool (1300 pages of 64, 32 rows, table
+# width 128).  The commit after the layer scan (``_paged_cache_write_all``)
+# must scatter into the pool in the pool's own layout: a scatter the TPU compiler gives another
 # operand layout comes wrapped in two copies of the WHOLE pool per leaf,
 # which cost 33 ms of a 53 ms decode block on the chip.  name -> (int8 pool,
 # rows, t, start position: "ragged" a traced [B] vector, "traced" a traced
@@ -276,39 +402,11 @@ COMMIT_CASES = {
 
 
 @pytest.mark.parametrize("name", sorted(COMMIT_CASES))
-def test_paged_commit_never_relayouts_the_pool(topo, monkeypatch, name):
-    from tfmesos_tpu.models import transformer
-    from tfmesos_tpu.ops.attention import attend
-
+def test_paged_commit_never_relayouts_the_pool(topo, name):
     quantized, rows, t, start = COMMIT_CASES[name]
-    cfg = transformer.TransformerConfig(
-        vocab_size=32768, d_model=4096, n_layers=16, n_heads=32,
-        n_kv_heads=8, d_ff=14336, max_seq_len=8192, rope_theta=1e6,
-        dtype=BF16, param_dtype=BF16)
-    n_pages, width = 1300, 128
-    # The gates ask jax.default_backend(), which is the CPU here.
-    monkeypatch.setattr(transformer, "_decode_kernel_kwargs",
-                        lambda *a, **k: {"use_pallas": True})
-    monkeypatch.setattr(transformer, "attend",
-                        partial(attend, use_pallas=True))
-    one_chip = SingleDeviceSharding(topo.devices[0])
-
-    def struct(x):
-        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
-
-    params = jax.tree_util.tree_map(struct, jax.eval_shape(
-        lambda: transformer.init_params(cfg, jax.random.PRNGKey(0))))
-    cache = jax.tree_util.tree_map(struct, jax.eval_shape(
-        lambda: dict(transformer.init_paged_cache(cfg, n_pages, PAGE,
-                                                  quantized=quantized),
-                     pages=jnp.zeros((rows, width), I32))))
-    tokens = jax.ShapeDtypeStruct((rows, t), I32, sharding=one_chip)
-    # A static start is closed over; a traced one is the step's last argument.
-    traced = () if isinstance(start, int) else (jax.ShapeDtypeStruct(
-        (rows,) if start == "ragged" else (), I32, sharding=one_chip),)
-    step = jax.jit(lambda p, c, tok, *pos: transformer.decode_step(
-        cfg, p, c, tok, *(pos or (start,))), donate_argnums=1)
-    text = step.lower(params, cache, tokens, *traced).compile().as_text()
+    n_pages = _step_models()["mistral"][1]
+    cfg, _, _, text = _compiled_step(topo, "mistral", rows, t, 128, start,
+                                     int8=quantized)
     assert "tpu_custom_call" in text            # the kernel path was taken
     assert " scatter(" in text                  # and the commit is in there
     # The K/V leaf ([L, P, KV, page, Dh]; an int8 pool's ``values``).  The
@@ -320,6 +418,111 @@ def test_paged_commit_never_relayouts_the_pool(topo, monkeypatch, name):
     moved = re.findall(r"= " + re.escape(leaf)
                        + r"\S* (?:copy|transpose)\([^)]*\)", text)
     assert not moved, f"{leaf} is relayouted: {moved[:2]}"
+
+
+# -- no step moves a stacked weight (PR 44) ------------------------------------
+#
+# Every matmul reads its layer of a stacked parameter where the parameter
+# lies.  Where an elementwise op, a slice or a pad read a projection's heads,
+# the TPU compiler folded the head split into the dot, which then wanted the
+# weight as [heads, Dh, in]: each run of the program cut a layer of ``wq`` and
+# of ``wk`` out of the stack and wrote it again transposed (a table under 128
+# pages: inside the scan over layers; from 128 pages on, where the scan is
+# unrolled by two: the whole stack, hoisted), 1.0 ms of a 12.6 ms Mistral
+# decode block on the chip; every other configuration's steps did the same
+# to their attention, window or KDA projections.  The five
+# configurations' decode and prefill steps at their cells' widths, Mistral's
+# in both regimes.  name -> (model, rows, t, table width, start).
+WEIGHT_CASES = {
+    "mistral_decode_w32": ("mistral", 32, 1, 32, None),
+    "mistral_decode_w128": ("mistral", 32, 1, 128, None),
+    "mistral_prefill_t704_w32": ("mistral", 1, 704, 32, None),
+    "mistral_prefill_t704_w128": ("mistral", 1, 704, 128, None),
+    "evabyte_decode": ("evabyte", 16, 1, 62, None),
+    "evabyte_prefill_window_2048": ("evabyte", 1, 2048, 62, "traced"),
+    "granite_decode": ("granite", 64, 1, 128, None),
+    "granite_prefill_t1024": ("granite", 1, 1024, 128, None),
+    "solar_decode": ("solar", 192, 1, 128, None),
+    "solar_prefill_t1024": ("solar", 1, 1024, 128, None),
+    "laguna_decode": ("laguna", 128, 1, 17408 // PAGE, None),
+    "laguna_prefill_t448": ("laguna", 1, 448, 17408 // PAGE, None),
+}
+
+
+def _weight_moves(params, text, min_bytes=1 << 20):
+    """The instructions of the compiled ``text`` that copy, transpose or
+    materialise a stacked weight of ``params["layers"]``, a layer of it or
+    the whole stack: a top-level ``copy`` / ``transpose`` one of whose
+    operands has the shape of the stack ``[L, ...]`` or of one layer (``[1,
+    ...]`` or ``[...]``), and a top-level fusion that reads one such shape
+    and results in one (a slice written out; a dot reads one and results in
+    activations).  Matrices of at least ``min_bytes`` a layer; instructions
+    inside a fusion's computation are not the program's."""
+    views = set()
+    for leaf in jax.tree_util.tree_leaves(params["layers"]):
+        if leaf.ndim < 3 or (leaf.size // leaf.shape[0]
+                             * leaf.dtype.itemsize) < min_bytes:
+            continue
+        dt = {"bfloat16": "bf16", "float32": "f32"}[str(leaf.dtype)]
+        rest = ",".join(map(str, leaf.shape[1:]))
+        views |= {f"{dt}[{leaf.shape[0]},{rest}]", f"{dt}[1,{rest}]",
+                  f"{dt}[{rest}]"}
+    shape_of = dict(re.findall(r"%([\w.\-]+) = (\w+\[[\d,]*\])", text))
+    fused = set(re.findall(r" fusion\(.*?calls=%([\w.\-]+)", text))
+    moves, inside = [], False
+    for ln in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", ln)
+        if head:
+            inside = head.group(1) in fused
+            continue
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (\w+\[[\d,]*\])\S* "
+                     r"(copy|transpose|fusion)\(([^)]*)\)", ln)
+        if inside or not m:
+            continue
+        result, op, operands = m.groups()
+        reads = any(shape_of.get(o) in views
+                    for o in re.findall(r"%([\w.\-]+)", operands))
+        if reads and (op != "fusion" or result in views):
+            moves.append(ln.strip()[:160])
+    return moves
+
+
+@pytest.mark.parametrize("name", sorted(WEIGHT_CASES))
+def test_step_never_copies_a_stacked_weight(topo, name):
+    model, rows, t, width, start = WEIGHT_CASES[name]
+    _, params, _, text = _compiled_step(topo, model, rows, t, width, start)
+    assert "tpu_custom_call" in text            # the kernel paths were taken
+    moves = _weight_moves(params, text)
+    assert not moves, f"{len(moves)} weight moves, e.g. {moves[:3]}"
+
+
+def test_weight_moves_reads_the_parents_program():
+    """The reader on the lines the parent's Mistral steps compiled to (the
+    per-layer form, the hoisted form) and on lines it must pass over."""
+    params = {"layers": {"wq": jax.ShapeDtypeStruct((16, 4096, 4096), BF16),
+                         "norm": jax.ShapeDtypeStruct((16, 4096), BF16)}}
+    sliced = ("  %gte.1 = bf16[16,4096,4096]{2,1,0} get-tuple-element(%t)\n"
+              "  %slice_fusion.6 = bf16[1,4096,4096]{2,1,0:T(8,128)(2,1)} "
+              "fusion(%gte.1, %i), kind=kLoop, calls=%fc.95\n"
+              "  %copy.130 = bf16[1,4096,4096]{1,2,0:T(8,128)(2,1)} "
+              "copy(%slice_fusion.6), metadata={}\n")
+    assert len(_weight_moves(params, sliced)) == 2
+    hoisted = ("  %p.1 = bf16[16,4096,4096]{2,1,0} parameter(9)\n"
+               "  %copy.177 = bf16[16,4096,4096]{1,2,0:T(8,128)(2,1)} "
+               "copy(%p.1), sharding={replicated}\n")
+    assert len(_weight_moves(params, hoisted)) == 1
+    clean = ("%fc.95 (a: bf16[16,4096,4096]) -> bf16[1,4096,4096] {\n"
+             "  %a = bf16[16,4096,4096]{2,1,0} parameter(0)\n"
+             "  %copy.3 = bf16[16,4096,4096]{2,1,0} copy(%a)\n"
+             "}\n"
+             "%body (t: (bf16[16,4096,4096])) -> bf16[32,4096] {\n"
+             "  %gte.1 = bf16[16,4096,4096]{2,1,0} get-tuple-element(%t)\n"
+             "  %fusion.7 = bf16[32,4096]{1,0} fusion(%gte.1, %x), "
+             "kind=kOutput, calls=%fc.95\n"
+             "  %x.1 = bf16[4096,4096]{1,0} fusion(%y, %z), kind=kLoop, "
+             "calls=%fc.96\n"
+             "}\n")
+    assert _weight_moves(params, clean) == []
 
 
 # -- a typed stack's state store and pool stay where they are (PR 32) ---------
@@ -336,55 +539,9 @@ def test_paged_commit_never_relayouts_the_pool(topo, monkeypatch, name):
 
 @pytest.mark.parametrize("name,rows,t", [("decode_r64", 64, 1),
                                          ("prefill_t1024", 1, 1024)])
-def test_typed_step_never_relayouts_the_state_store(topo, monkeypatch, name,
-                                                    rows, t):
-    from tfmesos_tpu.models import transformer
-    from tfmesos_tpu.ops import moe, ssm
-    from tfmesos_tpu.ops.attention import attend
-
-    kinds = ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
-    cfg = transformer.TransformerConfig(
-        vocab_size=100352, d_model=4096, n_layers=10, n_heads=32,
-        n_kv_heads=8, d_ff=768, max_seq_len=8192,
-        dtype=BF16, param_dtype=BF16, layer_types=kinds, mamba_heads=128,
-        mamba_head_dim=64, mamba_state=128, rope=False,
-        attn_scale=0.0078125, embed_scale=12.0, residual_scale=0.22,
-        logits_scale=16.0, tie_embeddings=True, norm_eps=1e-5,
-        logits_dtype=F32, n_experts=72, top_k=10, moe_impl="grouped",
-        experts_held=36, shared_d_ff=1536)
-    monkeypatch.setattr(transformer, "_decode_kernel_kwargs",
-                        lambda *a, **k: {"use_pallas": True})
-    monkeypatch.setattr(transformer, "attend",
-                        partial(attend, use_pallas=True))
-    monkeypatch.setattr(moe, "_on_tpu", lambda use: True)
-    monkeypatch.setattr(ssm, "_on_tpu", lambda use: True)
-    one_chip = SingleDeviceSharding(topo.devices[0])
-
-    def struct(x):
-        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
-
-    slots = 64
-    params = jax.tree_util.tree_map(struct, jax.eval_shape(
-        lambda: transformer.init_params(cfg, jax.random.PRNGKey(0))))
-    cache = dict(jax.eval_shape(
-        lambda: transformer.init_paged_cache(cfg, 4096, PAGE)))
-    cache["state"] = jax.eval_shape(
-        lambda: transformer.init_row_state(cfg, slots))
-    cache["pages"] = jnp.zeros((rows, 128), I32)
-    if t > 1:
-        cache["slots"] = jnp.zeros((rows,), I32)
-        cache["valid"] = jnp.zeros((rows,), I32)
-    cache = jax.tree_util.tree_map(struct, cache)
-    tokens = jax.ShapeDtypeStruct((rows, t), I32, sharding=one_chip)
-    if t == 1:
-        pos = (jax.ShapeDtypeStruct((rows,), I32, sharding=one_chip),)
-        step = jax.jit(lambda p, c, tok, at: transformer.decode_step(
-            cfg, p, c, tok, at), donate_argnums=1)
-    else:
-        pos = ()
-        step = jax.jit(lambda p, c, tok: transformer.decode_step(
-            cfg, p, c, tok, 0), donate_argnums=1)
-    text = step.lower(params, cache, tokens, *pos).compile().as_text()
+def test_typed_step_never_relayouts_the_state_store(topo, name, rows, t):
+    slots = _step_models()["granite"][2]
+    _, _, _, text = _compiled_step(topo, "granite", rows, t, 128)
     for kernel in ("moe_grouped_swiglu", "moe_grouped_matmul"):
         assert kernel in text, kernel
     assert ("flash_decode_paged" if t == 1 else "flash_attention_fwd") in text
@@ -425,56 +582,9 @@ def test_typed_step_never_relayouts_the_state_store(topo, monkeypatch, name,
 
 @pytest.mark.parametrize("name,rows,t", [("decode_r192", 192, 1),
                                          ("prefill_t1024", 1, 1024)])
-def test_kda_step_never_relayouts_the_state_store(topo, monkeypatch, name,
-                                                  rows, t):
-    from tfmesos_tpu.models import transformer
-    from tfmesos_tpu.ops import kda, moe
-    from tfmesos_tpu.ops.attention import attend
-
-    cfg = transformer.TransformerConfig(
-        vocab_size=24576, d_model=4096, n_layers=4, n_heads=64,
-        n_kv_heads=8, attn_head_dim=128, d_ff=1280, max_seq_len=8192,
-        dtype=BF16, param_dtype=BF16,
-        layer_types=("attention", "kda", "kda", "kda"), kda_heads=64,
-        kda_head_dim=128, kda_neg_eigval=True, rope=False, attn_gate=True,
-        norm_eps=1e-5, logits_dtype=F32, n_experts=320, top_k=8,
-        moe_impl="grouped", experts_held=40, shared_d_ff=1280,
-        router_score="sigmoid")
-    monkeypatch.setattr(transformer, "_decode_kernel_kwargs",
-                        lambda *a, **k: {"use_pallas": True})
-    monkeypatch.setattr(transformer, "attend",
-                        partial(attend, use_pallas=True))
-    monkeypatch.setattr(moe, "_on_tpu", lambda use: True)
-    monkeypatch.setattr(kda, "_on_tpu", lambda use: True)
-    one_chip = SingleDeviceSharding(topo.devices[0])
-
-    def struct(x):
-        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
-
-    slots, n_pages = 192, 9216
-    params = jax.tree_util.tree_map(struct, jax.eval_shape(
-        lambda: transformer.init_params(cfg, jax.random.PRNGKey(0))))
-    cache = dict(jax.eval_shape(
-        lambda: transformer.init_paged_cache(cfg, n_pages, PAGE)))
-    cache["state"] = jax.eval_shape(
-        lambda: transformer.init_row_state(cfg, slots))
-    assert set(cache["state"]) == {"kda_s", "kda_conv"}
-    cache["pages"] = jnp.zeros((rows, 128), I32)
-    if t > 1:
-        cache["slots"] = jnp.zeros((rows,), I32)
-        cache["valid"] = jnp.zeros((rows,), I32)
-    cache = jax.tree_util.tree_map(struct, cache)
-    tokens = jax.ShapeDtypeStruct((rows, t), I32, sharding=one_chip)
-    if t == 1:
-        pos = (jax.ShapeDtypeStruct((rows,), I32, sharding=one_chip),)
-        step = jax.jit(lambda p, c, tok, at: transformer.decode_step(
-            cfg, p, c, tok, at), donate_argnums=1)
-    else:
-        pos = ()
-        step = jax.jit(lambda p, c, tok: transformer.decode_step(
-            cfg, p, c, tok, 0), donate_argnums=1)
-    compiled = step.lower(params, cache, tokens, *pos).compile()
-    text = compiled.as_text()
+def test_kda_step_never_relayouts_the_state_store(topo, name, rows, t):
+    _, n_pages, slots, _ = _step_models()["solar"]
+    _, _, compiled, text = _compiled_step(topo, "solar", rows, t, 128)
     for kernel in ("moe_grouped_swiglu", "moe_grouped_matmul"):
         assert kernel in text, kernel
     assert ("flash_decode_paged" if t == 1 else "flash_attention_fwd") in text
@@ -537,42 +647,32 @@ def test_eva_programs_compile_and_keep_the_pool_in_place(topo, monkeypatch,
     from tfmesos_tpu.ops import attention
 
     what, rows, t = EVA_CASES[name]
-    cfg = transformer.TransformerConfig(
-        vocab_size=320, d_model=4096, n_layers=16, n_heads=32, n_kv_heads=32,
-        d_ff=11008, max_seq_len=32768, rope_theta=1e5, dtype=BF16,
-        param_dtype=BF16, attention="eva", eva_chunk=16, eva_window=2048,
-        norm_eps=1e-5, norm_offset=True, residual_dtype=F32,
-        logits_dtype=F32, n_pred_heads=8)
-    n_pages, width = 372, 62
+    cfg, n_pages, _, _ = _step_models()["evabyte"]
+    width = 62
     assert width == -(-cfg.cache_entries_peak(0, 32768) // PAGE)
-    monkeypatch.setattr(transformer, "_decode_kernel_kwargs",
-                        lambda *a, **k: {"use_pallas": True})
-    # the chunk's own part through the flash kernel, as on the chip
-    monkeypatch.setattr(attention.jax, "default_backend", lambda: "tpu")
-    one_chip = SingleDeviceSharding(topo.devices[0])
-
-    def struct(x):
-        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
-
-    params = jax.tree_util.tree_map(struct, jax.eval_shape(
-        lambda: transformer.init_params(cfg, jax.random.PRNGKey(0))))
-    pool = jax.tree_util.tree_map(struct, jax.eval_shape(
-        lambda: transformer.init_paged_cache(cfg, n_pages, PAGE)))
-    table = jax.ShapeDtypeStruct((rows, width), I32, sharding=one_chip)
-    scalar = jax.ShapeDtypeStruct((), I32, sharding=one_chip)
     if what == "close":
-        fn = jax.jit(lambda p, c, tb, w: transformer.eva_close_window(
-            cfg, p, c, tb, w), donate_argnums=1)
-        args = (params, pool, table, scalar)
+        one_chip = SingleDeviceSharding(topo.devices[0])
+
+        def struct(x):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+        params = jax.tree_util.tree_map(struct, jax.eval_shape(
+            lambda: transformer.init_params(cfg, jax.random.PRNGKey(0))))
+        pool = jax.tree_util.tree_map(struct, jax.eval_shape(
+            lambda: transformer.init_paged_cache(cfg, n_pages, PAGE)))
+        table = jax.ShapeDtypeStruct((rows, width), I32, sharding=one_chip)
+        scalar = jax.ShapeDtypeStruct((), I32, sharding=one_chip)
+        monkeypatch.setattr(transformer, "_decode_kernel_kwargs",
+                            lambda *a, **k: {"use_pallas": True})
+        monkeypatch.setattr(attention.jax, "default_backend", lambda: "tpu")
+        compiled = jax.jit(
+            lambda p, c, tb, w: transformer.eva_close_window(cfg, p, c, tb, w),
+            donate_argnums=1).lower(params, pool, table, scalar).compile()
+        text = compiled.as_text()
     else:
-        pos = scalar if t > 1 else jax.ShapeDtypeStruct((rows,), I32,
-                                                        sharding=one_chip)
-        fn = jax.jit(lambda p, c, tb, tok, at: transformer.decode_step(
-            cfg, p, dict(c, pages=tb), tok, at), donate_argnums=1)
-        args = (params, pool, table,
-                jax.ShapeDtypeStruct((rows, t), I32, sharding=one_chip), pos)
-    compiled = fn.lower(*args).compile()
-    text = compiled.as_text()
+        # a window's chunk starts at a traced position
+        _, _, compiled, text = _compiled_step(
+            topo, "evabyte", rows, t, width, "ragged" if t == 1 else "traced")
     assert " scatter(" in text
     if what == "step":
         assert "tpu_custom_call" in text
@@ -655,59 +755,10 @@ def test_eva_pipelined_decode_program_compiles_for_v5e(topo, monkeypatch):
                                          ("prefill_t448", 1, 448),
                                          ("prefill_t16256", 1, 16256)])
 def test_window_stack_compiles_and_keeps_the_rings_in_place(
-        topo, monkeypatch, name, rows, t):
-    from tfmesos_tpu.models import transformer
-    from tfmesos_tpu.ops import attention, moe
-    from tfmesos_tpu.ops.attention import attend
-
-    kinds = ("attention", "window", "window", "window", "attention")
-    cfg = transformer.TransformerConfig(
-        vocab_size=100352, d_model=2048, n_layers=5, n_heads=48,
-        n_kv_heads=8, attn_head_dim=128, d_ff=8192, max_seq_len=17408,
-        dtype=BF16, param_dtype=BF16, layer_types=kinds, window=512,
-        window_heads=64, window_rope=transformer.RopeSpec(theta=10000.0),
-        attn_rope=transformer.RopeSpec(theta=500000.0, fraction=0.5,
-                                       yarn=(64.0, 4096, 64.0, 1.0)),
-        ffn_types=("dense",) + ("sparse",) * 4, expert_d_ff=512,
-        attn_gate="head", norm_eps=1e-6, logits_dtype=F32, n_experts=256,
-        top_k=8, moe_impl="grouped", shared_d_ff=512,
-        router_score="sigmoid", routed_scale=2.5)
-    monkeypatch.setattr(transformer, "_decode_kernel_kwargs",
-                        lambda *a, **k: {"use_pallas": True})
-    monkeypatch.setattr(transformer, "attend",
-                        partial(attend, use_pallas=True))
-    monkeypatch.setattr(moe, "_on_tpu", lambda use: True)
-    # the window mixer calls flash_decode as the program does: by the backend
-    monkeypatch.setattr(attention.jax, "default_backend", lambda: "tpu")
-    one_chip = SingleDeviceSharding(topo.devices[0])
-
-    def struct(x):
-        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
-
-    slots, n_pages = 128, 7168
-    params = jax.tree_util.tree_map(struct, jax.eval_shape(
-        lambda: transformer.init_params(cfg, jax.random.PRNGKey(0))))
-    cache = dict(jax.eval_shape(
-        lambda: transformer.init_paged_cache(cfg, n_pages, PAGE)))
-    cache["state"] = jax.eval_shape(
-        lambda: transformer.init_row_state(cfg, slots))
-    assert set(cache["state"]) == {"swa_k", "swa_v"}
-    cache["pages"] = jnp.zeros((rows, 17408 // PAGE), I32)
-    if t > 1:
-        cache["slots"] = jnp.zeros((rows,), I32)
-        cache["valid"] = jnp.zeros((rows,), I32)
-    cache = jax.tree_util.tree_map(struct, cache)
-    tokens = jax.ShapeDtypeStruct((rows, t), I32, sharding=one_chip)
-    if t == 1:
-        pos = (jax.ShapeDtypeStruct((rows,), I32, sharding=one_chip),)
-        step = jax.jit(lambda p, c, tok, at: transformer.decode_step(
-            cfg, p, c, tok, at), donate_argnums=1)
-    else:
-        pos = ()
-        step = jax.jit(lambda p, c, tok: transformer.decode_step(
-            cfg, p, c, tok, 0), donate_argnums=1)
-    compiled = step.lower(params, cache, tokens, *pos).compile()
-    text = compiled.as_text()
+        topo, name, rows, t):
+    _, n_pages, slots, _ = _step_models()["laguna"]
+    _, _, compiled, text = _compiled_step(topo, "laguna", rows, t,
+                                          17408 // PAGE)
     for kernel in ("moe_grouped_swiglu", "moe_grouped_matmul"):
         assert kernel in text, kernel
     if t == 1:

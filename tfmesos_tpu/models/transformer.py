@@ -2038,17 +2038,29 @@ def _rope_kwargs(cfg: TransformerConfig, kind: str = "attention"):
     return spec.kwargs(cfg.head_dim)
 
 
+def _split_heads(y, heads: int):
+    """A projection's product ``y`` [B, t, heads * Dh] as [B, t, heads, Dh].
+
+    The product stands as a matrix first (the barrier).  Left to itself the
+    TPU compiler folds the split into the dot wherever an elementwise op, a
+    slice or a pad reads the heads (rope's half-heads, a gate's sigmoid, the
+    kernels' padded query groups): the dot then wants its weight as [heads,
+    Dh, in], which is no view of the stored [in, out], and every run of the
+    program cuts a layer of the weight out of its stack and transposes it
+    (``wq`` and ``wk``: 1.0 ms of a 12.6 ms Mistral-7B decode block on the
+    v5e, PR 44).  With it the matmul reads the parameter where it lies and
+    the split costs a pass over the activations."""
+    b, t, n = y.shape
+    return jax.lax.optimization_barrier(y).reshape(b, t, heads, n // heads)
+
+
 def _project_qkv(cfg: TransformerConfig, h, lp, positions,
                  kind: str = "attention"):
     """q [B, t, heads of ``kind``, Dh], k and v [B, t, KV, Dh] of the normed
     input ``h``, q and k under the kind's rope (``cfg.rope``)."""
-    b, t, _ = h.shape
-    q = _qmm(h, lp["wq"], cfg.dtype).reshape(b, t, cfg.kind_heads(kind),
-                                             cfg.head_dim)
-    k = _qmm(h, lp["wk"], cfg.dtype).reshape(b, t, cfg.kv_heads,
-                                             cfg.head_dim)
-    v = _qmm(h, lp["wv"], cfg.dtype).reshape(b, t, cfg.kv_heads,
-                                             cfg.head_dim)
+    q = _split_heads(_qmm(h, lp["wq"], cfg.dtype), cfg.kind_heads(kind))
+    k = _split_heads(_qmm(h, lp["wk"], cfg.dtype), cfg.kv_heads)
+    v = _split_heads(_qmm(h, lp["wv"], cfg.dtype), cfg.kv_heads)
     if cfg.rope:
         rkw = _rope_kwargs(cfg, kind)
         q = rope(q, positions, **rkw)
@@ -2505,9 +2517,11 @@ def _kda_mixer(cfg: TransformerConfig, x, lp, state, ki, slots, valid,
     h = _norm(cfg, x, lp["attn_norm"])
     qkv = _qmm(h, lp["in_proj"], cfg.dtype)
     # the log-decay per head and key channel, and the step per head: float32
-    f = _qmm(_qmm(h, lp["f_down"], cfg.dtype), lp["f_up"], cfg.dtype)
-    g = jax.nn.softplus(f.astype(f32) + lp["dt_bias"].astype(f32)).reshape(
-        b, t, nh, dk) * -jnp.exp(lp["A_log"].astype(f32))[:, None]
+    f = _split_heads(
+        _qmm(_qmm(h, lp["f_down"], cfg.dtype), lp["f_up"], cfg.dtype), nh)
+    g = jax.nn.softplus(
+        f.astype(f32) + lp["dt_bias"].astype(f32).reshape(nh, dk)
+    ) * -jnp.exp(lp["A_log"].astype(f32))[:, None]
     beta = jax.nn.sigmoid(_qmm(h, lp["b_proj"], cfg.dtype).astype(f32))
     if cfg.kda_neg_eigval:
         beta = 2.0 * beta
@@ -2538,9 +2552,10 @@ def _kda_mixer(cfg: TransformerConfig, x, lp, state, ki, slots, valid,
         conv = conv.at[ki, slots].set(new_tail.astype(conv.dtype))
     # RMSNorm per head, then the low-rank sigmoid gate
     kw = {} if cfg.norm_eps is None else {"eps": cfg.norm_eps}
-    gate = _qmm(_qmm(h, lp["g_down"], cfg.dtype), lp["g_up"], cfg.dtype)
+    gate = _split_heads(
+        _qmm(_qmm(h, lp["g_down"], cfg.dtype), lp["g_up"], cfg.dtype), nh)
     o = rms_norm(o, lp["norm"].astype(f32), **kw) * jax.nn.sigmoid(
-        gate.astype(f32)).reshape(b, t, nh, dk)
+        gate.astype(f32))
     o = o.reshape(b, t, hk).astype(cfg.dtype)
     return _residual(cfg, x, _qmm(o, lp["out_proj"], cfg.dtype)), (s, conv)
 
