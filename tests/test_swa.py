@@ -425,10 +425,10 @@ def test_what_a_window_row_cannot_do_is_refused(model, weights, what, kw):
 @pytest.mark.parametrize("t,window", [(160, None), (160, 24), (200, 70),
                                       (129, None)])
 def test_segmented_flash_attention_is_attention(monkeypatch, t, window):
-    """Past ``FLASH_MAX_KEYS`` a forward-only call runs segment by segment
+    """Past ``flash_max_keys`` a forward-only call runs segment by segment
     and merges the partials by their log-sum-exps (here at 64 keys a call,
     the kernel interpreted)."""
-    monkeypatch.setattr(A, "FLASH_MAX_KEYS", 64)
+    monkeypatch.setattr(A, "FLASH_MAX_KV_BYTES", 64 * 2 * A.LANES * 4)
     rng = np.random.default_rng(t)
     q = jnp.asarray(rng.standard_normal((1, t, 6, 16)), F32)
     k = jnp.asarray(rng.standard_normal((1, t, 2, 16)), F32)
@@ -443,9 +443,9 @@ def test_segmented_flash_attention_is_attention(monkeypatch, t, window):
 def test_long_sequences_stay_differentiable(monkeypatch, window):
     """Only a caller that states ``forward_only`` (the serving prefill)
     takes the segmented forward, which has no VJP: a gradient through
-    ``flash_attention`` past ``FLASH_MAX_KEYS`` runs the kernel pair it
+    ``flash_attention`` past ``flash_max_keys`` runs the kernel pair it
     always ran."""
-    monkeypatch.setattr(A, "FLASH_MAX_KEYS", 64)
+    monkeypatch.setattr(A, "FLASH_MAX_KV_BYTES", 64 * 2 * A.LANES * 4)
     monkeypatch.setattr(A, "_flash_segmented", lambda *a, **k: 1 / 0)
     rng = np.random.default_rng(7)
     q = jnp.asarray(rng.standard_normal((1, 160, 4, 16)), F32)

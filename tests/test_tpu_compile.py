@@ -320,6 +320,24 @@ def _step_models():
             top_k=8, moe_impl="grouped", shared_d_ff=512,
             router_score="sigmoid", routed_scale=2.5),
             7168, 128, ("attend", "moe", "backend")),
+        # MiMo-V2-Flash's: [full | window x 4 | full | window] behind a
+        # leading dense layer, keys of 192 and values of 128 channels, 4
+        # against 8 K/V heads, a sink a window head, 16 of 256 experts held,
+        # a sliced vocabulary
+        "mimo": (cfg(
+            vocab_size=19072, d_model=4096, n_layers=7, n_heads=64,
+            n_kv_heads=4, attn_head_dim=192, attn_v_head_dim=128,
+            attn_value_scale=0.707, d_ff=16384, max_seq_len=18944,
+            layer_types=("attention",) + ("window",) * 4
+            + ("attention", "window"),
+            window=128, window_kv_heads=8, window_sink=True,
+            window_rope=transformer.RopeSpec(theta=10000.0,
+                                             fraction=64 / 192),
+            attn_rope=transformer.RopeSpec(theta=5e6, fraction=64 / 192),
+            ffn_types=("dense",) + ("sparse",) * 6, expert_d_ff=2048,
+            norm_eps=1e-5, logits_dtype=F32, n_experts=256, top_k=8,
+            moe_impl="grouped", experts_held=16, router_score="sigmoid"),
+            15360, 128, ("attend", "moe", "backend")),
     }
 
 
@@ -446,6 +464,8 @@ WEIGHT_CASES = {
     "solar_prefill_t1024": ("solar", 1, 1024, 128, None),
     "laguna_decode": ("laguna", 128, 1, 17408 // PAGE, None),
     "laguna_prefill_t448": ("laguna", 1, 448, 17408 // PAGE, None),
+    "mimo_decode": ("mimo", 128, 1, 18944 // PAGE, None),
+    "mimo_prefill_t2112": ("mimo", 1, 2112, 18944 // PAGE, None),
 }
 
 
@@ -747,7 +767,7 @@ def test_eva_pipelined_decode_program_compiles_for_v5e(topo, monkeypatch):
 # over 8 K/V heads of 128, 256 experts of 512 all held, 7168 pages of 64, 128
 # rows, rings of 512 positions): the paged kernel at 6 query heads a K/V
 # head, ``flash_decode`` over the rings at 8, the windowed flash kernel, and
-# a prompt past ``FLASH_MAX_KEYS`` (the segmented forward: whole, a KV head's
+# a prompt past ``flash_max_keys`` (the segmented forward: whole, a KV head's
 # K and V asked 48.5 MB of VMEM).  Each donates pool and rings and must leave
 # them where they are.
 
@@ -783,3 +803,100 @@ def test_window_stack_compiles_and_keeps_the_rings_in_place(
     mem = compiled.memory_analysis()
     limit = {1: 5e7, 448: 1.5e8, 16256: 2.2e9}[t]
     assert mem.temp_size_in_bytes < limit, mem.temp_size_in_bytes
+
+
+# -- keys and values of unequal width, a sink, K heads side by side (PR 45) ----
+#
+# MiMo-V2-Flash's first seven layers compiled whole for the described v5e at
+# the benchmark's widths (hidden 4096, 64 query heads, keys of 192 and values
+# of 128 channels, 4 K/V heads in a full layer and 8 in a window layer, 16 of
+# 256 experts of 2048 held, 15360 pages of 64, 128 rows, rings of 128
+# positions): the paged kernel at 16 query heads a K/V head over a pool whose
+# K leaf lays two heads' keys side by side, ``flash_decode`` over rings laid
+# out the same way with a sink a head, the flash forward with and without a
+# sink, and a prompt past ``flash_max_keys`` (5,461 keys of 192 + 128
+# channels).  Each donates pool and rings and must leave them where they are.
+
+def _tiled_bytes(shape, layout, itemsize=2):
+    """Bytes an array of ``shape`` occupies in HBM under ``layout`` (XLA's
+    text: minor-to-major dims, then the tile, e.g. ``4,3,2,1,0:T(8,128)(2,1)``
+    for a row-major bfloat16 array): the minor-most dim in whole tiles of
+    lanes, the next in whole tiles of sublanes (times the packing)."""
+    order, tiles = layout.split(":", 1)
+    order = [int(x) for x in order.split(",")]
+    sub, lanes = map(int, re.match(r"T\((\d+),(\d+)\)", tiles).groups())
+    pack = re.search(r"\)\((\d+),1\)", tiles)
+    sub *= int(pack.group(1)) if pack else 1
+    dims = list(shape)
+    dims[order[0]] = -(-dims[order[0]] // lanes) * lanes
+    dims[order[1]] = -(-dims[order[1]] // sub) * sub
+    return int(np.prod(dims)) * itemsize
+
+
+def test_tiled_bytes_pads_a_key_of_192_channels_to_256_lanes():
+    """Row-major, as the kernels read a cache: 192 channels stand in 256
+    lanes; two heads' keys side by side (384) pad nothing."""
+    row_major = "4,3,2,1,0:T(8,128)(2,1)"
+    assert _tiled_bytes((2, 10, 4, 64, 192), row_major) == 2 * 10 * 4 * 64 \
+        * 256 * 2
+    assert _tiled_bytes((2, 10, 2, 64, 384), row_major) == 2 * 10 * 2 * 64 \
+        * 384 * 2
+    assert _tiled_bytes((2, 10, 4, 64, 128), row_major) == 2 * 10 * 4 * 64 \
+        * 128 * 2
+
+
+@pytest.mark.parametrize("name,rows,t", [("decode_r128", 128, 1),
+                                         ("prefill_t2112", 1, 2112),
+                                         ("prefill_t16896", 1, 16896)])
+def test_unequal_head_sizes_compile_and_the_pool_pads_nothing(
+        topo, name, rows, t):
+    _, n_pages, slots, _ = _step_models()["mimo"]
+    _, _, compiled, text = _compiled_step(topo, "mimo", rows, t,
+                                          18944 // PAGE)
+    for kernel in ("moe_grouped_swiglu", "moe_grouped_matmul"):
+        assert kernel in text, kernel
+    if t == 1:
+        # the full layers' kernel at 16 query heads a K/V head, the rings' at
+        # 8; both results have the values' 128 channels
+        assert re.search(r"%flash_decode_paged[.\d]* = bf16\[128,4,16,128\]",
+                         text)
+        assert re.search(r"%flash_decode[.\d]* = bf16\[128,8,8,128\]", text)
+    else:
+        # both kinds' prefill kernels have 64 query heads: the window
+        # layers' carries the sink and is told by its name
+        assert re.search(r"%flash_attention_fwd[.\d]* = \(bf16\[1,64,", text)
+        assert re.search(r"%flash_attention_fwd_sink[.\d]* = \(bf16\[1,64,",
+                         text)
+    if t == 16896:      # in four segments of at most 5,461 keys
+        assert re.search(r"%flash_attention_fwd[.\d]* = \(bf16\[1,64,4608,",
+                         text)
+    pool_k, pool_v = (2, n_pages, 2, PAGE, 384), (2, n_pages, 4, PAGE, 128)
+    ring_k, ring_v = (5, slots, 4, 128, 384), (5, slots, 8, 128, 128)
+    as_text = lambda s: "bf16[" + ",".join(map(str, s)) + "]"
+    for leaf in (pool_k, pool_v, ring_k, ring_v, (6, 16, 4096, 2048),
+                 (6, 16, 2048, 4096)):
+        assert as_text(leaf) in text, leaf
+        moved = re.findall(r"= " + re.escape(as_text(leaf))
+                           + r"\S* (?:copy|transpose|slice)\([^)]*\)", text)
+        assert not moved, f"{leaf} is copied: {moved[:2]}"
+    # the pool as the compiled step holds it costs the 5,120 B a position
+    # docs/SERVING.md states: 2 full layers x 4 K/V heads x (192 + 128)
+    # channels x 2 B, nothing padded (in the native layout K alone would
+    # stand at 2 x 4 x 256 x 2 B and a position at 6,144); the rings
+    # 3,276,800 B a row slot
+    def held(shape):
+        layouts = set(re.findall(re.escape(as_text(shape))
+                                 + r"\{([\d,]+:T\([^}]*?\))(?:S\(\d\))?\}",
+                                 text))
+        assert len(layouts) == 1, (shape, layouts)
+        return _tiled_bytes(shape, layouts.pop())
+
+    assert (held(pool_k) + held(pool_v)) // (n_pages * PAGE) == 5120
+    assert (held(ring_k) + held(ring_v)) // slots == 3276800
+    # beside 6.9 GB of weights, 5.0 GB of pool and 0.4 GB of rings: a
+    # step's temporaries (the longest prompt's attention operands in four
+    # segments, its sorted expert rows)
+    mem = compiled.memory_analysis()
+    limit = {1: 5e7, 2112: 6e8, 16896: 3.0e9}[t]
+    assert mem.temp_size_in_bytes < limit, mem.temp_size_in_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.3e9

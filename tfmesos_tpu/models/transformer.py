@@ -235,19 +235,26 @@ class TransformerConfig:
     # (``init_row_state``: the leaves of the kinds present).
     layer_types: Optional[Tuple[str, ...]] = None
     # "window": a second kind of attention layer beside "attention", with
-    # its own query heads (``window_heads``; None: n_heads; K/V heads and
-    # the head size are the stack's), its own rope (``window_rope``; None:
-    # plain rope at ``rope_theta``) and a sliding window: position t attends
-    # positions max(0, t - window + 1) .. t (``window``, which with
-    # ``layer_types`` is these layers' and no one else's).  Its mixer leaves
-    # are stacked under ``layers["window"]`` (wq / wo differ in shape from
-    # the "attention" kind's).  It keeps no pages: a row slot holds a RING
-    # of the last ``window`` positions' K and V a window layer
-    # (``init_row_state``: ``swa_k`` / ``swa_v``, slot = position mod
-    # window, keys after rope), O(window) a row whatever its context.
-    # ``attn_rope``: the rope of the "attention" kind (and of a homogeneous
-    # stack's decode path) where it is more than ``rope_theta``.
+    # its own query heads (``window_heads``; None: n_heads), its own K/V
+    # heads (``window_kv_heads``; None: the stack's ``kv_heads``), its own
+    # rope (``window_rope``; None: plain rope at ``rope_theta``) and a
+    # sliding window: position t attends positions max(0, t - window + 1)
+    # .. t (``window``, which with ``layer_types`` is these layers' and no
+    # one else's).  The head sizes are the stack's (``head_dim`` for queries
+    # and keys, ``v_head_dim`` for values).  ``window_sink``: a learned
+    # logit a window head (the leaf ``sink`` [window layers, heads] float32
+    # beside ``wq``) joins the softmax's denominator and carries no value:
+    # p_j = exp(s_j - m) / (sum_j exp(s_j - m) + exp(b_h - m)).  Its mixer
+    # leaves are stacked under ``layers["window"]`` (wq / wk / wv / wo may
+    # all differ in shape from the "attention" kind's).  It keeps no pages:
+    # a row slot holds a RING of the last ``window`` positions' K and V a
+    # window layer (``init_row_state``: ``swa_k`` / ``swa_v``, slot =
+    # position mod window, keys after rope), O(window) a row whatever its
+    # context.  ``attn_rope``: the rope of the "attention" kind (and of a
+    # homogeneous stack's decode path) where it is more than ``rope_theta``.
     window_heads: Optional[int] = None
+    window_kv_heads: Optional[int] = None
+    window_sink: bool = False
     window_rope: Optional[RopeSpec] = None
     attn_rope: Optional[RopeSpec] = None
     # A FEED-FORWARD PATTERN beside the mixer pattern (None: every block's
@@ -291,9 +298,17 @@ class TransformerConfig:
     # ``wo``, by ``sigmoid(x W_g)`` taken from the block's normed input (an
     # elementwise output gate: the leaf ``wg`` beside ``wq``, [d, heads *
     # head_dim]; ``attn_gate="head"``: one gate a HEAD, ``wg`` [d, heads]).
+    # ``attn_v_head_dim``: the VALUES' head size where it is not the keys'
+    # (None: ``head_dim``): ``wv`` [d, KV * Dv], ``wo`` [heads * Dv, d], the
+    # caches' V leaves Dv wide; ``attn_value_scale`` multiplies the values
+    # (attention is linear in them, so they are scaled once, as projected:
+    # what the caches hold is scaled).  Both are a typed stack's with window
+    # layers (the serving path's; ``docs/SERVING.md`` "The hybrid cache").
     rope: bool = True
     attn_scale: Optional[float] = None
     attn_head_dim: Optional[int] = None
+    attn_v_head_dim: Optional[int] = None
+    attn_value_scale: Optional[float] = None
     attn_gate: Any = False
     # Multipliers (None: absent): the embedding's output is scaled by
     # ``embed_scale``, every block adds ``residual_scale`` times its mixer
@@ -327,6 +342,12 @@ class TransformerConfig:
         if self.attn_head_dim is not None:
             return self.attn_head_dim
         return self.d_model // self.n_heads
+
+    @property
+    def v_head_dim(self) -> int:
+        """The values' head size (the keys' unless stated)."""
+        return (self.head_dim if self.attn_v_head_dim is None
+                else self.attn_v_head_dim)
 
     def __post_init__(self):
         if self.window is not None and self.window < 1:
@@ -376,10 +397,11 @@ class TransformerConfig:
                     "EVA); window is the 'window' layers' and is stated "
                     "with them and only with them")
             if windowed and (self.window_heads or self.n_heads) % \
-                    self.kv_heads:
+                    self.kind_kv_heads("window"):
                 raise ValueError(
                     f"window_heads ({self.window_heads}) must be a "
-                    f"multiple of the K/V heads ({self.kv_heads})")
+                    f"multiple of the K/V heads "
+                    f"({self.kind_kv_heads('window')})")
             if "mamba" in self.layer_types and (
                     self.mamba_heads < 1 or self.mamba_conv < 2):
                 raise ValueError("mamba layers need mamba_heads >= 1 and "
@@ -389,10 +411,22 @@ class TransformerConfig:
                     or self.kda_chunk < 1):
                 raise ValueError("kda layers need kda_heads >= 1, kda_conv "
                                  ">= 2 and kda_chunk >= 1")
-        if any(v is not None for v in (self.window_heads, self.window_rope)) \
+        if (any(v is not None for v in (
+                self.window_heads, self.window_rope, self.window_kv_heads))
+                or self.window_sink) \
                 and "window" not in (self.layer_types or ()):
-            raise ValueError("window_heads / window_rope are the 'window' "
-                             "layers' (layer_types)")
+            raise ValueError("window_heads / window_kv_heads / window_sink / "
+                             "window_rope are the 'window' layers' "
+                             "(layer_types)")
+        if (self.attn_v_head_dim is not None
+                or self.attn_value_scale is not None) \
+                and "window" not in (self.layer_types or ()):
+            # what moves or shares pages by shape (export, the KV tier, a
+            # suspended row's snapshot) knows one head size, and is closed
+            # where a row keeps a window layer's ring
+            raise ValueError("attn_v_head_dim / attn_value_scale are served "
+                             "in a typed stack with 'window' layers "
+                             "(layer_types)")
         if self.attn_gate not in (False, True, "head"):
             raise ValueError(f"attn_gate must be False, True (elementwise) "
                              f"or 'head', got {self.attn_gate!r}")
@@ -497,6 +531,19 @@ class TransformerConfig:
             return self.window_heads
         return self.n_heads
 
+    def kind_kv_heads(self, kind: str) -> int:
+        """K/V heads of an attention layer of ``kind``."""
+        if kind == "window" and self.window_kv_heads is not None:
+            return self.window_kv_heads
+        return self.kv_heads
+
+    def k_pack(self, kind: str = "attention") -> int:
+        """K heads a row of the kind's K cache holds side by side
+        (``ops/attention.pack_k``: 2 where a key is a lane tile and a half
+        wide, so that the cache pads nothing in HBM; else 1)."""
+        from tfmesos_tpu.ops.attention import pack_k
+        return pack_k(self.head_dim, self.kind_kv_heads(kind))
+
     @property
     def layer_period(self) -> int:
         """Length of the shortest prefix of the pattern behind the leading
@@ -586,7 +633,6 @@ def init_params(cfg: TransformerConfig, rng) -> Dict[str, Any]:
             "n_shared_experts requires n_experts > 0 — without routed "
             "experts there is nothing to share beside; widen d_ff instead")
     d, f, l = cfg.d_model, cfg.d_ff, cfg.n_layers
-    kvd = cfg.kv_heads * cfg.head_dim
     keys = iter(jax.random.split(rng, 48 if cfg.ffn_types else
                                  32 if cfg.layer_types else 16))
 
@@ -597,23 +643,28 @@ def init_params(cfg: TransformerConfig, rng) -> Dict[str, Any]:
     # a gain g with the unit offset scales by (1 + g): the identity is 0
     gain = jnp.zeros if cfg.norm_offset else jnp.ones
 
-    def attn_leaves(la, heads):
-        """The leaves of ``la`` attention layers of ``heads`` query heads."""
-        hd = heads * cfg.head_dim
+    def attn_leaves(la, kind):
+        """The leaves of ``la`` attention layers of ``kind``."""
+        heads, kv = cfg.kind_heads(kind), cfg.kind_kv_heads(kind)
+        hd, ho = heads * cfg.head_dim, heads * cfg.v_head_dim
         leaves = {
             "wq": norm((la, d, hd), 1 / math.sqrt(d)),
-            "wk": norm((la, d, kvd), 1 / math.sqrt(d)),
-            "wv": norm((la, d, kvd), 1 / math.sqrt(d)),
-            "wo": norm((la, hd, d), 1 / math.sqrt(hd) / math.sqrt(2 * l)),
+            "wk": norm((la, d, kv * cfg.head_dim), 1 / math.sqrt(d)),
+            "wv": norm((la, d, kv * cfg.v_head_dim), 1 / math.sqrt(d)),
+            "wo": norm((la, ho, d), 1 / math.sqrt(ho) / math.sqrt(2 * l)),
         }
         if cfg.attn_gate:
             leaves["wg"] = norm(
-                (la, d, heads if cfg.attn_gate == "head" else hd),
+                (la, d, heads if cfg.attn_gate == "head" else ho),
                 1 / math.sqrt(d))
+        if kind == "window" and cfg.window_sink:
+            # the sink's logit: float32 whatever the parameters' dtype
+            leaves["sink"] = jax.random.normal(next(keys), (la, heads),
+                                               jnp.float32)
         return leaves
 
     la = cfg.n_attn_layers
-    attn = attn_leaves(la, cfg.n_heads)
+    attn = attn_leaves(la, "attention")
     layers = {"attn_norm": gain((l, d), cfg.param_dtype),
               "mlp_norm": gain((l, d), cfg.param_dtype)}
     if cfg.layer_types is None:
@@ -623,8 +674,7 @@ def init_params(cfg: TransformerConfig, rng) -> Dict[str, Any]:
         if la:
             layers["attention"] = attn
         if cfg.n_window_layers:
-            layers["window"] = attn_leaves(cfg.n_window_layers,
-                                           cfg.kind_heads("window"))
+            layers["window"] = attn_leaves(cfg.n_window_layers, "window")
         u = lambda shape, lo, hi: jax.random.uniform(
             next(keys), shape, jnp.float32, lo, hi)
         lm = cfg.n_mamba_layers
@@ -1420,6 +1470,15 @@ def init_paged_cache(cfg: TransformerConfig, n_pages: int,
     pool as int8 with per-position scales (the paged kernel folds them
     into the score rows, so HBM streams int8 pages).  Windowed (rolling)
     configs address by slot and don't page.
+
+    The leaves: ``v`` [attention layers, P, KV, page, Dv] and ``k``
+    [attention layers, P, KV / f, page, f * Dk], ``f`` = ``cfg.k_pack()``
+    heads' keys of a position side by side (1 for every head size that is
+    whole lane tiles: K then has V's layout; 2 at 192 channels, which the
+    native layout would pad to 256 lanes in HBM).  A position's keys
+    ``[KV, Dk]`` ARE ``[KV / f, f * Dk]`` as they lie, so the writes take
+    the packed chunk by a reshape; the paged kernel reads the pool as laid
+    out, no transpose or pad a call.
     """
     if cfg.window is not None and cfg.layer_types is None:
         raise ValueError("paged caches do not compose with sliding-window "
@@ -1444,6 +1503,9 @@ def init_paged_cache(cfg: TransformerConfig, n_pages: int,
         if dtype is not None:
             raise ValueError("init_paged_cache: dtype and quantized=True "
                              "conflict (an int8 pool's dtypes are fixed)")
+        if cfg.v_head_dim != cfg.head_dim:
+            raise ValueError("an int8 pool keeps one head size for K and V "
+                             "(attn_v_head_dim is a plain pool's)")
         shape = (cfg.n_attn_layers, n_pages, cfg.kv_heads, page_size,
                  cfg.head_dim)
 
@@ -1461,9 +1523,12 @@ def init_paged_cache(cfg: TransformerConfig, n_pages: int,
     dtype = dtype or cfg.dtype
     # (page, head_dim) trailing — the kernel's native layout, so serving
     # never transposes the shared pool.
-    shape = (cfg.n_attn_layers, n_pages, cfg.kv_heads, page_size,
-             cfg.head_dim)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    f = cfg.k_pack()
+    lead = (cfg.n_attn_layers, n_pages)
+    return {"k": jnp.zeros(lead + (cfg.kv_heads // f, page_size,
+                                   f * cfg.head_dim), dtype),
+            "v": jnp.zeros(lead + (cfg.kv_heads, page_size, cfg.v_head_dim),
+                           dtype)}
 
 
 def init_row_state(cfg: TransformerConfig, rows: int) -> Dict[str, Any]:
@@ -1476,8 +1541,13 @@ def init_row_state(cfg: TransformerConfig, rows: int) -> Dict[str, Any]:
     each row's next position, in the compute dtype.  KDA layers: ``kda_s``
     [kda layers, rows, heads * head_dim, head_dim] float32 (heads and key
     channels as one dim) and ``kda_conv`` [kda layers, rows, kda_conv - 1,
-    3 * heads * head_dim], the inputs of the conv over [q | k | v].  Its
-    size does not depend on a row's context.  Pass it to ``decode_step`` under
+    3 * heads * head_dim], the inputs of the conv over [q | k | v].  Window
+    layers: ``swa_k`` [window layers, rows, KV / f, window, f * Dk] and
+    ``swa_v`` [window layers, rows, KV, window, Dv], a ring of the last
+    ``window`` positions' K and V (KV the window kind's K/V heads, Dk / Dv
+    the keys' and the values' head sizes, ``f`` = ``cfg.k_pack("window")``
+    heads' keys of a slot side by side: 1 unless a key is a lane tile and a
+    half).  Its size does not depend on a row's context.  Pass it to ``decode_step`` under
     ``cache["state"]`` beside the pool; a one-token step reads and writes
     every slot, a prefill from position 0 starts from an empty state and
     writes its rows' slots (``cache["slots"]``), whatever they held."""
@@ -1485,10 +1555,15 @@ def init_row_state(cfg: TransformerConfig, rows: int) -> Dict[str, Any]:
     lm, lk, lw = cfg.n_mamba_layers, cfg.n_kda_layers, cfg.n_window_layers
     if lw:
         # K and V of the last ``window`` positions (slot = position mod
-        # window, keys after rope), in the layout ``flash_decode`` reads
-        ring = (lw, rows, cfg.kv_heads, cfg.window, cfg.head_dim)
-        state.update(swa_k=jnp.zeros(ring, cfg.dtype),
-                     swa_v=jnp.zeros(ring, cfg.dtype))
+        # window, keys after rope), in the layout ``flash_decode`` reads:
+        # the window kind's K/V heads, V at its own head size, K with
+        # ``k_pack("window")`` heads' keys of a slot side by side
+        kv, f = cfg.kind_kv_heads("window"), cfg.k_pack("window")
+        state.update(
+            swa_k=jnp.zeros((lw, rows, kv // f, cfg.window,
+                             f * cfg.head_dim), cfg.dtype),
+            swa_v=jnp.zeros((lw, rows, kv, cfg.window, cfg.v_head_dim),
+                            cfg.dtype))
     if lm:
         state.update(
             ssm=jnp.zeros((lm, rows, cfg.mamba_inner, cfg.mamba_state),
@@ -2056,11 +2131,15 @@ def _split_heads(y, heads: int):
 
 def _project_qkv(cfg: TransformerConfig, h, lp, positions,
                  kind: str = "attention"):
-    """q [B, t, heads of ``kind``, Dh], k and v [B, t, KV, Dh] of the normed
-    input ``h``, q and k under the kind's rope (``cfg.rope``)."""
+    """q [B, t, heads of ``kind``, Dh], k [B, t, KV of ``kind``, Dh] and v
+    [B, t, KV, Dv] of the normed input ``h``, q and k under the kind's rope
+    (``cfg.rope``), v times ``attn_value_scale`` where one is stated."""
+    kv = cfg.kind_kv_heads(kind)
     q = _split_heads(_qmm(h, lp["wq"], cfg.dtype), cfg.kind_heads(kind))
-    k = _split_heads(_qmm(h, lp["wk"], cfg.dtype), cfg.kv_heads)
-    v = _split_heads(_qmm(h, lp["wv"], cfg.dtype), cfg.kv_heads)
+    k = _split_heads(_qmm(h, lp["wk"], cfg.dtype), kv)
+    v = _split_heads(_qmm(h, lp["wv"], cfg.dtype), kv)
+    if cfg.attn_value_scale is not None:
+        v = v * jnp.asarray(cfg.attn_value_scale, v.dtype)
     if cfg.rope:
         rkw = _rope_kwargs(cfg, kind)
         q = rope(q, positions, **rkw)
@@ -2074,9 +2153,19 @@ def _attn_gated(cfg: TransformerConfig, o, h, lp):
     gate = jax.nn.sigmoid(_qmm(h, lp["wg"], cfg.dtype))
     if cfg.attn_gate == "head":
         b, t, _ = o.shape
-        return (o.reshape(b, t, -1, cfg.head_dim)
+        return (o.reshape(b, t, -1, cfg.v_head_dim)
                 * gate[..., None]).reshape(b, t, -1)
     return o * gate
+
+
+def _packed_keys(cfg: TransformerConfig, k, kind: str = "attention"):
+    """A chunk's keys [B, t, KV, Dh] as the kind's K cache holds a position
+    ([B, t, KV / f, f * Dh], ``cfg.k_pack``): the same values as they lie."""
+    f = cfg.k_pack(kind)
+    if f == 1:
+        return k
+    b, t, kv, dh = k.shape
+    return k.reshape(b, t, kv // f, f * dh)
 
 
 def _block_decode(cfg: TransformerConfig, x, lp, ck, cv, li, positions,
@@ -2149,6 +2238,8 @@ def _attend_decode(cfg: TransformerConfig, x, lp, ck, cv, li, positions,
     # one commit pair per dispatch, instead of per-layer write-then-
     # attend scatters.
     defer = pages is not None and not sharded
+    # the chunk's keys as the pool holds a position's (``k_pack``)
+    kp = _packed_keys(cfg, k) if defer else k
     if pages is not None and sharded:
         # Multi-chip serving: write + paged attention per shard (the page
         # indirection cannot be GSPMD-partitioned; everything around it
@@ -2223,7 +2314,7 @@ def _attend_decode(cfg: TransformerConfig, x, lp, ck, cv, li, positions,
                     *quantize_int8_reference(c))
                 self_kv = (rq(k), rq(v))
             else:
-                self_kv = (k, v)
+                self_kv = (kp, v)
         kw = _decode_kernel_kwargs(cfg, m, t, False)
         at = positions[:, 0] if kpos is None else kpos
         with jax.named_scope("paged_attention"):
@@ -2286,7 +2377,7 @@ def _attend_decode(cfg: TransformerConfig, x, lp, ck, cv, li, positions,
         with jax.named_scope("attention.gate"):
             o = _attn_gated(cfg, o, h, lp)
     x = _residual(cfg, x, _qmm(o, lp["wo"], cfg.dtype))
-    return x, ck, cv, ((k, v) if defer else None)
+    return x, ck, cv, ((kp, v) if defer else None)
 
 
 def _embed_chunk(cfg: TransformerConfig, params, tokens, pos):
@@ -2565,7 +2656,8 @@ def _window_mixer(cfg: TransformerConfig, x, lp, state, wi, slots, valid,
     """A sliding-window attention layer over a token chunk; returns ``(x,
     (ring_k, ring_v))`` with layer ``wi`` of the stacked rings updated.
 
-    ``ring_k`` / ``ring_v`` [Lw, rows, KV, W, Dh] (``init_row_state``): slot
+    ``ring_k`` [Lw, rows, KV / f, W, f * Dh] / ``ring_v`` [Lw, rows, KV, W,
+    Dv] (``init_row_state``; KV the window kind's, ``f`` its ``k_pack``): slot
     ``p mod W`` of a row holds position ``p``'s K / V (keys after rope), W =
     ``cfg.window``, so a row that has written position ``t`` holds exactly
     the window's positions ``max(0, t - W + 1) .. t`` in slots ``0 ..
@@ -2577,28 +2669,32 @@ def _window_mixer(cfg: TransformerConfig, x, lp, state, wi, slots, valid,
     ``flash_decode`` bounded at ``min(t, W - 1)`` (softmax does not mind the
     ring's order).  ``t > 1``: a prefill from position 0, windowed flash
     attention over the chunk itself; the last W real positions (``valid``
-    [B] each row's; the rest is padding) go to the rings of ``slots``."""
+    [B] each row's; the rest is padding) go to the rings of ``slots``.  With
+    ``window_sink`` the layer's ``sink`` [heads] rides into either kernel."""
     from tfmesos_tpu.ops.attention import flash_decode
     rk, rv = state
     b, t, _ = x.shape
-    kv, w = cfg.kv_heads, cfg.window
+    w = cfg.window
     h = _norm(cfg, x, lp["attn_norm"])
     q, k, v = _project_qkv(cfg, h, lp, positions, "window")
     skw = {} if cfg.attn_scale is None else {"scale": cfg.attn_scale}
+    if cfg.window_sink:
+        skw["sink"] = lp["sink"]
+    kp = _packed_keys(cfg, k, "window")     # as the ring holds a slot's keys
     # The writes below are scatters IN THE RINGS' OWN LAYOUT, by the rule of
     # ``_paged_cache_write_all``: every dim in front of the window is
     # indexed and the window is a trailing slab ([Dh] a step, [W, Dh] a
     # prompt), so the compiler scatters into the donated store in place (a
     # per-row dynamic-update-slice under vmap carried the store rows-major
     # through the layer scan and transposed all of it back a layer).
-    ki = jnp.arange(kv, dtype=jnp.int32)[None]
+    heads = lambda c: jnp.arange(c.shape[2], dtype=jnp.int32)[None]
     if t == 1:
         pos = positions[:, 0]
         with jax.named_scope("swa.write"):
-            at = (wi, jnp.arange(b, dtype=jnp.int32)[:, None], ki,
-                  (pos % w)[:, None])
-            rk = rk.at[at].set(k[:, 0].astype(rk.dtype))
-            rv = rv.at[at].set(v[:, 0].astype(rv.dtype))
+            at = lambda c: (wi, jnp.arange(b, dtype=jnp.int32)[:, None],
+                            heads(c), (pos % w)[:, None])
+            rk = rk.at[at(kp)].set(kp[:, 0].astype(rk.dtype))
+            rv = rv.at[at(v)].set(v[:, 0].astype(rv.dtype))
         with jax.named_scope("swa.decode"):
             o = flash_decode(q, rk, rv, jnp.minimum(pos, w - 1), layer=wi,
                              **skw)
@@ -2616,10 +2712,10 @@ def _window_mixer(cfg: TransformerConfig, x, lp, state, wi, slots, valid,
 
             def put(ring, c):
                 keep = jnp.take_along_axis(c, src, axis=1)  # [B, W, KV, Dh]
-                return ring.at[wi, slots[:, None], ki].set(
+                return ring.at[wi, slots[:, None], heads(c)].set(
                     keep.transpose(0, 2, 1, 3).astype(ring.dtype))
 
-            rk, rv = put(rk, k), put(rv, v)
+            rk, rv = put(rk, kp), put(rv, v)
     o = o.reshape(b, t, -1)
     if cfg.attn_gate:
         with jax.named_scope("attention.gate"):

@@ -50,7 +50,8 @@ _PAGED_BLOCK_POSITIONS = 512
 
 
 def _paged_block(kv: int, ps: int, d: int, itemsize: int, width: int,
-                 quantized: bool = False) -> tuple[int, int]:
+                 quantized: bool = False, d_v: Optional[int] = None,
+                 pack: int = 1) -> tuple[int, int]:
     """``(head_block, pages_per_block)`` of the paged decode kernel, from
     what a call sees and nothing else.  One grid step covers a K/V block
     of ``pages_per_block`` pool pages of ``head_block`` heads each.  The
@@ -63,16 +64,19 @@ def _paged_block(kv: int, ps: int, d: int, itemsize: int, width: int,
     that do: a page that fills the budget alone (page 1024) yields one
     page a step, a value of the rule and not a mode.  An int8 pool's
     page carries one (8, 128)-padded float32 scale tile per 128
-    positions and head."""
-    page_bytes = ps * d * itemsize
+    positions and head.  ``d_v``: the values' head size where it is not
+    the keys' ``d`` (a head's page is then ``d + d_v`` channels a
+    position, not ``2 d``); ``pack``: K heads a row of the K pool holds
+    side by side (``pack_k``), which a head block takes whole."""
+    page_bytes = ps * (d + (d if d_v is None else d_v)) * itemsize
     if quantized:
-        page_bytes += 8 * 128 * 4 * -(-ps // 128)
+        page_bytes += 2 * 8 * 128 * 4 * -(-ps // 128)
     pages = max(1, min(_PAGED_BLOCK_POSITIONS // ps, width))
-    fit = lambda hb: _PAGED_VMEM_BUDGET // (4 * hb * page_bytes)
+    fit = lambda hb: _PAGED_VMEM_BUDGET // (2 * hb * page_bytes)
     for hb in range(kv, 0, -1):
-        if kv % hb == 0 and fit(hb) >= pages:
+        if kv % hb == 0 and hb % pack == 0 and fit(hb) >= pages:
             return hb, pages
-    return 1, max(1, fit(1))
+    return pack, max(1, fit(pack))
 
 
 def _paged_walk(page_table, live_pages, pages_per_block: int):
@@ -117,15 +121,90 @@ def _paged_walk(page_table, live_pages, pages_per_block: int):
 def _check_gqa_heads(q, k, v):
     """Every attention path shares one clear failure for bad GQA shapes
     (e.g. 4 q heads over 3 kv heads would otherwise floor to rep=1 and die
-    later in an opaque einsum shape error)."""
+    later in an opaque einsum shape error).  Heads stand at axis 2; K and
+    V agree in their heads and may differ in their head size (the
+    queries' is the keys', the result's the values')."""
     if q.shape[2] % k.shape[2] or k.shape[2] != v.shape[2]:
         raise ValueError(
             f"q heads ({q.shape[2]}) must be a multiple of kv heads "
             f"({k.shape[2]}/{v.shape[2]}, which must agree)")
+    if q.shape[-1] != k.shape[-1]:
+        raise ValueError(
+            f"q and k must share a head size, got {q.shape[-1]} and "
+            f"{k.shape[-1]} (v's, {v.shape[-1]}, is the result's)")
+
+
+#: lanes of a vector register row: a cache's trailing dim is laid out in
+#: HBM in whole multiples of it
+LANES = 128
+
+
+def pack_k(head_dim: int, kv_heads: int) -> int:
+    """K heads a row of a K CACHE (the paged pool, a window layer's ring)
+    holds side by side: 1, or 2 where a key's channels are a lane tile and
+    a half (192 = 128 + 64) and the K/V heads pair up.  In the caches'
+    native layout (``[.., positions, D]``, D trailing) a bfloat16 K of 192
+    channels is padded to 256 lanes in HBM: 4/3 of its bytes held and read.
+    Two heads' keys of one position side by side are 384 channels, three
+    whole tiles: ``[.., KV / 2, positions, 2 D]``, which is a plain reshape
+    of a position's ``[KV, D]`` keys, so every write stays as it was.  The
+    decode kernels read a pair's block once with the pair's queries laid
+    block-diagonal over it (``_pack_queries``); V keeps its layout."""
+    return 2 if (head_dim > LANES and head_dim % LANES == LANES // 2
+                 and kv_heads % 2 == 0) else 1
+
+
+def _cache_pack(q, kc, vc) -> int:
+    """``pack_k``'s factor as a decode cache's shapes show it (K/V heads at
+    axis 2 of the stacked leaves): V's heads over K's rows of heads."""
+    kv = vc.shape[2]
+    f = kv // max(1, kc.shape[2])
+    if q.shape[2] % kv or f < 1 or kc.shape[2] * f != kv:
+        raise ValueError(
+            f"q heads ({q.shape[2]}) must be a multiple of kv heads ({kv}; "
+            f"the K cache holds them in {kc.shape[2]} rows, which must "
+            f"divide them)")
+    if kc.shape[-1] != f * q.shape[-1]:
+        raise ValueError(
+            f"a K cache row of {kc.shape[-1]} channels is not {f} heads of "
+            f"the queries' size ({q.shape[-1]})")
+    return f
+
+
+def _unpack_k(k, f: int):
+    """[.., KV / f, M, f * D] -> [.., KV, M, D] (the reference paths)."""
+    if f == 1:
+        return k
+    *lead, rows, m, fd = k.shape
+    k = k.reshape(*lead, rows, m, f, fd // f)
+    return jnp.moveaxis(k, -2, -3).reshape(*lead, rows * f, m, fd // f)
+
+
+def _pack_queries(qt, f: int):
+    """[B, KV, r, D] query rows a K/V head -> [B, KV / f, f * r, f * D]: the
+    rows of the ``f`` heads that share a packed K row, head ``i``'s in rows
+    ``i r .. (i + 1) r`` with its channels at ``i D .. (i + 1) D`` and
+    zeros elsewhere, so that ONE product with the row's block gives every
+    head its own scores."""
+    if f == 1:
+        return qt
+    b, kv, r, d = qt.shape
+    q6 = qt.reshape(b, kv // f, f, r, 1, d) * jnp.eye(
+        f, dtype=qt.dtype)[None, None, :, None, :, None]
+    return q6.reshape(b, kv // f, f * r, f * d)
+
+
+def _sink_softmax(s, sink):
+    """Softmax over the last axis of ``s`` with one more logit, ``sink``
+    (broadcast against ``s`` with a trailing 1), in the denominator only."""
+    m = jnp.maximum(jnp.max(s, axis=-1, keepdims=True), sink)
+    p = jnp.exp(s - m)
+    return p / (jnp.sum(p, axis=-1, keepdims=True) + jnp.exp(sink - m))
+
 
 
 def mha_reference(q, k, v, causal: bool = False, scale: Optional[float] = None,
-                  window: Optional[int] = None):
+                  window: Optional[int] = None, sink=None):
     """Plain-XLA scaled-dot-product attention (ground truth / fallback).
 
     Grouped-query attention is accepted directly: when ``k``/``v`` carry
@@ -134,7 +213,9 @@ def mha_reference(q, k, v, causal: bool = False, scale: Optional[float] = None,
     materializing the repeat.
 
     ``window`` (requires ``causal``): sliding-window attention — query i
-    sees keys [i-window+1, i] only."""
+    sees keys [i-window+1, i] only.  ``sink`` ([H] float32): one logit a
+    query head that joins the softmax's denominator and carries no value.
+    K and V may differ in their head size."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     if window is not None and not causal:
@@ -153,7 +234,11 @@ def mha_reference(q, k, v, causal: bool = False, scale: Optional[float] = None,
         if window is not None:
             bad = bad | (kpos < qpos - (window - 1))
         scores = jnp.where(bad, NEG_INF, scores)
-    probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+    if sink is not None:
+        probs = _sink_softmax(scores, jnp.asarray(sink, jnp.float32)[
+            None, :, None, None]).astype(v.dtype)
+    else:
+        probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
@@ -192,21 +277,27 @@ def _round_up(x: int, m: int) -> int:
 
 
 def _flash_vmem_bytes(block_q: int, block_k: int, t_k: int, head_dim: int,
-                      itemsize: int) -> int:
+                      itemsize: int, v_dim: Optional[int] = None) -> int:
     """VMEM the forward kernel reserves at a tile: one KV head's K and V
     whole (padded to ``block_k``) and the q / o / lse blocks, each
     double-buffered by the pipeline, plus the float32 score block, its
     probabilities in the operand dtype, and the (o, m, l) accumulators
-    ([rows, 1] columns occupy whole 128-lane rows)."""
-    kv = 2 * 2 * _round_up(t_k, block_k) * head_dim * itemsize
-    qo = 2 * 2 * block_q * head_dim * itemsize + 2 * block_q * 128 * 4
+    ([rows, 1] columns occupy whole 128-lane rows).  ``v_dim``: the
+    values' head size where it is not the keys'."""
+    # channels past one lane tile occupy whole tiles (192 stands as 256)
+    lanes = lambda x: x if x <= LANES else _round_up(x, LANES)
+    v_dim = lanes(head_dim if v_dim is None else v_dim)
+    head_dim = lanes(head_dim)
+    kv = 2 * _round_up(t_k, block_k) * (head_dim + v_dim) * itemsize
+    qo = 2 * block_q * (head_dim + v_dim) * itemsize + 2 * block_q * 128 * 4
     scores = block_q * block_k * (2 * 4 + itemsize)
-    acc = block_q * (head_dim + 2 * 128) * 4
+    acc = block_q * (v_dim + 2 * 128) * 4
     return kv + qo + scores + acc
 
 
 def _flash_tiles(t_q: int, t_k: int, head_dim: int, itemsize: int,
-                 target_q: int = 512, target_k: int = 512):
+                 target_q: int = 512, target_k: int = 512,
+                 v_dim: Optional[int] = None):
     """(block_q, block_k) of the forward kernel, from the chip and not from
     the divisors of the lengths: a length at or under its target is one
     block; a longer one is cut into ``ceil(t / target)`` equal blocks,
@@ -224,7 +315,7 @@ def _flash_tiles(t_q: int, t_k: int, head_dim: int, itemsize: int,
 
     bq, bk = cut(t_q, target_q, sub), cut(t_k, target_k, 128)
     while (max(bq, bk) > 256 and _flash_vmem_bytes(
-            bq, bk, t_k, head_dim, itemsize) > _VMEM_SCOPED_LIMIT):
+            bq, bk, t_k, head_dim, itemsize, v_dim) > _VMEM_SCOPED_LIMIT):
         if bk >= bq:
             bk = _round_up(bk // 2, 128)
         else:
@@ -232,8 +323,7 @@ def _flash_tiles(t_q: int, t_k: int, head_dim: int, itemsize: int,
     return bq, bk
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, cfg: _FlashCfg,
-                  seq_len: int):
+def _flash_kernel(q_ref, k_ref, v_ref, *rest, cfg: _FlashCfg, seq_len: int):
     """One (batch, head, q-block) grid cell: stream K/V blocks with online
     softmax.  Accumulation in fp32; output cast back at the end.
 
@@ -245,7 +335,13 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, cfg: _FlashCfg,
     Operands stay in their input dtype (bf16 runs the MXU at full rate) with
     fp32 accumulation via ``preferred_element_type``; softmax statistics are
     fp32 throughout.
+
+    With a sink (``rest`` then starts with the [H] float32 logits, in SMEM)
+    the head's logit starts the recurrence in the place of an empty one:
+    running maximum ``b_h``, denominator 1, no value.  The softmax is then
+    exact whatever the order of the blocks, and no row is ever empty.
     """
+    sink_ref, o_ref, lse_ref = rest if len(rest) == 3 else (None, *rest)
     q = q_ref[0, 0, :, :]  # [bq, d], input dtype
     bq, bk = cfg.block_q, cfg.block_k
     q_lo = pl.program_id(2) * bq + cfg.q_offset  # first row's position
@@ -309,10 +405,12 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, cfg: _FlashCfg,
             preferred_element_type=jnp.float32)
         return o_new, m_new, l_new
 
-    d = q.shape[-1]
+    d = v_ref.shape[-1]
+    m0, l0 = ((NEG_INF, 0.0) if sink_ref is None
+              else (sink_ref[pl.program_id(1)], 1.0))
     carry = (jnp.zeros((bq, d), jnp.float32),
-             jnp.full((bq, 1), NEG_INF, jnp.float32),
-             jnp.zeros((bq, 1), jnp.float32))
+             jnp.full((bq, 1), m0, jnp.float32),
+             jnp.full((bq, 1), l0, jnp.float32))
     if cfg.window is None and (cfg.causal or n_clear):
         carry = jax.lax.fori_loop(0, n_clear, functools.partial(step, False),
                                   carry)
@@ -337,14 +435,19 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, cfg: _FlashCfg,
         lse_ref[0, 0, :, :] = m + jnp.log(l)
 
 
-def _flash_forward(cfg: _FlashCfg, q, k, v):
+def _flash_forward(cfg: _FlashCfg, q, k, v, sink=None):
     """The forward kernel at ``cfg``'s tile, which need not divide either
     length: q is padded to whole ``block_q`` blocks and K/V to whole
     ``block_k`` blocks with zeros (so a masked score never meets garbage
     in ``p @ v``), inside the transposes this wrapper makes anyway; the
     kernel masks keys past the array by position and the rows past ``T``
-    are cut off again.  Returns ``o`` [B, T, H, D] and ``lse`` [B, H, T, 1]."""
+    are cut off again.  Returns ``o`` [B, T, H, Dv] and ``lse`` [B, H, T, 1]
+    (K and V may differ in their head size).  ``sink`` ([H] float32): a
+    logit a head in the softmax's denominator (and in ``lse``); the call is
+    then named ``flash_attention_fwd_sink``, so that a device trace tells
+    it from a sinkless forward of the same shapes."""
     b, t, h, d = q.shape
+    dv = v.shape[-1]
     tk = k.shape[1]
     g = h // k.shape[2]  # q heads per kv head (1 = plain MHA)
     bq, bk = cfg.block_q, cfg.block_k
@@ -373,24 +476,37 @@ def _flash_forward(cfg: _FlashCfg, q, k, v):
     lse_spec = pl.BlockSpec((1, 1, bq, 1),
                             lambda bi, hi, qi: (bi, hi, qi, 0),
                             memory_space=pltpu.VMEM)
+    # V and the result at the values' head size (the keys' unless stated)
+    v_spec = pl.BlockSpec((1, 1, kt.shape[2], dv),
+                          lambda bi, hi, qi: (bi, hi // g, 0, 0),
+                          memory_space=pltpu.VMEM)
+    o_spec = pl.BlockSpec((1, 1, bq, dv),
+                          lambda bi, hi, qi: (bi, hi, qi, 0),
+                          memory_space=pltpu.VMEM)
+    in_specs, operands = [q_spec, kv_spec, v_spec], [qt, kt, vt]
+    if sink is not None:
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+        operands.append(jnp.asarray(sink, jnp.float32).reshape(h))
     kernel = functools.partial(_flash_kernel, cfg=cfg, seq_len=tk)
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=[q_spec, lse_spec],
-        out_shape=[jax.ShapeDtypeStruct(qt.shape, q.dtype),
+        in_specs=in_specs,
+        out_specs=[o_spec, lse_spec],
+        out_shape=[jax.ShapeDtypeStruct(qt.shape[:3] + (dv,), q.dtype),
                    jax.ShapeDtypeStruct((b, h, t_pad, 1), jnp.float32)],
         interpret=cfg.interpret,
-        name="flash_attention_fwd",
+        name=("flash_attention_fwd" if sink is None
+              else "flash_attention_fwd_sink"),
         compiler_params=None if cfg.interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         cost_estimate=pl.CostEstimate(
-            flops=4 * b * h * t * tk * d,
-            bytes_accessed=(q.size + k.size + v.size + q.size) * q.dtype.itemsize,
+            flops=2 * b * h * t * tk * (d + dv),
+            bytes_accessed=(q.size + k.size + v.size + b * t * h * dv)
+            * q.dtype.itemsize,
             transcendentals=b * h * t * tk,
         ),
-    )(qt, kt, vt)
+    )(*operands)
     return out[:, :, :t].transpose(0, 2, 1, 3), lse[:, :, :t]
 
 
@@ -635,7 +751,7 @@ def flash_attention(q, k, v, causal: bool = False, scale: Optional[float] = None
                     use_pallas: Optional[bool] = None,
                     interpret: bool = False,
                     window: Optional[int] = None,
-                    forward_only: bool = False):
+                    forward_only: bool = False, sink=None):
     """Blocked attention; Pallas kernel on TPU, reference math elsewhere.
 
     ``use_pallas=None`` auto-selects: the kernel runs when the default
@@ -655,9 +771,15 @@ def flash_attention(q, k, v, causal: bool = False, scale: Optional[float] = None
 
     ``forward_only``: the caller takes no gradient of the result (the
     serving path's prefill states it).  Only then does a causal
-    self-attention past ``FLASH_MAX_KEYS`` keys run segment by segment
+    self-attention past ``flash_max_keys`` keys run segment by segment
     (``_flash_segmented``, which has no VJP); every other call goes through
     the differentiable kernel pair at any length, as it always has.
+
+    K and V of unequal head size (the result has V's) and ``sink`` ([H]
+    float32: a logit a query head that joins the softmax's denominator and
+    carries no value) are the forward's: the backward kernels keep one head
+    size and no sink, and a call that does not state ``forward_only``
+    is refused with either.
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
@@ -676,8 +798,9 @@ def flash_attention(q, k, v, causal: bool = False, scale: Optional[float] = None
     # back to XLA instead of dragging a whole [t, t] score block through
     # VMEM in the backward.
     aligned = _pick_block(t) <= 1024 and _pick_block(tk) <= 1024
+    dv = v.shape[-1]
     block_q, block_k = _flash_tiles(t, tk, q.shape[-1], q.dtype.itemsize,
-                                    block_q, block_k)
+                                    block_q, block_k, dv)
     if use_pallas is None:
         on_tpu = jax.default_backend() == "tpu"
         use_pallas = aligned and (on_tpu or interpret)
@@ -689,36 +812,56 @@ def flash_attention(q, k, v, causal: bool = False, scale: Optional[float] = None
             f"Mosaic-legal block tiling for the backward kernels")
     if not use_pallas:
         return mha_reference(q, k, v, causal=causal, scale=scale,
-                             window=window)
-    if forward_only and causal and t == tk and tk > FLASH_MAX_KEYS:
+                             window=window, sink=sink)
+    forward_form = sink is not None or dv != q.shape[-1]
+    if forward_form and not forward_only:
+        raise ValueError(
+            "flash_attention: K and V of unequal head size and a sink are "
+            "the forward kernel's (the backward kernels keep Dk == Dv and "
+            "no sink): state forward_only=True")
+    max_keys = flash_max_keys(q.shape[-1], dv, q.dtype.itemsize)
+    if forward_only and causal and t == tk and tk > max_keys:
         return _flash_segmented(q, k, v, float(scale), window,
-                                bool(interpret))
+                                bool(interpret), sink)
     cfg = _FlashCfg(causal=bool(causal), scale=float(scale),
                     block_q=block_q, block_k=block_k,
                     interpret=bool(interpret),
                     window=None if window is None else int(window))
+    if forward_form:
+        return _flash_forward(cfg, q, k, v, sink)[0]
     return _flash(cfg, q, k, v)
 
 
-#: keys one call of the forward kernel may hold where the caller states
-#: ``forward_only``: a KV head's K and V are whole in VMEM, and at 16,384
-#: keys of 128 channels Mosaic asked 48.5 MB of a v5e's 48
-FLASH_MAX_KEYS = 8192
+#: bytes of ONE K/V head's K and V (channels in whole lane tiles) one call
+#: of the forward kernel may hold where the caller states ``forward_only``:
+#: they are whole in VMEM, double-buffered, and at 16,384 keys of 128 + 128
+#: bfloat16 channels (8 MiB) Mosaic asked 48.5 MB of a v5e's 48.  This is
+#: 8,192 such keys: the count PR 42 set, as the bytes it stood for.
+FLASH_MAX_KV_BYTES = 8192 * (128 + 128) * 2
+
+
+def flash_max_keys(d_k: int, d_v: int, itemsize: int) -> int:
+    """Keys one forward call may hold under ``FLASH_MAX_KV_BYTES``: 8,192
+    of 128 + 128 bfloat16 channels, 5,461 of 192 (256 lanes) + 128."""
+    return max(1, FLASH_MAX_KV_BYTES // (
+        (_round_up(d_k, LANES) + _round_up(d_v, LANES)) * itemsize))
 
 
 def _flash_segmented(q, k, v, scale: float, window: Optional[int],
-                     interpret: bool):
-    """Causal self-attention of a sequence longer than ``FLASH_MAX_KEYS``
+                     interpret: bool, sink=None):
+    """Causal self-attention of a sequence longer than ``flash_max_keys``
     (forward only, no VJP: the serving path's long prompts, which ask for it
     with ``flash_attention(forward_only=True)``): the sequence is cut
     into equal segments of at most that many positions, segment ``i``'s
     queries attend each segment ``j <= i`` of the keys their window reaches
     through the forward kernel at the static offset ``(i - j) * segment``,
     and the normalized partials are merged by their log-sum-exps, in
-    float32."""
+    float32.  A ``sink`` joins the FIRST partial of every query segment and
+    no other: the merge then counts it once."""
     t = q.shape[1]
-    n = -(-t // FLASH_MAX_KEYS)
-    seg = min(_round_up(-(-t // n), 512), FLASH_MAX_KEYS)
+    max_keys = flash_max_keys(q.shape[-1], v.shape[-1], q.dtype.itemsize)
+    n = -(-t // max_keys)
+    seg = min(_round_up(-(-t // n), 512), max_keys)
     outs = []
     for i in range(n):
         cut = lambda x, a: x[:, a * seg:(a + 1) * seg]
@@ -729,11 +872,12 @@ def _flash_segmented(q, k, v, scale: float, window: Optional[int],
         for j in range(first, i + 1):
             kj, vj = cut(k, j), cut(v, j)
             bq, bk = _flash_tiles(qi.shape[1], kj.shape[1], q.shape[-1],
-                                  q.dtype.itemsize)
+                                  q.dtype.itemsize, v_dim=v.shape[-1])
             oj, lj = _flash_forward(
                 _FlashCfg(causal=True, scale=scale, block_q=bq, block_k=bk,
                           interpret=interpret, window=window,
-                          q_offset=(i - j) * seg), qi, kj, vj)
+                          q_offset=(i - j) * seg), qi, kj, vj,
+                sink if j == first else None)
             oj = oj.astype(jnp.float32)
             if o is None:
                 o, lse = oj, lj
@@ -830,13 +974,15 @@ def eva_prefill_attention(q, k, v, k_pool, v_pool, layer, page_table,
     return (o / l).reshape(b, t, h, d).astype(q.dtype)
 
 
-def _decode_reference(q, k_cache, v_cache, pos, scale):
+def _decode_reference(q, k_cache, v_cache, pos, scale, sink=None):
     """Dense masked attention of a query chunk over a KV cache (ground
     truth / non-TPU path for ``flash_decode``).  Grouped einsum: the cache
     streams at kv width, q heads grouped kv-major as [kv, g].  ``q`` is
     [B, H, D] (single token) or [B, t, H, D] (chunk; token tt sees
     positions <= pos + tt); the cache is the kernel-native
-    [B, KV, M, D] (seq and head_dim trailing)."""
+    [B, KV, M, D] (seq and head_dim trailing; V's head size may be another
+    than K's, and is the result's).  ``sink``: [H] float32, a logit a head
+    in the denominator only."""
     squeeze = q.ndim == 3
     if squeeze:
         q = q[:, None]
@@ -853,9 +999,13 @@ def _decode_reference(q, k_cache, v_cache, pos, scale):
            pos[:, None, None] + jnp.arange(t, dtype=jnp.int32)[None, :,
                                                                None])
     s = jnp.where(bad[:, None, None], NEG_INF, s)       # [b,kv,g,t,m]
-    p = jax.nn.softmax(s, axis=-1).astype(v_cache.dtype)
+    if sink is not None:
+        p = _sink_softmax(s, jnp.asarray(sink, jnp.float32).reshape(
+            1, kv, g, 1, 1)).astype(v_cache.dtype)
+    else:
+        p = jax.nn.softmax(s, axis=-1).astype(v_cache.dtype)
     o = jnp.einsum("bkgtm,bkmd->btkgd", p, v_cache)
-    o = o.reshape(b, t, h, d)
+    o = o.reshape(b, t, h, v_cache.shape[-1])
     return o[:, 0] if squeeze else o
 
 
@@ -893,7 +1043,8 @@ def _decode_accumulate(s, v_blk, acc, vs_row=None):
 
 
 def _flash_decode_kernel(s_ref, q_ref, k_ref, v_ref, *rest, block_m: int,
-                         scale: float, quantized: bool, q_per_kv: int):
+                         scale: float, quantized: bool, q_per_kv: int,
+                         pack: int = 1, sink: bool = False):
     """One (batch, kv-head, m-block) grid step of cache-bounded decode.
 
     The q block carries this kv head's rows for the WHOLE chunk, t-major:
@@ -923,42 +1074,65 @@ def _flash_decode_kernel(s_ref, q_ref, k_ref, v_ref, *rest, block_m: int,
     single-host steps defer their pool commit, so only
     ``_flash_decode_paged_kernel`` carries the self block — the linear
     cache commits before attending and this kernel reads it directly.
+
+    ``pack`` > 1 (``pack_k``; not with ``quantized``): the K block is a
+    ROW of ``pack`` heads' keys side by side, [block_m, pack * d], and the
+    q block the heads' rows block-diagonal over it (``_pack_queries``):
+    one product gives head ``i`` its scores in rows ``i r .. (i + 1) r``
+    (r = t * g), which then meet head ``i``'s own V block and its rows of
+    the scratch.  ``sink``: a [pack * r, 1] float32 operand, each row's
+    head's logit, starts the recurrence in the place of an empty one
+    (maximum the logit, denominator 1, no value).
     """
     it = list(rest)
+    ks_ref = vs_ref = sink_ref = None
     if quantized:
         ks_ref, vs_ref = it[0], it[1]
         it = it[2:]
+    if sink:
+        sink_ref, it = it[0], it[1:]
     o_ref, o_acc, m_acc, l_acc = it
     bi = pl.program_id(0)
     j = pl.program_id(2)
     nb = s_ref[0, bi]      # per-batch-row block bound (ragged serving)
     pos = s_ref[1, bi]     # first chunk position for this row
+    r = o_acc.shape[0] // pack      # rows of one K/V head: t * g
 
     @pl.when(j == 0)
     def _init():
         o_acc[...] = jnp.zeros_like(o_acc)
-        m_acc[...] = jnp.full_like(m_acc, NEG_INF)
-        l_acc[...] = jnp.zeros_like(l_acc)
+        if sink:
+            m_acc[...] = sink_ref[0, :, :]
+            l_acc[...] = jnp.ones_like(l_acc)
+        else:
+            m_acc[...] = jnp.full_like(m_acc, NEG_INF)
+            l_acc[...] = jnp.zeros_like(l_acc)
 
     @pl.when(j < nb)
     def _step():
-        q = q_ref[0, 0, :, :]                       # [t*g, d]
+        q = q_ref[0, 0, :, :]                       # [pack*t*g, pack*d]
         s = _decode_block_scores(
             q, k_ref[0, 0, 0, :, :], scale,
             ks_ref[0, 0, 0, 0, :] if quantized else None)
         kpos = j * block_m + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1)
-        tt = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // q_per_kv
+        tt = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        tt = (tt % r if pack > 1 else tt) // q_per_kv
         s = jnp.where(kpos > pos + tt, NEG_INF, s)
-        m_acc[...], l_acc[...], o_acc[...] = _decode_accumulate(
-            s, v_ref[0, 0, 0, :, :], (m_acc[...], l_acc[...], o_acc[...]),
-            vs_ref[0, 0, 0, 0, :] if quantized else None)
+        for i in range(pack):
+            at = slice(i * r, (i + 1) * r)
+            m_acc[at], l_acc[at], o_acc[at] = _decode_accumulate(
+                s[at], v_ref[0, 0, i, :, :],
+                (m_acc[at], l_acc[at], o_acc[at]),
+                vs_ref[0, 0, 0, 0, :] if quantized else None)
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _finish():
         # Every row has at least one attended slot (block 0 holds
         # position 0), so l > 0.
-        o_ref[0, 0, :, :] = (o_acc[...] / l_acc[...]).astype(o_ref.dtype)
+        o = (o_acc[...] / l_acc[...]).astype(o_ref.dtype)
+        for i in range(pack):
+            o_ref[0, i, :, :] = o[i * r:(i + 1) * r]
 
 
 def _dequant_lane_major(qt_leaf, dtype):
@@ -1004,7 +1178,7 @@ def _stacked_cache(k_cache, v_cache, layer):
 
 def flash_decode(q, k_cache, v_cache, pos, scale: Optional[float] = None,
                  block_m: int = 1024, use_pallas: Optional[bool] = None,
-                 interpret: bool = False, layer=None):
+                 interpret: bool = False, layer=None, sink=None):
     """Single-token decode attention over a KV cache, bounded at ``pos``.
 
     ``q``: [B, H, D] (one new token's heads, kv-major groups) or
@@ -1036,6 +1210,12 @@ def flash_decode(q, k_cache, v_cache, pos, scale: Optional[float] = None,
     bigger blocks cut per-step grid overhead — measured 2.62 -> 2.25
     ms/step on the 16k-buffer decode_longctx config (v5e, round 5);
     ``_pick_block`` still clamps to a legal divisor for small caches.
+
+    K and V of unequal head size: ``v_cache`` [(L,) B, KV, M, Dv], the
+    result [.., H, Dv].  A PACKED K cache (``pack_k``: [(L,) B, KV / f, M,
+    f * D], ``f`` heads' keys of a position side by side) is told by its
+    shape; plain arrays only.  ``sink`` ([H] float32): a logit a query head
+    that joins the softmax's denominator and carries no value.
     """
     kc, vc, ksc, vsc, li, quantized = _stacked_cache(k_cache, v_cache,
                                                      layer)
@@ -1043,8 +1223,10 @@ def flash_decode(q, k_cache, v_cache, pos, scale: Optional[float] = None,
     if squeeze:
         q = q[:, None]
     b, t, h, d = q.shape
-    kv, m = kc.shape[2], kc.shape[3]
-    _check_gqa_heads(q, kc, vc)     # heads at axis 2 of the stacked cache
+    kv, m, dv = vc.shape[2], kc.shape[3], vc.shape[-1]
+    f = _cache_pack(q, kc, vc)      # heads at axis 2 of the stacked cache
+    if quantized and (f > 1 or dv != d):
+        raise ValueError("an int8 cache keeps one head size for K and V")
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     g = h // kv
@@ -1056,12 +1238,12 @@ def flash_decode(q, k_cache, v_cache, pos, scale: Optional[float] = None,
     if not use_pallas:
         take = lambda a: jax.lax.dynamic_index_in_dim(a, li, 0,
                                                       keepdims=False)
-        k_l, v_l = take(kc), take(vc)
+        k_l, v_l = _unpack_k(take(kc), f), take(vc)
         if quantized:
             from tfmesos_tpu.ops.quant import QTensor
             k_l = _dequant_lane_major(QTensor(k_l, take(ksc)), q.dtype)
             v_l = _dequant_lane_major(QTensor(v_l, take(vsc)), q.dtype)
-        out = _decode_reference(q, k_l, v_l, pos, scale)
+        out = _decode_reference(q, k_l, v_l, pos, scale, sink)
         return out[:, 0] if squeeze else out
 
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
@@ -1079,16 +1261,21 @@ def flash_decode(q, k_cache, v_cache, pos, scale: Optional[float] = None,
     qt = q.reshape(b, t, kv, g, d).transpose(0, 2, 1, 3, 4).reshape(
         b, kv, t * g, d)
 
-    q_spec = pl.BlockSpec((1, 1, t * g, d),
+    # A grid step covers one ROW of K heads (``f`` of them: one without
+    # packing) and those heads' V blocks and output rows.
+    q_spec = pl.BlockSpec((1, 1, f * t * g, f * d),
                           lambda bi, hi, j, s: (bi, hi, 0, 0),
                           memory_space=pltpu.VMEM)
-    kv_spec = pl.BlockSpec(
-        (1, 1, 1, block_m, d),
+    o_spec = pl.BlockSpec((1, f, t * g, dv),
+                          lambda bi, hi, j, s: (bi, hi, 0, 0),
+                          memory_space=pltpu.VMEM)
+    cache_spec = lambda heads, width: pl.BlockSpec(
+        (1, 1, heads, block_m, width),
         lambda bi, hi, j, s: (s[2, 0], bi, hi,
                               jnp.minimum(j, s[0, bi] - 1), 0),
         memory_space=pltpu.VMEM)
-    in_specs = [q_spec, kv_spec, kv_spec]
-    operands = [qt, kc, vc]
+    in_specs = [q_spec, cache_spec(1, f * d), cache_spec(f, dv)]
+    operands = [_pack_queries(qt, f), kc, vc]
     if quantized:
         # Scales stay stacked lane-major [L, B, KV, 1, M]: positions on
         # the lane dim, same pinned index map as their values.
@@ -1099,32 +1286,42 @@ def flash_decode(q, k_cache, v_cache, pos, scale: Optional[float] = None,
             memory_space=pltpu.VMEM)
         in_specs += [sc_spec, sc_spec]
         operands += [ksc, vsc]
+    if sink is not None:
+        # each row's head's logit, in the rows' own order: [KV / f, f * t
+        # * g, 1] (row = head in the pack, chunk token, group member)
+        rows = jnp.broadcast_to(
+            jnp.asarray(sink, jnp.float32).reshape(kv // f, f, 1, g),
+            (kv // f, f, t, g)).reshape(kv // f, f * t * g, 1)
+        in_specs.append(pl.BlockSpec((1, f * t * g, 1),
+                                     lambda bi, hi, j, s: (hi, 0, 0),
+                                     memory_space=pltpu.VMEM))
+        operands.append(rows)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(b, kv, m // block_m),
+        grid=(b, kv // f, m // block_m),
         in_specs=in_specs,
-        out_specs=q_spec,
-        scratch_shapes=[pltpu.VMEM((t * g, d), jnp.float32),
-                        pltpu.VMEM((t * g, 1), jnp.float32),
-                        pltpu.VMEM((t * g, 1), jnp.float32)])
+        out_specs=o_spec,
+        scratch_shapes=[pltpu.VMEM((f * t * g, dv), jnp.float32),
+                        pltpu.VMEM((f * t * g, 1), jnp.float32),
+                        pltpu.VMEM((f * t * g, 1), jnp.float32)])
     out = pl.pallas_call(
         functools.partial(_flash_decode_kernel, block_m=block_m,
                           scale=float(scale), quantized=quantized,
-                          q_per_kv=g),
+                          q_per_kv=g, pack=f, sink=sink is not None),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, kv, t * g, dv), q.dtype),
         interpret=interpret,
         name="flash_decode",
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         cost_estimate=pl.CostEstimate(
-            flops=4 * b * t * h * m * d,
-            bytes_accessed=(kc[0].size * kc.dtype.itemsize * 2
+            flops=2 * b * t * h * m * (d + dv),
+            bytes_accessed=((kc[0].size + vc[0].size) * kc.dtype.itemsize
                             + 2 * q.size * q.dtype.itemsize),
             transcendentals=b * t * h * m),
     )(scalars, *operands)
-    out = out.reshape(b, kv, t, g, d).transpose(0, 2, 1, 3, 4).reshape(
-        b, t, h, d)
+    out = out.reshape(b, kv, t, g, dv).transpose(0, 2, 1, 3, 4).reshape(
+        b, t, h, dv)
     return out[:, 0] if squeeze else out
 
 
@@ -1143,7 +1340,9 @@ def _paged_decode_reference(q, k_pool, v_pool, page_table, pos, scale,
 
     kc, vc, ksc, vsc, li, quantized = _stacked_cache(k_pool, v_pool, layer)
     take = lambda a: jax.lax.dynamic_index_in_dim(a, li, 0, keepdims=False)
-    k_pool, v_pool = take(kc), take(vc)
+    # a packed K pool (``pack_k``) is read as the heads it holds
+    f = _cache_pack(q[:, None] if q.ndim == 3 else q, kc, vc)
+    k_pool, v_pool = _unpack_k(take(kc), f), take(vc)
     if quantized:
         # Paged pools carry LANE-MAJOR scales ([P, KV, 1, page]); move
         # them back over the positions to dequantize (test/CPU path —
@@ -1163,7 +1362,10 @@ def _paged_decode_reference(q, k_pool, v_pool, page_table, pos, scale,
             lambda v_, c_, p_: jax.lax.dynamic_update_slice(
                 v_, c_.astype(v_.dtype), (0, p_, 0)))(
             view, c.transpose(0, 2, 1, 3), posv)
-        k_view = put(k_view, self_kv[0])
+        ks = self_kv[0]
+        if f > 1:       # [B, t, KV / f, f * D] is [B, t, KV, D] as it lies
+            ks = ks.reshape(*ks.shape[:2], kv, -1)
+        k_view = put(k_view, ks)
         v_view = put(v_view, self_kv[1])
     return _decode_reference(q, k_view, v_view, pos, scale)
 
@@ -1171,7 +1373,8 @@ def _paged_decode_reference(q, k_pool, v_pool, page_table, pos, scale,
 def _flash_decode_paged_kernel(s_ref, walk_ref, fetch_ref, q_ref, *rest,
                                page: int, pages_per_block: int,
                                scale: float, quantized: bool, q_per_kv: int,
-                               head_block: int, self_attend: bool = False):
+                               head_block: int, self_attend: bool = False,
+                               pack: int = 1):
     """One (head-block, step of the walk) grid step of paged decode.
 
     A step's K/V block is ``pages_per_block`` pages of one row: the pool
@@ -1220,7 +1423,15 @@ def _flash_decode_paged_kernel(s_ref, walk_ref, fetch_ref, q_ref, *rest,
     is the FUSED multi-row step: a t-token chunk (speculative verify /
     chunked-prefill tail) retires t decode rows through ONE launch per
     layer, the page table scalar-prefetched once for the whole chunk
-    instead of once per step."""
+    instead of once per step.
+
+    ``pack`` > 1 (``pack_k``; not with ``quantized``): a K slab is
+    [head_block / pack, page, pack * d], a ROW of ``pack`` heads' keys
+    side by side, and the q block those heads' rows block-diagonal over it
+    (``_pack_queries``): one product a row of heads gives head ``i`` of it
+    its scores in rows ``i r .. (i + 1) r`` (r = t * g), which meet the
+    head's own V block and its slice of the scratch.  The self operand's K
+    is packed the same way."""
     del fetch_ref  # consumed by the slots' index maps
     ppb = pages_per_block
     it = list(rest)
@@ -1250,7 +1461,18 @@ def _flash_decode_paged_kernel(s_ref, walk_ref, fetch_ref, q_ref, *rest,
             body(h)
             return carry
 
-        jax.lax.fori_loop(0, head_block, step, 0, unroll=True)
+        jax.lax.fori_loop(0, head_block // pack, step, 0, unroll=True)
+
+    tg = o_acc.shape[1]             # rows of one K/V head: t * g
+
+    def accumulate(hk, s, v_of, vs_row=None):
+        """Scores ``s`` of K row ``hk`` ([pack * r, block]: its heads' rows
+        one under another) meet each head's own V block and scratch."""
+        for i in range(pack):
+            h = hk * pack + i if pack > 1 else hk
+            m_acc[h], l_acc[h], o_acc[h] = _decode_accumulate(
+                s[i * tg:(i + 1) * tg] if pack > 1 else s, v_of(h),
+                (m_acc[h], l_acc[h], o_acc[h]), vs_row)
 
     @pl.when(j == 0)
     def _init():
@@ -1277,11 +1499,11 @@ def _flash_decode_paged_kernel(s_ref, walk_ref, fetch_ref, q_ref, *rest,
                 # self block, which carries the causal mask.
                 s = jnp.where(kpos > bound, NEG_INF, s)
             else:
-                tt = jax.lax.broadcasted_iota(jnp.int32, s.shape,
-                                              0) // q_per_kv
+                tt = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+                tt = (tt % tg if pack > 1 else tt) // q_per_kv
                 s = jnp.where(kpos > bound + tt, NEG_INF, s)
-            m_acc[h], l_acc[h], o_acc[h] = _decode_accumulate(
-                s, join(v_refs, 0), (m_acc[h], l_acc[h], o_acc[h]),
+            accumulate(h, s, lambda hv: jnp.concatenate(
+                [ref[0, 0, hv] for ref in v_refs], axis=0),
                 join(vs_refs, 1) if quantized else None)
 
         heads(head)
@@ -1303,11 +1525,10 @@ def _flash_decode_paged_kernel(s_ref, walk_ref, fetch_ref, q_ref, *rest,
                 # ss's K/V, and row tt attends slots <= tt (t = 1 masks
                 # nothing — the single-token deferred step unchanged).
                 ss = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-                tt = jax.lax.broadcasted_iota(jnp.int32, s.shape,
-                                              0) // q_per_kv
+                tt = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+                tt = (tt % tg if pack > 1 else tt) // q_per_kv
                 s = jnp.where(ss > tt, NEG_INF, s)
-                m_acc[h], l_acc[h], o_acc[h] = _decode_accumulate(
-                    s, vself_ref[0, h], (m_acc[h], l_acc[h], o_acc[h]))
+                accumulate(h, s, lambda hv: vself_ref[0, hv])
 
             heads(head)
 
@@ -1350,6 +1571,11 @@ def flash_decode_paged(q, k_pool, v_pool, page_table, pos,
     through one launch per layer, the page table prefetched once for
     the chunk (int8 pools: pre-quantize-dequantize the chunk so its
     numerics match a committed slot).
+
+    K and V of unequal head size: ``v_pool`` [(L,) P, KV, page, Dv], the
+    result [.., H, Dv].  A PACKED K pool (``pack_k``: [(L,) P, KV / f,
+    page, f * D]) is told by its shape, and ``self_kv``'s K is then packed
+    the same way ([B, t, KV / f, f * D]: a reshape); plain arrays only.
     """
     PAGED_CALL_STATS["calls"] += 1
     kp, vp, ksc, vsc, li, quantized = _stacked_cache(k_pool, v_pool, layer)
@@ -1357,8 +1583,10 @@ def flash_decode_paged(q, k_pool, v_pool, page_table, pos,
     if squeeze:
         q = q[:, None]
     b, t, h, d = q.shape
-    kv, ps = kp.shape[2], kp.shape[3]
-    _check_gqa_heads(q, kp, vp)     # kv heads at axis 2 of the pool
+    kv, ps, dv = vp.shape[2], kp.shape[3], vp.shape[-1]
+    f = _cache_pack(q, kp, vp)      # kv heads at axis 2 of the pool
+    if quantized and (f > 1 or dv != d):
+        raise ValueError("an int8 pool keeps one head size for K and V")
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     g = h // kv
@@ -1367,7 +1595,8 @@ def flash_decode_paged(q, k_pool, v_pool, page_table, pos,
     # per grid step as the budget allows, so big kv x page x d products
     # shrink the block instead of losing the kernel.
     aligned = (ps % 8 == 0 and ps <= 1024
-               and 4 * ps * d * kp.dtype.itemsize <= _PAGED_VMEM_BUDGET)
+               and 2 * f * ps * (d + dv) * kp.dtype.itemsize
+               <= _PAGED_VMEM_BUDGET)
     if use_pallas is None:
         on_tpu = jax.default_backend() == "tpu"
         use_pallas = aligned and (on_tpu or interpret)
@@ -1411,9 +1640,12 @@ def flash_decode_paged(q, k_pool, v_pool, page_table, pos,
     # (the next row's first) copies under this step's compute.
     np_ = page_table.shape[1]
     head_block, ppb = _paged_block(kv, ps, d, kp.dtype.itemsize, np_,
-                                   quantized)
+                                   quantized, dv, f)
     walk, fetch, total = _paged_walk(page_table, nb, ppb)
-    q_spec = pl.BlockSpec((1, head_block, t * g, d),
+    q_spec = pl.BlockSpec((1, head_block // f, f * t * g, f * d),
+                          lambda hi, at, s, wk, ft: (wk[0, at], hi, 0, 0),
+                          memory_space=pltpu.VMEM)
+    o_spec = pl.BlockSpec((1, head_block, t * g, dv),
                           lambda hi, at, s, wk, ft: (wk[0, at], hi, 0, 0),
                           memory_space=pltpu.VMEM)
 
@@ -1424,8 +1656,10 @@ def flash_decode_paged(q, k_pool, v_pool, page_table, pos,
                                             hi, 0, 0),
             memory_space=pltpu.VMEM) for i in range(ppb)]
 
-    in_specs = [q_spec] + 2 * slot_specs((1, 1, head_block, ps, d))
-    operands = [qt] + [kp] * ppb + [vp] * ppb   # pools (page, d)-trailing
+    in_specs = ([q_spec] + slot_specs((1, 1, head_block // f, ps, f * d))
+                + slot_specs((1, 1, head_block, ps, dv)))
+    # pools (page, d)-trailing
+    operands = [_pack_queries(qt, f)] + [kp] * ppb + [vp] * ppb
     if quantized:
         # Scales as [L, P, KV, 1, page]: positions on the lane dim, each
         # page's beside its values under the same index map.
@@ -1437,18 +1671,19 @@ def flash_decode_paged(q, k_pool, v_pool, page_table, pos,
         # numerics match a committed slot exactly).
         kself, vself = (c.transpose(0, 2, 1, 3).astype(q.dtype)
                         for c in self_kv)
-        self_spec = pl.BlockSpec((1, head_block, t, d),
-                                 lambda hi, at, s, wk, ft: (wk[0, at], hi,
-                                                            0, 0),
-                                 memory_space=pltpu.VMEM)
-        in_specs += [self_spec, self_spec]
+        self_spec = lambda heads, width: pl.BlockSpec(
+            (1, heads, t, width),
+            lambda hi, at, s, wk, ft: (wk[0, at], hi, 0, 0),
+            memory_space=pltpu.VMEM)
+        in_specs += [self_spec(head_block // f, f * d),
+                     self_spec(head_block, dv)]
         operands += [kself, vself]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(kv // head_block, total),
         in_specs=in_specs,
-        out_specs=q_spec,
-        scratch_shapes=[pltpu.VMEM((head_block, t * g, d), jnp.float32),
+        out_specs=o_spec,
+        scratch_shapes=[pltpu.VMEM((head_block, t * g, dv), jnp.float32),
                         pltpu.VMEM((head_block, t * g, 1), jnp.float32),
                         pltpu.VMEM((head_block, t * g, 1), jnp.float32)])
     # Static cost estimate.  bytes_accessed charges the slabs this
@@ -1466,27 +1701,27 @@ def flash_decode_paged(q, k_pool, v_pool, page_table, pos,
     except jax.errors.ConcretizationTypeError:
         est_nb = np_
     est_nb = max(1, min(est_nb, np_))
-    slab_bytes = kv * ps * d * kp.dtype.itemsize
+    slab_bytes = kv * ps * (d + dv) * kp.dtype.itemsize // 2
     out = pl.pallas_call(
         functools.partial(_flash_decode_paged_kernel, page=ps,
                           pages_per_block=ppb, scale=float(scale),
                           quantized=quantized, q_per_kv=g,
                           head_block=head_block,
-                          self_attend=self_kv is not None),
+                          self_attend=self_kv is not None, pack=f),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, kv, t * g, dv), q.dtype),
         interpret=interpret,
         name="flash_decode_paged",
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         cost_estimate=pl.CostEstimate(
-            flops=4 * b * t * h * est_nb * ps * d,
+            flops=2 * b * t * h * est_nb * ps * (d + dv),
             bytes_accessed=(2 * b * est_nb * slab_bytes
                             + 2 * q.size * q.dtype.itemsize),
             transcendentals=b * t * h * est_nb * ps),
     )(scalars, walk, fetch, *operands)
-    out = out.reshape(b, kv, t, g, d).transpose(0, 2, 1, 3, 4).reshape(
-        b, t, h, d)
+    out = out.reshape(b, kv, t, g, dv).transpose(0, 2, 1, 3, 4).reshape(
+        b, t, h, dv)
     return out[:, 0] if squeeze else out
 
 

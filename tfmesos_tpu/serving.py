@@ -2036,8 +2036,12 @@ class ContinuousBatcher:
             # held experts of every layer and step that took at least one
             # and the rows of the live tiles those assignments were padded
             # to (each (step, layer, expert)'s up to whole tiles)
+            # ``moe_routed``: the assignments the routers made over ALL
+            # ``n_experts`` for the block's rows (``moe_assignments`` over
+            # it is the share of them this shard's experts took)
             rec.update(moe_assignments=0, moe_expert_max=0,
-                       moe_experts_touched=0, moe_tile_rows=0)
+                       moe_experts_touched=0, moe_tile_rows=0,
+                       moe_routed=0)
         if self._swa:
             # a decode block's rows: the contexts they reach, and the
             # positions of them a window layer's ring holds
@@ -2135,13 +2139,15 @@ class ContinuousBatcher:
         return _Phase(self._tick, name, stats)
 
     def _tick_moe(self, block: np.ndarray) -> np.ndarray:
-        """A block as read back, ``[rows (+ 4), K]``: with a grouped expert
+        """A block as read back, ``[rows (+ 5), K]``: with a grouped expert
         layer its last four rows are the block's expert counters
         (``[assignments, most one expert took, experts touched, rows of the
-        live tiles]``, computed beside its tokens), which go into the tick
-        record.  Returns the tokens, ``[rows, K]``."""
+        live tiles, assignments routed over all experts]``, computed beside
+        its tokens), which go into the tick record.  Returns the tokens,
+        ``[rows, K]``."""
         if self._moe_counts:
-            a, m, n, tr = block[self.rows:, 0]
+            a, m, n, tr, routed = block[self.rows:, 0]
+            self._tick["moe_routed"] += int(routed)
             self._tick["moe_assignments"] += int(a)
             self._tick["moe_expert_max"] = max(self._tick["moe_expert_max"],
                                                int(m))
@@ -2594,18 +2600,23 @@ class ContinuousBatcher:
                 # [assignments on held experts, the most one expert took,
                 # (step, layer, expert)s that took any, the rows of the
                 # tiles they filled (``grouped_layout`` pads each expert's
-                # to whole tiles)] over the block, as four rows under the
-                # tokens: ONE array comes back to the
+                # to whole tiles), the assignments the routers made over all
+                # ``n_experts``: every row's ``top_k`` a step and expert
+                # layer, wherever they fell] over the block, as five rows
+                # under the tokens: ONE array comes back to the
                 # host (a second one is a second round trip every tick)
                 toks_all, counts = toks_all             # [K, L, held]
                 touched = jnp.sum(counts > 0)
                 tile_rows = moe_tile_rows(counts, self.rows, self.cfg.top_k,
                                           self.cfg.n_experts)
+                routed = (counts.shape[0] * counts.shape[1] * self.rows
+                          * self.cfg.top_k)
                 counts = jnp.sum(counts, axis=0)
                 stats = jnp.stack([jnp.sum(counts), jnp.max(counts),
-                                   touched, tile_rows]).astype(jnp.int32)
+                                   touched, tile_rows,
+                                   routed]).astype(jnp.int32)
                 return pool, jnp.concatenate(
-                    [toks_all.T, jnp.broadcast_to(stats[:, None], (4, K))])
+                    [toks_all.T, jnp.broadcast_to(stats[:, None], (5, K))])
             return pool, toks_all.T                         # [rows, K]
 
         if self._pipelined:
