@@ -651,6 +651,171 @@ def test_flash_decode_chunk_ragged_and_int8():
                                rtol=2e-5, atol=2e-5)
 
 
+# What a grid step of several K/V heads, and of several rows of a one-block
+# cache, can get wrong (PR 46).  name -> (b, kv, g, f, dv, sink, int8, t, m,
+# block_m, VMEM budget in KiB or None for the kernel's own, the (rows,
+# head_block) the rule must then give).  d = 32; ``f`` = 2 packs two heads'
+# keys a K row and takes values of another width (dv != d).  A float32 head's
+# K + V block of 16 positions stands in 16 KiB of VMEM (32 channels in a lane
+# tile each; 12 KiB a head of a packed pair, whose 64 channels share one), an
+# int8 head's of 32 in 8 KiB beside 16 KiB of scale tiles.  One block
+# (m == block_m): positions ragged across the rows of ONE step, a row at
+# position 0 beside a full one; several blocks (m > block_m): a step is one
+# row and rows end at different blocks.
+_DECODE_BLOCKS = {
+    "mqa_rows_all": (4, 1, 4, 1, 32, False, False, 1, 16, 16, None, (4, 1)),
+    "kv2_rows_all": (4, 2, 2, 1, 32, False, False, 1, 16, 16, None, (4, 2)),
+    "kv8_rows_all": (4, 8, 1, 1, 32, False, False, 1, 16, 16, None, (4, 8)),
+    "kv8_rows_2_of_4": (4, 8, 2, 1, 32, False, False, 1, 16, 16, 512, (2, 8)),
+    "kv8_rows_3_of_6_t3": (6, 8, 1, 1, 32, False, False, 3, 16, 16, 1024,
+                           (3, 8)),
+    "kv8_heads_4_one_block": (3, 8, 2, 1, 32, False, False, 1, 16, 16, 128,
+                              (1, 4)),
+    "kv8_sink_rows_2": (4, 8, 2, 1, 32, True, False, 1, 16, 16, 512, (2, 8)),
+    "kv8_int8_rows_2_t3": (4, 8, 2, 1, 32, False, True, 3, 32, 32, 768,
+                           (2, 8)),
+    "kv2_int8_rows_all": (3, 2, 4, 1, 32, False, True, 1, 32, 32, None,
+                          (3, 2)),
+    "packed_kv2_rows_all": (4, 2, 4, 2, 16, False, False, 1, 16, 16, None,
+                            (4, 2)),
+    "packed_kv8_sink_rows_2": (4, 8, 2, 2, 16, True, False, 1, 16, 16, 384,
+                               (2, 8)),
+    "packed_kv8_sink_rows_2_t3": (4, 8, 2, 2, 16, True, False, 3, 16, 16,
+                                  384, (2, 8)),
+    "packed_kv8_heads_4": (2, 8, 2, 2, 16, False, False, 1, 16, 16, 96,
+                           (1, 4)),
+    "blocks_4_kv1": (4, 1, 4, 1, 32, False, False, 1, 64, 16, None, (1, 1)),
+    "blocks_4_kv8": (4, 8, 2, 1, 32, False, False, 1, 64, 16, None, (1, 8)),
+    "blocks_4_kv8_heads_2_t3": (4, 8, 1, 1, 32, False, False, 3, 64, 16, 64,
+                                (1, 2)),
+    "blocks_4_kv8_sink": (4, 8, 2, 1, 32, True, False, 1, 64, 16, None,
+                          (1, 8)),
+    "blocks_2_kv8_int8_heads_4": (4, 8, 2, 1, 32, False, True, 1, 64, 32,
+                                  192, (1, 4)),
+    "blocks_4_packed_kv8_sink_t3": (4, 8, 2, 2, 16, True, False, 3, 64, 16,
+                                    None, (1, 8)),
+    "blocks_4_packed_kv8_heads_2": (4, 8, 2, 2, 16, False, False, 1, 64, 16,
+                                    48, (1, 2)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_DECODE_BLOCKS))
+def test_flash_decode_block_equivalence_matrix(name, monkeypatch):
+    """``flash_decode`` (interpret mode) against ``_decode_reference`` over
+    the block rule's cases: whatever rows and heads a grid step takes, every
+    row keeps its own position and mask and every head its own K, V, scales
+    and sink."""
+    from tfmesos_tpu.ops import attention
+    from tfmesos_tpu.ops.attention import _decode_reference, flash_decode
+
+    b, kv, g, f, dv, sink, int8, t, m, block_m, budget, expect = \
+        _DECODE_BLOCKS[name]
+    d = 32
+    if budget is not None:
+        monkeypatch.setattr(attention, "_PAGED_VMEM_BUDGET", budget * 1024)
+    assert attention._decode_block(b, kv, m // block_m, block_m, d, dv,
+                                   1 if int8 else 4, int8, f) == expect
+    ks = jax.random.split(jax.random.PRNGKey(46), 4)
+    q = jax.random.normal(ks[0], (b, t, kv * g, d), jnp.float32)
+    kc = jax.random.normal(ks[1], (b, kv, m, d), jnp.float32)
+    vc = jax.random.normal(ks[2], (b, kv, m, dv), jnp.float32)
+    logits = jax.random.normal(ks[3], (kv * g,)) if sink else None
+    # a row at position 0, a full one, and the rest ending at different
+    # blocks (or, in a cache of one block, at different slots of it)
+    last = m - t
+    pos = jnp.asarray(([0, last, block_m + 1, block_m - 1, 2 * block_m, 5]
+                       if m > block_m else [0, last, 7, 3, last, 1])[:b],
+                      jnp.int32)
+    if int8:
+        k_in, k_ref = _lane_major_quant(kc)
+        v_in, v_ref = _lane_major_quant(vc)
+    else:
+        k_in, v_in, k_ref, v_ref = kc, vc, kc, vc
+        if f > 1:   # [B, KV, M, D] -> [B, KV / f, M, f * D], heads side by side
+            k_in = jnp.moveaxis(kc.reshape(b, kv // f, f, m, d), 2, 3).reshape(
+                b, kv // f, m, f * d)
+    want = _decode_reference(q, k_ref, v_ref, pos, d ** -0.5, logits)
+    got = flash_decode(q, k_in, v_in, pos, use_pallas=True, interpret=True,
+                       block_m=block_m, sink=logits)
+    assert got.shape == (b, t, kv * g, dv)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("name,call,expect", [
+    # (rows of the batch, kv heads, blocks of the cache, positions a block,
+    # keys' channels, values', itemsize[, int8[, K heads a row]]) -> (rows,
+    # head block) of a grid step
+    ("mimo_rings", (128, 8, 1, 128, 192, 128, 2, False, 2), (4, 8)),
+    ("laguna_rings", (128, 8, 1, 512, 128, 128, 2), (2, 8)),    # the budget
+    ("dense_1024", (32, 8, 8, 1024, 128, 128, 2), (1, 8)),      # the budget
+    ("dense_1024_one_block", (8, 8, 1, 1024, 128, 128, 2), (1, 8)),
+    ("dense_1024_int8", (32, 8, 8, 1024, 128, 128, 1, True), (1, 8)),
+    ("dense_32_heads", (16, 32, 16, 1024, 128, 128, 2), (1, 8)),
+    ("dense_32_heads_int8", (16, 32, 16, 1024, 128, 128, 1, True), (1, 8)),
+    ("dense_12_heads", (4, 12, 4, 1024, 128, 128, 2), (1, 6)),
+    ("packed_16_heads", (8, 16, 8, 1024, 192, 128, 2, False, 2), (1, 4)),
+    ("packed_6_heads", (8, 6, 8, 1024, 320, 256, 2, False, 2), (1, 2)),
+    ("unpacked_192", (128, 8, 1, 128, 192, 128, 2), (4, 8)),    # 256 lanes
+    ("flagship_d64", (8, 8, 1, 1024, 64, 64, 2), (1, 8)),   # a lane tile each
+    ("flagship_d64_short", (8, 8, 1, 256, 64, 64, 2), (4, 8)),
+    ("rings_several_blocks", (128, 8, 4, 128, 192, 128, 2, False, 2), (1, 8)),
+    ("rows_of_6", (6, 8, 1, 128, 192, 128, 2, False, 2), (6, 8)),
+    ("rows_of_14", (14, 8, 1, 128, 192, 128, 2, False, 2), (2, 8)),
+    ("one_row", (1, 8, 1, 128, 128, 128, 2), (1, 8)),
+    ("tests_f32", (3, 2, 1, 16, 32, 32, 4), (3, 2)),
+    ("a_head_past_the_budget", (4, 2, 2, 1024, 2048, 2048, 4), (1, 1)),
+    ("a_pair_past_the_budget", (4, 4, 2, 1024, 1984, 2048, 4, False, 2),
+     (1, 2)),
+    ("mqa", (32, 1, 8, 1024, 128, 128, 2), (1, 1)),
+    ("mqa_ring", (32, 1, 1, 512, 128, 128, 2), (16, 1)),
+])
+def test_decode_block_rule(name, call, expect):
+    """``_decode_block`` is a pure function of what a call sees: every head
+    of a row where they fit, else a divisor of them in whole K rows; rows
+    only of a cache that is one block; the double-buffered K + V blocks
+    within the budget wherever one K row of heads fits at all."""
+    from tfmesos_tpu.ops import attention
+
+    rows, hb = attention._decode_block(*call)
+    assert (rows, hb) == expect
+    b, kv, blocks, block_m, d, dv, itemsize = call[:7]
+    quantized = call[7] if len(call) > 7 else False
+    f = call[8] if len(call) > 8 else 1
+    assert kv % hb == 0 and hb % f == 0 and b % rows == 0
+    assert rows == 1 or (blocks == 1 and hb == kv)
+    moved = 2 * rows * hb * block_m * (d + dv) * itemsize    # unpadded
+    if quantized:
+        moved += 2 * rows * hb * 2 * 8 * 128 * 4 * -(-block_m // 128)
+    assert moved <= attention._PAGED_VMEM_BUDGET or (rows, hb) == (1, f)
+
+
+@pytest.mark.parametrize("cell,shapes,grid", [
+    # (window layers, rows, kv, K heads a row, window, d, dv, q_per_kv)
+    ("mimo.agent_batch", (5, 128, 8, 2, 128, 192, 128, 8), (32, 1, 1)),
+    ("laguna.mixed_batch", (3, 128, 8, 1, 512, 128, 128, 8), (64, 1, 1)),
+])
+def test_ring_decode_grid_at_the_cells_shapes(cell, shapes, grid):
+    """The mechanism's engagement is the grid itself: traced (nothing runs)
+    at each cell's ring shapes, ONE ``flash_decode`` call of the rule's grid,
+    a row's heads and the budget's rows a step, its one result still
+    ``[rows, kv, t * g, dv]``."""
+    from tfmesos_tpu.ops.attention import flash_decode
+
+    layers, b, kv, f, w, d, dv, g = shapes
+    bf16 = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(lambda q, k, v, pos, li: flash_decode(
+        q, k, v, pos, layer=li, use_pallas=True))(
+        bf16(b, kv * g, d), bf16(layers, b, kv // f, w, f * d),
+        bf16(layers, b, kv, w, dv), jax.ShapeDtypeStruct((b,), jnp.int32),
+        jax.ShapeDtypeStruct((), jnp.int32))
+    calls = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    assert calls[0].params["grid_mapping"].grid == grid
+    assert calls[0].params["name"] == "flash_decode"
+    assert [o.aval.shape for o in calls[0].outvars] == [(b, kv, g, dv)]
+
+
 def test_decode_step_chunk_kernel_path_matches_dense():
     """decode_step on a multi-token chunk (the speculative-verify shape)
     with the kernel gate forced: logits match the einsum path, uniform

@@ -900,3 +900,49 @@ def test_unequal_head_sizes_compile_and_the_pool_pads_nothing(
     limit = {1: 5e7, 2112: 6e8, 16896: 3.0e9}[t]
     assert mem.temp_size_in_bytes < limit, mem.temp_size_in_bytes
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.3e9
+
+
+# -- the rings' decode kernel by rows and heads (PR 46) ------------------------
+#
+# ``flash_decode`` takes a row's K/V heads, and several rows of a one-block
+# ring, in one grid step (``_decode_block``).  What the benchmark's readers
+# tell the kernel by stays: ONE custom call a window layer named
+# ``flash_decode`` whose one result is ``[rows, kv heads, t * g, dv]``.  And
+# the rings ride into it WHERE THEY LIE: whole, in the store's own row-major
+# layout, no instruction of the step copying or transposing a ring, a layer of
+# one or anything of a ring's extent in front of the call.
+# model -> (table width, K ring, V ring)
+RING_CASES = {
+    "laguna": (17408 // PAGE, (3, 128, 8, 512, 128), (3, 128, 8, 512, 128)),
+    "mimo": (18944 // PAGE, (5, 128, 4, 128, 384), (5, 128, 8, 128, 128)),
+}
+
+
+@pytest.mark.parametrize("model", sorted(RING_CASES))
+def test_ring_decode_keeps_its_name_its_result_and_the_rings_in_place(
+        topo, model):
+    width, ring_k, ring_v = RING_CASES[model]
+    _, _, _, text = _compiled_step(topo, model, 128, 1, width)
+    calls = re.findall(r"%flash_decode[.\d]* = (\S+) custom-call\(.*?"
+                       r"operand_layout_constraints=\{(.*?)\}, \w+=", text)
+    assert calls, "no flash_decode custom call in the step"
+    row_major = lambda s: ("bf16[" + ",".join(map(str, s)) + "]{"
+                           + ",".join(map(str, range(len(s) - 1, -1, -1)))
+                           + "}")
+    for result, operands in calls:
+        assert result.startswith("bf16[128,8,8,128]{"), result
+        assert row_major(ring_k) in operands, operands
+        assert row_major(ring_v) in operands, operands
+    # a ring, a layer of one (with or without its leading 1), in any order
+    # of dims
+    extents = {tuple(sorted(s[cut:])) for s in (ring_k, ring_v)
+               for cut in (0, 1)}
+    moved = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) (?:copy|copy-start|"
+                     r"transpose)\(", line)
+        for dims in re.findall(r"bf16\[([\d,]+)\]", m.group(1)) if m else ():
+            dims = tuple(sorted(int(x) for x in dims.split(",") if x != "1"))
+            if dims in extents:
+                moved.append(line.strip()[:160])
+    assert not moved, moved[:2]

@@ -79,6 +79,38 @@ def _paged_block(kv: int, ps: int, d: int, itemsize: int, width: int,
     return pack, max(1, fit(pack))
 
 
+def _decode_block(b: int, kv: int, blocks: int, block_m: int, d: int,
+                  d_v: int, itemsize: int, quantized: bool = False,
+                  pack: int = 1) -> tuple[int, int]:
+    """``(rows, head_block)`` of the un-paged decode kernel
+    (``flash_decode``), from what a call sees and nothing else: one grid
+    step covers ``block_m`` positions of ``head_block`` K/V heads of
+    ``rows`` batch rows.  The head block is the largest divisor of ``kv``
+    (whole K rows of ``pack`` heads, ``pack_k``) whose K + V blocks,
+    double-buffered, fit :data:`_PAGED_VMEM_BUDGET`, as they stand in VMEM:
+    channels in whole lane tiles, positions in whole sublane tiles, an int8
+    cache's (8, 128)-padded float32 scale tile per 128 positions and head
+    beside them (``_paged_block``'s count).  Where every head of a row fits
+    and the cache is ONE block (``blocks`` == 1: a window layer's ring),
+    the step takes the largest divisor of ``b`` rows that still fits;
+    where it is several blocks rows end at different ones (each row's index
+    map pins at its own last live block), so a step is one row."""
+    sublanes = 32 // itemsize       # a tile's rows: 8 float32, 16 bf16, 32 int8
+    lanes = lambda c: -(-c // LANES) * LANES
+    head_bytes = (-(-block_m // sublanes) * sublanes * itemsize
+                  * (lanes(pack * d) // pack + lanes(d_v)))
+    if quantized:
+        head_bytes += 2 * 8 * 128 * 4 * -(-block_m // 128)
+    fits = lambda heads: 2 * heads * head_bytes <= _PAGED_VMEM_BUDGET
+    head_block = next((hb for hb in range(kv, pack, -1)
+                       if kv % hb == 0 and hb % pack == 0 and fits(hb)), pack)
+    rows = 1
+    if blocks == 1 and head_block == kv:
+        rows = max(n for n in range(1, b + 1)
+                   if b % n == 0 and (n == 1 or fits(n * kv)))
+    return rows, head_block
+
+
 def _paged_walk(page_table, live_pages, pages_per_block: int):
     """The paged kernel's grid, flattened over the steps that have work:
     ``(walk, fetch, total)``.  Row after row, a row takes one step per
@@ -1044,20 +1076,32 @@ def _decode_accumulate(s, v_blk, acc, vs_row=None):
 
 def _flash_decode_kernel(s_ref, q_ref, k_ref, v_ref, *rest, block_m: int,
                          scale: float, quantized: bool, q_per_kv: int,
+                         rows: int, head_block: int, blocks: int,
                          pack: int = 1, sink: bool = False):
-    """One (batch, kv-head, m-block) grid step of cache-bounded decode.
+    """One (row-block, head-block, m-block) grid step of cache-bounded
+    decode: ``rows`` batch rows x ``head_block`` K/V heads of one block of
+    ``block_m`` positions, the block ``_decode_block``'s.  A step costs the
+    pipeline ~0.3 us that no copy hides (v5e, PERF.md section 6, PR 46), so
+    a row's heads ride in one step where they fit, and several rows where
+    the cache is one block long (a window layer's ring).
 
-    The q block carries this kv head's rows for the WHOLE chunk, t-major:
+    The q block carries each kv head's rows for the WHOLE chunk, t-major:
     row r = chunk token (r // g), group member (r % g) — t = 1 in
     steady-state decode, t > 1 for speculative verify / chunked prefill.
     Chunk token tt sees cache positions <= pos_first + tt.
 
     ``s_ref`` holds the scalar-prefetched per-row triples (n_live_blocks,
-    first chunk position, layer index).  Blocks past the bound are skipped
-    AND their index map pins to the last live block, so Mosaic's
-    unchanged-index elision never DMAs them — HBM traffic is O(pos), not
-    O(max_len).  Online softmax accumulates across the m grid dim in VMEM
-    scratch; the normalized output writes once on the final step.
+    first chunk position, layer index); each row of the step reads its
+    own.  Blocks past a row's bound are skipped AND their index map pins
+    to the last live block, so Mosaic's unchanged-index elision never DMAs
+    them — HBM traffic is O(pos), not O(max_len); ``rows`` > 1 only where
+    the cache is ONE block, which every row has.  Online softmax
+    accumulates across the m grid dim in VMEM scratch ([rows, head_block /
+    pack, pack * t * g, ..]: a unit of the step is one row's one K row of
+    heads); the normalized output writes once on the final step.  The
+    units run one after another in a loop Mosaic unrolls, each unit's
+    chain of product, softmax, product and scratch update free to overlap
+    the next's (``_flash_decode_paged_kernel``'s ``heads``).
 
     K/V refs are blocks of the STACKED cache ([L, ..., block_m, d] — the
     layer index rides row 2 of the scalar prefetch into the index maps),
@@ -1075,13 +1119,13 @@ def _flash_decode_kernel(s_ref, q_ref, k_ref, v_ref, *rest, block_m: int,
     ``_flash_decode_paged_kernel`` carries the self block — the linear
     cache commits before attending and this kernel reads it directly.
 
-    ``pack`` > 1 (``pack_k``; not with ``quantized``): the K block is a
-    ROW of ``pack`` heads' keys side by side, [block_m, pack * d], and the
-    q block the heads' rows block-diagonal over it (``_pack_queries``):
-    one product gives head ``i`` its scores in rows ``i r .. (i + 1) r``
-    (r = t * g), which then meet head ``i``'s own V block and its rows of
-    the scratch.  ``sink``: a [pack * r, 1] float32 operand, each row's
-    head's logit, starts the recurrence in the place of an empty one
+    ``pack`` > 1 (``pack_k``; not with ``quantized``): a K row is ``pack``
+    heads' keys side by side, [block_m, pack * d], and its q block the
+    heads' rows block-diagonal over it (``_pack_queries``): one product
+    gives head ``i`` its scores in rows ``i r .. (i + 1) r`` (r = t * g),
+    which then meet head ``i``'s own V block and its rows of the scratch.
+    ``sink``: a [head_block / pack, pack * r, 1] float32 operand, each
+    row's head's logit, starts the recurrence in the place of an empty one
     (maximum the logit, denominator 1, no value).
     """
     it = list(rest)
@@ -1094,45 +1138,67 @@ def _flash_decode_kernel(s_ref, q_ref, k_ref, v_ref, *rest, block_m: int,
     o_ref, o_acc, m_acc, l_acc = it
     bi = pl.program_id(0)
     j = pl.program_id(2)
-    nb = s_ref[0, bi]      # per-batch-row block bound (ragged serving)
-    pos = s_ref[1, bi]     # first chunk position for this row
-    r = o_acc.shape[0] // pack      # rows of one K/V head: t * g
+    r = o_acc.shape[2] // pack      # rows of one K/V head: t * g
+    # A cache of one block has no phase to tell apart: every step starts,
+    # attends and finishes, in one basic block the scheduler may reorder.
+    when = pl.when if blocks > 1 else (lambda cond: lambda fn: fn())
 
-    @pl.when(j == 0)
+    def units(body):
+        """``body(rw, hk)`` over the step's units: row ``rw`` of its rows,
+        K row ``hk`` of its head block."""
+        def of_row(rw, carry):
+            def of_heads(hk, carry):
+                body(rw, hk)
+                return carry
+            return jax.lax.fori_loop(0, head_block // pack, of_heads, carry,
+                                     unroll=True)
+
+        jax.lax.fori_loop(0, rows, of_row, 0, unroll=True)
+
+    @when(j == 0)
     def _init():
         o_acc[...] = jnp.zeros_like(o_acc)
         if sink:
-            m_acc[...] = sink_ref[0, :, :]
+            m_acc[...] = jnp.broadcast_to(sink_ref[...][None], m_acc.shape)
             l_acc[...] = jnp.ones_like(l_acc)
         else:
             m_acc[...] = jnp.full_like(m_acc, NEG_INF)
             l_acc[...] = jnp.zeros_like(l_acc)
 
-    @pl.when(j < nb)
+    # Several blocks: the step is ONE row (``_decode_block``) and this its
+    # block bound (ragged serving); block 0 is live in every row.
+    @when(j < s_ref[0, bi * rows])
     def _step():
-        q = q_ref[0, 0, :, :]                       # [pack*t*g, pack*d]
-        s = _decode_block_scores(
-            q, k_ref[0, 0, 0, :, :], scale,
-            ks_ref[0, 0, 0, 0, :] if quantized else None)
-        kpos = j * block_m + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        tt = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        tt = (tt % r if pack > 1 else tt) // q_per_kv
-        s = jnp.where(kpos > pos + tt, NEG_INF, s)
-        for i in range(pack):
-            at = slice(i * r, (i + 1) * r)
-            m_acc[at], l_acc[at], o_acc[at] = _decode_accumulate(
-                s[at], v_ref[0, 0, i, :, :],
-                (m_acc[at], l_acc[at], o_acc[at]),
-                vs_ref[0, 0, 0, 0, :] if quantized else None)
+        def unit(rw, hk):
+            s = _decode_block_scores(           # q: [pack*t*g, pack*d]
+                q_ref[rw, hk], k_ref[0, rw, hk], scale,
+                ks_ref[0, rw, hk] if quantized else None)
+            kpos = j * block_m + jax.lax.broadcasted_iota(jnp.int32, s.shape,
+                                                          1)
+            tt = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            tt = (tt % r if pack > 1 else tt) // q_per_kv
+            # each row masked at its own first chunk position
+            s = jnp.where(kpos > s_ref[1, bi * rows + rw] + tt, NEG_INF, s)
+            for i in range(pack):
+                at = (rw, hk, slice(i * r, (i + 1) * r))
+                h = hk * pack + i
+                m_acc[at], l_acc[at], o_acc[at] = _decode_accumulate(
+                    s[at[2]], v_ref[0, rw, h],
+                    (m_acc[at], l_acc[at], o_acc[at]),
+                    vs_ref[0, rw, h] if quantized else None)
 
-    @pl.when(j == pl.num_programs(2) - 1)
+        units(unit)
+
+    @when(j == pl.num_programs(2) - 1)
     def _finish():
         # Every row has at least one attended slot (block 0 holds
         # position 0), so l > 0.
-        o = (o_acc[...] / l_acc[...]).astype(o_ref.dtype)
-        for i in range(pack):
-            o_ref[0, i, :, :] = o[i * r:(i + 1) * r]
+        def unit(rw, hk):
+            o = (o_acc[rw, hk] / l_acc[rw, hk]).astype(o_ref.dtype)
+            for i in range(pack):
+                o_ref[rw, hk * pack + i] = o[i * r:(i + 1) * r]
+
+        units(unit)
 
 
 def _dequant_lane_major(qt_leaf, dtype):
@@ -1210,6 +1276,12 @@ def flash_decode(q, k_cache, v_cache, pos, scale: Optional[float] = None,
     bigger blocks cut per-step grid overhead — measured 2.62 -> 2.25
     ms/step on the 16k-buffer decode_longctx config (v5e, round 5);
     ``_pick_block`` still clamps to a legal divisor for small caches.
+    The grid is (B / rows, KV / head_block, M / block_m): a step takes
+    ``head_block`` of a row's K/V heads (all of them where they fit) and,
+    where the cache is one block (a window layer's ring), ``rows`` rows —
+    ``_decode_block``'s, from the call's shapes and a VMEM budget.  One
+    custom call named ``flash_decode`` with one result [B, KV, t * g, Dv]
+    whatever the block (the benchmark's readers tell the kernel by both).
 
     K and V of unequal head size: ``v_cache`` [(L,) B, KV, M, Dv], the
     result [.., H, Dv].  A PACKED K cache (``pack_k``: [(L,) B, KV / f, M,
@@ -1261,53 +1333,59 @@ def flash_decode(q, k_cache, v_cache, pos, scale: Optional[float] = None,
     qt = q.reshape(b, t, kv, g, d).transpose(0, 2, 1, 3, 4).reshape(
         b, kv, t * g, d)
 
-    # A grid step covers one ROW of K heads (``f`` of them: one without
-    # packing) and those heads' V blocks and output rows.
-    q_spec = pl.BlockSpec((1, 1, f * t * g, f * d),
-                          lambda bi, hi, j, s: (bi, hi, 0, 0),
+    # Grid (b // rows, kv // head_block, m // block_m): a step covers one
+    # block of positions of head_block K/V heads (head_block // f K rows)
+    # of rows batch rows, every operand blocked alike (_decode_block).  A
+    # row's live-block bound pins its index map; rows > 1 only where the
+    # cache is one block, the same for every row.
+    blocks = m // block_m
+    rows, head_block = _decode_block(b, kv, blocks, block_m, d, dv,
+                                     kc.dtype.itemsize, quantized, f)
+    assert rows == 1 or blocks == 1     # rows end at different blocks
+    nk = head_block // f
+    at_row = lambda bi, hi, j, s: (bi, hi, 0, 0)
+    q_spec = pl.BlockSpec((rows, nk, f * t * g, f * d), at_row,
                           memory_space=pltpu.VMEM)
-    o_spec = pl.BlockSpec((1, f, t * g, dv),
-                          lambda bi, hi, j, s: (bi, hi, 0, 0),
+    o_spec = pl.BlockSpec((rows, head_block, t * g, dv), at_row,
                           memory_space=pltpu.VMEM)
+    last_live = lambda bi, j, s: jnp.minimum(j, s[0, bi * rows] - 1)
     cache_spec = lambda heads, width: pl.BlockSpec(
-        (1, 1, heads, block_m, width),
-        lambda bi, hi, j, s: (s[2, 0], bi, hi,
-                              jnp.minimum(j, s[0, bi] - 1), 0),
+        (1, rows, heads, block_m, width),
+        lambda bi, hi, j, s: (s[2, 0], bi, hi, last_live(bi, j, s), 0),
         memory_space=pltpu.VMEM)
-    in_specs = [q_spec, cache_spec(1, f * d), cache_spec(f, dv)]
+    in_specs = [q_spec, cache_spec(nk, f * d), cache_spec(head_block, dv)]
     operands = [_pack_queries(qt, f), kc, vc]
     if quantized:
         # Scales stay stacked lane-major [L, B, KV, 1, M]: positions on
         # the lane dim, same pinned index map as their values.
         sc_spec = pl.BlockSpec(
-            (1, 1, 1, 1, block_m),
-            lambda bi, hi, j, s: (s[2, 0], bi, hi, 0,
-                                  jnp.minimum(j, s[0, bi] - 1)),
+            (1, rows, head_block, 1, block_m),
+            lambda bi, hi, j, s: (s[2, 0], bi, hi, 0, last_live(bi, j, s)),
             memory_space=pltpu.VMEM)
         in_specs += [sc_spec, sc_spec]
         operands += [ksc, vsc]
     if sink is not None:
         # each row's head's logit, in the rows' own order: [KV / f, f * t
         # * g, 1] (row = head in the pack, chunk token, group member)
-        rows = jnp.broadcast_to(
+        logits = jnp.broadcast_to(
             jnp.asarray(sink, jnp.float32).reshape(kv // f, f, 1, g),
             (kv // f, f, t, g)).reshape(kv // f, f * t * g, 1)
-        in_specs.append(pl.BlockSpec((1, f * t * g, 1),
+        in_specs.append(pl.BlockSpec((nk, f * t * g, 1),
                                      lambda bi, hi, j, s: (hi, 0, 0),
                                      memory_space=pltpu.VMEM))
-        operands.append(rows)
+        operands.append(logits)
+    acc = lambda width: pltpu.VMEM((rows, nk, f * t * g, width), jnp.float32)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(b, kv // f, m // block_m),
+        grid=(b // rows, kv // head_block, blocks),
         in_specs=in_specs,
         out_specs=o_spec,
-        scratch_shapes=[pltpu.VMEM((f * t * g, dv), jnp.float32),
-                        pltpu.VMEM((f * t * g, 1), jnp.float32),
-                        pltpu.VMEM((f * t * g, 1), jnp.float32)])
+        scratch_shapes=[acc(dv), acc(1), acc(1)])
     out = pl.pallas_call(
         functools.partial(_flash_decode_kernel, block_m=block_m,
                           scale=float(scale), quantized=quantized,
-                          q_per_kv=g, pack=f, sink=sink is not None),
+                          q_per_kv=g, rows=rows, head_block=head_block,
+                          blocks=blocks, pack=f, sink=sink is not None),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kv, t * g, dv), q.dtype),
         interpret=interpret,
