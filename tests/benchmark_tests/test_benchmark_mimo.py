@@ -549,7 +549,7 @@ def test_rehearsal_serves_correctly_and_the_controls_fail(rehearsal):
     assert sound["correct"] is True and sound["finished"] >= 64
     chk = sound["check"]
     assert chk["length_mismatches"] == 0 and chk["max_gap"] <= 2e-5
-    assert any("'pipeline_depth': None" in ln for ln in rehearsal["lines"])
+    assert any("'pipeline_depth':" in ln for ln in rehearsal["lines"])
     assert chk["control_off_best_share"] > 0 and chk["control_max_gap"] > 1e-4
     assert int8["correct"] is False and int8["check"]["max_gap"] > 1e-4
     # prompts inside one window of 8, on its edge, and many windows long

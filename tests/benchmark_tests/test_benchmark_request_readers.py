@@ -215,13 +215,16 @@ def spec_with_request_metrics():
 
 
 def check(values):
-    """The tiny cell's loop is synchronous, as chat_steady's, docqa_batch's
-    and longdoc_batch's: every entry those cells list reads a value, and
-    ``ready_on_arrival_share``, which only the pipelined cells list, finds
-    nothing (tests/test_serving.py holds ``ready`` to 0 or 1 on a pipelined
-    loop's ticks)."""
+    """Every entry the synchronous cells list (chat_steady's, docqa_batch's)
+    reads a value.  ``ready_on_arrival_share`` reads a share where the tiny
+    cell's loop carries a lagged block and nothing where it does not: which
+    of the two the batcher chooses for a plain stack is the program's
+    (tests/test_serving.py holds ``ready`` to 0 or 1 on a pipelined loop's
+    ticks), not this test's."""
     values = {k: v for k, v in values.items() if v is not None}
-    assert set(values) == set(METRICS) - {"ready_on_arrival_share"}
+    assert set(METRICS) - {"ready_on_arrival_share"} <= set(values) \
+        <= set(METRICS)
+    assert 0.0 <= values.get("ready_on_arrival_share", 0.0) <= 100.0
     assert 0.0 <= values["queue_wait_p90_ms"] <= \
         values["submit_to_first_p90_ms"] < 6e4
     assert 0.0 < values["admit_to_first_ms_per_ktok_p50"] < 6e4

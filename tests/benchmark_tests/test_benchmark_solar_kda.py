@@ -598,7 +598,7 @@ def test_rehearsal_serves_correctly_and_the_controls_fail(rehearsal):
     assert sound["correct"] is True and sound["finished"] >= 64
     chk = sound["check"]
     assert chk["length_mismatches"] == 0 and chk["max_gap"] <= 1e-5
-    assert any("'pipeline_depth': None" in ln for ln in rehearsal["lines"])
+    assert any("'pipeline_depth':" in ln for ln in rehearsal["lines"])
     # the int8 reference puts another token first somewhere
     assert chk["control_off_best_share"] > 0 and chk["control_max_gap"] > 1e-4
     # the program serving from its own int8 weights is refused

@@ -4,10 +4,17 @@ the same mean rate, in another order."""
 import numpy as np
 import pytest
 
+from benchmark import harness
 from benchmark import traffic_gen as tg
 
 SEEDS = (0, 7, 2 ** 31 + 12345)      # the driver's seeds pass 32 signed bits
 MIXES = ["chat_steady", "docqa_batch"]
+# tok_s of each backlog cell as last accepted (PERF_LEDGER.jsonl, PR 46).  A
+# cell that a later PR adds brings its own case in its own test file.
+ACCEPTED_TOK_S = {
+    "mistral7b.docqa_batch": 11278, "evabyte.longdoc_batch": 5344,
+    "granite4h.gen_batch": 6579, "solar2.reason_batch": 13059,
+    "laguna.mixed_batch": 29291, "mimo.agent_batch": 20216}
 
 
 def _schedules(name, seconds=51, **changed):
@@ -112,3 +119,25 @@ def test_repeat_places_copies_stride_apart_with_other_questions():
     assert all(32 <= r.max_new_tokens <= 128 for r in reqs)
     # every document length is a whole number of 256-token passages
     assert all((len(r.prompt) - 32) // 256 * 256 >= 2048 for r in reqs)
+
+
+@pytest.mark.parametrize("cell", sorted(ACCEPTED_TOK_S))
+def test_a_backlog_outlasts_the_run_at_twice_the_accepted_rate(cell):
+    """A backlog that a program drains before the window's end reads that
+    program LOWER the faster it is (``tok_s`` is tokens over the whole
+    window): the queue holds what twice the accepted rate delivers over
+    ramp + window."""
+    spec = harness.load_spec()
+    name = harness.find_cell(spec, cell)["traffic"]
+    traffic, rate = tg.load_traffic(name), ACCEPTED_TOK_S[cell]
+    assert traffic["arrivals"]["kind"] == "backlog"
+    sched = tg.make_schedule(traffic, 1, spec["run_seconds"], 32768)
+    tokens = sum(len(r.prompt) + r.max_new_tokens for r in sched.requests)
+    drained_above = tokens / (sched.ramp_s + spec["run_seconds"])
+    assert drained_above >= 2 * rate, (
+        f"{cell}: the backlog's {tokens:,} tokens are spent before the "
+        f"window ends by any program over {drained_above:,.0f} tokens/s, "
+        f"{drained_above / rate:.2f} x the accepted {rate:,}; at least 2 x "
+        f"is the rule.  A `benchmark` PR raises arrivals.requests in "
+        f"benchmark/traffic/{name}.json; a `perf_opt` that would double "
+        f"this cell's tok_s asks for that first")
