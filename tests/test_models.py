@@ -1841,3 +1841,118 @@ def test_split_heads_is_a_reshape_behind_a_barrier():
     np.testing.assert_array_equal(grad, 2 * y)
     text = jax.jit(lambda a: transformer._split_heads(a, 4)).lower(y).as_text()
     assert "optimization_barrier" in text
+
+
+# -- decode_step's layer scan reads the weights where the stack lies (PR 49) --
+#
+# The scan runs over the layer index and the body takes its layer of every
+# stacked leaf; until PR 49 the stack was the scan's ``xs``.  Same arithmetic
+# in the same order: logits and cache are bit-identical to the ``xs`` form's,
+# rolled (a page pool at any width, a short linear buffer) or unrolled by two
+# (a linear buffer from 8,192 slots).
+
+def _decode_step_over_xs(cfg, params, cache, tokens, pos):
+    """``decode_step`` for a plain stack as it stood: the stacked weights
+    as the (rolled) layer scan's ``xs``."""
+    x, positions, _ = transformer._embed_chunk(cfg, params, tokens, pos)
+    pages = cache.get("pages")
+
+    def body(carry, layer):
+        x, ck, cv = carry
+        li, lp = layer
+        x, ck, cv, chunks = transformer._block_decode(
+            cfg, x, lp, ck, cv, li, positions, pos, pages=pages)
+        return (x, ck, cv), chunks
+
+    (x, new_k, new_v), chunks = jax.lax.scan(
+        body, (x, cache["k"], cache["v"]),
+        (jnp.arange(cfg.n_layers, dtype=jnp.int32), params["layers"]))
+    if chunks is not None:
+        new_k = transformer._paged_cache_write_all(new_k, chunks[0], pages,
+                                                   pos)
+        new_v = transformer._paged_cache_write_all(new_v, chunks[1], pages,
+                                                   pos)
+    out = {"k": new_k, "v": new_v}
+    if pages is not None:
+        out["pages"] = pages
+    return transformer._final_logits(cfg, params, x), out
+
+
+def _scan_toy(dtype=jnp.float32, int8=False):
+    cfg = transformer.TransformerConfig(
+        vocab_size=64, d_model=32, n_layers=4, n_heads=4, n_kv_heads=2,
+        d_ff=64, max_seq_len=8192, dtype=dtype)
+    params = transformer.init_params(cfg, jax.random.PRNGKey(0))
+    return cfg, (transformer.quantize_params(cfg, params) if int8
+                 else params)
+
+
+def _scan_cache(cfg, kind, rows):
+    """``paged``: a pool behind scrambled tables; ``short`` / ``long``: the
+    linear buffer under / at the unroll's 8,192 slots."""
+    if kind != "paged":
+        return transformer.init_cache(cfg, rows,
+                                      8192 if kind == "long" else 64)
+    alloc = transformer.PageAllocator(n_pages=16, page_size=8)
+    np.random.RandomState(3).shuffle(alloc.free)
+    for i in range(rows):
+        alloc.ensure(i, 16)
+    cache = transformer.init_paged_cache(cfg, 16, page_size=8)
+    cache["pages"] = alloc.table(range(rows))
+    return cache
+
+
+def _layer_scans(jaxpr, n_layers):
+    """The ``unroll`` of every scan of ``n_layers`` iterations in ``jaxpr``
+    or under it."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan" and eqn.params["length"] == n_layers:
+            found.append(eqn.params["unroll"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _layer_scans(sub, n_layers)
+    return found
+
+
+@pytest.mark.parametrize("chunk", ["t1_ragged", "t5"])
+@pytest.mark.parametrize("weights", ["bf16", "int8"])
+@pytest.mark.parametrize("kind", ["paged", "short", "long"])
+def test_decode_step_scan_matches_weights_as_xs(kind, weights, chunk):
+    cfg, params = _scan_toy(jnp.bfloat16 if weights == "bf16"
+                            else jnp.float32, int8=weights == "int8")
+    rows = 3
+    toks = jax.random.randint(jax.random.PRNGKey(1), (rows, 9), 0,
+                              cfg.vocab_size)
+    new = jax.jit(lambda c, tok, pos: transformer.decode_step(
+        cfg, params, c, tok, pos))
+    old = jax.jit(lambda c, tok, pos: _decode_step_over_xs(
+        cfg, params, c, tok, pos))
+    cache = _scan_cache(cfg, kind, rows)
+    if chunk == "t5":
+        args = (cache, toks[:, :5], 0)
+    else:       # a row each at its own position, over a filled cache
+        _, cache = new(cache, toks[:, :8], 0)
+        args = (cache, toks[:, 8:], jnp.asarray([5, 8, 3], jnp.int32))
+    got, want = new(*args), old(*args)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert np.isfinite(np.asarray(got[0], np.float32)).all()
+
+
+@pytest.mark.parametrize("kind,width,unroll", [
+    ("paged", 128, 1),      # 128 pages of 64: a logical 8,192, rolled
+    ("paged", 32, 1),
+    ("long", None, 2),      # the linear buffer at 8,192 slots keeps its two
+    ("short", None, 1)])
+def test_decode_step_unrolls_the_linear_buffer_only(kind, width, unroll):
+    cfg, params = _scan_toy()
+    if kind == "paged":
+        cache = transformer.init_paged_cache(cfg, 4, page_size=64)
+        cache["pages"] = jnp.zeros((2, width), jnp.int32)
+    else:
+        cache = _scan_cache(cfg, kind, 2)
+    jaxpr = jax.make_jaxpr(lambda c, tok, pos: transformer.decode_step(
+        cfg, params, c, tok, pos))(
+            cache, jnp.zeros((2, 1), jnp.int32), jnp.zeros((2,), jnp.int32))
+    assert _layer_scans(jaxpr.jaxpr, cfg.n_layers) == [unroll]

@@ -344,18 +344,21 @@ def _step_models():
 _STEPS = {}
 
 
-def _compiled_step(topo, model, rows, t, width, start=None, int8=False):
+def _compiled_step(topo, model, rows, t, width, start=None, int8=False,
+                   max_len=None):
     """``(cfg, params, compiled, text)`` of ``model``'s ``decode_step`` over
     ``rows`` x ``t`` tokens and a page table ``width`` wide; ``params`` are
     the parameters' shapes.  ``start``: ``"ragged"`` a traced [rows] vector
     (what ``t == 1`` defaults to), ``"traced"`` a traced scalar, or a static
-    int (``t > 1``: 0, a prefill from an empty cache)."""
+    int (``t > 1``: 0, a prefill from an empty cache).  ``max_len``: over the
+    un-paged linear cache of so many slots a row (``init_cache``) in the
+    pool's place; ``width`` says nothing then."""
     from tfmesos_tpu.models import transformer
     from tfmesos_tpu.ops import attention, kda, moe, ssm
 
     if start is None:
         start = "ragged" if t == 1 else 0
-    key = (model, rows, t, width, start, int8)
+    key = (model, rows, t, width, start, int8, max_len)
     if key in _STEPS:
         return _STEPS[key]
     cfg, n_pages, slots, gates = _step_models()[model]
@@ -366,15 +369,20 @@ def _compiled_step(topo, model, rows, t, width, start=None, int8=False):
 
     params = jax.eval_shape(
         lambda: transformer.init_params(cfg, jax.random.PRNGKey(0)))
-    cache = dict(jax.eval_shape(lambda: transformer.init_paged_cache(
-        cfg, n_pages, PAGE, quantized=int8)))
+    if max_len is not None:
+        cache = dict(jax.eval_shape(lambda: transformer.init_cache(
+            cfg, rows, max_len, quantized=int8)))
+    else:
+        cache = dict(jax.eval_shape(lambda: transformer.init_paged_cache(
+            cfg, n_pages, PAGE, quantized=int8)))
     if slots:
         cache["state"] = jax.eval_shape(
             lambda: transformer.init_row_state(cfg, slots))
         if t > 1:
             cache["slots"] = jnp.zeros((rows,), I32)
             cache["valid"] = jnp.zeros((rows,), I32)
-    cache["pages"] = jnp.zeros((rows, width), I32)
+    if max_len is None:
+        cache["pages"] = jnp.zeros((rows, width), I32)
     tokens = jax.ShapeDtypeStruct((rows, t), I32, sharding=one_chip)
     # A static start is closed over; a traced one is the step's last argument.
     traced = () if isinstance(start, int) else (jax.ShapeDtypeStruct(
@@ -471,22 +479,26 @@ WEIGHT_CASES = {
 
 def _weight_moves(params, text, min_bytes=1 << 20):
     """The instructions of the compiled ``text`` that copy, transpose or
-    materialise a stacked weight of ``params["layers"]``, a layer of it or
-    the whole stack: a top-level ``copy`` / ``transpose`` one of whose
-    operands has the shape of the stack ``[L, ...]`` or of one layer (``[1,
-    ...]`` or ``[...]``), and a top-level fusion that reads one such shape
-    and results in one (a slice written out; a dot reads one and results in
-    activations).  Matrices of at least ``min_bytes`` a layer; instructions
-    inside a fusion's computation are not the program's."""
+    materialise a stacked weight of ``params["layers"]``, a layer of it, a
+    group of layers or the whole stack: a top-level ``copy`` / ``transpose``
+    one of whose operands has the shape of the stack ``[L, ...]``, of one
+    layer (``[1, ...]`` or ``[...]``) or of the stack as a scan unrolled by
+    ``u`` sees it (``[L/u, u, ...]`` and an iteration's ``[u, ...]``, for
+    every ``u`` dividing ``L``), and a top-level fusion that reads one such
+    shape and results in one (a slice written out; a dot reads one and
+    results in activations).  Matrices of at least ``min_bytes`` a layer;
+    instructions inside a fusion's computation are not the program's."""
     views = set()
     for leaf in jax.tree_util.tree_leaves(params["layers"]):
         if leaf.ndim < 3 or (leaf.size // leaf.shape[0]
                              * leaf.dtype.itemsize) < min_bytes:
             continue
         dt = {"bfloat16": "bf16", "float32": "f32"}[str(leaf.dtype)]
-        rest = ",".join(map(str, leaf.shape[1:]))
-        views |= {f"{dt}[{leaf.shape[0]},{rest}]", f"{dt}[1,{rest}]",
-                  f"{dt}[{rest}]"}
+        n, rest = leaf.shape[0], ",".join(map(str, leaf.shape[1:]))
+        views.add(f"{dt}[{rest}]")
+        for u in range(1, n + 1):
+            if n % u == 0:
+                views |= {f"{dt}[{u},{rest}]", f"{dt}[{n // u},{u},{rest}]"}
     shape_of = dict(re.findall(r"%([\w.\-]+) = (\w+\[[\d,]*\])", text))
     fused = set(re.findall(r" fusion\(.*?calls=%([\w.\-]+)", text))
     moves, inside = [], False
@@ -531,6 +543,15 @@ def test_weight_moves_reads_the_parents_program():
                "  %copy.177 = bf16[16,4096,4096]{1,2,0:T(8,128)(2,1)} "
                "copy(%p.1), sharding={replicated}\n")
     assert len(_weight_moves(params, hoisted)) == 1
+    # PR 48's parent: the weights as the scan's xs under ``unroll=2``, two
+    # layers of a stacked MLP weight written out once an iteration
+    params["layers"]["w_down"] = jax.ShapeDtypeStruct((16, 14336, 4096), BF16)
+    grouped = ("  %gte = bf16[8,2,14336,4096]{3,2,1,0} "
+               "get-tuple-element(%t), index=5\n"
+               "  %dynamic-slice_bitcast_fusion.57 = bf16[2,14336,4096]"
+               "{2,1,0:T(8,128)(2,1)} fusion(%gte, %i), kind=kLoop, "
+               "calls=%fc.57\n")
+    assert len(_weight_moves(params, grouped)) == 1
     clean = ("%fc.95 (a: bf16[16,4096,4096]) -> bf16[1,4096,4096] {\n"
              "  %a = bf16[16,4096,4096]{2,1,0} parameter(0)\n"
              "  %copy.3 = bf16[16,4096,4096]{2,1,0} copy(%a)\n"
@@ -543,6 +564,35 @@ def test_weight_moves_reads_the_parents_program():
              "calls=%fc.96\n"
              "}\n")
     assert _weight_moves(params, clean) == []
+
+
+# -- the un-paged buffer keeps its unroll, and its weights in place (PR 49) ---
+#
+# ``decode_step`` over ``init_cache``'s linear buffer at Mistral's widths, 4
+# rows, one token a row (``generate``'s step; no cell runs it).  From 8,192
+# slots a row the layer loop is unrolled by two, which the text shows as two
+# ``flash_decode`` calls in the loop's body; under that a short buffer and
+# every page pool keep the rolled loop's one.  With the weights as the scan's
+# xs the unrolled form wrote two layers of every stacked weight out once an
+# iteration (the parent's step at 16,384 slots: 7 such moves, 0.79 GB of
+# temporaries); read where the stack lies, no form moves one.
+# name -> (max_len, start, ``flash_decode`` calls in the program)
+UNPAGED_CASES = {
+    "m16384_scalar": (16384, "traced", 2),
+    "m16384_ragged": (16384, "ragged", 2),
+    "m4096_scalar": (4096, "traced", 1),
+}
+
+
+@pytest.mark.parametrize("name", sorted(UNPAGED_CASES))
+def test_unpaged_step_unrolls_by_its_length_and_moves_no_weight(topo, name):
+    max_len, start, calls = UNPAGED_CASES[name]
+    _, params, _, text = _compiled_step(topo, "mistral", 4, 1, None, start,
+                                        max_len=max_len)
+    assert len(re.findall(r"%flash_decode[.\d]* = \S+ custom-call\(",
+                          text)) == calls
+    moves = _weight_moves(params, text)
+    assert not moves, f"{len(moves)} weight moves, e.g. {moves[:3]}"
 
 
 # -- a typed stack's state store and pool stay where they are (PR 32) ---------

@@ -2486,23 +2486,40 @@ def decode_step(cfg: TransformerConfig, params, cache, tokens, pos,
     # the index.  Scanning the cache through xs/ys instead would restack
     # the ENTIRE [L, ...] buffer every step — ~2 GB of HBM traffic per
     # token at max_len=16k, an order of magnitude over the einsum's own
-    # read cost (measured round 5).
-    def body(carry, layer):
+    # read cost (measured round 5).  The weights are no xs either: the scan
+    # runs over the layer index alone and the body takes its layer of every
+    # stacked leaf, so each matmul reads its weight where the stack lies,
+    # rolled or unrolled.  As xs of a scan unrolled by two, XLA views a
+    # stacked weight as [L/2, 2, ...] and writes an iteration's two layers
+    # out: the whole model copied once a run, 18 ms of a 37 ms Mistral
+    # decode block at 128 pages a row (v5e, PR 47).
+    layers = params["layers"]
+
+    def body(carry, li):
         x, ck, cv = carry
-        li, lp = layer
+        lp = jax.tree_util.tree_map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, li, 0, keepdims=False),
+            layers)
         x, ck, cv, chunks = _block_decode(cfg, x, lp, ck, cv, li,
                                           positions, pos, sharded=sharded,
                                           mesh=mesh, pages=pages, kpos=kpos)
         return (x, ck, cv), chunks
 
-    # Long-buffer decode gains ~40% from a 2-wide unroll (cross-layer DMA
-    # overlap; 1759 -> 2497 tok/s at max_len=16k on the v5e) while short
-    # buffers LOSE ~6% to it and m=4k is a wash — gate on the static
-    # buffer length.  unroll=4 loses the win again (VMEM pressure).
+    # The un-paged linear buffer of 8,192 slots or more keeps its 2-wide
+    # unroll (round 5: cross-layer DMA overlap, 1759 -> 2497 tok/s at
+    # max_len=16k, while short buffers LOST ~6% to it and m=4k was a wash:
+    # hence the gate on the buffer's static length).  On the v5e at
+    # Mistral's widths, 4 rows, max_len 16,384 (PR 49): 328.5 ms a step
+    # against 432.9 rolled with a position a row (the ragged write's passes
+    # over the cache overlap), 14.38 against 14.41 with a scalar position.
+    # A page pool always takes the rolled loop: with the weights read in
+    # place the unroll buys it nothing (32 rows: 18.58 ms rolled against
+    # 19.17 two-wide at 128 pages a row, 11.64 against 11.72 at 32; PR 47).
+    unroll = 2 if (pages is None
+                   and _cache_logical_len(cache["k"]) >= 8192) else 1
     (x, new_k, new_v), chunks = jax.lax.scan(
         body, (x, cache["k"], cache["v"]),
-        (jnp.arange(cfg.n_layers, dtype=jnp.int32), params["layers"]),
-        unroll=2 if _cache_logical_len(cache["k"], pages) >= 8192 else 1)
+        jnp.arange(cfg.n_layers, dtype=jnp.int32), unroll=unroll)
     if chunks is not None:
         # Deferred single-token paged writes (see _block_decode): commit
         # every layer's chunk in one scatter per pool leaf.
