@@ -79,7 +79,6 @@ _HEAVY_MULTICHIP = {
     "test_transformer_switch_moe_on_ep_mesh",
     "test_shared_experts_switch_and_pp",
     "test_load_balance_loss_trains_router_to_balance",
-    "test_bandwidth_multi_device_path",
     # Parametrized duplicates: one representative of each family stays
     # in tier-1, the sibling axes/sizes run with the slow suite.
     "test_pipeline_1f1b_matches_sequential[4-2-8]",
@@ -125,10 +124,9 @@ _HEAVY_MULTICHIP = {
     # heaviest sibling-covered variants move to the full suite — one
     # representative of each family ([False] serve example, the other
     # mesh/pipelined/multistep batcher axes, the remaining moe
-    # shared-expert/aux tests, the short-context decode benches) stays
+    # shared-expert/aux tests) stays
     # in tier-1.
     "test_serve_example_end_to_end[True]",
-    "test_decode_long_context_bench_smoke",
     "test_shared_experts_add_dense_ffn",
     "test_mesh_batcher_token_identical[axes2-spec_chunk_prefix]",
     "test_switch_moe_topk_aux_metrics_in_loss",
@@ -155,7 +153,6 @@ _HEAVY_MULTICHIP = {
     "test_speculative_batcher_with_shared_prefix[21]",
     "test_speculative_with_chunked_prefill[True]",
     "test_warmup_outputs_bit_identical[pcache]",
-    "test_decode_bench_int8_smoke",
     "test_shared_prefix_matches_generate[11]",
     "test_prefix_cache_composes_with_global_prefix[11]",
     "test_mesh_batcher_token_identical[axes3-sampled]",

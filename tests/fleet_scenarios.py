@@ -1,37 +1,11 @@
-"""Project benchmark: mnist_replica steps/sec/chip (BASELINE.json metric),
-plus MFU and memory/interconnect-bandwidth accounting.
+"""Fleet scenarios: end-to-end protocols over local CPU replicas, the
+simulator or a loopback socket that ASSERT the fleet's contracts (zero
+lost requests under a kill, breaker isolation, exactly-once replay).
 
-Runs the reference's canonical workload — the mnist_replica trainer at its
-published scale (batch 100, hidden 100, mnist_replica.py:70-73) — as a jit'd
-sync-SGD step on this host's accelerator, the flagship transformer at
-T=2048, and a compute-dense transformer config sized so the MXU (not the
-VPU) bounds it.  Parse the LAST stdout JSON line:
-
-    {"metric": ..., "value": N, "unit": ..., "vs_baseline": N,
-     "mfu_transformer": ..., "mfu_dense": ..., "allreduce_gbps": ...,
-     "hbm_gbps": ...}
-
-(once the headline metric is in hand, a flushed ``"partial": true`` line is
-printed so an external timeout still leaves a parseable result; the final
-full line supersedes it)
-
-A run that finds no accelerator exits non-zero: there is no CPU stand-in
-for a device metric, and a phase that raises fails the run.
-
-``vs_baseline``: the reference publishes no numbers, so the baseline is
-our own round-1 value measured by the driver under this same protocol
-(K fused steps per dispatch, timed region ends in a device-to-host
-fetch), recorded in BASELINE_SELF below; >1.0 means faster than round-1's
-framework, like for like.
-
-MFU = analytic matmul FLOPs / elapsed / per-chip peak.  Peaks are the
-published bf16 figures per device kind; a kind that is not in the table
-is an error, never a default.
-
-Bandwidth: with >1 device, a psum sweep (1MB-256MB) reports achieved
-all-reduce algorithmic bandwidth vs the ICI roofline; on a single chip there
-is no ICI, so an HBM triad sweep reports memory bandwidth vs the HBM
-roofline instead (the roofline that actually bounds single-chip kernels).
+Each returns the figures its smoke in ``tests/test_fleet_scenarios.py``
+pins shapes and directions on.  They are counts and timings of CPU
+replicas: tests, not measurements of anything a chip runs (the
+benchmark is ``benchmark/run.py``).
 """
 
 import json
@@ -39,993 +13,16 @@ import time
 
 import numpy as np
 
-# Round-1 value for bench_mnist_replica measured by the round driver on one
-# v5e chip under THIS protocol.  Run-to-run spread on this metric was ±40%
-# in rounds 1-5 — read vs_baseline accordingly.
-BASELINE_SELF = 10429.09
-
 
 def _p99(vals):
-    """Rank-index p99 shared by the fleet benches (priority, soak,
-    trace overhead) — ONE estimator, so the benches cannot silently
+    """Rank-index p99 shared by the fleet scenarios (priority, soak,
+    trace overhead) — ONE estimator, so the scenarios cannot silently
     disagree about rounding."""
     vals = sorted(vals)
     return vals[min(len(vals) - 1, int(0.99 * len(vals)))]
 
-# Published peak bf16 matmul throughput per chip and HBM bandwidth, by
-# device kind string (jax.devices()[0].device_kind).
-PEAK_BF16 = {
-    "TPU v4": 275e12,
-    "TPU v5 lite": 197e12,
-    "TPU v5": 459e12,
-    "TPU v5p": 459e12,
-    "TPU v6 lite": 918e12,
-}
-HBM_GBPS = {
-    "TPU v4": 1228.0,
-    "TPU v5 lite": 819.0,
-    "TPU v5": 2765.0,
-    "TPU v5p": 2765.0,
-    "TPU v6 lite": 1640.0,
-}
-# Per-link ICI bandwidth (GB/s, one direction) — v5e: 4 links x ~100GB/s
-# usable per chip; used only to contextualize the all-reduce number.
-ICI_GBPS = {"TPU v5 lite": 400.0, "TPU v4": 300.0, "TPU v5p": 600.0}
 
-
-def _device_kind():
-    import jax
-
-    return jax.devices()[0].device_kind
-
-
-def _peak_flops():
-    kind = _device_kind()
-    if kind not in PEAK_BF16:
-        raise RuntimeError(
-            f"no published bf16 peak for device kind {kind!r}; an MFU "
-            f"against a guessed peak is not a measurement (known: "
-            f"{sorted(PEAK_BF16)})")
-    return PEAK_BF16[kind], kind
-
-
-def mlp_flops_per_step(cfg, batch: int) -> float:
-    """Dense fwd+bwd ~= 6 FLOPs per weight per sample (2 fwd, 4 bwd)."""
-    w = 784 * cfg.hidden + cfg.hidden * 10
-    return 6.0 * w * batch
-
-
-def transformer_flops_per_token(cfg, t: int) -> float:
-    """Analytic matmul FLOPs per token, fwd+bwd (~3x forward).
-
-    Per layer: qkv+out projections 4·d², swiglu 3·d·d_ff; unembed d·vocab;
-    causal attention ≈ 2·T·d per layer per token (QKᵀ + PV at the average
-    causal length T/2).  Elementwise work (norms, rope, softmax) is excluded
-    — MFU measures MXU math against MXU peak.
-    """
-    per_layer_w = 4 * cfg.d_model ** 2 + 3 * cfg.d_model * cfg.d_ff
-    w = cfg.n_layers * per_layer_w + cfg.d_model * cfg.vocab_size
-    fwd = 2 * w + cfg.n_layers * 2 * t * cfg.d_model
-    return 3.0 * fwd
-
-
-def bench_mnist_replica(steps=2000, warmup=100):
-    # Protocol: K=20 optimizer steps fused per dispatch via lax.scan;
-    # `steps` counts individual optimizer steps; the timed chain ends in a
-    # real host fetch.
-    import jax
-    import optax
-    from tfmesos_tpu.models import mlp
-    from tfmesos_tpu.parallel.mesh import build_mesh
-    from tfmesos_tpu.parallel.sharding import make_global_batch
-    from tfmesos_tpu.train import data as datalib
-    from tfmesos_tpu.train.trainer import make_train_step
-
-    n_chips = max(1, jax.device_count())
-    mesh = build_mesh()  # every chip on a data-parallel axis
-    cfg = mlp.MLPConfig(hidden=100)
-    params = mlp.init_params(cfg, jax.random.PRNGKey(0))
-    opt = optax.sgd(0.01)  # reference lr (mnist_replica.py:71)
-    k = 20
-    step = make_train_step(lambda p, b: mlp.loss_fn(cfg, p, b), opt, mesh=mesh,
-                           steps_per_call=k)
-
-    ds = datalib.SyntheticMNIST()
-    # Reference batch 100, rounded so it shards evenly over the chips —
-    # the step really runs on all of them, so dividing by n_chips is honest.
-    local_bs = max(1, 100 // n_chips)
-    gen = ds.batches(local_bs * n_chips)
-
-    def stacked_batch():
-        ms = [next(gen) for _ in range(k)]
-        return make_global_batch(
-            mesh, {key: np.stack([m[key] for m in ms]) for key in ms[0]},
-            batch_dim=1)
-
-    params, opt_state = step.place(params, opt.init(params))
-    batch = stacked_batch()
-    for _ in range(max(1, warmup // k)):
-        params, opt_state, metrics = step(params, opt_state, batch)
-    float(metrics["loss"])  # drain the warmup chain with a real fetch
-    calls = max(1, steps // k)
-    t0 = time.perf_counter()
-    for _ in range(calls):
-        params, opt_state, metrics = step(params, opt_state, batch)
-    # Steps chain through donated params, so the device must run them in
-    # order; the host fetch forces completion of the whole chain.
-    final_loss = float(np.asarray(metrics["loss"]))
-    dt = time.perf_counter() - t0
-    steps_per_sec = calls * k / dt / n_chips
-    peak, _ = _peak_flops()
-    mfu = mlp_flops_per_step(cfg, local_bs * n_chips) * calls * k / dt / (
-        n_chips * peak)
-    return steps_per_sec, final_loss, mfu
-
-
-def _bench_transformer_config(cfg_kwargs, b, t, k, iters=3):
-    """Fused-scan transformer train-step timing; returns (tokens/s, mfu)."""
-    import jax
-    import jax.numpy as jnp
-    import optax
-    from jax import lax
-    from tfmesos_tpu.models import transformer
-
-    cfg = transformer.TransformerConfig(max_seq_len=t, dtype=jnp.bfloat16,
-                                        **cfg_kwargs)
-    params = transformer.init_params(cfg, jax.random.PRNGKey(0))
-    opt = optax.sgd(1e-4)
-    opt_state = opt.init(params)
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (k, b, t + 1), 0,
-                                cfg.vocab_size, dtype=jnp.int32)
-
-    @jax.jit
-    def fused(params, opt_state, tokens):
-        def body(carry, tok):
-            params, opt_state = carry
-            loss, grads = jax.value_and_grad(
-                lambda p: transformer.loss_fn(cfg, p, {"tokens": tok})[0]
-            )(params)
-            updates, opt_state = opt.update(grads, opt_state, params)
-            return (optax.apply_updates(params, updates), opt_state), loss
-
-        (params, opt_state), losses = lax.scan(body, (params, opt_state),
-                                               tokens)
-        return params, opt_state, losses[-1]
-
-    p, s, loss = fused(params, opt_state, tokens)
-    jax.block_until_ready(loss)
-    best = float("inf")
-    for _ in range(iters):
-        t0 = time.perf_counter()
-        p, s, loss = fused(params, opt_state, tokens)
-        float(np.asarray(loss))  # real device-to-host fetch ends the chain
-        best = min(best, (time.perf_counter() - t0) / k)
-    peak, _ = _peak_flops()
-    tokens_per_sec = b * t / best
-    mfu = transformer_flops_per_token(cfg, t) * b * t / best / peak
-    return tokens_per_sec, mfu
-
-
-def bench_transformer_tokens():
-    """Flagship transformer (34M, d512) at T=2048, K=8 fused steps."""
-    return _bench_transformer_config(
-        dict(vocab_size=8192, d_model=512, n_layers=8, n_heads=8, d_ff=1408),
-        b=8, t=2048, k=8)
-
-
-def bench_transformer_dense():
-    """Compute-dense config (d2048): the MXU-bound MFU probe.  The flagship's
-    d512 layers leave the step partly VPU/elementwise-bound; this config
-    shows the framework's ceiling when matmuls dominate."""
-    return _bench_transformer_config(
-        dict(vocab_size=8192, d_model=2048, n_layers=4, n_heads=16,
-             d_ff=5632),
-        b=4, t=2048, k=4)
-
-
-def bench_decode(batch=8, prompt_len=128, new_tokens=256, quantized=False,
-                 quantized_cache=False):
-    """Steady-state decode throughput on the flagship config (KV cache,
-    greedy): generated tokens per second across the batch.  The prompt is
-    prefilled OUTSIDE the timed region — only the per-token scan is timed,
-    so the metric stays comparable if the prompt/new-token ratio changes.
-
-    ``quantized=True`` serves weight-only int8 params (per-row absmax,
-    ``transformer.quantize_params``): t=1 decode is weight-bandwidth-bound,
-    so halving the streamed bytes is the serving-side headline.
-    ``quantized_cache=True`` additionally stores K/V as int8 — together
-    they are the full int8 serving configuration."""
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-    from tfmesos_tpu.models import transformer
-
-    cfg = transformer.TransformerConfig(
-        vocab_size=8192, d_model=512, n_layers=8, n_heads=8, d_ff=1408,
-        max_seq_len=prompt_len + new_tokens, dtype=jnp.bfloat16)
-    params = transformer.init_params(cfg, jax.random.PRNGKey(0))
-    if quantized:
-        params = jax.jit(
-            lambda p: transformer.quantize_params(cfg, p))(params)
-    prompt = jax.random.randint(jax.random.PRNGKey(1), (batch, prompt_len),
-                                0, cfg.vocab_size, dtype=jnp.int32)
-    cache0 = transformer.init_cache(cfg, batch, prompt_len + new_tokens,
-                                    quantized=quantized_cache)
-    prefill = jax.jit(lambda p, c, t: transformer.decode_step(cfg, p, c, t, 0))
-    logits, cache = prefill(params, cache0, prompt)
-    tok0 = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-
-    @jax.jit
-    def decode_loop(params, cache, tok):
-        def body(carry, _):
-            cache, tok, pos = carry
-            logits, cache = transformer.decode_step(cfg, params, cache,
-                                                    tok[:, None], pos)
-            nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-            return (cache, nxt, pos + 1), None
-        (cache, tok, _), _ = lax.scan(
-            body, (cache, tok, jnp.asarray(prompt_len, jnp.int32)), None,
-            length=new_tokens)
-        return tok
-
-    out = decode_loop(params, cache, tok0)
-    jax.block_until_ready(out)
-    best = float("inf")
-    for _ in range(3):
-        t0 = time.perf_counter()
-        out = decode_loop(params, cache, tok0)
-        np.asarray(out)  # real fetch ends the chain
-        best = min(best, time.perf_counter() - t0)
-    return batch * new_tokens / best
-
-
-def bench_decode_long_context(batch=4, max_len=16384, prompt_len=1024,
-                              new_tokens=64):
-    """Steady-state decode with a LONG cache buffer, early in generation —
-    the flash-decode kernel's case: its scalar-prefetched block bound reads
-    O(pos) cache slots while the XLA einsum pays for all ``max_len`` every
-    step.  Returns (kernel_tok_s, einsum_tok_s); their ratio is the
-    realized bandwidth saving (~max_len/pos bound at these shapes).
-    """
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-    from tfmesos_tpu.models import transformer
-
-    cfg = transformer.TransformerConfig(
-        vocab_size=8192, d_model=512, n_layers=8, n_heads=8, n_kv_heads=8,
-        d_ff=1408, max_seq_len=max_len, dtype=jnp.bfloat16)
-    params = transformer.init_params(cfg, jax.random.PRNGKey(0))
-    prompt = jax.random.randint(jax.random.PRNGKey(1), (batch, prompt_len),
-                                0, cfg.vocab_size, dtype=jnp.int32)
-    cache0 = transformer.init_cache(cfg, batch, max_len)
-    prefill = jax.jit(lambda p, c, t: transformer.decode_step(cfg, p, c, t, 0))
-    logits, cache = prefill(params, cache0, prompt)
-    tok0 = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-
-    def loop_with(gate):
-        from tfmesos_tpu.models import transformer as tr
-        orig = tr._decode_kernel_kwargs
-        tr._decode_kernel_kwargs = gate
-
-        @jax.jit
-        def decode_loop(params, cache, tok):
-            def body(carry, _):
-                cache, tok, pos = carry
-                logits, cache = tr.decode_step(cfg, params, cache,
-                                               tok[:, None], pos)
-                nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-                return (cache, nxt, pos + 1), None
-            (cache, tok, _), _ = lax.scan(
-                body, (cache, tok, jnp.asarray(prompt_len, jnp.int32)), None,
-                length=new_tokens)
-            return tok
-        try:
-            out = decode_loop(params, cache, tok0)
-            jax.block_until_ready(out)
-            best = float("inf")
-            for _ in range(3):
-                t0 = time.perf_counter()
-                out = decode_loop(params, cache, tok0)
-                np.asarray(out)
-                best = min(best, time.perf_counter() - t0)
-        finally:
-            tr._decode_kernel_kwargs = orig
-        return batch * new_tokens / best
-
-    from tfmesos_tpu.models import transformer as tr
-    kernel_gate = tr._decode_kernel_kwargs       # the real auto gate
-    einsum_gate = lambda *a, **k: None           # force the XLA einsum
-    return loop_with(kernel_gate), loop_with(einsum_gate)
-
-
-def _timed_attention_fwdbwd(attn, b, t, h, d, reps):
-    """Chained-scan fwd+bwd timing of one attention callable, ms per call.
-
-    ``reps`` dependent grad steps inside one jit; the timed region ends in
-    a host fetch.  Differentiates w.r.t. q AND k AND v:
-    the flash custom_vjp always runs both backward kernels, so a q-only
-    cotangent would let autodiff dead-code the reference's dk/dv paths and
-    bias the comparison.  dq+dk+dv are q-shaped, so their sum chains the
-    scan."""
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-
-    ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    q = jax.random.normal(ks[0], (b, t, h, d), jnp.bfloat16)
-    k = jax.random.normal(ks[1], (b, t, h, d), jnp.bfloat16)
-    v = jax.random.normal(ks[2], (b, t, h, d), jnp.bfloat16)
-
-    g = jax.grad(lambda q_, k_, v_: jnp.sum(
-        attn(q_, k_, v_).astype(jnp.float32) ** 2), argnums=(0, 1, 2))
-
-    @jax.jit
-    def chain(q0):
-        def body(c, _):
-            dq, dk, dv = g(c, k, v)
-            return (dq + dk + dv).astype(jnp.bfloat16), None
-        out, _ = lax.scan(body, q0, None, length=reps)
-        return out
-
-    out = chain(q)
-    float(np.asarray(out[0, 0, 0, 0]))  # warm + drain
-    best = float("inf")
-    for _ in range(3):
-        t0 = time.perf_counter()
-        out = chain(q)
-        float(np.asarray(out[0, 0, 0, 0]))
-        best = min(best, (time.perf_counter() - t0) / reps)
-    return best * 1000
-
-
-def bench_attention(b=4, t=2048, h=8, d=128, reps=10):
-    """Flash-kernel vs XLA-reference attention, fwd+bwd, at the BASELINE.md
-    comparison shape (B4/T2048/H8/D128 bf16 causal).  Returns
-    (flash_ms, xla_ms) per fwd+bwd call."""
-    from tfmesos_tpu.ops.attention import flash_attention, mha_reference
-
-    flash_ms = _timed_attention_fwdbwd(
-        lambda q_, k_, v_: flash_attention(q_, k_, v_, causal=True),
-        b, t, h, d, reps)
-    xla_ms = _timed_attention_fwdbwd(
-        lambda q_, k_, v_: mha_reference(q_, k_, v_, causal=True),
-        b, t, h, d, reps)
-    return flash_ms, xla_ms
-
-
-def bench_attention_blocks(b=4, t=2048, h=8, d=128, reps=10):
-    """Flash fwd+bwd per block_q choice — the recorded number BASELINE.md
-    asks for before re-raising the default from 512.  Same chained-scan
-    protocol as bench_attention; returns {"bq512": ms, "bq1024": ms}."""
-    from tfmesos_tpu.ops.attention import flash_attention
-
-    def timed(bq):
-        return round(_timed_attention_fwdbwd(
-            lambda q_, k_, v_: flash_attention(q_, k_, v_, causal=True,
-                                               block_q=bq),
-            b, t, h, d, reps), 3)
-
-    return {"bq512": timed(512), "bq1024": timed(1024)}
-
-
-def bench_attention_tsweep():
-    """Flash vs XLA fwd+bwd across sequence lengths — the regime sweep
-    behind the flash kernel's long-context claim (the win grows with T
-    as XLA's O(T^2) score materialization saturates HBM; round-5
-    measured ~2-3x at T=4k up to ~11x at T=8k on one v5e chip).  Each
-    point is bench_attention at (b, t) — one protocol for the headline
-    row and the sweep."""
-    res = {}
-    for t in (4096, 8192):
-        b = 4 if t <= 4096 else 2
-        reps = max(2, 10 * 2048 // t)
-        f, x = bench_attention(b=b, t=t, reps=reps)
-        res[f"t{t}"] = {"flash_ms": round(f, 2), "xla_ms": round(x, 2),
-                        "speedup": round(x / f, 3)}
-    return res
-
-
-def pipeline_bubble_stats(pp=8, m=8):
-    """STATIC 1F1B schedule analytics — a timetable, not a timed run.
-    Cost model: a forward tick costs
-    1 unit of a full stage's forward, a backward tick 3 (recompute +
-    backward — the schedule always remats from the stashed input), both
-    scaled by 1/v at v virtual chunks; devices synchronize on the ring
-    every tick, so wall-clock is the per-tick MAX over devices and the
-    bubble is each device's idle share of that wall.
-    ``interleave_speedup`` is the v=1 / v=2 wall ratio at equal work —
-    the interleaved schedule's claim in one number.  Defaults measure
-    the BUBBLE-BOUND regime (pp=8, m=8 — deep pipe, few microbatches)
-    where interleaving exists to help (~1.2x there); at m >> pp the
-    fill bubble amortizes away and the ratio approaches 1, and at
-    pp=2 it can dip below (prefer v=1 there)."""
-    import numpy as np
-    from tfmesos_tpu.parallel.pipeline import _schedule_1f1b
-
-    cost = np.array([0.0, 1.0, 3.0])    # idle / forward / backward
-    out = {}
-    walls = {}
-    for v in (1, 2):
-        kinds, _, _ = _schedule_1f1b(pp, m, v)
-        per_tick = cost[kinds].max(axis=1) / v          # [T]
-        wall = float(per_tick.sum())
-        busy = float((cost[kinds] / v).sum())           # device work units
-        out[f"pipeline_bubble_v{v}"] = round(1.0 - busy / (wall * pp), 4)
-        walls[v] = wall
-    out["pipeline_interleave_speedup"] = round(walls[1] / walls[2], 3)
-    return out
-
-
-def bench_ring_window(t=8192, window=1024, reps=10, interpret=False,
-                      h=8, d=128):
-    """Ring attention with a sliding window across every visible device:
-    the Pallas offset-window inner (per-step kernels skip k-blocks
-    outside the window — O(T·W) work ring-wide) vs the einsum inner.
-    Needs >1 device (an sp axis); returns (flash_ms, einsum_ms) or None.
-    ``interpret=True`` is the CI smoke path (Mosaic interpreter off-TPU)."""
-    import jax
-    import jax.numpy as jnp
-    from tfmesos_tpu.parallel.mesh import build_mesh
-    from tfmesos_tpu.parallel.ring_attention import ring_attention
-
-    n = jax.device_count()
-    if n < 2 or t % n:
-        return None
-    mesh = build_mesh({"sp": n})
-    b = 1
-    key = jax.random.PRNGKey(0)
-    kq, kk, kv = jax.random.split(key, 3)
-    dt = jnp.bfloat16 if jax.default_backend() == "tpu" else jnp.float32
-    q = jax.random.normal(kq, (b, t, h, d), dt)
-    k = jax.random.normal(kk, (b, t, h, d), dt)
-    v = jax.random.normal(kv, (b, t, h, d), dt)
-
-    def timed(impl):
-        fn = jax.jit(lambda q_, k_, v_: ring_attention(
-            q_, k_, v_, mesh, causal=True, window=window, impl=impl,
-            interpret=interpret))
-        jax.block_until_ready(fn(q, k, v))       # compile
-        best = float("inf")
-        for _ in range(3):
-            t0 = time.perf_counter()
-            for _ in range(reps):
-                out = fn(q, k, v)
-            jax.block_until_ready(out)
-            best = min(best, (time.perf_counter() - t0) / reps)
-        return best * 1000.0
-
-    return timed("flash"), timed("xla")
-
-
-def _serving_bench_setup(tiny: bool, max_len=None, plen=None, new=None):
-    """(cfg, params, reqs-maker, max_len, new-tokens) for the serving
-    benches — flagship config (with optional max_len/prompt/continuation
-    overrides, so every serving bench shares ONE protocol), or a
-    CI-affordable tiny one (which fixes its own sizes)."""
-    import jax
-    import jax.numpy as jnp
-    from tfmesos_tpu.models import transformer
-    from tfmesos_tpu.serving import Request
-
-    if tiny:
-        cfg = transformer.TransformerConfig(
-            vocab_size=128, d_model=32, n_layers=2, n_heads=4, d_ff=64,
-            max_seq_len=128, dtype=jnp.float32)
-        max_len, plen, new = 64, 8, 4
-    else:
-        max_len = max_len or 1024
-        plen, new = plen or 64, new or 64
-        cfg = transformer.TransformerConfig(
-            vocab_size=8192, d_model=512, n_layers=8, n_heads=8, d_ff=1408,
-            max_seq_len=max_len, dtype=jnp.bfloat16)
-    params = transformer.init_params(cfg, jax.random.PRNGKey(0))
-    rng = np.random.default_rng(0)
-
-    def reqs(n):
-        return [Request(prompt=rng.integers(0, cfg.vocab_size, size=(plen,))
-                        .astype(np.int32), max_new_tokens=new)
-                for _ in range(n)]
-
-    return cfg, params, reqs, max_len, new
-
-
-def bench_serving_continuous(n_requests=32, rows=8, tiny=False):
-    """Continuous-batching serving throughput: requests/s for a prompt
-    stream admitted into a persistent paged decode
-    (serving.ContinuousBatcher) — flagship config, or the tiny CI smoke
-    config with ``tiny=True``."""
-    from tfmesos_tpu.serving import ContinuousBatcher
-
-    cfg, params, reqs, max_len, _ = _serving_bench_setup(tiny)
-    batcher = ContinuousBatcher(cfg, params, rows=rows, max_len=max_len)
-    list(batcher.run(reqs(2)))  # warm the compiles outside the timed region
-    t0 = time.perf_counter()
-    done = list(batcher.run(reqs(n_requests)))
-    dt = time.perf_counter() - t0
-    assert len(done) == n_requests
-    mean_ttft_ms = 1000.0 * sum(c.ttft_s for c in done) / n_requests
-    # Decode-phase inter-token p50 of the BASELINE loop — the number
-    # the pipelined-decode bench (bench_serving_pipeline) is measured
-    # against, recorded here so every round has the un-pipelined
-    # reference even when the pipeline section is skipped.
-    decode_itl_p50_ms = _itl_p50_ms(done)
-
-    # Multi-step blocks: K decode steps fused into ONE dispatch, one
-    # host sync per [rows, K] token block.  Round-5 TPU profiling showed
-    # per-tick dispatch+sync dominating the batcher — this is the fix.
-    ms = ContinuousBatcher(cfg, params, rows=rows, max_len=max_len,
-                           multi_step=16)
-    list(ms.run(reqs(2)))
-    t0 = time.perf_counter()
-    mdone = list(ms.run(reqs(n_requests)))
-    multistep_rps = len(mdone) / (time.perf_counter() - t0)
-    return n_requests / dt, mean_ttft_ms, multistep_rps, decode_itl_p50_ms
-
-
-def _itl_p50_ms(completions) -> float:
-    """p50 over per-completion mean decode inter-token gaps (the time
-    AFTER the first token, normalized by the tokens that follow it)."""
-    vals = sorted(1000.0 * (c.total_s - c.ttft_s)
-                  / max(1, len(c.tokens) - 1) for c in completions)
-    return vals[len(vals) // 2]
-
-
-def bench_serving_pipeline(n_requests=16, rows=8, tiny=False):
-    """Pipelined device-resident decode (``pipeline_depth=1``) vs the
-    synchronous loop (``0``) on the SAME request objects in one
-    process: the pipelined batcher feeds block N+1 from the device-side
-    carry and syncs block N's tokens one block behind, so the decode
-    inter-token p50 must be STRICTLY better — and since pipelining only
-    moves the sync point, the outputs are asserted token-identical
-    first (a faster wrong stream is not a result)."""
-    from tfmesos_tpu.serving import ContinuousBatcher
-
-    cfg, params, reqs, max_len, _ = _serving_bench_setup(tiny)
-    warm_batch = reqs(2)
-    batch = reqs(n_requests)    # ONE workload, served by both modes
-
-    def run(depth):
-        b = ContinuousBatcher(cfg, params, rows=rows, max_len=max_len,
-                              pipeline_depth=depth)
-        list(b.run(list(warm_batch)))   # compiles outside the timing
-        t0 = time.perf_counter()
-        done = sorted((c.rid, c) for c in b.run(list(batch)))
-        dt = time.perf_counter() - t0
-        assert len(done) == n_requests
-        return ([c.tokens for _, c in done],
-                _itl_p50_ms(c for _, c in done), n_requests / dt)
-
-    base_tokens, base_itl, _ = run(0)
-    pipe_tokens, pipe_itl, pipe_rps = run(1)
-    assert pipe_tokens == base_tokens, \
-        "pipelined completions diverged from the synchronous loop"
-    assert pipe_itl < base_itl, \
-        (f"pipelined decode inter-token p50 {pipe_itl:.3f}ms not "
-         f"strictly better than synchronous {base_itl:.3f}ms")
-    return pipe_itl, base_itl, pipe_rps
-
-
-def bench_serving_fused_prefill(n_interactive=12, n_long=8, rows=4,
-                                tiny=False, best_of=3):
-    """Stall-free fused scheduling (docs/SERVING.md) vs the phase-split
-    chunked tick on the SAME long-prompt-interference workload: short
-    interactive requests decode while long prompts chunk in behind
-    them.  Phase-split pays a separate chunk dispatch ahead of every
-    decode block; the fused tick folds the budgeted chunk slots INTO
-    the decode dispatch, so the interactive decode inter-token p99
-    must be STRICTLY better fused — and since fusion only moves where
-    the chunk rides, the streams are asserted token-identical first
-    (a faster diverged stream is not a result).  The gap population is
-    REAL per-token stream timestamps (``Request.on_tokens`` fires at
-    every tick's flush), pooled across the interactive requests —
-    interfered ticks are a large fraction of that pool, so the p99
-    reads the stalled tick's duration, not one scheduler hiccup — and
-    the reported number is the median of per-run p99s over
-    ``best_of`` runs per mode."""
-    from tfmesos_tpu.serving import ContinuousBatcher, Request
-
-    cfg, params, _, max_len, _ = _serving_bench_setup(tiny)
-    chunk = 8 if tiny else 64
-    short_new = 24 if tiny else 48
-    long_chunks = 7 if tiny else 5      # tiny max_len 64: 56 + 2 fits
-    rng = np.random.default_rng(7)
-    shorts = [rng.integers(0, cfg.vocab_size, size=(chunk,))
-              .astype(np.int32) for _ in range(n_interactive)]
-    longs = [rng.integers(0, cfg.vocab_size, size=(long_chunks * chunk,))
-             .astype(np.int32) for _ in range(n_long)]
-
-    def mk():
-        # Shorts fill the rows first; each long admits as a row frees,
-        # so there is (nearly) always a prompt chunking while the
-        # resident shorts decode — the stall the fused tick removes.
-        items = [Request(prompt=p.copy(), max_new_tokens=short_new)
-                 for p in shorts[:rows]]
-        rest = [Request(prompt=p.copy(), max_new_tokens=short_new)
-                for p in shorts[rows:]]
-        for i, p in enumerate(longs):
-            items.append(Request(prompt=p.copy(), max_new_tokens=2))
-            items.extend(rest[2 * i:2 * (i + 1)])
-        items.extend(rest[2 * n_long:])
-        return items
-
-    n_total = n_interactive + n_long
-    interactive_idx = {i for i, r in enumerate(mk())
-                       if r.max_new_tokens == short_new}
-
-    def run(fused):
-        kw = dict(rows=rows, max_len=max_len, prefill_chunk=chunk,
-                  fused_prefill=fused)
-        tokens, p99s, dt = None, [], 1.0
-        for _ in range(best_of):
-            b = ContinuousBatcher(cfg, params, **kw)
-            b.warmup()      # the whole grid AOT, incl. fused [w,S]
-            items = mk()
-            stamps = [[] for _ in items]
-            for i in interactive_idx:
-                def cb(toks, off, acc=stamps[i]):
-                    acc.append(time.perf_counter())
-                items[i].on_tokens = cb
-            t0 = time.perf_counter()
-            done = {c.rid: c for c in b.run(items)}
-            dt = time.perf_counter() - t0
-            assert len(done) == n_total
-            if fused:
-                assert b.fused_ticks > 0 and b.fused_chunk_tokens > 0, \
-                    "fused batcher never fused a chunk into a tick"
-            # rid assignment follows pull order — map completions back
-            # to workload positions through the sorted rid sequence.
-            tokens = [done[rid].tokens for rid in sorted(done)]
-            gaps = sorted(1000.0 * (b2 - a)
-                          for acc in stamps
-                          for a, b2 in zip(acc, acc[1:]))
-            assert len(gaps) >= 50, \
-                "too few streamed gaps to read a p99 from"
-            p99s.append(gaps[min(len(gaps) - 1,
-                                 int(0.99 * len(gaps)))])
-        return tokens, sorted(p99s)[len(p99s) // 2], n_total / dt
-
-    split_tokens, split_p99, _ = run(False)
-    fused_tokens, fused_p99, fused_rps = run(True)
-    assert fused_tokens == split_tokens, \
-        "fused completions diverged from the phase-split tick"
-    assert fused_p99 < split_p99, \
-        (f"interactive inter-token p99 under long-prompt interference "
-         f"not strictly better fused: {fused_p99:.3f}ms vs phase-split "
-         f"{split_p99:.3f}ms")
-    return fused_p99, split_p99, fused_rps
-
-
-def bench_decode_paged_call(tiny=False, reps=30):
-    """Per-call paged-attention decode latency + launches-per-block —
-    the device floor BASELINE.md round 5 localized (~0.54 ms/launch x
-    8 launches per 16-step block) promoted to first-class bench keys
-    so the floor is tracked across rounds instead of living in prose.
-
-    Measures one jitted ``flash_decode_paged`` call at t=1 (the
-    synchronous steady-state step) and at t=8 (the FUSED multi-row
-    step a speculative verify dispatches: 8 decode rows retired
-    through ONE launch per layer), plus the analytic launches a
-    16-token block costs per mode
-    (``ContinuousBatcher.paged_launches_per_block``) — the fused path
-    asserted at <= 2, the acceptance bar."""
-    import jax
-    import jax.numpy as jnp
-    from tfmesos_tpu.models import transformer
-    from tfmesos_tpu.ops.attention import flash_decode_paged
-    from tfmesos_tpu.serving import ContinuousBatcher
-
-    if tiny:
-        b, kv, g, d, ps, npg = 2, 2, 2, 16, 16, 4
-    else:
-        b, kv, g, d, ps, npg = 4, 4, 2, 64, 64, 16
-    h = kv * g
-    dt = jnp.bfloat16 if jax.default_backend() == "tpu" else jnp.float32
-    key = jax.random.PRNGKey(0)
-    kq, kk, kvv = jax.random.split(key, 3)
-    pool_k = jax.random.normal(kk, (b * npg + 1, kv, ps, d), dt)
-    pool_v = jax.random.normal(kvv, (b * npg + 1, kv, ps, d), dt)
-    table = jnp.arange(b * npg, dtype=jnp.int32).reshape(b, npg)
-    pos = jnp.full((b,), (npg - 1) * ps, jnp.int32)
-
-    def timed(t):
-        q = jax.random.normal(kq, (b, t, h, d), dt)
-        self_kv = (jax.random.normal(kk, (b, t, kv, d), dt),
-                   jax.random.normal(kvv, (b, t, kv, d), dt))
-        fn = jax.jit(lambda q_, s_: flash_decode_paged(
-            q_, pool_k, pool_v, table, pos, self_kv=s_))
-        jax.block_until_ready(fn(q, self_kv))    # compile
-        best = float("inf")
-        for _ in range(3):
-            t0 = time.perf_counter()
-            for _ in range(reps):
-                out = fn(q, self_kv)
-            jax.block_until_ready(out)
-            best = min(best, (time.perf_counter() - t0) / reps)
-        return best * 1000.0
-
-    call_ms, fused_ms = timed(1), timed(8)
-
-    cfg, params, _, max_len, _ = _serving_bench_setup(True)
-    sync = ContinuousBatcher(cfg, params, rows=2, max_len=max_len)
-    dcfg = transformer.TransformerConfig(
-        vocab_size=cfg.vocab_size, d_model=16, n_layers=1, n_heads=2,
-        d_ff=32, max_seq_len=max_len + 8, dtype=jnp.float32)
-    dparams = transformer.init_params(dcfg, jax.random.PRNGKey(1))
-    spec = ContinuousBatcher(cfg, params, rows=2, max_len=max_len,
-                             draft_cfg=dcfg, draft_params=dparams,
-                             n_draft=7)
-    sync_lpb = sync.paged_launches_per_block(16)
-    fused_lpb = spec.paged_launches_per_block(16)
-    assert fused_lpb <= 2, \
-        (f"fused path costs {fused_lpb} paged launches per 16-step "
-         f"block — the acceptance bar is <= 2")
-    return call_ms, fused_ms, sync_lpb, fused_lpb
-
-
-def bench_serving_warmup(rows=4, tiny=False):
-    """First-request TTFT on a COLD batcher (the request pays the
-    admission-prefill and first-decode compiles) vs a WARMED one
-    (``ContinuousBatcher.warmup()`` built every executable at boot,
-    off the serving path) — the fleet's ``warming`` replica state
-    exists to buy exactly this, so warm must be STRICTLY below cold."""
-    from tfmesos_tpu.serving import ContinuousBatcher
-
-    cfg, params, reqs, max_len, _ = _serving_bench_setup(tiny)
-    probe = reqs(1)
-    cold = ContinuousBatcher(cfg, params, rows=rows, max_len=max_len)
-    cold_done = list(cold.run(list(probe)))
-    cold_ttft = 1000.0 * cold_done[0].ttft_s
-    warm = ContinuousBatcher(cfg, params, rows=rows, max_len=max_len)
-    warm_s = warm.warmup()["seconds"]
-    warm_done = list(warm.run(list(probe)))
-    warm_ttft = 1000.0 * warm_done[0].ttft_s
-    assert warm_done[0].tokens == cold_done[0].tokens, \
-        "warmup changed the served stream"
-    assert warm_ttft < cold_ttft, \
-        (f"warmed first-request TTFT {warm_ttft:.1f}ms not strictly "
-         f"below cold {cold_ttft:.1f}ms")
-    return warm_ttft, cold_ttft, warm_s
-
-
-def bench_serving_prefix_cache(n_requests=16, rows=4, tiny=False):
-    """Cross-request prefix caching on a shared-system-prompt workload
-    (the dominant online pattern: one system/few-shot prompt, distinct
-    user tails): mean TTFT with the prefix WARM in the cache vs COLD
-    full prefill, plus warm throughput and the observed hit rate.  The
-    correctness bar rides along: warm completions must EQUAL the
-    cold-prefill completions."""
-    from tfmesos_tpu.serving import ContinuousBatcher, Request
-
-    if tiny:
-        cfg, params, _, max_len, _ = _serving_bench_setup(True)
-        page, sys_len, tail_len, new = 16, 40, 8, 4
-    else:
-        cfg, params, _, max_len, _ = _serving_bench_setup(False)
-        page, sys_len, tail_len, new = 64, 448, 64, 32
-    rng = np.random.default_rng(7)
-    system = rng.integers(0, cfg.vocab_size, size=(sys_len,)).astype(np.int32)
-
-    def reqs(n, seed=1):
-        r2 = np.random.default_rng(seed)
-        return [Request(prompt=np.concatenate(
-                    [system, r2.integers(0, cfg.vocab_size,
-                                         size=(tail_len,)).astype(np.int32)]),
-                    max_new_tokens=new)
-                for _ in range(n)]
-
-    cold = ContinuousBatcher(cfg, params, rows=rows, max_len=max_len,
-                             page_size=page, prefill_bucket=page)
-    list(cold.run(reqs(2, seed=99)))    # warm the compiles only
-    cold_done = sorted((c.rid, c) for c in cold.run(reqs(n_requests)))
-    cold_ttft = 1000.0 * sum(c.ttft_s for _, c in cold_done) / n_requests
-
-    warm = ContinuousBatcher(cfg, params, rows=rows, max_len=max_len,
-                             page_size=page, prefill_bucket=page,
-                             prefix_cache_pages=4 * (sys_len // page + 2))
-    # Prime: compiles AND publishes the system prefix into the cache —
-    # with a DISTINCT tail seed, so the measured stream hits only on
-    # the shared system pages (a same-seed prime would make request 0
-    # a byte-identical full-prompt hit and flatter the warm TTFT).
-    list(warm.run(reqs(2, seed=99)))
-    list(warm.run(reqs(1, seed=98)))
-    t0 = time.perf_counter()
-    warm_done = sorted((c.rid, c) for c in warm.run(reqs(n_requests)))
-    dt = time.perf_counter() - t0
-    warm_ttft = 1000.0 * sum(c.ttft_s for _, c in warm_done) / n_requests
-    assert [c.tokens for _, c in warm_done] == \
-        [c.tokens for _, c in cold_done], \
-        "prefix-cached completions diverged from cold prefill"
-    st = warm.prefix_cache_stats()
-    hit_rate = st["hits"] / max(1, st["hits"] + st["misses"])
-    return warm_ttft, cold_ttft, n_requests / dt, hit_rate
-
-
-def bench_serving_spec_compose(n_requests=12, rows=4, tiny=False,
-                               decode_new=24, migrate_requests=6,
-                               strict=True):
-    """Speculative decoding composed with the fast path (the bypass
-    burn-down, ROADMAP item 6) — three arms:
-
-    * ``serving_spec_warm_ttft_ms`` vs ``serving_spec_cold_ttft_ms`` —
-      a SPECULATIVE batcher on the shared-system-prompt workload with
-      the prefix cache warm (twin target+draft pages mapped read-only,
-      only the tail prefilled through both writers) vs cold full
-      prefill; warm asserted STRICTLY below cold, streams asserted
-      EQUAL (a faster wrong stream is not a result).
-    * ``serving_spec_decode_p50_intertoken_ms`` vs the non-speculative
-      baseline on the same workload — measured with a PERFECT draft
-      (draft == target): every round commits n_draft+1 tokens for one
-      dispatch+sync.  RECORDED, not asserted: speculative decoding
-      wins where decode is bandwidth/dispatch-bound (the accelerator
-      regime); on this compute-bound CPU host a perfect draft costs
-      ~2x target FLOPs per committed token, so wall-clock favors the
-      baseline here by construction — the number tracks the overhead
-      honestly (``serving_spec_acceptance_rate`` rides along, 1.0 for
-      the perfect draft).
-    * ``serving_spec_migration_lost_requests`` — a live 2-replica
-      CPU fleet serving with drafts drain-MIGRATES one replica while
-      spec requests are mid-decode: suspended rows move as KV exports
-      CARRYING the draft-side payload and resume on the survivor;
-      asserted zero lost with every stream equal to the local
-      speculative reference.
-    """
-    import threading
-
-    import jax
-    import jax.numpy as jnp
-
-    from tfmesos_tpu.fleet.client import FleetClient
-    from tfmesos_tpu.fleet.launcher import FleetServer
-    from tfmesos_tpu.fleet.replica import tiny_draft_model, tiny_model
-    from tfmesos_tpu.models import transformer
-    from tfmesos_tpu.serving import ContinuousBatcher, Request
-
-    n_draft = 4
-    if tiny:
-        cfg, params, _, max_len, _ = _serving_bench_setup(True)
-        page, sys_len, tail_len, new = 16, 40, 8, 4
-        dcfg = transformer.TransformerConfig(
-            vocab_size=cfg.vocab_size, d_model=16, n_layers=1,
-            n_heads=2, d_ff=32, max_seq_len=max_len + n_draft + 1,
-            dtype=jnp.float32)
-    else:
-        cfg, params, _, max_len, _ = _serving_bench_setup(False)
-        page, sys_len, tail_len, new = 64, 448, 64, 16
-        dcfg = transformer.TransformerConfig(
-            vocab_size=cfg.vocab_size, d_model=128, n_layers=2,
-            n_heads=4, d_ff=352, max_seq_len=max_len + n_draft + 1,
-            dtype=jnp.bfloat16)
-    dparams = transformer.init_params(dcfg, jax.random.PRNGKey(1))
-    rng = np.random.default_rng(7)
-    system = rng.integers(0, cfg.vocab_size,
-                          size=(sys_len,)).astype(np.int32)
-
-    def reqs(n, seed=1, mnt=new):
-        r2 = np.random.default_rng(seed)
-        return [Request(prompt=np.concatenate(
-                    [system, r2.integers(0, cfg.vocab_size,
-                                         size=(tail_len,))
-                     .astype(np.int32)]), max_new_tokens=mnt)
-                for _ in range(n)]
-
-    spec_kw = dict(rows=rows, max_len=max_len, page_size=page,
-                   prefill_bucket=page, draft_cfg=dcfg,
-                   draft_params=dparams, n_draft=n_draft)
-    # Arm 1: spec + prefix cache, warm vs cold TTFT (streams equal).
-    cold = ContinuousBatcher(cfg, params, **spec_kw)
-    list(cold.run(reqs(2, seed=99)))        # compiles only
-    cold_done = sorted((c.rid, c) for c in cold.run(reqs(n_requests)))
-    cold_ttft = 1000.0 * sum(c.ttft_s
-                             for _, c in cold_done) / n_requests
-    warm = ContinuousBatcher(cfg, params,
-                             prefix_cache_pages=4 * (sys_len // page
-                                                     + 2), **spec_kw)
-    list(warm.run(reqs(2, seed=99)))        # compiles + publishes
-    list(warm.run(reqs(1, seed=98)))        # distinct tail: shared hit
-    warm_done = sorted((c.rid, c) for c in warm.run(reqs(n_requests)))
-    warm_ttft = 1000.0 * sum(c.ttft_s
-                             for _, c in warm_done) / n_requests
-    assert [c.tokens for _, c in warm_done] == \
-        [c.tokens for _, c in cold_done], \
-        "spec prefix-cached completions diverged from spec cold prefill"
-    # ``strict=False`` (the tiny CI smoke) keeps every CORRECTNESS
-    # assert but lets the two timing wins pass un-asserted — toy
-    # shapes invert timings; the flagship bench asserts both.
-    assert not strict or warm_ttft < cold_ttft, \
-        (f"spec+prefix warm TTFT {warm_ttft:.1f}ms not strictly below "
-         f"spec cold TTFT {cold_ttft:.1f}ms")
-
-    # Arm 2: spec inter-token p50 vs the non-spec baseline (perfect
-    # draft = the ceiling; acceptance_rate rides along).  The perfect
-    # draft IS the target config, whose max_seq_len must cover the
-    # verify overshoot — both arms serve at the reduced max_len so
-    # they measure the same workload.
-    ml2 = max_len - n_draft - 1
-    base = ContinuousBatcher(cfg, params, rows=rows, max_len=ml2,
-                             page_size=page, prefill_bucket=page)
-    list(base.run(reqs(2, seed=97)))
-    base_done = list(base.run(reqs(n_requests, seed=3)))
-    base_itl = _itl_p50_ms(base_done)
-    perfect = ContinuousBatcher(cfg, params, rows=rows, max_len=ml2,
-                                page_size=page, prefill_bucket=page,
-                                draft_cfg=cfg, draft_params=params,
-                                n_draft=n_draft)
-    list(perfect.run(reqs(2, seed=97)))
-    spec_done = list(perfect.run(reqs(n_requests, seed=3)))
-    spec_itl = _itl_p50_ms(spec_done)
-    accept = perfect.acceptance_rate or 0.0
-    # No strict assert here (see the docstring): the CPU host is
-    # compute-bound, where a perfect draft pays 2x FLOPs per token —
-    # the recorded pair is the honest comparison, and the round-count
-    # collapse is what the acceptance rate evidences.
-    assert accept > 0.9, \
-        f"perfect draft acceptance {accept:.3f} — the spec round is broken"
-
-    # Arm 3: mid-stream drain migration of a SPEC fleet, zero lost.
-    fleet = FleetServer(replicas=2, rows=2, tiny=True, max_len=64,
-                        page_size=16, prefill_bucket=16, draft=True,
-                        n_draft=3, workers=8, max_queue=64,
-                        request_timeout=300.0, start_timeout=300.0)
-    fleet.start()
-    try:
-        tcfg, tparams = tiny_model(seed=0)
-        tdcfg, tdparams = tiny_draft_model(max_len=64, n_draft=3)
-        ref_b = ContinuousBatcher(tcfg, tparams, rows=2, max_len=64,
-                                  page_size=16, prefill_bucket=16,
-                                  draft_cfg=tdcfg, draft_params=tdparams,
-                                  n_draft=3)
-        r2 = np.random.default_rng(11)
-        prompts = [r2.integers(0, tcfg.vocab_size,
-                               size=(9,)).astype(np.int32)
-                   for _ in range(migrate_requests)]
-        refs = {c.rid: c.tokens for c in ref_b.run(
-            [Request(prompt=p.copy(), max_new_tokens=decode_new)
-             for p in prompts])}
-        client = FleetClient(fleet.addr, fleet.token, timeout=300.0)
-        client.generate(prompts[0], 2)      # warm replica compiles
-        results = [None] * migrate_requests
-        errors = []
-
-        def one(i):
-            try:
-                results[i] = client.generate(prompts[i], decode_new,
-                                             timeout=300.0)
-            except Exception as e:
-                errors.append((i, e))
-
-        threads = [threading.Thread(target=one, args=(i,), daemon=True)
-                   for i in range(migrate_requests)]
-        for t in threads:
-            t.start()
-        # Migrate whichever replica has work in flight, MID-decode.
-        deadline = time.perf_counter() + 120.0
-        victim = None
-        while victim is None and time.perf_counter() < deadline:
-            busy = [r for r in fleet.registry.alive()
-                    if r.outstanding > 0]
-            victim = busy[0].addr if busy else None
-            time.sleep(0.02)
-        assert victim is not None, "no replica ever reported work"
-        fleet.request_migration(victim)
-        for t in threads:
-            t.join(timeout=300.0)
-        assert not errors, f"spec request lost in migration: {errors[0]!r}"
-        for i in range(migrate_requests):
-            assert results[i]["tokens"] == refs[i], \
-                f"migrated spec request {i} diverged from the reference"
-        c = fleet.snapshot()["counters"]
-        moved = (c.get("migration_resumes", 0)
-                 + c.get("migration_reruns", 0))
-        assert moved >= 1, f"migration never moved a request: {c}"
-        resumes = int(c.get("migration_resumes", 0))
-        client.close()
-    finally:
-        fleet.stop()
-    return (warm_ttft, cold_ttft, spec_itl, base_itl, accept, resumes)
-
-
-def bench_fleet_prefix_affinity(n_requests=24, replicas=2, rows=4,
+def scenario_prefix_affinity(n_requests=24, replicas=2, rows=4,
                                 n_prefixes=2, max_new_tokens=6,
                                 workers=8):
     """Prefix-affinity routing through the full fleet front door:
@@ -1090,10 +87,10 @@ def bench_fleet_prefix_affinity(n_requests=24, replicas=2, rows=4,
         fleet.stop()
 
 
-def bench_fleet_sessions(replicas=2, rows=4, turns=4, n_shared=8,
+def scenario_sessions(replicas=2, rows=4, turns=4, n_shared=8,
                          workers=8, max_new_tokens=8):
     """The fleet-wide KV economy (docs/SERVING.md "KV tiering &
-    sessions"), both halves asserted in-bench:
+    sessions"), both halves asserted in the scenario:
 
     * SESSIONS — a multi-turn conversation on a KV-tiered fleet: each
       turn's full-history prompt is served twice, once cold (no
@@ -1254,11 +251,11 @@ def bench_fleet_sessions(replicas=2, rows=4, turns=4, n_shared=8,
     return resumed_med, cold_med, hit_rate, prefills, aff_rate
 
 
-def bench_fleet_fabric(replicas=3, rows=2, workers=8, n_sessions=6,
+def scenario_fabric(replicas=3, rows=2, workers=8, n_sessions=6,
                        max_new_tokens=4, n_transfers=24,
                        artifact_mb=1.0, seed=21):
     """The cross-host KV fabric (docs/SERVING.md "Cross-host KV
-    fabric"), both halves asserted in-bench:
+    fabric"), both halves asserted in the scenario:
 
     * DIRECT vs RELAY streaming — the same artifact workload (seeded
       ~1 MB session blobs over raw HMAC frames) pushed straight to a
@@ -1276,7 +273,7 @@ def bench_fleet_fabric(replicas=3, rows=2, workers=8, n_sessions=6,
       fabric fetch of the holder's copy (the holder serves no
       generates, so affinity cannot shortcut the wire path) — with
       streams token-identical to a cold reference: ZERO lost sessions
-      and at least one forwarded fetch hit, asserted in-bench.
+      and at least one forwarded fetch hit, asserted in the scenario.
 
     Reports (direct_mb_s, relay_mb_s, resumed_sessions,
     fabric_fetch_hits)."""
@@ -1406,61 +403,7 @@ def bench_fleet_fabric(replicas=3, rows=2, workers=8, n_sessions=6,
     return direct_mb_s, relay_mb_s, n_sessions, fetch_hits
 
 
-def bench_serving_longctx(n_requests=8, rows=4, max_len=8192,
-                          plen=512, new=128, tiny=False):
-    """Continuous batching at LONG context — the regime the kernel-native
-    carried cache, bucketed decode tables, and deferred pool commits
-    were built for (an 8k-slot paged pool per row).  Reports generated
-    tokens/s across the stream and mean TTFT, with multi_step=16 +
-    pipeline_depth=1; same protocol/scaffolding as the
-    headline serving bench (``_serving_bench_setup``; ``tiny=True`` is
-    the CI smoke — same call path at toy sizes)."""
-    from tfmesos_tpu.serving import ContinuousBatcher
-
-    cfg, params, reqs, max_len, new = _serving_bench_setup(
-        tiny, max_len=max_len, plen=plen, new=new)
-    b = ContinuousBatcher(cfg, params, rows=rows, max_len=max_len,
-                          multi_step=2 if tiny else 16,
-                          pipeline_depth=1)
-    list(b.run(reqs(2)))    # warm the compiles outside the timed region
-    t0 = time.perf_counter()
-    done = list(b.run(reqs(n_requests)))
-    dt = time.perf_counter() - t0
-    assert len(done) == n_requests
-    ttft = 1000.0 * sum(c.ttft_s for c in done) / n_requests
-    return n_requests * new / dt, ttft
-
-
-def bench_serving_continuous_mesh(n_requests=32, rows=8, tiny=False):
-    """Multi-chip continuous serving: the same stream through a dp x tp
-    mesh over every visible device (pool pages sharded over dp, heads
-    over tp) — requests/s should scale with dp on real slices.  Its own
-    bench section so a mesh failure cannot discard the single-device
-    serving numbers."""
-    import jax
-    from tfmesos_tpu.parallel.mesh import build_mesh
-    from tfmesos_tpu.serving import ContinuousBatcher
-
-    n = jax.device_count()
-    if n < 2:
-        return None
-    cfg, params, reqs, max_len, _ = _serving_bench_setup(tiny)
-    tp = 2 if cfg.n_heads % 2 == 0 and n % 2 == 0 else 1
-    dp = n // tp
-    mesh = build_mesh({"dp": dp, "tp": tp},
-                      devices=jax.devices()[:dp * tp])
-    mrows = -(-rows // dp) * dp         # smallest multiple of dp >= rows
-    mb = ContinuousBatcher(cfg, params, rows=mrows, max_len=max_len,
-                           mesh=mesh)
-    list(mb.run(reqs(2)))   # warm the compiles outside the timed region
-    t0 = time.perf_counter()
-    done = list(mb.run(reqs(n_requests)))
-    dt = time.perf_counter() - t0
-    assert len(done) == n_requests
-    return n_requests / dt
-
-
-def bench_fleet_serving(n_requests=32, replicas=2, rows=4, tiny=True,
+def scenario_serving(n_requests=32, replicas=2, rows=4, tiny=True,
                         max_new_tokens=8, workers=16):
     """Online fleet serving: requests/s and mean TTFT through the full
     front door — gateway + admission + router + N ``LocalBackend``
@@ -1527,7 +470,7 @@ def bench_fleet_serving(n_requests=32, replicas=2, rows=4, tiny=True,
         fleet.stop()
 
 
-def bench_fleet_disagg(n_decode=8, decode_new=24, prompt_len=96,
+def scenario_disagg(n_decode=8, decode_new=24, prompt_len=96,
                        rows=4, workers=8, feeders=2):
     """Disaggregated prefill/decode serving vs a unified fleet of the
     SAME size on a mixed workload: long-prompt requests stream in
@@ -1584,7 +527,7 @@ def bench_fleet_disagg(n_decode=8, decode_new=24, prompt_len=96,
                         # headline dis_itl < uni_itl comparison is only
                         # meaningful while BOTH runs see continuous long
                         # prefills.  Keep feeding; only a persistent
-                        # streak aborts the bench loudly (asserted after
+                        # streak aborts the scenario loudly (asserted after
                         # join, not swallowed in a daemon thread).
                         streak += 1
                         if streak >= 8:
@@ -1656,7 +599,7 @@ def bench_fleet_disagg(n_decode=8, decode_new=24, prompt_len=96,
     return dis_ttft, dis_itl, uni_ttft, uni_itl, kv_mb_s
 
 
-def bench_fleet_gang(n_requests=6, gang_size=2, rows=4, decode_new=24,
+def scenario_gang(n_requests=6, gang_size=2, rows=4, decode_new=24,
                      workers=8):
     """Gang replicas (docs/SERVING.md "Gang replicas") behind the same
     gateway: each replica is ``gang_size`` member tasks forming one
@@ -1836,19 +779,19 @@ def bench_fleet_gang(n_requests=6, gang_size=2, rows=4, decode_new=24,
     return gang_itl, single_itl, reform_s
 
 
-def bench_fleet_autoscale(rows=2, max_new_tokens=4, workers=8):
-    """Control-plane reaction benchmarks on a live LocalBackend fleet:
+def scenario_autoscale(rows=2, max_new_tokens=4, workers=8):
+    """Control-plane reaction scenarios on a live LocalBackend fleet:
 
     * ``fleet_scaleup_reaction_s`` — surge start → a NEW replica task
       launched by the autoscaler is registered and ROUTABLE.  The surge
-      is an injected signal (the chaos.py discipline: the bench
+      is an injected signal (the chaos.py discipline: the scenario
       measures the fleet's launch→register→alive pipeline, not signal
       plumbing) and the loop is stepped by hand, so the number is the
       actuation cost, deterministically triggered.
     * ``fleet_rollout_downtime_ms`` — a blue-green rollout to a new
       weights_version runs under CONTINUOUS traffic; every request must
       succeed (zero Overloaded, zero RoutingError — asserted), so the
-      recorded downtime is 0 by contract and the bench fails loudly the
+      recorded downtime is 0 by contract and the scenario fails loudly the
       day it is not.
     """
     import threading
@@ -1934,13 +877,13 @@ def bench_fleet_autoscale(rows=2, max_new_tokens=4, workers=8):
         fleet.stop()
 
 
-def bench_fleet_multimodel(rows=2, max_new_tokens=4, workers=8):
+def scenario_multimodel(rows=2, max_new_tokens=4, workers=8):
     """Many models, one fleet (docs/SERVING.md "Model catalog") on a
-    live LocalBackend fleet, every contract asserted in-bench:
+    live LocalBackend fleet, every contract asserted in the scenario:
 
     * ``fleet_multimodel_trade_reaction_s`` — a two-model hotness flip
       on a FIXED replica budget: the hand-stepped ModelTrader (injected
-      signals, the chaos.py discipline — the bench measures the
+      signals, the chaos.py discipline — the scenario measures the
       drain→launch→register→alive pipeline, not signal plumbing) must
       TRADE a cold model's replica away and stand the hot model's
       second replica up; continuous two-tenant traffic rides through
@@ -2167,7 +1110,7 @@ def bench_fleet_multimodel(rows=2, max_new_tokens=4, workers=8):
         fleet.stop()
 
 
-def bench_fleet_priority(n_interactive=16, rows=3, workers=8,
+def scenario_priority(n_interactive=16, rows=3, workers=8,
                          flood_threads=3, interactive_new=2,
                          background_new=24):
     """SLO isolation + lossless migration under churn, on a live
@@ -2328,14 +1271,14 @@ def bench_fleet_priority(n_interactive=16, rows=3, workers=8,
         fleet.stop()
 
 
-def bench_fleet_soak(rows=2, workers=8, slow_delay_s=0.25,
+def scenario_soak(rows=2, workers=8, slow_delay_s=0.25,
                      n_timed=16, soak_probe_deadline_ms=60.0,
                      seed=20):
     """Seeded chaos soak: a live 3-replica CPU fleet driven through a
     GRAY failure (one replica alive-per-heartbeat but slow on every
     dispatch — chaos ``slow_task``), a SIGKILL + autoscaler-tick
     self-heal, a link sever, and a blue-green rollout, under continuous
-    two-class deadline-carrying traffic.  In-bench asserts (the PR's
+    two-class deadline-carrying traffic.  Asserts (the PR's
     acceptance criteria):
 
     * ``fleet_soak_lost_requests`` == 0 — every feeder request
@@ -2613,10 +1556,10 @@ def bench_fleet_soak(rows=2, workers=8, slow_delay_s=0.25,
             slow_attempt_ms, traces_detailed)
 
 
-def bench_fleet_sim(replicas=1000, n_requests=1_000_000, seed=0):
-    """Fleet-simulator scale + fidelity bench (docs/SIMULATOR.md).
+def scenario_sim(replicas=1000, n_requests=1_000_000, seed=0):
+    """Fleet-simulator scale + fidelity scenario (docs/SIMULATOR.md).
 
-    Two in-bench asserts:
+    Two asserts:
 
     * SCALE — the ``scale`` scenario (the REAL admission/router/
       containment/registry code on the virtual clock, 1000 simulated
@@ -2626,7 +1569,7 @@ def bench_fleet_sim(replicas=1000, n_requests=1_000_000, seed=0):
       per wall second) so per-request control-plane cost regressions
       surface as a throughput drop.
     * FIDELITY — the ``soak-replay`` scenario replays the seeded
-      ``bench_fleet_soak`` chaos timeline and must reproduce its
+      ``scenario_soak`` chaos timeline and must reproduce its
       qualitative outcomes: the gray-slow replica breaker-isolated
       (latency outlier) while heartbeat-alive, zero lost requests,
       retry amplification <= 1.5, conformant deadline probes.
@@ -2675,12 +1618,12 @@ def bench_fleet_sim(replicas=1000, n_requests=1_000_000, seed=0):
             fid["retry_amplification"], eps_10k)
 
 
-def bench_fleet_offline_lane(n_requests=1200, replicas=3, seed=13):
+def scenario_offline_lane(n_requests=1200, replicas=3, seed=13):
     """The OFFLINE lane (ROADMAP 6b): the ``offline-lane`` scenario's
     lane-on arm vs the lane-off baseline on the same seed — a diurnal
     interactive envelope whose trough leaves slots idle, plus a
     deadline-less batch backlog submitted through the strict-priority
-    ``batch`` class.  In-bench asserts: fleet utilization STRICTLY
+    ``batch`` class.  Asserts: fleet utilization STRICTLY
     higher with the lane on, interactive p99 held within the PR 7
     epsilon convention (1.5x + a small absolute floor), ZERO requests
     lost in either arm, and the whole batch backlog completes."""
@@ -2710,7 +1653,7 @@ def bench_fleet_offline_lane(n_requests=1200, replicas=3, seed=13):
             on.get("batch_deferrals", 0), n_batch)
 
 
-def bench_http_keepalive(n_requests=200):
+def scenario_http_keepalive(n_requests=200):
     """HTTP ingress connection reuse, before/after: requests/s for
     ``n_requests`` sequential POST /v1/completions over ONE kept-alive
     connection vs a fresh connection per request (the pre-keep-alive
@@ -2873,15 +1816,15 @@ def _gateway_flood(addr, token, n_conns, prompt, max_new_tokens=4,
     return ttfts, completed, n_conns - completed
 
 
-def bench_fleet_gateway_concurrency(n_conns=1100, kill_threads=8,
+def scenario_gateway_concurrency(n_conns=1100, kill_threads=8,
                                     kill_requests=30, workers=32,
                                     seed=11):
-    """Front-door scale bench (ROADMAP item 2 acceptance;
+    """Front-door scale scenario (ROADMAP item 2 acceptance;
     docs/SERVING.md "Front-door scaling").  jax-free — the event-loop
     gateway/registry/router/mux machinery IS the system under test;
     replicas are stub handlers replying streamed canned tokens.
 
-    Two phases, both asserted in-bench:
+    Two phases, both asserted in the scenario:
 
     * CONCURRENCY — ``n_conns`` (>= 1000) simultaneous client
       connections against ONE gateway (one selector thread server-side)
@@ -2934,7 +1877,7 @@ def bench_fleet_gateway_concurrency(n_conns=1100, kill_threads=8,
         # Synchronous streamed replies (no thread per request): a
         # `tokens` partial first — the TTFT marker — then the final
         # completion.  The front door, not replica compute, is what
-        # this bench loads.
+        # this scenario loads.
         def handler(msg, reply):
             mid = msg.get("id")
             if msg.get("stream"):
@@ -3057,15 +2000,15 @@ def bench_fleet_gateway_concurrency(n_conns=1100, kill_threads=8,
         reg.stop()
 
 
-def bench_fleet_gateway_procs(n_procs=4, threads=12, window_s=2.0,
+def scenario_gateway_procs(n_procs=4, threads=12, window_s=2.0,
                               workers=16, seed=13):
-    """Multi-process front door bench (docs/SERVING.md "Multi-process
+    """Multi-process front door scenario (docs/SERVING.md "Multi-process
     gateways").  jax-free — REAL gateway OS processes (``python -m
     tfmesos_tpu.fleet.gateway``, the ``tfserve --gateway-processes N``
     unit) routed over stub replicas; one CPython event loop per
     process, so N processes are the only way past one GIL.
 
-    Phases, all asserted in-bench:
+    Phases, all asserted in the scenario:
 
     * SATURATION — a closed-loop flood from ``threads`` wire clients
       for ``window_s`` against ONE gateway process, then against
@@ -3309,7 +2252,7 @@ def bench_fleet_gateway_procs(n_procs=4, threads=12, window_s=2.0,
         reg.stop()
 
 
-def bench_fleet_trace_overhead(n_requests=240, workers=4, threads=2,
+def scenario_trace_overhead(n_requests=240, workers=4, threads=2,
                                handler_delay_s=0.01, best_of=3):
     """Tracing overhead bound (PR 10 acceptance): the same seeded stub
     workload — jax-free; the gateway/router/tracing machinery IS the
@@ -3417,529 +2360,3 @@ def bench_fleet_trace_overhead(n_requests=240, workers=4, threads=2,
          f"vs summary-only p99 {p99_summary:.2f}ms "
          f"({overhead_pct:+.1f}%)")
     return overhead_pct, p99_summary, p99_detail
-
-
-def bench_bandwidth(sizes=None):
-    """Achieved bandwidth vs roofline.
-
-    Multi-device: psum sweep (1MB-256MB fp32), algorithmic bytes/s =
-    2·(n−1)/n · size / time per all-reduce — the ICI utilization metric
-    BASELINE.md promises.  Single chip: there is no ICI, so report an HBM
-    triad (c = a + b: 3 moved bytes/element) against the HBM roofline.
-    """
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-    kind = _device_kind()
-    n = jax.device_count()
-    if sizes is None:
-        sizes = [1 << 20, 1 << 23, 1 << 26, 1 << 28]  # bytes: 1MB..256MB
-    out = {"allreduce_gbps": None, "hbm_gbps": None,
-           "ici_roofline_gbps": ICI_GBPS.get(kind),
-           "hbm_roofline_gbps": HBM_GBPS.get(kind)}
-
-    if n > 1:
-        mesh = Mesh(np.array(jax.devices()), ("x",))
-        best_gbps = {}
-        for size in sizes:
-            # `size` is the PER-RANK psum payload (the standard algorithmic
-            # bandwidth convention): each of the n rows lives on one device.
-            elems = size // 4
-            x = jnp.ones((n, elems), jnp.float32)
-            x = jax.device_put(x, NamedSharding(mesh, P("x")))
-            reps = 10
-
-            @jax.jit
-            def sweep(x):
-                def body(x, _):
-                    s = jax.shard_map(
-                        lambda v: lax.psum(v, "x"), mesh=mesh,
-                        in_specs=P("x"), out_specs=P("x"))(x)
-                    return s / n, None  # keep magnitude stable, chain deps
-                return lax.scan(body, x, None, length=reps)[0]
-
-            y = sweep(x)
-            jax.block_until_ready(y)
-            t0 = time.perf_counter()
-            y = sweep(x)
-            float(np.asarray(y[0, 0]))
-            dt = (time.perf_counter() - t0) / reps
-            algbw = 2 * (n - 1) / n * size / dt
-            best_gbps[size] = algbw / 1e9
-        out["allreduce_gbps"] = round(max(best_gbps.values()), 2)
-        label = lambda s: f"{s >> 20}MB" if s >= 1 << 20 else f"{s >> 10}KB"
-        out["allreduce_sweep"] = {label(s): round(g, 2)
-                                  for s, g in best_gbps.items()}
-    else:
-        # One visible device: there is no inter-chip link to all-reduce
-        # over — say WHY the field is absent instead of a bare null
-        # (round 5 recorded allreduce_gbps: null with no explanation).
-        out["allreduce_skip_reason"] = (
-            f"single visible device ({kind or 'unknown kind'}): no ICI "
-            f"to measure; hbm_gbps triad recorded instead")
-        size = max(sizes)  # largest requested payload (default 256MB)
-        elems = size // 4
-        a = jnp.ones((elems,), jnp.float32)
-        b = jnp.full((elems,), 2.0, jnp.float32)
-        reps = 20
-
-        @jax.jit
-        def triad(a, b):
-            def body(a, _):
-                return a * 0.5 + b, None
-            return lax.scan(body, a, None, length=reps)[0]
-
-        y = triad(a, b)
-        jax.block_until_ready(y)
-        best = float("inf")
-        for _ in range(3):
-            t0 = time.perf_counter()
-            y = triad(a, b)
-            float(np.asarray(y[0]))
-            best = min(best, (time.perf_counter() - t0) / reps)
-        out["hbm_gbps"] = round(3 * size / best / 1e9, 1)
-    return out
-
-
-def main():
-    import sys
-
-    import jax
-
-    from tfmesos_tpu.utils.platform import enable_compile_cache
-
-    enable_compile_cache()
-    platform = jax.devices()[0].platform
-    if platform == "cpu":
-        raise SystemExit(
-            "bench.py measures the accelerator and found only the CPU "
-            "(JAX_PLATFORMS=%s); no CPU stand-in is recorded under a "
-            "device metric's name" % jax.config.jax_platforms)
-
-    # Each bench runs n times; one that raises fails the run, named.
-    def attempts(fn, label, n=3):
-        try:
-            return [fn() for _ in range(n)]
-        except Exception:
-            print(f"{label} failed:", file=sys.stderr)
-            raise
-
-    runs = attempts(lambda: bench_mnist_replica(steps=800), "bench", n=8)
-    value, final_loss, mlp_mfu = max(runs)
-    peak, kind = _peak_flops()
-    out = {
-        "metric": "mnist_replica_steps_per_sec_per_chip",
-        "value": round(value, 2),
-        "unit": "steps/s/chip",
-        "vs_baseline": round(value / BASELINE_SELF, 3),
-        "backend": jax.default_backend(),
-        "n_chips": jax.device_count(),
-        "device_kind": kind,
-        "peak_bf16_tflops": round(peak / 1e12, 1),
-        "final_loss": round(final_loss, 4),
-        "mfu_mlp": round(mlp_mfu, 5),
-    }
-    # The headline metric is in hand; the remaining probes each pay a heavy
-    # XLA compile.  Flush a parseable partial line after EVERY section so an
-    # external timeout keeps whatever hardware data had landed — the final
-    # full line supersedes them all.
-    def flush_partial():
-        print(json.dumps(dict(out, partial=True)), flush=True)
-
-    flush_partial()
-
-    # One attempt each: compile dominates wall-clock for these, and each
-    # attempt already takes best-of-`iters` timings internally.
-    tr = attempts(bench_transformer_tokens, "transformer bench", n=1)
-    if tr:
-        toks, mfu = max(tr)
-        out["transformer_tokens_per_sec"] = round(toks, 1)
-        out["mfu_transformer"] = round(mfu, 4)
-        flush_partial()
-    dense = attempts(bench_transformer_dense, "dense-mfu bench", n=1)
-    if dense:
-        _, mfu = max(dense)
-        out["mfu_dense"] = round(mfu, 4)
-        flush_partial()
-    dec = attempts(bench_decode, "decode bench", n=1)
-    if dec:
-        out["decode_tokens_per_sec"] = round(max(dec), 1)
-        flush_partial()
-    lat = attempts(lambda: bench_decode(batch=1), "decode latency bench",
-                   n=1)
-    if lat:
-        # Single-stream serving latency: ms per generated token at B=1.
-        out["decode_latency_ms_per_token"] = round(1000.0 / max(lat), 3)
-        flush_partial()
-    dec8 = attempts(lambda: bench_decode(quantized=True),
-                    "int8 decode bench", n=1)
-    if dec8:
-        out["decode_int8_tokens_per_sec"] = round(max(dec8), 1)
-        flush_partial()
-    dec8kv = attempts(
-        lambda: bench_decode(quantized=True, quantized_cache=True,
-                             prompt_len=1024, new_tokens=128),
-        "int8+int8kv decode bench", n=1)
-    if dec8kv:
-        # Long-prompt config: at 1k+ cached positions the cache bytes rival
-        # the weights', which is where the int8 KV cache earns its keep.
-        out["decode_int8_kv_tokens_per_sec"] = round(max(dec8kv), 1)
-        flush_partial()
-    longctx = attempts(bench_decode_long_context, "long-context decode bench",
-                       n=1)
-    if longctx:
-        kern_tok, einsum_tok = longctx[0]
-        out["decode_longctx_tokens_per_sec"] = round(kern_tok, 1)
-        out["decode_longctx_einsum_tokens_per_sec"] = round(einsum_tok, 1)
-        out["decode_longctx_kernel_speedup"] = round(
-            kern_tok / einsum_tok, 3)
-        flush_partial()
-    # Per-side MIN over attempts: round 5 measured the same flash program
-    # at 5.1 and 8.9 ms across identical calls while XLA held 8.6 — one
-    # attempt can land either mode and misreport the ratio by ~2x.
-    attn = attempts(bench_attention, "attention kernel bench", n=2)
-    if attn:
-        flash_ms = min(a[0] for a in attn)
-        xla_ms = min(a[1] for a in attn)
-        out["flash_attn_fwdbwd_ms"] = round(flash_ms, 3)
-        out["xla_attn_fwdbwd_ms"] = round(xla_ms, 3)
-        out["flash_attn_speedup"] = round(xla_ms / flash_ms, 3)
-        flush_partial()
-    tsweep = attempts(bench_attention_tsweep, "attention T sweep", n=1)
-    if tsweep:
-        out["flash_attn_t_sweep"] = tsweep[0]
-        flush_partial()
-    blocks = attempts(bench_attention_blocks, "attention block sweep", n=1)
-    if blocks:
-        # Settles the round-2 block_q question (BASELINE.md:95-99) with a
-        # recorded per-block number instead of an unconfirmed default bump.
-        out["flash_attn_block_sweep_ms"] = blocks[0]
-        flush_partial()
-    sv = attempts(bench_serving_continuous, "continuous serving bench", n=1)
-    if sv:
-        rps, ttft_ms, ms_rps, itl_p50 = sv[0]
-        out["serving_requests_per_sec"] = round(rps, 2)
-        out["serving_mean_ttft_ms"] = round(ttft_ms, 2)
-        out["serving_multistep_requests_per_sec"] = round(ms_rps, 2)
-        out["serving_decode_p50_intertoken_ms"] = round(itl_p50, 3)
-        flush_partial()
-    pc = attempts(bench_decode_paged_call, "paged decode call bench", n=1)
-    if pc:
-        # The paged-decode device floor as first-class keys: per-call
-        # kernel latency (t=1 sync step vs t=8 fused multi-row step)
-        # and the analytic launches per 16-token block per mode (fused
-        # <= 2 asserted in-bench — BASELINE.md's 8-launch floor).
-        call_ms, fused_ms, sync_lpb, fused_lpb = pc[0]
-        out["decode_paged_call_ms"] = round(call_ms, 3)
-        out["decode_paged_fused_call_ms"] = round(fused_ms, 3)
-        out["decode_paged_launches_per_block_sync"] = int(sync_lpb)
-        out["decode_paged_launches_per_block_fused"] = int(fused_lpb)
-        flush_partial()
-    pl = attempts(bench_serving_pipeline, "pipelined serving bench", n=1)
-    if pl:
-        # pipeline_depth=1 vs 0, same workload/process: token-identical
-        # asserted in-bench, pipelined inter-token p50 strictly better.
-        pipe_itl, base_itl, pipe_rps = pl[0]
-        out["serving_pipeline_decode_p50_intertoken_ms"] = round(
-            pipe_itl, 3)
-        out["serving_pipeline_baseline_p50_intertoken_ms"] = round(
-            base_itl, 3)
-        out["serving_pipeline_requests_per_sec"] = round(pipe_rps, 2)
-        out["serving_pipeline_speedup"] = round(base_itl / pipe_itl, 3)
-        flush_partial()
-    fp = attempts(bench_serving_fused_prefill,
-                  "fused prefill serving bench", n=1)
-    if fp:
-        # Fused prefill+decode ticks vs the phase-split chunked tick:
-        # token-identical asserted in-bench, interactive inter-token
-        # p99 under long-prompt interference strictly better fused.
-        fused_p99, split_p99, fused_rps = fp[0]
-        out["serving_fused_itl_p99_ms"] = round(fused_p99, 3)
-        out["serving_fused_split_itl_p99_ms"] = round(split_p99, 3)
-        out["serving_fused_speedup"] = round(split_p99 / fused_p99, 3)
-        out["serving_fused_requests_per_sec"] = round(fused_rps, 2)
-        flush_partial()
-    wu = attempts(bench_serving_warmup, "serving warmup probe", n=1)
-    if wu:
-        # Cold vs AOT-warmed first-request TTFT (warm < cold asserted).
-        warm_ttft, cold_ttft, warm_s = wu[0]
-        out["serving_warm_first_ttft_ms"] = round(warm_ttft, 2)
-        out["serving_cold_first_ttft_ms"] = round(cold_ttft, 2)
-        out["serving_warmup_seconds"] = round(warm_s, 2)
-        flush_partial()
-    psv = attempts(bench_serving_prefix_cache,
-                   "prefix-cache serving bench", n=1)
-    if psv:
-        # Shared-system-prompt workload: warm (prefix cached) vs cold
-        # TTFT, with warm completions asserted equal to cold prefill.
-        warm_ttft, cold_ttft, rps, hit_rate = psv[0]
-        out["serving_prefix_hit_ttft_ms"] = round(warm_ttft, 2)
-        out["serving_prefix_cold_ttft_ms"] = round(cold_ttft, 2)
-        out["serving_prefix_requests_per_sec"] = round(rps, 2)
-        out["serving_prefix_cache_hit_rate"] = round(hit_rate, 3)
-        flush_partial()
-    sc = attempts(bench_serving_spec_compose,
-                  "speculative composition bench", n=1)
-    if sc:
-        # Spec composed with the fast path (the bypass burn-down):
-        # spec+prefix warm TTFT strictly below spec cold (streams
-        # equal), spec inter-token p50 vs the non-spec baseline
-        # (perfect-draft ceiling), and a live spec fleet drain-migrated
-        # mid-stream with ZERO lost requests (asserted in-bench).
-        warm_ttft, cold_ttft, spec_itl, base_itl, accept, resumes = sc[0]
-        out["serving_spec_warm_ttft_ms"] = round(warm_ttft, 2)
-        out["serving_spec_cold_ttft_ms"] = round(cold_ttft, 2)
-        out["serving_spec_prefix_speedup"] = round(
-            cold_ttft / warm_ttft, 3)
-        out["serving_spec_decode_p50_intertoken_ms"] = round(spec_itl, 3)
-        out["serving_spec_baseline_p50_intertoken_ms"] = round(
-            base_itl, 3)
-        out["serving_spec_acceptance_rate"] = round(accept, 3)
-        out["serving_spec_migration_lost_requests"] = 0
-        out["serving_spec_migration_resumes"] = int(resumes)
-        flush_partial()
-    lsv = attempts(bench_serving_longctx, "long-context serving bench",
-                   n=1)
-    if lsv:
-        tok_s, ttft_ms = lsv[0]
-        out["serving_longctx_tokens_per_sec"] = round(tok_s, 1)
-        out["serving_longctx_ttft_ms"] = round(ttft_ms, 2)
-        flush_partial()
-    msv = attempts(bench_serving_continuous_mesh,
-                   "mesh continuous serving bench", n=1)
-    if msv and msv[0] is not None:  # >1 visible device: dp x tp serving
-        out["serving_mesh_requests_per_sec"] = round(msv[0], 2)
-        flush_partial()
-    fl = attempts(bench_fleet_serving, "fleet serving bench", n=1)
-    if fl:
-        # Gateway + 2 local CPU replicas: the online multi-replica path
-        # (fleet subsystem) — tracks fleet overhead, not chip speed.
-        rps, ttft_ms, queue_wait_p50, queue_wait_p99 = fl[0]
-        out["fleet_requests_per_sec"] = round(rps, 2)
-        out["fleet_mean_ttft_ms"] = round(ttft_ms, 2)
-        out["fleet_queue_wait_p50_ms"] = round(queue_wait_p50, 2)
-        out["fleet_queue_wait_p99_ms"] = round(queue_wait_p99, 2)
-        flush_partial()
-    asb = attempts(bench_fleet_autoscale, "fleet autoscale bench", n=1)
-    if asb:
-        # Control-plane reaction: surge start -> new replica routable,
-        # and a blue-green rollout under continuous traffic with ZERO
-        # failed requests asserted in-bench (downtime 0 by contract).
-        reaction_s, downtime_ms = asb[0]
-        out["fleet_scaleup_reaction_s"] = round(reaction_s, 2)
-        out["fleet_rollout_downtime_ms"] = round(downtime_ms, 2)
-        flush_partial()
-    pr = attempts(bench_fleet_priority, "fleet priority bench", n=1)
-    if pr:
-        # SLO isolation: interactive p99 held near its unloaded value
-        # under a background flood (WFQ + preemption, asserted
-        # in-bench), and ZERO lost requests across a migrating
-        # scale-down + rollout (drain-migrate-kill).
-        unloaded_p99, pri_p99, bg_p99, lost = pr[0]
-        out["fleet_priority_p99_ttft_ms"] = round(pri_p99, 2)
-        out["fleet_priority_unloaded_p99_ttft_ms"] = round(
-            unloaded_p99, 2)
-        out["fleet_background_p99_ttft_ms"] = round(bg_p99, 2)
-        out["fleet_migration_lost_requests"] = int(lost)
-        flush_partial()
-    sk = attempts(bench_fleet_soak, "fleet chaos soak", n=1)
-    if sk:
-        # Failure containment under seeded chaos: zero lost requests
-        # and bounded retry amplification through a gray-slow replica
-        # (breaker-isolated while heartbeat-alive), a SIGKILL +
-        # autoscaler self-heal, a link sever, and a rollout — with the
-        # breaker-disabled control arm's p99 degradation recorded next
-        # to the protected p99 (in-bench asserted strictly worse).
-        (lost, amplification, on_p99, control_p99, n_soak,
-         slow_attempt_ms, traces_detailed) = sk[0]
-        out["fleet_soak_lost_requests"] = int(lost)
-        out["fleet_soak_retry_amplification"] = round(amplification, 3)
-        out["fleet_soak_p99_ms"] = round(on_p99, 2)
-        out["fleet_soak_nobreaker_p99_ms"] = round(control_p99, 2)
-        out["fleet_soak_requests"] = int(n_soak)
-        # Tracing attribution (PR 10): the injected gray-failure delay
-        # as seen INSIDE a retained trace's router span toward the
-        # slow replica, plus how many traces kept full detail under
-        # tail-based retention.
-        out["fleet_trace_slow_attempt_ms"] = round(slow_attempt_ms, 2)
-        out["fleet_trace_detailed_retained"] = int(traces_detailed)
-        flush_partial()
-    sm = attempts(bench_fleet_sim, "fleet simulator bench", n=1)
-    if sm:
-        # Virtual-clock fleet simulator: the real control plane driven
-        # at 1000-replica / 1M-request scale in seconds of CPU, plus
-        # the soak-replay fidelity gate (gray-failure isolation, zero
-        # lost, bounded amplification — asserted in-bench).
-        (events_ps, replica_s_ps, wall_s, n_sim, sim_s, fid_amp,
-         eps_10k) = sm[0]
-        out["sim_events_per_sec"] = round(events_ps, 1)
-        out["sim_replicas_per_wallclock_sec"] = round(replica_s_ps, 1)
-        out["fleet_sim_wall_s"] = round(wall_s, 2)
-        out["fleet_sim_requests"] = int(n_sim)
-        out["fleet_sim_virtual_seconds"] = round(sim_s, 1)
-        out["fleet_sim_soak_amplification"] = round(fid_amp, 3)
-        # 10k-replica diurnal replay (sharded heartbeats, day/night
-        # envelope): the hot-path floor held at 10x replica count.
-        out["sim_events_per_sec_10k"] = round(eps_10k, 1)
-        flush_partial()
-    ol = attempts(bench_fleet_offline_lane, "offline lane bench", n=1)
-    if ol:
-        # The offline lane: utilization strictly higher with the batch
-        # lane on, interactive p99 held, zero lost, backlog complete —
-        # all asserted in-bench.
-        on_util, off_util, on_p99, off_p99, deferrals, n_batch = ol[0]
-        out["fleet_offline_utilization"] = round(on_util, 4)
-        out["fleet_offline_baseline_utilization"] = round(off_util, 4)
-        out["fleet_offline_interactive_p99_ms"] = round(on_p99, 2)
-        out["fleet_offline_baseline_interactive_p99_ms"] = round(
-            off_p99, 2)
-        out["fleet_offline_batch_completed"] = int(n_batch)
-        out["fleet_offline_batch_deferrals"] = int(deferrals)
-        out["fleet_offline_lost_requests"] = 0
-        flush_partial()
-    ka = attempts(bench_http_keepalive, "http keep-alive bench", n=1)
-    if ka:
-        # Before/after connection reuse on the HTTP ingress: one
-        # kept-alive connection vs a fresh connect per request.
-        keep_rps, close_rps = ka[0]
-        out["http_keepalive_requests_per_sec"] = round(keep_rps, 1)
-        out["http_per_conn_requests_per_sec"] = round(close_rps, 1)
-        out["http_keepalive_speedup"] = round(keep_rps / close_rps, 3)
-        flush_partial()
-    gc = attempts(bench_fleet_gateway_concurrency,
-                  "gateway concurrency bench", n=1)
-    if gc:
-        # Front-door scale (ROADMAP item 2): >= 1000 concurrent client
-        # connections on ONE event-loop gateway with bounded p99
-        # first-token latency, and a two-gateway kill soak where p99
-        # TTFT holds and zero idempotent requests are lost across the
-        # client failover — all asserted in-bench.
-        conns, flood_p99, pre_p99, post_p99, gw_lost = gc[0]
-        out["fleet_gateway_concurrent_connections"] = int(conns)
-        out["fleet_gateway_flood_p99_ttft_ms"] = round(flood_p99, 2)
-        out["fleet_gateway_prekill_p99_ttft_ms"] = round(pre_p99, 2)
-        out["fleet_gateway_kill_p99_ttft_ms"] = round(post_p99, 2)
-        out["fleet_gateway_lost_requests"] = int(gw_lost)
-        flush_partial()
-    gp = attempts(bench_fleet_gateway_procs,
-                  "multi-process gateway bench", n=1)
-    if gp:
-        # Multi-process front door: N real gateway OS processes behind
-        # one SO_REUSEPORT door (or per-process discovery ports) must
-        # strictly out-serve one process at saturation, and a mid-run
-        # SIGKILL of one process loses zero idempotent requests
-        # (failover replay across a process death) — asserted in-bench.
-        (rps1, rpsn, p99_1, pre99, post99, pl, mode) = gp[0]
-        out["fleet_gateway_procs_rps_1"] = round(rps1, 1)
-        out["fleet_gateway_procs_rps_n"] = round(rpsn, 1)
-        out["fleet_gateway_procs_p99_ttft_ms"] = round(p99_1, 2)
-        out["fleet_gateway_procs_prekill_p99_ttft_ms"] = round(pre99, 2)
-        out["fleet_gateway_procs_kill_p99_ttft_ms"] = round(post99, 2)
-        out["fleet_gateway_procs_lost_requests"] = int(pl)
-        out["fleet_gateway_procs_mode"] = mode
-        flush_partial()
-    tro = attempts(bench_fleet_trace_overhead, "trace overhead bench",
-                   n=1)
-    if tro:
-        # Tracing overhead bound: full-detail-on-every-request p99 vs
-        # summary-only p99 on the same seeded stub workload (asserted
-        # within 5% + 1ms in-bench).
-        overhead_pct, p99_sum, p99_det = tro[0]
-        out["fleet_trace_overhead_pct"] = round(overhead_pct, 2)
-        out["fleet_trace_summary_p99_ms"] = round(p99_sum, 3)
-        out["fleet_trace_detail_p99_ms"] = round(p99_det, 3)
-        flush_partial()
-    dg = attempts(bench_fleet_disagg, "disaggregated fleet bench", n=1)
-    if dg:
-        # Mixed long-prompt/long-decode workload: dedicated prefill +
-        # decode tiers (KV pages exported over raw wire frames) vs a
-        # same-size unified fleet; decode inter-token p50 is asserted
-        # strictly better disaggregated (no prefill-induced stalls).
-        dis_ttft, dis_itl, uni_ttft, uni_itl, kv_mb_s = dg[0]
-        out["serving_disagg_ttft_ms"] = round(dis_ttft, 2)
-        out["serving_disagg_decode_p50_intertoken_ms"] = round(dis_itl, 3)
-        out["serving_unified_mixed_ttft_ms"] = round(uni_ttft, 2)
-        out["serving_unified_mixed_decode_p50_intertoken_ms"] = round(
-            uni_itl, 3)
-        out["fleet_kv_transfer_mb_per_sec"] = round(kv_mb_s, 2)
-        flush_partial()
-    fa = attempts(bench_fleet_prefix_affinity,
-                  "fleet prefix-affinity bench", n=1)
-    if fa:
-        # Shared prefixes steered to the replica already caching them.
-        hit_rate, rps = fa[0]
-        out["fleet_prefix_affinity_hit_rate"] = round(hit_rate, 3)
-        out["fleet_prefix_requests_per_sec"] = round(rps, 2)
-        flush_partial()
-    ks = attempts(bench_fleet_sessions, "fleet KV-tier sessions bench",
-                  n=1)
-    if ks:
-        # Multi-turn session resume-from-tier vs cold full-history
-        # prefill (streams asserted token-identical in-bench), plus
-        # the shared prefix as a FLEET resource (prefilled once,
-        # router-directed).
-        resumed, cold, hit_rate, prefills, aff = ks[0]
-        out["fleet_session_resume_ttft_ms"] = round(resumed, 2)
-        out["fleet_session_cold_ttft_ms"] = round(cold, 2)
-        out["fleet_session_speedup"] = round(cold / max(1e-9, resumed), 3)
-        out["fleet_kv_tier_hit_rate"] = round(hit_rate, 3)
-        out["fleet_shared_prefix_prefills"] = prefills
-        out["fleet_shared_prefix_affinity_hit_rate"] = round(aff, 3)
-        flush_partial()
-    fb = attempts(bench_fleet_fabric, "fleet KV fabric bench", n=1)
-    if fb:
-        # Cross-host KV fabric: direct replica-to-replica artifact
-        # streaming vs the router-relay fallback on the same workload
-        # (strictly faster asserted in-bench), and a kv_replication=2
-        # fleet riding out a parker SIGKILL with zero lost sessions.
-        # The direct rate is the headline transfer number — it
-        # supersedes the disagg-derived sample above with a dedicated
-        # same-workload measurement.
-        direct_mb_s, relay_mb_s, resumed, fetch_hits = fb[0]
-        out["fleet_kv_transfer_mb_per_sec"] = round(direct_mb_s, 2)
-        out["fleet_kv_relay_mb_per_sec"] = round(relay_mb_s, 2)
-        out["fleet_fabric_resumed_sessions"] = int(resumed)
-        out["fleet_fabric_lost_sessions"] = 0
-        out["fleet_fabric_forwarded_fetch_hits"] = int(fetch_hits)
-        flush_partial()
-    mm = attempts(bench_fleet_multimodel, "fleet multi-model bench",
-                  n=1)
-    if mm:
-        # Model catalog: cross-model trading under a fixed budget,
-        # warm-pool cold start vs cold relaunch, adapter hot-swap
-        # under traffic, per-tenant x model metering — all asserted
-        # in-bench.
-        out.update(mm[0])
-        flush_partial()
-    gg = attempts(bench_fleet_gang, "fleet gang replica bench", n=1)
-    if gg:
-        # One model sharded across a gang of member tasks, served as
-        # ONE replica: streams asserted token-identical to a
-        # single-process fleet, zero lost requests across a mid-decode
-        # gang-member SIGKILL and across a gang drain-migration.
-        gang_itl, single_itl, reform_s = gg[0]
-        out["fleet_gang_itl_p50_ms"] = round(gang_itl, 3)
-        out["fleet_single_itl_p50_ms"] = round(single_itl, 3)
-        out["fleet_gang_reform_s"] = round(reform_s, 2)
-        flush_partial()
-    rw = attempts(bench_ring_window, "ring window bench", n=1)
-    if rw and rw[0] is not None:    # >1 visible device: sp ring
-        flash_ms, xla_ms = rw[0]
-        out["ring_window_flash_ms"] = round(flash_ms, 3)
-        out["ring_window_einsum_ms"] = round(xla_ms, 3)
-        out["ring_window_flash_speedup"] = round(xla_ms / flash_ms, 3)
-        flush_partial()
-    pb = attempts(pipeline_bubble_stats, "pipeline schedule stats", n=1)
-    if pb:
-        out.update(pb[0])
-        flush_partial()
-    bw = attempts(bench_bandwidth, "bandwidth bench", n=1)
-    if bw:
-        out.update(bw[0])
-    print(json.dumps(out), flush=True)
-
-
-if __name__ == "__main__":
-    main()

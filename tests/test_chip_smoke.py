@@ -77,7 +77,7 @@ def test_no_other_cache_directory_is_named_anywhere():
     files = subprocess.run(["git", "ls-files"], cwd=REPO, text=True,
                            capture_output=True).stdout.split()
     old = ("tpumesos-jax" + "-test-cache", "TPUMESOS_TEST" + "_CACHE")
-    for path in files or ["tests/conftest.py", "bench.py"]:
+    for path in files or ["tests/conftest.py", "chip_smoke.py"]:
         if path == "ISSUE.md" or not os.path.isfile(os.path.join(REPO, path)):
             continue
         with open(os.path.join(REPO, path), errors="replace") as f:

@@ -445,13 +445,13 @@ def test_gateway_deadline_exceeded_end_to_end(stub_fleet):
         gw.stop()
 
 
-# -- the short seeded soak smoke (the tier-1 slice of bench_fleet_soak) -----
+# -- the short seeded soak smoke (the tier-1 slice of scenario_soak) -----
 
 
 def test_stub_fleet_soak_smoke(stub_fleet):
     """A compressed stub-scale soak: continuous traffic through a
     3-replica fleet with one gray-slow member and one mid-soak death.
-    Asserts the bench_fleet_soak invariants at unit cost: zero lost
+    Asserts the scenario_soak invariants at unit cost: zero lost
     requests, the slow replica breaker-isolated while heartbeat-alive,
     and bounded retry amplification."""
     token, reg, servers = stub_fleet

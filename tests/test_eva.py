@@ -301,8 +301,7 @@ def test_what_eva_pages_cannot_do_is_refused_with_a_registered_reason(
         b.validate(Prefilled(req, art))
     with pytest.raises(ValueError, match=reason):
         b.submit(req, prefilled=art)
-    for kw in ({"prefix": np.arange(5, dtype=np.int32)},
-               {"prefill_chunk": 8}, {"quantized_cache": True}):
+    for kw in ({"prefill_chunk": 8}, {"quantized_cache": True}):
         with pytest.raises(ValueError, match="attention='eva'"):
             batcher(cfg, params, **kw)
     with pytest.raises(ValueError, match="multiple of page_size"):

@@ -6,7 +6,7 @@ rendezvous + gang gauges, the scheduler's atomic gang placement and
 per-member env stamping, and the per-replica rid seeding that closes
 the PR 4 cross-exporter rid-collision caveat.  The full-process gang
 e2e (2-member gang behind the gateway, token identity, member SIGKILL,
-drain migration) is the slow-marked bench smoke in test_bench.py."""
+drain migration) is the slow-marked smoke in test_fleet_scenarios.py."""
 
 import threading
 import time

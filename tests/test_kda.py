@@ -581,8 +581,7 @@ def test_batcher_ring_carries_the_state_and_expert_fields(served):
 def test_batcher_refuses_and_bypasses_what_a_row_state_closes(served):
     cfg, params, _ = served
     kw = dict(rows=2, max_len=128, page_size=16, prefill_bucket=16)
-    for bad, match in ((dict(prefix=np.arange(4, dtype=np.int32)), "prefix"),
-                       (dict(prefill_chunk=16), "prefill_chunk"),
+    for bad, match in ((dict(prefill_chunk=16), "prefill_chunk"),
                        (dict(quantized_cache=True), "quantized_cache"),
                        (dict(draft_cfg=cfg, draft_params=params),
                         "speculative")):

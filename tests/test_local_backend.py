@@ -207,7 +207,7 @@ def test_cross_process_continuous_batching():
     assert rs[0]["tokens"].keys() == want.keys()
     for rid, req in enumerate(reqs):
         _assert_tokens_match_modulo_ties(
-            cfg, params, None, req.prompt, rs[0]["tokens"][str(rid)],
+            cfg, params, req.prompt, rs[0]["tokens"][str(rid)],
             want[str(rid)])
 
 

@@ -31,6 +31,13 @@ def _prompts(cfg, n, seed=0):
             for _ in range(n)]
 
 
+def _behind(prefix, prompt):
+    """``prompt`` behind a shared system prompt (``prefix`` None: none).
+    The batcher declares nothing: with ``prefix_cache_pages`` the
+    cross-request prefix cache finds the shared pages at admission."""
+    return prompt if prefix is None else np.concatenate([prefix, prompt])
+
+
 def _offline(cfg, params, req: Request):
     """Reference continuation: a per-request generate() call (contiguous
     cache, greedy)."""
@@ -136,8 +143,8 @@ def test_sampled_streams_invariant_to_batching(setup):
     assert outs[0] == outs[1]
 
 
-def _assert_tokens_match_modulo_ties(cfg, params, prefix, prompt, got,
-                                     want, atol=1e-4):
+def _assert_tokens_match_modulo_ties(cfg, params, prompt, got, want,
+                                     atol=1e-4):
     """Greedy sequences from the chunked vs unchunked prefill paths are
     expected identical, EXCEPT where the two reduction orders land on a
     float tie: at the first divergence, teacher-force the agreed prefix
@@ -151,10 +158,8 @@ def _assert_tokens_match_modulo_ties(cfg, params, prefix, prompt, got,
     n = min(len(got), len(want))
     div = next(i for i in range(n) if got[i] != want[i])
     assert got[:div] == want[:div]
-    ctx = np.concatenate([
-        *( [np.asarray(prefix, np.int32)] if prefix is not None else [] ),
-        np.asarray(prompt, np.int32),
-        np.asarray(want[:div], np.int32)])
+    ctx = np.concatenate([np.asarray(prompt, np.int32),
+                          np.asarray(want[:div], np.int32)])
     logits = np.asarray(
         transformer.forward(cfg, params, jnp.asarray(ctx[None]))[0, -1],
         np.float32)
@@ -173,18 +178,20 @@ def test_chunked_prefill_matches_unchunked(setup, with_prefix):
     rng = np.random.RandomState(29)
     prefix = (rng.randint(0, cfg.vocab_size, size=11).astype(np.int32)
               if with_prefix else None)
-    prompts = [rng.randint(0, cfg.vocab_size, size=n).astype(np.int32)
-               for n in (3, 8, 13, 19, 16, 5)]
+    prompts = [_behind(prefix, rng.randint(
+        0, cfg.vocab_size, size=n).astype(np.int32))
+        for n in (3, 8, 13, 19, 16, 5)]
     mk = lambda: [Request(prompt=p, max_new_tokens=2 + (i % 4))
                   for i, p in enumerate(prompts)]
-    kw = dict(rows=3, max_len=96, page_size=16, prefix=prefix)
+    kw = dict(rows=3, max_len=96, page_size=16,
+              prefix_cache_pages=8 if with_prefix else 0)
     chunked = ContinuousBatcher(cfg, params, prefill_chunk=8, **kw)
     plain = ContinuousBatcher(cfg, params, prefill_bucket=8, **kw)
     got = {c.rid: c.tokens for c in chunked.run(mk())}
     want = {c.rid: c.tokens for c in plain.run(mk())}
     for rid in want:
         _assert_tokens_match_modulo_ties(
-            cfg, params, prefix, prompts[rid], got[rid], want[rid])
+            cfg, params, prompts[rid], got[rid], want[rid])
     assert chunked.alloc.rows == {}     # everything recycled
 
 
@@ -235,7 +242,7 @@ def test_speculative_batcher_matches_plain(setup, draft_setup,
     got = {c.rid: c.tokens for c in spec.run(reqs())}
     for rid in want:
         _assert_tokens_match_modulo_ties(
-            cfg, params, None, reqs()[rid].prompt, got[rid], want[rid])
+            cfg, params, reqs()[rid].prompt, got[rid], want[rid])
     assert spec.alloc.rows == {}
     rate = spec.acceptance_rate
     assert rate is not None and 0.0 <= rate <= 1.0
@@ -295,18 +302,20 @@ def test_speculative_batcher_stop_token(setup, draft_setup):
 @pytest.mark.parametrize("prefix_len", [16, 13, 21])
 def test_speculative_batcher_with_shared_prefix(setup, draft_setup,
                                                 prefix_len):
-    """prefix x speculative: the draft carries the broadcast prefix in
-    its cache, the target its shared pages — outputs still equal the
-    (prefix-sharing) target-only batcher's.  Covers aligned, tail-only,
-    and full+tail prefix page layouts (page_size 16)."""
+    """shared system prompt x speculative: the prefix cache maps the
+    prompt's pages into both pools (target and draft twins) — outputs
+    still equal the (prefix-sharing) target-only batcher's.  Covers
+    aligned, sub-page, and full+tail prefix page layouts (page_size
+    16)."""
     cfg, params = setup
     dcfg, dparams = draft_setup
     prefix = np.random.RandomState(43).randint(
         0, cfg.vocab_size, size=prefix_len).astype(np.int32)
-    reqs = lambda: [Request(prompt=p, max_new_tokens=3 + (i % 4))
+    reqs = lambda: [Request(prompt=_behind(prefix, p),
+                            max_new_tokens=3 + (i % 4))
                     for i, p in enumerate(_prompts(cfg, 5, seed=44))]
     kw = dict(rows=2, max_len=96, page_size=16, prefill_bucket=16,
-              prefix=prefix)
+              prefix_cache_pages=8)
     plain = ContinuousBatcher(cfg, params, **kw)
     want = {c.rid: c.tokens for c in plain.run(reqs())}
     spec = ContinuousBatcher(cfg, params, draft_cfg=dcfg,
@@ -314,7 +323,7 @@ def test_speculative_batcher_with_shared_prefix(setup, draft_setup,
     got = {c.rid: c.tokens for c in spec.run(reqs())}
     for rid in want:
         _assert_tokens_match_modulo_ties(
-            cfg, params, prefix, reqs()[rid].prompt, got[rid], want[rid])
+            cfg, params, reqs()[rid].prompt, got[rid], want[rid])
 
 
 def test_speculative_batcher_sampled_invariance_and_prefix_equality(
@@ -380,11 +389,13 @@ def test_speculative_with_chunked_prefill(setup, draft_setup,
     rng = np.random.RandomState(53)
     prefix = (rng.randint(0, cfg.vocab_size, size=11).astype(np.int32)
               if with_prefix else None)
-    prompts = [rng.randint(0, cfg.vocab_size, size=n).astype(np.int32)
-               for n in (3, 13, 19, 8, 16)]
+    prompts = [_behind(prefix, rng.randint(
+        0, cfg.vocab_size, size=n).astype(np.int32))
+        for n in (3, 13, 19, 8, 16)]
     mk = lambda: [Request(prompt=p, max_new_tokens=3 + (i % 4))
                   for i, p in enumerate(prompts)]
-    kw = dict(rows=3, max_len=96, page_size=16, prefix=prefix)
+    kw = dict(rows=3, max_len=96, page_size=16,
+              prefix_cache_pages=8 if with_prefix else 0)
     plain = ContinuousBatcher(cfg, params, prefill_bucket=8, **kw)
     want = {c.rid: c.tokens for c in plain.run(mk())}
     combo = ContinuousBatcher(cfg, params, prefill_chunk=8,
@@ -393,34 +404,37 @@ def test_speculative_with_chunked_prefill(setup, draft_setup,
     got = {c.rid: c.tokens for c in combo.run(mk())}
     for rid in want:
         _assert_tokens_match_modulo_ties(
-            cfg, params, prefix, prompts[rid], got[rid], want[rid])
+            cfg, params, prompts[rid], got[rid], want[rid])
     assert combo.alloc.rows == {}
 
 
 def test_speculative_draft_pool_tracks_live_tokens(setup, draft_setup):
     """The draft's K/V is paged like the target's: occupancy is bounded
     by in-flight rows' worst case, everything recycles at stream end,
-    and a shared prefix holds reserved draft pages instead of a per-row
-    broadcast copy."""
+    and what the prefix cache keeps of a shared system prompt it keeps
+    once in each pool (a node's target page and its draft twin) instead
+    of a per-row copy."""
     cfg, params = setup
     dcfg, dparams = draft_setup
     prefix = np.random.RandomState(71).randint(
         0, cfg.vocab_size, size=13).astype(np.int32)
-    reqs = [Request(prompt=p, max_new_tokens=4)
+    reqs = [Request(prompt=_behind(prefix, p), max_new_tokens=4)
             for p in _prompts(cfg, 6, seed=72)]
+    budget = 4
     b = ContinuousBatcher(cfg, params, rows=2, max_len=96, page_size=16,
-                          prefill_bucket=16, prefix=prefix,
+                          prefill_bucket=16, prefix_cache_pages=budget,
                           draft_cfg=dcfg, draft_params=dparams, n_draft=3)
     done = list(b.run(reqs))
     assert len(done) == len(reqs)
+    cached = b.prefix_cache_stats()["cached_pages"]
+    assert 0 < cached <= budget
     for side in (b.t_side, b.d_side):
-        # All own pages recycled; sink + prefix reservations persist.
-        n_reserved = -(-13 // 16)
+        # All own pages recycled; the sink and the cached pages persist.
         assert side.alloc.rows == {}
-        assert side.alloc.free_count() == side.n_pages - 1 - n_reserved
+        assert side.alloc.free_count() == side.n_pages - 1 - cached
         # High-water mark stayed within 2 concurrent worst cases.
-        per_row_worst = -(-(96 - 0) // 16)      # tail page is own (COW)
-        assert side.peak <= 2 * per_row_worst + 1 + n_reserved
+        per_row_worst = -(-96 // 16)
+        assert side.peak <= 2 * per_row_worst + 1 + budget
 
 
 def test_speculative_batcher_validation(setup, draft_setup):
@@ -475,32 +489,34 @@ def test_mesh_batcher_token_identical(mesh_setup, axes, variant):
     rng = np.random.RandomState(7)
     prompts = [rng.randint(0, 128, size=n).astype(np.int32)
                for n in (3, 8, 13, 19, 16, 5)]
-    mk = lambda: [Request(prompt=p, max_new_tokens=2 + (i % 4))
-                  for i, p in enumerate(prompts)]
     kw = dict(rows=4, max_len=96, page_size=16, prefill_bucket=16)
     if variant == "spec_chunk_prefix":
-        kw.update(prefix=rng.randint(0, 128, size=13).astype(np.int32),
+        prefix = rng.randint(0, 128, size=13).astype(np.int32)
+        prompts = [_behind(prefix, p) for p in prompts]
+        kw.update(prefix_cache_pages=8,
                   prefill_chunk=8, draft_cfg=dcfg, draft_params=dparams,
                   n_draft=3)
     elif variant == "sampled":
         kw.update(temperature=0.8, top_k=20, rng=jax.random.PRNGKey(3))
     elif variant == "int8":
         kw.update(quantized_cache=True)
+    mk = lambda: [Request(prompt=p, max_new_tokens=2 + (i % 4))
+                  for i, p in enumerate(prompts)]
     plain = ContinuousBatcher(cfg, params, **kw)
     want = {c.rid: c.tokens for c in plain.run(mk())}
     b = ContinuousBatcher(cfg, params, mesh=_mesh(axes), **kw)
     got = {c.rid: c.tokens for c in b.run(mk())}
     for rid in want:
         _assert_tokens_match_modulo_ties(
-            cfg, params, kw.get("prefix"), prompts[rid], got[rid],
-            want[rid])
-    # Per-shard invariants: every sub-pool recycled to sink+prefix.
+            cfg, params, prompts[rid], got[rid], want[rid])
+    # Per-shard invariants: every sub-pool recycled to its sink and what
+    # the prefix cache keeps resident.
     for side in filter(None, (b.t_side, b.d_side)):
         assert side.alloc.rows == {}
-        n_res = (1 + -(-13 // 16)) if "prefix" in kw else 1
         for s in range(b.n_shards):
-            assert side.alloc.free_count(s) == \
-                side.n_pages // b.n_shards - n_res
+            kept = b._pcache.reclaimable(s) if b._pcache else 0
+            assert side.alloc.free_count(s) + kept == \
+                side.n_pages // b.n_shards - 1
 
 
 # -- pipelined device-resident decode (pipeline_depth=1) --------------------
@@ -521,8 +537,8 @@ def test_pipelined_batcher_token_identical(setup, variant):
     fail the rid-checked ticket; sampled (rid, step) key folds are
     unchanged; chunked prefill flips and mid-stream re-admissions
     re-enter through the host-merge mask; the int8 pool pair compares
-    int8-to-int8; a static shared ``prefix`` offsets every carried
-    position."""
+    int8-to-int8; a shared system prompt's warm admissions enter the
+    carry from the prefix cache's tail prefill."""
     cfg, params = setup
     rng = np.random.RandomState(71)
     prompts = [rng.randint(0, cfg.vocab_size, size=n).astype(np.int32)
@@ -539,8 +555,9 @@ def test_pipelined_batcher_token_identical(setup, variant):
     elif variant == "int8":
         kw.update(quantized_cache=True)
     elif variant == "prefix":
-        kw.update(prefix=rng.randint(0, cfg.vocab_size,
-                                     size=13).astype(np.int32))
+        prefix = rng.randint(0, cfg.vocab_size, size=13).astype(np.int32)
+        prompts = [_behind(prefix, p) for p in prompts]
+        kw.update(prefix_cache_pages=8)
     if variant in ("stop", "multistep_stop"):
         # Find a token each prompt actually emits so stops trigger (and
         # land mid-block in the multistep case).
@@ -1074,55 +1091,77 @@ def test_typed_prng_key_accepted(setup):
 
 @pytest.mark.parametrize("prefix_len", [16, 11, 21])
 def test_shared_prefix_matches_generate(setup, prefix_len):
-    """Prefix page sharing (page_size 16: aligned, sub-page, and
-    full+tail cases): rows reference the shared prefix pages read-only,
-    and greedy outputs are token-identical to generate(prefix=...)."""
+    """A shared system prompt behind the prefix cache (page_size 16:
+    aligned, sub-page, and full+tail cases): rows reference the cached
+    prompt pages read-only — a partial last page is each row's own, its
+    tail prefilled at admission — and greedy outputs are
+    token-identical to generate(prefix=...)."""
     cfg, params = setup
     rng = np.random.RandomState(17)
     prefix = rng.randint(0, cfg.vocab_size, size=prefix_len).astype(np.int32)
-    reqs = [Request(prompt=p, max_new_tokens=3 + (i % 4))
-            for i, p in enumerate(_prompts(cfg, 6, seed=18))]
+    prompts = _prompts(cfg, 6, seed=18)
+    reqs = [Request(prompt=_behind(prefix, p), max_new_tokens=3 + (i % 4))
+            for i, p in enumerate(prompts)]
     batcher = ContinuousBatcher(cfg, params, rows=2, max_len=96,
                                 page_size=16, prefill_bucket=16,
-                                prefix=prefix)
+                                prefix_cache_pages=8)
     done = {c.rid: c for c in batcher.run(reqs)}
     assert len(done) == len(reqs)
     for rid, req in enumerate(reqs):
         out = transformer.generate(
-            cfg, params, jnp.asarray(req.prompt[None]),
+            cfg, params, jnp.asarray(prompts[rid][None]),
             req.max_new_tokens, temperature=0.0,
             prefix=jnp.asarray(prefix))
-        want = np.asarray(out)[0, prefix_len + req.prompt.size:].tolist()
+        want = np.asarray(out)[0, req.prompt.size:].tolist()
         assert done[rid].tokens == want, f"request {rid} diverged"
-    # Shared pages survive the whole stream; own pages all recycled
-    # (pool keeps sink + reserved prefix pages out of circulation).
-    n_reserved = -(-prefix_len // 16)
-    assert batcher.alloc.free_count() == batcher.n_pages - 1 - n_reserved
+    st = batcher.prefix_cache_stats()
+    if prefix_len >= 16:
+        # the prompt's full pages were prefilled once and then shared
+        assert st["hits"] >= len(reqs) - 2
+    # Cached pages survive the whole stream; own pages all recycled
+    # (pool keeps sink + cached pages out of circulation).
+    assert batcher.alloc.free_count() == \
+        batcher.n_pages - 1 - st["cached_pages"]
     assert batcher.alloc.rows == {}
 
 
-def test_shared_prefix_validation(setup):
+def test_static_prefix_argument_is_gone(setup):
+    """The batcher-level static ``prefix=`` went where the prefix cache
+    already was: Python's own TypeError, no shim."""
     cfg, params = setup
-    with pytest.raises(ValueError, match="non-empty"):
+    with pytest.raises(TypeError, match="prefix"):
         ContinuousBatcher(cfg, params, rows=1, max_len=64, page_size=16,
-                          prefix=np.zeros((0,), np.int32))
-    with pytest.raises(ValueError, match="no room"):
-        ContinuousBatcher(cfg, params, rows=1, max_len=32, page_size=16,
-                          prefix=np.zeros((32,), np.int32))
-    b = ContinuousBatcher(cfg, params, rows=1, max_len=48, page_size=16,
-                          prefill_bucket=16,
                           prefix=np.zeros((16,), np.int32))
-    too_long = Request(prompt=np.arange(20, dtype=np.int32),
-                       max_new_tokens=30)
-    with pytest.raises(ValueError, match="prefix 16"):
-        list(b.run([too_long]))
+
+
+def test_import_refuses_artifact_with_static_prefix(setup):
+    """The wire format keeps ``prefix_len`` / ``shared_len`` (an export
+    writes both as 0, version 1 as before), and an artifact cut behind
+    a static prefix — every position of it offset — is refused loudly,
+    never decoded against."""
+    from tfmesos_tpu.serving import Prefilled
+
+    cfg, params = setup
+    b = ContinuousBatcher(cfg, params, rows=1, max_len=64, page_size=16,
+                          prefill_bucket=16)
+    req = Request(prompt=_prompts(cfg, 1, seed=19)[0], max_new_tokens=4)
+    art = b.export_kv(req)
+    assert art["version"] == 1
+    assert art["prefix_len"] == 0 and art["shared_len"] == 0
+    b.validate(Prefilled(req, art))             # the real one imports
+    for key in ("prefix_len", "shared_len"):
+        with pytest.raises(ValueError,
+                           match=f"KV artifact {key} 16 does not match "
+                                 f"this batcher's 0"):
+            b.validate(Prefilled(req, dict(art, **{key: 16})))
 
 
 def test_tpu_shaped_serving_geometry(setup):
     """The serving-quality matrix at TPU-SHAPED geometry (VERDICT r4 weak
     #6): page_size=64, max_len=2048 (32 pages/row), bf16, long prompts —
-    prefix sharing + chunked prefill + speculative TOGETHER, where the
-    index-map arithmetic (block clamps, COW tail pages, verify-chunk
+    a shared system prompt behind the prefix cache + chunked prefill +
+    speculative TOGETHER, where the
+    index-map arithmetic (block clamps, shared pages, verify-chunk
     overshoot) actually bites.  CPU, so correctness not speed; outputs
     must match the plain (unchunked, non-speculative) paged batcher's
     modulo bf16 float-tie argmax forks, and both pools must recycle."""
@@ -1135,12 +1174,12 @@ def test_tpu_shaped_serving_geometry(setup):
         max_seq_len=2304, dtype=jnp.bfloat16)
     dparams = transformer.init_params(dcfg, jax.random.PRNGKey(6))
     rng = np.random.RandomState(83)
-    prefix = rng.randint(0, 128, size=100).astype(np.int32)  # COW tail
-    prompts = [rng.randint(0, 128, size=n).astype(np.int32)
+    prefix = rng.randint(0, 128, size=100).astype(np.int32)  # page + tail
+    prompts = [_behind(prefix, rng.randint(0, 128, size=n).astype(np.int32))
                for n in (700, 1150, 330)]
     mk = lambda: [Request(prompt=p, max_new_tokens=4 + i)
                   for i, p in enumerate(prompts)]
-    kw = dict(rows=2, max_len=2048, page_size=64, prefix=prefix)
+    kw = dict(rows=2, max_len=2048, page_size=64, prefix_cache_pages=32)
     plain = ContinuousBatcher(cfg, params, prefill_bucket=64, **kw)
     want = {c.rid: c.tokens for c in plain.run(mk())}
     combo = ContinuousBatcher(cfg, params, prefill_chunk=64,
@@ -1152,12 +1191,14 @@ def test_tpu_shaped_serving_geometry(setup):
         assert len(got[rid]) == len(want[rid])
         # bf16 logit spacing is coarse: allow forks only at near-ties.
         _assert_tokens_match_modulo_ties(
-            cfg, params, prefix, prompts[rid], got[rid], want[rid],
+            cfg, params, prompts[rid], got[rid], want[rid],
             atol=0.15)
+    cached = combo.prefix_cache_stats()["cached_pages"]
+    assert combo.prefix_cache_stats()["hits"] >= 1
     for side in (combo.t_side, combo.d_side):
-        n_res = 1 + -(-100 // 64)               # sink + 2 prefix pages
         assert side.alloc.rows == {}
-        assert side.alloc.free_count() == side.n_pages - n_res
+        # the sink and the cached pages (twins in the draft pool) stay
+        assert side.alloc.free_count() == side.n_pages - 1 - cached
         assert side.peak <= side.n_pages        # never oversubscribed
 
 
@@ -1221,8 +1262,6 @@ def test_multistep_batcher_token_identical(setup, mesh_setup, variant, k):
     rng = np.random.RandomState(31)
     prompts = [rng.randint(0, cfg.vocab_size, size=n).astype(np.int32)
                for n in (3, 8, 13, 19, 16, 5)]
-    mk = lambda: [Request(prompt=p, max_new_tokens=2 + (i % 5))
-                  for i, p in enumerate(prompts)]
     kw = dict(rows=4, max_len=96, page_size=16, prefill_bucket=16)
     mkw = {}
     if variant == "sampled":
@@ -1230,10 +1269,13 @@ def test_multistep_batcher_token_identical(setup, mesh_setup, variant, k):
     elif variant == "chunked":
         kw.update(prefill_chunk=8)
     elif variant == "prefix":
-        kw.update(prefix=rng.randint(0, cfg.vocab_size,
-                                     size=13).astype(np.int32))
+        prefix = rng.randint(0, cfg.vocab_size, size=13).astype(np.int32)
+        prompts = [_behind(prefix, p) for p in prompts]
+        kw.update(prefix_cache_pages=8)
     elif variant in ("mesh", "pipelined_mesh"):
         mkw.update(mesh=_mesh({"dp": 2, "tp": 2}))
+    mk = lambda: [Request(prompt=p, max_new_tokens=2 + (i % 5))
+                  for i, p in enumerate(prompts)]
     if variant.startswith("pipelined"):
         mkw.update(pipeline_depth=1)
     if variant in ("stop", "pipelined_stop"):
@@ -1263,21 +1305,20 @@ def test_multistep_batcher_token_identical(setup, mesh_setup, variant, k):
     if variant in ("mesh", "pipelined_mesh"):
         for rid in want:
             _assert_tokens_match_modulo_ties(
-                cfg, params, kw.get("prefix"), prompts[rid], got[rid],
-                want[rid])
+                cfg, params, prompts[rid], got[rid], want[rid])
     else:
         assert got == want
     assert mb._inflight is None             # loop drained
     assert mb.t_side.alloc.rows == {}       # nothing leaked
     # Reservation invariant held throughout: the pool high-water mark
-    # never exceeded sink + prefix + (concurrent rows x the largest
-    # admission reservation) — if a multi-step block ever ensured past
-    # its _Row.limit clamp, a row's allocations would exceed its
-    # reservation and the high-water mark would break this bound.
+    # never exceeded sink + what the prefix cache may keep + (concurrent
+    # rows x the largest admission reservation) — if a multi-step block
+    # ever ensured past its _Row.limit clamp, a row's allocations would
+    # exceed its reservation and the high-water mark would break this
+    # bound.
     worst = max(mb._worst_pages(q)[0] for q in mk())
-    n_prefix = len(mb.t_side.shared_pages) + (
-        1 if mb.t_side.tail_template is not None else 0)
-    assert mb.peak_pages_used <= 1 + n_prefix + kw["rows"] * worst
+    n_kept = kw.get("prefix_cache_pages", 0)
+    assert mb.peak_pages_used <= 1 + n_kept + kw["rows"] * worst
 
 
 def test_multistep_validation(setup, draft_setup):
@@ -1642,24 +1683,24 @@ def test_prefix_cache_with_pipelined_and_multistep(setup):
 
 @pytest.mark.parametrize("prefix_len", [16, 11])
 def test_prefix_cache_composes_with_global_prefix(setup, prefix_len):
-    """The static batcher-level ``prefix`` and the dynamic prefix cache
-    stack: cacheable chunks start AFTER the prefix's full pages, the
-    chain is seeded with its partial tail, and outputs still equal the
-    cache-off batcher's."""
+    """A deployment-wide prompt ahead of a shared system prompt: the
+    prefix cache shares the pages of both, whole pages or not (a
+    sub-page global prompt shifts every later page boundary), and
+    outputs still equal the cache-off batcher's."""
     cfg, params = setup
     rng = np.random.RandomState(17)
     prefix = rng.randint(0, cfg.vocab_size,
                          size=prefix_len).astype(np.int32)
-    kw = dict(rows=2, max_len=96, page_size=16, prefill_bucket=16,
-              prefix=prefix)
+    kw = dict(rows=2, max_len=96, page_size=16, prefill_bucket=16)
+    mk = lambda: [Request(prompt=_behind(prefix, r.prompt),
+                          max_new_tokens=r.max_new_tokens)
+                  for r in _shared_prefix_reqs(cfg, 5, sys_len=30)]
     cold = ContinuousBatcher(cfg, params, **kw)
     warm = ContinuousBatcher(cfg, params, prefix_cache_pages=8, **kw)
-    want = _tokens_in_order(cold, _shared_prefix_reqs(cfg, 5, sys_len=30))
-    assert _tokens_in_order(warm,
-                            _shared_prefix_reqs(cfg, 5, sys_len=30)) == want
+    want = _tokens_in_order(cold, mk())
+    assert _tokens_in_order(warm, mk()) == want
     assert warm.prefix_cache_stats()["hits"] >= 4
-    assert _tokens_in_order(warm,
-                            _shared_prefix_reqs(cfg, 5, sys_len=30)) == want
+    assert _tokens_in_order(warm, mk()) == want
 
 
 def test_prefix_cache_refcounts_protect_inflight_pages(setup):
@@ -1709,7 +1750,7 @@ def test_paged_side_tables_dirty_after_cow_remap():
     from tfmesos_tpu.serving import _PagedSide, _PrefixCache, _Row
 
     side = _PagedSide(n_pages=8, page_size=4, rows=2, np_max=4)
-    pc = _PrefixCache(side, page_size=4, first=4, seed=b"", budget=8)
+    pc = _PrefixCache(side, page_size=4, budget=8)
     digs = prompt_digests(np.arange(8, dtype=np.int32), 4)
     # Row 0 prefills two full pages and publishes them.
     side.ensure(0, 8)
@@ -1764,7 +1805,7 @@ def test_prefix_cache_with_mesh(mesh_setup, axes):
     got = _tokens_in_order(warm, reqs())
     for i, (g, w) in enumerate(zip(got, want)):
         _assert_tokens_match_modulo_ties(
-            cfg, params, None, reqs()[i].prompt, g, w)
+            cfg, params, reqs()[i].prompt, g, w)
     st = warm.prefix_cache_stats()
     assert st["hits"] >= 4, st
     # Shard-affine admission: the system prompt's pages live on ONE
@@ -2862,8 +2903,6 @@ def test_fused_tick_token_identical(setup, variant):
     # short ones decode, so fused ticks genuinely mix both lanes.
     prompts = [rng.randint(0, cfg.vocab_size, size=n).astype(np.int32)
                for n in (3, 21, 13, 34, 16, 5)]
-    mk = lambda: [Request(prompt=p.copy(), max_new_tokens=2 + (i % 5))
-                  for i, p in enumerate(prompts)]
     kw = dict(rows=4, max_len=96, page_size=16, prefill_bucket=16,
               prefill_chunk=8)
     fkw = {}
@@ -2872,9 +2911,9 @@ def test_fused_tick_token_identical(setup, variant):
     elif variant == "int8":
         kw.update(quantized_cache=True)
     elif variant == "pcache":
-        kw.update(prefix_cache_pages=16,
-                  prefix=rng.randint(0, cfg.vocab_size,
-                                     size=13).astype(np.int32))
+        prefix = rng.randint(0, cfg.vocab_size, size=13).astype(np.int32)
+        prompts = [_behind(prefix, p) for p in prompts]
+        kw.update(prefix_cache_pages=16)
     elif variant == "multistep":
         kw.update(multi_step=4)
     elif variant == "budget":
@@ -2882,6 +2921,8 @@ def test_fused_tick_token_identical(setup, variant):
         fkw.update(tokens_per_tick=4 + 8)
     elif variant == "spec":
         kw.update(**_spec_kw(max_len=96))
+    mk = lambda: [Request(prompt=p.copy(), max_new_tokens=2 + (i % 5))
+                  for i, p in enumerate(prompts)]
     plain = ContinuousBatcher(cfg, params, **kw)
     want = {c.rid: c.tokens for c in plain.run(mk())}
     fb = ContinuousBatcher(cfg, params, fused_prefill=True, **kw, **fkw)

@@ -1,6 +1,6 @@
 """Trace-driven fleet simulator (tfmesos_tpu/fleet/sim.py + workload.py):
 jax-free.  The centerpiece is the FIDELITY GATE — the ``soak-replay``
-scenario replays bench_fleet_soak's seeded chaos timeline (gray-slow
+scenario replays scenario_soak's seeded chaos timeline (gray-slow
 replica, SIGKILL + autoscaler self-heal, link sever, blue-green
 rollout) through the REAL admission/router/containment/registry code on
 the virtual clock and must reproduce the soak's qualitative outcomes
@@ -293,7 +293,7 @@ def test_replay_workload_drives_sim(sleep_trap):
 
 
 def test_soak_replay_fidelity_gate(sleep_trap):
-    """bench_fleet_soak's seeded chaos timeline through the real
+    """scenario_soak's seeded chaos timeline through the real
     control plane on the virtual clock: the simulator must reproduce
     the soak's qualitative outcomes, with zero real sleeping."""
     out = run_scenario("soak-replay", seed=20)

@@ -402,7 +402,7 @@ def test_the_ring_closes_every_surface_with_one_reason():
 
 
 @pytest.mark.parametrize("what,kw", [
-    ("a mesh", dict(mesh="mesh")), ("a shared prefix", dict(prefix=[1, 2])),
+    ("a mesh", dict(mesh="mesh")),
     ("prefill_chunk", dict(prefill_chunk=8)),
     ("quantized_cache", dict(quantized_cache=True)),
     ("speculative", dict(draft_cfg="cfg", draft_params={})),

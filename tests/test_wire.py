@@ -568,7 +568,7 @@ def test_wire_server_wake_listener_unblocks_selector_on_stop():
 def test_wire_server_connection_flood():
     """The point of the event loop: hundreds of concurrent client
     connections on ONE serve thread, every request answered.  (The
-    full-scale 1000+ figure is bench_fleet_gateway_concurrency's.)"""
+    full-scale 1000+ figure is scenario_gateway_concurrency's.)"""
     token = wire.new_token()
     srv = _echo_server(token)
     socks = []

@@ -1075,8 +1075,7 @@ def gateways_main(argv: List[str]) -> int:
 
 def build_metrics_parser() -> argparse.ArgumentParser:
     """``tfserve metrics`` — fetch the gateway snapshot and
-    pretty-print it (until now the JSON snapshot was only reachable
-    from bench code)."""
+    pretty-print it."""
     p = argparse.ArgumentParser(
         prog="tfserve metrics",
         description="Fetch a running fleet gateway's metrics snapshot "

@@ -2,8 +2,8 @@
 
 One ``FleetMetrics`` instance is shared by the gateway, router, and
 admission controller; everything it exports is a plain-JSON
-``snapshot()`` (served over the wire by the gateway's ``metrics`` op and
-recorded by ``bench.py`` as the ``fleet_*`` metrics) plus an optional
+``snapshot()`` (served over the wire by the gateway's ``metrics`` op)
+plus an optional
 periodic one-line log report.  No external metrics dependency — the
 control plane stays stdlib-only, like the rest of the framework.
 
@@ -17,8 +17,7 @@ relayed from the router/replicas, a subset of ``failed``).
 
 Prefix-affinity routing adds ``affinity_hits``/``affinity_misses``: one
 of the two per routing decision over a prompt-bearing request —
-``hits / (hits + misses)`` is the fleet's prefix-affinity hit rate
-(``fleet_prefix_affinity_hit_rate`` in bench.py).
+``hits / (hits + misses)`` is the fleet's prefix-affinity hit rate.
 """
 
 from __future__ import annotations

@@ -989,7 +989,7 @@ def tiny_model(seed: int = 0):
 
 
 def flagship_model(seed: int = 0, max_len: int = 1024):
-    """The flagship serving config (bench.py's 34M d512 transformer)."""
+    """The flagship serving config (a 34M d512 transformer)."""
     import jax
     import jax.numpy as jnp
 

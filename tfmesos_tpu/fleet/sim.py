@@ -45,7 +45,8 @@ Scenarios (``SCENARIOS``) package fleet + workload + timeline;
 breaker.latency_factor=2,4,8`` runs one per value for policy tuning
 (:func:`apply_override` addresses every promoted policy constant by
 path).  The ``soak-replay`` scenario is the FIDELITY GATE: it replays
-the seeded chaos timeline of ``bench_fleet_soak`` (gray-slow replica,
+the seeded chaos timeline of ``scenario_soak``
+(tests/fleet_scenarios.py: gray-slow replica,
 hard kill + autoscaler self-heal, link sever, blue-green rollout) and
 must reproduce its qualitative outcomes — breaker isolation of the
 slow replica while heartbeat-alive, zero lost requests, retry
@@ -1530,7 +1531,7 @@ class FleetSim:
             self.dispatch(item)
 
     def kill_gateway(self, idx: int) -> int:
-        """Hard-kill one front door mid-traffic (the bench's gateway
+        """Hard-kill one front door mid-traffic (the gateway scenarios'
         SIGKILL analog): its dispatch pool stops, and every item still
         QUEUED there is re-admitted on a surviving front — the
         client-failover replay (idempotent requests, nothing was
@@ -1601,7 +1602,7 @@ class FleetSim:
                      stop: Optional[Callable[[], bool]] = None) -> None:
         """Closed-loop feeder fiber over a request LIST: submit one,
         then serve one WFQ-dispatched item (its own or a peer's — net
-        flow conserved, WFQ order preserved), like the soak bench's
+        flow conserved, WFQ order preserved), like the soak scenario's
         client threads."""
         reqs = list(reqs)
         self.planned += len(reqs)
@@ -1818,7 +1819,7 @@ def scenario_soak_replay(overrides=(), n_per_feeder: int = 120,
                          workload=None, model_fit: Optional[dict] = None,
                          cfg: Optional[SimConfig] = None
                          ) -> Dict[str, Any]:
-    """THE FIDELITY GATE: the seeded ``bench_fleet_soak`` chaos
+    """THE FIDELITY GATE: the seeded ``scenario_soak`` chaos
     timeline replayed through the real control plane on the virtual
     clock — a gray-slow replica under two-class deadline-carrying
     traffic, short-deadline probes, a hard kill + real-autoscaler
@@ -1842,9 +1843,9 @@ def scenario_soak_replay(overrides=(), n_per_feeder: int = 120,
             if hasattr(cfg.model, k):
                 setattr(cfg.model, k, v)
     # The soak's shape at sim scale: ~10ms services, a 25x-gray victim
-    # (the bench's 0.25s slow_task against CPU-replica ~10ms decodes),
-    # liveness clocks as shipped so the kill is detected by heartbeat
-    # loss exactly like the bench.
+    # (the live scenario's 0.25s slow_task against CPU-replica ~10ms
+    # decodes), liveness clocks as shipped so the kill is detected by
+    # heartbeat loss exactly like the live scenario.
     cfg.model = dataclasses.replace(cfg.model, jitter=cfg.model.jitter
                                     or 0.05)
     sim = FleetSim(cfg)
@@ -1919,7 +1920,7 @@ def scenario_soak_replay(overrides=(), n_per_feeder: int = 120,
     # Phase B — hard churn: SIGKILL a healthy replica whole, then
     # hand-stepped REAL-autoscaler ticks with calm signals relaunch it
     # (crash self-heal through the warming state) — the exact shape of
-    # the bench's phase B.
+    # the live scenario's phase B.
     doomed = next(r for r in reps if r is not victim and not r.down)
     sim.kill(doomed)
     calm = {"queue_wait_p99_ms": 0.0, "util": 0.5, "kv_headroom": None}
@@ -2009,7 +2010,7 @@ def scenario_scale(overrides=(), n_requests: int = 1_000_000,
                    workload=None, model_fit: Optional[dict] = None,
                    cfg: Optional[SimConfig] = None) -> Dict[str, Any]:
     """The scale proof: 1000 replicas, >= 1M requests, open Poisson
-    arrivals — the ``bench_fleet_sim`` scenario (no deadlines, two
+    arrivals — what ``scenario_sim`` runs (no deadlines, two
     classes, breakers on).  Exists to keep ``sim_events_per_sec``
     honest; shrink ``n_requests``/``replicas`` for smoke runs."""
     cfg = _new_cfg(cfg, overrides)
@@ -2063,7 +2064,7 @@ def scenario_diurnal(overrides=(), n_requests: int = 1_000_000,
     not 10k timer pops per sim-second.  Byte-for-byte deterministic
     per seed; gateway counts, trader constants and admission bounds
     all sweepable.  Publishes ``sim_events_per_sec_10k`` — the
-    10x-replica hot-path floor benched next to ``sim_events_per_sec``
+    10x-replica hot-path floor read next to ``sim_events_per_sec``
     (the scale scenario's 45k events/s contract)."""
     cfg = _new_cfg(cfg, overrides)
     cfg.replicas = int(replicas) if replicas is not None else 10_000
@@ -2155,7 +2156,8 @@ def scenario_offline_lane(overrides=(), n_requests: int = 3000,
     interactive-vs-batch budget split: ``--sweep batch_slot_frac=
     0.25,0.5,0.75,1.0`` prices reserve headroom against harvested
     utilization, and ``--sweep batch_lane=false,true`` is the
-    lane-off baseline the bench asserts against (utilization strictly
+    lane-off baseline ``scenario_offline_lane`` asserts against
+    (utilization strictly
     higher with the lane on, interactive p99 held, zero interactive
     requests lost)."""
     cfg = _new_cfg(cfg, overrides)

@@ -36,8 +36,7 @@ active trace) — attribute themselves without plumbing.
 
 Exposure: the gateway's authenticated ``trace`` op (``tfserve trace``
 prints :func:`format_waterfall`), ``FleetMetrics.prometheus_text()``
-behind ``tfserve --metrics-port``, and the ``fleet_trace_*`` bench
-keys.  Everything here is stdlib-only and jax-free.
+behind ``tfserve --metrics-port``.  Everything here is stdlib-only and jax-free.
 """
 
 from __future__ import annotations

@@ -25,14 +25,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = float("-inf")
 
-#: Paged-decode launch accounting (bench_decode_paged_call and the
-#: launches-per-block assertions): Python-level call counts, bumped once
+#: Paged-decode launch accounting: Python-level call counts, bumped once
 #: per ``flash_decode_paged`` invocation.  Under ``jit`` a call site
 #: counts once per TRACE (a ``lax.scan`` body traces once however many
-#: steps it runs), so measure eager/microbench call sequences — the
-#: serving-level launches-per-block number comes from
-#: ``ContinuousBatcher.paged_launches_per_block`` instead, which knows
-#: the dispatch structure.
+#: steps it runs), so read it around eager call sequences.
 PAGED_CALL_STATS = {"calls": 0, "kernel_calls": 0}
 
 #: Per-core VMEM bytes the paged kernel's K + V blocks may claim, both

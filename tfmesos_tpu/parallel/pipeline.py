@@ -74,9 +74,8 @@ def _schedule_1f1b(n_stages: int, m: int, v: int = 1):
     peak stash S microbatch inputs.  At v>1 every microbatch laps the
     ring v times (chunk c feeds chunk c+1, always one device to the
     right), shrinking the FILL/DRAIN bubble for v x more ppermute hops
-    — worth ~1.2x wall at bubble-bound shapes (deep pipe, few
-    microbatches; bench.pipeline_bubble_stats measures this timetable
-    statically), and ~nothing once m >> pp amortizes the fill.
+    — worth wall time at bubble-bound shapes (deep pipe, few
+    microbatches), and ~nothing once m >> pp amortizes the fill.
     """
     import numpy as np
 

@@ -20,7 +20,7 @@ Trade-offs vs ring attention (both exact):
   keeps everything at O(T/sp).  For sequences that fit, Ulysses wins on
   collective volume; for extreme lengths the ring is the memory-safe pick.
 * A2a rides ICI as one fused collective; the ring pipelines hops behind
-  compute.  Measure on the target topology (``bench.py``); model code
+  compute.  Measure on the target topology; model code
   flips with ``TransformerConfig(sp_impl="ulysses")``.
 """
 

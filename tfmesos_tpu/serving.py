@@ -628,8 +628,8 @@ class _ShardedAlloc:
     pool, and every page id handed out is LOCAL to its shard.  With
     ``n_shards=1`` this is exactly one PageAllocator.  Reservations
     (``reserve_page``) are taken symmetrically in every shard and must
-    land on the same local id — so a single id names the sink or a
-    shared-prefix page in every shard's sub-pool."""
+    land on the same local id — so a single id names the sink in every
+    shard's sub-pool."""
 
     def __init__(self, n_pages_per_shard: int, page_size: int,
                  n_shards: int = 1, rows_per_shard: int = 0):
@@ -682,8 +682,8 @@ class _ShardedAlloc:
 
 class _PagedSide:
     """Host-side state of ONE paged pool — the target's, or (speculative
-    mode) the draft's: the per-shard allocator, reserved sink/prefix
-    pages, the device pool, and the cached page tables the jitted steps
+    mode) the draft's: the per-shard allocator, the reserved sink
+    page, the device pool, and the cached page tables the jitted steps
     consume.  Table entries are LOCAL page ids (see
     :class:`_ShardedAlloc`); a row with no allocation is all-sink."""
 
@@ -706,13 +706,10 @@ class _PagedSide:
         # request owns.  Reserve one pool page (per shard) as that sink.
         self.sink = self.alloc.reserve_page()
         self.pool = None                  # device arrays, set by owner
-        self.shared_pages: List[int] = []  # full prefix pages, read-only
-        self.shared_len = 0                # positions they cover
-        self.tail_template: Optional[int] = None  # partial-page template
         self.peak = 0                      # observability: high-water mark
         # Cross-request prefix cache (set by the owning batcher): pages
-        # a row references READ-ONLY between the global shared prefix
-        # and its own allocation (row table = [shared | cached | own]).
+        # a row references READ-ONLY ahead of its own allocation (row
+        # table = [cached | own]).
         self.pcache = None                        # _PrefixCache or None
         self.row_cached: Dict[int, List[int]] = {}
         self._cache = None        # device table; rebuilt when dirty
@@ -729,13 +726,11 @@ class _PagedSide:
         self._cache = self._cache_np = self._masked = None
 
     def ensure(self, row: int, length: int) -> None:
-        """Back ABSOLUTE positions [0, length): the shared prefix pages
-        cover [0, shared_len), mapped cached-prefix pages the next
-        ``len(row_cached[row]) * page_size``; the row's own allocation
-        covers the rest."""
+        """Back ABSOLUTE positions [0, length): mapped cached-prefix
+        pages cover the first ``len(row_cached[row]) * page_size``; the
+        row's own allocation covers the rest."""
         before = self.alloc.allocated(row)
-        covered = self.shared_len + self.page_size * len(
-            self.row_cached.get(row, ()))
+        covered = self.page_size * len(self.row_cached.get(row, ()))
         self.alloc.ensure(row, max(0, length - covered))
         if self.alloc.allocated(row) != before:
             self.dirty()
@@ -773,24 +768,19 @@ class _PagedSide:
         """Host master copy of the table (chunked prefill masks per-step
         variants off it)."""
         if self._cache_np is None:
-            # Rows WITH allocations see [shared prefix pages |
-            # cached-prefix pages | own pages]; rows without stay
-            # all-sink (an inactive row writes its garbage step at
-            # position 0 — that must never land on a shared or live
-            # page).
+            # Rows WITH allocations see [cached-prefix pages | own
+            # pages]; rows without stay all-sink (an inactive row writes
+            # its garbage step at position 0 — that must never land on
+            # a shared or live page).
             t = np.full((self.rows, self.np_max), self.sink, np.int32)
-            ns = len(self.shared_pages)
             rows_map = self.alloc.rows
             for r in range(self.rows):
                 own = rows_map.get(r) or []
                 cached = self.row_cached.get(r) or []
-                if own or cached:
-                    if ns:
-                        t[r, :ns] = self.shared_pages
-                    nc = len(cached)
-                    if nc:
-                        t[r, ns:ns + nc] = cached
-                    t[r, ns + nc:ns + nc + len(own)] = own
+                nc = len(cached)
+                if nc:
+                    t[r, :nc] = cached
+                t[r, nc:nc + len(own)] = own
             self._cache_np = t
         return self._cache_np
 
@@ -804,7 +794,7 @@ class _PagedSide:
 
     def bucket_width(self) -> int:
         """Smallest power-of-two table width covering every allocated
-        row (shared prefix pages + own pages), capped at ``np_max``.
+        row (cached-prefix pages + own pages), capped at ``np_max``.
         The table's width sizes what every decode dispatch carries: the
         paged kernel's scalar-prefetched tables and the XLA ops that
         build them, and the gather path (no TPU, or pages the kernel
@@ -822,9 +812,8 @@ class _PagedSide:
         out-of-reservation write (quota-finished mid-block) hits a
         column past its own pages — sink — never its last live page
         (at the np_max cap the pre-bucketing invariant already held)."""
-        ns = len(self.shared_pages)
         rows_map = self.alloc.rows
-        occ = max((ns + len(self.row_cached.get(r, ()))
+        occ = max((len(self.row_cached.get(r, ()))
                    + len(rows_map.get(r, ()))
                    for r in set(rows_map) | set(self.row_cached)
                    if rows_map.get(r) or self.row_cached.get(r)),
@@ -920,14 +909,11 @@ class _PrefixCache:
     thread, so every public method takes the lock.
     """
 
-    def __init__(self, side: _PagedSide, page_size: int, first: int,
-                 seed: bytes, budget: int, n_shards: int = 1,
-                 dside: Optional[_PagedSide] = None):
+    def __init__(self, side: _PagedSide, page_size: int, budget: int,
+                 n_shards: int = 1, dside: Optional[_PagedSide] = None):
         self.side = side
         self.dside = dside
         self.page_size = int(page_size)
-        self.first = int(first)     # width of chunk 0 (page - prefix tail)
-        self.seed = seed            # chain seed (constant prefix tail)
         self.budget = int(budget)   # max cached pages PER SHARD
         self.n_shards = int(n_shards)
         self.roots: List[Dict[bytes, _PrefixNode]] = [
@@ -1001,7 +987,7 @@ class _PrefixCache:
     def acquire(self, row: int, nodes: List[_PrefixNode]) -> None:
         """Map ``nodes``' pages read-only into ``row``'s table
         (refcount++ each) — the row's table becomes
-        [shared | these pages | own] — on BOTH pools in twin mode."""
+        [these pages | own] — on BOTH pools in twin mode."""
         with self._lock:
             self._tick += 1
             for n in nodes:
@@ -1248,11 +1234,13 @@ class _PrefixCache:
                      for n in self._walk(s)]
             nodes.sort(key=lambda n: n.last, reverse=True)
             # ``stats`` rides along for fleet-wide accounting (the
-            # shared-prefix bench sums misses across replicas to assert
-            # a common prompt prefilled once per FLEET); the router's
-            # matcher only reads the geometry + hashes.
-            return {"page": self.page_size, "first": self.first,
-                    "seed": self.seed.hex(),
+            # sessions scenario sums misses across replicas to assert a
+            # common prompt prefilled once per FLEET); the router's
+            # matcher only reads the geometry + hashes.  ``first`` and
+            # ``seed`` are prefixhash's chunk-0 width and chain seed:
+            # every chunk is one page and the chain starts empty.
+            return {"page": self.page_size, "first": self.page_size,
+                    "seed": "",
                     "hashes": [n.digest.hex()
                                for n in nodes[:max_entries]],
                     "stats": dict(self._stats)}
@@ -1287,7 +1275,7 @@ def _install_pages(pool, payload, ids):
 def _copy_page(pool, src, dst):
     """Copy pool page ``src`` into page ``dst`` on every layer and leaf
     (K and V; int8 QTensors copy values and scales alike) — the
-    copy-on-write step behind partially-shared prefix tail pages."""
+    copy-on-write step behind a page-aligned full prefix-cache hit."""
     return jax.tree_util.tree_map(
         lambda buf: buf.at[:, dst].set(buf[:, src]), pool)
 
@@ -1416,15 +1404,13 @@ class ContinuousBatcher:
     ``draft_cfg``/``draft_params`` (optional) turn on SPECULATIVE
     decoding inside the batcher: every tick, the draft proposes
     ``n_draft`` tokens per row (batched t=1 steps over its OWN paged
-    pool — draft HBM tracks live tokens exactly like the target's, and
-    a shared prefix occupies shared draft pages once instead of a
-    per-row broadcast; ``draft_n_pages`` sizes it, default fully
-    backed; ``draft_quantized_cache=True`` stores it int8 like the
-    target's ``quantized_cache``) and the target verifies them in ONE
-    ragged chunk over the
-    paged pool — rows commit their leading accepted run plus the
-    target's correction, so each tick emits 1..n_draft+1 tokens per row
-    instead of exactly 1.  Greedy outputs equal the target-only
+    pool — draft HBM tracks live tokens exactly like the target's;
+    ``draft_n_pages`` sizes it, default fully backed;
+    ``draft_quantized_cache=True`` stores it int8 like the target's
+    ``quantized_cache``) and the target verifies them in ONE ragged
+    chunk over the paged pool — rows commit their leading accepted run
+    plus the target's correction, so each tick emits 1..n_draft+1
+    tokens per row instead of exactly 1.  Greedy outputs equal the target-only
     batcher's (modulo float-tie argmax forks); with ``temperature > 0``
     the round is Leviathan-style rejection sampling (accept with
     min(1, pt/pd), corrections from norm(max(0, pt − pd))) whose draws
@@ -1432,9 +1418,8 @@ class ContinuousBatcher:
     speculative streams stay invariant to row packing, and committed
     tokens are distributed exactly as target-only sampling.  Composes
     with stop tokens, staggered admission, int8 target pools, and
-    shared prefixes (the draft prefills the prefix once and broadcasts
-    it to every row of its cache), and chunked prefill (the draft's
-    chunks advance in lockstep with the target's).
+    chunked prefill (the draft's chunks advance in lockstep with the
+    target's).
 
     ``prefill_chunk`` (optional) turns on CHUNKED PREFILL: instead of
     prefilling a whole prompt in one call (stalling every decoding row
@@ -1462,8 +1447,8 @@ class ContinuousBatcher:
     — the exact mid-block-stop discard semantics ``_step`` documents —
     so token streams are IDENTICAL to ``pipeline_depth=0`` (greedy AND
     sampled: the (rid, step) key folds are unchanged).  Composes with
-    ``multi_step``, chunked prefill, int8 pools, ``mesh``, ``prefix``,
-    and the prefix cache; speculative decoding BYPASSES explicitly
+    ``multi_step``, chunked prefill, int8 pools, ``mesh``, and the
+    prefix cache; speculative decoding BYPASSES explicitly
     (``pipeline_bypass_reason``: the carry has no speculative form, a
     speculative batcher serves synchronously).  ``0`` preserves the
     synchronous loop exactly.  ``None`` leaves the choice to the
@@ -1505,16 +1490,6 @@ class ContinuousBatcher:
     ``rows`` must divide over the data axes, tp must divide both
     models' head counts.
 
-    ``prefix`` (1-D int32, optional) is a SHARED prompt prefix (system
-    prompt), prefilled ONCE into reserved pool pages that every row's
-    page table references read-only — the paged analogue of
-    ``generate(prefix=...)``, at zero per-row HBM for the shared part.
-    A partial last page (prefix length not a page multiple) is COPIED
-    into each admitted row's first own page so per-row writes never
-    touch shared pages.  ``max_len`` still bounds the TOTAL sequence
-    (prefix + prompt + new tokens); request positions and outputs are
-    unchanged — the prefix is invisible except in attention.
-
     ``prefix_cache_pages`` (> 0 enables; the value caps resident cached
     pages per mesh data shard) turns on the CROSS-REQUEST PREFIX CACHE:
     full page-aligned prompt chunks are published into a per-shard hash
@@ -1525,14 +1500,14 @@ class ContinuousBatcher:
     copy-on-write before the one-token logits rewrite; finished
     requests leave zero-ref pages RESIDENT, reclaimed LRU-first only
     under allocation pressure (admission headroom counts them as free,
-    so the cache can never deadlock admission).  Unlike the static
-    ``prefix`` above, nothing needs declaring up front — any shared
-    system/few-shot prompt is discovered at admission.  Greedy warm
+    so the cache can never deadlock admission).  Nothing needs
+    declaring up front — any shared system/few-shot prompt is
+    discovered at admission.  Greedy warm
     completions match cold-prefill completions exactly up to float-tie
     argmax flips (the tail prefill runs cache-attention, like chunked
     prefill; bit-identical in practice on the CPU test config).
     Composes with ``prefill_chunk``, ``pipeline_depth``,
-    ``multi_step``, ``mesh``, ``prefix``, and SPECULATIVE decoding — a
+    ``multi_step``, ``mesh``, and SPECULATIVE decoding — a
     spec batcher's trie couples every target page with its draft-pool
     twin (one refcount, COW on both deepest pages, twin publish after
     prefill),
@@ -1566,7 +1541,7 @@ class ContinuousBatcher:
                  n_pages: Optional[int] = None, prefill_bucket: int = 64,
                  temperature: float = 0.0, top_k: Optional[int] = None,
                  top_p: Optional[float] = None, rng=None,
-                 quantized_cache: bool = False, prefix=None,
+                 quantized_cache: bool = False,
                  prefill_chunk: Optional[int] = None,
                  draft_cfg: Optional[TransformerConfig] = None,
                  draft_params=None, n_draft: int = 4,
@@ -1682,7 +1657,6 @@ class ContinuousBatcher:
                     f"speculative decoding is refused with typed layers: "
                     f"{self._bypass['speculative'] or 'one program per stack'}")
             for what, given in (("a mesh", mesh is not None),
-                                ("a shared prefix", prefix is not None),
                                 ("prefill_chunk", prefill_chunk is not None),
                                 ("quantized_cache", quantized_cache)):
                 if given:
@@ -1698,7 +1672,6 @@ class ContinuousBatcher:
                     f"speculative decoding is refused under "
                     f"attention='eva': {self._bypass['speculative']}")
             for what, given in (("a mesh", mesh is not None),
-                                ("a shared prefix", prefix is not None),
                                 ("prefill_chunk", prefill_chunk is not None),
                                 ("quantized_cache", quantized_cache)):
                 if given:
@@ -1728,23 +1701,10 @@ class ContinuousBatcher:
         # can hold (one per position, but for EVA: cfg.cache_entries).
         self.np_max = -(-cfg.cache_entries_peak(0, self.max_len)
                         // self.page_size)
-        # Default pool: every row's worst case (max_len minus whatever a
-        # shared prefix covers read-only) + the prefix's reserved pages +
-        # one inactive-row write sink — so the default always fully backs
-        # rows x max_len of live data, prefix or not.
-        prefix_np = None if prefix is None else np.asarray(prefix, np.int32)
-        n_prefix_pages = (0 if prefix_np is None
-                          else -(-int(prefix_np.size) // self.page_size))
-        shared_full = (0 if prefix_np is None else
-                       (int(prefix_np.size) // self.page_size)
-                       * self.page_size)
-        own_max = (self.np_max if eva else
-                   -(-(self.max_len - shared_full) // self.page_size))
         # Default pool: per data shard, its row block's worst case plus
-        # the shard's own prefix + sink reservations (reservations are
-        # PER SHARD — every sub-pool carries the prefix and a sink).
-        per_shard = ((rows // self.n_shards) * own_max
-                     + n_prefix_pages + 1)
+        # the shard's own inactive-row write sink — so the default
+        # always fully backs rows x max_len of live data.
+        per_shard = (rows // self.n_shards) * self.np_max + 1
         self.n_pages = int(n_pages or self.n_shards * per_shard)
         if prefill_chunk is not None:
             if prefill_chunk < 1 or prefill_chunk % 8:
@@ -1802,7 +1762,6 @@ class ContinuousBatcher:
             self.params = self._place(params, partition_specs(cfg, mesh))
         self._init_side_device_state(self.t_side, cfg,
                                      quantized=quantized_cache)
-        self.prefix_len = 0
         self._prefill_fns: Dict[int, Any] = {}
         self._decode = self._make_decode()
         # EVA: a window is closed by a program of its own, enqueued
@@ -1950,8 +1909,6 @@ class ContinuousBatcher:
         self._decode_walls: deque = deque(maxlen=STALL_WINDOW)
         self._stall_logged = 0.0
         self._tick = self._tick_open(None)
-        if prefix_np is not None:
-            self._init_prefix(prefix_np)
         # Cross-request prefix cache (prefix_cache_pages > 0 enables;
         # the value caps resident cached pages PER SHARD — per POOL in
         # speculative mode, where every trie node couples a target page
@@ -1967,13 +1924,9 @@ class ContinuousBatcher:
             self.prefix_cache_bypass_reason = \
                 self._bypass["prefix_cache"]
             if self.prefix_cache_bypass_reason is None:
-                off = self.prefix_len - self.t_side.shared_len
-                seed = (b"" if not off else _ph.chunk_digest(
-                    b"", prefix_np[self.t_side.shared_len:]))
                 self._pcache = _PrefixCache(
-                    self.t_side, self.page_size, self.page_size - off,
-                    seed, prefix_cache_pages, n_shards=self.n_shards,
-                    dside=self.d_side)
+                    self.t_side, self.page_size, prefix_cache_pages,
+                    n_shards=self.n_shards, dside=self.d_side)
                 self._tail_prefill = (self._chunk_prefill
                                       or self._make_chunk_prefill())
         # Tiered KV store (fleet/kvtier.py; docs/SERVING.md "KV tiering
@@ -1998,8 +1951,7 @@ class ContinuousBatcher:
                     self._pcache.on_evict = self._spill_page
                     kv_tier.prefix_geometry = {
                         "page": self.page_size,
-                        "first": self._pcache.first,
-                        "seed": self._pcache.seed.hex()}
+                        "first": self.page_size, "seed": ""}
 
     # -- the tick recorder ------------------------------------------------
 
@@ -2200,31 +2152,15 @@ class ContinuousBatcher:
         enumerates exactly when rows can be snapshotted."""
         return self.suspend_bypass_reason is None
 
-    def paged_launches_per_block(self, block_tokens: int = 16) -> int:
-        """Paged-attention kernel launches PER LAYER needed to retire
-        ``block_tokens`` decode tokens of one row under this batcher's
-        mode — the device-floor metric bench_decode_paged_call tracks
-        (BASELINE.md's "8 launches x ~0.54 ms" block cost).  Analytic
-        rather than counter-sampled because jit traces the kernel call
-        once per compiled step regardless of how many times the XLA
-        loop replays it.  Synchronous decode pays one launch per token;
-        a speculative round retires up to n_draft+1 tokens through ONE
-        fused (t=n_draft+1) verify launch, so 16-token blocks need
-        ceil(16 / (n_draft+1)) launches — <= 2 at n_draft >= 7."""
-        if self.draft_cfg is not None:
-            return -(-int(block_tokens) // (self.n_draft + 1))
-        return int(block_tokens)
-
     def fused_tokens_per_tick(self, n_decode: Optional[int] = None) -> int:
         """Tokens ONE device dispatch covers on a tick with ``n_decode``
-        decoding rows (default: all rows) — the analytic twin of
-        :meth:`paged_launches_per_block` for the stall-free scheduler.
-        Phase-split ticks dispatch only the decode block (the prefill
-        chunk rides a SECOND call the decode rows stall behind); a
-        fused tick packs the same block plus however many chunk slots
-        the ``tokens_per_tick`` budget leaves room for — floored at one
-        slot, so a saturated decode set still makes prefill progress
-        exactly like the phase-split tick did."""
+        decoding rows (default: all rows).  Phase-split ticks dispatch
+        only the decode block (the prefill chunk rides a SECOND call
+        the decode rows stall behind); a fused tick packs the same
+        block plus however many chunk slots the ``tokens_per_tick``
+        budget leaves room for — floored at one slot, so a saturated
+        decode set still makes prefill progress exactly like the
+        phase-split tick did."""
         n = self.rows if n_decode is None else int(n_decode)
         dt = n * self.multi_step
         if not self._fused:
@@ -2484,54 +2420,6 @@ class ContinuousBatcher:
                      _c(pool, jnp.asarray([src], jnp.int32),
                         jnp.asarray(dst, jnp.int32)))
 
-    def _init_prefix(self, prefix: np.ndarray) -> None:
-        """Reserve pages for the shared prefix and prefill it once —
-        into the target pool, and (speculative mode) into the draft's
-        paged pool the same way: both sides then reference the prefix
-        read-only, with a partially-filled last page kept as a
-        copy-on-write TEMPLATE copied into each admitted row's first own
-        page so row writes never touch shared state."""
-        if prefix.ndim != 1 or prefix.size == 0:
-            raise ValueError("prefix must be a non-empty 1-D token array")
-        if prefix.size >= self.max_len:
-            raise ValueError(f"prefix ({prefix.size} tokens) leaves no "
-                             f"room under max_len ({self.max_len})")
-        self.prefix_len = int(prefix.size)
-        full = self.prefix_len // self.page_size
-        tail = self.prefix_len % self.page_size
-        n_reserve = full + (1 if tail else 0)
-        sides = [(self.t_side, self.cfg, self.params)]
-        if self.d_side is not None:
-            sides.append((self.d_side, self.draft_cfg, self.draft_params))
-        sharded = self.mesh is not None
-        for side, cfg, params in sides:
-            pages = [side.alloc.reserve_page() for _ in range(n_reserve)]
-            # One prefill row PER SHARD, all with the same tokens and the
-            # same (symmetric) local page ids: every shard's sub-pool gets
-            # its own copy of the prefix, which its rows then reference
-            # read-only.
-            table = np.full((self.n_shards, side.np_max), side.sink,
-                            np.int32)
-            table[:, :n_reserve] = pages
-            toks = np.tile(prefix[None], (self.n_shards, 1))
-
-            @partial(jax.jit, donate_argnums=1)
-            def prefill_prefix(params, pool, t, toks, cfg=cfg):
-                cache = dict(pool, pages=t)
-                _, cache = decode_step(cfg, params, cache, toks, 0,
-                                       sharded=sharded, mesh=self.mesh)
-                return {"k": cache["k"], "v": cache["v"]}
-
-            side.pool = prefill_prefix(params, side.pool,
-                                       jnp.asarray(table),
-                                       jnp.asarray(toks))
-            if tail:
-                side.tail_template = pages[-1]
-                side.shared_pages = pages[:-1]
-            else:
-                side.shared_pages = pages
-            side.shared_len = len(side.shared_pages) * self.page_size
-
     # -- compiled shapes --------------------------------------------------
 
     def _host_read(self, x):
@@ -2784,9 +2672,8 @@ class ContinuousBatcher:
 
     def _make_draft_chunk(self):
         """Jitted DRAFT prompt writer over the draft's paged pool: serves
-        both the whole-prompt prefill (offset prefix_len — the prefix
-        pages are shared, so only the prompt is written) and chunked
-        prefill's per-chunk advance.  The caller passes a one-hot
+        both the whole-prompt prefill (offset 0) and chunked prefill's
+        per-chunk advance.  The caller passes a one-hot
         n_shards-row batch (``_one_hot_call``); one compile per chunk
         width."""
         sharded = self.mesh is not None
@@ -2986,13 +2873,9 @@ class ContinuousBatcher:
             @partial(jax.jit, donate_argnums=1)
             def prefill(params, pool, table, prompt, length, rid):
                 cache = dict(pool, pages=table)
-                # With a shared prefix the chunk prefills AT OFFSET
-                # prefix_len: rope positions, causal bounds, and page
-                # writes all follow (token tt of the chunk sees cache
-                # positions <= prefix_len + tt).
                 logits, cache = decode_step(self.cfg, params, cache, prompt,
-                                            self.prefix_len,
-                                            sharded=sharded, mesh=self.mesh)
+                                            0, sharded=sharded,
+                                            mesh=self.mesh)
                 last = jnp.take_along_axis(
                     logits, (length - 1)[:, None, None], axis=1)[:, 0]
                 nxt = self._sample(last, rid, jnp.zeros_like(rid))
@@ -3005,14 +2888,13 @@ class ContinuousBatcher:
     # -- host-side bookkeeping --------------------------------------------
 
     def _worst_pages(self, req: Request) -> tuple:
-        """Worst-case OWN pages beyond the shared prefix pages, per side,
-        plus the absolute position cap the reservation covers:
+        """Worst-case OWN pages, per side, plus the absolute position
+        cap the reservation covers:
         ``(target, draft, need_len)`` (draft 0 without speculative
         mode)."""
         width = -(-req.prompt.size // self.prefill_bucket) * \
             self.prefill_bucket
-        need_len = self.prefix_len + max(
-            width, req.prompt.size + req.max_new_tokens - 1)
+        need_len = max(width, req.prompt.size + req.max_new_tokens - 1)
         if self.draft_cfg is not None:
             # A speculative round at the final position still verifies a
             # (k+1)-token chunk: its writes overshoot by up to n_draft
@@ -3028,31 +2910,28 @@ class ContinuousBatcher:
         # reservation, and writes past it land on sink columns.
         if need_len > self.max_len:
             raise ValueError(
-                f"request needs {need_len} cache positions (prefix "
-                f"{self.prefix_len} + prompt {req.prompt.size} padded to "
-                f"{width}, plus {req.max_new_tokens} new tokens) > "
+                f"request needs {need_len} cache positions (prompt "
+                f"{req.prompt.size} padded to {width}, plus "
+                f"{req.max_new_tokens} new tokens) > "
                 f"max_len ({self.max_len})")
         # pages by the ENTRIES the context can hold on its way to need_len
         # (EVA: summaries and one window; otherwise one per position)
-        wt = -(-(self.cfg.cache_entries_peak(0, need_len)
-                 - self.t_side.shared_len) // self.page_size)
+        wt = -(-self.cfg.cache_entries_peak(0, need_len) // self.page_size)
         wd = 0
         if self.d_side is not None:
-            wd = -(-(need_len - self.d_side.shared_len) // self.page_size)
+            wd = -(-need_len // self.page_size)
         return wt, wd, need_len
 
     def _req_digests(self, req: Request) -> list:
         """Chain digests of ``req``'s complete page-aligned prompt
-        chunks (memoized on the request, keyed by the chunk geometry so
-        a request replayed into a differently-paged batcher rehashes —
+        chunks (memoized on the request, keyed by the page size so a
+        request replayed into a differently-paged batcher rehashes —
         without the memo, a request waiting for a row would rehash its
         prompt every admission tick)."""
-        pc = self._pcache
-        key = (pc.page_size, pc.first, pc.seed)
+        ps = self.page_size
         memo = getattr(req, "_pfx_digests", None)
-        if memo is None or memo[0] != key:
-            memo = (key, _ph.prompt_digests(req.prompt, pc.page_size,
-                                            pc.first, pc.seed))
+        if memo is None or memo[0] != ps:
+            memo = (ps, _ph.prompt_digests(req.prompt, ps))
             req._pfx_digests = memo
         return memo[1]
 
@@ -3077,8 +2956,7 @@ class ContinuousBatcher:
         nodes = self._pcache.match(shard, digs)
         if not nodes:
             return None
-        E = self.prefix_len + int(req.prompt.size)
-        sl = self.t_side.shared_len
+        E = int(req.prompt.size)
         ps, bucket = self.page_size, self.prefill_bucket
         n = len(nodes)
         if max_nodes is not None:
@@ -3087,14 +2965,12 @@ class ContinuousBatcher:
                 return None
         if self.prefill_chunk is not None:
             c = self.prefill_chunk
-            while n and (sl + n * ps > E - 1
-                         or (sl + n * ps - self.prefix_len) % c):
+            while n and (n * ps > E - 1 or (n * ps) % c):
                 n -= 1
-            return (_PrefixPlan(nodes[:n], False, sl + n * ps)
-                    if n else None)
+            return _PrefixPlan(nodes[:n], False, n * ps) if n else None
         while n:
-            cow = sl + n * ps >= E
-            ts = E - 1 if cow else sl + n * ps
+            cow = n * ps >= E
+            ts = E - 1 if cow else n * ps
             w = -(-(E - ts) // bucket) * bucket
             if ts + w <= self.np_max * ps:
                 return _PrefixPlan(nodes[:n], cow, ts)
@@ -3214,12 +3090,12 @@ class ContinuousBatcher:
         """Every padded prompt width non-chunked admission can dispatch:
         ``_admit_dispatch`` pads prompts to multiples of
         ``prefill_bucket``, and ``_worst_pages`` admits only widths
-        whose reservation (``prefix_len + width`` at minimum) fits
-        ``max_len`` — one jit trace each, mirroring the linear
-        ``_prefill_fns`` cache the live path fills lazily.  (Chunked
+        whose reservation (``width`` at minimum) fits ``max_len`` — one
+        jit trace each, mirroring the linear ``_prefill_fns`` cache the
+        live path fills lazily.  (Chunked
         mode has ONE chunk width and doesn't use this.)"""
         b = self.prefill_bucket
-        cap = ((self.max_len - self.prefix_len) // b) * b
+        cap = (self.max_len // b) * b
         if self._eva_roll is not None:
             cap = min(cap, self.cfg.eva_window)     # windows, then a tail
         return list(range(b, cap + 1, b)) or [b]
@@ -3243,8 +3119,8 @@ class ContinuousBatcher:
         prefill/tail/draft-chunk compiles are skipped the same way.
 
         Every write a warmup call dispatches lands on the sink page
-        (the table is all-sink), so no live row, shared-prefix page, or
-        prefix-cache state is touched: a warmed batcher's outputs are
+        (the table is all-sink), so no live row or prefix-cache state
+        is touched: a warmed batcher's outputs are
         bit-identical to a cold one's.  Call at boot, before
         :meth:`serve`/:meth:`run` — moving first-request compilation
         off the serving path is what the fleet's ``warming`` replica
@@ -3372,13 +3248,13 @@ class ContinuousBatcher:
                         self.draft_params, self.d_side.pool,
                         sink_table(self.d_side),
                         jnp.asarray(np.zeros((nd, w), np.int32)),
-                        jnp.asarray(self.prefix_len, jnp.int32))
+                        jnp.asarray(0, jnp.int32))
                     jax.block_until_ready(self.d_side.pool)
                     compiled.append(f"draft_chunk[{w}]")
             for side in (self.t_side, self.d_side):
                 if side is None:
                     continue
-                if side.tail_template is not None or side.pcache is not None:
+                if side.pcache is not None:
                     dst = np.full((nd,), side.sink, np.int32)
                     side.pool = side.copy(side.pool, side.sink, dst)
                     jax.block_until_ready(side.pool)
@@ -3448,10 +3324,10 @@ class ContinuousBatcher:
         """PREFILL-ONLY execution: run ``request``'s prompt through this
         batcher's (chunked) prefill on a borrowed row and return its
         paged-KV state as a compact host artifact — per-layer page
-        buffers for every position past the shared prefix (int8 pools
-        export values AND scales bit-exactly), page-table/geometry
-        metadata, and the sampler state (first token, the ``rid`` whose
-        in-graph key folds produced it).  The row's pages are released
+        buffers for every position (int8 pools export values AND scales
+        bit-exactly), page-table/geometry metadata, and the sampler
+        state (first token, the ``rid`` whose in-graph key folds
+        produced it).  The row's pages are released
         before returning; a matching batcher imports the artifact with
         ``submit(request, prefilled=artifact)`` and enters decode
         directly, token-for-token equivalent to admitting the request
@@ -3527,12 +3403,11 @@ class ContinuousBatcher:
 
     def _side_page_export(self, side: _PagedSide, pool, row: int,
                           n: int, pad_pow2: bool):
-        """Gather ``row``'s pages covering [shared_len, shared_len +
-        n*page_size) from ``pool`` to host — one side of an export.
-        ``pad_pow2`` buckets the gather's page count to a power of two
-        (padding with sink reads, sliced off host-side)."""
-        ns = len(side.shared_pages)
-        ids = np.asarray(side.table_np()[row, ns:ns + n], np.int32)
+        """Gather ``row``'s first ``n`` pages from ``pool`` to host —
+        one side of an export.  ``pad_pow2`` buckets the gather's page
+        count to a power of two (padding with sink reads, sliced off
+        host-side)."""
+        ids = np.asarray(side.table_np()[row, :n], np.int32)
         if pad_pow2:
             m = self._pow2(n)
             if m > n:
@@ -3547,14 +3422,11 @@ class ContinuousBatcher:
                     pad_pow2: bool = False,
                     final: bool = False) -> dict:
         """Snapshot ``row``'s post-prefill KV into a host artifact: the
-        pages covering absolute positions [shared_len, pos) — cached
-        prefix pages and own pages alike, in table order — pulled to
-        host in one gather.  A speculative batcher's artifact carries
-        the DRAFT pool's paired payload over the same positions
-        (``dk``/``dv`` + the ``draft`` geometry header), so a spec row
-        moves whole.  Shared-prefix pages are NOT exported: a
-        same-``prefix`` importer already holds identical ones (both
-        sides prefilled the same tokens with the same params).
+        pages covering absolute positions [0, pos) — cached prefix
+        pages and own pages alike, in table order — pulled to host in
+        one gather.  A speculative batcher's artifact carries the DRAFT
+        pool's paired payload over the same positions (``dk``/``dv`` +
+        the ``draft`` geometry header), so a spec row moves whole.
         ``pad_pow2`` buckets the GATHER's page count to a power of two
         (padding with sink reads, sliced off host-side) so the tier's
         park path dispatches log2(np_max) compiled gathers instead of
@@ -3564,7 +3436,7 @@ class ContinuousBatcher:
         boundary: the pipelined loop advances ``pos``/``step`` at
         dispatch, so a finished row's host view can overshoot the
         committed stream by the in-flight block — but every position below
-        ``prefix + prompt + len(out) - 1`` was written exactly once
+        ``prompt + len(out) - 1`` was written exactly once
         with the true token sequence (positions only move forward), so
         clamping there exports exactly the resumable state.  This is
         what lets session parking work in every decode mode instead of
@@ -3576,15 +3448,18 @@ class ContinuousBatcher:
         toks = [int(t) for t in state.out]
         if final:
             step = len(toks)
-            E = self.prefix_len + int(state.req.prompt.size) + step - 1
-        n = -(-(E - side.shared_len) // ps)
+            E = int(state.req.prompt.size) + step - 1
+        n = -(-E // ps)
         kv = self._side_page_export(side, self.pool, row, n, pad_pow2)
         quantized = isinstance(self.pool["k"], QTensor)
         art = {
             "version": 1,
             "page_size": ps,
-            "prefix_len": self.prefix_len,
-            "shared_len": side.shared_len,
+            # (a batcher-level static prefix once offset every position:
+            # the wire format keeps its two fields, always 0, and an
+            # importer still refuses any other value)
+            "prefix_len": 0,
+            "shared_len": 0,
             "pos": int(E),
             "prompt_len": int(state.req.prompt.size),
             "first_token": int(state.out[0]),
@@ -3614,8 +3489,7 @@ class ContinuousBatcher:
             art["v"] = np.asarray(kv["v"])
         if self.d_side is not None:
             # The paired draft-side payload: same positions, the draft
-            # pool's pages (draft shared_len equals the target's — both
-            # sides prefilled the same prefix at the same page size).
+            # pool's pages.
             dkv = self._side_page_export(self.d_side, self.d_side.pool,
                                          row, n, pad_pow2)
             art["draft"] = self._draft_geom()
@@ -3640,8 +3514,8 @@ class ContinuousBatcher:
                              f"{art.get('version')!r}")
         quantized = isinstance(self.pool["k"], QTensor)
         for key, want in (("page_size", self.page_size),
-                          ("prefix_len", self.prefix_len),
-                          ("shared_len", self.t_side.shared_len),
+                          ("prefix_len", 0),
+                          ("shared_len", 0),
                           ("quantized", quantized)):
             if art.get(key) != want:
                 raise ValueError(
@@ -3687,13 +3561,12 @@ class ContinuousBatcher:
                 raise ValueError("suspended KV artifact ends at the "
                                  "stop token — nothing to resume")
         E = art.get("pos")
-        if E != self.prefix_len + int(req.prompt.size) + step - 1 \
+        if E != int(req.prompt.size) + step - 1 \
                 or art.get("prompt_len", -1) != int(req.prompt.size):
             raise ValueError(
                 f"KV artifact covers {E!r} positions; this request needs "
-                f"prefix {self.prefix_len} + prompt {req.prompt.size} "
-                f"(+ {step - 1} resumed tokens)")
-        n = -(-(E - self.t_side.shared_len) // self.page_size)
+                f"prompt {req.prompt.size} (+ {step - 1} resumed tokens)")
+        n = -(-E // self.page_size)
         pool_k = self.pool["k"].values if quantized else self.pool["k"]
         self._check_payload_arrays(art, quantized, n, self.cfg, pool_k)
         self._validate_artifact_draft(art, n, step)
@@ -3791,7 +3664,7 @@ class ContinuousBatcher:
                           tick=self._tick["tick"])
         side = self.t_side
         n = art["k"].shape[1]
-        side.ensure(row, side.shared_len + n * self.page_size)
+        side.ensure(row, n * self.page_size)
         ids = side.alloc.rows[row]
         if art["quantized"]:
             payload = {
@@ -3843,7 +3716,7 @@ class ContinuousBatcher:
         cache is bit-identical to a local admission's."""
         dside = self.d_side
         if isinstance(art.get("dk"), np.ndarray):
-            dside.ensure(row, dside.shared_len + n * self.page_size)
+            dside.ensure(row, n * self.page_size)
             dids = dside.alloc.rows[row]
             if art["draft"]["quantized"]:
                 dpayload = {
@@ -3862,14 +3735,7 @@ class ContinuousBatcher:
         length = int(req.prompt.size)
         bucket = self.prefill_chunk or self.prefill_bucket
         width = -(-length // bucket) * bucket
-        fresh = dside.alloc.allocated(row) == 0
-        dside.ensure(row, min(self.prefix_len + width, need))
-        if dside.tail_template is not None and fresh \
-                and not dside.row_cached.get(row) \
-                and dside.alloc.allocated(row):
-            dst = np.full((self.n_shards,), dside.sink, np.int32)
-            dst[dside.alloc.shard_of(row)] = dside.alloc.rows[row][0]
-            dside.pool = dside.copy(dside.pool, dside.tail_template, dst)
+        dside.ensure(row, min(width, need))
         padded = np.zeros((1, width), np.int32)
         padded[0, :length] = req.prompt
         if self._chunk_prefill is not None:
@@ -3881,12 +3747,12 @@ class ContinuousBatcher:
                     dside, row, padded[:, off:off + c])
                 dside.pool = self._draft_chunk(
                     self.draft_params, dside.pool, dtable, dtoks,
-                    jnp.asarray(self.prefix_len + off, jnp.int32))
+                    jnp.asarray(off, jnp.int32))
         else:
             _, dtoks, dtable = self._one_hot_call(dside, row, padded)
             dside.pool = self._draft_chunk(
                 self.draft_params, dside.pool, dtable, dtoks,
-                jnp.asarray(self.prefix_len, jnp.int32))
+                jnp.asarray(0, jnp.int32))
 
     # -- the KV tier: prefix spill/promote + session park/resume -----------
 
@@ -4063,8 +3929,8 @@ class ContinuousBatcher:
             raise ValueError(f"unknown session artifact version "
                              f"{art.get('version')!r}")
         for key, want in (("page_size", self.page_size),
-                          ("prefix_len", self.prefix_len),
-                          ("shared_len", self.t_side.shared_len),
+                          ("prefix_len", 0),
+                          ("shared_len", 0),
                           ("quantized", False)):
             if art.get(key) != want:
                 raise ValueError(
@@ -4092,12 +3958,12 @@ class ContinuousBatcher:
                              "session history")
         covered = len(hist) - 1     # the last token is the tail's input
         E_art = art.get("pos")
-        if E_art != self.prefix_len + covered:
+        if E_art != covered:
             raise ValueError(
                 f"session artifact covers {E_art!r} positions; its "
-                f"history implies {self.prefix_len + covered}")
+                f"history implies {covered}")
         ps = self.page_size
-        n = -(-(E_art - self.t_side.shared_len) // ps)
+        n = -(-E_art // ps)
         want_shape = (int(self.cfg.n_layers), n, int(self.cfg.kv_heads),
                       ps, int(self.cfg.head_dim))
         dtype = np.dtype(self.pool["k"].dtype)
@@ -4140,7 +4006,7 @@ class ContinuousBatcher:
                         f"{dshape}/{ddtype} array")
         # The tail's padded prefill window must fit the page table
         # (same bound the prefix-plan trimmer enforces).
-        E = self.prefix_len + int(req.prompt.size)
+        E = int(req.prompt.size)
         w = -(-(E - E_art) // self.prefill_bucket) * self.prefill_bucket
         if E_art + w > self.np_max * ps:
             raise ValueError("session tail window exceeds the page "
@@ -4186,10 +4052,7 @@ class ContinuousBatcher:
         self._trace_event(req, "session_resume", rid=rid, row=row,
                           session=str(req.session_id),
                           covered=int(art["pos"]), tick=self._tick["tick"])
-        # The artifact's first own page embeds any shared-prefix tail
-        # template (the parking row's copy), so the plain ensure is
-        # right — no template re-copy, exactly like _admit_import.
-        side.ensure(row, side.shared_len + n * self.page_size)
+        side.ensure(row, n * self.page_size)
         ids = list(side.alloc.rows[row])
         # Bucket the install to a power-of-two page count (pad slots
         # scatter zeros onto the sink page — a write dump by
@@ -4214,11 +4077,11 @@ class ContinuousBatcher:
             # The paired draft payload backs the same positions of the
             # draft pool (validated present and shape-matched).
             dside = self.d_side
-            dside.ensure(row, dside.shared_len + n * self.page_size)
+            dside.ensure(row, n * self.page_size)
             dside.pool = pow2_install(dside.pool, dside.sink,
                                       dside.alloc.rows[row],
                                       art["dk"], art["dv"])
-        E = self.prefix_len + int(req.prompt.size)
+        E = int(req.prompt.size)
         ts = int(art["pos"])
         tlen = E - ts
         w = -(-tlen // self.prefill_bucket) * self.prefill_bucket
@@ -4713,27 +4576,13 @@ class ContinuousBatcher:
 
     def _ensure_sides(self, row: int, length: int, start: int = 0) -> None:
         """Back ABSOLUTE positions [0, length) of ``row`` on the target
-        (and, speculative mode, draft) side.  The first time a row gains
-        own pages, a partially-shared prefix tail page is copied into its
-        first own page (copy-on-write) before any row write can land in
-        it.  Under EVA the pages back entries: the most the row holds
-        while its context grows from ``start`` to ``length``."""
+        (and, speculative mode, draft) side.  Under EVA the pages back
+        entries: the most the row holds while its context grows from
+        ``start`` to ``length``."""
         length = self.cfg.cache_entries_peak(start, length)
-        sides = ([self.t_side] if self.d_side is None
-                 else [self.t_side, self.d_side])
-        for side in sides:
-            fresh = side.alloc.allocated(row) == 0
-            side.ensure(row, length)
-            # A row holding CACHED prefix pages skips the template copy:
-            # its first cacheable page (which embeds the template
-            # content) came from the cache — its first OWN page covers a
-            # later position range entirely.
-            if (side.tail_template is not None and fresh
-                    and not side.row_cached.get(row)
-                    and side.alloc.allocated(row)):
-                dst = np.full((self.n_shards,), side.sink, np.int32)
-                dst[side.alloc.shard_of(row)] = side.alloc.rows[row][0]
-                side.pool = side.copy(side.pool, side.tail_template, dst)
+        self.t_side.ensure(row, length)
+        if self.d_side is not None:
+            self.d_side.ensure(row, length)
 
     def _admit_dispatch(self, row: int, rid: int, req: Request, wt: int,
                         wd: int, need: int, active: Dict[int, _Row],
@@ -4761,8 +4610,7 @@ class ContinuousBatcher:
             self._pcache.acquire(row, plan.nodes)
             self._pcache.count("hits")
             self._pcache.count("hit_pages", len(plan.nodes))
-            self._pcache.count("hit_tokens",
-                               plan.tail_start - self.prefix_len)
+            self._pcache.count("hit_tokens", plan.tail_start)
             wt -= plan.save
             if self.d_side is not None:
                 # Coupled nodes: the draft-side reservation shrinks by
@@ -4775,15 +4623,14 @@ class ContinuousBatcher:
             # one chunk per tick, interleaved with the batched decode
             # step.  On a cache hit, filling starts AT THE TAIL (the
             # mapped pages already hold chunks [0, filled)).
-            self._ensure_sides(row, self.prefix_len + width)
+            self._ensure_sides(row, width)
             padded = np.zeros((1, width), np.int32)
             padded[0, :length] = req.prompt
-            state = _Row(rid=rid, req=req, pos=self.prefix_len + length,
+            state = _Row(rid=rid, req=req, pos=int(length),
                          step=1, last=0, out=[], worst_pages=wt,
                          worst_draft=wd, t_admit=t_admit,
                          admit_tick=tick["tick"], padded=padded,
-                         filled=(0 if plan is None
-                                 else plan.tail_start - self.prefix_len),
+                         filled=0 if plan is None else plan.tail_start,
                          decoding=False, limit=need)
             active[row] = state
             return None
@@ -4806,7 +4653,7 @@ class ContinuousBatcher:
             active[row] = state
             self._eva_account(active)
             return row, state, tok, 0
-        self._ensure_sides(row, self.prefix_len + width)
+        self._ensure_sides(row, width)
         padded = np.zeros((1, width), np.int32)
         padded[0, :length] = req.prompt
         s, toks, table = self._one_hot_call(self.t_side, row, padded)
@@ -4824,9 +4671,9 @@ class ContinuousBatcher:
             _, dtoks, dtable = self._one_hot_call(self.d_side, row, padded)
             self.d_side.pool = self._draft_chunk(
                 self.draft_params, self.d_side.pool, dtable, dtoks,
-                jnp.asarray(self.prefix_len, jnp.int32))
+                jnp.asarray(0, jnp.int32))
         tok.copy_to_host_async()    # transfer overlaps later dispatches
-        state = _Row(rid=rid, req=req, pos=self.prefix_len + length, step=1,
+        state = _Row(rid=rid, req=req, pos=int(length), step=1,
                      last=0, out=[], worst_pages=wt, worst_draft=wd,
                      t_admit=t_admit, admit_tick=tick["tick"],
                      prefill_tokens=width, limit=need)
@@ -4870,13 +4717,12 @@ class ContinuousBatcher:
         a fresh own page (``_copy_page`` copy-on-write) so the
         last-token rewrite never touches shared state."""
         side = self.t_side
-        E = self.prefix_len + int(req.prompt.size)
+        E = int(req.prompt.size)
         if plan.cow:
             cow_node = plan.nodes[-1]
             src = cow_node.page
             self._pcache.unmap_last(row)
-            side.ensure(row, side.shared_len
-                        + len(plan.nodes) * self.page_size)
+            side.ensure(row, len(plan.nodes) * self.page_size)
             dst = np.full((self.n_shards,), side.sink, np.int32)
             dst[side.alloc.shard_of(row)] = side.alloc.rows[row][0]
             side.pool = side.copy(side.pool, src, dst)
@@ -4885,8 +4731,7 @@ class ContinuousBatcher:
                 # rewrite at E-1 (the spec round's draft scan writes
                 # it), so it is copied-on-write symmetrically.
                 dside = self.d_side
-                dside.ensure(row, dside.shared_len
-                             + len(plan.nodes) * self.page_size)
+                dside.ensure(row, len(plan.nodes) * self.page_size)
                 ddst = np.full((self.n_shards,), dside.sink, np.int32)
                 ddst[dside.alloc.shard_of(row)] = dside.alloc.rows[row][0]
                 dside.pool = dside.copy(dside.pool, cow_node.dpage, ddst)
@@ -4919,7 +4764,7 @@ class ContinuousBatcher:
             # The draft pool's tail: the same uncached suffix written
             # at the same offset through the draft chunk writer — its
             # cached prefix pages (the twins mapped above) already
-            # cover [shared_len, ts).
+            # cover [0, ts).
             _, dtoks, dtable = self._one_hot_call(self.d_side, row,
                                                   padded)
             self.d_side.pool = self._draft_chunk(
@@ -5003,7 +4848,7 @@ class ContinuousBatcher:
             rids[s] = row.rid
             self.pool, tok = self._chunk_prefill(
                 self.params, self.pool, table, ctoks,
-                jnp.asarray(self.prefix_len + row.filled, jnp.int32),
+                jnp.asarray(row.filled, jnp.int32),
                 jnp.asarray(caps), jnp.asarray(rids))
             if self.d_side is not None:
                 # The draft's prompt chunks advance in lockstep so it is
@@ -5011,7 +4856,7 @@ class ContinuousBatcher:
                 _, dtoks, dtable = self._one_hot_call(self.d_side, r, chunk)
                 self.d_side.pool = self._draft_chunk(
                     self.draft_params, self.d_side.pool, dtable, dtoks,
-                    jnp.asarray(self.prefix_len + row.filled, jnp.int32))
+                    jnp.asarray(row.filled, jnp.int32))
             row.filled += c
         if row.filled < row.padded.shape[1]:
             return None
@@ -5066,7 +4911,7 @@ class ContinuousBatcher:
                 row = active[r]
                 ctable[i] = tbl[r]
                 chunks[i] = row.padded[0, row.filled:row.filled + c]
-                cpos[i] = self.prefix_len + row.filled
+                cpos[i] = row.filled
                 caps[i] = row.req.prompt.size - 1 - row.filled
                 crids[i] = row.rid
             toks = np.zeros((self.rows,), np.int32)
@@ -5475,11 +5320,10 @@ class ContinuousBatcher:
 
     def _suspend_row(self, r: int, active: Dict[int, _Row],
                      free_rows: List[int]) -> dict:
-        """Snapshot row ``r`` into a resumable KV artifact (pages past
-        the shared prefix + sampler state incl. the emitted tokens) and
-        release it — a suspended request IS a KV export, re-admitted
-        through ``submit(prefilled=...)`` here or on any matching
-        batcher."""
+        """Snapshot row ``r`` into a resumable KV artifact (its pages +
+        sampler state incl. the emitted tokens) and release it — a
+        suspended request IS a KV export, re-admitted through
+        ``submit(prefilled=...)`` here or on any matching batcher."""
         state = active[r]
         art = self._export_row(r, state)
         self._request_done("suspended", state.req, state)
@@ -5493,18 +5337,11 @@ class ContinuousBatcher:
         so a row admitted only thanks to a deep cache plan on a tight
         pool must not be suspended locally — its resume would exceed
         the pool outright and the parked artifact could never land."""
-        side = self.t_side
-        reserved = 1 + len(side.shared_pages) \
-            + (1 if side.tail_template is not None else 0)
         wt, wd, _ = self._worst_pages(req)
-        if wt > side.n_pages - reserved:
+        # (one page of each pool is the reserved sink)
+        if wt > self.t_side.n_pages - 1:
             return False
-        if self.d_side is not None:
-            dside = self.d_side
-            dreserved = 1 + len(dside.shared_pages) \
-                + (1 if dside.tail_template is not None else 0)
-            return wd <= dside.n_pages - dreserved
-        return True
+        return self.d_side is None or wd <= self.d_side.n_pages - 1
 
     def _maybe_preempt(self, priority: int, active: Dict[int, _Row],
                        free_rows: List[int]) -> bool:

@@ -16,8 +16,8 @@ _COUNT_RE = r"--xla_force_host_platform_device_count=(\d+)"
 CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 #: The cache's home when the environment names none: one fixed directory in
 #: the checkout (git-ignored), so every process of a run — scheduler-launched
-#: tasks, replicas, bench, tests — hits what any other compiled; a per-run or
-#: per-pid directory would never hit.
+#: tasks, replicas, the benchmark, tests — hits what any other compiled; a
+#: per-run or per-pid directory would never hit.
 DEFAULT_CACHE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__)))), ".jax_cache")
@@ -56,8 +56,7 @@ def enable_compile_cache() -> Optional[str]:
     quick its compile: a process of this system compiles hundreds of small
     ones at start-up, and a step that compiles in under JAX's default
     one-second threshold is still a step the next process should not pay.
-    Called by ``runtime.initialize``, the fleet replica, ``bench.py`` and
-    the tests.
+    Called by ``runtime.initialize``, the fleet replica and the tests.
     """
     import jax
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
